@@ -90,6 +90,16 @@ import sys
 from typing import List, Optional
 
 
+def _ran_on() -> dict:
+    """What a device command ran on, printed with its result so a CPU run
+    and a chip run never give the same line: platform, device kind and
+    count as JAX reports them, and the kernel plan ``auto`` resolved to."""
+    from blit.device import device_facts
+    from blit.ops.channelize import last_kernel_plan
+
+    return {**device_facts(), "kernel_plan": last_kernel_plan()}
+
+
 def _cmd_reduce(args: argparse.Namespace) -> int:
     from blit.pipeline import RawReducer, reducer_for_product
 
@@ -115,6 +125,7 @@ def _cmd_reduce(args: argparse.Namespace) -> int:
                 "nifs": hdr.get("nifs"),
                 "input_bytes": stats.input_bytes,
                 "gbps": round(stats.gbps, 3),
+                **_ran_on(),
             }
         )
     )
@@ -122,6 +133,7 @@ def _cmd_reduce(args: argparse.Namespace) -> int:
 
 
 def _cmd_search(args: argparse.Namespace) -> int:
+    from blit.ops.pallas_dedoppler import last_dedoppler_plan
     from blit.pipeline import PRODUCT_PRESETS
     from blit.search import DedopplerReducer
 
@@ -153,6 +165,8 @@ def _cmd_search(args: argparse.Namespace) -> int:
                 # distributions (sync path populates tree_s; the async
                 # plane's equivalent is out.chunk_latency_s).
                 "hists": tl.get("hists", {}),
+                **_ran_on(),
+                "dedoppler_plan": last_dedoppler_plan(),
             }
         )
     )
@@ -377,8 +391,12 @@ def _cmd_scan(args: argparse.Namespace) -> int:
                 "windows": hdr.get("search_windows"),
                 "nchans": hdr.get("nchans"),
             }))
+        from blit.ops.pallas_dedoppler import last_dedoppler_plan
+
         print(json.dumps({"window_frames": wf, "parallel": parallel,
-                          "tuning": tuning, "stages": tl.report()}))
+                          "tuning": tuning, "stages": tl.report(),
+                          **_ran_on(),
+                          "dedoppler_plan": last_dedoppler_plan()}))
         return 0
     kw = dict(
         inventories=invs,
@@ -435,7 +453,8 @@ def _cmd_scan(args: argparse.Namespace) -> int:
         )
     # Per-stage throughput (read/device/readback/write), like blit reduce.
     print(json.dumps({"window_frames": wf, "parallel": parallel,
-                      "tuning": tuning, "stages": tl.report()}))
+                      "tuning": tuning, "stages": tl.report(),
+                      **_ran_on()}))
     return 0
 
 
@@ -627,6 +646,10 @@ def _spawn_fleet_peers(td: str, npeers: int, *, concurrency: int,
         if i >= npeers:
             cmd.append("--standby")
         env = dict(os.environ)
+        # One process per chip, and these rigs start several peers per
+        # host: unless the caller names a platform the peers derive on
+        # the CPU.  Each peer reports its platform in /stats and the
+        # bench reports carry it, so a CPU fleet never reads as a chip.
         env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(extra_env or {})
         logf = open(os.path.join(td, f"{name}.log"), "w")
@@ -985,6 +1008,7 @@ def _serve_bench_fleet(args: argparse.Namespace) -> int:
                 for k in tiers:
                     tiers[k] += int(c.get(k, 0))
                 per_peer[name] = {
+                    "platform": s.get("platform"),
                     "hit_rate": s.get("hit_rate"),
                     "scheduled": s.get("scheduled"),
                     "coalesced": s.get("coalesced"),
@@ -1511,14 +1535,6 @@ def _serve_bench_archive_day(args: argparse.Namespace) -> int:
     from blit.serve.scheduler import DeadlineExpired
     from blit.testing import build_observation_tree
 
-    try:
-        import jax
-
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — rig label only
-        backend = (os.environ.get("JAX_PLATFORMS") or "cpu").split(
-            ",")[0] or "cpu"
-
     def q(h, p: float) -> float:
         return round(h.percentile(p), 6) if h is not None and h.n else 0.0
 
@@ -1687,12 +1703,14 @@ def _serve_bench_archive_day(args: argparse.Namespace) -> int:
                     errors.append(f"probe: {e!r}")
                 tiers = {"hit.ram": 0, "hit.disk": 0, "hit.wire": 0,
                          "hit.cold": 0, "derive": 0, "miss": 0}
+                platforms = set()
                 for _name, url in sorted(peers.items()):
                     try:
                         _, _, s = http_json("GET", url, "/stats",
                                             timeout=5.0)
                     except OSError:
                         continue
+                    platforms.add(s.get("platform"))
                     cst = (s.get("cache") or {})
                     for k in tiers:
                         tiers[k] += int(cst.get(k, 0))
@@ -1712,6 +1730,10 @@ def _serve_bench_archive_day(args: argparse.Namespace) -> int:
                 c = door.stats()["counters"]
                 rep = {
                     "wire": wire_mode,
+                    # The rig label comes from the PEERS (the processes
+                    # that hold a device): this parent stays off JAX so
+                    # it never takes a chip a peer needs.
+                    "platform": ",".join(sorted(map(str, platforms))),
                     "wall_s": round(wall, 3),
                     "rps": (round(args.requests / wall, 1)
                             if wall else None),
@@ -1792,7 +1814,7 @@ def _serve_bench_archive_day(args: argparse.Namespace) -> int:
             "slo_ms": args.slo_ms,
             "clock_accel": round(accel, 1),
             "modeled_day_requests": int(args.requests * accel),
-            "config": {"backend": backend, "nfft": args.nfft,
+            "config": {"backend": bin_rep["platform"], "nfft": args.nfft,
                        "peers": args.peers,
                        "deflate": bool(args.deflate),
                        "disk_bytes": args.disk_bytes},
@@ -3709,6 +3731,13 @@ def _looks_like_raw(path: str) -> bool:
 # choices; tests/test_cli.py pins the two lists equal).
 _PRODUCTS = ("0000", "0001", "0002")
 
+# Commands that read files and reports only: they never import jax, so they
+# skip the compile-cache set-up (which does).
+_HOST_ONLY = frozenset((
+    "inventory", "info", "top", "bench-diff", "trace-view", "requests",
+    "incidents", "incident", "slo-report",
+))
+
 
 def _add_monitor_flags(parser: argparse.ArgumentParser) -> None:
     """The shared ``--monitor-*`` flag set (ISSUE 11): commands that run
@@ -4597,6 +4626,13 @@ def main(argv: Optional[List[str]] = None) -> int:
     psr.set_defaults(fn=_cmd_slo_report)
 
     args = p.parse_args(argv)
+    if args.command not in _HOST_ONLY:
+        # Every command that compiles device programs shares one
+        # persistent cache (the hi-res channelizer is not recompiled on
+        # each invocation); spawned `blit` peers pass through here too.
+        from blit.device import use_compile_cache
+
+        use_compile_cache()
     return args.fn(args)
 
 

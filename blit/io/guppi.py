@@ -160,7 +160,8 @@ class GuppiRaw(_BlockStream):
     still never fully load).
 
     ``native``: ``None`` auto-detects the built library; ``True`` requires
-    it; ``False`` forces the memmap path.
+    it; ``False`` forces the memmap path.  The device paths hold whatever
+    was chosen to :func:`require_native_reader`.
     """
 
     def __init__(self, path: str, native: Optional[bool] = None):
@@ -727,6 +728,25 @@ class GuppiScan(_BlockStream):
 
 
 RawSource = Union[str, Sequence[str], GuppiRaw, GuppiScan]
+
+
+def require_native_reader(raw) -> None:
+    """The device paths' reader rule: on an accelerator the block reader is
+    the native one or the run fails.  The memmap copy serves the CPU tests
+    (and ``native=False`` chooses it there by name); on a chip it would
+    feed the device at a fraction of the rate and say nothing.  Live
+    sources carry no reader of their own and pass."""
+    if getattr(raw, "native", True):
+        return
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{raw.path}: the native GUPPI reader is not in use on backend "
+            f"{backend!r} — build it with `make -C blit/native`; the memmap "
+            "reader is for CPU runs"
+        )
 
 
 def open_raw(src: RawSource, native: Optional[bool] = None):

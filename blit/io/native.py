@@ -3,7 +3,10 @@
 SURVEY.md §2.3: the reference's native surface lives in its dependencies —
 the bitshuffle HDF5 filter (C/SSE2/AVX2) and Blio's block readers.  blit
 provides C++ equivalents under ``blit/native/``; this module locates the
-built artifacts and degrades gracefully (NumPy fallbacks) when absent.
+built artifacts.  When they are absent the GUPPI reader falls back to a
+memmap copy — for CPU runs only: the device paths require the native
+reader (:func:`blit.io.guppi.require_native_reader`) — and the bitshuffle
+codec has no fallback at all.
 """
 
 from __future__ import annotations
@@ -80,10 +83,8 @@ def guppi_pread_strided(
     lib = guppi_lib()
     if lib is None:
         raise RuntimeError("native GUPPI reader unbuilt: make -C blit/native")
-    try:  # numpy 2.x home, 1.x fallback
-        from numpy.lib.array_utils import byte_bounds
-    except ImportError:  # pragma: no cover
-        from numpy import byte_bounds
+    from numpy.lib.array_utils import byte_bounds
+
     low, high = byte_bounds(dst)
     base = dst.ctypes.data
     if base < low or base + dst_stride * (nchan - 1) + chan_bytes > high:

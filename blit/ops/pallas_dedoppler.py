@@ -29,11 +29,11 @@ Three execution paths, byte-identical where they overlap:
 
 - the PURE-LAX reference (``kernel="reference"``) — rolls + adds only,
   runs everywhere (the tier-1 CPU path);
-- the Pallas TPU kernel (``kernel="pallas"``) — the same stage body on
-  VMEM-resident frequency tiles (halo = T columns of real neighbour
-  data), grid over tiles; ``interpret=True`` runs it on CPU for tests.
-  Both paths perform the identical per-element add sequence (one add
-  per stage), so results agree BITWISE, not just approximately.
+- the Pallas TPU kernel (``kernel="pallas"``) — the same stage
+  recursion on VMEM-resident frequency tiles (halo >= T columns of real
+  neighbour data), grid over tiles; ``interpret=True`` runs it on CPU
+  for tests.  Both paths perform the identical per-element add sequence
+  (one add per stage), so results agree BITWISE, not just approximately.
 - ``kernel="auto"`` resolves to pallas on TPU backends when
   :func:`fits` passes, else reference.
 
@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from blit.device import TPU_BACKEND
+
 # Per-instance VMEM budget for the tiled kernel (pallas_detect's stance:
 # leave headroom for double buffering on a ~16 MB part).
 _VMEM_BUDGET = 6 << 20
@@ -67,6 +69,18 @@ MAX_WINDOW = 1024
 # Encoded hit-table columns (:func:`dedoppler_hits` packed output):
 # [snr_bits(f32), power_bits(f32), drift_bins(i32), chan(i32)].
 HIT_PACK_COLS = 4
+
+
+# Kernel resolution of the most recent taylor_tree TRACE (the
+# blit.ops.channelize._LAST_PLAN convention): 'auto' must be attributable.
+_LAST_PLAN: dict = {}
+
+
+def last_dedoppler_plan() -> dict:
+    """Which drift-transform kernel the most recent :func:`taylor_tree`
+    trace resolved to (``{"kernel": "pallas" | "reference", ...}``; empty
+    until a trace happens — a jit cache hit does not refresh it)."""
+    return dict(_LAST_PLAN)
 
 
 def tree_path_shift(d: int, t: int, T: int) -> int:
@@ -96,11 +110,11 @@ def _check_window(T: int) -> None:
 
 
 def _tree_stages(buf: jax.Array, T: int) -> jax.Array:
-    """The shared tree body: ``(T, Fp)`` padded power → ``(T, Fp)`` drift
-    sums (drifts 0..T-1, module-docstring convention).  Rolls + adds
-    only — mosaic-safe inside the pallas kernel, XLA-friendly as the
-    reference — and ONE add per element per stage, so every execution
-    path produces bitwise-identical sums."""
+    """The reference tree body: ``(T, Fp)`` padded power → ``(T, Fp)``
+    drift sums (drifts 0..T-1, module-docstring convention).  Rolls +
+    adds only, ONE add per element per stage — the add sequence
+    :func:`_tree_kernel` repeats on refs, so both execution paths produce
+    bitwise-identical sums."""
     # (nblocks, L, Fp) block view; stage L -> 2L merges block pairs.
     buf = buf[:, None, :]  # (T, 1, Fp)
     L = 1
@@ -119,20 +133,66 @@ def _tree_stages(buf: jax.Array, T: int) -> jax.Array:
     return buf[0]
 
 
+def _halo(T: int) -> int:
+    """Halo columns per tile: T real neighbour columns, rounded up to a
+    whole number of 128-lane vregs so the in-kernel lane rotations stay
+    aligned."""
+    return -(-T // 128) * 128
+
+
 def fits(T: int, tile: int = _DEF_TILE) -> bool:
-    """VMEM-fit gate for the tiled pallas kernel: the (T, tile+T) f32
-    block plus one stage's worth of live scratch must fit the budget."""
+    """VMEM-fit gate for the tiled pallas kernel: two (T, tile+halo) f32
+    ping-pong buffers plus the double-buffered input, halo and output
+    blocks must fit the budget, and the halo must tile the body."""
     if T < 2 or T & (T - 1) or T > MAX_WINDOW:
         return False
-    per = T * (tile + T) * 4
-    # input block + output block + ~2 live stage buffers.
-    return 4 * per <= _VMEM_BUDGET
+    if tile % _halo(T):
+        return False
+    per = T * (tile + _halo(T)) * 4
+    return 6 * per <= _VMEM_BUDGET
 
 
-def _tree_kernel(T, x_ref, o_ref):
-    # x: (1, T, tile+T) power tile with T halo columns; o: (1, T, tile).
-    out = _tree_stages(x_ref[0], T)
-    o_ref[0] = out[:, : o_ref.shape[2]]
+def _bit_reverse_rows(x: jax.Array) -> jax.Array:
+    """Rows of ``(T, F)`` into bit-reversed time order (T a power of two):
+    the tree pairs rows that differ in the LOWEST time bit first, and in
+    this order every stage's pairs are two contiguous half-slabs."""
+    T, F = x.shape
+    k = T.bit_length() - 1
+    x = x.reshape((2,) * k + (F,))
+    return x.transpose(tuple(range(k - 1, -1, -1)) + (k,)).reshape(T, F)
+
+
+def _tree_kernel(T, x_ref, h_ref, o_ref, a_ref, b_ref):
+    """One frequency tile of the tree on refs.  ``x_ref`` (T, tile) body
+    and ``h_ref`` (T, halo) right-neighbour columns arrive with rows in
+    bit-reversed time order; ``a_ref``/``b_ref`` are (T, tile+halo)
+    ping-pong scratch.  Rows are laid out ``d * nb + p`` (drift-major, ``p``
+    the bit-reversed block index), so stage L -> 2L reads each drift
+    half's even blocks as one contiguous slab and its odd blocks as the
+    next — static slices only, and the same one add per element per stage
+    as :func:`_tree_stages`.  A lane rotation stands in for the roll: it
+    wraps, but no path's total shift reaches the halo's width, so the
+    wrapped columns never enter the first ``tile`` outputs."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    tile = x_ref.shape[1]
+    W = a_ref.shape[1]
+    a_ref[:, :tile] = x_ref[...]
+    a_ref[:, tile:] = h_ref[...]
+    src, dst = a_ref, b_ref
+    L, nb = 1, T
+    while L < T:
+        half = nb // 2
+        for d in range(2 * L):
+            s = (d + 1) >> 1
+            base = (d >> 1) * nb
+            bot = src[base + half:base + nb, :]
+            if s:
+                bot = pltpu.roll(bot, W - s, 1)
+            dst[d * half:(d + 1) * half, :] = src[base:base + half, :] + bot
+        src, dst = dst, src
+        L, nb = 2 * L, half
+    o_ref[...] = src[:, :tile]
 
 
 def taylor_tree(
@@ -155,8 +215,10 @@ def taylor_tree(
         # interpret=True is a request to EXERCISE the pallas kernel (CPU
         # smoke tests) — auto must not silently resolve it away to the
         # reference path.
-        want_pallas = interpret or jax.default_backend() == "tpu"
+        want_pallas = interpret or jax.default_backend() == TPU_BACKEND
         kernel = "pallas" if want_pallas and fits(T, tile) else "reference"
+    _LAST_PLAN.clear()
+    _LAST_PLAN.update(kernel=kernel, window_spectra=T, interpret=interpret)
     if kernel == "reference":
         xp = jnp.pad(power, ((0, 0), (0, T)))
         return _tree_stages(xp, T)[:, :F]
@@ -168,26 +230,28 @@ def taylor_tree(
             "use kernel='reference' or a smaller tile"
         )
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     ntiles = -(-F // tile)
-    # Pad so every tile has a full `tile` body plus T halo columns of
-    # real neighbour data (zeros past the band edge).
-    xp = jnp.pad(power, ((0, 0), (0, ntiles * tile + T - F)))
-    tiles = jnp.stack(
-        [
-            jax.lax.slice(xp, (0, i * tile), (T, i * tile + tile + T))
-            for i in range(ntiles)
-        ]
-    )  # (ntiles, T, tile+T)
+    halo = _halo(T)
+    # Pad so every tile has a full `tile` body plus `halo` columns of
+    # real neighbour data (zeros past the band edge); the halo of tile i
+    # is the head of tile i+1, read through a second view of the array.
+    xp = _bit_reverse_rows(
+        jnp.pad(power, ((0, 0), (0, ntiles * tile + halo - F))))
     out = pl.pallas_call(
         functools.partial(_tree_kernel, T),
         grid=(ntiles,),
-        in_specs=[pl.BlockSpec((1, T, tile + T), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, T, tile), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntiles, T, tile), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((T, tile), lambda i: (0, i)),
+            pl.BlockSpec((T, halo), lambda i: (0, (i + 1) * (tile // halo))),
+        ],
+        out_specs=pl.BlockSpec((T, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((T, ntiles * tile), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((T, tile + halo), jnp.float32)] * 2,
         interpret=interpret,
-    )(tiles)
-    return out.transpose(1, 0, 2).reshape(T, ntiles * tile)[:, :F]
+    )(xp, xp)
+    return out[:, :F]
 
 
 def drift_spectra(

@@ -31,8 +31,7 @@ Two access shapes:
   so windowed spectra are bit-identical to a one-shot F-engine pass.
 
 Voltages arrive planar — ``(re, im)`` float32 pairs dequantized from the
-RAW int8 complex samples — because this TPU backend has no complex-dtype
-HLOs (DESIGN.md §1).
+RAW int8 complex samples — the blit-wide TPU convention (DESIGN.md §1).
 """
 
 from __future__ import annotations
@@ -43,7 +42,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from blit import faults, observability
-from blit.io.guppi import open_raw
+from blit.io.guppi import open_raw, require_native_reader
 from blit.observability import Timeline
 from blit.parallel.scan import _gapless, _gather_int64, _kept_samples
 
@@ -99,6 +98,7 @@ def _open_antennas(raw_paths: Sequence, needed: Sequence[int]):
             r = open_raw(raw_paths[a])
             if r.nblocks == 0:
                 raise ValueError(f"empty RAW file: {r.path}")
+            require_native_reader(r)
             raws[a] = r
         except Exception as e:  # noqa: BLE001 — reported pod-wide below
             errs[a] = e

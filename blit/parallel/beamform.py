@@ -13,11 +13,11 @@ matmul over the antenna axis); the ``psum`` over the mesh axis completes the
 tied-array sum.  Detection + integration then reuse the single-chip kernels.
 
 TPU note: the compute is **planar** — complex values travel as ``(re, im)``
-pairs of float32 arrays, the blit-wide convention (blit/ops/dft.py), because
-this TPU backend implements no complex-dtype HLOs (DESIGN.md §1; not even
-complex ``device_put`` executes).  The public entry points accept either
-planar pairs (the TPU path) or complex arrays (CPU/GPU convenience — output
-dtype follows input).  One complex contraction = 4 real MXU einsums.
+pairs of float32 arrays, the blit-wide convention (blit/ops/dft.py,
+DESIGN.md §1): real MXU matmuls and real-valued Pallas tiles.  The public
+entry points accept either planar pairs (the TPU path) or complex arrays (a
+convenience — output dtype follows input).  One complex contraction = 4
+real MXU einsums.
 
 The reference has no beamforming (it reads post-rawspec products) — this is
 the capability extension BASELINE.json prescribes, built so the per-chip
@@ -32,8 +32,6 @@ from typing import Optional
 import numpy as np
 
 import jax
-
-from blit.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -66,7 +64,7 @@ def delay_weights_planar(
     ``delays_s``: (nbeam, nant) seconds; ``freqs_hz``: (nchan,) sky
     frequencies of the coarse channels.  Returns ``(wr, wi)`` float32 pairs
     shaped (nbeam, nant, nchan) holding ``cos/sin`` of ``-2π f τ`` —
-    real-valued trig only, so this runs on the complex-free TPU backend.
+    real-valued trig only, the planar TPU convention.
     Optionally scaled by per-antenna ``amplitudes`` (nbeam, nant) or (nant,).
     """
     phase = (-2.0 * jnp.pi * delays_s[..., None] * freqs_hz[None, None, :]).astype(
@@ -86,8 +84,8 @@ def delay_weights(
     delays_s: jax.Array, freqs_hz: jax.Array, amplitudes: Optional[jax.Array] = None
 ) -> jax.Array:
     """Complex-dtype convenience over :func:`delay_weights_planar`:
-    ``exp(-2πi f τ)`` shaped (nbeam, nant, nchan) complex64.  CPU/GPU only —
-    on the complex-free TPU backend use the planar form directly."""
+    ``exp(-2πi f τ)`` shaped (nbeam, nant, nchan) complex64.  The TPU path
+    uses the planar form directly."""
     wr, wi = delay_weights_planar(delays_s, freqs_hz, amplitudes)
     return jax.lax.complex(wr, wi).astype(jnp.complex64)
 
@@ -183,7 +181,7 @@ def beamform(
         return br, bi
 
     out_specs = P() if detect else (P(), P())
-    out = shard_map(
+    out = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(None, axis), P(None, axis)),
@@ -215,7 +213,7 @@ def _beamform_chan(
     the ``mesh.shape[axis] == 1`` gate.
     """
     from blit.ops import pallas_beamform as PB
-    from blit.ops.channelize import _MATMUL_ONLY_BACKENDS
+    from blit.device import TPU_BACKEND
 
     vr, vi, v_cplx = as_planar(voltages)
     wr, wi, w_cplx = as_planar(weights)
@@ -232,7 +230,7 @@ def _beamform_chan(
     fuse = (
         detect
         and mesh.shape[axis] == 1
-        and jax.default_backend() in _MATMUL_ONLY_BACKENDS
+        and jax.default_backend() == TPU_BACKEND
         and PB.pick_tile(nant, nbeam, npol, ntime, nint,
                          itemsize=vr.dtype.itemsize) is not None
     )
@@ -266,7 +264,7 @@ def _beamform_chan(
         return br, bi
 
     out_specs = P() if (detect or fuse) else (P(), P())
-    out = shard_map(
+    out = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, None, axis),

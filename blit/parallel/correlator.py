@@ -19,9 +19,9 @@ single-chip filterbank path (blit/ops/channelize), applied to complex
 voltages held as ``(re, im)`` planes; X-engine = the baseline cross-products
 summed over frames — 4 real batched einsums per complex product on the MXU.
 
-TPU note: everything is **planar** (blit/ops/dft.py convention) because this
-TPU backend has no complex-dtype HLOs at all (DESIGN.md §1).  The public
-``correlate`` accepts planar pairs (TPU path) or complex arrays (CPU/GPU
+TPU note: everything is **planar** (blit/ops/dft.py convention; DESIGN.md
+§1): real MXU matmuls and real-valued Pallas tiles.  The public
+``correlate`` accepts planar pairs (TPU path) or complex arrays (a
 convenience; output dtype follows input).  The fftshift every fine spectrum
 needs is folded into the PFB window by the shift theorem — the same
 two-HBM-passes saving the filterbank path uses (DESIGN.md §2).
@@ -38,8 +38,6 @@ from blit.ops.dft import ComplexOrPlanar, Planar, as_planar
 import numpy as np
 
 import jax
-
-from blit.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -142,14 +140,14 @@ def _xengine_packed(sr: jax.Array, si: jax.Array) -> Planar:
     the standard layout, tools/ab_fx64.py, so the fallback costs nothing).
     """
     from blit.ops import pallas_xengine
-    from blit.ops.channelize import _MATMUL_ONLY_BACKENDS
+    from blit.device import TPU_BACKEND
 
     nant, _c, npol = sr.shape[0], sr.shape[1], sr.shape[2]
     nap = nant * npol
     ft = pallas_xengine.pick_ft(
         nap, sr.shape[-1], sr.shape[3], itemsize=sr.dtype.itemsize
     )
-    fused = jax.default_backend() in _MATMUL_ONLY_BACKENDS and ft is not None
+    fused = jax.default_backend() == TPU_BACKEND and ft is not None
     _LAST_PLAN.clear()
     _LAST_PLAN.update(
         {"layout": "packed", "engine": "pallas" if fused else "einsum"}
@@ -277,7 +275,7 @@ def correlate(
     out_spec = (
         P(BANK_AXIS) if vis_layout == "packed" else P(None, None, BANK_AXIS)
     )
-    visr, visi = shard_map(
+    visr, visi = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(spec_v, spec_v, P()),
@@ -341,7 +339,7 @@ def _window_vis(vr, vi, h, *, mesh: Mesh, vis_layout: str):
         return pr[None], pi[None]  # leading band block axis
 
     spec = _acc_spec(vis_layout)
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh, in_specs=(_SPEC_V, _SPEC_V, P()),
         out_specs=(spec, spec), check_vma=False,
     )(vr, vi, h)
@@ -361,7 +359,7 @@ def _accum_vis(accr, acci, vr, vi, h, *, mesh: Mesh, vis_layout: str):
         return ar + pr[None], ai + pi[None]
 
     spec = _acc_spec(vis_layout)
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh, in_specs=(spec, spec, _SPEC_V, _SPEC_V, P()),
         out_specs=(spec, spec), check_vma=False,
     )(accr, acci, vr, vi, h)
@@ -389,7 +387,7 @@ def _finish_vis(accr, acci, *, mesh: Mesh, vis_layout: str):
     out = (
         P(BANK_AXIS) if vis_layout == "packed" else P(None, None, BANK_AXIS)
     )
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh, in_specs=(spec, spec), out_specs=(out, out),
         check_vma=False,  # psum output is band-invariant
     )(accr, acci)

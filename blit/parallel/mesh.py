@@ -22,8 +22,6 @@ from typing import Dict, Optional, Union
 import numpy as np
 
 import jax
-
-from blit.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from blit.ops.channelize import channelize
@@ -235,11 +233,14 @@ def band_reduce(
             out = despike(out, despike_nfpc)
         return out[None]  # leading band axis block
 
-    # check_vma=False when stitching: the varying-mesh-axes analysis cannot
-    # statically see that all_gather's output is bank-invariant.
-    return shard_map(
+    # check_vma=False on both branches: the varying-mesh-axes analysis
+    # cannot see that all_gather's output is bank-invariant, and it
+    # rejects the Pallas kernels the per-chip channelize resolves to on the
+    # TPU (their out_shape carries no vma) — the check must not pass only
+    # on the XLA path the CPU takes.
+    return jax.shard_map(
         step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=not stitch,
+        check_vma=False,
     )(voltages, coeffs)
 
 
@@ -274,7 +275,7 @@ def stitch_despike(x: jax.Array, *, mesh: Mesh, despike_nfpc: int = 0):
             out = despike(out, despike_nfpc)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         gather,
         mesh=mesh,
         in_specs=partition_rule("filterbank_sharded"),

@@ -12,6 +12,17 @@ Rebuild of the reference's ``Distributed.addprocs``-over-ssh star topology
   ``addprocs``-over-ssh workers, with calls routed to the host that owns
   the files.
 
+One process per chip.  A process that has touched JAX holds the
+accelerator until it exits, and a second process that needs the same chip
+fails or hangs.  ``local`` and ``thread`` workers share the driver's
+process and therefore its chip.  ``process`` workers are SPAWNED, never
+forked — a fork of a driver that holds the chip inherits a client it
+cannot use — and are held to the host's platform by name
+(:func:`blit.device.named_platform`): a worker sent a reduction
+(``gbt.reduce_raw``) that cannot get the device raises instead of carrying
+on on the CPU.  On a one-chip host that means reductions belong on the
+``local``/``thread`` backends or on one ``remote`` agent per host.
+
 Differences from the reference, by design (SURVEY.md §5 "Failure detection"):
 
 - ``setup_workers`` with a live pool returns *the live pool* (the reference
@@ -47,6 +58,16 @@ def _traced_call(ctx, wid: int, host: str, fn: Callable, args, kw):
         with tr.span(f"pool.{getattr(fn, '__name__', 'call')}",
                      worker=wid, host=host):
             return fn(*args, **kw)
+
+def _hold_platform(platform: Optional[str]) -> None:
+    """``process``-worker initializer (module docstring): name the
+    platform before the worker's first ``import jax``, so a device it
+    cannot get is an error, not a fall back to the CPU."""
+    if platform:
+        import os
+
+        os.environ["JAX_PLATFORMS"] = platform
+
 
 # Distinguishes "not given" (inherit SiteConfig) from an explicit None
 # (disable the deadline — the reference's blocking behavior).
@@ -134,7 +155,15 @@ class WorkerPool:
                 max_workers=max(1, len(self.workers)), thread_name_prefix="blit-w"
             )
         elif backend == "process":
-            self._exec = ProcessPoolExecutor()
+            import multiprocessing
+
+            from blit.device import named_platform
+
+            self._exec = ProcessPoolExecutor(
+                mp_context=multiprocessing.get_context("spawn"),
+                initializer=_hold_platform,
+                initargs=(named_platform(),),
+            )
         if backend == "remote":
             import os
 

@@ -18,7 +18,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from blit.io.guppi import GuppiRaw, open_raw
+from blit.io.guppi import GuppiRaw, open_raw, require_native_reader
 from blit.monitor import published
 from blit.ops.channelize import (
     STOKES_NIF,
@@ -189,6 +189,7 @@ def _open_players(raw_paths, mesh):
             r = open_raw(raw_paths[b][k])
             if r.nblocks == 0:
                 raise ValueError(f"empty RAW file: {r.path}")
+            require_native_reader(r)
             raws[(b, k)] = r
         except Exception as e:  # noqa: BLE001 — reported pod-wide below
             local_errs[(b, k)] = e
@@ -797,8 +798,7 @@ def reduce_scan_mesh_to_files(
             # The compute wait is charged to "device" here (not at the
             # async dispatch): this is where the host actually blocks on
             # the window's collectives, mirroring RawReducer's stage
-            # semantics.  (On rigs whose tunnel makes block_until_ready
-            # lazy — DESIGN.md §8 — that wait lands in "readback".)
+            # semantics.
             with tl.stage("device", byte_free=True):
                 out.block_until_ready()
             by_dev = {s.device: s for s in out.addressable_shards}

@@ -440,7 +440,6 @@ def _mesh_dedoppler_fn():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from blit.compat import shard_map
     from blit.ops.pallas_dedoppler import dedoppler_hits
 
     @functools.partial(
@@ -457,7 +456,7 @@ def _mesh_dedoppler_fn():
                 interpret=interpret,
             )[None, None]
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(M.BAND_AXIS, None, M.BANK_AXIS), P()),
             out_specs=M.partition_rule("packed_hits"),

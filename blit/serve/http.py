@@ -984,8 +984,14 @@ class PeerServer:
         return doc
 
     def stats(self) -> Dict:
+        import jax
+
         s = self.service.stats()
         s["name"] = self.name
+        # What this peer's derives run on — a peer is a device process,
+        # and a fleet report must say which device (the spawner of the
+        # bench rigs defaults peers to the CPU).
+        s["platform"] = jax.default_backend()
         s["hot"] = self.service.cache.hot(8)
         with self._counts_lock:
             s["http"] = dict(self.counts)

@@ -17,7 +17,7 @@ work at every level —
   (:func:`blit.pipeline.reducer_for_product` /
   :class:`~blit.pipeline.RawReducer`).
 
-Failures propagate the PR-2 error taxonomy per ticket
+Failures propagate the PR-2 error classes per ticket
 (``RemoteError(etype="HostDegraded")``, ``TimeoutError``,
 ``InjectedFault``, ...) and a failed flight is REMOVED from the
 single-flight table — later identical requests start a fresh reduction
@@ -697,7 +697,7 @@ class ProductService:
     ) -> Tuple[Dict, np.ndarray]:
         """Block until the ticket's product is ready → ``(header, data)``
         with ``data`` read-only ``(nsamps, nif, nchans)`` float32.  Raises
-        the flight's failure for this ticket (PR-2 taxonomy passes
+        the flight's failure for this ticket (PR-2 error classes pass
         through), :class:`Cancelled` for a cancelled ticket, and the
         builtin ``TimeoutError`` past ``timeout``."""
         if ticket.cancelled:

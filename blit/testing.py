@@ -165,6 +165,62 @@ def make_voltages(
     return np.clip(np.round(v), -128, 127).astype(np.int8)
 
 
+def voltage_blocks(
+    nblocks: int,
+    obsnchan: int,
+    ntime_per_block: int,
+    *,
+    seed: int,
+    nfft: int,
+    tone_chan: int,
+    tone_fine: int,
+    npol: int = 2,
+    tone_amp: float = 20.0,
+    noise_rms: float = 8.0,
+    workers: int = 4,
+):
+    """Yield ``nblocks`` int8 voltage blocks ``(obsnchan, ntime_per_block,
+    npol, 2)`` ONE AT A TIME — the recorder-size counterpart of
+    :func:`make_voltages`, which builds the whole stream in RAM through
+    float64: here at most ``workers`` blocks (plus their f32 scratch) are
+    alive, so a multi-GB recording streams straight into
+    :func:`blit.io.write_raw`.
+
+    Each block is seeded Gaussian noise (its own ``[seed, block]``
+    generator, so the bytes do not depend on ``workers``) plus one complex
+    tone in coarse channel ``tone_chan``, phase-continuous across blocks,
+    centred on fine channel ``tone_fine`` of an ``nfft``-point fftshifted
+    product (``(tone_fine - nfft/2) / nfft`` cycles/sample)."""
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    k = tone_fine - nfft // 2
+
+    def block(b: int) -> np.ndarray:
+        rng = np.random.default_rng([seed, b])
+        v = rng.standard_normal((obsnchan, ntime_per_block, npol, 2),
+                                dtype=np.float32)
+        v *= np.float32(noise_rms)
+        n = b * ntime_per_block + np.arange(ntime_per_block, dtype=np.int64)
+        ph = (2 * np.pi / nfft) * ((k * n) % nfft)  # exact integer phase
+        v[tone_chan, :, :, 0] += (tone_amp * np.cos(ph)).astype(
+            np.float32)[:, None]
+        v[tone_chan, :, :, 1] += (tone_amp * np.sin(ph)).astype(
+            np.float32)[:, None]
+        np.rint(v, out=v)
+        np.clip(v, -128, 127, out=v)
+        return v.astype(np.int8)
+
+    with ThreadPoolExecutor(max_workers=max(1, workers)) as pool:
+        ahead: deque = deque()
+        for b in range(nblocks):
+            ahead.append(pool.submit(block, b))
+            if len(ahead) >= max(1, workers):
+                yield ahead.popleft().result()
+        while ahead:
+            yield ahead.popleft().result()
+
+
 def synth_raw(
     path: str,
     nblocks: int = 2,

@@ -2,10 +2,12 @@
 
 All package metadata lives in pyproject.toml; this file exists only to
 compile ``blit/native`` (bitshuffle+LZ4 codec, GUPPI block reader) during
-``pip install`` / wheel builds.  The build is best-effort by design:
-blit degrades to its NumPy fallback paths when the libraries are absent
-(blit/io/native.py), so a host without a C++ toolchain still installs —
-it just reads bitshuffle files and RAW blocks more slowly.
+``pip install`` / wheel builds.  A host without a C++ toolchain still
+installs, for inventory and plain SIGPROC/FBH5 work: without the
+libraries bitshuffle-compressed FBH5 can be neither read nor written
+(there is no NumPy codec), RAW blocks read through a memmap copy on the
+CPU, and the device paths refuse to run (blit/io/guppi.py
+``require_native_reader``).
 """
 
 import os
@@ -24,9 +26,9 @@ class build_py_with_native(build_py):
             subprocess.run(["make", "-C", native], check=True)
         except (OSError, subprocess.CalledProcessError) as e:
             print(
-                f"blit: native build skipped ({e}); the installed package "
-                "falls back to NumPy codec paths (build later with "
-                "`make -C blit/native` inside the installed tree)",
+                f"blit: native build skipped ({e}); no bitshuffle codec "
+                "and no native RAW reader until you run "
+                "`make -C blit/native` inside the installed tree",
                 file=sys.stderr,
             )
         super().run()

@@ -28,6 +28,11 @@ class TestReduce:
         assert rc == 0
         rep = json.loads(txt)
         assert rep["output"] == out and rep["nsamps"] > 0
+        # A CPU run says so: the line names what it ran on and which
+        # kernels "auto" resolved to.
+        assert (rep["platform"], rep["device_kind"]) == ("cpu", "cpu")
+        assert rep["device_count"] == 8
+        assert rep["kernel_plan"]["pfb_kernel"] == "xla"
         from blit.io.sigproc import read_fil_data
 
         hdr, data = read_fil_data(out)
@@ -144,7 +149,10 @@ class TestScanCommand:
                       "-o", str(tmp_path), "--nfft", "64", "--nint", "2",
                       "--window-frames", "4")
         assert rc == 0
-        stats = json.loads(txt.strip().splitlines()[-1])["stages"]
+        line = json.loads(txt.strip().splitlines()[-1])
+        assert line["platform"] == "cpu" and line["device_count"] == 8
+        assert line["kernel_plan"]["fft_method"] in ("direct", "four_step")
+        stats = line["stages"]
         for stage in ("read", "dispatch", "device", "readback", "write"):
             assert stats[stage]["calls"] > 0, stage
         assert stats["read"]["bytes"] > 0

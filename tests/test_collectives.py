@@ -139,7 +139,7 @@ class TestBeamformBf16:
 
 
 class TestBeamformPlanar:
-    """The TPU-native planar (re, im) input path (complex-free backend)."""
+    """The TPU-native planar (re, im) input path."""
 
     def test_planar_matches_complex_path(self):
         nant, nbeam = 8, 3

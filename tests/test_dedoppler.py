@@ -84,10 +84,11 @@ class TestTaylorTree:
 
     def test_pallas_kernel_bitwise_matches_reference(self):
         rng = np.random.default_rng(1)
-        x = rng.normal(50.0, 5.0, size=(16, 200)).astype(np.float32)
+        # Several lane-aligned tiles and a ragged last one.
+        x = rng.normal(50.0, 5.0, size=(16, 700)).astype(np.float32)
         ref = np.asarray(pd.taylor_tree(x, kernel="reference"))
         pal = np.asarray(
-            pd.taylor_tree(x, kernel="pallas", interpret=True, tile=64))
+            pd.taylor_tree(x, kernel="pallas", interpret=True, tile=128))
         assert np.array_equal(ref, pal)
 
     def test_tree_path_shift_invariants(self):
@@ -596,6 +597,8 @@ class TestSearchCLI:
         assert rc == 0
         doc = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
         assert doc["output"] == out and doc["windows"] == 2
+        assert doc["platform"] == "cpu"
+        assert doc["dedoppler_plan"]["kernel"] == "reference"
         hdr, hits = read_hits(out)
         assert hdr["search_window_spectra"] == T
         assert len(hits) == doc["hits"]

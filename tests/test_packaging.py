@@ -75,10 +75,11 @@ class TestPublicSurface:
         import blit.serve
 
         expected = {
-            "Cancelled", "DeadlineExpired", "FleetError",
+            "Cancelled", "ConnectionPool", "DeadlineExpired",
+            "FleetController", "FleetError",
             "FleetFrontDoor", "FrontDoorServer", "HashRing", "Job",
             "Overloaded", "PeerServer", "ProductCache", "ProductRequest",
-            "ProductService", "Scheduler", "Ticket",
+            "ProductService", "Scheduler", "Ticket", "WireError",
             "fingerprint_for", "reduction_fingerprint",
         }
         assert set(blit.serve.__all__) == expected

@@ -7,9 +7,7 @@ including masked-antenna and resume-replay runs, on the >= 8-device
 forced-host CPU mesh the suite provisions (tests/conftest.py /
 the CI mesh-smoke job's XLA_FLAGS).  Plus the plane's building blocks:
 the partition-rule registry, `ShardedAccumulator`'s spec-drift check,
-ICI byte accounting, the `BLIT_MESH_*` knob resolution, and the
-`blit.compat.shard_map` version shim's resolution on both the oldest
-and newest supported jax spellings.
+ICI byte accounting and the `BLIT_MESH_*` knob resolution.
 """
 
 import filecmp
@@ -423,70 +421,6 @@ class TestMeshDefaults:
         d = mesh_defaults(SiteConfig())
         assert d == {"sharded": False, "probe_windows": 2,
                      "prefetch_depth": None, "out_depth": None}
-
-
-class TestCompatShardMapShim:
-    """ISSUE 9 satellite: the blit.compat.shard_map version shim
-    RESOLVES on both supported jax spellings — the newest
-    (jax.shard_map, check_vma) and the oldest
-    (jax.experimental.shard_map.shard_map, check_rep)."""
-
-    def test_newest_spelling_routes_check_vma(self, monkeypatch):
-        from blit import compat
-
-        seen = {}
-
-        def fake(f, *, mesh, in_specs, out_specs, check_vma):
-            seen.update(mesh=mesh, check_vma=check_vma)
-            return lambda *a: "new-api"
-
-        monkeypatch.setattr(jax, "shard_map", fake, raising=False)
-        got = compat.shard_map(lambda x: x, mesh="m", in_specs=None,
-                               out_specs=None, check_vma=False)()
-        assert got == "new-api"
-        assert seen == {"mesh": "m", "check_vma": False}
-
-    def test_oldest_spelling_routes_check_rep(self, monkeypatch):
-        import sys
-        import types
-
-        from blit import compat
-
-        seen = {}
-
-        def fake(f, *, mesh, in_specs, out_specs, check_rep):
-            seen.update(mesh=mesh, check_rep=check_rep)
-            return lambda *a: "old-api"
-
-        # Oldest jax: no jax.shard_map attribute, the API lives at
-        # jax.experimental.shard_map.shard_map with check_rep.
-        monkeypatch.delattr(jax, "shard_map", raising=False)
-        mod = types.ModuleType("jax.experimental.shard_map")
-        mod.shard_map = fake
-        monkeypatch.setitem(sys.modules, "jax.experimental.shard_map", mod)
-        got = compat.shard_map(lambda x: x, mesh="m", in_specs=None,
-                               out_specs=None, check_vma=True)()
-        assert got == "old-api"
-        assert seen == {"mesh": "m", "check_rep": True}
-
-    def test_live_resolution_executes_a_collective(self):
-        # Whatever THIS jax provides, the shim must produce a working
-        # shard_map: an 8-way psum over the bank axis.
-        from jax.sharding import PartitionSpec as P
-
-        from blit.compat import shard_map
-
-        mesh = make_mesh(1, 8)
-        x = jax.device_put(
-            np.arange(8, dtype=np.float32).reshape(8, 1),
-            jax.sharding.NamedSharding(mesh, P("bank", None)),
-        )
-        out = shard_map(
-            lambda b: jax.lax.psum(b, "bank"), mesh=mesh,
-            in_specs=P("bank", None), out_specs=P(None, None),
-            check_vma=False,
-        )(x)
-        np.testing.assert_array_equal(np.asarray(out), [[28.0]])
 
 
 class TestGbtWrappers:

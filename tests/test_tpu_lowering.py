@@ -1,0 +1,273 @@
+"""What can be known of the TPU path without a TPU.
+
+Every branch below is one only the TPU backend takes, so the CPU suite
+never sees it unless it asks:
+
+- every Pallas kernel is CROSS-LOWERED for the TPU at its production
+  shape (``jax.export`` with ``platforms=["tpu"]`` runs the Pallas→Mosaic
+  lowering on any host) — a kernel that cannot lower is caught here, not
+  on chip time;
+- ``band_reduce`` runs on the CPU mesh with a Pallas kernel (interpreted)
+  inside the per-chip ``channelize`` — what ``auto`` resolves to on a chip;
+- kernel requests on a backend that is neither TPU nor CPU raise;
+- the reducer splits a chunk into channel groups when the device reports
+  a memory limit, and holds the chip path to the native reader;
+- the compile cache is placed by ``JAX_COMPILATION_CACHE_DIR`` when set.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from blit import device  # noqa: E402
+from blit.ops import channelize as ch  # noqa: E402
+from blit.ops import dft as D  # noqa: E402
+
+NFFT = 1 << 20
+FACTORS = D.default_factors(NFFT)  # (128, 128, 64)
+NCHAN, FRAMES, NTAP = 2, 8, 4
+
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+def lower_for_tpu(fn, *specs):
+    """Pallas→Mosaic lowering of ``fn`` for the TPU, on this host."""
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*specs)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+class TestEveryKernelLowersForTheTpu:
+    """Production shapes: nfft 2^20 = 128 x 128 x 64, 8 frames per chunk,
+    f32 and bf16 stages; the collective kernels at bench.py's shapes."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_pfb_dft1(self, dtype):
+        from blit.ops.pallas_pfb import pfb_dft1
+
+        n1 = FACTORS[0]
+        lower_for_tpu(
+            functools.partial(pfb_dft1, dtype=dtype),
+            spec((NCHAN, (FRAMES + NTAP - 1) * NFFT, 2, 2), "int8"),
+            spec((NTAP, NFFT), "float32"),
+            spec((n1, n1), "float32"), spec((n1, n1), "float32"),
+            spec((n1, NFFT // n1), "float32"),
+            spec((n1, NFFT // n1), "float32"),
+        )
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_pfb_dequant(self, dtype):
+        from blit.ops.pallas_pfb import pfb_dequant
+
+        lower_for_tpu(
+            functools.partial(pfb_dequant, dtype=dtype),
+            spec((NCHAN, (FRAMES + NTAP - 1) * NFFT, 2, 2), "int8"),
+            spec((NTAP, NFFT), "float32"),
+        )
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("stokes", ["I", "IQUV"])
+    def test_tail2_detect(self, stokes, dtype):
+        from blit.ops.pallas_detect import tail2_detect
+
+        u = spec((NCHAN, 2, FRAMES, FACTORS[0], NFFT // FACTORS[0]), dtype)
+        lower_for_tpu(
+            lambda a, b: tail2_detect(a, b, FACTORS[1], FACTORS[2],
+                                      stokes=stokes), u, u)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_dft_tail2(self, dtype):
+        from blit.ops.pallas_dft import dft_tail2
+
+        u = spec((NCHAN, 2, FRAMES, FACTORS[0], NFFT // FACTORS[0]), dtype)
+        lower_for_tpu(
+            lambda a, b: dft_tail2(a, b, FACTORS[1], FACTORS[2],
+                                   dtype=dtype), u, u)
+
+    def test_detect_untwist_i(self):
+        from blit.ops.pallas_detect import detect_untwist_i
+
+        s = spec((NCHAN, 2, FRAMES, NFFT), "bfloat16")
+        lower_for_tpu(lambda a, b: detect_untwist_i(a, b, FACTORS), s, s)
+
+    def test_fused_beamform_detect(self):
+        from blit.ops.pallas_beamform import fused_beamform_detect
+
+        nant, nbeam, nchan, ntime, nint = 64, 64, 64, 8192, 8
+        v = spec((nchan, nant, 2, ntime), "bfloat16")
+        w = spec((nchan, nbeam, nant), "bfloat16")
+        lower_for_tpu(
+            functools.partial(fused_beamform_detect, nint=nint), v, v, w, w)
+
+    def test_xengine_packed(self):
+        from blit.ops.pallas_xengine import pick_ft, xengine_packed
+
+        nant, nchan, nfft, nframes = 64, 16, 512, 61
+        ft = pick_ft(nant * 2, nfft, nframes, itemsize=4)
+        assert ft is not None
+        s = spec((nant, nchan, 2, nframes, nfft), "float32")
+        lower_for_tpu(functools.partial(xengine_packed, ft=ft), s, s)
+
+    def test_taylor_tree(self):
+        from blit.ops.pallas_dedoppler import taylor_tree
+
+        lower_for_tpu(functools.partial(taylor_tree, kernel="pallas"),
+                      spec((64, NFFT), "float32"))
+
+
+class TestMeshWithPallasInside:
+    """On a chip ``auto`` puts a Pallas kernel inside ``band_reduce``'s
+    shard_map body; ``check_vma=True`` rejected exactly that, on a branch
+    the CPU's XLA path never took."""
+
+    @pytest.mark.parametrize("stitch", [False, True])
+    def test_band_reduce_runs_with_a_pallas_channelize(self, monkeypatch,
+                                                       stitch):
+        from blit.parallel import mesh as M
+
+        nfft, nbank, nchan = 128, 4, 2
+        monkeypatch.setattr(M, "channelize", functools.partial(
+            ch.channelize, pfb_kernel="pallas"))  # interpreted on the CPU
+        mesh = M.make_mesh(1, nbank)
+        rng = np.random.default_rng(5)
+        # A shape no other test traces, so the patched body is the one
+        # band_reduce's jit cache holds.
+        v = rng.integers(-40, 40, (1, nbank, nchan, 9 * nfft, 2, 2), np.int8)
+        h = jnp.asarray(ch.pfb_coeffs(4, nfft))
+        out = np.asarray(M.band_reduce(
+            M.shard_voltages(v, mesh), h, mesh=mesh, nfft=nfft, nint=2,
+            stitch=stitch))
+        assert ch.last_kernel_plan()["pfb_kernel"] == "pallas"
+        want = np.concatenate(
+            [ch.channelize_np(v[0, k], ch.pfb_coeffs(4, nfft), nfft=nfft,
+                              nint=2) for k in range(nbank)], axis=-1)
+        np.testing.assert_allclose(out[0], want, rtol=1e-4, atol=1e-2)
+
+
+class TestKernelRequestsOffTpuAndCpu:
+    def test_pallas_interpret_names_its_backends(self):
+        assert device.pallas_interpret("tpu") is False
+        assert device.pallas_interpret("cpu") is True
+        with pytest.raises(ValueError, match="not supported on backend"):
+            device.pallas_interpret("gpu")
+
+    def test_requested_kernel_raises_on_another_backend(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        v = jnp.zeros((1, 5 * 256, 2, 2), jnp.int8)
+        h = jnp.asarray(ch.pfb_coeffs(4, 256))
+        with pytest.raises(ValueError, match="not supported on backend"):
+            ch.channelize(v, h, nfft=256, pfb_kernel="pallas",
+                          stokes="XX")  # a signature no other test traces
+        x = jnp.zeros((2, 256), jnp.float32), jnp.zeros((2, 256), jnp.float32)
+        with pytest.raises(ValueError, match="not supported on backend"):
+            D.dft(*x, use_pallas=True)
+
+
+class TestReducerOnADeviceWithAMemoryLimit:
+    def _recording(self, tmp_path, nchan=16, nfft=1024):
+        from blit.testing import synth_raw
+
+        path = str(tmp_path / "a.raw")
+        synth_raw(path, nblocks=4, obsnchan=nchan,
+                  ntime_per_block=20 * nfft, seed=1, tone_chan=3)
+        return path
+
+    def test_chunks_split_into_channel_groups(self, tmp_path, monkeypatch):
+        import blit.pipeline as P
+
+        path = self._recording(tmp_path)
+        kw = dict(nfft=1024, chunk_frames=16)
+        _, whole = P.RawReducer(**kw).reduce(path)
+        # A device that holds ~12 MB: the compiler's account of one
+        # 16-channel dispatch does not fit beside the resident products.
+        monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 12_000_000)
+        red = P.RawReducer(**kw)
+        _, grouped = red.reduce(path)
+        assert red._channel_block((16, 19 * 1024, 2, 2)) < 16
+        # Grouping changes no arithmetic; a backend may round a
+        # differently-batched program differently in the last bit.
+        np.testing.assert_allclose(grouped, whole, rtol=1e-6, atol=1e-6)
+
+    def test_a_chunk_that_cannot_fit_raises(self, tmp_path, monkeypatch):
+        import blit.pipeline as P
+
+        path = self._recording(tmp_path)
+        monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 3_000_000)
+        with pytest.raises(MemoryError, match="device memory"):
+            P.RawReducer(nfft=1024, chunk_frames=16).reduce(path)
+
+    def test_no_limit_reported_means_one_dispatch(self):
+        import blit.pipeline as P
+
+        red = P.RawReducer(nfft=1024, chunk_frames=16)
+        assert red._channel_block((16, 19 * 1024, 2, 2)) == 16
+
+    def test_chip_path_requires_the_native_reader(self, tmp_path,
+                                                  monkeypatch):
+        from blit.io.guppi import GuppiRaw, require_native_reader
+
+        raw = GuppiRaw(self._recording(tmp_path), native=False)
+        require_native_reader(raw)  # the CPU: the memmap reader is fine
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="native GUPPI reader"):
+            require_native_reader(raw)
+
+
+class TestCompileCachePlacement:
+    def test_variable_set_means_nothing_is_set_in_code(self, monkeypatch,
+                                                       tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a, **k: calls.append(a))
+        assert device.use_compile_cache() == str(tmp_path)
+        assert calls == []
+
+    def test_unset_means_the_checkout(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a, **k: calls.append(a))
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(
+            device.__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert device.use_compile_cache() == want
+        assert calls == [("jax_compilation_cache_dir", want)]
+
+
+class TestBenchRefusesToFallBack:
+    def _bench(self):
+        import importlib.util
+
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "bench.py")
+        sp = importlib.util.spec_from_file_location("blit_bench", path)
+        mod = importlib.util.module_from_spec(sp)
+        sp.loader.exec_module(mod)
+        return mod
+
+    def test_cpu_config_needs_the_caller_to_name_the_cpu(self, monkeypatch,
+                                                         capsys):
+        import json
+
+        bench = self._bench()
+        monkeypatch.setattr(bench, "_probe_platform", lambda: "cpu")
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
+        assert bench.main() == 1
+        rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert rec["platform"] == "cpu" and rec["value"] == 0.0
+        assert "JAX_PLATFORMS=cpu" in rec["errors"]["run"]
+
+    def test_unknown_platform_is_an_error(self, monkeypatch, capsys):
+        bench = self._bench()
+        monkeypatch.setattr(bench, "_probe_platform", lambda: "gpu")
+        monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
+        assert bench.main() == 1
+        assert "no bench config" in capsys.readouterr().out

@@ -18,7 +18,7 @@ weight phasors round.
 Reports time/call and f32-equivalent input GB/s (same voltage content on
 both sides), plus max relative error of the detected power.
 
-Run on the TPU rig:  python tools/ab_bf16_beamform.py [nant nbeam nchan ntime nint rounds reps]
+Run on the chip:  python tools/ab_bf16_beamform.py [nant nbeam nchan ntime nint rounds reps]
 """
 
 from __future__ import annotations
@@ -30,8 +30,6 @@ import time
 import numpy as np
 
 import jax
-
-from blit.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -48,10 +46,9 @@ def main() -> int:
     reps = int(sys.argv[7]) if len(sys.argv) > 7 else 48
     npol = 2
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from blit.device import use_compile_cache
+
+    use_compile_cache()
 
     from blit.ops.channelize import integrate
     from blit.parallel import beamform as B
@@ -105,7 +102,7 @@ def main() -> int:
             bi = bi.astype(jnp.float32)
             return integrate(br**2 + bi**2, nint)
 
-        return shard_map(
+        return jax.shard_map(
             step, mesh=mesh,
             in_specs=(P("bank"), P("bank"), P(None, "bank"),
                       P(None, "bank")),

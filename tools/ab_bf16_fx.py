@@ -15,7 +15,7 @@ kernel's read, and its VMEM blocks.
 
 Accuracy is reported as max/mean relative error of visibilities vs A.
 
-Run on the TPU rig:  python tools/ab_bf16_fx.py [nant nchan nfft nblk rounds reps]
+Run on the chip:  python tools/ab_bf16_fx.py [nant nchan nfft nblk rounds reps]
 """
 
 from __future__ import annotations
@@ -42,10 +42,9 @@ def main() -> int:
     ntap, npol = 4, 2
     ntime = nblk * nfft
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from blit.device import use_compile_cache
+
+    use_compile_cache()
 
     from blit.ops.channelize import pfb_coeffs
     from blit.ops.pallas_xengine import xengine_packed

@@ -1,14 +1,13 @@
 """Interleaved on-chip A/B of FX-correlator X-engine variants.
 
 Same interleaving + single-fetch methodology as tools/ab_channelize.py
-(the rig's ±25% run-to-run variance makes cross-process comparisons
-meaningless; DESIGN.md §9 item 6).  Compares the whole jitted correlate
+(cross-process comparisons drown in run-to-run spread).  Compares the whole jitted correlate
 call — input GB/s — with the X-engine computed as:
 
   A  split4   four (nant·npol)² einsums over (re, im) pairs
   B  stacked  one (2·nant·npol)² einsum over the re/im-stacked operand
 
-Run on the TPU rig:  python tools/ab_fx.py [nant nchan nfft nblk rounds reps]
+Run on the chip:  python tools/ab_fx.py [nant nchan nfft nblk rounds reps]
 """
 
 from __future__ import annotations
@@ -35,10 +34,9 @@ def main() -> int:
     ntap, npol = 4, 2
     ntime = nblk * nfft
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from blit.device import use_compile_cache
+
+    use_compile_cache()
 
     from blit.ops.channelize import pfb_coeffs
     from blit.parallel.correlator import _xengine_planar, f_engine_planar

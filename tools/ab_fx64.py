@@ -3,8 +3,8 @@
 evidence; at 64 antennas the per-(chan, fine) matmul is 128², exactly
 MXU-sized, and must be re-measured).
 
-Same interleaving + single-fetch methodology as tools/ab_fx.py
-(rig variance ±25%: never compare across processes; DESIGN.md §9).
+Same interleaving + single-fetch methodology as tools/ab_fx.py (never
+compare across processes).
 
 Variants (whole jitted F+X call, input GB/s; sum() sink is
 layout-invariant so checksums cross-check the math):
@@ -19,7 +19,7 @@ layout-invariant so checksums cross-check the math):
                        (MXU-native dots, f32 accumulation): halves the
                        X-engine's spectra read traffic
 
-Run on the TPU rig:  python tools/ab_fx64.py [nant nchan nfft nblk rounds reps]
+Run on the chip:  python tools/ab_fx64.py [nant nchan nfft nblk rounds reps]
 """
 
 from __future__ import annotations
@@ -46,10 +46,9 @@ def main() -> int:
     ntap, npol = 4, 2
     ntime = nblk * nfft
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from blit.device import use_compile_cache
+
+    use_compile_cache()
 
     from blit.ops.channelize import pfb_coeffs
     from blit.parallel.correlator import _xengine_planar, f_engine_planar

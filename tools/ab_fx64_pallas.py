@@ -12,7 +12,7 @@ byte written once.  tools/ab_fx64.py already measured packed-layout
 OUTPUT parity for the einsum path, so the packed emission is not the
 variable under test; the single-pass VMEM residency is.
 
-Run on the TPU rig:  python tools/ab_fx64_pallas.py [nant nchan nfft nblk rounds reps ft]
+Run on the chip:  python tools/ab_fx64_pallas.py [nant nchan nfft nblk rounds reps ft]
 """
 
 from __future__ import annotations
@@ -41,10 +41,9 @@ def main() -> int:
     ntap, npol = 4, 2
     ntime = nblk * nfft
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from blit.device import use_compile_cache
+
+    use_compile_cache()
 
     from blit.ops.channelize import pfb_coeffs
     # The SHIPPED kernel, not a prototype copy: re-running this tool keeps

@@ -17,7 +17,7 @@ weights (nchan, nbeam, nant), output (nchan, nbeam, npol, ntime/nint) —
 packed, chan-major; the public API's (nbeam, nchan, t, npol) is one
 cheap transpose of the SMALL output if a consumer needs it.
 
-Run on the TPU rig:
+Run on the chip:
   python tools/ab_pallas_beamform.py [nant nbeam nchan ntime nint rounds reps tile dtype]
 """
 
@@ -61,10 +61,9 @@ def main() -> int:
     dtype = sys.argv[9] if len(sys.argv) > 9 else "bfloat16"
     npol = 2
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from blit.device import use_compile_cache
+
+    use_compile_cache()
 
     from blit.parallel import beamform as B
     from blit.parallel import mesh as M
