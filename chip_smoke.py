@@ -20,7 +20,11 @@ The default run, in ONE process (a chip belongs to one process at a time):
    and whether complex64 ``device_put`` / ``jnp.fft.fft`` run;
 4. writes, block by block from a seed, one GUPPI RAW recording at the GBT
    recorder's geometry (OBSNCHAN 64, 8 bit, dual-pol complex, 128 MiB
-   blocks; MacMahon+ 2018) outside the checkout, cut in DURATION only;
+   blocks; MacMahon+ 2018) outside the checkout, cut in DURATION only —
+   as one file where the machine allows a file that large, else as the
+   recorder's own ``.0000.raw``, ``.0001.raw``, … sequence; a cap on the
+   size of one file also caps the spectra the product may hold, which
+   cuts frames again and is printed;
 5. reduces it through the CLI's own ``main()`` —
    ``blit reduce <raw> -o <out>.fil --product 0000`` — and checks the
    product: header geometry, the injected tone in the fine channel the
@@ -39,6 +43,7 @@ is ``{"ok": true, "device": {...}}``.
 from __future__ import annotations
 
 import contextlib
+import errno
 import io
 import json
 import os
@@ -197,20 +202,115 @@ def rig_facts() -> None:
 
 # -- the recording ------------------------------------------------------------
 
-def scratch_dir(need_bytes: int, prefer=("/dev/shm", "/tmp")) -> str:
-    """A fresh directory OUTSIDE the checkout with ``need_bytes`` free
-    (a multi-GB tree inside the repo breaks the chip tool's copy)."""
+FIL_HEADER_ROOM = 4096   # a SIGPROC header is a few hundred bytes
+RAW_HEADER_ROOM = 4096   # so is one RAW block's card header
+
+
+def host_facts() -> dict:
+    """What this machine lets one process write, said before anything is
+    written: the file-size limit (the soft one raised to the hard one — the
+    script asks for nothing the machine's owner withheld) and the room in
+    the places a recording may go."""
+    import resource
+
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    if soft != hard:
+        try:
+            resource.setrlimit(resource.RLIMIT_FSIZE, (hard, hard))
+        except (ValueError, OSError):
+            pass
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+
+    def show(v):
+        return "unlimited" if v == resource.RLIM_INFINITY else v
+
+    facts = {"RLIMIT_FSIZE": [show(soft), show(hard)]}
+    for root in ("/dev/shm", "/tmp"):
+        if os.path.isdir(root):
+            facts["free " + root] = shutil.disk_usage(root).free
+    return facts
+
+
+def max_file_bytes(directory: str, want: int) -> int:
+    """The largest single file, up to ``want`` bytes, this process may
+    write in ``directory`` — asked of the machine, not assumed.  A sparse
+    ``ftruncate`` meets the checks a ``write`` at that offset meets
+    (RLIMIT_FSIZE, the filesystem's own maximum) and moves no data."""
+    with tempfile.TemporaryFile(dir=directory) as f:
+
+        def allowed(n: int) -> bool:
+            try:
+                os.ftruncate(f.fileno(), n)
+            except OSError as e:
+                if e.errno not in (errno.EFBIG, errno.EINVAL):
+                    raise
+                return False
+            os.ftruncate(f.fileno(), 0)
+            return True
+
+        if allowed(want):
+            return want
+        lo, hi = 0, want  # allowed(lo), not allowed(hi)
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            lo, hi = (mid, hi) if allowed(mid) else (lo, mid)
+        return lo
+
+
+def scratch_dir(need_bytes: int, file_bytes: int,
+                prefer=("/dev/shm", "/tmp")):
+    """A fresh directory OUTSIDE the checkout with ``need_bytes`` free (a
+    multi-GB tree inside the repo breaks the chip tool's copy), and the
+    largest single file, up to ``file_bytes``, that may be written there.
+    The first root that allows ``file_bytes`` wins; failing that, the one
+    that allows most."""
+    best = None
     for root in prefer:
-        if os.path.isdir(root) and shutil.disk_usage(root).free > need_bytes:
-            return tempfile.mkdtemp(prefix="blit-smoke-", dir=root)
-    raise RuntimeError(f"no scratch with {need_bytes} B free in {prefer}")
+        if not (os.path.isdir(root)
+                and shutil.disk_usage(root).free > need_bytes):
+            continue
+        cap = max_file_bytes(root, file_bytes)
+        if best is None or cap > best[1]:
+            best = (root, cap)
+        if cap >= file_bytes:
+            break
+    if best is None:
+        raise RuntimeError(f"no scratch with {need_bytes} B free in {prefer}: "
+                           f"{host_facts()}")
+    return tempfile.mkdtemp(prefix="blit-smoke-", dir=best[0]), best[1]
 
 
-def write_recording(path: str, size: dict, nframes: int, *, seed: int,
-                    tone_chan: int, tone_fine: int, **hdrkw) -> dict:
+def product_dir(frames: int, spectrum_bytes: int, copies: int = 1):
+    """Where the product goes, and how many frames it may cover.  A
+    machine that caps the size of one file caps the spectra one product
+    holds: that cuts DURATION (fewer frames), never width, and is said.
+    Returns ``(directory, frames)``."""
+    want = (frames - 3) * spectrum_bytes + FIL_HEADER_ROOM
+    outdir, cap = scratch_dir(copies * want + (1 << 30), want,
+                              prefer=("/tmp", "/dev/shm"))
+    spectra = min(frames - 3, (cap - FIL_HEADER_ROOM) // spectrum_bytes)
+    if spectra < 1:
+        shutil.rmtree(outdir, ignore_errors=True)
+        raise RuntimeError(
+            f"one spectrum at this width is {spectrum_bytes} B and the "
+            f"largest file this machine allows is {cap} B: {host_facts()}")
+    if spectra < frames - 3:
+        say("reduced", what="duration", frames_was=frames,
+            frames_now=spectra + 3,
+            why=f"the largest file this machine allows is {cap} B; the "
+                f"product would be {want} B", **host_facts())
+    return outdir, spectra + 3
+
+
+def write_recording(stem: str, size: dict, nframes: int, file_cap: int, *,
+                    seed: int, tone_chan: int, tone_fine: int, **hdrkw):
     """One RAW recording of exactly ``nframes`` PFB frames' worth of
     samples (so the last chunk is full and no second compile triggers),
-    streamed block by block.  Returns the RAW header."""
+    streamed block by block into ``<stem>.0000.raw``, ``.0001.raw``, … —
+    the recorder's own sequence convention, each member at most
+    ``file_cap`` bytes.  Returns ``(RAW header, member paths)``."""
+    import itertools
+
     from blit.io import write_raw
     from blit.testing import make_raw_header, voltage_blocks
 
@@ -218,22 +318,35 @@ def write_recording(path: str, size: dict, nframes: int, *, seed: int,
     nblocks, rem = divmod(nsamples, size["block_samples"])
     if rem:
         raise ValueError("frames do not fill whole blocks")
+    block_bytes = size["block_samples"] * size["nchan"] * 4
+    per_file = min(nblocks, file_cap // (block_bytes + RAW_HEADER_ROOM))
+    if per_file < 1:
+        raise RuntimeError(
+            f"one RAW block is {block_bytes} B and the largest file this "
+            f"machine allows is {file_cap} B: {host_facts()}")
     hdr = make_raw_header(obsnchan=size["nchan"], npol=2, **hdrkw)
-    write_raw(path, hdr, voltage_blocks(
+    blocks = voltage_blocks(
         nblocks, size["nchan"], size["block_samples"], seed=seed,
         nfft=size["nfft"], tone_chan=tone_chan, tone_fine=tone_fine,
-        workers=min(8, os.cpu_count() or 1)))
-    return hdr
+        workers=min(8, os.cpu_count() or 1))
+    paths = []
+    for first in range(0, nblocks, per_file):
+        paths.append(f"{stem}.{len(paths):04d}.raw")
+        write_raw(paths[-1],
+                  {**hdr, "PKTIDX": first * size["block_samples"]},
+                  itertools.islice(blocks, per_file))
+    blocks.close()  # ends the generator's worker threads
+    return hdr, paths
 
 
-def read_channels(raw_path: str, chans) -> "np.ndarray":
+def read_channels(raw_paths, chans) -> "np.ndarray":
     """The recording's bytes for a few coarse channels, gap-free:
     ``(len(chans), ntime, npol, 2)`` int8 — the reference's input."""
     import numpy as np
 
-    from blit.io.guppi import GuppiRaw
+    from blit.io.guppi import open_raw
 
-    raw = GuppiRaw(raw_path)
+    raw = open_raw(raw_paths)
     return np.concatenate(
         [np.ascontiguousarray(raw.read_block(i)[list(chans)])
          for i in range(raw.nblocks)], axis=1)
@@ -312,7 +425,7 @@ def rel_err(got, want) -> float:
 TOL = 2e-2
 
 
-def check_tone_and_reference(fil: str, raw: str, rawhdr: dict, size: dict,
+def check_tone_and_reference(fil: str, raw: list, rawhdr: dict, size: dict,
                              tone_chan: int, tone_fine: int,
                              other_chan: int, nspectra: int) -> None:
     import numpy as np
@@ -369,35 +482,39 @@ def reduce_leg(size: dict, on_tpu: bool) -> None:
     """Steps 4-5: the main path, one chip, full width."""
     import jax
 
-    from blit.io.guppi import GuppiRaw
+    from blit.io.guppi import open_raw
     from blit.pipeline import RawReducer
 
     nfft, nchan = size["nfft"], size["nchan"]
-    frames = size["frames"]
-    raw_bytes = frames * nfft * nchan * 4
     tbin = nchan / 187.5e6
-    say("reduce.plan", OBSNCHAN=nchan, NBITS=8, npol=2,
-        BLOCSIZE=size["block_samples"] * nchan * 4, nfft=nfft,
-        frames=frames, raw_bytes=raw_bytes,
-        reduced=f"duration {size['scan_seconds']:.0f} s -> "
-                f"{frames * nfft * tbin:.1f} s "
-                f"({frames} frames; width uncut)")
-    rawdir = scratch_dir(raw_bytes + (1 << 30))
-    outdir = scratch_dir((frames - 3) * nchan * nfft * 4 + (1 << 30),
-                         prefer=("/tmp", "/dev/shm"))
+    outdir, frames = product_dir(size["frames"], nchan * nfft * 4)
+    raw_bytes = frames * nfft * nchan * 4
+    rawdir = None
     try:
-        raw = os.path.join(rawdir, "blc00_guppi_59897_21221_SMOKE_0001.0000.raw")
+        rawdir, raw_cap = scratch_dir(
+            raw_bytes + (1 << 30),
+            raw_bytes + (raw_bytes // (size["block_samples"] * nchan * 4))
+            * RAW_HEADER_ROOM)
+        say("reduce.plan", OBSNCHAN=nchan, NBITS=8, npol=2,
+            BLOCSIZE=size["block_samples"] * nchan * 4, nfft=nfft,
+            frames=frames, raw_bytes=raw_bytes,
+            reduced=f"duration {size['scan_seconds']:.0f} s -> "
+                    f"{frames * nfft * tbin:.1f} s "
+                    f"({frames} frames; width uncut)")
+        stem = os.path.join(rawdir, "blc00_guppi_59897_21221_SMOKE_0001")
         tone_chan, other_chan = nchan // 3, nchan - 1
         tone_fine = nfft // 2 + nfft // 5 + 3
         t0 = time.perf_counter()
-        rawhdr = write_recording(raw, size, frames, seed=SEED,
-                                 tone_chan=tone_chan, tone_fine=tone_fine)
-        say("reduce.synth", path=raw, seconds=round(time.perf_counter() - t0, 1),
+        rawhdr, raws = write_recording(stem, size, frames, raw_cap, seed=SEED,
+                                       tone_chan=tone_chan,
+                                       tone_fine=tone_fine)
+        say("reduce.synth", files=[os.path.basename(p) for p in raws],
+            directory=rawdir, seconds=round(time.perf_counter() - t0, 1),
             scratch_free=shutil.disk_usage(rawdir).free)
 
         out = os.path.join(outdir, "smoke.rawspec.0000.fil")
-        argv = (["reduce", raw, "-o", out, "--product", "0000"] if on_tpu
-                else ["reduce", raw, "-o", out, "--nfft", str(nfft)])
+        argv = (["reduce", *raws, "-o", out, "--product", "0000"] if on_tpu
+                else ["reduce", *raws, "-o", out, "--nfft", str(nfft)])
         first = {}
         done = threading.Event()
 
@@ -426,7 +543,7 @@ def reduce_leg(size: dict, on_tpu: bool) -> None:
             peak_bytes_in_use=stats.get("peak_bytes_in_use"),
             bytes_limit=stats.get("bytes_limit"),
             kernel_plan=res["kernel_plan"],
-            raw_native=GuppiRaw(raw).native,
+            raw_native=open_raw(raws).native,
             tuning=probe.tuning_provenance())
         if res["platform"] != jax.devices()[0].platform:
             raise AssertionError(f"CLI reports {res['platform']}")
@@ -436,10 +553,11 @@ def reduce_leg(size: dict, on_tpu: bool) -> None:
                     "fused1", "tail2_detect"):
                 raise AssertionError(
                     f"'auto' did not resolve to the fused plan: {plan}")
-        check_tone_and_reference(out, raw, rawhdr, size, tone_chan,
+        check_tone_and_reference(out, raws, rawhdr, size, tone_chan,
                                  tone_fine, other_chan, frames - 3)
     finally:
-        shutil.rmtree(rawdir, ignore_errors=True)
+        if rawdir:
+            shutil.rmtree(rawdir, ignore_errors=True)
         shutil.rmtree(outdir, ignore_errors=True)
 
 
@@ -619,26 +737,28 @@ def mesh_leg(size: dict, on_tpu: bool) -> None:
 
     from blit.config import default_window_frames
 
-    nfft, nchan, frames = size["nfft"], size["nchan"], size["mesh_frames"]
+    nfft, nchan = size["nfft"], size["nchan"]
     session, scan, nbank = "AGBT22B_999_01", "0011", 4
     if len(jax.devices()) < nbank:
         raise RuntimeError(f"the mesh leg needs {nbank} devices, "
                            f"found {len(jax.devices())}")
+    outdir, frames = product_dir(size["mesh_frames"],
+                                 nbank * nchan * nfft * 4, copies=4)
     bank_bytes = frames * nfft * nchan * 4
-    product_bytes = (frames - 3) * nbank * nchan * nfft * 4
-    root = scratch_dir(nbank * bank_bytes + (1 << 30))
-    outdir = scratch_dir(4 * product_bytes + (1 << 30),
-                         prefer=("/tmp", "/dev/shm"))
+    root = None
     try:
+        root, raw_cap = scratch_dir(
+            nbank * bank_bytes + (1 << 30),
+            bank_bytes + (bank_bytes // (size["block_samples"] * nchan * 4))
+            * RAW_HEADER_ROOM)
         t0 = time.perf_counter()
         bank_bw = -187.5 / 8
         for k in range(nbank):  # build_observation_tree's layout and tiling
             d = os.path.join(root, session, "GUPPI", f"BLP0{k}")
             os.makedirs(d)
             write_recording(
-                os.path.join(d, f"blc0{k}_guppi_59897_21221_HD_84406_"
-                                f"{scan}.0000.raw"),
-                size, frames, seed=SEED + 1 + k, tone_chan=k,
+                os.path.join(d, f"blc0{k}_guppi_59897_21221_HD_84406_{scan}"),
+                size, frames, raw_cap, seed=SEED + 1 + k, tone_chan=k,
                 tone_fine=nfft // 2 + 17 + k, obsbw=bank_bw,
                 obsfreq=8000.0 + (k + 0.5) * bank_bw)
         say("mesh.synth", banks=nbank, bank_bytes=bank_bytes,
@@ -732,7 +852,8 @@ def mesh_leg(size: dict, on_tpu: bool) -> None:
         say("mesh.search.check", products=nhits,
             dedoppler_plan=sres["last"].get("dedoppler_plan"))
     finally:
-        shutil.rmtree(root, ignore_errors=True)
+        if root:
+            shutil.rmtree(root, ignore_errors=True)
         shutil.rmtree(outdir, ignore_errors=True)
 
 
@@ -760,6 +881,7 @@ def main(argv) -> int:
     on_tpu = device["platform"] == "tpu"
     size = TOY if rehearse else FULL
     build_native()
+    say("host", **host_facts())
     if mode != "mesh":
         rig_facts()
         reduce_leg(size, on_tpu)
@@ -778,4 +900,10 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main(sys.argv))
+    try:
+        sys.exit(main(sys.argv))
+    except Exception as e:
+        # Still a failure (re-raised): only the END of stderr comes back
+        # from a sealed machine, so what that machine allows goes last.
+        e.add_note(f"chip_smoke: this machine allows {host_facts()}")
+        raise

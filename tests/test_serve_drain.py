@@ -208,8 +208,12 @@ class TestServiceDrain:
             ProductRequest(raw=raw, kind="stream", out=out, nfft=NFFT),
             client="live")
         deadline = time.monotonic() + 20
-        while service.scheduler.held() < 1:
-            assert time.monotonic() < deadline, "hold never pinned"
+        # Drain only once the session has taken its first chunk (the
+        # resumable writer creates the product then): a drain that beats
+        # the tailer to it leaves an empty stream, which is an error, not
+        # a product — and was this test's flake on a loaded machine.
+        while service.scheduler.held() < 1 or not os.path.exists(out):
+            assert time.monotonic() < deadline, "session never started"
             time.sleep(0.02)
         res = service.drain(timeout=30)
         assert res["stopped"] == 1
