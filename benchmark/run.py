@@ -1,0 +1,716 @@
+"""benchmark/run.py — run ONE cell of BENCHMARK.json ONCE, on the chip.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+One process (a chip belongs to one process at a time).  A *pass* is one
+whole user command, the CLI's own ``blit.__main__.main(argv)`` called
+here: seeded GUPPI RAW on RAM-backed scratch -> finished product at its
+final path, manifest published.  The pass's clock stops when this file's
+own ``os.fsync`` of the product has returned.
+
+set-up   refuse without a TPU holding the cell's chips; fixed compile
+         cache; empty tuning directory; ``make -B`` of blit/native; ask
+         the machine what one file may hold and size the pass (``reduced``);
+         write the recording from ``--seed``; one warm-up pass, checked
+         against the plain reference.  All of it is ``setup_s``.
+window   passes back to back; a pass starts only while the summed time of
+         the passes so far is under ``--seconds``, and every started pass
+         completes and counts.  ``reduce_rate`` is the median pass's.
+         Checks run between passes, outside every timed interval.  A
+         compile inside a pass makes the run incorrect.
+traced   with ``--trace 1``, one more pass under ``jax.profiler``; the
+         per-layer metrics come from it, from the window's rusage and
+         from ``memory_stats``.
+
+The harness holds no list of cells, traffic mixes, driver kinds or
+per-layer metrics: ``BENCHMARK.json`` names them and each is a file of its
+own (configs/, traffic/, drivers/, layer_metrics/, readers/, peaks.json).
+``--rehearse`` runs the same code at toy sizes on the CPU and prints no
+metric; it proves nothing of the chip.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import contextlib  # noqa: E402
+import glob  # noqa: E402
+import importlib  # noqa: E402
+import io  # noqa: E402
+import json  # noqa: E402
+import math  # noqa: E402
+import os  # noqa: E402
+import resource  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import statistics  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+import threading  # noqa: E402
+from concurrent.futures import ThreadPoolExecutor  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device")
+FIRST_PRODUCT_BYTES = 1 << 16   # header + the first rows have landed
+WATCH_POLL_S = 0.02
+SMALL_PRODUCT_BYTES = 1 << 28   # read whole after every pass up to here
+MEMORY_HEADROOM_SHARE = 0.3   # of the machine's memory
+
+
+def memory_facts() -> dict:
+    """Host memory as this machine accounts it, for the log and the guard."""
+    try:
+        with open("/proc/meminfo") as f:
+            info = {ln.split(":")[0]: int(ln.split()[1]) << 10 for ln in f}
+        with open("/proc/self/statm") as f:
+            rss = int(f.read().split()[1]) * os.sysconf("SC_PAGESIZE")
+        return {"mem_total": info["MemTotal"], "mem_free": info["MemFree"],
+                "mem_cached": info["Cached"], "mem_shmem": info["Shmem"],
+                "rss": rss}
+    except (OSError, KeyError, ValueError):
+        return {}
+
+
+def memory_guard(stop: threading.Event) -> None:
+    """End the run in order (SIGTERM -> the clean-up in ``run``) before the
+    machine's own limit ends it without one: a run that meets that limit
+    loses the machine.  Free, not available: the chip tool's limit counts
+    the page cache and ``/dev/shm``."""
+    while not stop.wait(0.25):
+        m = memory_facts()
+        if m and m["mem_free"] < MEMORY_HEADROOM_SHARE * m["mem_total"]:
+            print(f"benchmark refused: host memory nearly spent: {m}",
+                  file=sys.stderr, flush=True)
+            os.kill(os.getpid(), signal.SIGTERM)
+            if not stop.wait(10):
+                os._exit(3)
+            return
+
+
+class Refused(Exception):
+    """The run cannot be made here; the message carries the numbers."""
+
+
+def say(phase: str, **facts) -> None:
+    print(f"[{phase}] " + json.dumps(facts, default=str), flush=True)
+
+
+# -- what BENCHMARK.json names -------------------------------------------------
+
+def load_cell(workload: str, rehearse: bool) -> dict:
+    """The cell's entry, its configuration file, its traffic file and its
+    driver module, each found by the name ``BENCHMARK.json`` gives."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise Refused(f"no workload {workload!r} in BENCHMARK.json "
+                      f"(has {sorted(cells)})")
+    cell = cells[workload]
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    if rehearse:  # toy sizes, named in the same files
+        config["geometry"].update(config.get("rehearse", {}))
+        traffic.update(traffic.get("rehearse", {}))
+
+    def applies(metric):
+        return workload in metric.get("workloads", [workload])
+
+    return {
+        "name": workload, "chips": cell["chips"], "config": config,
+        "traffic": traffic,
+        "driver": importlib.import_module("drivers." + traffic["driver"]),
+        "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
+        "per_layer": [m for m in bench["per_layer"] if applies(m)],
+    }
+
+
+def plan_pass(cell: dict, out_cap: int) -> dict:
+    """How long a pass is on this machine.  A cap on the size of one file
+    caps the rows one product holds: that cuts duration (blocks), never
+    width, down to whole ``align_rows`` (a chunk, a window) where it can."""
+    g, t = cell["config"]["geometry"], cell["traffic"]
+    nfft, nint, ntap = t["nfft"], t["nint"], t["ntap"]
+    nslots = cell["config"]["banks"] * g["obsnchan"]
+    row_bytes = nslots * nfft * 4
+
+    def rows_of(blocks):
+        return ((blocks * g["block_samples"]) // nfft - (ntap - 1)) // nint
+
+    from scratch import FIL_HEADER_ROOM
+
+    want_rows = rows_of(t["blocks"])
+    if want_rows < 1:
+        raise Refused(f"{t['blocks']} blocks of {g['block_samples']} samples "
+                      f"hold no product row at nfft {nfft}, nint {nint}")
+    rows = min(want_rows, (out_cap - FIL_HEADER_ROOM) // row_bytes)
+    if rows < 1:
+        raise Refused(
+            f"one product row of {cell['name']} is {row_bytes} B and the "
+            f"largest file this machine allows is {out_cap} B: this cell "
+            "cannot run here")
+    blocks = t["blocks"]
+    if rows < want_rows:
+        if rows >= t["align_rows"]:
+            rows -= rows % t["align_rows"]
+        blocks = math.ceil((rows * nint + ntap - 1) * nfft
+                           / g["block_samples"])
+        while rows_of(blocks) > rows:
+            blocks -= 1
+        rows = rows_of(blocks)
+    block_bytes = g["block_samples"] * g["obsnchan"] * g["npol"] * 2
+    return {
+        "blocks": blocks, "rows": rows, "row_bytes": row_bytes,
+        "nslots": nslots, "warm_rows": min(rows, t["align_rows"]),
+        "raw_bytes": cell["config"]["banks"] * blocks * block_bytes,
+        "product_bytes": rows * row_bytes,
+        "blocks_wanted": t["blocks"], "rows_wanted": want_rows,
+    }
+
+
+def write_inputs(cell: dict, plan: dict, rawdir: str, raw_cap: int,
+                 seed: int) -> dict:
+    """The recordings of every bank, and the reference's input slices."""
+    import recording
+
+    cfg, t, g = cell["config"], cell["traffic"], cell["config"]["geometry"]
+    banks = cfg["banks"]
+    workers = max(1, min(t["pool_blocks"], 8,
+                         ((os.cpu_count() or 2) - 1) // banks))
+
+    def bank(k: int):
+        hdr = recording.raw_header(
+            g, obsfreq=cfg["first_bank_obsfreq_mhz"] + k * cfg["obsbw_mhz"],
+            obsbw=cfg["obsbw_mhz"])
+        tone = t["tones"][k]
+        keep = sorted({tone["chan"], *tone.get("also", [])})
+        paths, kept = recording.write_recording(
+            cell["driver"].stem(rawdir, k, t), g, hdr, plan["blocks"],
+            raw_cap, seed=[seed, k], nfft=t["nfft"],
+            tone_chan=tone["chan"],
+            tone_fine_offset=tone["fine_offset"],
+            pool_blocks=t["pool_blocks"], keep_chans=keep, workers=workers)
+        slices = [{"volt": v, "chan": c, "slot": k * g["obsnchan"] + c,
+                   "raw_hdr": hdr,
+                   "tone_fine_offset": tone["fine_offset"]
+                   if c == tone["chan"] else None}
+                  for c, v in kept.items()]
+        return paths, slices
+
+    with ThreadPoolExecutor(max_workers=banks) as ex:
+        done = list(ex.map(bank, range(banks)))
+    return {"rawdir": rawdir, "raws": [p for p, _ in done],
+            "slices": [s for _, ss in done for s in ss]}
+
+
+# -- one pass ------------------------------------------------------------------
+
+def run_cli(argv) -> list:
+    """The CLI's own ``main()`` in this process; echoes what it printed and
+    returns its JSON lines."""
+    from blit.__main__ import main as blit_main
+
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        rc = blit_main(argv)
+    lines = [ln for ln in out.getvalue().splitlines() if ln.strip()]
+    for ln in lines:
+        print("  blit> " + (ln if len(ln) < 1500 else ln[:1500] + " ..."),
+              flush=True)
+    if rc != 0:
+        raise RuntimeError(f"blit {' '.join(argv)} exited {rc}")
+    return [json.loads(ln) for ln in lines if ln.startswith("{")]
+
+
+@contextlib.contextmanager
+def compile_account():
+    """What JAX's compiler did while the block ran (copied from
+    chip_smoke.py): backend compile steps, their seconds, and the
+    persistent cache's hits and misses."""
+    import jax
+
+    acct = {"backend_compiles": 0, "backend_compile_s": 0.0,
+            "cache_hits": 0, "cache_misses": 0}
+
+    def on_secs(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            acct["backend_compiles"] += 1
+            acct["backend_compile_s"] += secs
+
+    def on_event(name, **_):
+        for key in ("cache_hits", "cache_misses"):
+            if name == "/jax/compilation_cache/" + key:
+                acct[key] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_secs)
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        yield acct
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_secs)
+        jax.monitoring.unregister_event_listener(on_event)
+
+
+def cpu_seconds() -> float:
+    return sum(r.ru_utime + r.ru_stime
+               for r in (resource.getrusage(resource.RUSAGE_SELF),
+                         resource.getrusage(resource.RUSAGE_CHILDREN)))
+
+
+def timed_pass(cell: dict, inputs: dict, outdir: str, tag: str, *,
+               warm_rows=None, traced: bool = False) -> dict:
+    """One pass: command entry -> this file's fsync of the finished
+    product.  A watcher thread (20 ms poll, from chip_smoke.py) notes when
+    the first product rows are in the product file or its ``.partial``."""
+    drv, t = cell["driver"], cell["traffic"]
+    out = drv.new_out(outdir, tag)
+    product = drv.product(out)
+    first, done = {}, threading.Event()
+
+    def watch(t0):
+        while not done.wait(WATCH_POLL_S):
+            for p in (product + ".partial", product):
+                try:
+                    if os.path.getsize(p) > FIRST_PRODUCT_BYTES:
+                        first["s"] = time.perf_counter() - t0
+                        return
+                except OSError:
+                    pass
+
+    res = {"tag": tag, "out": out, "product": product}
+    cpu0 = cpu_seconds()
+    t0 = time.perf_counter()
+    watcher = threading.Thread(target=watch, args=(t0,), daemon=True)
+    watcher.start()
+    try:
+        with compile_account() as res["compiles"]:
+            if traced:
+                res["stages"] = drv.traced(t, inputs, out, run_cli)
+            else:
+                res["cli"] = run_cli(drv.argv(t, inputs, out, warm_rows))
+            fd = os.open(product, os.O_RDONLY)
+            try:
+                os.fsync(fd)
+            finally:
+                os.close(fd)
+            res["wall_s"] = time.perf_counter() - t0
+    finally:
+        done.set()
+        watcher.join()
+    res["cpu_s"] = cpu_seconds() - cpu0
+    res["first_product_s"] = first.get("s", res["wall_s"])
+    return res
+
+
+def profiled_pass(cell: dict, inputs: dict, outdir: str):
+    """One pass under ``jax.profiler``, device ops only -> (the pass, the
+    ``.xplane.pb`` files written).  The host tracer (level 1 and up) makes
+    a 1.5 s pass take 45 s and grow this process by over 10 GB on this
+    machine (PERF.md section 3), so no host event or annotation is recorded
+    and the traced window is the pass's host-clock time."""
+    import jax
+
+    trace_dir = os.path.join(outdir, "trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 0
+    opts.advanced_configuration = {"tpu_trace_mode": "TRACE_ONLY_XLA"}
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        tp = timed_pass(cell, inputs, outdir, "traced", traced=True)
+    finally:
+        jax.profiler.stop_trace()
+    return tp, sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+
+
+def discard(res: dict) -> None:
+    """Remove a pass's product (and sidecar) once it has been checked."""
+    if os.path.isdir(res["out"]):
+        shutil.rmtree(res["out"], ignore_errors=True)
+    else:
+        for p in (res["product"], res["product"] + ".manifest.json"):
+            with contextlib.suppress(FileNotFoundError):
+                os.remove(p)
+
+
+# -- the run -------------------------------------------------------------------
+
+def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
+                device: dict, breakdown=None) -> str:
+    """The contract's last line: these keys and no others."""
+    doc = {"correct": bool(correct), "attempted": attempted, "failed": failed,
+           "metrics": metrics, "device": device}
+    if breakdown is not None:
+        doc["breakdown"] = breakdown
+    return json.dumps(doc)
+
+
+def layer_metrics(cell: dict, evidence: dict) -> dict:
+    """Each per-layer metric of the cell through its own file
+    (``layer_metrics/<name>.json`` names the reader and its arguments).  A
+    reader that finds nothing to read returns nothing and the metric is
+    left out."""
+    out = {}
+    for m in cell["per_layer"]:
+        with open(os.path.join(HERE, "layer_metrics",
+                               m["name"] + ".json")) as f:
+            spec = json.load(f)
+        reader = importlib.import_module("readers." + spec["reader"])
+        value = reader.read(spec.get("args", {}), evidence)
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+    return out
+
+
+def run(args) -> int:
+    import check
+    import reference
+    import scratch
+
+    rehearse = args.rehearse
+    cell = load_cell(args.workload, rehearse)
+    cfg, t, drv = cell["config"], cell["traffic"], cell["driver"]
+    if not os.path.exists(os.path.join(ROOT, "blit", "__main__.py")):
+        raise Refused("the system under test (blit/) is not in this "
+                      f"checkout: {ROOT}")
+    if rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        # Single-threaded Eigen: the CPU backend's threaded contractions
+        # round in an order that depends on timing (two float32 variants
+        # 1.8e-6 apart, about one toy pass in ten), which the byte-for-byte
+        # comparison of passes would report.  Nothing of this reaches a chip.
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={cell['chips']}"
+            + " --xla_cpu_multi_thread_eigen=false"
+        ).strip()
+    # A fixed path inside the checkout: the path is part of the cache's
+    # key.  blit.device.use_compile_cache takes what the variable says.
+    cache = os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                                  os.path.join(ROOT, ".jax_cache"))
+    state = tempfile.mkdtemp(prefix="blit-bench-state-")
+    os.environ["BLIT_TUNE_DIR"] = os.path.join(state, "tune-empty")
+    os.makedirs(os.environ["BLIT_TUNE_DIR"])
+    made = [state]
+    parts = {}
+    try:
+        import jax
+
+        devs = jax.devices()
+        device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                  "count": len(devs)}
+        if not rehearse and (device["platform"] != "tpu"
+                             or device["count"] < cell["chips"]):
+            raise Refused(f"{cell['name']} needs {cell['chips']} TPU "
+                          f"chip(s); JAX reports {device}.  There is no CPU "
+                          "continuation (--rehearse is the toy-size debug "
+                          "run and prints no metric)")
+        import jaxlib
+
+        say("device", **device, jax=jax.__version__,
+            jaxlib=jaxlib.__version__, python=sys.version.split()[0],
+            compile_cache=cache,
+            compile_cache_entries=len(os.listdir(cache))
+            if os.path.isdir(cache) else 0,
+            JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS"))
+        if rehearse:
+            say("rehearse", note="toy sizes on the CPU: this run proves "
+                "nothing of the chip and prints no metric")
+        with open(os.path.join(HERE, "peaks.json")) as f:
+            peaks = json.load(f)["by_device_kind"]
+        if not rehearse and device["kind"] not in peaks:
+            raise Refused(f"no peaks recorded for device kind "
+                          f"{device['kind']!r}; add it to benchmark/"
+                          "peaks.json with its source")
+        say("peaks", **peaks.get(device["kind"], {}))
+        parts["import_and_device_s"] = time.perf_counter() - T_START
+
+        # blit/native is built on the machine that runs (-march=native).
+        t0 = time.perf_counter()
+        subprocess.run(["make", "-B", "-C", os.path.join(ROOT, "blit",
+                                                          "native")],
+                       check=True, stdout=subprocess.DEVNULL)
+        parts["native_build_s"] = time.perf_counter() - t0
+
+        # Size the pass from what this machine lets one file hold.
+        scratch.raise_file_limit()
+        raw_roots = ["/dev/shm", tempfile.gettempdir()]
+        out_roots = [tempfile.gettempdir(), "/dev/shm"]
+        say("host", **scratch.host_facts(sorted(set(raw_roots + out_roots))))
+        unbounded = plan_pass(cell, 1 << 62)
+        want_file = unbounded["product_bytes"] + scratch.FIL_HEADER_ROOM
+        outdir, out_cap = scratch.scratch_dir(
+            out_roots, 2 * want_file + (1 << 30), want_file)
+        made.append(outdir)
+        plan = plan_pass(cell, out_cap)
+        raw_file = plan["raw_bytes"] // cfg["banks"] \
+            + plan["blocks"] * scratch.RAW_HEADER_ROOM
+        rawdir, raw_cap = scratch.scratch_dir(
+            raw_roots, cfg["banks"] * raw_file + (1 << 30), raw_file)
+        made.append(rawdir)
+        say("plan", **plan, largest_product_file=out_cap,
+            largest_raw_file=raw_cap, rawdir=rawdir, outdir=outdir,
+            geometry=cfg["geometry"], banks=cfg["banks"])
+        say("reduced", config=cfg["reduced"],
+            blocks=[plan["blocks_wanted"], plan["blocks"]],
+            rows=[plan["rows_wanted"], plan["rows"]],
+            why="as the traffic file asks" if plan["blocks"]
+            == plan["blocks_wanted"] else
+            f"the largest file this machine allows is {out_cap} B; the "
+            f"product would be {want_file} B.  Duration is cut, width never")
+
+        t0 = time.perf_counter()
+        inputs = write_inputs(cell, plan, rawdir, raw_cap, args.seed)
+        parts["synth_s"] = time.perf_counter() - t0
+        say("synth", files=[[os.path.basename(p) for p in ps]
+                            for ps in inputs["raws"]],
+            seconds=parts["synth_s"], pool_blocks=t["pool_blocks"])
+
+        ref_kw = dict(nslots=plan["nslots"], nfft=t["nfft"], nint=t["nint"],
+                      ntap=t["ntap"], despike=t["despike"],
+                      tolerance=t["tolerance"])
+        problems = []   # what made the run incorrect
+        bad = set()     # the passes whose product was wrong
+
+        small = plan["product_bytes"] <= SMALL_PRODUCT_BYTES
+
+        def verify(res, rows, *, read_all, against_reference=False,
+                   golden=None):
+            """Guarantees, then the plain reference and/or the verified
+            product.  Outside every timed interval."""
+            try:
+                res["facts"] = check.guarantees(res["product"], rows,
+                                                read_all or small)
+                if against_reference:
+                    say("check.reference", pass_=res["tag"],
+                        **check.against_reference(
+                            res["product"], inputs["slices"], rows=rows,
+                            **ref_kw))
+                if golden is not None:
+                    check.same_product(res["product"], res["facts"], golden,
+                                       args.seed)
+                return True
+            except check.Incorrect as e:
+                problems.append(f"{res['tag']}: {e}")
+                bad.add(res["tag"])
+                say("INCORRECT", pass_=res["tag"], problem=str(e))
+                return False
+
+        def keep_as_golden(res):
+            """The verified product's facts and seeded byte sample stay;
+            the product itself goes (memory is what a run is short of)."""
+            g = {**res["facts"], "sample": check.sample(
+                res["product"], res["facts"]["bytes"], args.seed)}
+            discard(res)
+            return g
+
+        # Warm-up: compiles or loads this cell's own programs, faults the
+        # staging pool in.
+        t0 = time.perf_counter()
+        whole_warmup = not (drv.WARMUP_CUT
+                            and plan["warm_rows"] < plan["rows"])
+        warm = timed_pass(cell, inputs, outdir, "warmup",
+                          warm_rows=None if whole_warmup
+                          else plan["warm_rows"])
+        parts["warmup_pass_s"] = time.perf_counter() - t0
+        plan_got = (warm["cli"][-1].get("kernel_plan") or {})
+        say("warmup", wall_s=warm["wall_s"],
+            first_product_s=warm["first_product_s"], **warm["compiles"],
+            kernel_plan=plan_got, expected_plan=t.get("expect_plan"),
+            plan_as_expected=None if rehearse or "expect_plan" not in t
+            else all(plan_got.get(k) == v
+                     for k, v in t["expect_plan"].items()),
+            whole_pass=whole_warmup)
+        t0 = time.perf_counter()
+        from blit.integrity import verify_product
+
+        # blit's own whole-file verification of the warm-up product (size
+        # and CRC against the manifest): the one full read of set-up.
+        _, said = verify_product(warm["product"])
+        if said:
+            problems.append(f"warmup: blit's own verify_product: {said}")
+        golden = None
+        if whole_warmup:
+            if verify(warm, plan["rows"], read_all=False,
+                      against_reference=True):
+                golden = keep_as_golden(warm)
+        else:
+            verify(warm, plan["warm_rows"], read_all=False)
+        discard(warm)
+        parts["warmup_check_s"] = time.perf_counter() - t0
+        try:
+            from blit.pipeline import RawReducer
+
+            tuning = RawReducer(nfft=t["nfft"], nint=t["nint"]
+                                ).tuning_provenance()
+        except Exception as e:  # noqa: BLE001 — a label for the log only
+            tuning = f"{type(e).__name__}: {e}"
+        say("tuning", BLIT_TUNE_DIR=os.environ["BLIT_TUNE_DIR"],
+            provenance=tuning)
+        setup_s = time.perf_counter() - T_START
+        say("setup", setup_s=setup_s, **parts)
+
+        # The window.
+        passes = []
+        last = None   # the newest pass's product is read whole at the end
+        while not passes or sum(p["wall_s"] for p in passes) \
+                < args.seconds:
+            if last is not None:
+                discard(last)
+            p = last = timed_pass(cell, inputs, outdir, f"pass{len(passes)}")
+            passes.append(p)
+            say("pass", n=len(passes) - 1, wall_s=p["wall_s"],
+                first_product_s=p["first_product_s"], cpu_s=p["cpu_s"],
+                **p["compiles"], **memory_facts())
+            if p["compiles"]["backend_compiles"]:
+                problems.append(f"{p['tag']}: {p['compiles']} — a compile "
+                                "inside the measured window")
+            if golden is not None:
+                verify(p, plan["rows"], read_all=False, golden=golden)
+            elif verify(p, plan["rows"], read_all=False,
+                        against_reference=True):
+                # The first whole product (the warm-up was cut): the plain
+                # reference has checked it here, between passes.
+                golden, last = keep_as_golden(p), None
+        if last is not None:
+            verify(last, plan["rows"], read_all=True, golden=golden)
+            discard(last)
+        measured_s = sum(p["wall_s"] for p in passes)
+        window_raw = plan["raw_bytes"] * len(passes)
+
+        # The traced pass and the per-layer metrics.
+        breakdown = None
+        if args.trace:
+            tp, found = profiled_pass(cell, inputs, outdir)
+            verify(tp, plan["rows"], read_all=False, golden=golden)
+            discard(tp)
+            from readers import xplane
+
+            trace = xplane.reduce_trace(found[-1], tp["wall_s"]) if found \
+                else None
+            say("traced", **memory_facts(), wall_s=tp["wall_s"],
+                first_product_s=tp["first_product_s"], stages=tp["stages"],
+                trace_file_bytes=os.path.getsize(found[-1]) if found else 0,
+                chips=trace and trace["chips"],
+                busy_s_by_chip=trace and trace["busy_s_by_chip"],
+                collective_s=trace and trace["collective_s"],
+                note="the stage table is host-side busy/wait seconds per "
+                "thread under the profiler; its 'device' row is a wait on "
+                "a dispatch, not device busy time")
+            if args.keep_trace and found:
+                os.makedirs(args.keep_trace, exist_ok=True)
+                shutil.copy(found[-1], os.path.join(
+                    args.keep_trace, cell["name"] + ".xplane.pb"))
+            if trace:
+                device["busy_s"] = trace["busy_s"]
+                device["window_s"] = trace["window_s"]
+
+                def top(d):
+                    return [[k, v] for k, v in sorted(
+                        d.items(), key=lambda kv: -kv[1])[:10]]
+
+                breakdown = {"device_ops": top(trace["per_op_s"]),
+                             "idle_gaps": top(trace["idle_gaps_s"])}
+                stage_s = {k: v["seconds"] for k, v in tp["stages"].items()
+                           if isinstance(v, dict) and "seconds" in v
+                           and k not in drv.WRAPPER_STAGES}
+                say("overlap", pass_wall_s=tp["wall_s"], stage_s=stage_s,
+                    largest_stage_s=max(stage_s.values(), default=None),
+                    sum_of_stages_s=sum(stage_s.values()),
+                    note="the stages run on threads of their own: with "
+                    "perfect overlap the pass takes the largest, with none "
+                    "their sum")
+
+        peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use") or 0)
+                   for d in devs)
+        device["memory_peak_bytes"] = peak
+        if args.trace:
+            metrics = layer_metrics(cell, {
+                "stages": tp["stages"], "trace": trace,
+                "traced_raw_bytes": plan["raw_bytes"],
+                "traced_least_bytes": reference.least_bytes(
+                    plan["raw_bytes"], plan["product_bytes"]),
+                "window_raw_bytes": window_raw,
+                "window_cpu_s": sum(p["cpu_s"] for p in passes),
+                "window_first_product_s": [p["first_product_s"]
+                                           for p in passes],
+                "memory_peak_bytes": peak, "device_kind": device["kind"],
+                "peaks": peaks})
+        else:
+            own = {
+                # Every pass reduces the same bytes: the median pass, so
+                # that one stalled pass on a shared host (3 s in a 1.5 s
+                # pass was seen) does not set the run's number.  The mean
+                # over the window is on the [window] line.
+                "reduce_rate": plan["raw_bytes"] / statistics.median(
+                    p["wall_s"] for p in passes) / 1e9,
+                "first_product_s": statistics.median(
+                    p["first_product_s"] for p in passes),
+                "setup_s": setup_s,
+            }
+            metrics = {m["name"]: {"value": own[m["name"]], "unit": m["unit"]}
+                       for m in cell["end_to_end"]}
+        say("window", passes=len(passes), measured_s=measured_s,
+            raw_bytes=window_raw, mean_rate_GBps=window_raw / measured_s / 1e9,
+            problems=problems,
+            wall_s_by_pass=[p["wall_s"] for p in passes])
+        if rehearse:
+            print(json.dumps({"rehearsal": True, "platform":
+                              device["platform"], "correct": not problems,
+                              "attempted": len(passes),
+                              "failed": len(bad - {"warmup", "traced"}),
+                              "metric_names": sorted(metrics),
+                              "breakdown": breakdown is not None}),
+                  flush=True)
+            return 0 if not problems else 1
+        print(result_line(not problems, len(passes),
+                          len(bad - {"warmup", "traced"}), metrics, device,
+                          breakdown), flush=True)
+        return 0
+    finally:
+        for d in made:
+            shutil.rmtree(d, ignore_errors=True)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy sizes on the CPU; prints no metric")
+    ap.add_argument("--keep-trace", default=None, metavar="DIR",
+                    help="copy the traced pass's .xplane.pb to DIR")
+    args = ap.parse_args(argv)
+    sys.path[:0] = [HERE, ROOT]
+    signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
+    guard_stop = threading.Event()
+    threading.Thread(target=memory_guard, args=(guard_stop,),
+                     daemon=True).start()
+    import logging
+
+    logging.basicConfig(stream=sys.stdout, format="  log> %(message)s")
+    logging.getLogger("blit.pipeline").setLevel(logging.INFO)
+    try:
+        return run(args)
+    except Refused as e:
+        # No result line; the reason goes LAST on stderr, which is all a
+        # sealed machine hands back.
+        print(f"benchmark refused: {e}", file=sys.stderr, flush=True)
+        return 2
+    finally:
+        guard_stop.set()
+
+
+if __name__ == "__main__":
+    sys.exit(main())
