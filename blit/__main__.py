@@ -125,6 +125,9 @@ def _cmd_reduce(args: argparse.Namespace) -> int:
                 "nifs": hdr.get("nifs"),
                 "input_bytes": stats.input_bytes,
                 "gbps": round(stats.gbps, 3),
+                # Per-stage seconds and bytes, every wait.* row included,
+                # under the key `blit scan` uses.
+                "stages": red.timeline.report(),
                 **_ran_on(),
             }
         )
@@ -3931,7 +3934,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "if you have measured headroom")
     ps.add_argument("--max-frames", type=int, default=None)
     ps.add_argument("--trace-logdir", default=None,
-                    help="write a JAX profiler trace of the window loop")
+                    help="write a device-only JAX profiler trace of the "
+                         "window loop (.xplane.pb; host and Python "
+                         "tracers off) and, beside it, blit-spans.json: "
+                         "every stage and wait of the loop as a span on "
+                         "the same epoch clock")
     ps.add_argument("--compression", default=None,
                     choices=["gzip", "bitshuffle"],
                     help="write .h5 (FBH5) band products with this codec")

@@ -326,6 +326,38 @@ class HistogramStats:
         return h
 
 
+class StageWait:
+    """Where a pump thread may block (``with timeline.wait(name) as w``):
+    call :meth:`block` right before each blocking call.  The first one
+    enters the stage, leaving the ``with`` closes it, and a path that
+    never blocked records nothing — the row's seconds are blocked
+    seconds only."""
+
+    __slots__ = ("_tl", "_name", "_cm")
+
+    def __init__(self, timeline: "Timeline", name: str):
+        self._tl = timeline
+        self._name = name
+        self._cm = None
+
+    @property
+    def blocking(self) -> bool:
+        return self._cm is not None
+
+    def block(self) -> None:
+        if self._cm is None:
+            self._cm = self._tl.stage(self._name, byte_free=True)
+            self._cm.__enter__()
+
+    def __enter__(self) -> "StageWait":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._cm is not None:
+            cm, self._cm = self._cm, None
+            cm.__exit__(None, None, None)
+
+
 @dataclass
 class Timeline:
     """A registry of named stage timings (one per pipeline/driver)."""
@@ -339,10 +371,17 @@ class Timeline:
     @contextlib.contextmanager
     def stage(
         self, name: str, nbytes: int = 0, byte_free: bool = False
-    ) -> Iterator[None]:
+    ) -> Iterator[Optional["Span"]]:
+        """Time one stage: the sums below, and — so the seconds can be
+        laid against another clock — the interval itself, as a span of
+        the process tracer under the ambient span (attrs ``bytes`` and
+        ``stage=1``).  Yields that live span, for a caller with attrs of
+        its own to add; ``BLIT_SPANS=0`` keeps the sums, drops the span
+        and yields ``None``.  Span and row carry the same duration."""
+        sp = _TRACER.open_span(name, {"bytes": nbytes, "stage": 1})
         t0 = time.perf_counter()
         try:
-            yield
+            yield sp
         finally:
             dt = time.perf_counter() - t0
             s = self.stages[name]
@@ -351,7 +390,21 @@ class Timeline:
             s.bytes += nbytes
             if byte_free:
                 s.byte_free = True
-            _FLIGHT.stage_event(name, dt, nbytes)
+            if sp is None:
+                _FLIGHT.stage_event(name, dt, nbytes)
+            else:  # the span is the stage's one entry in the flight ring
+                _TRACER.close_span(sp, dt)
+
+    def wait(self, name: str) -> "StageWait":
+        """A byte-free ``wait.<what>`` stage that starts only when the
+        thread is about to block (:class:`StageWait`)."""
+        return StageWait(self, name)
+
+    def declare(self, *names: str) -> None:
+        """Byte-free rows that exist from now on: a wait that never
+        blocked then reads 0 calls instead of being absent."""
+        for name in names:
+            self.stages[name].byte_free = True
 
     def count(self, name: str, n: int = 1) -> None:
         """Record a byte-free event counter as a stage (``calls`` carries
@@ -565,15 +618,32 @@ class Timeline:
 
 @contextlib.contextmanager
 def profile_trace(logdir: Optional[str]) -> Iterator[None]:
-    """JAX profiler trace around a region (TensorBoard/Perfetto readable).
-    ``logdir=None`` is a no-op, so call sites need no conditionals."""
+    """JAX profiler trace around a region, device ops only, plus the
+    region's spans beside it.  ``logdir=None`` is a no-op, so call sites
+    need no conditionals.
+
+    The host and Python tracers are off and the TPU traces XLA ops only:
+    at the profiler's default levels a 1.5 s pass took 45 s on the v5e
+    machine and grew the process past 10 GB (PERF.md section 3).  What
+    the host did is in ``<logdir>/blit-spans.json`` instead (Chrome trace
+    events, ``ts`` in epoch microseconds): every pump stage and wait of
+    the region is a span there, and the ``.xplane.pb``'s ``Task
+    Environment`` plane stamps its start in epoch nanoseconds, so the two
+    lie on one clock (docs/WORKFLOWS.md "Diagnosing a slow link")."""
     if logdir is None:
         yield
         return
     import jax
 
-    with jax.profiler.trace(logdir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 0
+    opts.advanced_configuration = {"tpu_trace_mode": "TRACE_ONLY_XLA"}
+    cursor = _TRACER._total
+    with jax.profiler.trace(logdir, profiler_options=opts):
         yield
+    _TRACER.export_chrome(os.path.join(logdir, "blit-spans.json"),
+                          since=cursor)
 
 
 # -- spans ------------------------------------------------------------------
@@ -604,7 +674,7 @@ class Span:
     created on context-manager entry, recorded on exit."""
 
     __slots__ = ("name", "t0", "duration_s", "trace_id", "span_id",
-                 "parent_id", "host", "worker", "tid", "attrs")
+                 "parent_id", "host", "worker", "tid", "attrs", "stack")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: Optional[str], attrs: Optional[Dict]):
@@ -618,6 +688,7 @@ class Span:
         self.worker = _WORKER
         self.tid = threading.get_ident() & 0x7FFFFFFF
         self.attrs = attrs
+        self.stack = None  # the Tracer's, while the span is open
 
     def as_dict(self) -> Dict:
         d = {"name": self.name, "t0": self.t0,
@@ -683,26 +754,50 @@ class Tracer:
         """Time a traced operation.  Yields the live :class:`Span` (or
         ``None`` when tracing is disabled); extra keyword args become
         span attrs."""
-        if not self.enabled:
+        sp = self.open_span(name, attrs or None)
+        if sp is None:
             yield None
             return
+        p0 = time.perf_counter()
+        try:
+            yield sp
+        finally:
+            self.close_span(sp, time.perf_counter() - p0)
+
+    def open_span(self, name: str, attrs: Optional[Dict]) -> Optional[Span]:
+        """Start a span under the ambient one and make it this thread's
+        ambient span (``None`` when tracing is disabled).  Pair with
+        :meth:`close_span`; :meth:`span` is the two as a context
+        manager."""
+        if not self.enabled:
+            return None
         stack = self._stack()
         if stack:
             trace_id, parent_id = stack[-1]
         else:
             trace_id, parent_id = _new_id(), None
-        sp = Span(name, trace_id, _new_id(), parent_id, attrs or None)
+        sp = Span(name, trace_id, _new_id(), parent_id, attrs)
+        sp.stack = stack
         stack.append((trace_id, sp.span_id))
-        p0 = time.perf_counter()
-        try:
-            yield sp
-        finally:
-            sp.duration_s = time.perf_counter() - p0
+        return sp
+
+    def close_span(self, sp: Span, duration_s: float) -> None:
+        """Record ``sp`` and take ITS OWN entry off the stack it was
+        pushed on — not whatever is on top: a span held across a
+        ``yield`` (``reduce.stream``, the ``stream`` stage) closes when
+        its generator does, with spans the consumer opened since then
+        still above it."""
+        sp.duration_s = duration_s
+        stack, sp.stack = sp.stack, None
+        entry = (sp.trace_id, sp.span_id)
+        if stack and stack[-1] == entry:
             stack.pop()
-            with self._span_lock:
-                self._spans.append(sp)
-                self._total += 1
-            _FLIGHT.span_event(sp)
+        elif stack and entry in stack:
+            stack.remove(entry)
+        with self._span_lock:
+            self._spans.append(sp)
+            self._total += 1
+        _FLIGHT.span_event(sp)
 
     @contextlib.contextmanager
     def activate(self, ctx: Optional[Dict]) -> Iterator[None]:
@@ -769,12 +864,17 @@ class Tracer:
         self._spans.clear()
 
     def export_chrome(self, path: Optional[str] = None,
-                      extra: Optional[Iterable[Dict]] = None):
+                      extra: Optional[Iterable[Dict]] = None,
+                      since: int = 0):
         """Render the recorded spans as Chrome trace events (Perfetto /
         ``chrome://tracing`` loadable).  ``extra`` takes harvested span
-        dicts to merge in.  Returns the event document; writes JSON to
-        ``path`` when given and returns the path instead."""
+        dicts to merge in; ``since`` (a :meth:`spans_since` cursor) keeps
+        only the spans recorded after it.  Returns the event document;
+        writes JSON to ``path`` when given and returns the path instead."""
         spans = self.spans()
+        if since:
+            new = self._total - since
+            spans = spans[-new:] if new > 0 else []
         if extra:
             spans = spans + [Span.from_dict(d) for d in extra]
         # Dedupe by span id: with the in-process pool backends a harvest
@@ -876,11 +976,15 @@ class FlightRecorder:
         self._ring.append(e)
 
     def span_event(self, sp: Span) -> None:
-        self._ring.append({"t": sp.t0, "kind": "span", "name": sp.name,
-                           "dur_s": round(sp.duration_s, 6),
-                           "span": sp.span_id, "parent": sp.parent_id})
+        ev = {"t": sp.t0, "kind": "span", "name": sp.name,
+              "dur_s": round(sp.duration_s, 6),
+              "span": sp.span_id, "parent": sp.parent_id}
+        if sp.attrs and sp.attrs.get("stage"):  # a Timeline stage's span
+            ev["bytes"] = sp.attrs.get("bytes", 0)
+        self._ring.append(ev)
 
     def stage_event(self, name: str, seconds: float, nbytes: int) -> None:
+        """A stage that recorded no span (``BLIT_SPANS=0``)."""
         self._ring.append({"t": time.time(), "kind": "stage", "name": name,
                            "s": round(seconds, 6), "bytes": nbytes})
 

@@ -145,6 +145,7 @@ class OutputRotation:
         self.reuse = reuse
         self.stall_timeout_s = stall_timeout_s
         self._tl = timeline if timeline is not None else Timeline()
+        self._tl.declare("wait.out_slot", "wait.out_drain", "wait.slab")
         self._in: "queue.Queue" = queue.Queue()
         self._cv = threading.Condition()
         self._pending = 0        # put but not yet emitted (readback bound)
@@ -273,7 +274,7 @@ class OutputRotation:
         ticking.  Returns None if closed while waiting."""
         alloc_shape = None
         evicted = None
-        with self._cv:
+        with self._tl.wait("wait.slab") as w, self._cv:
             while True:
                 for i, s in enumerate(self._free):
                     if s.shape == shape and s.dtype == dtype:
@@ -289,6 +290,7 @@ class OutputRotation:
                 if self._stop.is_set():
                     return None
                 self._wd.beat()
+                w.block()
                 self._cv.wait(timeout=0.2)
         # Aligned, pool-recycled staging (blit/hostmem.py): a previous
         # stream's already-faulted slab when one matches.
@@ -345,13 +347,14 @@ class OutputRotation:
         self._in.put((out, nbytes, payload, on_consumed, fetch,
                       time.perf_counter()))
         ready: List[OutputSlab] = []
-        with self._cv:
+        with self._tl.wait("wait.out_slot") as w, self._cv:
             while True:
                 while self._done:
                     ready.append(self._done.popleft())
                 self._check()
                 if self._pending < self.depth:
                     return ready
+                w.block()
                 self._cv.wait(timeout=self._poll())
 
     def drain(self) -> Iterator[OutputSlab]:
@@ -360,7 +363,7 @@ class OutputRotation:
         while True:
             batch: List[OutputSlab] = []
             finished = False
-            with self._cv:
+            with self._tl.wait("wait.out_drain") as w, self._cv:
                 while True:
                     while self._done:
                         batch.append(self._done.popleft())
@@ -370,6 +373,7 @@ class OutputRotation:
                         break
                     if batch:
                         break
+                    w.block()
                     self._cv.wait(timeout=self._poll())
             # Yield OUTSIDE the lock: consumers release slabs (and the
             # sink thread releases ring slabs) re-entering _cv.
@@ -456,6 +460,7 @@ class AsyncSink:
                  stall_timeout_s: Optional[float] = None):
         self._writer = writer
         self._tl = timeline if timeline is not None else Timeline()
+        self._tl.declare("wait.sink", "wait.sink_flush")
         self._key = key if key is not None else getattr(writer, "path", None)
         self.stall_timeout_s = stall_timeout_s
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
@@ -537,14 +542,18 @@ class AsyncSink:
 
     def _put(self, item) -> None:
         poll = self._wd.poll_s(0.2)
-        while True:
-            try:
-                self._q.put(item, timeout=poll)
-                return
-            except queue.Full:
-                self._check()
-                self._wd.check("writer stalled",
-                               active=self._thread.is_alive())
+        with self._tl.wait("wait.sink") as w:
+            while True:
+                try:
+                    self._q.put(item, block=w.blocking, timeout=poll)
+                    return
+                except queue.Full:
+                    if not w.blocking:
+                        w.block()
+                        continue
+                    self._check()
+                    self._wd.check("writer stalled",
+                                   active=self._thread.is_alive())
 
     def append(self, slab: np.ndarray,
                release: Optional[Callable[[], None]] = None) -> None:
@@ -564,11 +573,15 @@ class AsyncSink:
         barrier = _FlushBarrier()
         self._put(barrier)
         poll = self._wd.poll_s(0.5)
-        while not barrier.event.wait(timeout=poll):
-            self._wd.check("writer stalled inside flush barrier",
-                           active=self._thread.is_alive())
-            if not self._thread.is_alive():
-                break  # died without recording? _check below decides
+        with self._tl.wait("wait.sink_flush") as w:
+            while not barrier.event.is_set():
+                w.block()
+                if barrier.event.wait(timeout=poll):
+                    break
+                self._wd.check("writer stalled inside flush barrier",
+                               active=self._thread.is_alive())
+                if not self._thread.is_alive():
+                    break  # died without recording? _check below decides
         self._check()
 
     def _join(self, join_timeout_s: float) -> bool:

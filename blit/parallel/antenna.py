@@ -51,14 +51,11 @@ log = logging.getLogger("blit.antenna")
 
 def _traced_fill(fill, name: str):
     """Wrap a BufferRotation fill callback so the producer thread's whole
-    run records as one span, parented on the driver span that started the
-    feed (the fill runs on the rotation's thread, where the driver's
-    thread-local trace context would otherwise be invisible)."""
-    ctx = observability.tracer().context()
+    run records as one span (the rotation itself parents its thread on
+    the driver span that built it)."""
 
     def run(rot):
-        tr = observability.tracer()
-        with tr.activate(ctx), tr.span(name):
+        with observability.span(name):
             fill(rot)
 
     return run
@@ -667,7 +664,7 @@ class AntennaStream(_DegradedContinuation):
         rot = BufferRotation(
             self.prefetch_depth, _traced_fill(self._fill, "antenna.produce"),
             name="blit-antenna-feed",
-            stall_timeout_s=self.stall_timeout_s,
+            stall_timeout_s=self.stall_timeout_s, timeline=tl,
         )
         try:
             for slot, (w, w0, wt, masked) in rot.slots():
@@ -917,7 +914,7 @@ class CorrelatorStream(_DegradedContinuation):
             self.prefetch_depth,
             _traced_fill(self._fill, "correlator.produce"),
             name="blit-correlator-feed",
-            stall_timeout_s=self.stall_timeout_s,
+            stall_timeout_s=self.stall_timeout_s, timeline=tl,
         )
         try:
             for slot, (w, f0, fw, used, masked) in rot.slots():

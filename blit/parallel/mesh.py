@@ -24,6 +24,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from blit.observability import Timeline
 from blit.ops.channelize import channelize
 from blit.ops.despike import despike
 
@@ -293,7 +294,8 @@ def shard_voltages(
 
 
 def put_local_shards(
-    blocks: Dict, mesh: Mesh, global_shape, role: Union[str, P] = "voltages"
+    blocks: Dict, mesh: Mesh, global_shape, role: Union[str, P] = "voltages",
+    timeline=None,
 ) -> jax.Array:
     """``jax.device_put`` with shardings, multi-host-shaped: assemble the
     global sharded array for ``role`` from one host block per LOCALLY
@@ -306,11 +308,13 @@ def put_local_shards(
     single-device shards, so the host never materializes the whole scan
     and no ``device_put`` targets a non-addressable device (the
     multi-process contract of :func:`blit.parallel.scan._feed_window`,
-    now partition-rule-driven)."""
-    shards = [
-        jax.device_put(blk, mesh.devices[b, k])
-        for (b, k), blk in sorted(blocks.items())
-    ]
+    now partition-rule-driven).  Each player's put is a ``feed.put``
+    stage of its own (that block's bytes) on ``timeline``."""
+    tl = timeline if timeline is not None else Timeline()
+    shards = []
+    for (b, k), blk in sorted(blocks.items()):
+        with tl.stage("feed.put", blk.nbytes):
+            shards.append(jax.device_put(blk, mesh.devices[b, k]))
     return jax.make_array_from_single_device_arrays(
         tuple(global_shape), sharding_for(mesh, role), shards
     )

@@ -18,6 +18,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from blit import observability
 from blit.io.guppi import GuppiRaw, open_raw, require_native_reader
 from blit.monitor import published
 from blit.ops.channelize import (
@@ -236,19 +237,23 @@ def _open_players(raw_paths, mesh):
     return mesh, local, raws, int(geo[0][0]), int(geo[0][1]), int(samps.min())
 
 
-def _feed_window(raws, local, mesh, nchan, npol, start, ntime):
+def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None):
     """Assemble the global sharded voltage array for gap-free samples
-    ``[start, start + ntime)`` of every player.  One bank in host memory at
-    a time: each local player's block goes straight onto its chip, and the
-    global array is built from the single-device shards (no whole-scan host
-    buffer, no device_put to any non-addressable device) — the assembly
-    itself is :func:`blit.parallel.mesh.put_local_shards`, the ONE
+    ``[start, start + ntime)`` of every player.  Every LOCAL player's
+    window is read into host memory first, one after the other
+    (``feed.read`` per bank on ``tl``); then each block goes straight
+    onto its chip (``feed.put`` per bank) and the global array is built
+    from the single-device shards (no whole-scan host buffer, no
+    device_put to any non-addressable device) — the assembly itself is
+    :func:`blit.parallel.mesh.put_local_shards`, the ONE
     partition-rule-driven implementation the sharded plane shares."""
     nband, nbank = mesh.devices.shape
+    tl = tl if tl is not None else observability.Timeline()
     blocks = {}
     for b, k in local:
         r = raws[(b, k)]
-        v = _gapless(r, ntime, skip=start)
+        with tl.stage("feed.read", nchan * ntime * npol * 2):
+            v = _gapless(r, ntime, skip=start)
         if v.shape[0] != nchan or v.shape[1] < ntime or v.shape[2:] != (npol, 2):
             raise ValueError(
                 f"{r.path}: shape {v.shape} incompatible with "
@@ -256,7 +261,7 @@ def _feed_window(raws, local, mesh, nchan, npol, start, ntime):
             )
         blocks[(b, k)] = np.ascontiguousarray(v[None, None, :, :ntime])
     return M.put_local_shards(
-        blocks, mesh, (nband, nbank, nchan, ntime, npol, 2)
+        blocks, mesh, (nband, nbank, nchan, ntime, npol, 2), timeline=tl
     )
 
 
@@ -706,14 +711,16 @@ def reduce_scan_mesh_to_files(
 
     Observability (SURVEY.md §5 metrics bar): pass ``timeline`` (a
     :class:`blit.observability.Timeline`) to accumulate per-window stage
-    timings with byte counts — ``read`` (host RAW ingest + device feed),
+    timings with byte counts — ``read`` (host RAW ingest + device feed;
+    inside it one ``feed.read`` and one ``feed.put`` per local bank),
     ``dispatch`` (async window dispatch, ~0 after the first compile),
     ``device`` (the blocking wait on the window's compute+collectives),
     ``readback`` (stitched-band device→host), ``write`` (product
     append) — mirroring the single-chip ``RawReducer`` stages;
     ``blit scan`` prints the report as a stats JSON line.
-    ``trace_logdir`` wraps the window loop in a JAX profiler trace
-    (TensorBoard/Perfetto).
+    ``trace_logdir`` wraps the window loop in a device-only JAX profiler
+    trace and writes the loop's spans beside it as ``blit-spans.json``
+    (:func:`blit.observability.profile_trace`).
 
     Output naming: ``out_paths`` (band-ascending, one per band; ``.fil``
     or ``.h5`` per path) or ``out_dir`` + ``band<id>.fil`` (``.h5`` when
@@ -816,33 +823,38 @@ def reduce_scan_mesh_to_files(
         # I/O overlaps device compute at one extra window of HBM.
         pending = None
         f0 = f0_start
-        with profile_trace(trace_logdir):
+        with observability.span(
+            "scan.reduce", nfft=nfft,
+            out=out_paths[0],  # the first product, as reduce.to_file's
+        ), profile_trace(trace_logdir):
             while f0 < total:
                 n = min(wf, total - f0)
                 ntime = (n + ntap - 1) * nfft
                 # Locally fed voltage bytes: complex int8 = 2 B/sample.
                 fed = len(raws) * nchan * ntime * npol * 2
-                with tl.stage("read", fed):
-                    volt = _feed_window(
-                        raws, local, mesh, nchan, npol, f0 * nfft, ntime
-                    )
-                with tl.stage("dispatch", byte_free=True):
-                    out = M.band_reduce(
-                        volt,
-                        coeffs,
-                        mesh=mesh,
-                        nfft=nfft,
-                        ntap=ntap,
-                        nint=nint,
-                        stokes=stokes,
-                        fft_method=fft_method,
-                        stitch=True,
-                        despike_nfpc=despike_nfpc,
-                        fqav_by=fqav_by,
-                        dtype=dtype,
-                    )
-                if pending is not None:
-                    flush(pending)
+                with observability.span("scan.window", f0=f0):
+                    with tl.stage("read", fed):
+                        volt = _feed_window(
+                            raws, local, mesh, nchan, npol, f0 * nfft,
+                            ntime, tl,
+                        )
+                    with tl.stage("dispatch", byte_free=True):
+                        out = M.band_reduce(
+                            volt,
+                            coeffs,
+                            mesh=mesh,
+                            nfft=nfft,
+                            ntap=ntap,
+                            nint=nint,
+                            stokes=stokes,
+                            fft_method=fft_method,
+                            stitch=True,
+                            despike_nfpc=despike_nfpc,
+                            fqav_by=fqav_by,
+                            dtype=dtype,
+                        )
+                    if pending is not None:
+                        flush(pending)
                 pending = out
                 f0 += n
             if pending is not None:

@@ -166,7 +166,7 @@ class _ShardFeed:
         nband, nbank = self.mesh.devices.shape
         rot = BufferRotation(
             self.nslots, self._fill, name="blit-mesh-feed",
-            stall_timeout_s=self.stall_timeout_s,
+            stall_timeout_s=self.stall_timeout_s, timeline=self.tl,
         )
         try:
             for slot, (w, f0, n, ntime) in rot.slots():

@@ -122,7 +122,10 @@ class BufferRotation:
       return), fills its buffers, and ``rot.emit(slot, payload)``.
       Returning ends the stream; exceptions re-raise in the consumer.
     - Waiting in ``acquire`` is back-pressure from the consumer, not
-      producer work — time it outside any ingest stage.
+      producer work — time it outside any ingest stage.  The rotation
+      times its own two waits on ``timeline``, blocked seconds only:
+      ``wait.ingest_slot`` (producer, no free slot) and ``wait.chunk``
+      (consumer, nothing filled yet).
     - A slot is only refilled after the consumer released it; concurrent
       READS of an emitted slot (e.g. copying a filter-state tail into the
       next slot) are safe.
@@ -134,9 +137,15 @@ class BufferRotation:
     """
 
     def __init__(self, nslots: int, fill, *, name: str = "blit-feed",
-                 stall_timeout_s: Optional[float] = None):
+                 stall_timeout_s: Optional[float] = None,
+                 timeline: Optional[Timeline] = None):
         self.nslots = max(2, nslots)
         self.stall_timeout_s = stall_timeout_s
+        self._tl = timeline if timeline is not None else Timeline()
+        self._tl.declare("wait.chunk", "wait.ingest_slot")
+        # Captured on the consumer's thread: the producer's stages join
+        # the trace of whatever pass built the rotation.
+        self._span_ctx = observability.tracer().context()
         self._free: "queue.Queue[int]" = queue.Queue()
         for j in range(self.nslots):
             self._free.put(j)
@@ -159,7 +168,8 @@ class BufferRotation:
 
     def _run(self) -> None:
         try:
-            self._fill(self)
+            with observability.tracer().activate(self._span_ctx):
+                self._fill(self)
             self._filled.put(None)
         except BaseException as e:  # noqa: BLE001 — forwarded to the consumer
             self._filled.put((_ROT_ERR, e))
@@ -167,15 +177,18 @@ class BufferRotation:
     # -- producer side ----------------------------------------------------
     def acquire(self) -> Optional[int]:
         """Next free slot index; ``None`` once the consumer is gone."""
-        while not self._stop.is_set():
-            try:
-                slot = self._free.get(timeout=0.2)
-            except queue.Empty:
-                # Back-pressure from the consumer is not a producer stall.
+        with self._tl.wait("wait.ingest_slot") as w:
+            while not self._stop.is_set():
+                try:
+                    slot = self._free.get(block=w.blocking, timeout=0.2)
+                except queue.Empty:
+                    # Back-pressure from the consumer is not a producer
+                    # stall.
+                    w.block()
+                    self._wd.beat()
+                    continue
                 self._wd.beat()
-                continue
-            self._wd.beat()
-            return slot
+                return slot
         return None
 
     def emit(self, slot: int, payload) -> None:
@@ -199,24 +212,8 @@ class BufferRotation:
         poll = self._wd.poll_s(0.5)
         try:
             while True:
-                try:
-                    item = self._filled.get(timeout=poll)
-                except queue.Empty:
-                    if self._held >= self.nslots:
-                        msg = (
-                            f"BufferRotation starved: all {self.nslots} "
-                            "slots are held unreleased by the consumer — "
-                            "release() earlier chunks/windows before "
-                            "requesting more, or raise prefetch_depth"
-                        )
-                        observability.flight_recorder().dump(msg)
-                        raise RuntimeError(msg)
-                    # The watchdog dumps the incident trail BEFORE the
-                    # raise unwinds and teardown noise overwrites the
-                    # flight-recorder ring (ISSUE 5 tentpole #4).
-                    self._wd.check("producer stalled",
-                                   active=self._thread.is_alive())
-                    continue
+                with self._tl.wait("wait.chunk") as w:
+                    item = self._next_filled(w, poll)
                 if item is None:
                     return
                 slot, payload = item
@@ -227,6 +224,31 @@ class BufferRotation:
                 yield slot, payload
         finally:
             self.close()
+
+    def _next_filled(self, w, poll: float):
+        """The next item off the filled queue; blocked seconds go to
+        ``w``."""
+        while True:
+            try:
+                return self._filled.get(block=w.blocking, timeout=poll)
+            except queue.Empty:
+                if not w.blocking:
+                    w.block()
+                    continue
+                if self._held >= self.nslots:
+                    msg = (
+                        f"BufferRotation starved: all {self.nslots} "
+                        "slots are held unreleased by the consumer — "
+                        "release() earlier chunks/windows before "
+                        "requesting more, or raise prefetch_depth"
+                    )
+                    observability.flight_recorder().dump(msg)
+                    raise RuntimeError(msg)
+                # The watchdog dumps the incident trail BEFORE the
+                # raise unwinds and teardown noise overwrites the
+                # flight-recorder ring (ISSUE 5 tentpole #4).
+                self._wd.check("producer stalled",
+                               active=self._thread.is_alive())
 
     def close(self, join_timeout_s: float = 10.0) -> None:
         """Stop the producer and join it (idempotent; safe mid-stream).
@@ -297,10 +319,12 @@ class RawReducer:
     chunk_frames: Optional[int] = None
     # Per-stage timing/byte registry ("ingest" / "state" / "stream" on the
     # source side; "dispatch" / "device" / "readback" / "write" on the
-    # output plane — see blit/outplane.py).
+    # output plane — see blit/outplane.py; "wait.*" where a pump thread
+    # blocked).  Every stage is also a span of the process tracer.
     timeline: Timeline = field(default_factory=Timeline)
-    # When set, a JAX profiler trace (TensorBoard/Perfetto readable) wraps
-    # every streaming run — SURVEY.md §5 "traces around ingest + kernels".
+    # When set, a device-only JAX profiler trace wraps every streaming run
+    # and the run's spans land beside it as blit-spans.json
+    # (observability.profile_trace).
     trace_logdir: Optional[str] = None
     # Asynchronous output plane (ISSUE 4): device outputs are read back on
     # a dedicated thread (device→host overlaps the next chunk's compute)
@@ -881,7 +905,7 @@ class RawReducer:
         rot = BufferRotation(
             nbufs,
             lambda r: self._producer(raw, skip_frames, bufs, r),
-            name="blit-ingest",
+            name="blit-ingest", timeline=self.timeline,
         )
         with self.timeline.stage("stream"):
             try:

@@ -250,7 +250,7 @@ class DedopplerReducer:
         rot = BufferRotation(
             nbufs,
             lambda r: self._producer(raw, skip_windows, nchans, bufs, r),
-            name="blit-search-feed",
+            name="blit-search-feed", timeline=self.timeline,
         )
         try:
             for idx, widx in rot.slots():

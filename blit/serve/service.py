@@ -602,9 +602,13 @@ class ProductService:
                     ctx=None) -> Tuple[Dict, np.ndarray]:
         tr = observability.tracer()
         try:
+            # The stage is the request's span (Timeline.stage records
+            # one and yields it): no second span of the same name.
             with tr.activate(ctx), \
-                    tr.span("serve.stream", out=request.out), \
-                    self.timeline.stage("serve.stream", byte_free=True):
+                    self.timeline.stage("serve.stream",
+                                        byte_free=True) as sp:
+                if sp is not None:
+                    sp.attrs = dict(sp.attrs, out=request.out)
                 from blit.stream import (
                     FileTailSource,
                     ReplaySource,
@@ -641,9 +645,13 @@ class ProductService:
         reduction's spans parent onto the request."""
         tr = observability.tracer()
         try:
+            # The stage is the request's span (Timeline.stage records
+            # one and yields it): no second span of the same name.
             with tr.activate(ctx), \
-                    tr.span("serve.reduce", fp=fp[:16]) as sp, \
-                    self.timeline.stage("serve.reduce", byte_free=True):
+                    self.timeline.stage("serve.reduce",
+                                        byte_free=True) as sp:
+                if sp is not None:
+                    sp.attrs = dict(sp.attrs, fp=fp[:16])
                 # Construct INSIDE the span/stage: reducer construction
                 # (tuning-profile lookup, and at hi-res nfft the PFB
                 # coefficient build) is request work — it must show in

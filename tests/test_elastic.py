@@ -131,6 +131,11 @@ def grow_until_incoming(efleet, tmp_path, joiner, want=1, cap=24):
         fps.append(fp_of(r))
         incoming = efleet.door.ring.incoming_keys(joiner, fps)
         if want <= len(incoming) < len(fps):
+            # The rig's door sees leases only on explicit ticks, and the
+            # first reductions (a compile, a loaded machine) can outlast
+            # the lease TTL: tick once, so the standby's freshness is
+            # judged on its heartbeat and not on how long this loop took.
+            efleet.door.observe()
             return reqs, fps, incoming
     raise AssertionError("keyspace never gave the joiner a share")
 

@@ -1,0 +1,425 @@
+"""Every pump stage and every pump wait is a span (ISSUE 24): the stage
+table's seconds, laid on the epoch clock by the process tracer, with the
+thread that spent them and the pass's trace id.
+
+No pytest-timeout here, so every test that waits on a thread runs under
+:func:`within` — its own deadline, a failure instead of a hung suite."""
+
+import collections
+import json
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from blit import observability  # noqa: E402
+from blit.observability import Timeline, Tracer  # noqa: E402
+from blit.outplane import AsyncSink, OutputRotation  # noqa: E402
+from blit.pipeline import BufferRotation, RawReducer  # noqa: E402
+from blit.testing import synth_raw  # noqa: E402
+
+WAITS = ("wait.chunk", "wait.ingest_slot", "wait.out_slot", "wait.out_drain",
+         "wait.sink", "wait.sink_flush", "wait.slab")
+NFFT, NINT = 64, 2
+
+
+def within(seconds, fn):
+    """Run ``fn`` on a thread of its own; fail if it outlives ``seconds``."""
+    box = {}
+
+    def run():
+        try:
+            box["value"] = fn()
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            box["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"still running after {seconds} s"
+    if "error" in box:
+        raise box["error"]
+    return box.get("value")
+
+
+def toy_raw(tmp_path, nblocks=8):
+    raw = str(tmp_path / "toy.0000.raw")
+    synth_raw(raw, nblocks=nblocks, obsnchan=4, ntime_per_block=4096)
+    return raw
+
+
+def toy_pass(tmp_path, **kw):
+    """One ``reduce_to_file`` pass -> (stage table, its spans)."""
+    observability.tracer().reset()
+    red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=32, **kw)
+    within(120, lambda: red.reduce_to_file(toy_raw(tmp_path),
+                                           str(tmp_path / "toy.fil")))
+    return red.timeline.report(), observability.tracer().span_dicts()
+
+
+def stage_spans(spans):
+    return [s for s in spans if (s.get("attrs") or {}).get("stage") == 1]
+
+
+class SlowWriter:
+    """The slab-writer contract, ``delay`` seconds per append."""
+
+    path = "/fake/slow.fil"
+
+    def __init__(self, delay):
+        self.delay, self.nsamps = delay, 0
+
+    def append(self, slab):
+        time.sleep(self.delay)
+        self.nsamps += slab.shape[0]
+
+    def close(self):
+        pass
+
+    def abort(self):
+        pass
+
+
+class TestToyPass:
+    def test_one_trace_three_threads_every_stage_parented(self, tmp_path):
+        _, spans = toy_pass(tmp_path)
+        st = stage_spans(spans)
+        assert len({s["trace"] for s in spans}) == 1
+        assert len({s["tid"] for s in st}) >= 3
+        ids = {s["span"] for s in spans}
+        assert all(s["parent"] in ids for s in st)
+        assert all(isinstance(s["attrs"]["bytes"], int) for s in st)
+
+    def test_span_durations_sum_to_the_stage_table(self, tmp_path):
+        table, spans = toy_pass(tmp_path)
+        total = collections.Counter()
+        for s in stage_spans(spans):
+            total[s["name"]] += s["duration_s"]
+        rows = {k: v for k, v in table.items()
+                if isinstance(v, dict) and v.get("seconds", 0) > 0}
+        # `stream` wraps the pump and is topped up by hand with the
+        # readback and write tails (pipeline.py), so its one span is
+        # shorter than its row; every other row is its spans.
+        rows.pop("stream")
+        assert rows
+        for name, row in rows.items():
+            assert total[name] == pytest.approx(row["seconds"], rel=0.01,
+                                                abs=2e-5), name
+
+    def test_every_wait_row_is_in_the_table(self, tmp_path):
+        table, _ = toy_pass(tmp_path)
+        for name in WAITS:
+            assert table[name]["byte_free"] is True
+            assert table[name]["bytes"] == 0
+
+    def test_spans_off_same_table_no_span(self, tmp_path, monkeypatch):
+        on, _ = toy_pass(tmp_path)
+        monkeypatch.setattr(observability, "_TRACER", Tracer(enabled=False))
+        off, spans = toy_pass(tmp_path)
+        assert spans == []
+        assert sorted(off) == sorted(on)
+        for name, row in on.items():
+            if name in ("gauges", "hists"):
+                continue
+            assert off[name]["bytes"] == row["bytes"], name
+            if not name.startswith("wait."):  # how often a wait blocks varies
+                assert off[name]["calls"] == row["calls"], name
+
+    def test_blit_reduce_prints_its_stage_table(self, tmp_path, capsys):
+        from blit.__main__ import main
+
+        raw = toy_raw(tmp_path)
+        rc = within(120, lambda: main(
+            ["reduce", raw, "-o", str(tmp_path / "cli.fil"),
+             "--nfft", str(NFFT), "--nint", str(NINT)]))
+        assert rc == 0
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        for name in WAITS + ("ingest", "dispatch", "device", "write"):
+            assert "seconds" in line["stages"][name], name
+
+
+class TestBackPressure:
+    def test_slow_writer_shows_as_wait_sink(self):
+        tl = Timeline()
+        sink = AsyncSink(SlowWriter(0.05), depth=1, timeline=tl)
+
+        def run():
+            for _ in range(4):
+                sink.append(np.zeros((1, 1, 4), np.float32))
+            sink.close()
+
+        try:
+            within(30, run)
+        finally:
+            sink.abort()
+        row = tl.report()["wait.sink"]
+        assert row["calls"] >= 1 and row["seconds"] >= 0.05
+        assert tl.stages["wait.sink_flush"].seconds > 0
+
+    def test_slow_writer_backs_the_pump_up(self, tmp_path):
+        """Through the whole pump the slow write reaches the dispatcher
+        either as a full sink queue or (CPU backends, where the readback
+        ring recycles slabs the writer still holds) as ``wait.slab``
+        behind ``wait.out_slot``."""
+        from blit.io.guppi import open_raw
+
+        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=32)
+        raw = open_raw(toy_raw(tmp_path))
+        within(120, lambda: red._pump(raw, SlowWriter(0.05)))
+        table = red.timeline.report()
+        assert table["wait.sink"]["seconds"] \
+            + table["wait.slab"]["seconds"] >= 0.05
+
+    def test_slow_reader_shows_as_wait_chunk(self, tmp_path):
+        from blit.io.guppi import open_raw
+
+        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=32)
+        raw = open_raw(toy_raw(tmp_path))
+        fast = raw.read_block_into
+
+        def slow(*a, **kw):
+            time.sleep(0.05)
+            return fast(*a, **kw)
+
+        raw.read_block_into = slow
+        within(120, lambda: red._pump(raw, SlowWriter(0.0)))
+        table = red.timeline.report()
+        assert table["wait.chunk"]["seconds"] >= 0.05 * 8 * 0.5
+        assert table["wait.sink"]["calls"] == 0
+
+    def test_sink_with_room_never_blocks(self):
+        tl = Timeline()
+        sink = AsyncSink(SlowWriter(0.0), depth=8, timeline=tl)
+        try:
+            for _ in range(4):
+                sink.append(np.zeros((1, 1, 4), np.float32))
+            time.sleep(0.1)  # the writer works the queue off
+            within(30, sink.flush)
+        finally:
+            sink.abort()
+        assert tl.stages["wait.sink"].calls == 0
+        assert tl.stages["wait.sink"].seconds == 0.0
+
+    def test_consumer_behind_the_producer_never_blocks(self):
+        tl = Timeline()
+
+        def fill(rot):
+            for i in range(3):
+                rot.emit(rot.acquire(), i)
+
+        rot = BufferRotation(4, fill, timeline=tl, name="blit-feed-test")
+
+        def consume():
+            got = []
+            it = rot.slots()
+            slot, payload = next(it)   # may block: the producer just started
+            got.append(payload)
+            first = tl.stages["wait.chunk"].calls
+            time.sleep(0.2)            # the producer runs ahead
+            for slot, payload in it:
+                got.append(payload)
+            return got, first
+
+        got, first = within(30, consume)
+        assert got == [0, 1, 2]
+        assert tl.stages["wait.chunk"].calls == first
+        # The producer never met a full rotation either.
+        assert tl.stages["wait.ingest_slot"].calls == 0
+
+    def test_full_rotation_shows_as_wait_ingest_slot(self):
+        tl = Timeline()
+
+        def fill(rot):
+            for i in range(4):
+                slot = rot.acquire()
+                if slot is None:
+                    return
+                rot.emit(slot, i)
+
+        rot = BufferRotation(2, fill, timeline=tl, name="blit-feed-test")
+
+        def consume():
+            for slot, _ in rot.slots():
+                time.sleep(0.05)
+                rot.release(slot)
+
+        within(30, consume)
+        assert tl.stages["wait.ingest_slot"].seconds >= 0.04
+
+    def test_out_slot_and_drain_wait_on_the_readback(self):
+        import jax.numpy as jnp
+
+        tl = Timeline()
+        rot = OutputRotation(depth=1, timeline=tl, name="blit-readback-test")
+
+        def run():
+            slabs = []
+            for i in range(3):
+                slabs += rot.put(jnp.full((4,), float(i)))
+            slabs += list(rot.drain())
+            return slabs
+
+        try:
+            slabs = within(60, run)
+        finally:
+            rot.close()
+        assert len(slabs) == 3
+        # depth 1: every put waits for its own fetch.
+        assert tl.stages["wait.out_slot"].calls == 3
+        assert tl.stages["wait.out_slot"].seconds > 0
+
+
+class TestSpansAcrossAYield:
+    def test_a_generator_span_pops_its_own_entry(self):
+        tr = Tracer(enabled=True)
+
+        def gen():
+            with tr.span("held"):
+                yield 1
+                yield 2
+
+        with tr.span("root") as root:
+            g = gen()
+            next(g)
+            with tr.span("consumer") as consumer:
+                # `held` is still on this thread's stack, under `consumer`.
+                g.close()
+                assert tr.context()["span"] == consumer.span_id
+                with tr.span("child") as child:
+                    pass
+            assert tr.context()["span"] == root.span_id
+        assert tr.context() is None
+        by = {s.name: s for s in tr.spans()}
+        assert by["child"].parent_id == by["consumer"].span_id
+        assert by["held"].parent_id == by["root"].span_id
+
+    def test_stream_consumer_spans_keep_their_parents(self, tmp_path):
+        """`reduce.stream` and the `stream` stage live inside generators
+        on the consumer's thread; what the consumer opens between slabs
+        must close onto its own parent."""
+        observability.tracer().reset()
+        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=32)
+        from blit.io.guppi import open_raw
+
+        raw = open_raw(toy_raw(tmp_path))
+
+        def run():
+            tr = observability.tracer()
+            with tr.span("caller") as caller:
+                it = red.stream(raw)
+                with tr.span("first"):
+                    next(it)  # pushes reduce.stream and `stream` above it
+                # `first` took its own entry off, not the generator's.
+                held = tr.context()["span"]
+                it.close()
+                after = tr.context()["span"]
+            return caller.span_id, held, after, tr.context()
+
+        caller, held, after, outside = within(120, run)
+        by = {s["name"]: s for s in observability.tracer().span_dicts()}
+        assert held == by["stream"]["span"]
+        assert after == caller
+        assert outside is None
+        assert by["first"]["parent"] == caller
+        assert by["reduce.stream"]["parent"] == by["first"]["span"]
+
+    def test_a_stage_yields_its_span(self):
+        """The serving door adds its attrs (``fp``, ``tuned``, ``out``) to
+        the stage's own span instead of wrapping it in a second one; a
+        stage nested in one of its own name still gets its interval."""
+        observability.tracer().reset()
+        tl = Timeline()
+        with tl.stage("serve.reduce", byte_free=True) as sp:
+            sp.attrs = dict(sp.attrs, fp="abc")
+            with tl.stage("serve.reduce", nbytes=8) as inner:
+                assert inner.parent_id == sp.span_id
+        got = [s for s in observability.tracer().span_dicts()
+               if s["name"] == "serve.reduce"]
+        assert len(got) == tl.stages["serve.reduce"].calls == 2
+        assert got[1]["attrs"] == {"bytes": 0, "stage": 1, "fp": "abc"}
+        assert sum(s["duration_s"] for s in got) == pytest.approx(
+            tl.stages["serve.reduce"].seconds)
+
+    @pytest.mark.parametrize("spans_on", [True, False])
+    def test_a_stage_is_one_entry_in_the_flight_ring(self, spans_on,
+                                                     monkeypatch):
+        """With its span recorded a stage is that span's event (carrying
+        ``bytes``), not a second ``kind: stage`` entry: an incident dump
+        holds as many stages as before.  ``BLIT_SPANS=0`` keeps the old
+        end-stamped entry."""
+        tr, rec = observability.tracer(), observability.flight_recorder()
+        monkeypatch.setattr(tr, "enabled", spans_on)
+        rec.clear()
+        with Timeline().stage("ingest", nbytes=64):
+            pass
+        got = [e for e in rec.events() if e["name"] == "ingest"]
+        assert [e["kind"] for e in got] == ["span" if spans_on else "stage"]
+        assert got[0]["bytes"] == 64
+
+
+class TestScanWindows:
+    def test_each_window_has_a_read_and_a_put_per_bank(self, tmp_path):
+        from blit.parallel.scan import reduce_scan_mesh_to_files
+
+        # One band of four contiguous banks: four virtual devices.
+        bank_bw = -187.5 / 4
+        paths = [[]]
+        for k in range(4):
+            paths[0].append(str(tmp_path / f"blc0{k}.raw"))
+            synth_raw(paths[0][k], nblocks=4, obsnchan=2,
+                      ntime_per_block=1024, seed=k, obsbw=bank_bw,
+                      obsfreq=8000.0 + (k + 0.5) * bank_bw)
+        os.makedirs(tmp_path / "out")
+        observability.tracer().reset()
+        tl = Timeline()
+        within(300, lambda: reduce_scan_mesh_to_files(
+            paths, out_dir=str(tmp_path / "out"), nfft=NFFT, nint=NINT,
+            window_frames=16, timeline=tl))
+        spans = observability.tracer().span_dicts()
+        by_id = {s["span"]: s for s in spans}
+        roots = [s for s in spans if s["name"] == "scan.reduce"]
+        windows = [s for s in spans if s["name"] == "scan.window"]
+        assert len(roots) == 1 and len(windows) >= 2
+        assert len({s["trace"] for s in spans}) == 1
+        assert all(w["parent"] == roots[0]["span"] for w in windows)
+        assert len({w["attrs"]["f0"] for w in windows}) == len(windows)
+        for w in windows:
+            reads = [s for s in spans if s["name"] == "read"
+                     and s["parent"] == w["span"]]
+            assert len(reads) == 1
+            kids = [s["name"] for s in spans
+                    if s["parent"] == reads[0]["span"]]
+            assert kids == ["feed.read"] * 4 + ["feed.put"] * 4
+        # The per-bank stages carry the bank's bytes; `read` what it read.
+        table = tl.report()
+        assert table["feed.read"]["bytes"] == table["read"]["bytes"]
+        assert table["feed.put"]["bytes"] == table["read"]["bytes"]
+        assert by_id[windows[0]["parent"]]["name"] == "scan.reduce"
+
+
+class TestProfileTrace:
+    def test_writes_the_spans_beside_the_trace(self, tmp_path):
+        from blit.observability import profile_trace
+
+        logdir = str(tmp_path / "trace")
+        tl = Timeline()
+        with observability.span("before"):
+            pass
+        with profile_trace(logdir):
+            with tl.stage("ingest", nbytes=16):
+                time.sleep(0.01)
+        doc = json.load(open(os.path.join(logdir, "blit-spans.json")))
+        events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        assert [e["name"] for e in events] == ["ingest"]
+        assert events[0]["args"]["stage"] == 1
+        # Epoch microseconds: the clock the .xplane.pb's Task Environment
+        # stamps its start on.
+        assert abs(events[0]["ts"] / 1e6 - time.time()) < 60
+        found = []
+        for root, _, files in os.walk(logdir):
+            found += [f for f in files if f.endswith(".xplane.pb")]
+        assert found
