@@ -156,8 +156,9 @@ class SiteConfig:
     # Ingest performance plane (blit/tune.py + blit/hostmem.py; ISSUE 8).
     # tune_dir overrides where per-rig tuning profiles live (None = the
     # BLIT_TUNE_DIR env, else ~/.cache/blit/tune); staging_pool_bytes is
-    # the process-wide staging-slab pool budget (env BLIT_STAGING_BYTES
-    # wins; 0 disables pooling).
+    # a fixed byte cap on the process-wide staging-slab pool (env
+    # BLIT_STAGING_BYTES wins; 0 disables pooling; None = the pool keeps
+    # what one stretch of work held at its peak, blit/hostmem.py).
     tune_dir: Optional[str] = None
     staging_pool_bytes: Optional[int] = None
     # Sharded reduction plane (blit/parallel/sharded.py; ISSUE 9).

@@ -112,3 +112,29 @@ def hbm_bytes_limit() -> Optional[int]:
     stats = jax.devices()[0].memory_stats() or {}
     limit = stats.get("bytes_limit")
     return None if limit is None else int(limit)
+
+
+# libtpu premaps (pins) this much host memory for transfers when
+# TPU_PREMAPPED_BUFFER_SIZE does not say otherwise.
+_TPU_PREMAPPED_DEFAULT = 4 << 30
+
+
+def host_link_bytes() -> Optional[int]:
+    """Bytes the runtime's host<->device transfer path takes at speed at
+    one time, or ``None`` where the backend stages nothing (the CPU).
+
+    The TPU runtime stages every transfer through a premapped host region
+    (``TPU_PREMAPPED_BUFFER_SIZE``, libtpu's own variable; 4 GiB unless
+    set).  A transfer ENQUEUED while the region is taken goes another way
+    and crawls: on a v5e a 2.95 GB chunk lands in 0.4 s alone and in 7 s
+    when it is dispatched behind another (PERF.md section 6, PR 25: at
+    12 GiB both land in 0.5 s).  Callers keep what they have in flight
+    under this."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    try:
+        return int(os.environ["TPU_PREMAPPED_BUFFER_SIZE"])
+    except (KeyError, ValueError):
+        return _TPU_PREMAPPED_DEFAULT

@@ -300,8 +300,8 @@ class OutputRotation:
         if evicted is not None:
             # The replaced steady-state slab retires to the staging pool
             # (the close() rule) — not to the GC.
-            pool.give(evicted)
-        return pool.take(alloc_shape, dtype)
+            pool.give(evicted, self._tl)
+        return pool.take(alloc_shape, dtype, self._tl)
 
     def _release_slab(self, slab: np.ndarray) -> None:
         with self._cv:
@@ -316,7 +316,7 @@ class OutputRotation:
         # faults for its tail slabs.
         from blit import hostmem
 
-        hostmem.slab_pool().give(slab)
+        hostmem.slab_pool().give(slab, self._tl)
 
     # -- consumer side -----------------------------------------------------
     def _poll(self) -> float:
@@ -408,7 +408,7 @@ class OutputRotation:
         with self._cv:
             free, self._free = self._free, []
         for s in free:
-            pool.give(s)
+            pool.give(s, self._tl)
 
 
 class _FlushBarrier:

@@ -18,7 +18,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from blit import observability
+from blit import hostmem, observability
 from blit.io.guppi import GuppiRaw, open_raw, require_native_reader
 from blit.monitor import published
 from blit.ops.channelize import (
@@ -237,7 +237,8 @@ def _open_players(raw_paths, mesh):
     return mesh, local, raws, int(geo[0][0]), int(geo[0][1]), int(samps.min())
 
 
-def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None):
+def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
+                 staged=None):
     """Assemble the global sharded voltage array for gap-free samples
     ``[start, start + ntime)`` of every player.  Every LOCAL player's
     window is read into host memory first, one after the other
@@ -246,14 +247,27 @@ def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None):
     from the single-device shards (no whole-scan host buffer, no
     device_put to any non-addressable device) — the assembly itself is
     :func:`blit.parallel.mesh.put_local_shards`, the ONE
-    partition-rule-driven implementation the sharded plane shares."""
+    partition-rule-driven implementation the sharded plane shares.
+
+    ``staged`` (a list) makes each bank's window buffer a slab of the
+    process staging pool (blit/hostmem.py), of this window's exact
+    shape, and appends it: the caller gives the slabs back once THIS
+    window's dispatch has synchronized — never sooner, ``device_put``
+    returns before the bytes have landed (and on the CPU backend may
+    alias the slab outright).  Without it each window reads into fresh
+    memory that the returned array keeps alive."""
     nband, nbank = mesh.devices.shape
     tl = tl if tl is not None else observability.Timeline()
     blocks = {}
     for b, k in local:
         r = raws[(b, k)]
+        buf = None
+        if staged is not None:
+            buf = hostmem.slab_pool().take((nchan, ntime, npol, 2), np.int8,
+                                           tl)
+            staged.append(buf)
         with tl.stage("feed.read", nchan * ntime * npol * 2):
-            v = _gapless(r, ntime, skip=start)
+            v = _gapless(r, ntime, skip=start, out=buf)
         if v.shape[0] != nchan or v.shape[1] < ntime or v.shape[2:] != (npol, 2):
             raise ValueError(
                 f"{r.path}: shape {v.shape} incompatible with "
@@ -800,7 +814,7 @@ def reduce_scan_mesh_to_files(
 
         tl = timeline if timeline is not None else Timeline()
 
-        def flush(out):
+        def flush(out, staged):
             # Blocking readback of one window's stitched bands -> disk.
             # The compute wait is charged to "device" here (not at the
             # async dispatch): this is where the host actually blocks on
@@ -808,6 +822,12 @@ def reduce_scan_mesh_to_files(
             # semantics.
             with tl.stage("device", byte_free=True):
                 out.block_until_ready()
+            # The window has consumed its input: only now may its
+            # staging slabs serve another window (the one after next
+            # takes them, already faulted).
+            pool = hostmem.slab_pool()
+            for buf in staged:
+                pool.give(buf, tl)
             by_dev = {s.device: s for s in out.addressable_shards}
             for b in mine:
                 with tl.stage("readback"):
@@ -833,10 +853,11 @@ def reduce_scan_mesh_to_files(
                 # Locally fed voltage bytes: complex int8 = 2 B/sample.
                 fed = len(raws) * nchan * ntime * npol * 2
                 with observability.span("scan.window", f0=f0):
+                    staged = []
                     with tl.stage("read", fed):
                         volt = _feed_window(
                             raws, local, mesh, nchan, npol, f0 * nfft,
-                            ntime, tl,
+                            ntime, tl, staged,
                         )
                     with tl.stage("dispatch", byte_free=True):
                         out = M.band_reduce(
@@ -854,11 +875,11 @@ def reduce_scan_mesh_to_files(
                             dtype=dtype,
                         )
                     if pending is not None:
-                        flush(pending)
-                pending = out
+                        flush(*pending)
+                pending = (out, staged)
                 f0 += n
             if pending is not None:
-                flush(pending)
+                flush(*pending)
         done = {}
         for b in list(writers):
             writers[b].close()  # on failure the finally aborts the rest

@@ -128,7 +128,7 @@ class _ShardFeed:
             shape = (self.nchan, self.max_ntime, self.npol, 2)
             pool = hostmem.slab_pool()
             self._store[slot] = {
-                bk: pool.take(shape, np.int8) for bk in self.local
+                bk: pool.take(shape, np.int8, self.tl) for bk in self.local
             }
         return self._store[slot]
 
@@ -199,7 +199,7 @@ class _ShardFeed:
         for store in self._store:
             if store:
                 for slab in store.values():
-                    pool.give(slab)
+                    pool.give(slab, self.tl)
         self._store = [None] * self.nslots
 
 

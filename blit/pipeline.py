@@ -44,7 +44,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from blit import observability
-from blit.device import hbm_bytes_limit
+from blit.device import hbm_bytes_limit, host_link_bytes
 from blit.io.guppi import GuppiRaw, RawSource, open_raw, require_native_reader
 from blit.observability import Timeline, profile_trace
 from blit.ops.channelize import (
@@ -508,7 +508,7 @@ class RawReducer:
 
         pool = hostmem.slab_pool()
         for b in self._buf_cache:
-            pool.give(b)
+            pool.give(b, self.timeline)
         self._buf_cache = []
 
     @property
@@ -649,6 +649,13 @@ class RawReducer:
         fetch), so the chunk rotation runs one slot wider
         (``extra_slots=1``) to keep a slot free for the producer's
         read-ahead.
+
+        That overlap holds only while both chunks' transfers fit what
+        the runtime's transfer path stages at speed
+        (:func:`blit.device.host_link_bytes`): a recorder-width hi-res
+        chunk (2.95 GB in, 2.1 GB out) does not, its successor's input
+        would crawl behind it, and the depth drops to one chunk on the
+        link at a time (ISSUE 25).
         """
         from blit.outplane import OutputRotation, readback_extra_slots
 
@@ -661,6 +668,7 @@ class RawReducer:
         do_narrow = narrow and self.nbits < 32
         if do_narrow:
             from blit.ops.narrow import narrow_device
+        link = host_link_bytes()
         try:
             extra = readback_extra_slots(depth, self.prefetch_depth)
             for chunk in self._chunks(raw, skip_frames, extra_slots=extra):
@@ -675,6 +683,14 @@ class RawReducer:
                         out = narrow_device(out, self.nbits,
                                             self.quant_scale,
                                             self.quant_offset)
+                nin = chunk.view.nbytes
+                if link is not None and nin + max(nin, out.nbytes) >= link:
+                    # The next chunk's input would be enqueued behind
+                    # this one's input or product fetch, and the two do
+                    # not fit what the runtime stages at speed
+                    # (blit.device.host_link_bytes): one at a time, then.
+                    # put() below returns once THIS chunk is fetched.
+                    rot.depth = 1
                 self._output_frames += chunk.frames
                 if tuner is not None:
                     tuner.observe_chunk()
@@ -854,7 +870,7 @@ class RawReducer:
                             from blit import hostmem
 
                             bufs[cur] = hostmem.slab_pool().take(
-                                shape, np.int8
+                                shape, np.int8, self.timeline
                             )
                     if prev is not None:
                         # Separate stage: filter-state memcpy between
