@@ -2295,7 +2295,7 @@ def _cmd_tune(args: argparse.Namespace) -> int:
     import time as _time
 
     from blit import tune as T
-    from blit.pipeline import RawReducer
+    from blit.pipeline import RawReducer, dispatch_frames, fold_frames
     from blit.testing import synth_raw
 
     def build(knobs: dict, **kw) -> "RawReducer":
@@ -2330,13 +2330,19 @@ def _cmd_tune(args: argparse.Namespace) -> int:
         # a chunk spanning most of the file measures a degenerate
         # near-zero-overhead run that always wins and then missizes
         # every real reduction on the rig.
-        max_cf = max(args.nint, total_samps // args.nfft // 2)
+        # chunk_frames moves in whole integrations where a dispatch
+        # holds one; one it cannot hold (-f 1048576 -t 51) is carried,
+        # binds nothing, and the ladder stays inside the dispatch budget.
+        fold = fold_frames(args.nfft, args.nint)
+        max_cf = max(fold, total_samps // args.nfft // 2)
+        if fold != args.nint:
+            max_cf = min(max_cf, dispatch_frames(args.nfft))
         # Normalize FIRST so the untimed warmup (jit compile + page
         # faults) runs at the exact knob set tune() measures first — a
         # recording-clamped base must not pay its compile inside the
         # first timed trial (that would understate baseline_gbps).
         base = T.normalize_base({"chunk_frames": args.chunk_frames},
-                                nint=args.nint, max_chunk_frames=max_cf)
+                                nint=fold, max_chunk_frames=max_cf)
         build(base).reduce_to_file(raw_path, os.path.join(td, "warm.fil"))
         seq = [0]
 
@@ -2353,7 +2359,7 @@ def _cmd_tune(args: argparse.Namespace) -> int:
                 os.unlink(out)
             return best
 
-        best, trials = T.tune(measure, base=base, nint=args.nint,
+        best, trials = T.tune(measure, base=base, nint=fold,
                               max_trials=args.trials,
                               max_chunk_frames=max_cf)
         # One confirmation pass at the winner captures the stage tails

@@ -370,14 +370,16 @@ class Timeline:
 
     @contextlib.contextmanager
     def stage(
-        self, name: str, nbytes: int = 0, byte_free: bool = False
+        self, name: str, nbytes: int = 0, byte_free: bool = False,
+        calls: int = 1,
     ) -> Iterator[Optional["Span"]]:
         """Time one stage: the sums below, and — so the seconds can be
         laid against another clock — the interval itself, as a span of
         the process tracer under the ambient span (attrs ``bytes`` and
         ``stage=1``).  Yields that live span, for a caller with attrs of
         its own to add; ``BLIT_SPANS=0`` keeps the sums, drops the span
-        and yields ``None``.  Span and row carry the same duration."""
+        and yields ``None``.  Span and row carry the same duration;
+        ``calls`` is what the row counts it as (:meth:`mark`)."""
         sp = _TRACER.open_span(name, {"bytes": nbytes, "stage": 1})
         t0 = time.perf_counter()
         try:
@@ -385,7 +387,7 @@ class Timeline:
         finally:
             dt = time.perf_counter() - t0
             s = self.stages[name]
-            s.calls += 1
+            s.calls += calls
             s.seconds += dt
             s.bytes += nbytes
             if byte_free:
@@ -394,6 +396,12 @@ class Timeline:
                 _FLIGHT.stage_event(name, dt, nbytes)
             else:  # the span is the stage's one entry in the flight ring
                 _TRACER.close_span(sp, dt)
+
+    def mark(self, name: str, nbytes: int = 0, calls: int = 1) -> None:
+        """A counted instant: a zero-length stage (row and span, on the
+        clock of every other stage) standing for ``calls`` events."""
+        with self.stage(name, nbytes=nbytes, calls=calls):
+            pass
 
     def wait(self, name: str) -> "StageWait":
         """A byte-free ``wait.<what>`` stage that starts only when the

@@ -50,13 +50,19 @@ _DIRECT_FFT_MAX = 8192
 
 
 
-def usable_frames(nsamps: int, nfft: int, ntap: int, nint: int) -> int:
-    """Whole PFB frames a gap-free span of ``nsamps`` samples yields, rounded
-    down to the integration length — THE frame-accounting invariant shared by
-    the streaming flush (blit/pipeline.py) and the mesh scan loader
-    (blit/parallel/scan.py)."""
+def usable_frames(nsamps: int, nfft: int, ntap: int, nint: int,
+                  open_frames: int = 0) -> int:
+    """Whole PFB frames a gap-free span of ``nsamps`` samples yields, cut
+    so that the stream ends on a whole integration — THE frame-accounting
+    invariant shared by the streaming flush (blit/pipeline.py) and the mesh
+    scan loader (blit/parallel/scan.py).  ``open_frames`` counts the frames
+    an integration carried into the span already holds
+    (:func:`integrate_carry`): they close with the span's first
+    ``nint - open_frames``."""
     frames = nsamps // nfft - ntap + 1
-    return (frames // nint) * nint if frames > 0 else 0
+    if frames <= 0:
+        return 0
+    return max(0, (open_frames + frames) // nint * nint - open_frames)
 
 
 def pfb_coeffs(ntap: int, nfft: int, window: str = "hamming") -> np.ndarray:
@@ -728,6 +734,95 @@ def channelize_blocked(
         for c in range(0, nchan, channel_block)
     ]
     return jnp.concatenate(outs, axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames=("nint",))
+def integrate_carry(
+    power: jax.Array, acc: jax.Array, filled: jax.Array, *, nint: int
+) -> Tuple[jax.Array, jax.Array]:
+    """Integrate one dispatch's spectra into an integration that is longer
+    than a dispatch, or straddles its boundary.
+
+    ``power`` is :func:`channelize`'s product at ``nint=1``, frame-major
+    ``(nframes, nif, nchan)``; ``acc`` ``(nif, nchan)`` float32 holds the
+    ``filled`` frames (``0 <= filled < nint``, a device scalar: the split
+    is data, so one program serves every place the boundary falls) the
+    open integration has so far.  Returns ``(rows, acc)``: ``rows``
+    ``((nint - 1 + nframes) // nint, nif, nchan)``, of which the first
+    ``(filled + nframes) // nint`` closed in this dispatch (the rest are
+    zeros), and the accumulator to hand to the next dispatch.
+
+    Frames are added one at a time in stream order and a fresh integration
+    starts from zero, so a row's bits depend on each frame's place in the
+    integration, not on where the dispatch grid fell: a reduction resumed
+    at another row gives the same bytes.
+    """
+    nframes = power.shape[0]
+    rows = jnp.zeros(((nint - 1 + nframes) // nint,) + acc.shape, acc.dtype)
+
+    def add_frame(j, carry):
+        acc, rows = carry
+        acc = acc + power[j]
+        n = filled + j + 1
+        closes = n % nint == 0
+        rows = jax.lax.cond(
+            closes,
+            lambda: jax.lax.dynamic_update_index_in_dim(
+                rows, acc, n // nint - 1, 0),
+            lambda: rows,
+        )
+        return jnp.where(closes, jnp.zeros_like(acc), acc), rows
+
+    acc, rows = jax.lax.fori_loop(0, nframes, add_frame, (acc, rows))
+    return rows, acc
+
+
+def channelize_carry(
+    voltages,
+    coeffs,
+    accs: Optional[list],
+    filled: int,
+    *,
+    channel_block: int,
+    nint: int,
+    **kw,
+) -> Tuple[Optional[jax.Array], list]:
+    """:func:`channelize_blocked` for an integration carried across
+    dispatches: each ``channel_block``-sized group of coarse channels is
+    channelized to frame-major power and folded into that group's
+    device-resident accumulator (:func:`integrate_carry`).
+
+    ``accs`` is the previous dispatch's second result (``None`` to start
+    a stream) and ``filled`` the frames the open integration holds.
+    Returns ``(rows, accs)``: the ``(filled + nframes) // nint`` rows that
+    closed, ``(k, nif, nchan*nfft)`` assembled on the device — ``None``
+    when none did, and then no group's partial sum is concatenated,
+    fetched or written.
+    """
+    nchan = voltages.shape[0]
+    if channel_block <= 0 or channel_block >= nchan:
+        channel_block = nchan
+    if nchan % channel_block:
+        raise ValueError(
+            f"channel_block={channel_block} does not divide nchan={nchan}"
+        )
+    at = np.int32(filled)  # data, not a static argument: one program
+    rows, new_accs = [], []
+    for g, c in enumerate(range(0, nchan, channel_block)):
+        power = channelize(voltages[c : c + channel_block], coeffs,
+                           nint=1, **kw)
+        nclosed = (filled + power.shape[0]) // nint
+        acc = (jnp.zeros(power.shape[1:], jnp.float32) if accs is None
+               else accs[g])
+        closed, acc = integrate_carry(power, acc, at, nint=nint)
+        new_accs.append(acc)
+        if nclosed:
+            rows.append(closed if nclosed == closed.shape[0]
+                        else closed[:nclosed])
+    if not nclosed:
+        return None, new_accs
+    return (rows[0] if len(rows) == 1
+            else jnp.concatenate(rows, axis=-1)), new_accs
 
 
 @functools.lru_cache(maxsize=None)

@@ -16,8 +16,14 @@ Design:
   yields ``chunk_frames`` PFB frames; consecutive chunks share a
   ``(ntap-1) * nfft``-sample filter-state overlap — frame continuity across
   chunks is exact (golden-tested against a whole-file reduction).
-- ``chunk_frames`` is a multiple of ``nint`` so integration never straddles a
-  chunk boundary.  Trailing samples that can't fill an integration are
+- Where an integration fits a dispatch, ``chunk_frames`` is a multiple of
+  ``nint`` and each chunk integrates inside its own program.  Where it does
+  not (``nint * nfft`` beyond the per-dispatch sample budget — rawspec's
+  ``-f 1048576 -t 51`` — or an explicit ``chunk_frames`` that ``nint`` does
+  not divide), the integration is CARRIED: a float32 accumulator per
+  channel group stays on the device from dispatch to dispatch
+  (:func:`blit.ops.channelize.integrate_carry`), and a row leaves the chip
+  only when it closes.  Trailing samples that can't fill an integration are
   dropped, as rawspec does.
 - Ingest is PIPELINED: a producer thread fills a rotation of
   ``prefetch_depth`` stable chunk buffers straight from the file (native
@@ -50,6 +56,7 @@ from blit.observability import Timeline, profile_trace
 from blit.ops.channelize import (
     STOKES_NIF,
     channelize_blocked,
+    channelize_carry,
     channels_per_dispatch,
     output_header,
     pfb_coeffs,
@@ -61,6 +68,24 @@ log = logging.getLogger("blit.pipeline")
 # Share of the device's memory limit a reduction plans against; the rest is
 # the runtime's own reserve and allocator fragmentation.
 _HBM_FRACTION = 0.9
+# Samples per coarse channel one device call is sized for.
+_DISPATCH_SAMPLES = 1 << 23
+
+
+def dispatch_frames(nfft: int) -> int:
+    """Frames of ``nfft`` samples one device call is sized for."""
+    return max(1, _DISPATCH_SAMPLES // nfft)
+
+
+def fold_frames(nfft: int, nint: int) -> int:
+    """The multiple ``chunk_frames`` moves in — THE rule for "does an
+    integration fit a dispatch", for the reducer's own sizing and for
+    everything that recommends a chunk size (``blit tune``,
+    :mod:`blit.tune`'s ``nint`` arguments): ``nint`` where
+    :func:`dispatch_frames` holds one, so it folds inside one program;
+    else 1 — the integration is carried across dispatches
+    (:func:`blit.ops.channelize.integrate_carry`) and binds no chunk."""
+    return nint if nint <= dispatch_frames(nfft) else 1
 
 
 @dataclass
@@ -95,6 +120,22 @@ class _Chunk:
         if self._free is not None:
             free, self._free = self._free, None
             free(self._idx)
+
+
+class _OpenIntegration:
+    """What a carried reduction holds between dispatches: the frames the
+    open integration has so far, each channel group's accumulator on the
+    device, and the group size they were laid out for — the stream's first
+    chunk's, kept for every later one (a smaller flush chunk would fit
+    more channels per dispatch, and find no accumulator of that shape).
+    The last two are ``None`` until the stream's first dispatch."""
+
+    __slots__ = ("filled", "accs", "channel_block")
+
+    def __init__(self) -> None:
+        self.filled = 0
+        self.accs: Optional[list] = None
+        self.channel_block: Optional[int] = None
 
 
 _ROT_ERR = object()  # producer-exception marker on the filled queue
@@ -315,7 +356,11 @@ class RawReducer:
     # bf16 halves the inter-stage HBM, fitting ~2x the frames per dispatch
     # at a measured accuracy cost (DESIGN.md §8).
     dtype: str = "float32"
-    # Output frames per device call; rounded up to a multiple of nint.
+    # Output frames per device call.  None = the tuning profile, else the
+    # per-dispatch sample budget's: a multiple of nint where an
+    # integration fits it, else the budget's frames with the integration
+    # carried across dispatches.  An explicit value is kept as given
+    # (nint need not divide it: the reduction then carries).
     chunk_frames: Optional[int] = None
     # Per-stage timing/byte registry ("ingest" / "state" / "stream" on the
     # source side; "dispatch" / "device" / "readback" / "write" on the
@@ -403,15 +448,30 @@ class RawReducer:
             self.out_depth = max(2, self.prefetch_depth)
         self.out_depth = max(2, self.out_depth)
 
+        # Budget-driven sizing: ~8M samples per coarse channel per device
+        # call.  Small-nfft products get many frames per call (amortizes
+        # dispatch); the 1M-point hi-res product gets few (the complex64
+        # FFT intermediates are what bound HBM, not dispatch overhead).
+        budget = dispatch_frames(self.nfft)
+        fold = fold_frames(self.nfft, self.nint)
+        fits = fold == self.nint
         if self.chunk_frames is None:
-            # Budget-driven default: ~8M samples per coarse channel per device
-            # call.  Small-nfft products get many frames per call (amortizes
-            # dispatch); the 1M-point hi-res product gets few (the complex64
-            # FFT intermediates are what bound HBM, not dispatch overhead).
-            budget = max(1, (1 << 23) // self.nfft)
-            self.chunk_frames = self.nint * max(1, min(64, budget) // self.nint)
-        if self.chunk_frames % self.nint:
-            self.chunk_frames += self.nint - self.chunk_frames % self.nint
+            # An integration the budget cannot hold (rawspec's -f 1048576
+            # -t 51: 51 frames against 8) does not size the dispatch: the
+            # chunk is the budget's and the integration is carried.
+            self.chunk_frames = (
+                self.nint * max(1, min(64, budget) // self.nint)
+                if fits else budget)
+        elif self._knob_sources["chunk_frames"] == "profile":
+            if fits:
+                self.chunk_frames = -(-self.chunk_frames // fold) * fold
+            else:
+                # A profile from before the carry rounded up to the
+                # integration (51 frames at 2^20: a 14.5 GB chunk); one
+                # the budget cannot hold is re-sized, never honoured.
+                self.chunk_frames = min(self.chunk_frames, budget)
+        if self.chunk_frames < 1:
+            raise ValueError(f"chunk_frames={self.chunk_frames} must be >= 1")
         if self.fqav_by > 1 and self.nfft % self.fqav_by:
             # Averaging groups must not straddle coarse-channel boundaries
             # (despike/nfpc consumers key on fine-per-coarse counts).
@@ -556,35 +616,81 @@ class RawReducer:
         if limit is None:
             return nchan
         frames = shape[1] // self.nfft - self.ntap + 1
-        product = (frames // self.nint * STOKES_NIF[self.stokes]
-                   * nchan * (self.nfft // self.fqav_by) * 4)
-        resident = (max(2, self.out_depth) + 1) * product
+        row = (STOKES_NIF[self.stokes] * nchan
+               * (self.nfft // self.fqav_by) * 4)
+        kw = self._channelize_kw
+        if self._carries:
+            # The accumulators (the old set lives until the new one is
+            # written) beside the rows a dispatch may close; the probe
+            # program is the frame-major one the carry reads.
+            product = (self.nint - 1 + frames) // self.nint * row
+            resident = 2 * row + (max(2, self.out_depth) + 1) * product
+            kw = dict(kw, nint=1)
+        else:
+            product = frames // self.nint * row
+            resident = (max(2, self.out_depth) + 1) * product
         cb = channels_per_dispatch(
-            tuple(shape), int(_HBM_FRACTION * limit) - resident,
-            **self._channelize_kw,
+            tuple(shape), int(_HBM_FRACTION * limit) - resident, **kw,
         )
         log.debug("chunk %s: %d of %d coarse channels per dispatch "
                   "(device limit %d B, %d B of products resident)",
                   shape, cb, nchan, limit, resident)
         return cb
 
-    def _dispatch(self, chunk: np.ndarray):
-        """One host chunk → its device product, dispatched async in as
+    @property
+    def _carries(self) -> bool:
+        """Does an integration straddle dispatches (module docstring)?"""
+        return self.chunk_frames % self.nint != 0
+
+    def _open_integration(self) -> Optional[_OpenIntegration]:
+        """A stream's carry state, ``None`` where each chunk integrates
+        inside its own program.  One per stream: a stream starts on a row
+        boundary (``skip_frames`` is whole rows)."""
+        return _OpenIntegration() if self._carries else None
+
+    def _dispatch(self, chunk: np.ndarray,
+                  carry: Optional[_OpenIntegration] = None):
+        """One host chunk → ``(product, token)``, dispatched async in as
         many channel groups as :meth:`_channel_block` says (each group's
         voltages go up on their own, so the whole chunk is never resident
-        as one input)."""
-        return channelize_blocked(
-            chunk, self._coeffs,
-            channel_block=self._channel_block(chunk.shape),
-            **self._channelize_kw,
-        )
+        as one input).  ``token`` is ready once the chunk's input has been
+        consumed.  With ``carry`` the product is the rows that closed in
+        this chunk — ``None`` when none did — and ``carry`` moves on."""
+        frames = chunk.shape[1] // self.nfft - self.ntap + 1
+        if carry is None:
+            out = channelize_blocked(
+                chunk, self._coeffs,
+                channel_block=self._channel_block(chunk.shape),
+                **self._channelize_kw)
+            self._output_frames += frames
+            return out, out
+        if carry.channel_block is None:
+            carry.channel_block = self._channel_block(chunk.shape)
+        kw = self._channelize_kw
+        nint = kw.pop("nint")
+        rows, carry.accs = channelize_carry(
+            chunk, self._coeffs, carry.accs, carry.filled,
+            channel_block=carry.channel_block, nint=nint, **kw)
+        carry.filled = (carry.filled + frames) % nint
+        if carry.filled:  # the dispatch left an integration open
+            self.timeline.mark("integrate.carry",
+                               sum(a.nbytes for a in carry.accs))
+        if rows is None:
+            return None, carry.accs
+        self.timeline.mark("integrate.emit", rows.nbytes,
+                           calls=rows.shape[0])
+        self._output_frames += rows.shape[0] * nint
+        return rows, rows
 
-    def _run_chunk(self, chunk: np.ndarray) -> np.ndarray:
+    def _run_chunk(self, chunk: np.ndarray,
+                   carry: Optional[_OpenIntegration] = None
+                   ) -> Optional[np.ndarray]:
         import jax
 
         with self.timeline.stage("device", nbytes=chunk.nbytes):
-            out = np.asarray(jax.block_until_ready(self._dispatch(chunk)))
-        return out
+            out, token = self._dispatch(chunk, carry)
+            jax.block_until_ready(token)
+            return None if out is None else np.asarray(out)
 
     def stream(self, raw: GuppiRaw, skip_frames: int = 0) -> Iterator[np.ndarray]:
         """Yield filterbank slabs ``(nspectra, nif, nchan*nfft)`` covering
@@ -610,13 +716,14 @@ class RawReducer:
             "reduce.stream", nfft=self.nfft, path=getattr(raw, "path", "")
         ):
             if not self.async_output:
+                carry = self._open_integration()
                 for chunk in self._chunks(raw, skip_frames):
                     try:
-                        out = self._run_chunk(chunk.view)
+                        out = self._run_chunk(chunk.view, carry)
                     finally:
                         chunk.release()
-                    self._output_frames += chunk.frames
-                    yield self._narrow_host(out)
+                    if out is not None:
+                        yield self._narrow_host(out)
                 self._retire_staging()
                 return
             for slab in self._stream_async(raw, skip_frames, reuse=False,
@@ -648,7 +755,9 @@ class RawReducer:
         ingest slots (released at ``block_until_ready``, before the
         fetch), so the chunk rotation runs one slot wider
         (``extra_slots=1``) to keep a slot free for the producer's
-        read-ahead.
+        read-ahead.  A chunk of a carried reduction that closed no row
+        is put sync-only (``fetch=False``): its slot is released the same
+        way, and nothing of it crosses to the host.
 
         That overlap holds only while both chunks' transfers fit what
         the runtime's transfer path stages at speed
@@ -669,33 +778,35 @@ class RawReducer:
         if do_narrow:
             from blit.ops.narrow import narrow_device
         link = host_link_bytes()
+        carry = self._open_integration()
         try:
             extra = readback_extra_slots(depth, self.prefetch_depth)
             for chunk in self._chunks(raw, skip_frames, extra_slots=extra):
                 with self.timeline.stage("dispatch", byte_free=True):
-                    out = self._dispatch(chunk.view)
-                    if do_narrow:
+                    out, token = self._dispatch(chunk.view, carry)
+                    if do_narrow and out is not None:
                         # Quantize to the product's on-disk integer form
                         # BEFORE D2H: 4x (nbits=8) / 2x (nbits=16) fewer
                         # bytes cross the slow link, bit-identical to the
                         # sync path's host-side narrowing
                         # (blit/ops/narrow.py).
-                        out = narrow_device(out, self.nbits,
-                                            self.quant_scale,
-                                            self.quant_offset)
+                        out = token = narrow_device(
+                            out, self.nbits, self.quant_scale,
+                            self.quant_offset)
                 nin = chunk.view.nbytes
-                if link is not None and nin + max(nin, out.nbytes) >= link:
+                nout = 0 if out is None else out.nbytes
+                if link is not None and nin + max(nin, nout) >= link:
                     # The next chunk's input would be enqueued behind
                     # this one's input or product fetch, and the two do
                     # not fit what the runtime stages at speed
                     # (blit.device.host_link_bytes): one at a time, then.
                     # put() below returns once THIS chunk is fetched.
                     rot.depth = 1
-                self._output_frames += chunk.frames
                 if tuner is not None:
                     tuner.observe_chunk()
-                for slab in rot.put(out, nbytes=chunk.view.nbytes,
-                                    on_consumed=chunk.release):
+                for slab in rot.put(token, nbytes=nin,
+                                    on_consumed=chunk.release,
+                                    fetch=out is not None):
                     yield slab
             # The chunker's "stream" stage closed when its generator
             # exhausted above; the readback tail it no longer covers is
@@ -755,7 +866,8 @@ class RawReducer:
                 {"chunk_frames": self.chunk_frames,
                  "prefetch_depth": self.prefetch_depth,
                  "out_depth": self.out_depth},
-                nint=self.nint,
+                # A carried integration does not bind the chunk size.
+                nint=1 if self._carries else self.nint,
             )
         sink = AsyncSink(
             writer, depth=max(2, self.out_depth),
@@ -838,6 +950,7 @@ class RawReducer:
         cur: Optional[int] = None
         prev: Optional[int] = None
         filled = 0
+        emitted = 0  # frames in the chunks emitted so far
         for hdr, nt, read_into in feed:
             if to_skip >= nt:
                 to_skip -= nt
@@ -893,10 +1006,14 @@ class RawReducer:
                 nt -= take
                 if filled == chunk_samps:
                     rot.emit(cur, (self.chunk_frames, chunk_samps))
+                    emitted += self.chunk_frames
                     prev, cur = cur, None
         if cur is not None and filled > (state if prev is not None else 0):
-            # Flush: whole frames remaining, rounded to the integration.
-            frames = usable_frames(filled, nfft, ntap, nint)
+            # Flush: the whole frames remaining, up to the last that
+            # closes an integration (one carried in from earlier chunks
+            # counts with the frames it already holds).
+            frames = usable_frames(filled, nfft, ntap, nint,
+                                   open_frames=emitted % nint)
             if frames > 0:
                 rot.emit(cur, (frames, (frames + ntap - 1) * nfft))
 
@@ -960,19 +1077,26 @@ class RawReducer:
         with profile_trace(self.trace_logdir):
             total = 0.0
             pending: deque = deque()
+            carry = self._open_integration()
+
+            def retire() -> float:
+                done, s, token = pending.popleft()
+                # sync: the device is done with the input
+                part = float(s) if s is not None else 0.0
+                jax.block_until_ready(token)
+                done.release()
+                return part
+
             for chunk in self._chunks(raw):
                 with self.timeline.stage("device", nbytes=chunk.view.nbytes):
-                    out = self._dispatch(chunk.view)
-                    pending.append((chunk, jnp.sum(out)))
-                self._output_frames += chunk.frames
+                    out, token = self._dispatch(chunk.view, carry)
+                    pending.append(
+                        (chunk, None if out is None else jnp.sum(out),
+                         token))
                 while len(pending) >= max(2, self.prefetch_depth):
-                    done, s = pending.popleft()
-                    total += float(s)  # sync: device is done with the input
-                    done.release()
+                    total += retire()
             while pending:
-                done, s = pending.popleft()
-                total += float(s)
-                done.release()
+                total += retire()
             self._retire_staging()
             return total
 
@@ -1353,6 +1477,13 @@ class ResumableFilWriter:
 
 
 # rawspec-equivalent product presets (SURVEY.md §0: products 0000/0001/0002).
+# BL's published reduction is ``rawspec -f 1048576,8,1024 -t 51,128,3072``
+# (Lebofsky+ 2019); "0000" and "0002" still say nint 1 and 2048 because the
+# benchmark's ``bank.hires`` cell runs ``--product 0000`` and its traffic
+# file states nint 1: a benchmark PR names ``--nfft 1048576 --nint 1`` there
+# first, then these become (1 << 20, 51) and (1 << 10, 3072).  Until then
+# the published products are ``--nfft 1048576 --nint 51`` (the integration
+# is carried across dispatches) and ``--nfft 1024 --nint 3072``.
 PRODUCT_PRESETS = {
     # name: (nfft, nint)
     "0000": (1 << 20, 1),  # hi-res: ~3 Hz channels

@@ -245,6 +245,13 @@ def lookup(config=None, **fingerprint_kw) -> Optional[TuningProfile]:
 
 # -- offline sweep --------------------------------------------------------
 
+# ``nint`` below is the multiple chunk_frames moves in.  Whether an
+# integration fits a dispatch is decided in ONE place,
+# ``blit.pipeline.fold_frames(nfft, nint)``, and callers pass its result:
+# the integration length where a dispatch holds one, 1 where the reducer
+# carries it across dispatches (rawspec's -f 1048576 -t 51) — so nothing
+# here rounds a recommendation up to an integration no dispatch can hold.
+
 def _cf_bound(nint: int, max_chunk_frames: Optional[int] = None) -> int:
     """The effective chunk_frames ceiling: the caller's recording bound
     capped by the global ladder limit, floored to an nint multiple

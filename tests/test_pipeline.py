@@ -61,8 +61,22 @@ class TestStreaming:
         np.testing.assert_allclose(got, want, rtol=1e-5)
 
     def test_chunk_frames_rounds_to_nint(self):
+        # The contract since ISSUE 26: an explicit chunk_frames is kept as
+        # given (nint need not divide it — the integration is then carried
+        # across dispatches); the DEFAULT still fits whole integrations
+        # wherever the per-dispatch sample budget (2^23) holds one ...
         red = RawReducer(nfft=64, nint=6, chunk_frames=8)
-        assert red.chunk_frames % 6 == 0
+        assert red.chunk_frames == 8 and red._carries
+        for nfft, nint in [(64, 6), (1024, 3072), (1 << 20, 1),
+                           (1 << 20, 8), (8, 128)]:
+            red = RawReducer(nfft=nfft, nint=nint)
+            assert red.chunk_frames % nint == 0 and not red._carries
+        # ... and is the budget's own where it cannot (rawspec's
+        # -f 1048576 -t 51: 8 frames a dispatch, never a 54-frame chunk).
+        red = RawReducer(nfft=1 << 20, nint=51)
+        assert red.chunk_frames == 8 and red._carries
+        with pytest.raises(ValueError, match="chunk_frames"):
+            RawReducer(nfft=64, nint=2, chunk_frames=0)
 
     @pytest.mark.parametrize("depth", [2, 3, 4])
     def test_prefetch_depth_invariant(self, tmp_path, depth):

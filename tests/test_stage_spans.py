@@ -116,6 +116,21 @@ class TestToyPass:
             assert table[name]["byte_free"] is True
             assert table[name]["bytes"] == 0
 
+    def test_a_mark_is_one_span_counted_as_many(self):
+        # Timeline.mark: a counted instant (ISSUE 26's integrate.carry /
+        # integrate.emit) — one zero-length stage span on the tracer's
+        # clock, `calls` events in the row, bytes summed once.
+        observability.tracer().reset()
+        tl = Timeline()
+        tl.mark("integrate.emit", nbytes=4096, calls=3)
+        tl.mark("integrate.emit", nbytes=1024)
+        row = tl.report()["integrate.emit"]
+        assert (row["calls"], row["bytes"]) == (4, 5120)
+        spans = [s for s in stage_spans(observability.tracer().span_dicts())
+                 if s["name"] == "integrate.emit"]
+        assert [s["attrs"]["bytes"] for s in spans] == [4096, 1024]
+        assert all(s["duration_s"] < 0.01 for s in spans)
+
     def test_spans_off_same_table_no_span(self, tmp_path, monkeypatch):
         on, _ = toy_pass(tmp_path)
         monkeypatch.setattr(observability, "_TRACER", Tracer(enabled=False))
@@ -126,7 +141,9 @@ class TestToyPass:
             if name in ("gauges", "hists"):
                 continue
             assert off[name]["bytes"] == row["bytes"], name
-            if not name.startswith("wait."):  # how often a wait blocks varies
+            # How often a wait blocks varies, and what the staging pool
+            # lends or allocates depends on what earlier passes left in it.
+            if not name.startswith(("wait.", "staging.")):
                 assert off[name]["calls"] == row["calls"], name
 
     def test_blit_reduce_prints_its_stage_table(self, tmp_path, capsys):

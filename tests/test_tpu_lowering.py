@@ -194,6 +194,25 @@ class TestReducerOnADeviceWithAMemoryLimit:
         # differently-batched program differently in the last bit.
         np.testing.assert_allclose(grouped, whole, rtol=1e-6, atol=1e-6)
 
+    def test_carried_integration_is_sized_and_grouped_once(self, tmp_path,
+                                                           monkeypatch):
+        # nint 24 in 16-frame chunks: the integration is carried, the
+        # 8-frame flush chunk would fit more channels per dispatch than a
+        # full one, and the accumulators keep the first chunk's groups
+        # (my chip run, PR 26: 32 channels against 64 at nfft 2^20).
+        import blit.pipeline as P
+
+        path = self._recording(tmp_path)
+        kw = dict(nfft=1024, nint=24, chunk_frames=16)
+        _, whole = P.RawReducer(**kw).reduce(path)
+        assert whole.shape[0] == 3  # 77 frames: three rows, 5 dropped
+        monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 12_000_000)
+        red = P.RawReducer(**kw)
+        _, grouped = red.reduce(path)
+        full = red._channel_block((16, 19 * 1024, 2, 2))
+        assert full < 16 and full < red._channel_block((16, 11 * 1024, 2, 2))
+        np.testing.assert_allclose(grouped, whole, rtol=1e-6, atol=1e-6)
+
     def test_a_chunk_that_cannot_fit_raises(self, tmp_path, monkeypatch):
         import blit.pipeline as P
 

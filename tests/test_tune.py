@@ -81,6 +81,86 @@ class TestOfflineConvergence:
         assert all(t["chunk_frames"] % 6 == 0 for t in trials)
         assert best["chunk_frames"] % 6 == 0
 
+    def test_integration_no_dispatch_holds_is_not_rounded_up_to(self):
+        # ONE rule says whether an integration fits a dispatch
+        # (blit.pipeline.fold_frames) and tune takes its answer as the
+        # multiple chunk_frames moves in.  rawspec's -f 1048576 -t 51 on
+        # a 300 s scan (838 frames, half = 419): the budget holds 8
+        # frames, the reducer carries the integration (ISSUE 26), and
+        # neither the bound, the base, the ladder nor the online
+        # recommendation may say 51 — the parent's 14.5 GB chunk.
+        from blit.pipeline import dispatch_frames, fold_frames
+
+        assert dispatch_frames(1 << 20) == 8
+        fold = fold_frames(1 << 20, 51)
+        assert fold == 1
+        assert fold_frames(1024, 51) == 51          # fits: still folds
+        assert fold_frames(1024, 3072) == 3072      # rawspec's 0002
+        assert fold_frames(1 << 20, 8) == 8
+        assert T._cf_bound(fold, 419) == 419
+        base = T.normalize_base({"chunk_frames": 8}, nint=fold,
+                                max_chunk_frames=419)
+        assert base["chunk_frames"] == 8
+        assert T.normalize_base({"chunk_frames": 8}, nint=6,
+                                max_chunk_frames=27)["chunk_frames"] == 12
+        opt = {"chunk_frames": 16, "prefetch_depth": 2, "out_depth": 2}
+        best, trials = T.tune(cost_model(opt), nint=fold,
+                              base={"chunk_frames": 8}, max_trials=30,
+                              max_chunk_frames=419)
+        assert best["chunk_frames"] == 16
+        assert not any(t["chunk_frames"] % 51 == 0 for t in trials)
+        rec = T.recommend_from_stages(
+            {"dispatch": {"calls": 6, "seconds": 3.0},
+             "device": {"calls": 6, "seconds": 6.0}}, {},
+            {"chunk_frames": 8, "prefetch_depth": 2, "out_depth": 2},
+            nint=fold)
+        assert rec.knobs["chunk_frames"] == 16
+
+    def test_blit_tune_stays_inside_the_dispatch_budget_when_carried(
+            self, monkeypatch):
+        # `blit tune --nfft 1048576 --nint 51` on a recording of 838
+        # frames: what _cmd_tune hands to normalize_base / tune.  Caught
+        # at the first reduction it would run (the warm-up at the base).
+        import argparse
+
+        import blit.__main__ as cli
+        import blit.io.guppi as guppi
+
+        class Rdr:
+            nblocks = 1676
+
+            def header(self, i):
+                return {"OBSNCHAN": 64}
+
+            def block_ntime_kept(self, i):
+                return 1 << 19
+
+        class Stop(Exception):
+            pass
+
+        seen = {}
+
+        def warm(self, raw, out):
+            seen["chunk_frames"] = self.chunk_frames
+            seen["carries"] = self._carries
+            raise Stop
+
+        from blit.pipeline import RawReducer
+
+        monkeypatch.setattr(guppi, "open_raw", lambda p: Rdr())
+        monkeypatch.setattr("os.path.getsize", lambda p: 1)
+        monkeypatch.setattr(RawReducer, "reduce_to_file", warm)
+        args = argparse.Namespace(
+            raw="scan.raw", nfft=1 << 20, nint=51, fqav=1,
+            dtype="float32", nbits=8, chunk_frames=8, trials=4, reps=1)
+        with pytest.raises(Stop):
+            cli._cmd_tune(args)
+        assert seen == {"chunk_frames": 8, "carries": True}
+        args.chunk_frames = 64  # asked beyond the budget: clamped to it
+        with pytest.raises(Stop):
+            cli._cmd_tune(args)
+        assert seen == {"chunk_frames": 8, "carries": True}
+
     def test_budget_bounds_measurements(self):
         opt = {"chunk_frames": 1024, "prefetch_depth": 8, "out_depth": 8}
         _, trials = T.tune(cost_model(opt), base={"chunk_frames": 8},
@@ -265,6 +345,28 @@ class TestReducerAutoload:
             out_depth=2))
         red = RawReducer(nfft=64, nint=4)
         assert red.chunk_frames % 4 == 0  # the nint rounding still runs
+
+    def test_profile_beyond_the_budget_is_resized_when_carried(
+            self, tmp_path, monkeypatch):
+        # A profile the parent's `blit tune` wrote for -f 1048576 -t 51
+        # says 51 or 102 frames (rounded up to the integration): 14.5 GB
+        # a chunk.  The budget holds 8; the reducer re-sizes, and an
+        # explicit chunk_frames is still honoured as given.
+        from blit.pipeline import RawReducer
+
+        monkeypatch.setenv("BLIT_TUNE_DIR", str(tmp_path))
+        key, ident = T.rig_fingerprint(
+            **RawReducer(nfft=1 << 20, nint=51)._tune_fingerprint_kw())
+        for saved, want in [(51, 8), (102, 8), (4, 4)]:
+            T.save_profile(T.TuningProfile(
+                key=key, rig=ident, chunk_frames=saved, prefetch_depth=2,
+                out_depth=2))
+            red = RawReducer(nfft=1 << 20, nint=51)
+            assert red.tuning_provenance()["sources"][
+                "chunk_frames"] == "profile"
+            assert red.chunk_frames == want and red._carries
+        assert RawReducer(nfft=1 << 20, nint=51,
+                          chunk_frames=51).chunk_frames == 51
 
     def test_profile_nchan_mismatch_warns_once(self, tmp_path, monkeypatch,
                                                caplog):
