@@ -24,8 +24,10 @@ name instead of carrying on slower.
 
 from __future__ import annotations
 
+import contextlib
 import os
-from typing import Dict, Optional
+import threading
+from typing import Callable, Dict, Iterator, List, Optional
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -128,8 +130,8 @@ def host_link_bytes() -> Optional[int]:
     set).  A transfer ENQUEUED while the region is taken goes another way
     and crawls: on a v5e a 2.95 GB chunk lands in 0.4 s alone and in 7 s
     when it is dispatched behind another (PERF.md section 6, PR 25: at
-    12 GiB both land in 0.5 s).  Callers keep what they have in flight
-    under this."""
+    12 GiB both land in 0.5 s).  :class:`HostLink` keeps what the process
+    has in flight under this."""
     import jax
 
     if jax.default_backend() != "tpu":
@@ -138,3 +140,154 @@ def host_link_bytes() -> Optional[int]:
         return int(os.environ["TPU_PREMAPPED_BUFFER_SIZE"])
     except (KeyError, ValueError):
         return _TPU_PREMAPPED_DEFAULT
+
+
+def _landed(handle) -> bool:
+    """Is everything in ``handle`` (an array or a tree of them) ready?  (A
+    deleted array is asked nothing: its ``is_ready()`` crashes the
+    process.)"""
+    import jax
+
+    return all(a.is_deleted() or a.is_ready()
+               for a in jax.tree_util.tree_leaves(handle))
+
+
+_FETCH_DEBIT = 2  # a fetch counts twice (HostLink)
+
+
+class HostLink:
+    """The process's byte budget for the host<->device link, drawn on per
+    transfer (ISSUE 27): a transfer of ``n`` staging bytes is admitted
+    only while what is in flight plus ``n`` stays under
+    :func:`host_link_bytes`, so nothing is ever enqueued behind a full
+    region.  Dispatcher and readback threads draw on the one budget.
+
+    - :meth:`put` is an H2D transfer: admit, then ``jax.device_put``.  Its
+      bytes stay in flight until a handle is ready: the put array or,
+      with ``then``, what the program that consumes it returned.  Both
+      were measured to keep a recorder-width stream at speed (PERF.md
+      section 6, PR 27).  The budget holds its handle until then, so the
+      pump names the program's output (which it keeps anyway): a group's
+      voltages leave device memory with their program, not at a later
+      admit.
+    - :meth:`fetch` brackets a D2H transfer: admitted like a put, in
+      flight while it runs, and debited TWICE its bytes, so a product that
+      is large beside the link has it to itself — H2D and D2H take turns,
+      as they always did there — and a small one rides along.  Measured
+      (PERF.md section 6, PR 27): a 2.1 GB product fetched beside 2.2 GB
+      of puts took 1.5-4.5 s instead of 0.65 s, and enqueued blind behind
+      4 GB of them 2.5 s.
+    - An admit that must wait does so in the caller's ``wait.link`` (a
+      :class:`blit.observability.StageWait`: blocked seconds only), on the
+      oldest put in flight, or on a fetch of another thread.  A transfer larger
+      than the link is admitted alone.  What is in flight after each
+      admit is the observation ``link.inflight_bytes`` (its ``max`` is
+      the pass's peak).
+    - Where the backend stages nothing (``host_link_bytes()`` is ``None``:
+      the CPU) everything is admitted at once and nothing is kept.
+    """
+
+    def __init__(self) -> None:
+        self._cv = threading.Condition()
+        self._puts: List = []  # (handle, bytes) in flight, oldest first
+        self._bytes = 0        # in flight: the puts' and the running fetches'
+
+    def _retire(self) -> None:
+        """Drop the puts that have landed (under ``_cv``)."""
+        flying = []
+        for handle, nbytes in self._puts:
+            if _landed(handle):
+                self._bytes -= nbytes
+            else:
+                flying.append((handle, nbytes))
+        self._puts = flying
+
+    def _admit(self, nbytes: int, timeline) -> bool:
+        """Block until ``nbytes`` fit, then count them in flight.  False
+        where the backend has no link to budget."""
+        import jax
+
+        if timeline is not None:
+            timeline.declare("wait.link")
+        link = host_link_bytes()
+        if link is None:
+            return False
+        wait = (timeline.wait("wait.link") if timeline is not None
+                else contextlib.nullcontext())
+        with wait as w, self._cv:
+            while True:
+                self._retire()
+                if not self._bytes or self._bytes + nbytes < link:
+                    break
+                if w is not None:
+                    w.block()
+                if not self._puts:  # a fetch on another thread holds it
+                    self._cv.wait(timeout=0.2)
+                    continue
+                oldest = self._puts[0][0]
+                self._cv.release()
+                try:
+                    jax.block_until_ready(oldest)
+                finally:
+                    self._cv.acquire()
+            self._bytes += nbytes
+            if timeline is not None:
+                timeline.observe("link.inflight_bytes", self._bytes)
+        return True
+
+    def _release(self, nbytes: int) -> None:
+        with self._cv:
+            self._bytes -= nbytes
+            self._cv.notify_all()
+
+    def put(self, host, device=None, timeline=None,
+            then: Optional[Callable] = None):
+        """``jax.device_put(host, device)`` through the budget, or with
+        ``then`` what that program returns for the put array."""
+        import jax
+
+        counted = self._admit(host.nbytes, timeline)
+        try:
+            out = jax.device_put(host, device)
+            if then is not None:
+                out = then(out)
+        except BaseException:
+            if counted:
+                self._release(host.nbytes)
+            raise
+        if counted:
+            with self._cv:
+                self._puts.append((out, host.nbytes))
+        return out
+
+    @contextlib.contextmanager
+    def fetch(self, nbytes: int, timeline=None) -> Iterator[None]:
+        """A device->host transfer of ``nbytes``, in flight for the body
+        (at twice its bytes: class docstring)."""
+        counted = self._admit(_FETCH_DEBIT * nbytes, timeline)
+        try:
+            yield
+        finally:
+            if counted:
+                self._release(_FETCH_DEBIT * nbytes)
+
+    @staticmethod
+    def fetch_takes_all(nbytes: int) -> bool:
+        """Does a fetch of ``nbytes`` leave the link no room for a put?"""
+        link = host_link_bytes()
+        return link is not None and _FETCH_DEBIT * nbytes >= link
+
+    def inflight_bytes(self) -> int:
+        """Bytes in flight now (what has landed is let go of first)."""
+        with self._cv:
+            self._retire()
+            return self._bytes
+
+
+_HOST_LINK = HostLink()
+
+
+def host_link() -> HostLink:
+    """The process-wide :class:`HostLink` (the link is the process's, not
+    a reducer's)."""
+    return _HOST_LINK

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import numpy as np
 
@@ -706,6 +706,7 @@ def channelize_blocked(
     coeffs,
     *,
     channel_block: int,
+    put: Callable = lambda group, then: then(group),
     **kw,
 ) -> jax.Array:
     """Host-looped channel blocking: the compile-friendly replacement for
@@ -718,19 +719,25 @@ def channelize_blocked(
     products.  Peak HBM is bounded by one group's intermediates plus the
     final product, so the per-*call* net work can grow well past what the
     flat layout fits (the dispatch-amortization lever of DESIGN.md §3 at
-    bounded memory, now at seconds-scale compile).
+    bounded memory, now at seconds-scale compile).  ``put(group, then=)``
+    takes a group of host voltages to the device and returns what its
+    program ``then`` makes of them (the caller's transfer policy; by
+    default the jit's own argument transfer).
 
     Same result as ``channelize(..., channel_block=0)`` (golden-tested).
     """
     nchan = voltages.shape[0]
+    def program(group):
+        return channelize(group, coeffs, **kw)
+
     if channel_block <= 0 or channel_block >= nchan:
-        return channelize(voltages, coeffs, **kw)
+        return put(voltages, then=program)
     if nchan % channel_block:
         raise ValueError(
             f"channel_block={channel_block} does not divide nchan={nchan}"
         )
     outs = [
-        channelize(voltages[c : c + channel_block], coeffs, **kw)
+        put(voltages[c : c + channel_block], then=program)
         for c in range(0, nchan, channel_block)
     ]
     return jnp.concatenate(outs, axis=-1)
@@ -785,6 +792,7 @@ def channelize_carry(
     *,
     channel_block: int,
     nint: int,
+    put: Callable = lambda group, then: then(group),
     **kw,
 ) -> Tuple[Optional[jax.Array], list]:
     """:func:`channelize_blocked` for an integration carried across
@@ -797,7 +805,7 @@ def channelize_carry(
     Returns ``(rows, accs)``: the ``(filled + nframes) // nint`` rows that
     closed, ``(k, nif, nchan*nfft)`` assembled on the device — ``None``
     when none did, and then no group's partial sum is concatenated,
-    fetched or written.
+    fetched or written.  ``put`` as in :func:`channelize_blocked`.
     """
     nchan = voltages.shape[0]
     if channel_block <= 0 or channel_block >= nchan:
@@ -807,14 +815,17 @@ def channelize_carry(
             f"channel_block={channel_block} does not divide nchan={nchan}"
         )
     at = np.int32(filled)  # data, not a static argument: one program
-    rows, new_accs = [], []
+    rows, new_accs, nclosed = [], [], 0
     for g, c in enumerate(range(0, nchan, channel_block)):
-        power = channelize(voltages[c : c + channel_block], coeffs,
-                           nint=1, **kw)
-        nclosed = (filled + power.shape[0]) // nint
-        acc = (jnp.zeros(power.shape[1:], jnp.float32) if accs is None
-               else accs[g])
-        closed, acc = integrate_carry(power, acc, at, nint=nint)
+        def program(group, g=g):
+            nonlocal nclosed
+            power = channelize(group, coeffs, nint=1, **kw)
+            nclosed = (filled + power.shape[0]) // nint
+            acc = (jnp.zeros(power.shape[1:], jnp.float32) if accs is None
+                   else accs[g])
+            return integrate_carry(power, acc, at, nint=nint)
+
+        closed, acc = put(voltages[c : c + channel_block], then=program)
         new_accs.append(acc)
         if nclosed:
             rows.append(closed if nclosed == closed.shape[0]

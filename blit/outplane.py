@@ -65,6 +65,7 @@ from typing import Callable, Iterator, List, Optional
 import numpy as np
 
 from blit import faults, observability
+from blit.device import host_link
 from blit.observability import Timeline
 
 log = logging.getLogger("blit.outplane")
@@ -120,7 +121,9 @@ class OutputRotation:
 
     - :meth:`put` hands an async-dispatched device array to the readback
       thread and returns any slabs completed so far (stream order).  It
-      blocks while ``depth`` outputs are already pending — that wait is
+      blocks while ``depth`` outputs are already pending (one, for a
+      product whose fetch takes the whole host link,
+      :meth:`blit.device.HostLink.fetch`) — that wait is
       the device-memory bound AND where compute/readback overlap happens
       (the caller's *next* dispatch is already queued device-side).
     - ``on_consumed`` fires on the readback thread right after the
@@ -223,7 +226,10 @@ class OutputRotation:
                         self._cv.notify_all()
                     continue
                 recycled = False
-                with self._tl.stage("readback"):
+                # The fetch draws on the process's link budget
+                # (blit.device.HostLink): it waits for what is going up.
+                with host_link().fetch(getattr(out, "nbytes", 0), self._tl), \
+                        self._tl.stage("readback"):
                     host = np.asarray(out)
                     if self.reuse and (host.base is not None
                                        or not host.flags.owndata):
@@ -341,6 +347,11 @@ class OutputRotation:
         bytes) lands on the ``device`` stage; omitted ⇒ byte-free.
         ``fetch=False`` syncs the dispatch (and fires ``on_consumed``)
         without a device→host fetch — no slab is ever emitted for it."""
+        # A product whose fetch takes the whole host link is waited out
+        # here: its slab is handed on at once, and the next chunk's
+        # voltages do not get on the link in front of it.
+        depth = 1 if fetch and host_link().fetch_takes_all(
+            getattr(out, "nbytes", 0)) else self.depth
         with self._cv:
             self._check()
             self._pending += 1
@@ -352,7 +363,7 @@ class OutputRotation:
                 while self._done:
                     ready.append(self._done.popleft())
                 self._check()
-                if self._pending < self.depth:
+                if self._pending < depth:
                     return ready
                 w.block()
                 self._cv.wait(timeout=self._poll())

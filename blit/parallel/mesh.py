@@ -24,7 +24,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from blit.device import host_link_bytes
+from blit.device import host_link
 from blit.observability import Timeline
 from blit.ops.channelize import channelize
 from blit.ops.despike import despike
@@ -310,21 +310,16 @@ def put_local_shards(
     and no ``device_put`` targets a non-addressable device (the
     multi-process contract of :func:`blit.parallel.scan._feed_window`,
     now partition-rule-driven).  Each player's put is a ``feed.put``
-    stage of its own (that block's bytes) on ``timeline``; a put that
-    would not fit what the runtime's transfer path stages at speed
-    beside the ones in flight (:func:`blit.device.host_link_bytes`:
-    four 1.34 GB banks against 4 GiB, and the fourth landed 7-9 s late)
-    first waits inside its stage for those to land."""
+    stage of its own (that block's bytes) on ``timeline`` and draws on
+    the process's link budget (:class:`blit.device.HostLink`): one that
+    would not fit beside those in flight (four 1.34 GB banks against
+    4 GiB, and the fourth landed 7-9 s late) first waits, inside its
+    stage, for the oldest to land."""
     tl = timeline if timeline is not None else Timeline()
-    link = host_link_bytes()
-    shards, flying = [], 0
+    shards = []
     for (b, k), blk in sorted(blocks.items()):
         with tl.stage("feed.put", blk.nbytes):
-            if link is not None and flying + blk.nbytes >= link:
-                jax.block_until_ready(shards)
-                flying = 0
-            shards.append(jax.device_put(blk, mesh.devices[b, k]))
-            flying += blk.nbytes
+            shards.append(host_link().put(blk, mesh.devices[b, k], tl))
     return jax.make_array_from_single_device_arrays(
         tuple(global_shape), sharding_for(mesh, role), shards
     )

@@ -19,6 +19,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from blit import hostmem, observability
+from blit.device import host_link
 from blit.io.guppi import GuppiRaw, open_raw, require_native_reader
 from blit.monitor import published
 from blit.ops.channelize import (
@@ -830,10 +831,11 @@ def reduce_scan_mesh_to_files(
                 pool.give(buf, tl)
             by_dev = {s.device: s for s in out.addressable_shards}
             for b in mine:
-                with tl.stage("readback"):
-                    slab = np.ascontiguousarray(
-                        np.asarray(by_dev[mesh.devices[b, 0]].data)[0]
-                    )
+                band = by_dev[mesh.devices[b, 0]].data
+                # (Behind the next window's puts on the link budget.)
+                with host_link().fetch(band.nbytes, tl), \
+                        tl.stage("readback"):
+                    slab = np.ascontiguousarray(np.asarray(band)[0])
                 tl.stages["readback"].bytes += slab.nbytes
                 with tl.stage("write", slab.nbytes):
                     writers[b].append(slab)

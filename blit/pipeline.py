@@ -50,7 +50,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from blit import observability
-from blit.device import hbm_bytes_limit, host_link_bytes
+from blit.device import hbm_bytes_limit, host_link
 from blit.io.guppi import GuppiRaw, RawSource, open_raw, require_native_reader
 from blit.observability import Timeline, profile_trace
 from blit.ops.channelize import (
@@ -570,6 +570,9 @@ class RawReducer:
         for b in self._buf_cache:
             pool.give(b, self.timeline)
         self._buf_cache = []
+        # Every dispatch is synced: the link budget lets go of the stream's
+        # last handles now, not at the next reduction's first put.
+        host_link().inflight_bytes()
 
     @property
     def stats(self) -> ReductionStats:
@@ -657,10 +660,11 @@ class RawReducer:
         consumed.  With ``carry`` the product is the rows that closed in
         this chunk — ``None`` when none did — and ``carry`` moves on."""
         frames = chunk.shape[1] // self.nfft - self.ntap + 1
+        put = functools.partial(host_link().put, timeline=self.timeline)
         if carry is None:
             out = channelize_blocked(
                 chunk, self._coeffs,
-                channel_block=self._channel_block(chunk.shape),
+                channel_block=self._channel_block(chunk.shape), put=put,
                 **self._channelize_kw)
             self._output_frames += frames
             return out, out
@@ -670,7 +674,7 @@ class RawReducer:
         nint = kw.pop("nint")
         rows, carry.accs = channelize_carry(
             chunk, self._coeffs, carry.accs, carry.filled,
-            channel_block=carry.channel_block, nint=nint, **kw)
+            channel_block=carry.channel_block, nint=nint, put=put, **kw)
         carry.filled = (carry.filled + frames) % nint
         if carry.filled:  # the dispatch left an integration open
             self.timeline.mark("integrate.carry",
@@ -759,12 +763,11 @@ class RawReducer:
         is put sync-only (``fetch=False``): its slot is released the same
         way, and nothing of it crosses to the host.
 
-        That overlap holds only while both chunks' transfers fit what
-        the runtime's transfer path stages at speed
-        (:func:`blit.device.host_link_bytes`): a recorder-width hi-res
-        chunk (2.95 GB in, 2.1 GB out) does not, its successor's input
-        would crawl behind it, and the depth drops to one chunk on the
-        link at a time (ISSUE 25).
+        What is on the host link at any instant is a channel group or
+        two, not a chunk: :meth:`_dispatch` hands each group up through
+        the process's byte budget (:class:`blit.device.HostLink`), so
+        chunk ``w+1``'s voltages go up while chunk ``w``'s programs run
+        and nothing is enqueued behind a full link (ISSUE 27).
         """
         from blit.outplane import OutputRotation, readback_extra_slots
 
@@ -777,7 +780,6 @@ class RawReducer:
         do_narrow = narrow and self.nbits < 32
         if do_narrow:
             from blit.ops.narrow import narrow_device
-        link = host_link_bytes()
         carry = self._open_integration()
         try:
             extra = readback_extra_slots(depth, self.prefetch_depth)
@@ -793,18 +795,9 @@ class RawReducer:
                         out = token = narrow_device(
                             out, self.nbits, self.quant_scale,
                             self.quant_offset)
-                nin = chunk.view.nbytes
-                nout = 0 if out is None else out.nbytes
-                if link is not None and nin + max(nin, nout) >= link:
-                    # The next chunk's input would be enqueued behind
-                    # this one's input or product fetch, and the two do
-                    # not fit what the runtime stages at speed
-                    # (blit.device.host_link_bytes): one at a time, then.
-                    # put() below returns once THIS chunk is fetched.
-                    rot.depth = 1
                 if tuner is not None:
                     tuner.observe_chunk()
-                for slab in rot.put(token, nbytes=nin,
+                for slab in rot.put(token, nbytes=chunk.view.nbytes,
                                     on_consumed=chunk.release,
                                     fetch=out is not None):
                     yield slab

@@ -12,6 +12,7 @@ import gc
 import json
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -306,26 +307,31 @@ class TestReductionReportsItsStaging:
             "byte_free": True}
 
 
-class TestOneChunkOnTheLinkAtATime:
+class TestTheLinkIsBudgetedByTransfer:
     """Once the read is fast the next chunk's input is enqueued while the
-    last one's transfers are in flight; where the two do not fit what the
-    runtime stages at speed (``blit.device.host_link_bytes``) the pump
-    keeps one chunk on the link at a time."""
+    last one's transfers are in flight.  What the runtime stages at speed
+    (``blit.device.host_link_bytes``) is drawn on per transfer — a channel
+    group up, a product down (``blit.device.HostLink``, ISSUE 27) — so the
+    pump overlaps chunks whatever two whole chunks would weigh (until
+    PR 27 it took turns where they did not fit: ISSUE 25's depth-1 rule)."""
 
     CHUNK = 2 * (4 + 3) * NFFT * 2 * 2  # one toy chunk buffer, bytes
 
-    @pytest.mark.parametrize("link,depth", [
-        (None, 2),               # a backend that stages nothing (the CPU)
-        (1 << 30, 2),            # two chunks fit: overlapped as before
-        (2 * CHUNK + 1, 2),
-        (2 * CHUNK, 1),          # they do not (exactly the region is not
-                                 # counted on to fit): one at a time
+    @pytest.mark.parametrize("nint", [
+        NINT,    # each chunk integrates inside its own program
+        11,      # carried: an integration straddles chunks, a 3-frame
+                 # flush chunk, most chunks fetch nothing
     ])
-    def test_depth_follows_what_the_link_takes(self, tmp_path, monkeypatch,
-                                               link, depth):
+    @pytest.mark.parametrize("link", [
+        None,                # a backend that stages nothing (the CPU)
+        1 << 30,             # two chunks fit
+        2 * CHUNK,           # they do not: chunks took turns here
+        CHUNK + CHUNK // 4,  # two groups fit, two chunks do not
+    ])
+    def test_the_next_chunk_goes_up_while_this_one_computes(
+            self, tmp_path, monkeypatch, link, nint):
         import blit.outplane as O
-        import blit.pipeline as P
-        from blit import observability
+        from blit import device, observability
 
         seen = []
 
@@ -335,31 +341,106 @@ class TestOneChunkOnTheLinkAtATime:
                 return super().put(*a, **kw)
 
         monkeypatch.setattr(O, "OutputRotation", Spy)
+        # As on the chip: a chunk goes up in two channel groups.
+        monkeypatch.setattr(RawReducer, "_channel_block", lambda *a: 1)
         p = str(tmp_path / "s.raw")
-        synth_raw(p, nblocks=4, obsnchan=2, ntime_per_block=2048)
-        RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4).reduce_to_file(
-            p, str(tmp_path / "ref.fil"))
+        synth_raw(p, nblocks=2, obsnchan=2, ntime_per_block=2048)
+
+        def reducer():
+            return RawReducer(nfft=NFFT, nint=nint, chunk_frames=4,
+                              tune_online=False)
+
+        reducer().reduce_to_file(p, str(tmp_path / "ref.fil"))
         seen.clear()
-        monkeypatch.setattr(P, "host_link_bytes", lambda: link)
+        monkeypatch.setattr(device, "host_link_bytes", lambda: link)
+        monkeypatch.setattr(device, "_HOST_LINK", device.HostLink())
+        wait = jax.block_until_ready
+
+        def a_program_takes_a_while(x):
+            time.sleep(0.02)
+            return wait(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", a_program_takes_a_while)
         observability.tracer().reset()
-        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4)
+        red = reducer()
         red.reduce_to_file(p, str(tmp_path / "got.fil"))
-        assert len(seen) > 3 and set(seen) == {depth}
+        assert len(seen) > 3 and set(seen) == {2}
         assert filecmp.cmp(tmp_path / "ref.fil", tmp_path / "got.fil",
                            shallow=False)
-        if depth == 1:
-            spans = observability.tracer().span_dicts()
+        spans = observability.tracer().span_dicts()
 
-            def ends(name):
-                return sorted((s["t0"], s["t0"] + s["duration_s"])
-                              for s in spans if s["name"] == name)
+        def ends(name):
+            return sorted((s["t0"], s["t0"] + s["duration_s"])
+                          for s in spans if s["name"] == name)
 
-            dispatches, fetches = ends("dispatch"), ends("readback")
-            assert len(dispatches) == len(fetches) == len(seen)
-            # Chunk k+1 goes up only after chunk k has come down.
-            for (t0, _), (_, fetched) in zip(dispatches[1:], fetches):
-                assert t0 >= fetched
-            assert red.timeline.report()["wait.out_slot"]["calls"] > 0
+        dispatches, waits = ends("dispatch"), ends("device")
+        assert len(dispatches) == len(waits) == len(seen)
+        # Chunk k+1's dispatch opens before chunk k's programs are done.
+        for (t0, _), (_, done) in zip(dispatches[1:], waits):
+            assert t0 < done
+        table = red.timeline.report()
+        assert table["wait.link"]["byte_free"]  # declared, engaged or not
+        if link is None:
+            assert "link.inflight_bytes" not in table["hists"]
+        else:
+            peak = table["hists"]["link.inflight_bytes"]
+            # Every group's put and every fetched product was admitted.
+            assert peak["n"] == 2 * len(seen) + table["readback"]["calls"]
+            assert self.CHUNK // 2 <= peak["max"] < link
+        if nint == 11:
+            assert table["integrate.carry"]["calls"] > 3
+            assert table["readback"]["calls"] < len(seen)
+
+    def test_a_large_product_is_down_before_the_next_chunk_goes_up(
+            self, tmp_path, monkeypatch):
+        """A product whose fetch takes the whole link (it counts twice) is
+        waited out before the next chunk is dispatched: H2D and D2H take
+        turns there, the order PR 25 measured safe for recorder-width
+        hi-res."""
+        from blit import device, observability
+
+        monkeypatch.setattr(RawReducer, "_channel_block", lambda *a: 1)
+        p = str(tmp_path / "s.raw")
+        synth_raw(p, nblocks=2, obsnchan=2, ntime_per_block=2048)
+        product = 4 // NINT * 2 * NFFT * 4  # one chunk's rows, bytes
+        link = 2 * product
+        assert self.CHUNK // 2 < link  # a group fits, twice a product not
+        monkeypatch.setattr(device, "host_link_bytes", lambda: link)
+        monkeypatch.setattr(device, "_HOST_LINK", device.HostLink())
+        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4,
+                         tune_online=False)
+        put = jax.device_put
+
+        def marked_put(*a, **kw):
+            red.timeline.mark("test.put")
+            return put(*a, **kw)
+
+        monkeypatch.setattr(jax, "device_put", marked_put)
+        observability.tracer().reset()
+        red.reduce_to_file(p, str(tmp_path / "got.fil"))
+        spans = observability.tracer().span_dicts()
+        monkeypatch.undo()
+        RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4,
+                   tune_online=False).reduce_to_file(
+                       p, str(tmp_path / "ref.fil"))
+        assert filecmp.cmp(tmp_path / "ref.fil", tmp_path / "got.fil",
+                           shallow=False)
+        puts = sorted(s["t0"] for s in spans if s["name"] == "test.put")
+        down = sorted(s["t0"] + s["duration_s"] for s in spans
+                      if s["name"] == "readback")
+        assert len(puts) == 2 * len(down) > 6
+        for k, fetched in enumerate(down[:-1]):
+            assert puts[2 * (k + 1)] >= fetched
+        # ... and is waited out in OutputRotation.put, where its slab is
+        # handed to the sink before the next chunk is dispatched.
+        starts = sorted(s["t0"] for s in spans if s["name"] == "dispatch")
+        assert len(starts) == len(down)
+        for k, fetched in enumerate(down[:-1]):
+            assert fetched <= starts[k + 1]
+        table = red.timeline.report()
+        assert table["wait.out_slot"]["calls"] >= len(down) - 1
+        # (The fetch, alone on the link, is what counts for all of it.)
+        assert table["hists"]["link.inflight_bytes"]["max"] == link
 
     @pytest.mark.parametrize("env,want", [(None, 4 << 30), ("123", 123),
                                           ("junk", 4 << 30)])
@@ -481,11 +562,14 @@ class TestMeshScanWindowFeed:
         (None, "pppp"),    # a backend that stages nothing: as before
         (4.5, "pppp"),     # all four fit
         (4.0, "pppwp"),    # exactly the region: not counted on to fit
-        (3.2, "pppwp"),    # four 1.34 GB banks against 4 GiB: three fit
+        (3.2, "pppwp"),    # four 1.34 GB banks against 4 GiB: three fit,
+                           # and the fourth waits for the first alone
+        (2.5, "ppwpwp"),   # the oldest in flight, not all of them
         (1.5, "pwpwpwp"),
     ])
     def test_a_put_waits_for_what_would_not_fit_beside_it(
             self, monkeypatch, link_blocks, want):
+        from blit import device
         from blit.parallel import mesh as M
 
         mesh = M.make_mesh(1, self.NBANK)
@@ -496,28 +580,67 @@ class TestMeshScanWindowFeed:
                   for k in range(self.NBANK)}
         nb = blocks[(0, 0)].nbytes
         monkeypatch.setattr(
-            M, "host_link_bytes",
+            device, "host_link_bytes",
             lambda: None if link_blocks is None else int(link_blocks * nb))
-        events = []
+        monkeypatch.setattr(device, "_HOST_LINK", device.HostLink())
+        events, flying = [], []
         put, wait = jax.device_put, jax.block_until_ready
 
         def spy_put(*a, **kw):
             events.append("p")
-            return put(*a, **kw)
+            flying.append(put(*a, **kw))
+            return flying[-1]
 
         def spy_wait(x):
             events.append("w")
-            assert len(x) == events.count("p")  # everything in flight
+            assert x is flying.pop(0)  # the oldest in flight, alone
             return wait(x)
 
-        monkeypatch.setattr(M.jax, "device_put", spy_put)
-        monkeypatch.setattr(M.jax, "block_until_ready", spy_wait)
+        # On the CPU a put has landed when it returns: here one is in
+        # flight until it is waited for.
+        monkeypatch.setattr(device, "_landed",
+                            lambda arr: not any(arr is f for f in flying))
+        monkeypatch.setattr(jax, "device_put", spy_put)
+        monkeypatch.setattr(jax, "block_until_ready", spy_wait)
         tl = Timeline()
         volt = M.put_local_shards(blocks, mesh, shape, timeline=tl)
         monkeypatch.undo()
         assert "".join(events) == want
-        assert tl.stages["feed.put"].calls == self.NBANK
+        table = tl.report()
+        assert table["feed.put"]["calls"] == self.NBANK
+        assert table["wait.link"]["calls"] == want.count("w")
+        if link_blocks is not None:
+            assert table["hists"]["link.inflight_bytes"]["max"] \
+                < link_blocks * nb
         np.testing.assert_array_equal(np.asarray(volt), whole)
+
+    def test_a_window_comes_down_through_the_budget_too(self, tmp_path,
+                                                        monkeypatch):
+        """The next window's banks are already going up when a window's
+        stitched band is fetched: that fetch is admitted by the same
+        budget, not enqueued blind behind them."""
+        from blit import device
+        from blit.parallel.scan import reduce_scan_mesh_to_files
+
+        paths = self.scan(tmp_path)
+        tables = {}
+        for tag, link in (("free", None), ("budgeted", 1 << 20)):
+            (tmp_path / tag).mkdir()
+            monkeypatch.setattr(device, "host_link_bytes", lambda: link)
+            monkeypatch.setattr(device, "_HOST_LINK", device.HostLink())
+            tl = Timeline()
+            reduce_scan_mesh_to_files(
+                paths, out_dir=str(tmp_path / tag), nfft=NFFT, nint=NINT,
+                window_frames=16, timeline=tl)
+            tables[tag] = tl.report()
+        assert filecmp.cmp(tmp_path / "free" / "band0.fil",
+                           tmp_path / "budgeted" / "band0.fil",
+                           shallow=False)
+        table = tables["budgeted"]
+        assert table["hists"]["link.inflight_bytes"]["n"] \
+            == table["feed.put"]["calls"] + table["readback"]["calls"]
+        assert table["wait.link"]["byte_free"]
+        assert "link.inflight_bytes" not in tables["free"].get("hists", {})
 
     def test_a_second_scan_allocates_nothing(self, tmp_path,
                                              fresh_process_pool):
