@@ -39,8 +39,8 @@ Layer map (mirrors SURVEY.md §1, rebuilt TPU-first):
 - ``blit.monitor``  — the live monitoring & SLO plane: the background
   metrics publisher (interval snapshots → spool JSONL + ``/metrics``/
   ``/healthz``/``/snapshot`` HTTP endpoint), multi-window burn-rate SLO
-  evaluation with load-shed breach actions, the ``blit top`` terminal
-  dashboard, and the ``blit bench-diff`` perf-regression gate.
+  evaluation with load-shed breach actions, and the ``blit top``
+  terminal dashboard.
 - ``blit.tune``      — the ingest autotuner: per-rig content-addressed
   tuning profiles (chunk_frames / prefetch_depth / out_depth) converged
   offline (``blit tune``) or online during the first windows of a

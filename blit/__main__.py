@@ -24,25 +24,11 @@ Commands:
   inventory  Crawl a data tree (reference getinventory semantics) and
              print records as JSON lines or a table.
   info       Print the normalized header of a .fil / .h5 / .raw file.
-  serve-bench
-             Replay a zipfian request mix against a ProductService
-             (blit/serve) over synthetic RAW inputs and report hit-rate,
-             coalesce counts, and p50/p99 queue wait.  ``--fleet``
-             replays through a REAL multi-process fleet front door
-             (ISSUE 14: consistent-hash routing, hedged reads, deadline
-             propagation) and reports per-tier hit-rate, SLO attainment
-             and the hedge counters.
   fleet-peer Run ONE serving peer of the fleet (ISSUE 14): a
              ProductService over stdlib HTTP (/product /warm /stats
              /healthz /metrics /drain) beating a heartbeat lease;
              SIGTERM drains gracefully — refuse new, finish in-flight,
              release live capacity holds.
-  ingest-bench
-             File→product throughput probe of the asynchronous output
-             plane (blit/outplane): per-stage table with the readback/
-             write stages and the overlap-efficiency gauge, optionally
-             A/B'd against the synchronous path (and against spans
-             disabled, for the tracing-overhead bound).
   tune       Offline ingest autotune (ISSUE 8): sweep the ingest knobs
              (chunk_frames / prefetch_depth / out_depth) with real timed
              reductions on THIS rig and persist the winner as a
@@ -77,9 +63,6 @@ Commands:
              reduce/scan/stream/serve — per-stage throughput, stage-tail
              p50/p99, SLO burn, host health.  ``--once`` renders one
              frame (tests/scripts).
-  bench-diff Compare a fresh bench.py / ingest-bench JSON against the
-             checked-in BENCH_*.json trajectory with noise bands and
-             exit 0 (pass) / 2 (regress) — the CI perf-regression gate.
 """
 
 from __future__ import annotations
@@ -597,13 +580,9 @@ def _spawn_fleet_peers(td: str, npeers: int, *, concurrency: int,
                        queue_depth: int, ram_bytes: int,
                        beat_interval_s: float = 0.2,
                        bringup_timeout_s: float = 120.0,
-                       standbys: int = 0,
-                       extra_env: Optional[dict] = None,
-                       catalog_root: Optional[str] = None,
-                       cold_dirs: bool = False,
-                       disk_bytes: Optional[int] = None):
+                       standbys: int = 0):
     """Bring up ``npeers`` REAL ``blit fleet-peer`` subprocesses (the
-    bench/chaos rig): per-peer cache dirs + one shared lease dir under
+    chaos rig): per-peer cache dirs + one shared lease dir under
     ``td``, ephemeral ports published through port files.  Returns
     ``(procs, peers, lease_dir)`` with ``procs`` a list of
     ``(Popen, logfile)`` pairs and ``peers`` the name→url map the
@@ -612,12 +591,7 @@ def _spawn_fleet_peers(td: str, npeers: int, *, concurrency: int,
     ``standbys`` additionally spawns that many ``--standby`` peers
     (ISSUE 17): named ``standby{j}``, lease proc ``npeers + j``,
     appended to both ``procs`` and ``peers`` — the caller registers
-    them via ``door.add_standby`` instead of the ring-seeding map.
-
-    Archive plane (ISSUE 19): ``catalog_root`` arms every peer's
-    catalog, ``cold_dirs`` gives each peer a per-peer cold tier under
-    ``td``, ``disk_bytes`` caps the hot disk tier (what forces
-    demotion)."""
+    them via ``door.add_standby`` instead of the ring-seeding map."""
     import os
     import subprocess
     import time as _time
@@ -640,21 +614,14 @@ def _spawn_fleet_peers(td: str, npeers: int, *, concurrency: int,
                "--ram-bytes", str(ram_bytes),
                "--beat-interval", str(beat_interval_s),
                "--retry-seed", str(i)]
-        if catalog_root:
-            cmd += ["--catalog-root", catalog_root]
-        if cold_dirs:
-            cmd += ["--cold-dir", os.path.join(td, f"cold-{name}")]
-        if disk_bytes is not None:
-            cmd += ["--disk-bytes", str(disk_bytes)]
         if i >= npeers:
             cmd.append("--standby")
         env = dict(os.environ)
         # One process per chip, and these rigs start several peers per
         # host: unless the caller names a platform the peers derive on
-        # the CPU.  Each peer reports its platform in /stats and the
-        # bench reports carry it, so a CPU fleet never reads as a chip.
+        # the CPU.  Each peer reports its platform in /stats, so a CPU
+        # fleet never reads as a chip.
         env.setdefault("JAX_PLATFORMS", "cpu")
-        env.update(extra_env or {})
         logf = open(os.path.join(td, f"{name}.log"), "w")
         procs.append((subprocess.Popen(cmd, stdout=logf, stderr=logf,
                                        env=env), logf))
@@ -682,7 +649,7 @@ def _spawn_fleet_peers(td: str, npeers: int, *, concurrency: int,
 
 def _reap_fleet_peers(procs) -> None:
     """Terminate (then kill) peer subprocesses and close their logs —
-    every exit path of the bench/chaos rigs."""
+    every exit path of the chaos rigs."""
     for p, _ in procs:
         if p.poll() is None:
             p.terminate()
@@ -699,1183 +666,6 @@ def _reap_fleet_peers(procs) -> None:
             logf.close()
         except OSError:
             pass
-
-
-def _cmd_serve_bench(args: argparse.Namespace) -> int:
-    """Drive a ProductService with a zipfian request replay — the serving
-    layer's dispatch-overhead probe (ISSUE 3): most traffic re-asks for a
-    few hot products, so the report's hit-rate/coalesce/queue-wait numbers
-    are what a multi-tenant deployment would see.  ``--fleet`` replays
-    the same mix through a REAL multi-process fleet front door instead
-    (ISSUE 14): N ``fleet-peer`` subprocesses behind consistent-hash
-    routing, reporting per-tier hit-rate, SLO attainment and the hedge
-    counters."""
-    import math
-    import os
-    import random
-    import tempfile
-    import threading
-    import time as _time
-
-    from blit.observability import Timeline
-    from blit.serve import (
-        Overloaded,
-        ProductCache,
-        ProductRequest,
-        ProductService,
-        Scheduler,
-    )
-    from blit.serve.http import install_drain_handler
-    from blit.testing import synth_raw
-
-    if args.archive_day:
-        return _serve_bench_archive_day(args)
-    if args.diurnal:
-        return _serve_bench_diurnal(args)
-    if args.fleet:
-        return _serve_bench_fleet(args)
-    from blit.config import DEFAULT
-
-    with tempfile.TemporaryDirectory(prefix="blit-serve-bench-") as td:
-        # Distinct products = distinct synthetic recordings (tiny: the
-        # bench measures the serving layer, not the channelizer).
-        ntime = (8 + 3) * args.nfft  # 8 PFB frames at ntap=4
-        reqs = []
-        for i in range(args.distinct):
-            path = os.path.join(td, f"bench{i:03d}.raw")
-            synth_raw(path, nblocks=1, obsnchan=2, ntime_per_block=ntime,
-                      seed=i)
-            reqs.append(ProductRequest(raw=path, nfft=args.nfft, nint=1))
-        # Zipfian popularity over the distinct products: p(k) ∝ 1/(k+1)^s
-        # — one pick sequence, replayed identically by every pass so the
-        # request-log A/B compares the same workload.
-        rng = random.Random(args.seed)
-        weights = [1.0 / math.pow(k + 1, args.zipf_s)
-                   for k in range(args.distinct)]
-        picks = rng.choices(range(args.distinct), weights=weights,
-                            k=args.requests)
-
-        def one_pass(request_log_dir, pass_id: int = 0) -> dict:
-            tl = Timeline()
-            cache_dir = (os.path.join(td, f"cache{pass_id}")
-                         if args.disk_cache else None)
-            # Pin the env for this pass's service construction: an
-            # ambient BLIT_REQUEST_LOG would override the config and
-            # silently invalidate the off/on A/B ("" = disabled, the
-            # request_log_defaults encoding).
-            prev = os.environ.get("BLIT_REQUEST_LOG")
-            os.environ["BLIT_REQUEST_LOG"] = request_log_dir or ""
-            try:
-                service = ProductService(
-                    cache=ProductCache(cache_dir,
-                                       ram_bytes=args.ram_bytes,
-                                       timeline=tl),
-                    scheduler=Scheduler(max_concurrency=args.concurrency,
-                                        queue_depth=args.queue_depth,
-                                        timeline=tl,
-                                        retry_seed=args.seed),
-                    timeline=tl,
-                    config=DEFAULT.with_(
-                        request_log_dir=request_log_dir),
-                )
-            finally:
-                if prev is None:
-                    os.environ.pop("BLIT_REQUEST_LOG", None)
-                else:
-                    os.environ["BLIT_REQUEST_LOG"] = prev
-            # Graceful-shutdown satellite (ISSUE 14): SIGTERM/SIGINT
-            # drains the scheduler — in-flight jobs finish, queued ones
-            # deliver Cancelled, and kind="stream" capacity holds
-            # release instead of leaking on interpreter exit.
-            uninstall_signals = install_drain_handler(
-                lambda: service.drain(timeout=30.0))
-            errors: list = []
-            rejected = [0]
-            lock = threading.Lock()
-            it = iter(picks)
-
-            def client_loop(cid: int) -> None:
-                while True:
-                    with lock:
-                        k = next(it, None)
-                    if k is None:
-                        return
-                    try:
-                        service.get(reqs[k], timeout=120,
-                                    client=f"client{cid}")
-                    except Overloaded:
-                        with lock:
-                            rejected[0] += 1
-                    except Exception as e:  # noqa: BLE001 — reported
-                        with lock:
-                            errors.append(repr(e))
-
-            t0 = _time.perf_counter()
-            threads = [threading.Thread(target=client_loop, args=(c,))
-                       for c in range(args.clients)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            wall = _time.perf_counter() - t0
-            uninstall_signals()
-            service.close()
-            stats = service.stats()
-            qw = stats["queue_wait"]
-            rep = {
-                "requests": args.requests,
-                "distinct": args.distinct,
-                "clients": args.clients,
-                "zipf_s": args.zipf_s,
-                "wall_s": round(wall, 3),
-                "hit_rate": stats["hit_rate"],
-                "coalesced": stats["coalesced"],
-                "scheduled": stats["scheduled"],
-                "rejected_overloaded": rejected[0],
-                "queue_wait_p50_s": round(qw["p50"], 6),
-                "queue_wait_p99_s": round(qw["p99"], 6),
-                "cache": stats["cache"],
-                # Latency distributions (ISSUE 5): the bounded
-                # histograms the serving timeline accumulated — tails,
-                # not averages.
-                "hists": tl.report().get("hists", {}),
-                "errors": errors[:5],
-            }
-            if request_log_dir:
-                from blit import monitor
-
-                recs = monitor.read_requests(request_log_dir)
-                rep["request_log"] = monitor.aggregate_requests(recs)
-            return rep
-
-        if args.request_log_compare:
-            # The ISSUE 15 A/B (the --spans-compare discipline): the
-            # identical replay with request logging DISABLED then
-            # ENABLED — the report pins the disabled pass's record
-            # count at zero and prices the enabled pass.  An untimed
-            # warmup pass absorbs the XLA compiles first, so off/on
-            # compare warm against warm instead of cold against warm.
-            one_pass(None, 9)
-            off = one_pass(None, 0)
-            log_dir = args.request_log or os.path.join(td, "reqlog")
-            # Measured, not assumed: the disabled pass must have
-            # written NOTHING anywhere under the bench root.
-            import glob as _glob
-
-            off_records = len(_glob.glob(
-                os.path.join(td, "**", "requests-*.jsonl*"),
-                recursive=True))
-            on = one_pass(log_dir, 1)
-            overhead = (on["wall_s"] / off["wall_s"] - 1.0
-                        if off["wall_s"] else 0.0)
-            print(json.dumps({
-                "request_log_compare": True,
-                "off_wall_s": off["wall_s"],
-                "on_wall_s": on["wall_s"],
-                "overhead_pct": round(overhead * 100.0, 2),
-                "off_records": off_records,
-                "on_records": (on.get("request_log") or {}).get(
-                    "records", 0),
-                "off": off,
-                "on": on,
-            }))
-            return 1 if off["errors"] or on["errors"] else 0
-        rep = one_pass(args.request_log, 0)
-        print(json.dumps(rep))
-        return 1 if rep["errors"] else 0
-
-
-def _serve_bench_fleet(args: argparse.Namespace) -> int:
-    """``serve-bench --fleet`` (ISSUE 14): replay the zipfian mix at
-    accelerated clock through a REAL fleet — N ``fleet-peer``
-    subprocesses behind an in-process :class:`FleetFrontDoor` (the HTTP
-    hop is at the peer boundary, where the bytes actually move).  The
-    report is what a deployment watches: per-tier hit-rate across the
-    fleet, SLO attainment against ``--slo-ms``, request p50/p99, and
-    the hedge/failover counters with the duplicate-compute bound."""
-    import math
-    import os
-    import random
-    import tempfile
-    import threading
-    import time as _time
-
-    from blit import monitor, observability
-    from blit.config import DEFAULT
-    from blit.observability import HistogramStats, Timeline
-    from blit.serve import Overloaded, ProductRequest
-    from blit.serve.fleet import FleetFrontDoor
-    from blit.serve.http import (
-        WIRE_CTYPE,
-        WIRE_HEADER,
-        decode_product,
-        decode_product_wire,
-        http_json,
-        http_request,
-        install_drain_handler,
-        wire_request,
-    )
-    from blit.serve.scheduler import DeadlineExpired
-    from blit.testing import synth_raw
-
-    rng = random.Random(args.seed)
-    tl = Timeline()
-    with tempfile.TemporaryDirectory(prefix="blit-fleet-bench-") as td:
-        ntime = (8 + 3) * args.nfft  # 8 PFB frames at ntap=4
-        reqs = []
-        for i in range(args.distinct):
-            path = os.path.join(td, f"bench{i:03d}.raw")
-            synth_raw(path, nblocks=1, obsnchan=2, ntime_per_block=ntime,
-                      seed=i)
-            reqs.append(ProductRequest(raw=path, nfft=args.nfft, nint=1))
-        # Request observability is ON for the fleet replay (ISSUE 15):
-        # the report's p50/p99 come from the access records, and the
-        # peers inherit the spool dir through their environment.  The
-        # door's env is pinned too — an ambient BLIT_REQUEST_LOG would
-        # override the config and send its records elsewhere.
-        reqlog_dir = args.request_log or os.path.join(td, "reqlog")
-        os.environ["BLIT_REQUEST_LOG"] = reqlog_dir
-        procs, peers, lease_dir = _spawn_fleet_peers(
-            td, args.peers, concurrency=args.concurrency,
-            queue_depth=args.queue_depth, ram_bytes=args.ram_bytes,
-            extra_env={"BLIT_REQUEST_LOG": reqlog_dir})
-        door = FleetFrontDoor(
-            peers, lease_dir=lease_dir, timeline=tl,
-            replicas=args.replicas, peer_ttl_s=args.peer_ttl,
-            poll_s=min(0.1, args.peer_ttl / 4),
-            hedge_floor_s=args.hedge_floor_ms / 1e3,
-            request_timeout_s=60.0,
-            config=DEFAULT.with_(request_log_dir=reqlog_dir)).start()
-        uninstall = install_drain_handler(lambda: door.drain())
-        weights = [1.0 / math.pow(k + 1, args.zipf_s)
-                   for k in range(args.distinct)]
-        picks = rng.choices(range(args.distinct), weights=weights,
-                            k=args.requests)
-        lat = HistogramStats()
-        slo_s = args.slo_ms / 1e3
-        lock = threading.Lock()
-        attained = [0]
-        rejected = [0]
-        expired = [0]
-        errors: list = []
-        it = iter(picks)
-
-        def client_loop(cid: int) -> None:
-            while True:
-                with lock:
-                    k = next(it, None)
-                if k is None:
-                    return
-                t = _time.perf_counter()
-                ok = False
-                try:
-                    door.get(reqs[k], client=f"client{cid}",
-                             deadline_s=args.deadline)
-                    ok = True
-                except DeadlineExpired:
-                    with lock:
-                        expired[0] += 1
-                except Overloaded as e:
-                    with lock:
-                        rejected[0] += 1
-                    _time.sleep(min(0.25, e.retry_after_s))
-                except Exception as e:  # noqa: BLE001 — reported below
-                    with lock:
-                        errors.append(repr(e))
-                dt = _time.perf_counter() - t
-                lat.observe(dt)
-                # SLO attainment counts SERVED requests only: a fleet
-                # that 503s everything in a millisecond must read as
-                # 0% attained, not 100%.
-                if ok and dt <= slo_s:
-                    with lock:
-                        attained[0] += 1
-
-        try:
-            t0 = _time.perf_counter()
-            threads = [threading.Thread(target=client_loop, args=(c,))
-                       for c in range(args.clients)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            wall = _time.perf_counter() - t0
-            tiers = {"hit.ram": 0, "hit.disk": 0, "miss": 0}
-            per_peer = {}
-            for name, url in sorted(peers.items()):
-                try:
-                    _, _, s = http_json("GET", url, "/stats", timeout=5.0)
-                except OSError:
-                    continue
-                c = (s.get("cache") or {})
-                for k in tiers:
-                    tiers[k] += int(c.get(k, 0))
-                per_peer[name] = {
-                    "platform": s.get("platform"),
-                    "hit_rate": s.get("hit_rate"),
-                    "scheduled": s.get("scheduled"),
-                    "coalesced": s.get("coalesced"),
-                }
-            served_tier = tiers["hit.ram"] + tiers["hit.disk"]
-            total_tier = served_tier + tiers["miss"]
-            # Wire back-compat probe (ISSUE 16): ONE explicit
-            # legacy-JSON request and ONE binary request against the
-            # same peer for the same product — the CI smoke pins that
-            # the binary frame was actually negotiated somewhere AND
-            # that a client which never sends the binary Accept still
-            # gets the identical bytes.
-            try:
-                probe_doc = json.dumps(wire_request(
-                    reqs[0], client="compat")).encode()
-                purl = sorted(peers.values())[0]
-                st_j, hdr_j, pay_j = http_request(
-                    "POST", purl, "/product", body=probe_doc,
-                    headers={"Content-Type": "application/json"},
-                    timeout=60.0)
-                st_b, hdr_b, pay_b = http_request(
-                    "POST", purl, "/product", body=probe_doc,
-                    headers={"Content-Type": "application/json",
-                             "Accept": f"{WIRE_CTYPE}, application/json"},
-                    timeout=60.0)
-                _, dj = decode_product(json.loads(pay_j))
-                _, db = decode_product_wire(
-                    pay_b, encoding=hdr_b.get("content-encoding"))
-                compat = {
-                    "legacy_wire": hdr_j.get(WIRE_HEADER.lower()),
-                    "binary_wire": hdr_b.get(WIRE_HEADER.lower()),
-                    "byte_identical": bool(
-                        st_j == 200 and st_b == 200
-                        and dj.dtype == db.dtype
-                        and dj.shape == db.shape
-                        and dj.tobytes() == db.tobytes()),
-                }
-            except Exception as e:  # noqa: BLE001 — probe is advisory
-                compat = {"error": repr(e)}
-            # Fleet trace harvest (ISSUE 15 tentpole #4): stitch the
-            # peers' span batches (their live /snapshot endpoints, with
-            # histogram exemplars) and the door's own spans/hists into
-            # ONE reviewable artifact — the Perfetto export plus a raw
-            # .snapshot.json that `blit trace-view --fleet` reads after
-            # the peers are gone.
-            trace_block = None
-            if args.trace_out:
-                spans, hists = monitor.gather_trace_sources(
-                    list(peers.values()))
-                seen_ids = {s.get("span") for s in spans}
-                spans.extend(s for s in observability.tracer().span_dicts()
-                             if s.get("span") not in seen_ids)
-                for k, h in list(tl.hists.items()):
-                    if k in hists:
-                        hists[k].merge(h)
-                    else:
-                        hists[k] = HistogramStats.from_state(h.state())
-                stitcher = observability.Tracer(
-                    max_spans=max(1, len(spans)), enabled=True)
-                stitcher.ingest(spans)
-                stitcher.export_chrome(args.trace_out)
-                snap_path = args.trace_out + ".snapshot.json"
-                with open(snap_path, "w") as f:
-                    json.dump({"spans": spans,
-                               "hists": {k: h.state()
-                                         for k, h in hists.items()}}, f)
-                trace_block = dict(observability.trace_summary(spans),
-                                   out=args.trace_out,
-                                   snapshot=snap_path)
-            # The report's latency quantiles come from the ACCESS
-            # RECORDS (ISSUE 15 satellite): what the door actually
-            # logged per request, not a separate in-bench stopwatch.
-            all_recs = monitor.read_requests(reqlog_dir)
-            door_agg = monitor.aggregate_requests(
-                monitor.filter_requests(all_recs, role="door"))
-            fstats = door.stats()
-            c = fstats["counters"]
-            hedges = c.get("fleet.hedge", 0)
-            report = {
-                "fleet": True,
-                "requests": args.requests,
-                "distinct": args.distinct,
-                "clients": args.clients,
-                "peers": args.peers,
-                "replicas": args.replicas,
-                "zipf_s": args.zipf_s,
-                "wall_s": round(wall, 3),
-                "rps": round(args.requests / wall, 1) if wall else None,
-                "tiers": tiers,
-                "hit_rate": (round(served_tier / total_tier, 4)
-                             if total_tier else 0.0),
-                "hit_rate_ram": (round(tiers["hit.ram"] / total_tier, 4)
-                                 if total_tier else 0.0),
-                "hit_rate_disk": (round(tiers["hit.disk"] / total_tier, 4)
-                                  if total_tier else 0.0),
-                "slo": {"target_s": slo_s,
-                        "attained": round(attained[0] / args.requests, 4)
-                        if args.requests else None},
-                "request_p50_s": round(lat.percentile(0.50), 6),
-                "request_p99_s": round(lat.percentile(0.99), 6),
-                "hedge": {
-                    "hedges": hedges,
-                    "wins": c.get("fleet.hedge.win", 0),
-                    "dup_done": c.get("fleet.hedge.dup_done", 0),
-                    "rate": (round(hedges / args.requests, 4)
-                             if args.requests else 0.0),
-                    # The acceptance bound: each hedge adds at most ONE
-                    # duplicate dispatch, so compute on the hedged slice
-                    # is <= 2x by construction; dup_ratio reports how
-                    # much actually ran to completion.
-                    "dup_ratio": (round(
-                        c.get("fleet.hedge.dup_done", 0) / hedges, 4)
-                        if hedges else 0.0),
-                },
-                "failovers": c.get("fleet.failover", 0),
-                # The hot-path data plane (ISSUE 16): which wire each
-                # peer answer rode, the keep-alive pool's reuse ratio,
-                # and the negotiation/back-compat probe CI asserts on.
-                "wire": {
-                    "mode": fstats.get("wire"),
-                    "binary_responses": c.get("fleet.wire.binary", 0),
-                    "json_responses": c.get("fleet.wire.json", 0),
-                    "wire_gb": round(
-                        tl.hists["fleet.wire_bytes"].total / 1e9, 6)
-                    if "fleet.wire_bytes" in tl.hists else 0.0,
-                    "pool": {
-                        "open": c.get("fleet.pool.open", 0),
-                        "reuse": c.get("fleet.pool.reuse", 0),
-                        "evict": c.get("fleet.pool.evict", 0),
-                        "idle": fstats.get("pool"),
-                    },
-                    "compat": compat,
-                },
-                "rejected_overloaded": rejected[0],
-                "deadline_expired": expired[0],
-                "per_peer": per_peer,
-                "request_log": {
-                    "dir": reqlog_dir,
-                    "records": len(all_recs),
-                    "door_records": door_agg["records"],
-                    "p50_s": door_agg["p50_s"],
-                    "p99_s": door_agg["p99_s"],
-                    "by_status": door_agg["by_status"],
-                    "by_tier": door_agg["by_tier"],
-                },
-                "errors": errors[:5],
-            }
-            if trace_block is not None:
-                report["trace"] = trace_block
-            print(json.dumps(report))
-        finally:
-            uninstall()
-            door.close()
-            _reap_fleet_peers(procs)
-    return 1 if errors else 0
-
-
-def _serve_bench_diurnal(args: argparse.Namespace) -> int:
-    """``serve-bench --diurnal`` (ISSUE 17 tentpole #4): day-shaped
-    load at accelerated clock over a REAL fleet with the ELASTIC
-    controller in the loop.  Each cycle is one diurnal swing: a peak
-    burst that should page the burn-rate evaluator into a scale-out
-    (warm handoff → membership flip; forced through the manual lever
-    when the rig serves the peak inside the SLO, and the report says
-    which lever moved), a post-resize probe that pins the hit-rate
-    within 10% of the pre-resize probe, then a trough of idle
-    controller ticks that drains the coldest peer back out.  The
-    report asserts what the acceptance gates on: SLO attainment
-    through all the resizes, the hit-rate bound per cycle, and ZERO
-    requests routed to a departed peer."""
-    import math
-    import os
-    import random
-    import tempfile
-    import threading
-    import time as _time
-
-    from blit.monitor import BurnRateEvaluator, SLObjective
-    from blit.observability import HistogramStats, Timeline
-    from blit.serve import Overloaded, ProductRequest
-    from blit.serve.elastic import FleetController
-    from blit.serve.fleet import FleetError, FleetFrontDoor
-    from blit.serve.http import http_json, install_drain_handler
-    from blit.serve.scheduler import DeadlineExpired
-    from blit.testing import synth_raw
-
-    rng = random.Random(args.seed)
-    tl = Timeline()
-    cycles = max(1, args.cycles)
-    standbys = args.standbys if args.standbys is not None else cycles
-    report: dict = {"diurnal": True, "cycles": cycles,
-                    "peers": args.peers, "standbys": standbys,
-                    "replicas": args.replicas, "distinct": args.distinct,
-                    "clients": args.clients, "zipf_s": args.zipf_s}
-    ok = False
-    with tempfile.TemporaryDirectory(prefix="blit-diurnal-") as td:
-        ntime = (8 + 3) * args.nfft  # 8 PFB frames at ntap=4
-        reqs = []
-        for i in range(args.distinct):
-            path = os.path.join(td, f"bench{i:03d}.raw")
-            synth_raw(path, nblocks=1, obsnchan=2, ntime_per_block=ntime,
-                      seed=i)
-            reqs.append(ProductRequest(raw=path, nfft=args.nfft, nint=1))
-        procs, peers, lease_dir = _spawn_fleet_peers(
-            td, args.peers, concurrency=args.concurrency,
-            queue_depth=args.queue_depth, ram_bytes=args.ram_bytes,
-            standbys=standbys)
-        names = [f"peer{i}" for i in range(args.peers)]
-        standby_names = [f"standby{j}" for j in range(standbys)]
-        proc_of = {nm: procs[i][0]
-                   for i, nm in enumerate(names + standby_names)}
-        door = FleetFrontDoor(
-            {nm: peers[nm] for nm in names}, lease_dir=lease_dir,
-            timeline=tl, replicas=args.replicas,
-            peer_ttl_s=args.peer_ttl, poll_s=min(0.1, args.peer_ttl / 4),
-            hedge_floor_s=args.hedge_floor_ms / 1e3,
-            request_timeout_s=60.0).start()
-        for j, nm in enumerate(standby_names):
-            door.add_standby(nm, peers[nm], proc=args.peers + j)
-
-        def terminate(nm: str) -> None:
-            """The scale-in epilogue: SIGTERM the retired child — the
-            peer's drain handler finishes in-flight work and exits."""
-            p = proc_of.get(nm)
-            if p is not None and p.poll() is None:
-                p.terminate()
-
-        uninstall = install_drain_handler(lambda: door.drain())
-        weights = [1.0 / math.pow(k + 1, args.zipf_s)
-                   for k in range(args.distinct)]
-        slo_s = args.slo_ms / 1e3
-        lat = HistogramStats()
-        lock = threading.Lock()
-        counts = {"issued": 0, "served": 0, "attained": 0,
-                  "rejected": 0, "expired": 0}
-        errors: list = []
-
-        def run_burst(n: int, record: bool = True) -> None:
-            picks = rng.choices(range(args.distinct), weights=weights,
-                                k=n)
-            it = iter(picks)
-
-            def worker(cid: int) -> None:
-                while True:
-                    with lock:
-                        k = next(it, None)
-                    if k is None:
-                        return
-                    t = _time.perf_counter()
-                    got, err = False, None
-                    for _attempt in range(4):
-                        try:
-                            door.get(reqs[k], client=f"diurnal{cid}")
-                            got = True
-                            break
-                        except DeadlineExpired:
-                            with lock:
-                                counts["expired"] += 1
-                            break
-                        except Overloaded as e:
-                            with lock:
-                                counts["rejected"] += 1
-                            _time.sleep(min(0.25, e.retry_after_s))
-                        except (FleetError, OSError) as e:
-                            # Transient while a flip/eject settles:
-                            # back off a beat and retry, like a real
-                            # client's loop.
-                            err = repr(e)
-                            _time.sleep(0.2)
-                        except Exception as e:  # noqa: BLE001
-                            err = repr(e)
-                            break
-                    if not got and err is not None:
-                        with lock:
-                            errors.append(err)
-                    if not record:
-                        continue
-                    dt = _time.perf_counter() - t
-                    lat.observe(dt)
-                    with lock:
-                        counts["issued"] += 1
-                        if got:
-                            counts["served"] += 1
-                            if dt <= slo_s:
-                                counts["attained"] += 1
-
-            threads = [threading.Thread(target=worker, args=(c,))
-                       for c in range(args.clients)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-
-        def cache_totals() -> dict:
-            out = {}
-            for nm, p in sorted(door._peers.items()):
-                try:
-                    _, _, s = http_json("GET", p.url, "/stats",
-                                        timeout=2.0, pool=door.pool)
-                except OSError:
-                    continue
-                c = s.get("cache") or {}
-                out[nm] = (c.get("hit.ram", 0) + c.get("hit.disk", 0),
-                           c.get("miss", 0))
-            return out
-
-        def window_hit_rate(before: dict, after: dict):
-            dh = dm = 0
-            for nm, (h1, m1) in after.items():
-                if nm not in before:
-                    continue
-                h0, m0 = before[nm]
-                dh += max(0, h1 - h0)
-                dm += max(0, m1 - m0)
-            return (dh / (dh + dm)) if dh + dm else None
-
-        peak_n = max(16, args.requests // 2)
-        probe_n = max(12, args.requests // 4)
-        tick_s = 30.0  # the accelerated clock: one tick "is" 30s of day
-        forced = {"out": 0, "in": 0}
-        departed: dict = {}
-        cyc_reports: list = []
-        ctl = None
-        try:
-            # Untimed warm-up: first-touch XLA compiles and cache fills
-            # land OUTSIDE the SLO ledger, like a deployment bring-up.
-            run_burst(args.requests, record=False)
-            ev = BurnRateEvaluator(
-                [SLObjective("fleet-latency", "fleet.request_s",
-                             args.burn_threshold_ms / 1e3, budget=0.05)],
-                fast_window=2, slow_window=4, fast_burn=4.0,
-                slow_burn=2.0)
-            ctl = FleetController(
-                door, ev, feed=tl, terminate=terminate,
-                idle_windows=args.idle_windows,
-                hysteresis_s=args.hysteresis,
-                warm_timeout_s=args.warm_timeout,
-                min_peers=args.peers, poll_s=0.5)
-            # Prime the feed baseline so the warm-up's latencies are
-            # not the first tick's delta — the day starts NOW.
-            ctl._feed_state = tl.state()
-            t0 = _time.perf_counter()
-            for c in range(cycles):
-                ring_pre = sorted(door.ring.peers())
-                # Pre-resize probe: the hit-rate the flip must not
-                # crater (caches are warm from the previous swing).
-                a0 = cache_totals()
-                run_burst(probe_n)
-                a1 = cache_totals()
-                hit_pre = window_hit_rate(a0, a1)
-                # -- PEAK: the day's load pages the evaluator.
-                out_rec = None
-                for _ in range(4):
-                    run_burst(peak_n)
-                    act = ctl.observe(interval_s=tick_s)
-                    if act is not None and act["action"] == "scale-out":
-                        out_rec = act
-                        break
-                organic_out = out_rec is not None
-                if out_rec is None:
-                    # A fast rig can serve the whole peak inside the
-                    # SLO; force the flip so the resize contract is
-                    # still exercised — the report says which lever.
-                    out_rec = ctl.scale_out()
-                    if out_rec is not None:
-                        forced["out"] += 1
-                # Post-resize probe: the warm-handoff dividend.
-                b0 = cache_totals()
-                run_burst(probe_n)
-                b1 = cache_totals()
-                hit_post = window_hit_rate(b0, b1)
-                hit_ok = (hit_pre is not None and hit_post is not None
-                          and hit_post >= hit_pre - 0.10)
-                # -- TROUGH: sustained idle drains the coldest peer.
-                _time.sleep(args.hysteresis)  # let the flap guard lapse
-                in_rec = None
-                for _ in range(args.idle_windows + 6):
-                    act = ctl.observe(interval_s=tick_s)
-                    if act is not None and act["action"] == "scale-in":
-                        in_rec = act
-                        break
-                organic_in = in_rec is not None
-                if in_rec is None:
-                    in_rec = ctl.scale_in()
-                    if in_rec is not None:
-                        forced["in"] += 1
-                if in_rec is not None:
-                    victim = in_rec["peer"]
-                    departed[victim] = door._peers[victim].requests
-                _time.sleep(args.hysteresis)  # disarm before next peak
-                cyc_reports.append({
-                    "cycle": c,
-                    "ring_pre": ring_pre,
-                    "ring_post": sorted(door.ring.peers()),
-                    "scale_out": out_rec,
-                    "organic_out": organic_out,
-                    "scale_in": in_rec,
-                    "organic_in": organic_in,
-                    "hit_rate_pre_resize": (round(hit_pre, 4)
-                                            if hit_pre is not None
-                                            else None),
-                    "hit_rate_post_resize": (round(hit_post, 4)
-                                             if hit_post is not None
-                                             else None),
-                    "hit_bound_ok": hit_ok,
-                })
-            wall = _time.perf_counter() - t0
-            # ZERO requests to a departed peer: the per-peer request
-            # counter of every retired peer must not have moved since
-            # its retirement.
-            requests_to_departed = sum(
-                max(0, door._peers[nm].requests - snap)
-                for nm, snap in departed.items())
-            attain = (counts["attained"] / counts["issued"]
-                      if counts["issued"] else None)
-            slo_ok = attain is not None and attain >= args.slo_floor
-            resizes_out = sum(1 for r in cyc_reports if r["scale_out"])
-            resizes_in = sum(1 for r in cyc_reports if r["scale_in"])
-            hit_ok_all = all(r["hit_bound_ok"] for r in cyc_reports)
-            fstats = door.stats()
-            cnt = fstats["counters"]
-            rh = tl.hists.get("elastic.resize_s")
-            wb = tl.hists.get("elastic.warm_bytes")
-            ok = (resizes_out >= cycles and resizes_in >= cycles
-                  and slo_ok and hit_ok_all
-                  and requests_to_departed == 0 and not errors)
-            report.update(
-                requests=counts["issued"],
-                served=counts["served"],
-                wall_s=round(wall, 3),
-                slo={"target_s": slo_s,
-                     "attained": (round(attain, 4)
-                                  if attain is not None else None),
-                     "floor": args.slo_floor, "ok": slo_ok},
-                request_p50_s=round(lat.percentile(0.50), 6),
-                request_p99_s=round(lat.percentile(0.99), 6),
-                scale_outs=resizes_out,
-                scale_ins=resizes_in,
-                forced_resizes=forced,
-                requests_to_departed=requests_to_departed,
-                hit_bound_ok=hit_ok_all,
-                cycles_detail=cyc_reports,
-                elastic={
-                    "scale_out": cnt.get("elastic.scale_out", 0),
-                    "scale_in": cnt.get("elastic.scale_in", 0),
-                    "warm_timeout": cnt.get("elastic.warm_timeout", 0),
-                    "flap_suppressed": cnt.get(
-                        "elastic.flap_suppressed", 0),
-                    "resize_p50_s": (round(rh.percentile(0.50), 6)
-                                     if rh is not None else None),
-                    "resize_p99_s": (round(rh.percentile(0.99), 6)
-                                     if rh is not None else None),
-                    "warm_bytes": int(wb.total) if wb is not None else 0,
-                },
-                controller=ctl.stats(),
-                rejected_overloaded=counts["rejected"],
-                deadline_expired=counts["expired"],
-                errors=errors[:5],
-            )
-            # The flat scalar block bench-diff extracts and gates,
-            # exactly like the ingest/archive-day records.
-            report["metrics"] = {
-                "diurnal.cycles": float(len(cyc_reports)),
-                "diurnal.slo_attained": float(attain or 0.0),
-                "diurnal.request_p50_s": report["request_p50_s"],
-                "diurnal.request_p99_s": report["request_p99_s"],
-                "diurnal.scale_out": float(cnt.get(
-                    "elastic.scale_out", 0)),
-                "diurnal.scale_in": float(cnt.get("elastic.scale_in", 0)),
-                "diurnal.warm_timeouts": float(cnt.get(
-                    "elastic.warm_timeout", 0)),
-                "diurnal.requests_to_departed": float(
-                    requests_to_departed),
-                "diurnal.post_resize_min_hit_rate": float(min(
-                    (r["hit_rate_post_resize"] for r in cyc_reports
-                     if r["hit_rate_post_resize"] is not None),
-                    default=0.0)),
-            }
-            report["ok"] = ok
-            body = json.dumps(report)
-            print(body)
-            if args.out:
-                with open(args.out, "w") as f:
-                    f.write(body)
-        finally:
-            uninstall()
-            if ctl is not None:
-                ctl.close()
-            door.close()
-            _reap_fleet_peers(procs)
-    return 0 if ok else 1
-
-
-def _serve_bench_archive_day(args: argparse.Namespace) -> int:
-    """``serve-bench --archive-day`` (ISSUE 16 tentpole #4, extended
-    into the ISSUE 19 archive-plane proof): replay a zipfian
-    MULTI-SESSION observing day at accelerated clock over REAL
-    ``fleet-peer`` subprocesses serving a REAL on-disk archive tree.
-    Every product ask is by-(session, scan, player) and resolves
-    through the door's catalog, peers run hot(+cold) tiered caches
-    with a bounded hot disk (what forces demotion), and
-    ``kind="catalog"`` asks ride the same wire.  Two passes per run —
-    binary then legacy JSON, identical seeds, fresh peer caches — and
-    the report carries catalog-lookup p50/p99, per-tier
-    (ram/wire/disk/cold/derive) rates, SLO attainment against
-    ``--slo-ms``, the wire A/B with a byte-identity pin AND the
-    addressed-vs-explicit-member byte-identity pin.  The record
-    carries ``config.backend`` (the rig) and a flat ``metrics`` dict
-    so ``blit bench-diff`` extracts and gates it exactly like the
-    ingest records."""
-    import math
-    import os
-    import random
-    import tempfile
-    import threading
-    import time as _time
-
-    from blit import monitor
-    from blit.config import DEFAULT
-    from blit.observability import HistogramStats, Timeline
-    from blit.serve import Overloaded, ProductRequest
-    from blit.serve.fleet import FleetFrontDoor
-    from blit.serve.http import http_json, install_drain_handler
-    from blit.serve.scheduler import DeadlineExpired
-    from blit.testing import build_observation_tree
-
-    def q(h, p: float) -> float:
-        return round(h.percentile(p), 6) if h is not None and h.n else 0.0
-
-    players = ((0, 0), (0, 1))
-    slo_s = args.slo_ms / 1e3
-    with tempfile.TemporaryDirectory(prefix="blit-archive-day-") as td:
-        # The day's archive is a REAL on-disk BL tree (ISSUE 19):
-        # --sessions observing sessions x --distinct scans x the
-        # player pair, crawled by every peer's catalog AND the
-        # door's.  Popularity is zipfian along BOTH axes — a few hot
-        # sessions dominate the day and within a session a few hot
-        # scans dominate — which is what makes the warm tiers earn
-        # their bytes.
-        arc = os.path.join(td, "archive")
-        raw_ntime = 6 * args.nfft  # x2 blocks/file = 12 frames' worth
-        scan_names = [f"{i + 1:04d}" for i in range(args.distinct)]
-        sess_names = [f"AGBT25A_999_{s:02d}"
-                      for s in range(args.sessions)]
-        for sess in sess_names:
-            build_observation_tree(
-                arc, sess, scans=tuple(scan_names), players=players,
-                kind="raw", nchans=2, raw_ntime=raw_ntime, nfiles=1)
-        reqs = []   # (addressed request, session, scan)
-        weights = []
-        for s, sess in enumerate(sess_names):
-            for i, scan in enumerate(scan_names):
-                w = 1.0 / (math.pow(s + 1, args.zipf_s)
-                           * math.pow(i + 1, args.zipf_s))
-                for band, bank in players:
-                    reqs.append((ProductRequest(
-                        raw="", session=sess, scan=scan, band=band,
-                        bank=bank, nfft=args.nfft, nint=1),
-                        sess, scan))
-                    weights.append(w)
-        picks = random.Random(args.seed).choices(
-            range(len(reqs)), weights=weights, k=args.requests)
-
-        def one_pass(wire_mode: str, tag: str):
-            """One full day replay on a fresh fleet speaking
-            ``wire_mode``; returns ``(pass_report, probe)`` where
-            ``probe`` is the decoded hottest product for the cross-wire
-            byte-identity pin."""
-            pd = os.path.join(td, tag)
-            os.makedirs(pd, exist_ok=True)
-            tl = Timeline()
-            # Pin the pass's wire on the environment: fleet_defaults
-            # lets ambient BLIT_FLEET_WIRE* override the config, which
-            # would silently turn the A/B into two identical passes.
-            pinned = {"BLIT_FLEET_WIRE": wire_mode,
-                      "BLIT_FLEET_WIRE_DEFLATE": "1" if args.deflate
-                      else "0",
-                      "BLIT_REQUEST_LOG": args.request_log or ""}
-            prev = {k: os.environ.get(k) for k in pinned}
-            os.environ.update(pinned)
-            procs, peers, lease_dir = _spawn_fleet_peers(
-                pd, args.peers, concurrency=args.concurrency,
-                queue_depth=args.queue_depth, ram_bytes=args.ram_bytes,
-                extra_env=pinned, catalog_root=arc, cold_dirs=True,
-                disk_bytes=args.disk_bytes)
-            try:
-                door = FleetFrontDoor(
-                    peers, lease_dir=lease_dir, timeline=tl,
-                    replicas=args.replicas, peer_ttl_s=args.peer_ttl,
-                    poll_s=min(0.1, args.peer_ttl / 4),
-                    hedge_floor_s=args.hedge_floor_ms / 1e3,
-                    request_timeout_s=60.0,
-                    config=DEFAULT.with_(
-                        fleet_wire=wire_mode, catalog_root=arc,
-                        request_log_dir=args.request_log)).start()
-            finally:
-                for k, v in prev.items():
-                    if v is None:
-                        os.environ.pop(k, None)
-                    else:
-                        os.environ[k] = v
-            uninstall = install_drain_handler(lambda: door.drain())
-            lat = HistogramStats()
-            lock = threading.Lock()
-            rejected = [0]
-            delivered = [0]  # decoded product bytes handed to clients
-            slo_ok = [0]
-            nprod = [0]
-            catalog_asks = [0]
-            errors: list = []
-            it = iter(enumerate(picks))
-
-            def client_loop(cid: int) -> None:
-                while True:
-                    with lock:
-                        nk = next(it, None)
-                    if nk is None:
-                        return
-                    n, k = nk
-                    req, sess, scan = reqs[k]
-                    if n % 16 == 0:
-                        # Every 16th slot also asks the CATALOG about
-                        # the scan it is about to fetch — the
-                        # archive-plane control queries ride the same
-                        # wire and feed the same catalog.lookup_s
-                        # histogram as door-side resolution.
-                        try:
-                            door.get(ProductRequest(
-                                kind="catalog",
-                                raw=f"{sess}/{scan}"),
-                                client=f"client{cid}")
-                            with lock:
-                                catalog_asks[0] += 1
-                        except Exception as e:  # noqa: BLE001
-                            with lock:
-                                errors.append(f"catalog: {e!r}")
-                    t = _time.perf_counter()
-                    ok = False
-                    try:
-                        _, d = door.get(req, client=f"client{cid}")
-                        ok = True
-                        with lock:
-                            delivered[0] += d.nbytes
-                    except (Overloaded, DeadlineExpired) as e:
-                        with lock:
-                            rejected[0] += 1
-                        if isinstance(e, Overloaded):
-                            _time.sleep(min(0.25, e.retry_after_s))
-                    except Exception as e:  # noqa: BLE001 — reported
-                        with lock:
-                            errors.append(repr(e))
-                    dur = _time.perf_counter() - t
-                    lat.observe(dur)
-                    with lock:
-                        nprod[0] += 1
-                        if ok and dur <= slo_s:
-                            slo_ok[0] += 1
-
-            try:
-                t0 = _time.perf_counter()
-                threads = [threading.Thread(target=client_loop,
-                                            args=(c,))
-                           for c in range(args.clients)]
-                for t in threads:
-                    t.start()
-                for t in threads:
-                    t.join()
-                wall = _time.perf_counter() - t0
-                # Byte-identity probes on the day's hottest product:
-                # (1) decoded through THIS pass's wire for the
-                # cross-wire pin, (2) addressed-by-(session, scan,
-                # player) vs explicit member paths — the ISSUE 19
-                # catalog-resolution acceptance.
-                probe = None
-                addr_identical = None
-                try:
-                    req0, sess0, scan0 = reqs[0]
-                    ph, pdata = door.get(req0, client="probe")
-                    probe = (dict(ph), pdata.dtype.str,
-                             tuple(pdata.shape), pdata.tobytes())
-                    members = door.catalog.resolve(
-                        sess0, scan0, band=req0.band, bank=req0.bank)
-                    _, edata = door.get(
-                        ProductRequest(raw=tuple(members),
-                                       nfft=args.nfft, nint=1),
-                        client="probe-explicit")
-                    addr_identical = (
-                        pdata.dtype == edata.dtype
-                        and pdata.shape == edata.shape
-                        and pdata.tobytes() == edata.tobytes())
-                except Exception as e:  # noqa: BLE001 — reported
-                    errors.append(f"probe: {e!r}")
-                tiers = {"hit.ram": 0, "hit.disk": 0, "hit.wire": 0,
-                         "hit.cold": 0, "derive": 0, "miss": 0}
-                platforms = set()
-                for _name, url in sorted(peers.items()):
-                    try:
-                        _, _, s = http_json("GET", url, "/stats",
-                                            timeout=5.0)
-                    except OSError:
-                        continue
-                    platforms.add(s.get("platform"))
-                    cst = (s.get("cache") or {})
-                    for k in tiers:
-                        tiers[k] += int(cst.get(k, 0))
-                # The peers' serialize histogram rides their /snapshot
-                # endpoints (merged across the fleet); deserialize and
-                # wire bytes live on the door's own timeline.
-                _, peer_hists = monitor.gather_trace_sources(
-                    list(peers.values()))
-                ser = peer_hists.get("fleet.serialize_s")
-                de = tl.hists.get("fleet.deserialize_s")
-                wire_h = tl.hists.get("fleet.wire_bytes")
-                wire_bytes = float(wire_h.total) if wire_h else 0.0
-                served = (tiers["hit.ram"] + tiers["hit.disk"]
-                          + tiers["hit.cold"])
-                total = served + tiers["miss"]
-                ch = tl.hists.get("catalog.lookup_s")
-                c = door.stats()["counters"]
-                rep = {
-                    "wire": wire_mode,
-                    # The rig label comes from the PEERS (the processes
-                    # that hold a device): this parent stays off JAX so
-                    # it never takes a chip a peer needs.
-                    "platform": ",".join(sorted(map(str, platforms))),
-                    "wall_s": round(wall, 3),
-                    "rps": (round(args.requests / wall, 1)
-                            if wall else None),
-                    "tiers": tiers,
-                    "hit_rate": (round(served / total, 4)
-                                 if total else 0.0),
-                    # wire_bytes is what moved on the socket (base64
-                    # inflates the JSON pass ~4/3); wire_gbps is the
-                    # USEFUL throughput — decoded product bytes
-                    # delivered to clients per wall second, the number
-                    # the two wires compete on.
-                    "wire_bytes": int(wire_bytes),
-                    "delivered_bytes": delivered[0],
-                    "wire_gbps": (round(delivered[0] / wall / 1e9, 6)
-                                  if wall else 0.0),
-                    "request_p50_s": q(lat, 0.50),
-                    "request_p99_s": q(lat, 0.99),
-                    "serialize_p50_s": q(ser, 0.50),
-                    "serialize_p99_s": q(ser, 0.99),
-                    "deserialize_p50_s": q(de, 0.50),
-                    "deserialize_p99_s": q(de, 0.99),
-                    "catalog_lookup_p50_s": q(ch, 0.50),
-                    "catalog_lookup_p99_s": q(ch, 0.99),
-                    "catalog_asks": catalog_asks[0],
-                    "slo_attained": (round(slo_ok[0] / nprod[0], 4)
-                                     if nprod[0] else 0.0),
-                    "addressing_byte_identical": addr_identical,
-                    "door": {
-                        "binary_responses": c.get("fleet.wire.binary",
-                                                  0),
-                        "json_responses": c.get("fleet.wire.json", 0),
-                        "pool_open": c.get("fleet.pool.open", 0),
-                        "pool_reuse": c.get("fleet.pool.reuse", 0),
-                        "pool_evict": c.get("fleet.pool.evict", 0),
-                    },
-                    "rejected": rejected[0],
-                    "errors": errors[:5],
-                }
-                return rep, probe
-            finally:
-                uninstall()
-                door.close()
-                _reap_fleet_peers(procs)
-
-        bin_rep, bin_probe = one_pass("binary", "binary")
-        json_rep, json_probe = one_pass("json", "legacy")
-        byte_identical = (bin_probe is not None
-                          and bin_probe == json_probe)
-        addressing_ok = (bin_rep["addressing_byte_identical"] is True
-                         and json_rep["addressing_byte_identical"]
-                         is True)
-        speedup = (json_rep["wall_s"] / bin_rep["wall_s"]
-                   if bin_rep["wall_s"] else 0.0)
-        # The accelerated-clock framing: the replay IS the day's
-        # zipfian ask stream compressed into wall_s, so the modeled
-        # archive-day request count is requests x (86400 / wall_s) —
-        # the number the catalog/tier quantiles were measured under.
-        accel = (86400.0 / bin_rep["wall_s"] if bin_rep["wall_s"]
-                 else 0.0)
-        bt = bin_rep["tiers"]
-        t_total = (bt["hit.ram"] + bt["hit.disk"] + bt["hit.wire"]
-                   + bt["hit.cold"] + bt["miss"])
-
-        def tier_rate(k: str) -> float:
-            return round(bt[k] / t_total, 4) if t_total else 0.0
-
-        report = {
-            "serve_bench": "archive-day",
-            "requests": args.requests,
-            "sessions": args.sessions,
-            "scans_per_session": args.distinct,
-            "distinct": args.sessions * args.distinct * len(players),
-            "clients": args.clients,
-            "peers": args.peers,
-            "replicas": args.replicas,
-            "zipf_s": args.zipf_s,
-            "seed": args.seed,
-            "slo_ms": args.slo_ms,
-            "clock_accel": round(accel, 1),
-            "modeled_day_requests": int(args.requests * accel),
-            "config": {"backend": bin_rep["platform"], "nfft": args.nfft,
-                       "peers": args.peers,
-                       "deflate": bool(args.deflate),
-                       "disk_bytes": args.disk_bytes},
-            "binary": bin_rep,
-            "legacy_json": json_rep,
-            "ab": {
-                "byte_identical": byte_identical,
-                "addressing_byte_identical": addressing_ok,
-                "wire_speedup": round(speedup, 4),
-                "binary_wall_s": bin_rep["wall_s"],
-                "json_wall_s": json_rep["wall_s"],
-                "binary_wire_gbps": bin_rep["wire_gbps"],
-                "json_wire_gbps": json_rep["wire_gbps"],
-            },
-            # The flat gate surface: bench-diff reads exactly these
-            # (throughput/hit-rate/attainment band up,
-            # latency-quantile band inverted).  tier_derive_rate is
-            # report-only — a RISING derive rate is a regression, so
-            # it must not ride the higher-is-better extractor.
-            "metrics": {
-                "fleet_hit_rate": bin_rep["hit_rate"],
-                "fleet_wire_gbps": bin_rep["wire_gbps"],
-                "wire_speedup": round(speedup, 4),
-                "fleet_request_p50_s": bin_rep["request_p50_s"],
-                "fleet_request_p99_s": bin_rep["request_p99_s"],
-                "fleet_serialize_p99_s": bin_rep["serialize_p99_s"],
-                "fleet_deserialize_p99_s":
-                    bin_rep["deserialize_p99_s"],
-                "catalog_lookup_p50_s":
-                    bin_rep["catalog_lookup_p50_s"],
-                "catalog_lookup_p99_s":
-                    bin_rep["catalog_lookup_p99_s"],
-                "tier_ram_hit_rate": tier_rate("hit.ram"),
-                "tier_disk_hit_rate": tier_rate("hit.disk"),
-                "tier_wire_hit_rate": tier_rate("hit.wire"),
-                "tier_cold_hit_rate": tier_rate("hit.cold"),
-                "tier_derive_rate": (round(bt["derive"] / t_total, 4)
-                                     if t_total else 0.0),
-                "slo_attained": bin_rep["slo_attained"],
-            },
-            "errors": (bin_rep["errors"] + json_rep["errors"])[:5],
-        }
-        if args.request_log:
-            # The archive access log: door records carry the LOGICAL
-            # (session, scan) address, so `blit requests --aggregate`
-            # groups a day's traffic per scan (ISSUE 19 satellite).
-            recs = monitor.read_requests(args.request_log)
-            report["request_log"] = monitor.aggregate_requests(recs)
-        out = json.dumps(report)
-        print(out)
-        if args.out:
-            tmp = args.out + ".tmp"
-            with open(tmp, "w") as f:
-                f.write(out + "\n")
-            os.replace(tmp, args.out)
-    if report["errors"]:
-        return 1
-    return 0 if (byte_identical and addressing_ok) else 1
 
 
 def _monitor_from_flags(args: argparse.Namespace):
@@ -1898,385 +688,6 @@ def _monitor_from_flags(args: argparse.Namespace):
     if pub.port is not None:
         print(f"# monitor: {pub.url}/metrics", file=sys.stderr)
     return pub
-
-
-def _cmd_ingest_bench(args: argparse.Namespace) -> int:
-    """File→product throughput probe for the asynchronous output plane
-    (ISSUE 4): reduce a synthetic RAW recording to a real on-disk product
-    and print the per-stage table — including the new ``readback`` and
-    ``write`` stages — plus the overlap-efficiency gauge, optionally
-    A/B'ing against the fully synchronous path (``--sync-compare``).
-    This is the table an operator reads when a deployment's end-to-end
-    rate collapses below the kernel rate (docs/WORKFLOWS.md "Diagnosing
-    a slow link")."""
-    import os
-    import tempfile
-    import time as _time
-
-    from blit.outplane import INGEST_HISTS
-
-    from blit.pipeline import RawReducer
-    from blit.testing import synth_raw
-
-    def run(async_output: bool) -> dict:
-        red = RawReducer(nfft=args.nfft, nint=args.nint,
-                         chunk_frames=args.chunk_frames,
-                         fqav_by=args.fqav, dtype=args.dtype,
-                         nbits=args.nbits, quant_scale=args.quant_scale,
-                         async_output=async_output, tune_online=False)
-        out = os.path.join(td, "bench_async.fil" if async_output
-                           else "bench_sync.fil")
-        t0 = _time.perf_counter()
-        red.reduce_to_file(raw_path, out)
-        wall = _time.perf_counter() - t0
-        tl = red.timeline
-        return {
-            "async_output": async_output,
-            "wall_s": round(wall, 3),
-            "ingest_gbps": round(file_bytes / wall / 1e9, 3),
-            "overlap_efficiency": round(tl.overlap_efficiency(), 3),
-            "stages": {
-                k: {"calls": v.calls, "s": round(v.seconds, 4),
-                    "bytes": v.bytes}
-                for k, v in sorted(list(tl.stages.items()))
-            },
-            # Stage TAILS from the telemetry hists (ISSUE 8 satellite):
-            # readback lag / per-append write / per-chunk service
-            # latency p50/p99 — the burst an average hides.
-            "stage_quantiles": tl.hist_quantiles(INGEST_HISTS),
-            # Per-chunk latency distributions (out.chunk_latency_s /
-            # out.readback_lag_s — ISSUE 5): the tails behind the stage
-            # sums above.
-            "hists": tl.report().get("hists", {}),
-            "product_bytes": os.path.getsize(out),
-        }
-
-    def run_dedoppler() -> dict:
-        """The science leg (ISSUE 6): the same recording through the
-        search plane — RAW → windowed spectra → on-device Taylor tree →
-        ``.hits`` — reporting drift-rate trials/s alongside the ingest
-        rate (a drift trial = one (drift row, channel) cell scored)."""
-        from blit.search import DedopplerReducer
-
-        red = DedopplerReducer(
-            nfft=args.nfft, nint=args.nint,
-            chunk_frames=args.chunk_frames, dtype=args.dtype,
-            window_spectra=args.dedoppler_window, snr_threshold=5.0,
-        )
-        out = os.path.join(td, "bench.hits")
-        t0 = _time.perf_counter()
-        hdr = red.search_to_file(raw_path, out)
-        wall = _time.perf_counter() - t0
-        T = hdr["search_window_spectra"]
-        windows = hdr.get("search_windows", 0)
-        trials = (2 * T - 1) * hdr["nchans"] * windows
-        tl = red.timeline
-        return {
-            "windows": windows,
-            "window_spectra": T,
-            "hits": hdr.get("search_nhits"),
-            "wall_s": round(wall, 3),
-            "ingest_gbps": round(file_bytes / wall / 1e9, 4),
-            "drift_rates_per_s": round(trials / wall, 1),
-            "hists": tl.report().get("hists", {}),
-            "product_bytes": os.path.getsize(out),
-        }
-
-    def run_live(drill: bool) -> dict:
-        """The live leg (ISSUE 7): replay the recording through the
-        streaming ingest plane at ``--live-rate`` × wall-clock recording
-        rate and report p50/p99 chunk→product latency.  The recording is
-        re-synthesized with TBIN stretched so it SPANS ``--live-seconds``
-        of wall time — replay pacing is meaningless on a microsecond
-        recording.  ``drill=True`` is the seeded late-chunk drill: one
-        chunk held past a tightened lateness budget, proving the product
-        masks (and flight-records) instead of wedging.
-
-        With ``--packets`` (ISSUE 18) the replay goes through the
-        PACKET front end — the recording framed as datagrams, with the
-        ``--packet-drop``/``--packet-reorder``/``--packet-dup``
-        schedules applied — so the leg measures the sustained-capture
-        contract: 1× for the whole session, back-pressure shedding as
-        masked gaps (counted in the report), never a stall.  The stall
-        watchdog is ARMED, so a completed leg IS the zero-stall proof
-        (``stalls`` would have been a raised incident, not a number)."""
-        from blit.observability import Timeline
-        from blit.stream import (
-            PacketReplaySource,
-            ReplaySource,
-            stream_reduce,
-        )
-
-        packets = bool(getattr(args, "packets", False))
-        nblocks = max(4, args.blocks)
-        ntime = (args.chunks * args.chunk_frames + 3) * args.nfft
-        per_block = -(-ntime // nblocks)
-        live_raw = os.path.join(td, "live.raw")
-        synth_raw(live_raw, nblocks=nblocks, obsnchan=args.nchan,
-                  ntime_per_block=per_block,
-                  tbin=args.live_seconds / (nblocks * per_block))
-        tl = Timeline()
-        red = RawReducer(nfft=args.nfft, nint=args.nint,
-                         chunk_frames=args.chunk_frames, fqav_by=args.fqav,
-                         dtype=args.dtype, timeline=tl, tune_online=False)
-        lateness = None
-        late = {}
-        if drill:
-            # Chunk 1 arrives well past a tightened budget: it must be
-            # masked (zero weight) while the stream keeps flowing.
-            lateness = 0.02 * args.live_seconds
-            late = {1: 0.8 * args.live_seconds}
-        if packets:
-            src = PacketReplaySource(
-                live_raw, rate=args.live_rate,
-                packet_ntime=args.packet_ntime,
-                drop=(args.packet_drop or None),
-                reorder=args.packet_reorder, dup=args.packet_dup,
-                seed=0, timeline=tl)
-            # The sustained-capture leg must complete masked, not
-            # wedged: a whole-stream lateness stall would hide behind
-            # the default budget, so bound it by the recording span.
-            lateness = lateness or 0.25 * args.live_seconds
-        else:
-            src = ReplaySource(live_raw, rate=args.live_rate, late=late)
-        out = os.path.join(td, "live_drill.fil" if drill else "live.fil")
-        t0 = _time.perf_counter()
-        hdr = stream_reduce(src, out, reducer=red, lateness_s=lateness,
-                            stall_timeout_s=max(5.0,
-                                                2 * args.live_seconds))
-        wall = _time.perf_counter() - t0
-        lat = tl.report().get("hists", {}).get(
-            "stream.chunk_to_product_s", {})
-        leg = {
-            "rate": args.live_rate,
-            "recording_s": round(args.live_seconds, 3),
-            "wall_s": round(wall, 3),
-            "chunks": hdr["stream_chunks"],
-            "chunk_to_product_p50_s": lat.get("p50"),
-            "chunk_to_product_p99_s": lat.get("p99"),
-            "late_chunks": hdr["stream_late_chunks"],
-            "dup_chunks": hdr["stream_dup_chunks"],
-            "masked_chunks": hdr["stream_masked_chunks"],
-            # Output spectra whose PFB windows touched a zero-filled
-            # sample — the clean path must report 0 here.
-            "degraded_spectra": hdr["stream_degraded_spectra"],
-            "product_bytes": os.path.getsize(out),
-            # The armed watchdog raised on any stall, so reaching this
-            # line proves zero.
-            "stalls": 0,
-        }
-        if packets:
-            leg["packet"] = src.packet_report()
-        if hdr.get("stream_flight_dump"):
-            leg["flight_dump"] = hdr["stream_flight_dump"]
-        return leg
-
-    def run_chaos() -> dict:
-        """The recovery leg (ISSUE 12): a live consumer is SIGKILLed
-        mid-session by a seeded ``stream.chunk:kill`` fault, the
-        :class:`blit.recover.StreamSupervisor` detects the death and
-        restarts it with ``resume=True`` (StreamCursor rejoin), and the
-        leg reports detection latency (``recover.detect_s``), recovery
-        time (``recover.resume_s``), the frames the rejoin recomputed,
-        and product byte-identity against the batch oracle."""
-        from blit.observability import Timeline
-        from blit.recover import StreamSupervisor
-        from blit.stream import StreamCursor
-
-        nblocks = max(4, args.blocks)
-        ntime = (args.chunks * args.chunk_frames + 3) * args.nfft
-        chaos_raw = os.path.join(td, "chaos.raw")
-        synth_raw(chaos_raw, nblocks=nblocks, obsnchan=args.nchan,
-                  ntime_per_block=-(-ntime // nblocks))
-        oracle = os.path.join(td, "chaos_oracle.fil")
-        RawReducer(nfft=args.nfft, nint=args.nint,
-                   chunk_frames=args.chunk_frames, fqav_by=args.fqav,
-                   dtype=args.dtype,
-                   tune_online=False).reduce_to_file(chaos_raw, oracle)
-        out = os.path.join(td, "chaos.fil")
-        tl = Timeline()
-        sup = StreamSupervisor(
-            chaos_raw, out, kind="reduce",
-            knobs=dict(nfft=args.nfft, nint=args.nint,
-                       chunk_frames=args.chunk_frames,
-                       fqav_by=args.fqav, dtype=args.dtype,
-                       tune_online=False),
-            replay_rate=args.chaos_rate,
-            faults=f"stream.chunk:kill:after={args.chaos_after}",
-            lease_ttl_s=3.0, poll_s=0.05, timeline=tl,
-        )
-        import filecmp
-
-        t0 = _time.perf_counter()
-        rep = _chaos_run(sup)  # a failed drill becomes a failed LEG
-        wall = _time.perf_counter() - t0
-        try:
-            identical = filecmp.cmp(out, oracle, shallow=False)
-        except OSError:
-            identical = False
-        hists = tl.report().get("hists", {})
-        cur = StreamCursor.load(out)  # removed on clean completion
-        frames_claimed_at_crash = None
-        for a in rep.get("attempts", []):
-            if not a.get("ok", True):
-                frames_claimed_at_crash = a.get("failure", {})
-        return {
-            "wall_s": round(wall, 3),
-            "recovered": rep.get("recovered"),
-            "attempts": len(rep.get("attempts", [])),
-            "products_identical": identical,
-            "cursor_removed": cur is None,
-            "detect": hists.get("recover.detect_s", {}),
-            "resume": hists.get("recover.resume_s", {}),
-            "failure": frames_claimed_at_crash,
-        }
-
-    # --chunk-frames 0 (or negative) = auto: resolve from this rig's
-    # tuning profile (blit/tune.py) exactly as `blit reduce` would; the
-    # probe's provenance is embedded in the report's ingest_config.
-    if args.chunk_frames is not None and args.chunk_frames <= 0:
-        args.chunk_frames = None
-    # tune_online=False throughout the bench: a converged OnlineTuner
-    # persisting mid-run (warmup is exactly its warmup window) would
-    # reshape later legs' knobs AFTER this probe resolved the published
-    # provenance — the A/B legs and ingest_config must describe ONE
-    # knob set, like _cmd_tune's measured sweeps.
-    probe = RawReducer(nfft=args.nfft, nint=args.nint,
-                       chunk_frames=args.chunk_frames, fqav_by=args.fqav,
-                       dtype=args.dtype, nbits=args.nbits,
-                       tune_online=False)
-    args.chunk_frames = probe.chunk_frames
-
-    # Live monitoring (ISSUE 11): --monitor-spool / --monitor-port start
-    # the process publisher, so `blit top` (or a curl at /metrics) can
-    # watch this bench while it runs — the CI monitor smoke rides this.
-    pub = _monitor_from_flags(args)
-
-    with tempfile.TemporaryDirectory(prefix="blit-ingest-bench-") as td:
-        raw_path = os.path.join(td, "bench.raw")
-        # File length leaves exactly the (ntap-1)*nfft PFB tail after the
-        # last chunk so no flush-shape recompile triggers (bench.py rule).
-        ntime = (args.chunks * args.chunk_frames + 3) * args.nfft
-        _, blocks = synth_raw(raw_path, nblocks=args.blocks,
-                              obsnchan=args.nchan,
-                              ntime_per_block=-(-ntime // args.blocks))
-        file_bytes = sum(b.nbytes for b in blocks)
-        if args.digests:
-            # The integrity A/B (ISSUE 13 acceptance): every leg then
-            # ingests through per-block digest verification — the
-            # reported rates must sit inside the bench-diff noise band
-            # of an unarmed run.
-            from blit import integrity
-
-            integrity.write_raw_digests(raw_path)
-        # Untimed warmup: compile the channelizer (and fault the product
-        # path's buffers) so the timed legs measure steady-state
-        # streaming, not the one-off jit compile.
-        RawReducer(nfft=args.nfft, nint=args.nint,
-                   chunk_frames=args.chunk_frames, fqav_by=args.fqav,
-                   dtype=args.dtype, nbits=args.nbits,
-                   quant_scale=args.quant_scale,
-                   tune_online=False).reduce_to_file(
-            raw_path, os.path.join(td, "warmup.fil"))
-        legs = [run(True)]
-        if args.sync_compare:
-            legs.append(run(False))
-        report = {
-            "file_bytes": file_bytes,
-            # The knob set every leg ran, with tuning provenance (ISSUE 8
-            # satellite: the BENCH table names the profile behind it).
-            "ingest_config": {
-                "nfft": args.nfft, "nint": args.nint, "nchan": args.nchan,
-                "chunk_frames": args.chunk_frames,
-                "prefetch_depth": probe.prefetch_depth,
-                "out_depth": probe.out_depth, "dtype": args.dtype,
-                "nbits": args.nbits, "digests": bool(args.digests),
-                "tuning": probe.tuning_provenance(),
-            },
-            "legs": legs,
-        }
-        if args.dedoppler:
-            report["dedoppler"] = run_dedoppler()
-        if args.live:
-            report["live"] = run_live(False)
-        if args.live_drill:
-            report["live_drill"] = run_live(True)
-        if args.chaos:
-            report["chaos"] = run_chaos()
-        if len(legs) == 2 and legs[1]["wall_s"] > 0:
-            from blit.testing import sync_compare_verdict
-
-            report.update(sync_compare_verdict(
-                os.path.join(td, "bench_async.fil"),
-                os.path.join(td, "bench_sync.fil"),
-                async_wall_s=legs[0]["wall_s"],
-                sync_wall_s=legs[1]["wall_s"]))
-        if args.spans_compare:
-            # Tracing-overhead A/B (ISSUE 5 acceptance: always-on spans
-            # must cost <= 1%): interleave spans-on/spans-off legs so slow
-            # drift doesn't masquerade as overhead, and compare the best
-            # wall of each arm (min is the standard noise-floor estimator
-            # for identical repeated work).
-            from blit import observability
-
-            tr = observability.tracer()
-            prev, walls = tr.enabled, {True: [], False: []}
-            try:
-                for _ in range(args.spans_reps):
-                    for enabled in (True, False):
-                        tr.enabled = enabled
-                        walls[enabled].append(run(True)["wall_s"])
-            finally:
-                tr.enabled = prev
-            on, off = min(walls[True]), min(walls[False])
-            report["spans_on_s"] = on
-            report["spans_off_s"] = off
-            report["span_overhead"] = round(on / max(off, 1e-9) - 1.0, 4)
-        if args.history_compare:
-            # History+anomaly overhead A/B (ISSUE 20 acceptance: the
-            # durable store + baselines must cost <= 1% on an ingest
-            # leg) — the --spans-compare discipline: interleaved arms
-            # so slow drift doesn't masquerade as overhead, best wall
-            # per arm.  Each arm runs under a fast-ticking publisher;
-            # the ON arm's publisher also feeds tiered rings and
-            # scores anomaly baselines every tick.
-            import shutil as _shutil
-
-            from blit import monitor as _mon
-            from blit.config import SiteConfig as _SC
-
-            hist_td = os.path.join(td, "hist-ab")
-            hwalls = {True: [], False: []}
-            for _ in range(args.spans_reps):
-                for enabled in (True, False):
-                    if enabled:
-                        _shutil.rmtree(hist_td, ignore_errors=True)
-                        cfg = _SC(history_dir=hist_td,
-                                  history_raw_s=0.5)
-                    else:
-                        cfg = _SC(history_anomaly=False)
-                    p2 = _mon.MetricsPublisher(
-                        interval_s=0.05, spool_dir="", port=-1,
-                        config=cfg).start()
-                    try:
-                        hwalls[enabled].append(run(True)["wall_s"])
-                    finally:
-                        p2.close()
-            hon, hoff = min(hwalls[True]), min(hwalls[False])
-            report["history_on_s"] = hon
-            report["history_off_s"] = hoff
-            report["history_overhead"] = round(
-                hon / max(hoff, 1e-9) - 1.0, 4)
-        if pub is not None:
-            pub.tick()  # a final sample so short benches always spool one
-            report["monitor"] = {"port": pub.port,
-                                 "spool": pub.spool_path,
-                                 "samples": pub.seq}
-            from blit import monitor
-
-            monitor.shutdown_publisher()
-        print(json.dumps(report))
-    return 0
 
 
 def _cmd_tune(args: argparse.Namespace) -> int:
@@ -3503,50 +1914,6 @@ def _cmd_top(args: argparse.Namespace) -> int:
     return 0
 
 
-def _cmd_bench_diff(args: argparse.Namespace) -> int:
-    """``blit bench-diff`` (ISSUE 11 tentpole): the perf-regression
-    gate.  Loads the fresh record and the baseline trajectory (explicit
-    ``--baseline`` files and/or every ``BENCH_*.json`` under
-    ``--baseline-dir``, the fresh file itself excluded), compares every
-    shared higher-is-better metric against the trajectory's noise band,
-    and exits 0 on pass / 2 on regress."""
-    import os
-
-    from blit import monitor
-
-    baselines = []
-    if args.baseline_dir:
-        import glob
-
-        fresh_real = os.path.realpath(args.fresh)
-        for p in sorted(glob.glob(
-                os.path.join(args.baseline_dir, "BENCH_*.json"))):
-            if os.path.realpath(p) == fresh_real:
-                continue
-            try:
-                baselines.append(monitor.load_bench_json(p))
-            except ValueError as e:
-                # A failed round with no record line is part of history;
-                # it thins the trajectory, it doesn't break the gate.
-                print(f"# bench-diff: skipping {p}: {e}",
-                      file=sys.stderr)
-    for p in args.baseline or []:
-        baselines.append(monitor.load_bench_json(p))
-    if not baselines:
-        raise SystemExit("bench-diff needs at least one baseline "
-                         "(--baseline / --baseline-dir)")
-    fresh = monitor.load_bench_json(args.fresh)
-    metrics = args.metrics.split(",") if args.metrics else None
-    verdict = monitor.bench_diff(fresh, baselines, rel_tol=args.noise,
-                                 metrics=metrics,
-                                 cross_rig=args.cross_rig)
-    if args.json:
-        print(json.dumps(verdict))
-    else:
-        print(monitor.render_bench_diff(verdict))
-    return 0 if verdict["verdict"] == "pass" else 2
-
-
 def _cmd_trace_view(args: argparse.Namespace) -> int:
     """Render a flight-recorder dump into an incident summary, or
     (``--fleet``, ISSUE 15) stitch span batches from many processes —
@@ -3689,8 +2056,8 @@ def _cmd_slo_report(args: argparse.Namespace) -> int:
     """``blit slo-report`` (ISSUE 20): attainment + error-budget spend
     per objective over day/week windows, straight from a durable
     history store — text for the operator, ``--json`` for CI (its
-    ``metrics`` block rides ``bench_metrics``/``blit bench-diff``, so
-    attainment gates like any bench scalar)."""
+    flat ``metrics`` block carries one attainment scalar per
+    objective)."""
     from blit.history import (
         HistoryStore,
         render_slo_report,
@@ -3743,7 +2110,7 @@ _PRODUCTS = ("0000", "0001", "0002")
 # Commands that read files and reports only: they never import jax, so they
 # skip the compile-cache set-up (which does).
 _HOST_ONLY = frozenset((
-    "inventory", "info", "top", "bench-diff", "trace-view", "requests",
+    "inventory", "info", "top", "trace-view", "requests",
     "incidents", "incident", "slo-report",
 ))
 
@@ -3999,106 +2366,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     pf.add_argument("file")
     pf.set_defaults(fn=_cmd_info)
 
-    pg = sub.add_parser(
-        "ingest-bench",
-        help="file→product throughput probe of the async output plane "
-             "(per-stage readback/write table + overlap gauge)",
-    )
-    pg.add_argument("--nfft", type=int, default=1024)
-    pg.add_argument("--nint", type=int, default=1)
-    pg.add_argument("--nchan", type=int, default=4)
-    pg.add_argument("--chunk-frames", type=int, default=8)
-    pg.add_argument("--chunks", type=int, default=8,
-                    help="device chunks in the synthetic recording")
-    pg.add_argument("--blocks", type=int, default=4,
-                    help="RAW blocks the recording is split into")
-    pg.add_argument("--fqav", type=int, default=1,
-                    help="on-device frequency averaging (shrinks the "
-                         "product crossing the readback link)")
-    pg.add_argument("--dtype", default="float32",
-                    choices=["float32", "bfloat16"])
-    pg.add_argument("--nbits", type=int, default=32, choices=[8, 16, 32],
-                    help="SIGPROC product quantization: nbits<32 products "
-                         "are narrowed ON DEVICE before D2H (4x/2x fewer "
-                         "bytes across the readback link; byte-identical "
-                         "to the sync path's host quantization)")
-    pg.add_argument("--quant-scale", type=float, default=1.0,
-                    help="affine quantize scale for --nbits 8/16")
-    pg.add_argument("--digests", action="store_true",
-                    help="arm a per-block digest sidecar on the "
-                         "synthetic recording so every leg ingests "
-                         "through integrity verification (ISSUE 13; "
-                         "rates must stay inside the bench-diff noise "
-                         "band of an unarmed run)")
-    pg.add_argument("--sync-compare", action="store_true",
-                    help="also run the fully synchronous output path and "
-                         "report the async speedup")
-    pg.add_argument("--spans-compare", action="store_true",
-                    help="A/B the async leg with spans enabled vs disabled "
-                         "and report the tracing overhead ratio")
-    pg.add_argument("--spans-reps", type=int, default=3,
-                    help="interleaved repetitions per spans-compare / "
-                         "history-compare arm")
-    pg.add_argument("--history-compare", action="store_true",
-                    help="A/B the async leg under a fast-ticking "
-                         "publisher with the history store + anomaly "
-                         "baselines armed vs bare, and report the "
-                         "history overhead ratio (ISSUE 20: <= 1%%)")
-    pg.add_argument("--dedoppler", action="store_true",
-                    help="also run the drift-search science leg over the "
-                         "same recording and report drift-rate trials/s")
-    pg.add_argument("--dedoppler-window", type=int, default=8,
-                    help="search window (spectra per drift transform, "
-                         "power of two) for the --dedoppler leg")
-    pg.add_argument("--live", action="store_true",
-                    help="also replay the recording through the "
-                         "streaming ingest plane at --live-rate and "
-                         "report p50/p99 chunk→product latency "
-                         "(ISSUE 7)")
-    pg.add_argument("--live-rate", type=float, default=1.0,
-                    help="replay speed as a multiple of wall-clock "
-                         "recording rate (1.0 = real time)")
-    pg.add_argument("--live-seconds", type=float, default=0.5,
-                    help="wall-clock span the live recording is "
-                         "stretched to cover (TBIN-scaled)")
-    pg.add_argument("--packets", action="store_true",
-                    help="run the --live leg through the PACKET front "
-                         "end (ISSUE 18): the recording framed as "
-                         "datagrams via PacketReplaySource, gaps "
-                         "masked not stalled; the leg reports the "
-                         "packet gap/reorder/dup counters and block "
-                         "assembly tails beside chunk→product latency")
-    pg.add_argument("--packet-ntime", type=int, default=None,
-                    help="time samples per DATA packet (default "
-                         "SiteConfig/BLIT_PACKET_NTIME)")
-    pg.add_argument("--packet-drop", type=float, default=0.0,
-                    help="seeded fraction of DATA packets dropped in "
-                         "the --packets leg (a partial block becomes a "
-                         "masked gap)")
-    pg.add_argument("--packet-reorder", type=float, default=0.0,
-                    help="seeded fraction of DATA packets deferred out "
-                         "of order in the --packets leg")
-    pg.add_argument("--packet-dup", type=float, default=0.0,
-                    help="seeded fraction of DATA packets duplicated "
-                         "in the --packets leg")
-    pg.add_argument("--live-drill", action="store_true",
-                    help="also run the seeded late-chunk drill: one "
-                         "chunk past a tightened lateness budget must "
-                         "yield a masked (not wedged) product and a "
-                         "flight-recorder dump")
-    pg.add_argument("--chaos", action="store_true",
-                    help="also run the recovery drill (ISSUE 12): "
-                         "SIGKILL a supervised live consumer "
-                         "mid-session, rejoin via the StreamCursor, "
-                         "and report recover.detect_s / "
-                         "recover.resume_s + byte-identity")
-    pg.add_argument("--chaos-after", type=int, default=2,
-                    help="kill the consumer after this many chunks")
-    pg.add_argument("--chaos-rate", type=float, default=200.0,
-                    help="chaos-leg replay speed multiple")
-    _add_monitor_flags(pg)
-    pg.set_defaults(fn=_cmd_ingest_bench)
-
     pn = sub.add_parser(
         "tune",
         help="autotune the ingest knobs on THIS rig and persist the "
@@ -4128,111 +2395,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="repetitions per measurement (best-of; raise on "
                          "noisy rigs)")
     pn.set_defaults(fn=_cmd_tune)
-
-    pb = sub.add_parser(
-        "serve-bench",
-        help="replay a zipfian request mix against a ProductService",
-    )
-    pb.add_argument("--requests", type=int, default=64,
-                    help="total requests to replay")
-    pb.add_argument("--distinct", type=int, default=8,
-                    help="distinct products in the mix")
-    pb.add_argument("--clients", type=int, default=4,
-                    help="concurrent client threads")
-    pb.add_argument("--zipf-s", type=float, default=1.1,
-                    help="zipf exponent of the popularity skew")
-    pb.add_argument("--concurrency", type=int, default=2,
-                    help="scheduler concurrency budget")
-    pb.add_argument("--queue-depth", type=int, default=64,
-                    help="bounded per-priority queue depth")
-    pb.add_argument("--ram-bytes", type=int, default=64 << 20,
-                    help="RAM cache tier byte budget")
-    pb.add_argument("--disk-bytes", type=int, default=None,
-                    help="per-peer HOT disk tier capacity "
-                         "(--archive-day; a bound forces demotion "
-                         "into each peer's cold tier)")
-    pb.add_argument("--nfft", type=int, default=256)
-    pb.add_argument("--seed", type=int, default=0)
-    pb.add_argument("--disk-cache", action="store_true",
-                    help="enable the disk cache tier (tempdir)")
-    pb.add_argument("--fleet", action="store_true",
-                    help="replay through a REAL multi-process fleet "
-                         "front door (ISSUE 14): N fleet-peer "
-                         "subprocesses behind consistent-hash routing")
-    pb.add_argument("--peers", type=int, default=3,
-                    help="fleet peer subprocess count (--fleet)")
-    pb.add_argument("--replicas", type=int, default=2,
-                    help="ring owner-set size R (--fleet)")
-    pb.add_argument("--peer-ttl", type=float, default=3.0,
-                    help="peer heartbeat-lease TTL seconds (--fleet)")
-    pb.add_argument("--slo-ms", type=float, default=500.0,
-                    help="SLO attainment target per request (--fleet)")
-    pb.add_argument("--hedge-floor-ms", type=float, default=50.0,
-                    help="hedge delay before the live p99 exists "
-                         "(--fleet)")
-    pb.add_argument("--deadline", type=float, default=None,
-                    help="per-request deadline_s propagated through "
-                         "the fleet (--fleet)")
-    pb.add_argument("--request-log", default=None, metavar="DIR",
-                    help="per-request access records land here "
-                         "(ISSUE 15; --fleet defaults to a temp spool "
-                         "so the report's p50/p99 always come from the "
-                         "records — point it somewhere to keep them)")
-    pb.add_argument("--request-log-compare", action="store_true",
-                    help="A/B the identical replay with request "
-                         "logging off then on and report the overhead "
-                         "(the --spans-compare discipline; non-fleet)")
-    pb.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="with --fleet: stitch the peers' span batches "
-                         "+ the door's into one Perfetto trace at PATH "
-                         "(plus PATH.snapshot.json for trace-view "
-                         "--fleet)")
-    pb.add_argument("--archive-day", action="store_true",
-                    help="replay a zipfian multi-session observing day "
-                         "over REAL fleet-peer subprocesses, binary "
-                         "wire vs legacy JSON A/B with a byte-identity "
-                         "pin (ISSUE 16); emits a bench-diff-gateable "
-                         "record")
-    pb.add_argument("--sessions", type=int, default=4,
-                    help="observing sessions in the day, each with "
-                         "--distinct products (--archive-day)")
-    pb.add_argument("--deflate", action="store_true",
-                    help="advertise Accept-Encoding: deflate on the "
-                         "binary pass (--archive-day)")
-    pb.add_argument("--out", default=None, metavar="PATH",
-                    help="also write the report JSON here "
-                         "(--archive-day / --diurnal; the CI artifact)")
-    pb.add_argument("--diurnal", action="store_true",
-                    help="day-shaped load at accelerated clock over a "
-                         "REAL fleet + standbys with the ELASTIC "
-                         "controller in the loop (ISSUE 17): peak "
-                         "pages scale-out through a warm handoff, "
-                         "trough idles into a drain + scale-in; the "
-                         "report pins SLO attainment through the "
-                         "resizes and the post-resize hit-rate bound")
-    pb.add_argument("--cycles", type=int, default=3,
-                    help="peak/trough cycles, i.e. scale-out/in pairs "
-                         "(--diurnal)")
-    pb.add_argument("--standbys", type=int, default=None,
-                    help="standby fleet-peer subprocesses to pre-"
-                         "register (--diurnal; default --cycles)")
-    pb.add_argument("--idle-windows", type=int, default=3,
-                    help="consecutive idle controller ticks before "
-                         "scale-in (--diurnal)")
-    pb.add_argument("--hysteresis", type=float, default=2.0,
-                    help="flap-guard cooldown seconds after any resize "
-                         "(--diurnal)")
-    pb.add_argument("--warm-timeout", type=float, default=60.0,
-                    help="warm-handoff ack deadline seconds — the "
-                         "joiner's first XLA compile happens inside it "
-                         "(--diurnal)")
-    pb.add_argument("--burn-threshold-ms", type=float, default=250.0,
-                    help="per-request latency SLO the burn-rate "
-                         "evaluator pages on (--diurnal)")
-    pb.add_argument("--slo-floor", type=float, default=0.5,
-                    help="minimum end-to-end SLO attainment the "
-                         "diurnal leg must hold through the resizes")
-    pb.set_defaults(fn=_cmd_serve_bench)
 
     pfp = sub.add_parser(
         "fleet-peer",
@@ -4501,32 +2663,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "spans")
     po.set_defaults(fn=_cmd_top)
 
-    pd = sub.add_parser(
-        "bench-diff",
-        help="compare a fresh bench.py / ingest-bench JSON against the "
-             "checked-in BENCH_*.json trajectory (exit 2 on regress)",
-    )
-    pd.add_argument("fresh",
-                    help="fresh bench record (plain JSON or a "
-                         "BENCH_*.json wrapper)")
-    pd.add_argument("--baseline", action="append", default=[],
-                    help="baseline record (repeatable)")
-    pd.add_argument("--baseline-dir", default=None,
-                    help="load every BENCH_*.json here as the baseline "
-                         "trajectory (the fresh file itself excluded)")
-    pd.add_argument("--noise", type=float, default=0.35,
-                    help="relative noise band around the trajectory's "
-                         "[min, max] envelope (0.35 = ±35%%)")
-    pd.add_argument("--metrics", default=None,
-                    help="comma-separated metric filter (default: every "
-                         "shared metric)")
-    pd.add_argument("--cross-rig", action="store_true",
-                    help="compare against baselines from OTHER rigs "
-                         "(config.backend) too — default: same-rig only")
-    pd.add_argument("--json", action="store_true",
-                    help="print the verdict as JSON instead of a table")
-    pd.set_defaults(fn=_cmd_bench_diff)
-
     pv = sub.add_parser(
         "trace-view",
         help="render a flight-recorder dump into an incident summary, "
@@ -4623,7 +2759,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         "slo-report",
         help="attainment + error-budget spend per objective over "
              "day/week windows from a durable history store "
-             "(ISSUE 20; --json rides bench-diff)",
+             "(ISSUE 20; --json for CI)",
     )
     psr.add_argument("store",
                      help="history store dir (BLIT_HISTORY_DIR)")
@@ -4632,7 +2768,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                           "(the shared window grammar; default 1d)")
     psr.add_argument("--json", action="store_true",
                      help="machine output (the 'metrics' block carries "
-                          "slo.<name>_attained for bench-diff gating)")
+                          "slo.<name>_attained for CI gating)")
     psr.add_argument("--out", default=None,
                      help="also write the report to this file "
                           "(CI artifact)")

@@ -37,9 +37,8 @@ incident *reconstructable from one artifact*:
 
 - :func:`slo_report` — attainment and error-budget spend per objective
   over day/week windows straight from the store, text + JSON; the JSON
-  carries a flat ``metrics`` dict with ``*_attained`` keys, so
-  :func:`blit.monitor.bench_metrics` ingests it and ``blit bench-diff``
-  can gate attainment like any other bench scalar.
+  carries a flat ``metrics`` dict with ``*_attained`` keys, one scalar
+  per objective for a CI step to gate on.
 
 Import discipline: stdlib + :mod:`blit.config` +
 :mod:`blit.observability` at module level (the monitor rule — ``blit
@@ -1300,9 +1299,7 @@ def slo_report(store: Optional[HistoryStore] = None, *,
     (1.0 over an empty window — no traffic spends no budget);
     ``budget_spent = (bad/total) / budget`` (1.0 = the whole error
     budget, the SRE burn integral).  The ``metrics`` block carries
-    flat ``slo.<name>_attained`` keys so
-    :func:`blit.monitor.bench_metrics` ingests the report unchanged
-    and ``blit bench-diff`` gates attainment."""
+    flat ``slo.<name>_attained`` keys, one scalar per objective."""
     from blit.monitor import bad_fraction, objectives_for
 
     objs = list(objectives) if objectives is not None \

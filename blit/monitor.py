@@ -38,12 +38,6 @@ reports.  This module is that operating surface:
   showing per-stage throughput, stage-tail p50/p99, SLO burn and host
   health.  ``blit telemetry --watch N`` shares the same refresh path.
 
-- the **CI perf gate** — ``blit bench-diff`` (:func:`bench_diff`):
-  compare a fresh ``bench.py`` / ``ingest-bench`` JSON against the
-  checked-in ``BENCH_*.json`` trajectory with noise bands and emit a
-  pass/regress verdict, so the perf history becomes an automated
-  watchdog instead of an archive.
-
 Import discipline: this module imports only stdlib +
 :mod:`blit.config` + :mod:`blit.observability` — every plane can reach
 :func:`publishing` without a dependency cycle, and ``blit top`` never
@@ -1459,208 +1453,3 @@ def gather_trace_sources(sources: Iterable[str], *,
             seen.add(sid)
         unique.append(s)
     return unique, hists
-
-
-# -- bench-diff: the CI perf-regression gate --------------------------------
-
-# Higher-is-better scalar metrics worth tracking across BENCH rounds.
-_METRIC_KEY_RE = re.compile(
-    r"(_gbps|_per_s|_speedup|^async_speedup$|_efficiency|^hit_rate$"
-    r"|_hit_rate$|_attained$)",
-)
-# Lower-is-better scalars (ISSUE 16: the serve plane gates on request
-# latency quantiles) — the noise band inverts for these.
-_LOWER_METRIC_KEY_RE = re.compile(r"_p\d+_s$")
-
-
-def metric_lower_is_better(key: str) -> bool:
-    """Is ``key`` a lower-is-better metric (a latency quantile)?  Such
-    metrics regress when the fresh value rises ABOVE the noise band."""
-    return _LOWER_METRIC_KEY_RE.search(key) is not None
-
-
-def load_bench_json(path: str) -> Dict:
-    """Load a bench record: either a plain ``bench.py`` /
-    ``ingest-bench`` JSON document, or a checked-in ``BENCH_*.json``
-    wrapper (``{"n", "cmd", "rc", "tail"}`` — the recorded stdout tail,
-    whose last JSON line is the bench record)."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "tail" in doc and "cmd" in doc:
-        if isinstance(doc.get("parsed"), dict):
-            return doc["parsed"]
-        for line in reversed(str(doc["tail"]).strip().splitlines()):
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                return json.loads(line)
-            except ValueError:
-                continue
-        # A failed round (rc != 0, no record line) is part of history —
-        # callers skip it, it must not poison the trajectory.
-        raise ValueError(f"no JSON bench record in the tail of {path}")
-    if not isinstance(doc, dict):
-        raise ValueError(f"{path} is not a bench JSON document")
-    return doc
-
-
-def bench_metrics(doc: Dict) -> Dict[str, float]:
-    """Extract the comparable higher-is-better scalars from a bench
-    record: for ``ingest-bench`` documents the per-leg ingest rate /
-    overlap efficiency and the async speedup; for ``bench.py`` records
-    the headline ``value`` (keyed by its ``metric`` name) plus every
-    top-level ``*_gbps`` / ``*_per_s`` / speedup / efficiency scalar;
-    for serve-bench records (``serve-bench --archive-day``, ISSUE 16)
-    the flat ``metrics`` dict — fleet hit rate, wire GB/s, and the
-    request/serialize latency quantiles (``*_pNN_s``, which compare
-    lower-is-better).  ``blit slo-report --json`` documents ride the
-    same ``metrics`` branch (``slo.<name>_attained`` matches
-    ``_attained$``), so ``blit bench-diff`` gates attainment like any
-    other bench scalar."""
-    out: Dict[str, float] = {}
-
-    def num(v) -> Optional[float]:
-        return (float(v) if isinstance(v, (int, float))
-                and not isinstance(v, bool) else None)
-
-    if isinstance(doc.get("metrics"), dict):
-        for k, v in doc["metrics"].items():
-            f = num(v)
-            if f is None:
-                continue
-            if _METRIC_KEY_RE.search(k) or metric_lower_is_better(k):
-                out[k] = f
-        return out
-    if "legs" in doc:
-        for leg in doc.get("legs") or []:
-            name = "async" if leg.get("async_output") else "sync"
-            for k in ("ingest_gbps", "overlap_efficiency"):
-                v = num(leg.get(k))
-                if v is not None:
-                    out[f"{name}.{k}"] = v
-        v = num(doc.get("async_speedup"))
-        if v is not None:
-            out["async_speedup"] = v
-        v = num((doc.get("dedoppler") or {}).get("drift_rates_per_s"))
-        if v is not None:
-            out["dedoppler.drift_rates_per_s"] = v
-        # The live leg's latency tails (ISSUE 18: the --packets run is
-        # the sustained-capture gate) — *_pNN_s keys compare
-        # lower-is-better in bench_diff, like the serve quantiles.
-        live = doc.get("live") or {}
-        for k in ("chunk_to_product_p50_s", "chunk_to_product_p99_s"):
-            v = num(live.get(k))
-            if v is not None:
-                out[f"live.{k}"] = v
-        pk = live.get("packet") or {}
-        for k in ("assembly_p50_s", "assembly_p99_s"):
-            v = num(pk.get(k))
-            if v is not None:
-                out[f"packet.{k}"] = v
-        return out
-    metric = doc.get("metric")
-    for k, v in doc.items():
-        f = num(v)
-        if f is None:
-            continue
-        if k == "value" and metric:
-            out[str(metric)] = f
-        elif _METRIC_KEY_RE.search(k):
-            out[k] = f
-    return out
-
-
-def bench_rig(doc: Dict) -> Optional[str]:
-    """The rig a bench record measured (its ``config.backend``; None
-    when unrecorded — ingest-bench documents)."""
-    return (doc.get("config") or {}).get("backend")
-
-
-def bench_diff(fresh: Dict, baselines: List[Dict], *,
-               rel_tol: float = 0.35,
-               metrics: Optional[Iterable[str]] = None,
-               cross_rig: bool = False) -> Dict:
-    """Compare a fresh bench record against a baseline trajectory with
-    noise bands: per metric, the band is ``[min·(1-rel_tol),
-    max·(1+rel_tol)]`` over the trajectory — a fresh value below the
-    band REGRESSES (throughput-style scalars are higher-is-better),
-    above it IMPROVES, inside it is ok.  Latency quantiles
-    (:func:`metric_lower_is_better`) invert: rising ABOVE the band
-    regresses, dropping below it improves.  The verdict is
-    ``"regress"`` iff any tracked metric regressed.  Metrics with no
-    baseline datapoint are reported as ``"new"`` and never gate.
-
-    Baselines recorded on a DIFFERENT rig than the fresh record
-    (``config.backend`` — the checked-in trajectory mixes TPU and CPU
-    rounds) are excluded unless ``cross_rig=True``: a CPU run regressing
-    against a TPU number is noise, not signal."""
-    fresh_m = bench_metrics(fresh)
-    want = set(metrics) if metrics else None
-    rig = bench_rig(fresh)
-    skipped_rigs = 0
-    kept = []
-    for b in baselines:
-        brig = bench_rig(b)
-        if (not cross_rig and rig is not None and brig is not None
-                and brig != rig):
-            skipped_rigs += 1
-            continue
-        kept.append(b)
-    baselines = kept
-    traj: Dict[str, List[float]] = {}
-    for b in baselines:
-        for k, v in bench_metrics(b).items():
-            traj.setdefault(k, []).append(v)
-    rows: Dict[str, Dict] = {}
-    regressed = []
-    for k in sorted(fresh_m):
-        if want is not None and k not in want:
-            continue
-        v = fresh_m[k]
-        hist = traj.get(k)
-        if not hist:
-            rows[k] = {"fresh": v, "status": "new", "n": 0}
-            continue
-        lo, hi = min(hist), max(hist)
-        band_lo = lo * (1.0 - rel_tol)
-        band_hi = hi * (1.0 + rel_tol)
-        if metric_lower_is_better(k):
-            status = ("regress" if v > band_hi
-                      else "improved" if v < band_lo else "ok")
-        else:
-            status = ("regress" if v < band_lo
-                      else "improved" if v > band_hi else "ok")
-        if status == "regress":
-            regressed.append(k)
-        rows[k] = {"fresh": v, "lo": lo, "hi": hi,
-                   "band_lo": round(band_lo, 6),
-                   "band_hi": round(band_hi, 6),
-                   "status": status, "n": len(hist)}
-    return {
-        "verdict": "regress" if regressed else "pass",
-        "rel_tol": rel_tol,
-        "rig": rig,
-        "baselines": len(baselines),
-        "baselines_skipped_other_rig": skipped_rigs,
-        "regressed": regressed,
-        "metrics": rows,
-    }
-
-
-def render_bench_diff(verdict: Dict) -> str:
-    """``blit bench-diff``'s human table."""
-    lines = [f"bench-diff: {verdict['verdict'].upper()} "
-             f"({verdict['baselines']} baseline(s), "
-             f"noise ±{verdict['rel_tol'] * 100:.0f}%)"]
-    lines.append(f"{'metric':<44} {'fresh':>12} {'band_lo':>12} "
-                 f"{'band_hi':>12} status")
-    for k, row in verdict["metrics"].items():
-        def band(key):
-            v = row.get(key)
-            return f"{v:>12.4g}" if v is not None else f"{'-':>12}"
-
-        lines.append(
-            f"{k:<44} {row['fresh']:>12.4g} {band('band_lo')} "
-            f"{band('band_hi')} {row['status']}")
-    return "\n".join(lines)

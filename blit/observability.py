@@ -732,8 +732,8 @@ class Tracer:
     deque (oldest dropped) and in the process flight recorder.
 
     ``enabled=False`` (or ``BLIT_SPANS=0`` in the environment) turns
-    :meth:`span` into a near-free no-op — the ingest-bench A/B lever for
-    the ≤1 % overhead acceptance bound."""
+    :meth:`span` into a near-free no-op — the A/B lever for the ≤1 %
+    overhead acceptance bound."""
 
     def __init__(self, max_spans: int = 16384, enabled: Optional[bool] = None):
         if enabled is None:

@@ -561,7 +561,7 @@ def channelize(
     # fused kernels in round 3, so output diffs against older runs must be
     # attributable (ADVICE r3).  Trace-time only: a jit cache hit does not
     # re-run this body, so the record describes the most recent TRACE
-    # (bench.py surfaces it in its JSON metadata).
+    # (`blit reduce` prints it as `kernel_plan`).
     _LAST_PLAN.clear()
     _LAST_PLAN.update(
         fft_method=resolved,

@@ -9,7 +9,7 @@ one voltage tile, forms the four real products as dot_generals, squares,
 and integrates — voltages are read once, only integrated power is
 written.
 
-Measured (tools/ab_pallas_beamform.py, interleaved, bench shape nant=64
+Measured (interleaved A/B on the chip, nant=64
 nbeam=64 nchan=64 ntime=8192 nint=8, f32-equivalent input GB/s,
 steady-state rounds):
 

@@ -1,6 +1,6 @@
 """Pallas TPU kernel fusing dequantization + the polyphase FIR frontend.
 
-Why: the corrected roofline (DESIGN.md §9, tools/roofline.py) shows
+Why: the corrected roofline (DESIGN.md §9) shows
 dequant+PFB is the channelizer's dominant stage — 90 ms at 64 GB/s (8% of
 the HBM roof) vs 25-29 ms at ~230 GB/s for each DFT matmul stage — because
 XLA materializes the dequantized gross planes and re-reads them once per

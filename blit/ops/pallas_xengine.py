@@ -2,10 +2,10 @@
 
 The un-parking of DESIGN.md §9's round-4 decision ("pallas X-engine parked
 until a real workload's nant makes the tiles MXU-sized"): at the repo's own
-array scale of 64 antennas (bench.py beamform leg) the per-(chan, fine)
+array scale of 64 antennas the per-(chan, fine)
 baseline matmul is (nant·npol)² = 128² — exactly MXU-sized — and the
 measured whole-correlate rates at that shape justify the kernel
-(interleaved A/B on the chip, tools/ab_fx64_pallas.py, nant=64 nchan=16
+(interleaved A/B on the chip, nant=64 nchan=16
 nfft=512 nblk=64):
 
     einsum X-engine            21.1 GB/s input (median)
@@ -13,7 +13,7 @@ nfft=512 nblk=64):
     pallas ft=16               24.4 GB/s
     pallas ft=32               VMEM OOM (19.8 MB scoped > 16 MB)
 
-XLA-level alternatives measured first and at parity (tools/ab_fx64.py:
+XLA-level alternatives measured first and at parity (the same A/B:
 packed-layout einsums 0.996x, bf16-cast operands 0.996x), so the win here
 is genuinely the single-pass VMEM residency: per grid step both planes'
 ``(ft, nap, nframes)`` spectra blocks are loaded once and all four real

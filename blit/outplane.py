@@ -72,9 +72,8 @@ log = logging.getLogger("blit.outplane")
 
 _EOF = object()
 
-# The output plane's per-chunk histograms, in the order the bench /
-# ingest-bench / tune stage_quantiles blocks report them.  One constant —
-# adding or renaming a hist here updates every report surface at once.
+# The output plane's per-chunk histograms, in the order `blit tune`'s
+# profile reports them.
 INGEST_HISTS = ("out.chunk_latency_s", "out.readback_lag_s", "out.write_s")
 
 

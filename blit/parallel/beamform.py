@@ -42,8 +42,7 @@ ANT_AXIS_DEFAULT = "bank"
 
 # Dispatch resolution of the most recent beamform(layout="chan") TRACE
 # (the blit.ops.channelize._LAST_PLAN convention): silent fallbacks must
-# be attributable — the bench asserts the fused kernel actually ran
-# behind its beamform_fused_gbps number.
+# be attributable — chip_smoke.py asserts the fused kernel actually ran.
 _LAST_PLAN: dict = {}
 
 

@@ -50,7 +50,7 @@ BANK_AXIS = "bank"
 # blit.ops.channelize._LAST_PLAN convention, mirrored from
 # blit.parallel.beamform.last_beamform_plan): the pallas-vs-einsum gate
 # evaluates on per-shard LOCAL shapes inside shard_map, so provenance
-# consumers (bench.py) must read the actual decision here instead of
+# consumers (chip_smoke.py) must read the actual decision here instead of
 # re-deriving it from global shapes (ADVICE r5 low finding).
 _LAST_PLAN: dict = {}
 
@@ -109,7 +109,7 @@ def _xengine_planar(sr: jax.Array, si: jax.Array) -> Planar:
     block products as ONE einsum over the re/im-stacked operand (a
     (2·nant·npol)² matmul per (chan, fine) batch entry, 4x the work per
     MXU tile) LOSES on the chip — 18.9 vs 20.7 GB/s input rate
-    end-to-end (interleaved A/B, tools/ab_fx.py): the stack's
+    end-to-end (interleaved A/B on the chip): the stack's
     concatenate materializes an extra copy of both spectra planes, and
     the MXU tiles were not the binding resource.
     """
@@ -137,7 +137,7 @@ def _xengine_packed(sr: jax.Array, si: jax.Array) -> Planar:
     Pallas kernel (blit/ops/pallas_xengine.py — measured +19% on the whole
     correlate call at nant=64, the un-parking of DESIGN.md §9's round-4
     decision); elsewhere, packed-layout einsums (measured at parity with
-    the standard layout, tools/ab_fx64.py, so the fallback costs nothing).
+    the standard layout, so the fallback costs nothing).
     """
     from blit.ops import pallas_xengine
     from blit.device import TPU_BACKEND

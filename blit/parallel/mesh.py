@@ -66,8 +66,7 @@ PARTITION_RULES: Dict[str, P] = {
 }
 
 # The collective-latency histograms of the sharded plane (ISSUE 9): every
-# honestly-timeable collective observes into these Timeline hists, and the
-# bench's mesh_collectives leg reports their p50/p99.
+# honestly-timeable collective observes into these Timeline hists.
 MESH_HISTS = ("mesh.gather_s", "mesh.psum_s")
 
 

@@ -484,7 +484,7 @@ class RawReducer:
     def _coeffs(self):
         """PFB coefficient bank, built (and device-shipped) on FIRST
         compute use — not at construction.  Throwaway probe reducers
-        (scan/ingest-bench resolve tuning knobs through one) must not
+        (scan resolves tuning knobs through one) must not
         pay a multi-million-coefficient sinc*window build plus device
         transfer just to read provenance."""
         if self._pfb_coeffs is None:
@@ -506,9 +506,8 @@ class RawReducer:
         )
 
     def tuning_provenance(self) -> Dict:
-        """Where this reducer's ingest knobs came from — embedded in the
-        bench/ingest-bench ``ingest_config`` blocks so every recorded
-        number names the profile (or default) behind it."""
+        """Where this reducer's ingest knobs came from, so every recorded
+        number can name the profile (or default) behind it."""
         prov = {
             "chunk_frames": self.chunk_frames,
             "prefetch_depth": self.prefetch_depth,
@@ -591,8 +590,7 @@ class RawReducer:
         """The exact channelize kwarg set (jax.jit caches per call
         signature, so the kwarg set must be bit-stable across callers —
         fqav_by only appears when active, keeping the common-case cache
-        signature identical to callers that never heard of it, bench.py
-        included)."""
+        signature identical to callers that never heard of it)."""
         kw = dict(
             nfft=self.nfft, ntap=self.ntap, nint=self.nint,
             stokes=self.stokes, fft_method=self.fft_method,

@@ -40,7 +40,7 @@ sharded and streaming planes:
   modes (blit/faults.py) at the ``mesh.window`` / ``stream.chunk``
   injection points, driven end-to-end by ``blit chaos`` (run a seeded
   kill/hang schedule against a real multi-process scan or live stream,
-  assert recovery + byte-identity) and ``ingest-bench --chaos``.
+  assert recovery + byte-identity).
 
 Telemetry: ``recover.detect_s`` / ``recover.resume_s`` histograms and
 ``recover.*`` counters land on the supervisor's Timeline (published

@@ -70,20 +70,11 @@ class FleetController:
     ``door`` is the :class:`~blit.serve.fleet.FleetFrontDoor` whose
     ring this controller resizes; ``evaluator`` the
     :class:`~blit.monitor.BurnRateEvaluator` whose pages trigger
-    scale-out (None = manual/idle-only).  ``feed``, when set to a
-    :class:`~blit.observability.Timeline` (usually the door's), makes
-    the controller feed the evaluator that timeline's per-tick deltas —
-    leave it None when a MetricsPublisher already owns the evaluator's
-    diet, or the same interval would be counted twice.  ``terminate``
-    is an optional ``(peer_name) -> None`` callable run after a
-    scale-in flip — the CLI rig passes SIGTERM-the-child here, matching
-    the deadline-aware drain handler peers install."""
+    scale-out (None = manual/idle-only)."""
 
     def __init__(self, door, evaluator=None, *,
                  config: SiteConfig = DEFAULT,
                  timeline: Optional[Timeline] = None,
-                 feed: Optional[Timeline] = None,
-                 terminate: Optional[Callable[[str], None]] = None,
                  idle_rps: Optional[float] = None,
                  idle_windows: Optional[int] = None,
                  hysteresis_s: Optional[float] = None,
@@ -117,9 +108,6 @@ class FleetController:
             drain_timeout_s if drain_timeout_s is not None
             else d["drain_timeout_s"])
         self.clock = clock
-        self._feed = feed
-        self._feed_state: Optional[Dict] = None
-        self._terminate = terminate
         self._lock = threading.Lock()
         self._resizing: Optional[str] = None
         self._cooldown_until = 0.0
@@ -138,10 +126,10 @@ class FleetController:
     # -- the observation tick ----------------------------------------------
     def observe(self, interval_s: Optional[float] = None
                 ) -> Optional[Dict]:
-        """One controller tick (the loop's body; tests and the diurnal
-        bench drive it directly): feed the evaluator, judge paging vs
-        idle, and resize — unless the flap guard is armed.  Returns the
-        action record when a resize happened, else None."""
+        """One controller tick (the loop's body; tests drive it
+        directly): judge paging vs idle, and resize — unless the flap
+        guard is armed.  Returns the action record when a resize
+        happened, else None."""
         now = self.clock()
         if interval_s is not None:
             dt = float(interval_s)
@@ -151,12 +139,6 @@ class FleetController:
             dt = self.poll_s
         dt = max(dt, 1e-9)
         self._last_tick = now
-        if self._feed is not None and self.evaluator is not None:
-            from blit.monitor import _delta_timeline
-
-            delta = _delta_timeline(self._feed, self._feed_state)
-            self._feed_state = self._feed.state()
-            self.evaluator.observe(delta, dt)
         paging = bool(self.evaluator.breached()) if self.evaluator else False
         reqs = self._requests_total()
         rps = max(0, reqs - self._last_requests) / dt
@@ -285,12 +267,6 @@ class FleetController:
             hinted = self._prewarm_successors(victim)
             drained = self._drain_leaver(victim)
             self.door.retire_peer(victim)
-            if self._terminate is not None:
-                try:
-                    self._terminate(victim)
-                except Exception:  # noqa: BLE001 — the flip already won
-                    log.warning("elastic: terminate(%s) failed", victim,
-                                exc_info=True)
         finally:
             self._set_resizing(None)
             self._arm_guard()

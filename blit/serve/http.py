@@ -46,7 +46,7 @@ was never computed); anything else → **500** carrying the error type.
 
 :func:`install_drain_handler` wires SIGTERM/SIGINT to a graceful drain
 (refuse new, finish in-flight, release ``kind="stream"`` holds) — used
-by ``blit fleet-peer`` and ``blit serve-bench`` so an interpreter exit
+by ``blit fleet-peer`` so an interpreter exit
 stops leaking capacity holds (ISSUE 14 satellite).
 """
 

@@ -800,7 +800,7 @@ class ProductService:
     # -- reporting / teardown ---------------------------------------------
     def stats(self) -> Dict[str, object]:
         """Serving counters + cache counters + queue-wait percentiles —
-        the ``serve-bench`` CLI's report body."""
+        the body a peer's ``/stats`` answers with."""
         with self._lock:
             out: Dict[str, object] = dict(self.counts)
             out["inflight"] = len(self._flights)

@@ -7,7 +7,7 @@ file the recorder appends to, a paced replay, an in-memory queue — with
 watermark-based windowing, late/duplicate/missing-chunk repair (missing
 chunks mask to zero weight, the PR 2 antenna discipline), and bounded
 chunk→product latency as a first-class metric.  ``blit stream`` is the
-CLI; ``ingest-bench --live`` is the latency rig.
+CLI.
 
 The golden contract: streaming a fully-recorded file through
 :func:`stream_reduce` / :func:`stream_search` produces BYTE-IDENTICAL

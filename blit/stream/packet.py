@@ -40,8 +40,8 @@ sized by :func:`blit.config.packet_defaults` — a recorder never pauses,
 so the kernel buffer is the only back-pressure) and drains it inside
 ``get()``.  :class:`PacketReplaySource` replays an at-rest recording AS
 its packet stream at ``rate``× recording cadence, with seeded
-drop/reorder/dup schedules — the deterministic twin for tests, CI and
-``ingest-bench --live --packets``.  Both feed the SAME assembler, so
+drop/reorder/dup schedules — the deterministic twin for tests and CI.
+Both feed the SAME assembler, so
 the replay drills exercise the real wire path end to end.
 
 Chaos: every received packet fires the ``packet.recv`` fault point

@@ -27,7 +27,7 @@ Latency is a first-class metric: per-product-append
 product row depends on → that row durably handed to its writer), the
 ``stream.watermark_lag_s`` gauge (how far the feed runs behind arrivals)
 and ``stream.chunk.*`` counters, all on the reducer's Timeline — so
-``blit stream`` / ``ingest-bench --live`` report p50/p99 product latency
+``blit stream`` reports p50/p99 product latency
 with no extra plumbing.
 
 Entry points: :func:`stream_reduce` (``.fil``/``.h5`` filterbank

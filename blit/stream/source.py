@@ -14,9 +14,7 @@ rig and the tests:
   forever).
 - :class:`ReplaySource` replays an at-rest recording at wall-clock (or
   ``rate``-accelerated) cadence: block ``i`` is delivered when a real
-  recorder would have finished writing it.  ``late={seq: extra_s}``
-  defers individual chunks deterministically — the seeded late-chunk
-  drill of ``ingest-bench --live``.
+  recorder would have finished writing it.
 - :class:`QueueSource` is the in-memory source: tests push chunks in any
   order (late, duplicated, missing) and the watermark assembler
   (blit/stream/plane.py) is exercised without touching a clock.
@@ -142,13 +140,9 @@ class ReplaySource(ChunkSource):
     """Replay an at-rest recording at recording cadence (module
     docstring).  ``rate`` multiplies wall-clock speed (1.0 = exactly as
     recorded, per TBIN); chunk ``i`` is due once the recorder would have
-    finished writing block ``i``.  ``late`` defers individual chunks
-    past their natural slot — delivery stays in *due-time* order, so a
-    deferred chunk genuinely arrives after its successors (the seeded
-    late-chunk drill)."""
+    finished writing block ``i``."""
 
     def __init__(self, raw, rate: float = 1.0,
-                 late: Optional[Dict[int, float]] = None,
                  clock=time.monotonic, sleep=time.sleep):
         from blit.io.guppi import open_raw
 
@@ -162,16 +156,11 @@ class ReplaySource(ChunkSource):
         self.total = None  # published at finish, the source contract
         self._nblocks = self.raw.nblocks
         tbin = float(self.raw.header(0).get("TBIN", 0.0) or 0.0)
-        late = late or {}
         cum = 0
-        sched: List[Tuple[float, int]] = []
+        self._sched: List[Tuple[float, int]] = []
         for i in range(self._nblocks):
             cum += self.raw.block_ntime_kept(i)
-            due = cum * tbin / rate + late.get(i, 0.0)
-            sched.append((due, i))
-        # Due-time order IS delivery order: a deferred chunk arrives
-        # after whatever overtook it.
-        self._sched = sorted(sched)
+            self._sched.append((cum * tbin / rate, i))
         self._pos = 0
         self._t0: Optional[float] = None
 

@@ -343,20 +343,3 @@ def build_observation_tree(
                 raise ValueError(f"unknown kind {kind!r}")
             paths.append(p)
     return paths
-
-
-def sync_compare_verdict(async_path: str, sync_path: str,
-                         async_wall_s: float, sync_wall_s: float) -> Dict:
-    """The ISSUE 8 async-vs-sync acceptance, defined ONCE for every
-    surface that publishes it (``bench.py`` product leg, ``blit
-    ingest-bench --sync-compare``): the async (device-narrowed when
-    nbits<32) and sync products of the same recording must be the same
-    file, and the speedup is the sync/async wall ratio.  Constant-memory
-    compare — product files can be large."""
-    import filecmp
-
-    return {
-        "async_speedup": round(sync_wall_s / max(async_wall_s, 1e-9), 3),
-        "products_identical": filecmp.cmp(async_path, sync_path,
-                                          shallow=False),
-    }

@@ -140,7 +140,7 @@ class TuningProfile:
         }
 
     def provenance(self) -> Dict:
-        """The compact provenance block bench/ingest-bench embed."""
+        """The compact provenance block a report embeds."""
         return {
             "key": self.key,
             "source": self.source,

@@ -31,7 +31,7 @@ The default run, in ONE process (a chip belongs to one process at a time):
    header predicts, and two coarse channels x all spectra against
    ``channelize_np`` on the same bytes;
 6. compiles (never interprets) and checks the per-chip kernels that are
-   not on the 0000 path, at bench.py's shapes: fused beamform, packed
+   not on the 0000 path, at array-scale shapes: fused beamform, packed
    X-engine at nant 64, the drift-search tree at 64 x 2^20, and
    ``channelize`` with ``fqav_by=16`` and ``stokes="IQUV"``.
 
@@ -68,8 +68,8 @@ FULL = dict(
         beamform=dict(nant=64, nbeam=64, nchan=64, ntime=8192, nint=8),
         xengine=dict(nant=64, nchan=16, nfft=512, nblk=64),
         dedoppler=dict(T=64, F=1 << 20),
-        # bench.py runs these two at 48 channels, where XLA's own account
-        # is 15.6 of the chip's 15.75 GiB: nothing else may be resident.
+        # At 48 channels XLA's own account for these two is 15.6 of
+        # the chip's 15.75 GiB: nothing else may be resident.
         # Same kernels and per-channel grid at a third of the batch.
         channelize=dict(nchan=16, frames=8, dtype="bfloat16"),
     ),
@@ -592,7 +592,7 @@ def kernel_legs(size: dict, on_tpu: bool) -> None:
     def ints(shape):  # int8-valued voltages: exact in bf16
         return rng.integers(-40, 40, shape).astype(np.float32)
 
-    # Fused beamform + detect (bench.py `_run_collectives`), bf16 planes.
+    # Fused beamform + detect, bf16 planes.
     k = K["beamform"]
     t0 = time.perf_counter()
     vr = ints((k["nant"], k["nchan"], k["ntime"], 2))
@@ -622,7 +622,7 @@ def kernel_legs(size: dict, on_tpu: bool) -> None:
         seconds=round(time.perf_counter() - t0, 1))
     del vr, vi, kv, power
 
-    # Packed X-engine at nant 64 (bench.py's correlator64 leg).
+    # Packed X-engine at nant 64.
     k = K["xengine"]
     t0 = time.perf_counter()
     ntime = k["nblk"] * k["nfft"]
@@ -647,7 +647,7 @@ def kernel_legs(size: dict, on_tpu: bool) -> None:
         seconds=round(time.perf_counter() - t0, 1))
     del cr, ci, cvp, pr, pi, want
 
-    # Drift search (bench.py `_run_dedoppler`): the tree kernel against
+    # Drift search: the tree kernel against
     # the lax reference bitwise and a NumPy brute force on a window of
     # columns; then the full search step must recover the injected drift.
     k = K["dedoppler"]
@@ -686,8 +686,8 @@ def kernel_legs(size: dict, on_tpu: bool) -> None:
         seconds=round(time.perf_counter() - t0, 1))
     del x, xj, pal, ref, packed
 
-    # channelize with the fqav epilogue and with full Stokes (bench.py's
-    # fqav16 / stokes_iquv legs), against channelize_np on two channels.
+    # channelize with the fqav epilogue and with full Stokes, against
+    # channelize_np on two channels.
     k = K["channelize"]
     t0 = time.perf_counter()
     nfft = size["nfft"]

@@ -1,4 +1,5 @@
-"""CLI surface (python -m blit): reduce / inventory / info."""
+"""CLI surface (python -m blit): reduce / inventory / info / scan, and
+the documents' commands against the parser."""
 
 import json
 
@@ -54,6 +55,37 @@ class TestReduce:
         rc, txt = run(capsys, "reduce", raw, "-o", out, "--product", "0001")
         assert rc == 0
         assert json.loads(txt)["nchans"] == 2 * 8  # 0001: nfft=8
+
+
+@pytest.mark.parametrize("cmd", ["serve-bench", "ingest-bench", "bench-diff"])
+def test_product_has_no_bench_command(cmd, capsys):
+    # blit has one benchmark, benchmark/, and it is not in the product.
+    with pytest.raises(SystemExit) as e:
+        main([cmd])
+    assert e.value.code == 2
+    assert "invalid choice" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("doc", ["README.md", "docs/WORKFLOWS.md"])
+def test_documented_commands_exist(doc, capsys):
+    # Every `python -m blit <sub>` / `blit <sub>` a document shows in a
+    # code block is a subcommand the parser registers.
+    import os
+    import re
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, doc)) as f:
+        blocks = re.findall(r"^```[^\n]*\n(.*?)^```", f.read(),
+                            flags=re.S | re.M)
+    subs = {m.group(1) for b in blocks for m in re.finditer(
+        r"(?:python3? -m blit|^[ \t$]*blit)[ \t]+([a-z][a-z0-9-]*)", b,
+        flags=re.M)}
+    assert {"reduce", "scan"} <= subs
+    for sub in sorted(subs):
+        with pytest.raises(SystemExit) as e:
+            main([sub, "--help"])
+        assert e.value.code == 0, f"{doc} shows `blit {sub}`"
+        capsys.readouterr()
 
 
 def test_product_choices_mirror_presets():

@@ -375,22 +375,6 @@ class TestElasticCLI:
     """The REAL multi-process legs (subprocess peers, SIGTERM/SIGKILL)
     — the CI fleet-smoke job's shape, kept out of the tier-1 budget."""
 
-    def test_serve_bench_diurnal(self, tmp_path):
-        out = tmp_path / "diurnal.json"
-        res = subprocess.run(
-            [sys.executable, "-m", "blit", "serve-bench", "--diurnal",
-             "--peers", "2", "--cycles", "2", "--requests", "24",
-             "--distinct", "6", "--clients", "3", "--nfft", "128",
-             "--hysteresis", "1.0", "--idle-windows", "2",
-             "--out", str(out)],
-            capture_output=True, text=True, timeout=600)
-        assert res.returncode == 0, res.stdout + res.stderr
-        rep = json.loads(out.read_text())
-        assert rep["ok"] and len(rep["cycles_detail"]) == 2
-        assert rep["scale_outs"] == 2 and rep["scale_ins"] == 2
-        assert rep["requests_to_departed"] == 0
-        assert rep["slo"]["ok"] and rep["hit_bound_ok"]
-
     def test_chaos_fleet_resize_drill(self, tmp_path):
         out = tmp_path / "resize.json"
         res = subprocess.run(

@@ -422,14 +422,3 @@ class TestFleetCLI:
         assert rep["ok"] and rep["detected"] and rep["byte_identical"]
         assert rep["healthz"]["after_detect"] == "degraded"
         assert rep["hit_rate_recovered"]
-
-    def test_serve_bench_fleet_smoke(self, tmp_path):
-        res = subprocess.run(
-            [sys.executable, "-m", "blit", "serve-bench", "--fleet",
-             "--requests", "30", "--distinct", "4", "--clients", "3",
-             "--peers", "3", "--nfft", "128"],
-            capture_output=True, text=True, timeout=600)
-        assert res.returncode == 0, res.stdout + res.stderr
-        rep = json.loads(res.stdout.strip().splitlines()[-1])
-        assert rep["fleet"] and rep["hit_rate"] > 0
-        assert "hedge" in rep and "slo" in rep

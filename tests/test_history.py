@@ -7,8 +7,8 @@ two peers' stores), the median/MAD anomaly baseline (fires on an
 injected step, quiet on a seeded steady baseline, kill switch +
 per-metric sensitivity), incident bundles (self-contained: the
 exemplar trace id resolves into the bundle's own request records),
-`blit slo-report` against a hand-computed oracle (and its JSON riding
-`bench_metrics`), the shared window grammar, the wall-clock anchor
+`blit slo-report` against a hand-computed oracle (and its JSON's flat
+`metrics` block), the shared window grammar, the wall-clock anchor
 satellite, and the torn-tail drill (a writer SIGKILLed mid-line heals
 and counts on every monitor-path reader)."""
 
@@ -43,7 +43,7 @@ from blit.history import (
     sparkline,
     window_seconds,
 )
-from blit.monitor import MetricsPublisher, SLObjective, bench_metrics
+from blit.monitor import MetricsPublisher, SLObjective
 from blit.observability import HistogramStats, Timeline, wall_anchor
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -658,14 +658,6 @@ class TestSloReport:
         assert doc["objectives"]["api"]["attainment"] == 1.0
         assert doc["objectives"]["api"]["budget_spent"] == 0.0
 
-    def test_bench_metrics_ingests_the_report(self):
-        doc = {"metrics": {"slo.api_attained": 0.94,
-                           "slo.ingest_attained": 1.0}}
-        out = bench_metrics(doc)
-        assert out == {"slo.api_attained": 0.94,
-                       "slo.ingest_attained": 1.0}
-        assert not monitor.metric_lower_is_better("slo.api_attained")
-
 
 # -- torn-tail drills (satellite) --------------------------------------------
 
@@ -774,14 +766,14 @@ class TestAnchor:
 
 
 class TestCli:
-    def _store(self, tmp_path):
+    def _store(self, tmp_path, burn=None):
         # Near-now clock: the CLI windows anchor at real time.time().
         clock = FakeClock(time.time() - 15.0)
         store = HistoryStore(str(tmp_path / "h"), tiers=_small_tiers(),
                              slot_bytes=4096, clock=clock)
         for _ in range(10):
             store.append(clock(), 1.0, _tick_delta(),
-                         burn={"api": (1, 10)})
+                         burn=burn or {"api": (1, 10)})
             clock.advance(1.0)
         store.close()
         return str(tmp_path / "h")
@@ -803,6 +795,20 @@ class TestCli:
         assert doc["objectives"]["api"]["total"] == 100
         assert doc["metrics"]["slo.api_attained"] == pytest.approx(0.9)
         assert json.loads(out.read_text()) == doc
+
+    def test_slo_report_json_metrics_block_is_flat(self, tmp_path, capsys):
+        # What a CI step reads: one `slo.<name>_attained` scalar per
+        # objective recorded in the store, nothing nested, each the
+        # objective's own attainment.
+        from blit.__main__ import main
+
+        d = self._store(tmp_path, burn={"api": (1, 10), "ingest": (0, 4)})
+        assert main(["slo-report", d, "--json"]) == 0
+        doc = json.loads(capsys.readouterr().out)
+        assert doc["metrics"] == {"slo.api_attained": pytest.approx(0.9),
+                                  "slo.ingest_attained": 1.0}
+        for name, o in doc["objectives"].items():
+            assert doc["metrics"][f"slo.{name}_attained"] == o["attainment"]
 
     def test_incident_cli_list_and_show(self, tmp_path, capsys):
         from blit.__main__ import main

@@ -6,9 +6,7 @@ spool, HTTP endpoints), native Prometheus histogram exposition
 breach actions (alert + forced flight dump + scheduler shed), the
 deterministic SLO drill (BLIT_FAULTS latency injection → alert → dump →
 measurable shed → recovery), dump rate-limiting under an alert storm,
-`blit top` / `blit telemetry --watch`, and the `blit bench-diff`
-perf-regression gate over both synthetic trajectories and the
-checked-in BENCH_*.json history."""
+and `blit top` / `blit telemetry --watch`."""
 
 import json
 import math
@@ -25,9 +23,6 @@ from blit.monitor import (
     MetricsPublisher,
     SLObjective,
     bad_fraction,
-    bench_diff,
-    bench_metrics,
-    load_bench_json,
     parse_prometheus,
 )
 from blit.observability import (
@@ -39,8 +34,6 @@ from blit.observability import (
     render_prometheus,
     telemetry_snapshot,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -503,28 +496,35 @@ class TestTopCli:
         out = capsys.readouterr().out
         assert "ingest" in out
 
-    def test_top_during_live_ingest_bench(self, tmp_path, capsys):
+    def test_top_during_live_stream(self, tmp_path, capsys):
         """Acceptance (ISSUE 11): `blit top --once` renders a live
-        snapshot DURING `ingest-bench --live` — the bench publishes to
-        a spool on an interval; top reads it mid-run."""
+        snapshot DURING `blit stream` — the session publishes to a spool
+        on an interval; top reads it mid-run."""
         from blit.__main__ import main
+        from blit.testing import synth_raw
 
+        raw = str(tmp_path / "live.raw")
+        nfft = 256
+        # A 3 s session at --replay-rate 1: TBIN sized to the recording.
+        per_block = (8 + 3) * nfft
+        synth_raw(raw, nblocks=4, obsnchan=2, ntime_per_block=per_block,
+                  overlap=nfft, tone_chan=1, tbin=3.0 / (4 * per_block))
         spool = tmp_path / "spool"
         rc = {}
 
-        def bench():
+        def session():
             rc["rc"] = main([
-                "ingest-bench", "--nfft", "256", "--nchan", "2",
-                "--chunk-frames", "4", "--chunks", "4", "--blocks", "2",
-                "--live", "--live-seconds", "3.0",
+                "stream", raw, "-o", str(tmp_path / "live.fil"),
+                "--nfft", str(nfft), "--replay-rate", "1",
                 "--monitor-spool", str(spool),
                 "--monitor-interval", "0.05",
             ])
 
-        t = threading.Thread(target=bench, daemon=True)
+        t = threading.Thread(target=session, daemon=True)
         t.start()
         try:
             wait_for(lambda: monitor.read_spool(str(spool)), timeout=120)
+            assert t.is_alive()
             assert main(["top", "--once", "--spool", str(spool)]) == 0
             out = capsys.readouterr().out
             assert "blit top" in out
@@ -534,7 +534,7 @@ class TestTopCli:
         report = json.loads(capsys.readouterr().out.strip()
                             .splitlines()[-1])
         assert report["monitor"]["samples"] >= 1
-        assert report["live"]["chunks"] > 0
+        assert report["stream_chunks"] > 0
 
     def test_telemetry_watch_shares_refresh_loop(self, capsys):
         """Satellite: `blit telemetry --watch N` re-harvests and
@@ -549,209 +549,6 @@ class TestTopCli:
         out = capsys.readouterr().out
         assert out.count(monitor.ANSI_CLEAR) == 2
         assert "probe.watch" in out
-
-
-# -- bench-diff (the CI perf gate) -------------------------------------------
-
-
-class TestBenchDiff:
-    BASE = {"metric": "ingest_GBps", "value": 10.0, "unit": "GB/s",
-            "fqav16_gbps": 5.0,
-            "config": {"backend": "cpu", "name": "cpu"}}
-
-    def _wrap(self, doc, n=1, rc=0):
-        return {"n": n, "cmd": "python bench.py", "rc": rc,
-                "tail": "noise\n" + json.dumps(doc), "parsed": doc}
-
-    def test_metrics_extraction(self):
-        m = bench_metrics(self.BASE)
-        assert m == {"ingest_GBps": 10.0, "fqav16_gbps": 5.0}
-        ib = {"legs": [{"async_output": True, "ingest_gbps": 0.5,
-                        "overlap_efficiency": 1.4},
-                       {"async_output": False, "ingest_gbps": 0.4,
-                        "overlap_efficiency": 0.9}],
-              "async_speedup": 1.25}
-        m = bench_metrics(ib)
-        assert m["async.ingest_gbps"] == 0.5
-        assert m["sync.ingest_gbps"] == 0.4
-        assert m["async_speedup"] == 1.25
-
-    def test_pass_regress_improve_new(self):
-        baselines = [dict(self.BASE, value=9.0, fqav16_gbps=4.0),
-                     dict(self.BASE, value=11.0, fqav16_gbps=6.0)]
-        fresh = dict(self.BASE, value=10.5, fqav16_gbps=2.0,
-                     new_leg_gbps=1.0)
-        v = bench_diff(fresh, baselines, rel_tol=0.2)
-        rows = v["metrics"]
-        assert rows["ingest_GBps"]["status"] == "ok"
-        assert rows["fqav16_gbps"]["status"] == "regress"  # < 4*0.8
-        assert rows["new_leg_gbps"]["status"] == "new"
-        assert v["verdict"] == "regress"
-        assert v["regressed"] == ["fqav16_gbps"]
-        good = bench_diff(dict(self.BASE, value=30.0), baselines,
-                          rel_tol=0.2)
-        assert good["metrics"]["ingest_GBps"]["status"] == "improved"
-        assert good["verdict"] == "pass"
-
-    def test_serve_record_metrics_dict_extraction(self):
-        # ISSUE 16: serve-bench --archive-day records carry a flat
-        # "metrics" dict — hit rate / GB/s / speedup plus latency
-        # quantiles — which bench_metrics ingests directly.
-        rep = {"serve_bench": "archive-day",
-               "config": {"backend": "cpu"},
-               "metrics": {"fleet_hit_rate": 0.94,
-                           "fleet_wire_gbps": 0.028,
-                           "wire_speedup": 1.12,
-                           "fleet_request_p99_s": 1.5,
-                           "not_a_metric": 7.0,
-                           "errors": "nope"}}
-        m = bench_metrics(rep)
-        assert m == {"fleet_hit_rate": 0.94, "fleet_wire_gbps": 0.028,
-                     "wire_speedup": 1.12, "fleet_request_p99_s": 1.5}
-
-    def test_archive_day_r02_keys_pin(self):
-        # ISSUE 19: the archive-plane record's new keys — catalog
-        # lookup quantiles (lower-is-better), per-tier hit rates and
-        # SLO attainment (higher-is-better) — must ALL extract, while
-        # tier_derive_rate stays report-only (a rising derive rate is
-        # a regression, so it must not ride the higher-is-better
-        # extractor).
-        from blit.monitor import metric_lower_is_better
-
-        rep = {"serve_bench": "archive-day",
-               "config": {"backend": "cpu"},
-               "metrics": {"catalog_lookup_p50_s": 0.0001,
-                           "catalog_lookup_p99_s": 0.002,
-                           "tier_ram_hit_rate": 0.5,
-                           "tier_disk_hit_rate": 0.1,
-                           "tier_wire_hit_rate": 0.2,
-                           "tier_cold_hit_rate": 0.05,
-                           "tier_derive_rate": 0.15,
-                           "slo_attained": 0.98}}
-        m = bench_metrics(rep)
-        assert set(m) == {"catalog_lookup_p50_s",
-                          "catalog_lookup_p99_s",
-                          "tier_ram_hit_rate", "tier_disk_hit_rate",
-                          "tier_wire_hit_rate", "tier_cold_hit_rate",
-                          "slo_attained"}
-        assert metric_lower_is_better("catalog_lookup_p99_s")
-        assert not metric_lower_is_better("tier_cold_hit_rate")
-        assert not metric_lower_is_better("slo_attained")
-        # And the band inverts for the catalog quantile exactly like
-        # the serve quantiles.
-        def r(p99):
-            return {"config": {"backend": "cpu"},
-                    "metrics": {"catalog_lookup_p99_s": p99}}
-
-        worse = bench_diff(r(0.08), [r(0.002), r(0.003)], rel_tol=0.2)
-        assert worse["metrics"]["catalog_lookup_p99_s"][
-            "status"] == "regress"
-
-    def test_latency_quantiles_invert_the_band(self):
-        # Lower-is-better: a p99 RISING above the noise band regresses;
-        # dropping below it improves.  Higher-is-better metrics in the
-        # same record keep their direction.
-        def rec(p99, hr=0.9):
-            return {"config": {"backend": "cpu"},
-                    "metrics": {"fleet_request_p99_s": p99,
-                                "fleet_hit_rate": hr}}
-
-        baselines = [rec(1.0), rec(1.2)]
-        worse = bench_diff(rec(2.0), baselines, rel_tol=0.2)
-        assert worse["metrics"]["fleet_request_p99_s"][
-            "status"] == "regress"
-        assert worse["verdict"] == "regress"
-        better = bench_diff(rec(0.5), baselines, rel_tol=0.2)
-        assert better["metrics"]["fleet_request_p99_s"][
-            "status"] == "improved"
-        assert better["verdict"] == "pass"
-        inside = bench_diff(rec(1.1), baselines, rel_tol=0.2)
-        assert inside["metrics"]["fleet_request_p99_s"][
-            "status"] == "ok"
-        # The higher-is-better metric still regresses from BELOW.
-        low_hr = bench_diff(rec(1.0, hr=0.2), baselines, rel_tol=0.2)
-        assert low_hr["metrics"]["fleet_hit_rate"][
-            "status"] == "regress"
-
-    def test_rig_filter_excludes_other_backends(self):
-        tpu = dict(self.BASE, value=100.0,
-                   config={"backend": "tpu", "name": "tpu"})
-        v = bench_diff(dict(self.BASE, value=10.0), [tpu], rel_tol=0.2)
-        assert v["baselines"] == 0
-        assert v["baselines_skipped_other_rig"] == 1
-        assert v["metrics"]["ingest_GBps"]["status"] == "new"
-        assert v["verdict"] == "pass"
-        crossed = bench_diff(dict(self.BASE, value=10.0), [tpu],
-                             rel_tol=0.2, cross_rig=True)
-        assert crossed["verdict"] == "regress"
-
-    def test_wrapper_loading_prefers_parsed_then_tail(self, tmp_path):
-        p = tmp_path / "BENCH_x.json"
-        p.write_text(json.dumps(self._wrap(self.BASE)))
-        assert load_bench_json(str(p))["value"] == 10.0
-        w = self._wrap(self.BASE)
-        w["parsed"] = None  # old wrapper: fall back to the tail line
-        p.write_text(json.dumps(w))
-        assert load_bench_json(str(p))["value"] == 10.0
-        w["tail"] = "Traceback (most recent call last):\n  boom"
-        p.write_text(json.dumps(w))
-        with pytest.raises(ValueError):
-            load_bench_json(str(p))
-
-    def test_cli_flags_synthetic_regression_and_passes_history(
-            self, tmp_path, capsys):
-        """Acceptance (ISSUE 11): exit 2 on a synthetic regression, exit
-        0 on a matching-trajectory fresh record — over wrapper files."""
-        from blit.__main__ import main
-
-        for i, val in enumerate((9.0, 10.0, 11.0)):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps(self._wrap(dict(self.BASE, value=val))))
-        ok = tmp_path / "fresh_ok.json"
-        ok.write_text(json.dumps(dict(self.BASE, value=10.2)))
-        assert main(["bench-diff", "--baseline-dir", str(tmp_path),
-                     str(ok)]) == 0
-        bad = tmp_path / "fresh_bad.json"
-        bad.write_text(json.dumps(dict(self.BASE, value=1.0)))
-        rc = main(["bench-diff", "--baseline-dir", str(tmp_path),
-                   str(bad)])
-        assert rc == 2
-        out = capsys.readouterr().out
-        assert "REGRESS" in out
-
-    def test_checked_in_trajectory_passes(self, capsys):
-        """The repo's own BENCH history is a passing trajectory (the CI
-        gate's steady-state leg): the newest record diffed against the
-        older rounds — same-rig only, failed rounds skipped."""
-        from blit.__main__ import main
-
-        baselines = sorted(
-            p for p in os.listdir(REPO)
-            if p.startswith("BENCH_r") and p.endswith(".json"))
-        assert baselines, "no checked-in BENCH trajectory?"
-        fresh = os.path.join(REPO, baselines[-1])
-        rc = main(["bench-diff", "--baseline-dir", REPO, fresh])
-        assert rc == 0
-        out = capsys.readouterr().out
-        assert "PASS" in out
-
-    def test_checked_in_regression_is_flagged(self, tmp_path, capsys):
-        """A 5x-slower synthetic derived from the newest checked-in
-        record must regress against the real trajectory (exit 2)."""
-        from blit.__main__ import main
-
-        baselines = sorted(
-            p for p in os.listdir(REPO)
-            if p.startswith("BENCH_r") and p.endswith(".json"))
-        doc = load_bench_json(os.path.join(REPO, baselines[-1]))
-        reg = {k: (v * 0.2 if isinstance(v, (int, float))
-                   and not isinstance(v, bool) else v)
-               for k, v in doc.items()}
-        p = tmp_path / "regressed.json"
-        p.write_text(json.dumps(reg))
-        rc = main(["bench-diff", "--baseline-dir", REPO, str(p)])
-        assert rc == 2
-        assert "regress" in capsys.readouterr().out.lower()
 
 
 # -- packaging / config ------------------------------------------------------

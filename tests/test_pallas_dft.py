@@ -1,5 +1,5 @@
 """Pallas DFT-stage kernel tests — interpreter mode on CPU (the real-TPU
-path is exercised by bench.py / the driver's compile checks)."""
+path is exercised by chip_smoke.py / the driver's compile checks)."""
 
 import numpy as np
 import pytest

@@ -6,7 +6,7 @@ supervised multi-process sharded scans under seeded ``kill``/``hang``
 faults — detection within the lease budget, degrade-and-resume, and
 final products BYTE-IDENTICAL to an uninterrupted pool-oracle run —
 plus the supervised live-consumer rejoin drill (``StreamSupervisor``)
-and the ``blit chaos`` / ``ingest-bench --chaos`` CLI surfaces.
+and the ``blit chaos`` CLI surface.
 
 The subprocess drills each pay child jax imports; sizes are the chaos
 CLI's smallest (2x2 grid, nfft=32) so the whole module stays well

@@ -2,14 +2,11 @@
 propagation over the serve HTTP wire, per-request access records
 (RequestLog + `blit requests`), histogram exemplars (OpenMetrics
 exposition + `blit trace-view --exemplar`), per-reason flight-dump rate
-limiting, flight-dump trace correlation, tracer thread-safety under
-hedged/coalesced concurrency, and the real-subprocess stitched-trace
-acceptance drill."""
+limiting, flight-dump trace correlation, and tracer thread-safety under
+hedged/coalesced concurrency."""
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -404,7 +401,7 @@ class TestTracePropagation:
                                                   tmp_path):
         """Tentpole #1: the door's fleet.request → fleet.dispatch chain
         continues into serve.reduce THROUGH the HTTP wire (in-process
-        servers here; the subprocess twin is the acceptance drill)."""
+        servers)."""
         observability.tracer().reset()
         fleet.door.get(make_req(tmp_path), client="tp")
         fr = spans_by_name("fleet.request")
@@ -715,80 +712,3 @@ class TestTraceViewFleet:
         path = rec.dump("classic: drill")
         assert main(["trace-view", path]) == 0
         assert "classic: drill" in capsys.readouterr().out
-
-
-# -- the real-subprocess acceptance drill ------------------------------------
-
-
-@pytest.mark.slow
-class TestFleetEndToEndTrace:
-    def test_subprocess_fleet_stitches_one_trace(self, tmp_path):
-        """ISSUE 15 acceptance: a real-subprocess fleet (hedge drill —
-        the tiny hedge floor forces hedged dispatch on the slow cold
-        reductions) produces ONE stitched trace in which a peer-side
-        serve.reduce span's parent is a front-door span from ANOTHER
-        process, and the fleet.request_s tail-bucket exemplar resolves
-        to a logged trace via `blit trace-view`."""
-        trace_out = str(tmp_path / "fleet-trace.json")
-        reqlog = str(tmp_path / "reqlog")
-        res = subprocess.run(
-            [sys.executable, "-m", "blit", "serve-bench", "--fleet",
-             "--requests", "16", "--distinct", "3", "--clients", "3",
-             "--peers", "2", "--nfft", "128",
-             "--trace-out", trace_out, "--request-log", reqlog],
-            capture_output=True, text=True, timeout=560,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert res.returncode == 0, res.stderr[-2000:]
-        rep = json.loads(res.stdout.strip().splitlines()[-1])
-        # ≥1 cross-process parent/child pair in the artifact (the CI
-        # fleet-smoke assertion, pinned here too).
-        assert rep["trace"]["cross_process_pairs"] >= 1, rep["trace"]
-        assert rep["trace"]["processes"] >= 2
-        assert rep["request_log"]["door_records"] == 16
-        assert rep["request_log"]["p99_s"] > 0
-        # The saved snapshot re-stitches: find a peer-side serve.reduce
-        # whose parent lives in a DIFFERENT process (the door's
-        # dispatch span).
-        snap = json.load(open(rep["trace"]["snapshot"]))
-        spans = snap["spans"]
-        by_id = {s["span"]: s for s in spans}
-        proc = observability.span_process
-        cross = [
-            s for s in spans
-            if s["name"] == "serve.reduce" and s.get("parent") in by_id
-            and proc(s["parent"]) != proc(s["span"])
-            and by_id[s["parent"]]["name"] == "fleet.dispatch"
-        ]
-        assert cross, "no cross-process serve.reduce→fleet.dispatch edge"
-        # The exemplar resolves through `blit trace-view --fleet` to a
-        # trace that is ALSO in the request log (page → exemplar →
-        # trace → request record, the runbook loop).
-        res2 = subprocess.run(
-            [sys.executable, "-m", "blit", "trace-view", "--fleet",
-             rep["trace"]["snapshot"],
-             "--exemplar", "fleet.request_s"],
-            capture_output=True, text=True, timeout=120,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert res2.returncode == 0, res2.stderr[-2000:]
-        head = json.loads(res2.stdout.splitlines()[0])
-        ex_trace = head["exemplar"]["trace"]
-        logged = {r["trace"] for r in monitor.filter_requests(
-            monitor.read_requests(reqlog), role="door")}
-        assert ex_trace in logged
-        assert f"trace {ex_trace}" in res2.stdout
-
-    def test_request_log_compare_disabled_is_free(self, tmp_path):
-        """Acceptance bound: disabled request logging adds ZERO records
-        (measured) and the A/B report prices the enabled pass."""
-        res = subprocess.run(
-            [sys.executable, "-m", "blit", "serve-bench",
-             "--requests", "24", "--distinct", "4", "--clients", "3",
-             "--nfft", "128", "--request-log-compare"],
-            capture_output=True, text=True, timeout=560,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        assert res.returncode == 0, res.stderr[-2000:]
-        rep = json.loads(res.stdout.strip().splitlines()[-1])
-        assert rep["request_log_compare"] is True
-        assert rep["off_records"] == 0
-        assert rep["on_records"] == 24
-        assert "overhead_pct" in rep

@@ -2,7 +2,7 @@
 a completed recording == the batch reduction, for .fil/.h5/.hits, under
 reordering/duplicate/dropped-chunk faults with masking engaged), the
 watermark lateness semantics, the growing-file tailer, the latency
-metrics, and the `blit stream` / `ingest-bench --live` CLI legs."""
+metrics, and the `blit stream` CLI legs."""
 
 import io
 import contextlib
@@ -476,21 +476,27 @@ class TestWorkersAndCLI:
             rc = main(argv)
         return rc, json.loads(buf.getvalue().strip().splitlines()[-1])
 
-    def test_cli_stream_smoke(self, tmp_path):
+    @pytest.mark.parametrize("hdrkw, rate", [
+        ({}, "1000"), ({"tbin": 0.2 / (4 * (8 + 3) * NFFT)}, "8"),
+    ], ids=["as-fast-as-read", "paced"])
+    def test_cli_stream_smoke(self, tmp_path, hdrkw, rate):
         # The tier-1 CLI smoke (ISSUE 7 satellite): accelerated replay
-        # through `blit stream`, latency percentiles in the report.
+        # through `blit stream`, latency percentiles in the report.  The
+        # paced case is a 0.2 s recording replayed 8x against the clock:
+        # no chunk late, none masked, and a latency that is really one.
         raw = tmp_path / "r.raw"
-        _synth(raw)
+        _synth(raw, **hdrkw)
         out = str(tmp_path / "s.fil")
         rc, rep = self._main([
             "stream", str(raw), "-o", out, "--nfft", str(NFFT),
-            "--nint", str(NINT), "--replay-rate", "1000",
+            "--nint", str(NINT), "--replay-rate", rate,
         ])
         assert rc == 0
         assert rep["output"] == out
-        assert rep["masked_chunks"] == 0
+        assert rep["masked_chunks"] == 0 and rep["late_chunks"] == 0
+        assert rep["degraded_spectra"] == 0
         assert rep["chunk_to_product_p99_s"] >= rep[
-            "chunk_to_product_p50_s"] >= 0.0
+            "chunk_to_product_p50_s"] > 0.0
         assert _read(out) == _batch(raw, tmp_path / "ref.fil")
 
     def test_cli_stream_search_smoke(self, tmp_path):
@@ -505,24 +511,3 @@ class TestWorkersAndCLI:
         assert rc == 0
         assert rep["windows"] >= 1
         assert os.path.exists(out)
-
-    def test_ingest_bench_live_and_drill(self, tmp_path):
-        # The accelerated-replay latency leg: zero dropped windows on
-        # the clean path; the seeded late-chunk drill masks (does not
-        # wedge) and leaves a flight dump.
-        rc, rep = self._main([
-            "ingest-bench", "--nfft", str(NFFT), "--chunk-frames", "4",
-            "--chunks", "4", "--blocks", "4", "--live",
-            "--live-rate", "8", "--live-seconds", "0.2", "--live-drill",
-        ])
-        assert rc == 0
-        live = rep["live"]
-        assert live["degraded_spectra"] == 0
-        assert live["late_chunks"] == 0
-        assert live["chunk_to_product_p99_s"] >= live[
-            "chunk_to_product_p50_s"] > 0.0
-        drill = rep["live_drill"]
-        assert drill["masked_chunks"] == 1
-        assert drill["late_chunks"] == 1
-        assert drill["degraded_spectra"] > 0
-        assert os.path.exists(drill["flight_dump"])

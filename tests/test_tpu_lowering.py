@@ -45,7 +45,7 @@ def lower_for_tpu(fn, *specs):
 
 class TestEveryKernelLowersForTheTpu:
     """Production shapes: nfft 2^20 = 128 x 128 x 64, 8 frames per chunk,
-    f32 and bf16 stages; the collective kernels at bench.py's shapes."""
+    f32 and bf16 stages; the collective kernels at chip_smoke.py's shapes."""
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     def test_pfb_dft1(self, dtype):
@@ -258,35 +258,3 @@ class TestCompileCachePlacement:
         want = os.path.join(checkout, ".jax_cache")
         assert device.use_compile_cache() == want
         assert calls == [("jax_compilation_cache_dir", want)]
-
-
-class TestBenchRefusesToFallBack:
-    def _bench(self):
-        import importlib.util
-
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py")
-        sp = importlib.util.spec_from_file_location("blit_bench", path)
-        mod = importlib.util.module_from_spec(sp)
-        sp.loader.exec_module(mod)
-        return mod
-
-    def test_cpu_config_needs_the_caller_to_name_the_cpu(self, monkeypatch,
-                                                         capsys):
-        import json
-
-        bench = self._bench()
-        monkeypatch.setattr(bench, "_probe_platform", lambda: "cpu")
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
-        assert bench.main() == 1
-        rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["platform"] == "cpu" and rec["value"] == 0.0
-        assert "JAX_PLATFORMS=cpu" in rec["errors"]["run"]
-
-    def test_unknown_platform_is_an_error(self, monkeypatch, capsys):
-        bench = self._bench()
-        monkeypatch.setattr(bench, "_probe_platform", lambda: "gpu")
-        monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
-        assert bench.main() == 1
-        assert "no bench config" in capsys.readouterr().out
