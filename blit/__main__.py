@@ -1055,8 +1055,10 @@ def _chaos_corrupt(args: argparse.Namespace, work: str,
     write_raw(os.path.join(oracle_dir, "chaos.raw"),
               dict(rdr0.header(0)), blocks)
     integrity.write_raw_digests(raw)
-    # One chunk spans the whole recording: leave the (ntap-1)-frame PFB
-    # tail after chunk_frames so every block lands as one delivery.
+    # One chunk spans the whole recording (its (ntap-1)-frame head and
+    # chunk_frames new frames), so every block lands as one delivery —
+    # but for block 0, whose first samples are the stream's head: a
+    # delivery of its own, ahead of the rest of the block.
     cf = max(args.nint, (nblocks * per_block) // args.nfft - 3)
     kw = dict(nfft=args.nfft, nint=args.nint, chunk_frames=cf,
               tune_online=False)
@@ -1066,7 +1068,7 @@ def _chaos_corrupt(args: argparse.Namespace, work: str,
     out = os.path.join(work, "chaos.fil")
     faults.reset_counters()
     faults.install(faults.FaultRule(point="guppi.read", mode="corrupt",
-                                    after=victim, times=1))
+                                    after=victim + (victim > 0), times=1))
     try:
         rdr = GuppiRaw(raw)  # arms the digest sidecar
         hdr = RawReducer(**kw).reduce_to_file(rdr, out)

@@ -243,21 +243,33 @@ class HostLink:
     def put(self, host, device=None, timeline=None,
             then: Optional[Callable] = None):
         """``jax.device_put(host, device)`` through the budget, or with
-        ``then`` what that program returns for the put array."""
+        ``then`` what that program returns for what was put.  ``host`` is
+        an array, or a tree of arrays admitted as one transfer (a stream's
+        head with its first samples).  Each array put is counted in the
+        row ``link.put`` of ``timeline`` (a counted instant: ``calls`` =
+        arrays, ``bytes`` = host->device bytes), budgeted backend or not:
+        the row says what a reduction sent up, whatever the link made of
+        it.  The handle kept in flight must outlive its transfer: a
+        program that takes a put array by donation hands back something
+        else (``then``'s result), never the array."""
         import jax
 
-        counted = self._admit(host.nbytes, timeline)
+        leaves = jax.tree_util.tree_leaves(host)
+        nbytes = sum(a.nbytes for a in leaves)
+        counted = self._admit(nbytes, timeline)
         try:
+            if timeline is not None:
+                timeline.mark("link.put", nbytes, calls=len(leaves))
             out = jax.device_put(host, device)
             if then is not None:
                 out = then(out)
         except BaseException:
             if counted:
-                self._release(host.nbytes)
+                self._release(nbytes)
             raise
         if counted:
             with self._cv:
-                self._puts.append((out, host.nbytes))
+                self._puts.append((out, nbytes))
         return out
 
     @contextlib.contextmanager

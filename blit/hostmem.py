@@ -30,8 +30,9 @@ through faulted memory — so the hit rate is observable in every
 telemetry report.
 
 What it may hold follows what it can see (ISSUE 25).  A recorder-width
-hi-res chunk buffer is ``(64, 11*2**20, 2, 2)`` int8 = 2.95 GB and a
-rotation up to three of them; any constant sized for smaller streams
+hi-res chunk buffer is ``(64, 8*2**20, 2, 2)`` int8 = 2.15 GB and a
+rotation up to three of them (beside the stream's 0.81 GB head slab);
+any constant sized for smaller streams
 throws such a rotation away at every teardown, and the next reduction
 first-touches it anew (on the v5e machine 0.9 GB/s of ``ingest``
 against 15 GB/s into the same buffer again, PERF.md §5/§6).  So the pool keeps a
@@ -63,7 +64,7 @@ _ALIGN = 4096  # page size: the readinto/pread alignment contract
 # What the pool may always keep when no cap is set: room for the small
 # shapes of a serving process or a test suite to coexist without
 # hoarding RSS.  It is a floor, not the budget: a recorder-width
-# rotation weighs 3 x 2.95 GB, and the pool keeps what the last stretch
+# rotation weighs 3 x 2.15 GB, and the pool keeps what the last stretch
 # of work held at its peak (module docstring).  Per-process.
 _DEFAULT_BUDGET = 2 << 30
 

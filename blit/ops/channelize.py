@@ -300,14 +300,16 @@ def last_kernel_plan() -> dict:
     return dict(_LAST_PLAN)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "nfft", "ntap", "nint", "stokes", "fft_method", "precision",
-        "channel_block", "dtype", "fqav_by", "dft_order", "pfb_kernel",
-        "detect_kernel", "tail_kernel",
-    ),
+# The keyword arguments that select a program (:func:`channelize`'s, and
+# :func:`channelize_stream`'s, which passes them through).
+_CHANNELIZE_STATIC = (
+    "nfft", "ntap", "nint", "stokes", "fft_method", "precision",
+    "channel_block", "dtype", "fqav_by", "dft_order", "pfb_kernel",
+    "detect_kernel", "tail_kernel",
 )
+
+
+@functools.partial(jax.jit, static_argnames=_CHANNELIZE_STATIC)
 def channelize(
     voltages: jax.Array,
     coeffs: jax.Array,
@@ -701,46 +703,153 @@ def channelize(
     return out
 
 
-def channelize_blocked(
-    voltages,
-    coeffs,
-    *,
-    channel_block: int,
-    put: Callable = lambda group, then: then(group),
-    **kw,
-) -> jax.Array:
-    """Host-looped channel blocking: the compile-friendly replacement for
-    ``channelize(channel_block=)``'s in-jit ``lax.map`` (whose XLA loop
-    blows compile time past 500 s at nfft=2^20, DESIGN.md §3/§9).
+def _word_dtype(npol: int, ncomp: int = 2) -> np.dtype:
+    """The signed integer as wide as one time sample (``npol`` x (re, im)
+    int8): int32 at two polarizations, int16 at one."""
+    return np.dtype(f"i{npol * ncomp}")
 
-    Dispatches :func:`channelize` once per ``channel_block``-sized group of
-    coarse channels — ONE jit compile (group shape is constant), dispatches
-    enqueued async back-to-back, device-side concatenation of the per-group
-    products.  Peak HBM is bounded by one group's intermediates plus the
-    final product, so the per-*call* net work can grow well past what the
-    flat layout fits (the dispatch-amortization lever of DESIGN.md §3 at
-    bounded memory, now at seconds-scale compile).  ``put(group, then=)``
-    takes a group of host voltages to the device and returns what its
-    program ``then`` makes of them (the caller's transfer policy; by
-    default the jit's own argument transfer).
 
-    Same result as ``channelize(..., channel_block=0)`` (golden-tested).
+def sample_words(voltages: np.ndarray) -> np.ndarray:
+    """Host int8 voltages ``(nchan, ntime, npol, 2)`` as ``(nchan, ntime)``
+    machine words, one per time sample (int32 at two polarizations, int16
+    at one): a VIEW of the same memory, the form a stream's samples cross
+    the host link in (:func:`channelize_stream`).
+
+    Why a view and not the array itself: the TPU runtime re-tiles what it
+    is handed on the host, and an int8 array whose minor dimensions are
+    ``(2, 2)`` costs it far more than the words it is made of — on a v5e
+    a 32-channel x 8-frame hi-res group (1.07 GB, ``ntime`` 2^23) goes up
+    in 0.53 s and 4.3 cpu-s as int8 ``(32, 2^23, 2, 2)``, in 0.10 s and
+    0.17 cpu-s as int32 ``(32, 2^23)``; the 11-frame group the reducer
+    sent until PR 29 in 0.29 s against 0.15 (PERF.md section 6, PR 29)."""
+    nchan, ntime, npol, ncomp = voltages.shape
+    return voltages.reshape(nchan, ntime, npol * ncomp).view(
+        _word_dtype(npol, ncomp))[..., 0]
+
+
+def _word_samples(words: jax.Array) -> jax.Array:
+    """The inverse of :func:`sample_words`, in a program: ``(nchan, ntime)``
+    words → int8 ``(nchan, ntime, npol, 2)`` (a bitcast: byte 0 of a word
+    is the first polarization's real part, as on the host)."""
+    v = jax.lax.bitcast_convert_type(words, jnp.int8)
+    return v.reshape(words.shape + (v.shape[-1] // 2, 2))
+
+
+@functools.partial(jax.jit, static_argnames=_CHANNELIZE_STATIC,
+                   donate_argnames=("tail",))
+def channelize_stream(
+    tail: jax.Array, body: jax.Array, coeffs: jax.Array, **kw
+) -> Tuple[jax.Array, jax.Array]:
+    """One dispatch of a STREAM: ``concat(tail, body)`` reduced exactly as
+    :func:`channelize` (kwargs ``kw``) reduces that gross block, and the
+    filter state the next dispatch of the same channels starts from.
+
+    Both are :func:`sample_words` of voltages: ``tail`` ``(nchan,
+    (ntap-1)*nfft)`` is the last ``ntap - 1`` frames' worth of samples
+    before ``body`` ``(nchan, frames*nfft)``, the stream's new samples.
+    Returns ``(product, next_tail)``: ``next_tail`` is the last
+    ``(ntap-1)*nfft`` words of the concatenation (a body shorter than the
+    filter state keeps part of the old tail by the same line),
+    device-resident data the next dispatch consumes like
+    :func:`integrate_carry`'s accumulator — a stream's filter state
+    crosses the host link once, as its head.  ``tail`` is DONATED: the
+    next tail takes its place in device memory (a group's filter state is
+    held once, not twice), and a device array passed as ``tail`` is
+    deleted by the call.
     """
-    nchan = voltages.shape[0]
-    def program(group):
-        return channelize(group, coeffs, **kw)
+    gross = jnp.concatenate([tail, body], axis=1)
+    return (channelize(_word_samples(gross), coeffs, **kw),
+            gross[:, gross.shape[1] - tail.shape[1]:])
 
+
+def _direct_put(host, then: Callable):
+    """The default transfer policy: the jit's own argument transfer."""
+    return then(host)
+
+
+def _channel_groups(nchan: int, channel_block: int) -> range:
+    """Group starts for ``channel_block``-sized groups of ``nchan`` coarse
+    channels (one group where the block does not split them)."""
     if channel_block <= 0 or channel_block >= nchan:
-        return put(voltages, then=program)
+        channel_block = nchan
     if nchan % channel_block:
         raise ValueError(
             f"channel_block={channel_block} does not divide nchan={nchan}"
         )
-    outs = [
-        put(voltages[c : c + channel_block], then=program)
-        for c in range(0, nchan, channel_block)
-    ]
-    return jnp.concatenate(outs, axis=-1)
+    return range(0, nchan, channel_block)
+
+
+def split_tails(head, channel_block: int) -> list:
+    """A stream's head ``(nchan, (ntap-1)*nfft, npol, 2)`` as the per-group
+    ``tails`` of its first dispatch (views: nothing is copied)."""
+    groups = _channel_groups(head.shape[0], channel_block)
+    return [head[c : c + groups.step] for c in groups]
+
+
+def _put_group(put: Callable, tail, body, program: Callable):
+    """One group's new samples through ``put``, as :func:`sample_words`,
+    to ``program(tail, body)`` — and with them its filter state, where
+    that is still the stream's head in host memory (one transfer of the
+    pair: the tail is donated to the program, so no handle on it may
+    outlive the call)."""
+    body = sample_words(body)
+    if isinstance(tail, np.ndarray):
+        return put((sample_words(tail), body),
+                   then=lambda up: program(*up))
+    return put(body, then=lambda up: program(tail, up))
+
+
+def channelize_blocked(
+    voltages,
+    coeffs,
+    tails: list,
+    *,
+    channel_block: int,
+    put: Callable = _direct_put,
+    **kw,
+) -> Tuple[jax.Array, list]:
+    """Host-looped channel blocking of a stream's chunk: the
+    compile-friendly replacement for ``channelize(channel_block=)``'s
+    in-jit ``lax.map`` (whose XLA loop blows compile time past 500 s at
+    nfft=2^20, DESIGN.md §3/§9).
+
+    ``voltages`` is the chunk's NEW samples only, host int8 ``(nchan,
+    frames*nfft, npol, 2)``; ``tails`` holds each group's filter state
+    (:func:`split_tails` of the stream's head for its first dispatch, the
+    previous dispatch's second result — device words — after that).
+    Dispatches :func:`channelize_stream` once per ``channel_block``-sized
+    group of coarse channels — ONE jit compile (group shape is constant, and a tail
+    that came up from the host has the shape and dtype of one that stayed
+    on the chip), dispatches enqueued async back-to-back, device-side
+    concatenation of the per-group products.  Peak HBM is bounded by one
+    group's intermediates plus the final product, so the per-*call* net
+    work can grow well past what the flat layout fits (the
+    dispatch-amortization lever of DESIGN.md §3 at bounded memory, now at
+    seconds-scale compile).  ``put(host, then=)`` takes host memory (a
+    group's :func:`sample_words`, or the pair ``(head, samples)`` of them
+    on a stream's first dispatch) to the device and returns what the
+    program ``then`` makes of it — the caller's transfer policy; by
+    default the jit's own argument transfer.
+
+    Returns ``(product, tails)``: the product of ``channelize(concat(tail,
+    voltages), ..., channel_block=0)`` (golden-tested) and the tails to
+    hand to the stream's next dispatch.
+    """
+    groups = _channel_groups(voltages.shape[0], channel_block)
+    outs, new_tails = [], []
+
+    def program(tail, body):
+        # The product alone goes back to ``put`` (its handle on the
+        # transfer); the next tail leaves the same execution.
+        out, nxt = channelize_stream(tail, body, coeffs, **kw)
+        new_tails.append(nxt)
+        return out
+
+    for g, c in enumerate(groups):
+        outs.append(_put_group(put, tails[g],
+                               voltages[c : c + groups.step], program))
+    return (outs[0] if len(outs) == 1
+            else jnp.concatenate(outs, axis=-1)), new_tails
 
 
 @functools.partial(jax.jit, static_argnames=("nint",))
@@ -787,53 +896,52 @@ def integrate_carry(
 def channelize_carry(
     voltages,
     coeffs,
+    tails: list,
     accs: Optional[list],
     filled: int,
     *,
     channel_block: int,
     nint: int,
-    put: Callable = lambda group, then: then(group),
+    put: Callable = _direct_put,
     **kw,
-) -> Tuple[Optional[jax.Array], list]:
+) -> Tuple[Optional[jax.Array], list, list]:
     """:func:`channelize_blocked` for an integration carried across
     dispatches: each ``channel_block``-sized group of coarse channels is
-    channelized to frame-major power and folded into that group's
+    channelized to frame-major power (:func:`channelize_stream`, its
+    filter state in ``tails`` as there) and folded into that group's
     device-resident accumulator (:func:`integrate_carry`).
 
-    ``accs`` is the previous dispatch's second result (``None`` to start
+    ``accs`` is the previous dispatch's third result (``None`` to start
     a stream) and ``filled`` the frames the open integration holds.
-    Returns ``(rows, accs)``: the ``(filled + nframes) // nint`` rows that
-    closed, ``(k, nif, nchan*nfft)`` assembled on the device — ``None``
-    when none did, and then no group's partial sum is concatenated,
-    fetched or written.  ``put`` as in :func:`channelize_blocked`.
+    Returns ``(rows, tails, accs)``: the ``(filled + nframes) // nint``
+    rows that closed, ``(k, nif, nchan*nfft)`` assembled on the device —
+    ``None`` when none did, and then no group's partial sum is
+    concatenated, fetched or written.  ``put`` as in
+    :func:`channelize_blocked`.
     """
-    nchan = voltages.shape[0]
-    if channel_block <= 0 or channel_block >= nchan:
-        channel_block = nchan
-    if nchan % channel_block:
-        raise ValueError(
-            f"channel_block={channel_block} does not divide nchan={nchan}"
-        )
+    groups = _channel_groups(voltages.shape[0], channel_block)
     at = np.int32(filled)  # data, not a static argument: one program
-    rows, new_accs, nclosed = [], [], 0
-    for g, c in enumerate(range(0, nchan, channel_block)):
-        def program(group, g=g):
+    rows, new_tails, new_accs, nclosed = [], [], [], 0
+    for g, c in enumerate(groups):
+        def program(tail, body, g=g):
             nonlocal nclosed
-            power = channelize(group, coeffs, nint=1, **kw)
+            power, nxt = channelize_stream(tail, body, coeffs, nint=1, **kw)
+            new_tails.append(nxt)
             nclosed = (filled + power.shape[0]) // nint
             acc = (jnp.zeros(power.shape[1:], jnp.float32) if accs is None
                    else accs[g])
             return integrate_carry(power, acc, at, nint=nint)
 
-        closed, acc = put(voltages[c : c + channel_block], then=program)
+        closed, acc = _put_group(put, tails[g],
+                                 voltages[c : c + groups.step], program)
         new_accs.append(acc)
         if nclosed:
             rows.append(closed if nclosed == closed.shape[0]
                         else closed[:nclosed])
     if not nclosed:
-        return None, new_accs
+        return None, new_tails, new_accs
     return (rows[0] if len(rows) == 1
-            else jnp.concatenate(rows, axis=-1)), new_accs
+            else jnp.concatenate(rows, axis=-1)), new_tails, new_accs
 
 
 @functools.lru_cache(maxsize=None)
@@ -842,31 +950,43 @@ def channels_per_dispatch(
     budget_bytes: int,
     **kw,
 ) -> int:
-    """How many coarse channels of a ``shape`` voltage chunk one
-    :func:`channelize` dispatch (kwargs ``kw``) may take inside
-    ``budget_bytes`` of device memory — the ``channel_block`` for
+    """How many coarse channels of a stream's chunk — ``shape`` is its new
+    samples' ``(nchan, frames*nfft, npol, 2)`` — one
+    :func:`channelize_stream` dispatch (kwargs ``kw``) may take inside
+    ``budget_bytes`` of device memory: the ``channel_block`` for
     :func:`channelize_blocked`.  Cached process-wide: a fresh reducer per
     request or per bank asks once.
 
     The account is the compiler's own, not a model of today's kernels: a
-    probe program is compiled and its argument, temporary and output bytes
-    read back (``memory_analysis``).  Those scale linearly with the channel
-    count from about 8 channels up (below that the TPU's tiled layouts pad
-    the small batch and the per-channel figure is off by up to 2.5x), so
-    the probe runs at 8 channels and the answer is the largest divisor of
-    ``shape[0]`` that fits.  One extra compile of a few seconds, paid once
-    per chunk shape.  Raises when not even one channel fits.
+    probe of the program that runs (filter state and new samples in,
+    product and next filter state out) is compiled and its argument,
+    temporary and output bytes read back (``memory_analysis``: whatever
+    the concatenation materialises is in it), less what the program
+    aliases (the donated tail) and less the filter state itself — every
+    group's stays on the device between dispatches, so it is the caller's
+    to count, whole, against ``budget_bytes``.  Those scale
+    linearly with the channel count from about 8 channels up (below that
+    the TPU's tiled layouts pad the small batch and the per-channel figure
+    is off by up to 2.5x), so the probe runs at 8 channels and the answer
+    is the largest divisor of ``shape[0]`` that fits.  One extra compile
+    of a few seconds, paid once per chunk shape.  Raises when not even one
+    channel fits.
     """
     nchan = shape[0]
+    ntap, nfft = kw.get("ntap", 4), kw["nfft"]
     divisors = [d for d in range(1, nchan + 1) if nchan % d == 0]
     probe = min(d for d in divisors if d >= min(8, nchan))
-    m = channelize.lower(
-        jax.ShapeDtypeStruct((probe,) + tuple(shape[1:]), jnp.int8),
-        jax.ShapeDtypeStruct((kw.get("ntap", 4), kw["nfft"]), jnp.float32),
+    word = _word_dtype(shape[2], shape[3])
+    m = channelize_stream.lower(
+        jax.ShapeDtypeStruct((probe, (ntap - 1) * nfft), word),
+        jax.ShapeDtypeStruct((probe, shape[1]), word),
+        jax.ShapeDtypeStruct((ntap, nfft), jnp.float32),
         **kw,
     ).compile().memory_analysis()
+    tail = probe * (ntap - 1) * nfft * word.itemsize
     per_chan = -(-(m.argument_size_in_bytes + m.temp_size_in_bytes
-                   + m.output_size_in_bytes) // probe)
+                   + m.output_size_in_bytes - m.alias_size_in_bytes
+                   - tail) // probe)
     fit = budget_bytes // per_chan
     if fit < 1:
         raise MemoryError(

@@ -12,10 +12,20 @@ Design:
 
 - Every chunk handed to the device has the same static shape, so XLA compiles
   the reduction exactly once and the steady state is pure streaming.
-- A chunk of ``chunk_frames + ntap - 1`` gross blocks of ``nfft`` samples
-  yields ``chunk_frames`` PFB frames; consecutive chunks share a
-  ``(ntap-1) * nfft``-sample filter-state overlap — frame continuity across
-  chunks is exact (golden-tested against a whole-file reduction).
+- A chunk is a stream's NEW samples only: ``chunk_frames`` blocks of
+  ``nfft`` samples, which yield ``chunk_frames`` PFB frames.  The
+  ``(ntap-1) * nfft`` samples before them — the filter state — are on the
+  device already: each channel group's program takes ``(tail, body)``,
+  reduces ``concat(tail, body)`` and returns the next tail beside its
+  product (:func:`blit.ops.channelize.channelize_stream`).  Only the
+  stream's HEAD, its first ``(ntap-1) * nfft`` samples, is read into a
+  slab of its own and goes up as the first dispatch's tail; no sample
+  crosses the host link twice.  What crosses is a VIEW of the int8
+  buffers, one machine word per time sample
+  (:func:`blit.ops.channelize.sample_words`): the runtime re-tiles an
+  int8 ``(..., npol, 2)`` array on the host at a fifth of the rate it
+  takes the same bytes as int32.  Frame continuity across chunks is
+  exact (golden-tested against a whole-file reduction).
 - Where an integration fits a dispatch, ``chunk_frames`` is a multiple of
   ``nint`` and each chunk integrates inside its own program.  Where it does
   not (``nint * nfft`` beyond the per-dispatch sample budget — rawspec's
@@ -28,10 +38,9 @@ Design:
 - Ingest is PIPELINED: a producer thread fills a rotation of
   ``prefetch_depth`` stable chunk buffers straight from the file (native
   threaded pread per block when built) while the device works on earlier
-  chunks.  Each buffer's first ``(ntap-1)*nfft`` samples are memcpy'd from
-  the previous buffer's tail (the filter state); every other byte is read
-  from disk exactly once, directly into its final position — no ring
-  shifting, and no per-chunk stabilization copy before dispatch (the
+  chunks.  Every byte is read from disk exactly once, directly into its
+  final position — no ring shifting, no filter-state copy between
+  buffers, and no per-chunk stabilization copy before dispatch (the
   buffers themselves are stable until released).
 """
 
@@ -60,6 +69,7 @@ from blit.ops.channelize import (
     channels_per_dispatch,
     output_header,
     pfb_coeffs,
+    split_tails,
     usable_frames,
 )
 
@@ -106,15 +116,25 @@ class ReductionStats:
 class _Chunk:
     """A filled chunk buffer handed to the consumer.  ``view`` aliases the
     rotation buffer; it stays valid until :meth:`release`, after which the
-    producer may refill it."""
+    producer may refill it.  A stream's first chunk also carries its
+    ``head``, the ``(ntap-1)*nfft`` samples before ``view`` (the reducer's
+    own slab, valid to the stream's end); every later one has ``None``."""
 
-    __slots__ = ("view", "frames", "_idx", "_free")
+    __slots__ = ("view", "frames", "head", "_idx", "_free")
 
-    def __init__(self, view: np.ndarray, frames: int, idx: int, free) -> None:
+    def __init__(self, view: np.ndarray, frames: int, idx: int, free,
+                 head: Optional[np.ndarray] = None) -> None:
         self.view = view
         self.frames = frames
+        self.head = head
         self._idx = idx
         self._free = free
+
+    @property
+    def nbytes(self) -> int:
+        """Host bytes this chunk sends to the device."""
+        return self.view.nbytes + (
+            0 if self.head is None else self.head.nbytes)
 
     def release(self) -> None:
         if self._free is not None:
@@ -122,19 +142,24 @@ class _Chunk:
             free(self._idx)
 
 
-class _OpenIntegration:
-    """What a carried reduction holds between dispatches: the frames the
-    open integration has so far, each channel group's accumulator on the
-    device, and the group size they were laid out for — the stream's first
-    chunk's, kept for every later one (a smaller flush chunk would fit
-    more channels per dispatch, and find no accumulator of that shape).
-    The last two are ``None`` until the stream's first dispatch."""
+class _StreamState:
+    """What a stream keeps on the device between dispatches, per channel
+    group: the filter state (``tails``: the last ``(ntap-1)*nfft`` samples
+    dispatched, the next dispatch's first) and, where the integration is
+    carried, the frames the open integration has so far (``filled``) and
+    its accumulators (``accs``).  ``channel_block`` is the
+    group size both were laid out for — the stream's first chunk's, kept
+    for every later one (a smaller flush chunk would fit more channels
+    per dispatch, and find no tail or accumulator of that shape).
+    ``tails``, ``accs`` and ``channel_block`` are ``None`` until the
+    stream's first dispatch."""
 
-    __slots__ = ("filled", "accs", "channel_block")
+    __slots__ = ("filled", "accs", "tails", "channel_block")
 
     def __init__(self) -> None:
         self.filled = 0
         self.accs: Optional[list] = None
+        self.tails: Optional[list] = None
         self.channel_block: Optional[int] = None
 
 
@@ -168,8 +193,8 @@ class BufferRotation:
       ``wait.ingest_slot`` (producer, no free slot) and ``wait.chunk``
       (consumer, nothing filled yet).
     - A slot is only refilled after the consumer released it; concurrent
-      READS of an emitted slot (e.g. copying a filter-state tail into the
-      next slot) are safe.
+      READS of an emitted slot (the antenna feeds copy a filter-state
+      tail into the next slot) are safe.
     - ``stall_timeout_s`` arms a producer-progress watchdog: a live
       producer that neither acquires nor emits for that long (a wedged
       NFS read, a hung decoder) raises in the consumer instead of
@@ -362,10 +387,11 @@ class RawReducer:
     # carried across dispatches.  An explicit value is kept as given
     # (nint need not divide it: the reduction then carries).
     chunk_frames: Optional[int] = None
-    # Per-stage timing/byte registry ("ingest" / "state" / "stream" on the
-    # source side; "dispatch" / "device" / "readback" / "write" on the
-    # output plane — see blit/outplane.py; "wait.*" where a pump thread
-    # blocked).  Every stage is also a span of the process tracer.
+    # Per-stage timing/byte registry ("ingest" / "stream" on the source
+    # side; "dispatch" / "device" / "readback" / "write" on the output
+    # plane — see blit/outplane.py; "wait.*" where a pump thread blocked;
+    # counted instants "link.put", "state.head" / "state.carry",
+    # "integrate.*").  Every stage is also a span of the process tracer.
     timeline: Timeline = field(default_factory=Timeline)
     # When set, a device-only JAX profiler trace wraps every streaming run
     # and the run's spans land beside it as blit-spans.json
@@ -414,6 +440,8 @@ class RawReducer:
         # request, the next scan window) stages through already-faulted
         # aligned slabs too.  One stream at a time per reducer instance.
         self._buf_cache: List[np.ndarray] = []
+        # The slab a stream's head is read into (same discipline).
+        self._head_slab: Optional[np.ndarray] = None
 
         # Per-rig tuning profile (ISSUE 8): knobs the caller left unset
         # resolve from this rig's content-addressed profile when one
@@ -559,16 +587,19 @@ class RawReducer:
                            self.quant_offset)
 
     def _retire_staging(self) -> None:
-        """Return the stream's chunk buffers to the process staging pool
-        (blit/hostmem.py) — called only after a TERMINAL sync (stream
-        fully drained / sink closed), never on an error path where an
-        un-synced dispatch might still read a buffer."""
+        """Return the stream's chunk buffers and head slab to the process
+        staging pool (blit/hostmem.py) — called only after a TERMINAL sync
+        (stream fully drained / sink closed), never on an error path where
+        an un-synced dispatch might still read a buffer."""
         from blit import hostmem
 
         pool = hostmem.slab_pool()
         for b in self._buf_cache:
             pool.give(b, self.timeline)
         self._buf_cache = []
+        if self._head_slab is not None:
+            pool.give(self._head_slab, self.timeline)
+            self._head_slab = None
         # Every dispatch is synced: the link budget lets go of the stream's
         # last handles now, not at the next reduction's first put.
         host_link().inflight_bytes()
@@ -602,13 +633,14 @@ class RawReducer:
         return kw
 
     def _channel_block(self, shape: Tuple[int, int, int, int]) -> int:
-        """Coarse channels per device dispatch for chunks of ``shape``: all
-        of them where the backend reports no memory limit (the CPU), else
-        as many as the device holds beside what the output plane keeps
-        resident — the products still in readback flight
-        (``out_depth - 1``), this chunk's per-group products and their
-        concatenation.  A 64-channel hi-res chunk is ~3 GB of int8 whose
-        f32 intermediates alone exceed a 16 GB chip.  Grouping changes no
+        """Coarse channels per device dispatch for chunks of ``shape`` (a
+        chunk's new samples): all of them where the backend reports no
+        memory limit (the CPU), else as many as the device holds beside
+        what stays resident — every group's filter state, the products
+        still in readback flight (``out_depth - 1``), this chunk's
+        per-group products and their concatenation.  A 64-channel hi-res
+        chunk is ~2 GB of int8 whose f32 intermediates alone exceed a
+        16 GB chip.  Grouping changes no
         arithmetic — every coarse channel reduces on its own
         (``channelize_blocked``'s golden test) — though a backend may round
         a differently-batched program differently in the last bit."""
@@ -616,26 +648,29 @@ class RawReducer:
         limit = hbm_bytes_limit()
         if limit is None:
             return nchan
-        frames = shape[1] // self.nfft - self.ntap + 1
+        frames = shape[1] // self.nfft
         row = (STOKES_NIF[self.stokes] * nchan
                * (self.nfft // self.fqav_by) * 4)
+        # Every group's filter state (the probe leaves its own out).
+        tails = nchan * (self.ntap - 1) * self.nfft * shape[2] * shape[3]
         kw = self._channelize_kw
         if self._carries:
             # The accumulators (the old set lives until the new one is
             # written) beside the rows a dispatch may close; the probe
             # program is the frame-major one the carry reads.
             product = (self.nint - 1 + frames) // self.nint * row
-            resident = 2 * row + (max(2, self.out_depth) + 1) * product
+            resident = (tails + 2 * row
+                        + (max(2, self.out_depth) + 1) * product)
             kw = dict(kw, nint=1)
         else:
             product = frames // self.nint * row
-            resident = (max(2, self.out_depth) + 1) * product
+            resident = tails + (max(2, self.out_depth) + 1) * product
         cb = channels_per_dispatch(
             tuple(shape), int(_HBM_FRACTION * limit) - resident, **kw,
         )
-        log.debug("chunk %s: %d of %d coarse channels per dispatch "
-                  "(device limit %d B, %d B of products resident)",
-                  shape, cb, nchan, limit, resident)
+        log.info("chunk %s: %d of %d coarse channels per dispatch "
+                 "(device limit %d B, %d B resident)",
+                 shape, cb, nchan, limit, resident)
         return cb
 
     @property
@@ -643,54 +678,58 @@ class RawReducer:
         """Does an integration straddle dispatches (module docstring)?"""
         return self.chunk_frames % self.nint != 0
 
-    def _open_integration(self) -> Optional[_OpenIntegration]:
-        """A stream's carry state, ``None`` where each chunk integrates
-        inside its own program.  One per stream: a stream starts on a row
-        boundary (``skip_frames`` is whole rows)."""
-        return _OpenIntegration() if self._carries else None
-
-    def _dispatch(self, chunk: np.ndarray,
-                  carry: Optional[_OpenIntegration] = None):
+    def _dispatch(self, chunk: _Chunk, st: _StreamState):
         """One host chunk → ``(product, token)``, dispatched async in as
-        many channel groups as :meth:`_channel_block` says (each group's
-        voltages go up on their own, so the whole chunk is never resident
-        as one input).  ``token`` is ready once the chunk's input has been
-        consumed.  With ``carry`` the product is the rows that closed in
-        this chunk — ``None`` when none did — and ``carry`` moves on."""
-        frames = chunk.shape[1] // self.nfft - self.ntap + 1
+        many channel groups as :meth:`_channel_block` says for the
+        stream's first chunk (each group's new samples go up on their own,
+        so the whole chunk is never resident as one input; its filter
+        state is on the chip already — ``st.tails``, the previous
+        dispatch's output, or the stream's head going up once).  ``token``
+        is ready once the chunk's input has been consumed (the next tails
+        leave the same programs).  Where the integration is carried the
+        product is the rows that closed in this chunk — ``None`` when none
+        did.  ``st`` is the stream's own (a stream starts on a row
+        boundary — ``skip_frames`` is whole rows — and with a head of its
+        own) and moves on."""
+        body, frames = chunk.view, chunk.frames
         put = functools.partial(host_link().put, timeline=self.timeline)
-        if carry is None:
-            out = channelize_blocked(
-                chunk, self._coeffs,
-                channel_block=self._channel_block(chunk.shape), put=put,
+        if chunk.head is not None:  # the stream's first dispatch
+            st.channel_block = self._channel_block(body.shape)
+            st.tails = split_tails(chunk.head, st.channel_block)
+        # Filter state by where it comes from: up from the host (once per
+        # group per stream) or left on the chip by the last dispatch.
+        self.timeline.mark(
+            "state.carry" if chunk.head is None else "state.head",
+            sum(t.nbytes for t in st.tails), calls=len(st.tails))
+        if not self._carries:
+            out, st.tails = channelize_blocked(
+                body, self._coeffs, st.tails,
+                channel_block=st.channel_block, put=put,
                 **self._channelize_kw)
             self._output_frames += frames
             return out, out
-        if carry.channel_block is None:
-            carry.channel_block = self._channel_block(chunk.shape)
         kw = self._channelize_kw
         nint = kw.pop("nint")
-        rows, carry.accs = channelize_carry(
-            chunk, self._coeffs, carry.accs, carry.filled,
-            channel_block=carry.channel_block, nint=nint, put=put, **kw)
-        carry.filled = (carry.filled + frames) % nint
-        if carry.filled:  # the dispatch left an integration open
+        rows, st.tails, st.accs = channelize_carry(
+            body, self._coeffs, st.tails, st.accs, st.filled,
+            channel_block=st.channel_block, nint=nint, put=put, **kw)
+        st.filled = (st.filled + frames) % nint
+        if st.filled:  # the dispatch left an integration open
             self.timeline.mark("integrate.carry",
-                               sum(a.nbytes for a in carry.accs))
+                               sum(a.nbytes for a in st.accs))
         if rows is None:
-            return None, carry.accs
+            return None, st.accs
         self.timeline.mark("integrate.emit", rows.nbytes,
                            calls=rows.shape[0])
         self._output_frames += rows.shape[0] * nint
         return rows, rows
 
-    def _run_chunk(self, chunk: np.ndarray,
-                   carry: Optional[_OpenIntegration] = None
+    def _run_chunk(self, chunk: _Chunk, st: _StreamState
                    ) -> Optional[np.ndarray]:
         import jax
 
         with self.timeline.stage("device", nbytes=chunk.nbytes):
-            out, token = self._dispatch(chunk, carry)
+            out, token = self._dispatch(chunk, st)
             jax.block_until_ready(token)
             return None if out is None else np.asarray(out)
 
@@ -718,10 +757,10 @@ class RawReducer:
             "reduce.stream", nfft=self.nfft, path=getattr(raw, "path", "")
         ):
             if not self.async_output:
-                carry = self._open_integration()
+                st = _StreamState()
                 for chunk in self._chunks(raw, skip_frames):
                     try:
-                        out = self._run_chunk(chunk.view, carry)
+                        out = self._run_chunk(chunk, st)
                     finally:
                         chunk.release()
                     if out is not None:
@@ -778,12 +817,12 @@ class RawReducer:
         do_narrow = narrow and self.nbits < 32
         if do_narrow:
             from blit.ops.narrow import narrow_device
-        carry = self._open_integration()
+        st = _StreamState()
         try:
             extra = readback_extra_slots(depth, self.prefetch_depth)
             for chunk in self._chunks(raw, skip_frames, extra_slots=extra):
                 with self.timeline.stage("dispatch", byte_free=True):
-                    out, token = self._dispatch(chunk.view, carry)
+                    out, token = self._dispatch(chunk, st)
                     if do_narrow and out is not None:
                         # Quantize to the product's on-disk integer form
                         # BEFORE D2H: 4x (nbits=8) / 2x (nbits=16) fewer
@@ -795,7 +834,7 @@ class RawReducer:
                             self.quant_offset)
                 if tuner is not None:
                     tuner.observe_chunk()
-                for slab in rot.put(token, nbytes=chunk.view.nbytes,
+                for slab in rot.put(token, nbytes=chunk.nbytes,
                                     on_consumed=chunk.release,
                                     fetch=out is not None):
                     yield slab
@@ -924,22 +963,39 @@ class RawReducer:
         kept_samples, read_into)`` triples in stream order and emit
         fixed-shape device chunks.
 
-        Buffer ``j``'s first ``(ntap-1)*nfft`` samples are the filter state,
-        copied from the previously filled buffer's tail (which the consumer
-        may still be reading — concurrent reads are fine; a buffer is only
-        *refilled* after its consumer released it).  Everything else is read
-        from the source exactly once, directly into place
-        (``read_into(dst, t0, take)`` copies samples ``[t0, t0+take)`` of
-        the block into ``dst[:, :take]``).
+        The stream's first ``(ntap-1)*nfft`` samples after ``skip_frames``
+        — its HEAD, the filter state of its first frame — are read into a
+        slab of their own and ride with the first chunk emitted; after
+        that the device holds the filter state (:class:`_StreamState`).
+        Buffer ``j`` is ``chunk_frames * nfft`` NEW samples per channel,
+        so a channel group ``buf[c:c+cb]`` is one contiguous run of host
+        memory.  Every sample is read from the source exactly once,
+        directly into place (``read_into(dst, t0, take)`` copies samples
+        ``[t0, t0+take)`` of the block into ``dst[:, :take]``); payloads
+        are ``(frames, samples, head or None)``.
         """
+        from blit import hostmem
+
         nfft, ntap, nint = self.nfft, self.ntap, self.nint
-        chunk_samps = (self.chunk_frames + ntap - 1) * nfft
-        advance = self.chunk_frames * nfft
+        chunk_samps = self.chunk_frames * nfft
         state = (ntap - 1) * nfft
         to_skip = skip_frames * nfft
 
+        def slab(shape, cached: Optional[np.ndarray]) -> np.ndarray:
+            """``cached`` if it has ``shape``, else a page-aligned,
+            pool-recycled staging slab (blit/hostmem.py): an
+            already-faulted buffer from a previous stream when one
+            matches, so steady-state ingest never allocates."""
+            if cached is not None and cached.shape == shape:
+                return cached
+            pool = hostmem.slab_pool()
+            if cached is not None:
+                pool.give(cached, self.timeline)
+            return pool.take(shape, np.int8, self.timeline)
+
+        head: Optional[np.ndarray] = None  # rides with the first emit
+        head_left = state  # samples of the head still to read
         cur: Optional[int] = None
-        prev: Optional[int] = None
         filled = 0
         emitted = 0  # frames in the chunks emitted so far
         for hdr, nt, read_into in feed:
@@ -951,6 +1007,18 @@ class RawReducer:
             nchan = hdr["OBSNCHAN"]
             self._note_stream_nchan(nchan)
             npol = 2 if hdr["NPOL"] > 2 else hdr["NPOL"]
+            if head is None and not emitted:
+                head = self._head_slab = slab((nchan, state, npol, 2),
+                                              self._head_slab)
+            if head_left:
+                take = min(nt, head_left)
+                with self.timeline.stage(
+                    "ingest", nbytes=nchan * take * npol * 2
+                ):
+                    read_into(head[:, state - head_left:], t0, take)
+                head_left -= take
+                t0 += take
+                nt -= take
             while nt > 0:
                 if cur is None:
                     # Waiting for a free buffer is back-pressure from
@@ -967,26 +1035,8 @@ class RawReducer:
                                 bufs[cur] = self._buf_cache.pop(j)
                                 break
                         else:
-                            # Page-aligned, pool-recycled staging slab
-                            # (blit/hostmem.py): an already-faulted buffer
-                            # from a previous stream when one matches, so
-                            # steady-state ingest never allocates.
-                            from blit import hostmem
-
-                            bufs[cur] = hostmem.slab_pool().take(
-                                shape, np.int8, self.timeline
-                            )
-                    if prev is not None:
-                        # Separate stage: filter-state memcpy between
-                        # buffers is not file ingest ("ingest" bytes
-                        # must stay == file bytes for ReductionStats).
-                        state_bytes = nchan * state * npol * 2
-                        with self.timeline.stage("state",
-                                                 nbytes=state_bytes):
-                            bufs[cur][:, :state] = bufs[prev][:, advance:]
-                        filled = state
-                    else:
-                        filled = 0
+                            bufs[cur] = slab(shape, None)
+                    filled = 0
                 take = min(nt, chunk_samps - filled)
                 with self.timeline.stage(
                     "ingest", nbytes=nchan * take * npol * 2
@@ -996,17 +1046,17 @@ class RawReducer:
                 t0 += take
                 nt -= take
                 if filled == chunk_samps:
-                    rot.emit(cur, (self.chunk_frames, chunk_samps))
+                    rot.emit(cur, (self.chunk_frames, chunk_samps, head))
                     emitted += self.chunk_frames
-                    prev, cur = cur, None
-        if cur is not None and filled > (state if prev is not None else 0):
+                    head, cur = None, None
+        if cur is not None and filled > 0:
             # Flush: the whole frames remaining, up to the last that
             # closes an integration (one carried in from earlier chunks
             # counts with the frames it already holds).
-            frames = usable_frames(filled, nfft, ntap, nint,
+            frames = usable_frames(state + filled, nfft, ntap, nint,
                                    open_frames=emitted % nint)
             if frames > 0:
-                rot.emit(cur, (frames, (frames + ntap - 1) * nfft))
+                rot.emit(cur, (frames, frames * nfft, head))
 
     def _chunks(
         self, raw: GuppiRaw, skip_frames: int = 0, extra_slots: int = 0
@@ -1033,13 +1083,14 @@ class RawReducer:
         )
         with self.timeline.stage("stream"):
             try:
-                for idx, (frames, samps) in rot.slots():
-                    view = bufs[idx][:, :samps]
-                    # The stream stage moves every gross chunk byte it
-                    # hands downstream (VERDICT r5 weak #3: the dominant
-                    # stage must not report zero bytes).
-                    self.timeline.stages["stream"].bytes += view.nbytes
-                    yield _Chunk(view, frames, idx, rot.release)
+                for idx, (frames, samps, head) in rot.slots():
+                    chunk = _Chunk(bufs[idx][:, :samps], frames, idx,
+                                   rot.release, head)
+                    # The stream stage moves every byte it hands
+                    # downstream (VERDICT r5 weak #3: the dominant stage
+                    # must not report zero bytes).
+                    self.timeline.stages["stream"].bytes += chunk.nbytes
+                    yield chunk
             finally:
                 rot.close()
                 # Keep the (faulted) buffers for the next stream.
@@ -1068,7 +1119,7 @@ class RawReducer:
         with profile_trace(self.trace_logdir):
             total = 0.0
             pending: deque = deque()
-            carry = self._open_integration()
+            st = _StreamState()
 
             def retire() -> float:
                 done, s, token = pending.popleft()
@@ -1079,8 +1130,8 @@ class RawReducer:
                 return part
 
             for chunk in self._chunks(raw):
-                with self.timeline.stage("device", nbytes=chunk.view.nbytes):
-                    out, token = self._dispatch(chunk.view, carry)
+                with self.timeline.stage("device", nbytes=chunk.nbytes):
+                    out, token = self._dispatch(chunk, st)
                     pending.append(
                         (chunk, None if out is None else jnp.sum(out),
                          token))

@@ -142,32 +142,54 @@ class TestChannelize:
         assert hdr["nfpc"] == 64 // 4
         np.testing.assert_allclose(data, fqav(full, 4), rtol=1e-5, atol=1e-2)
 
-    def test_channelize_blocked_matches_flat(self):
-        # Host-looped channel blocking == flat single dispatch.
+    @pytest.mark.parametrize("frames", [1, 2, 3, 8])
+    @pytest.mark.parametrize("channel_block", [2, 8])
+    def test_channelize_blocked_matches_flat(self, channel_block, frames):
+        # Host-looped channel blocking of (filter state, new samples) ==
+        # the flat single dispatch of their concatenation, and the tails
+        # it hands on are the concatenation's last ntap-1 frames (a body
+        # shorter than the filter state keeps part of the old tail).
         nfft, ntap = 64, 4
-        v = make_voltages(nchan=8, ntime=6 * nfft)
-        h = ch.pfb_coeffs(ntap, nfft)
-        flat = np.asarray(
-            ch.channelize(jnp.asarray(v), jnp.asarray(h), nfft=nfft, ntap=ntap)
-        )
-        blocked = np.asarray(
-            ch.channelize_blocked(
-                jnp.asarray(v), jnp.asarray(h), channel_block=2,
-                nfft=nfft, ntap=ntap,
-            )
-        )
-        np.testing.assert_array_equal(blocked, flat)
-        # Degenerate block sizes fall through to the flat path.
-        whole = np.asarray(
-            ch.channelize_blocked(
-                jnp.asarray(v), jnp.asarray(h), channel_block=8,
-                nfft=nfft, ntap=ntap,
-            )
-        )
-        np.testing.assert_array_equal(whole, flat)
+        state = (ntap - 1) * nfft
+        v = make_voltages(nchan=8, ntime=(ntap - 1 + frames) * nfft)
+        h = jnp.asarray(ch.pfb_coeffs(ntap, nfft))
+        flat = np.asarray(ch.channelize(jnp.asarray(v), h, nfft=nfft,
+                                        ntap=ntap))
+        head, body = v[:, :state], v[:, state:]
+        puts = []
+
+        def put(host, then):
+            puts.append(jax.tree_util.tree_map(np.shape, host))
+            return then(host)
+
+        out, tails = ch.channelize_blocked(
+            body, h, ch.split_tails(head, channel_block),
+            channel_block=channel_block, put=put, nfft=nfft, ntap=ntap)
+        np.testing.assert_array_equal(np.asarray(out), flat)
+        groups = 8 // channel_block
+        assert len(tails) == groups
+        np.testing.assert_array_equal(
+            np.concatenate([np.asarray(t) for t in tails]),
+            ch.sample_words(v[:, -state:]))
+        # A head in host memory goes up through `put`, group by group,
+        # with its group's samples (both as one word per sample); a tail
+        # from the device does not.
+        assert puts == [((channel_block, state),
+                         (channel_block, frames * nfft))] * groups
+        puts.clear()
+        again, _ = ch.channelize_blocked(
+            body, h, [jnp.asarray(ch.sample_words(t)) for t in
+                      ch.split_tails(head, channel_block)],
+            channel_block=channel_block, put=put, nfft=nfft, ntap=ntap)
+        np.testing.assert_array_equal(np.asarray(again), flat)
+        assert puts == [(channel_block, frames * nfft)] * groups
+
+    def test_channelize_blocked_refuses_a_ragged_block(self):
+        v = make_voltages(nchan=8, ntime=6 * 64)
+        h = jnp.asarray(ch.pfb_coeffs(4, 64))
         with pytest.raises(ValueError, match="divide nchan"):
-            ch.channelize_blocked(jnp.asarray(v), jnp.asarray(h),
-                                  channel_block=3, nfft=nfft, ntap=ntap)
+            ch.channelize_blocked(v[:, 192:], h, [v[:, :192]],
+                                  channel_block=3, nfft=64, ntap=4)
 
     def test_fqav_must_divide_nfft(self, tmp_path):
         # Averaging groups must not straddle coarse-channel boundaries.

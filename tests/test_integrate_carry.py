@@ -140,7 +140,9 @@ def test_rawspec_hires_geometry_counts():
     # rounded down to nint inside the flush chunk) would have dropped.
     assert _expected_counts(51, 51, 8) == (6, 1, 51)
     nfft = 1 << 20
-    held = (3 + NTAP - 1) * nfft  # the flush chunk: 3 frames + filter state
+    # The flush chunk's 3 frames, counted with the filter state the chip
+    # holds (the rule counts samples as it always did; ISSUE 29).
+    held = (3 + NTAP - 1) * nfft
     assert usable_frames(held, nfft, NTAP, 51) == 0
     assert usable_frames(held, nfft, NTAP, 51, open_frames=48) == 3
     assert usable_frames(held, nfft, NTAP, 51, open_frames=47) == 0
@@ -185,7 +187,7 @@ def test_channel_groups_are_laid_out_once_per_stream(tmp_path, monkeypatch):
     asked = []
 
     def sized_by_shape(self, shape):
-        full = shape[1] == (cf + NTAP - 1) * nfft
+        full = shape[1] == cf * nfft  # a chunk is its new frames only
         asked.append(2 if full else 4)
         return asked[-1]
 
@@ -194,6 +196,12 @@ def test_channel_groups_are_laid_out_once_per_stream(tmp_path, monkeypatch):
                      tune_online=False)
     _, got = red.reduce(raw)
     assert asked == [2]  # asked once, for the stream's first chunk
+    # ... and the filter state is laid out with the accumulators: two
+    # groups, the head up once, every later tail left on the chip.
+    st = red.timeline.report()
+    dispatches = -(-(rows * nint) // cf)
+    assert st["state.head"]["calls"] == 2
+    assert st["state.carry"]["calls"] == 2 * (dispatches - 1)
     monkeypatch.undo()
     _, whole = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf,
                           tune_online=False).reduce(raw)

@@ -120,8 +120,9 @@ class TestIngestDigests:
         # The seeded ``corrupt`` fault mode (in-flight flip of the
         # DELIVERED frame, disk clean): detected per delivery, masked,
         # byte-identical to the zero-filled oracle.  Single-chunk
-        # geometry (chunk spans the recording) makes delivery k ==
-        # block k, so after=2 targets exactly block 2.
+        # geometry (chunk spans the recording) makes every block one
+        # delivery, after the stream's head (block 0's first samples, a
+        # delivery of its own): after=3 targets exactly block 2.
         raw = self._setup(tmp_path)
         integrity.write_raw_digests(raw)
         kw = dict(nfft=NFFT, chunk_frames=4 * 512 // NFFT - 3,
@@ -136,7 +137,7 @@ class TestIngestDigests:
         oracle = str(tmp_path / "oracle.fil")
         RawReducer(**kw).reduce_to_file(opath, oracle)
         faults.install(faults.FaultRule(point="guppi.read",
-                                        mode="corrupt", after=2, times=1))
+                                        mode="corrupt", after=3, times=1))
         out = str(tmp_path / "out.fil")
         rdr = GuppiRaw(raw)
         hdr = RawReducer(**kw).reduce_to_file(rdr, out)

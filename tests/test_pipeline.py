@@ -141,17 +141,30 @@ class TestStreaming:
                     "without declaring byte_free"
                 )
 
-    def test_stream_stage_counts_gross_chunk_bytes(self, tmp_path):
-        # The stream stage moves every gross chunk byte it hands
-        # downstream (net file bytes + the re-dispatched PFB tails).
+    def test_stream_stage_counts_every_chunk_byte_once(self, tmp_path):
+        # The stream stage moves every byte it hands downstream: each
+        # chunk's new samples and, with the first, the stream's head (the
+        # filter state of frame 0) — no sample twice (ISSUE 29: a chunk
+        # no longer re-sends the previous chunk's tail).
         p = str(tmp_path / "x.raw")
         synth_raw(p, nblocks=2, obsnchan=2, ntime_per_block=1024)
         red = RawReducer(nfft=64, nint=1, chunk_frames=4)
-        gross = 0
+        sent, heads, shapes = 0, [], set()
         for c in red._chunks(GuppiRaw(p)):
-            gross += c.view.nbytes
+            sent += c.nbytes
+            heads.append(c.head)
+            shapes.add(c.view.shape)
+            assert c.view.shape[1] == c.frames * 64
             c.release()
-        assert red.timeline.stages["stream"].bytes == gross > 0
+        assert red.timeline.stages["stream"].bytes == sent > 0
+        # 2048 samples: a 3-frame head, 7 chunks of 4 frames, one frame
+        # left for the flush chunk.
+        assert heads[0].shape == (2, 3 * 64, 2, 2)
+        assert all(h is None for h in heads[1:])
+        assert shapes == {(2, 4 * 64, 2, 2), (2, 64, 2, 2)}
+        assert sent == red.timeline.stages["ingest"].bytes \
+            == 2 * 2048 * 2 * 2
+        assert "state" not in red.timeline.report()
 
 
 class TestProducts:

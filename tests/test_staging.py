@@ -229,8 +229,10 @@ class TestReductionReportsItsStaging:
 
     def test_second_same_shape_reduction_allocates_nothing(
             self, tmp_path, fresh_process_pool):
-        # In memory: the chunk rotation alone stages (the readback ring,
-        # CPU backends only, takes 1-3 slabs as the sink's timing has it).
+        # In memory: the chunk rotation and the stream's head slab alone
+        # stage (the readback ring, CPU backends only, takes 1-3 slabs as
+        # the sink's timing has it).  A rotation buffer is a chunk's NEW
+        # samples, chunk_frames * nfft of them (ISSUE 29).
         p = self.toy(tmp_path)
         tables, products = [], []
         for _ in range(3):
@@ -238,15 +240,18 @@ class TestReductionReportsItsStaging:
             products.append(red.reduce(p)[1])
             tables.append(red.timeline.report())
         first, second, third = tables
-        assert first["staging.alloc"]["calls"] == 3  # the whole rotation
+        # the whole rotation, and the head
+        assert first["staging.alloc"]["calls"] == 4
         assert first["staging.reuse"]["calls"] == 0
         for t in (second, third):
             assert t["staging.alloc"]["calls"] == 0
-            assert t["staging.reuse"]["calls"] == 3
+            assert t["staging.reuse"]["calls"] == 4
             assert t["staging.drop"]["calls"] == 0
         st = fresh_process_pool.stats()
         assert (st["lent_bytes"], st["dropped"], st["free_slabs"]) \
-            == (0, 0, 3)
+            == (0, 0, 4)
+        shapes = sorted(shape for shape, _ in fresh_process_pool._free)
+        assert shapes == [(2, 3 * NFFT, 2, 2), (2, 4 * NFFT, 2, 2)]
         np.testing.assert_array_equal(products[0], products[2])
 
     def test_to_file_the_ring_comes_back_too(self, tmp_path,
@@ -258,10 +263,11 @@ class TestReductionReportsItsStaging:
             red.reduce_to_file(p, str(tmp_path / f"{tag}.fil"))
             tables.append(red.timeline.report())
         first, second = tables
-        # 3 chunk buffers + 1-3 ring slabs the first time; the second
-        # finds all of the first's back, and at most wants 2 ring slabs more.
-        assert first["staging.alloc"]["calls"] >= 4
-        assert second["staging.reuse"]["calls"] >= 4
+        # 3 chunk buffers + the head slab + 1-3 ring slabs the first
+        # time; the second finds all of the first's back, and at most
+        # wants 2 ring slabs more.
+        assert first["staging.alloc"]["calls"] >= 5
+        assert second["staging.reuse"]["calls"] >= 5
         assert second["staging.alloc"]["calls"] <= 2
         assert second["staging.drop"]["calls"] == 0
         assert fresh_process_pool.stats()["lent_bytes"] == 0
@@ -297,11 +303,11 @@ class TestReductionReportsItsStaging:
             lines.append(json.loads(
                 capsys.readouterr().out.strip().splitlines()[-1]))
         # (The ring's 1-3 slabs follow the sink's timing; the rotation's
-        # three buffers do not.)
-        assert lines[0]["stages"]["staging.alloc"]["calls"] >= 4
+        # three buffers and the head slab do not.)
+        assert lines[0]["stages"]["staging.alloc"]["calls"] >= 5
         assert lines[0]["stages"]["staging.reuse"]["calls"] == 0
         assert lines[1]["stages"]["staging.alloc"]["calls"] <= 2
-        assert lines[1]["stages"]["staging.reuse"]["calls"] >= 4
+        assert lines[1]["stages"]["staging.reuse"]["calls"] >= 5
         assert lines[1]["stages"]["staging.drop"] == {
             "calls": 0, "seconds": 0.0, "bytes": 0, "gbps": 0.0,
             "byte_free": True}
@@ -315,7 +321,8 @@ class TestTheLinkIsBudgetedByTransfer:
     pump overlaps chunks whatever two whole chunks would weigh (until
     PR 27 it took turns where they did not fit: ISSUE 25's depth-1 rule)."""
 
-    CHUNK = 2 * (4 + 3) * NFFT * 2 * 2  # one toy chunk buffer, bytes
+    CHUNK = 2 * 4 * NFFT * 2 * 2  # one toy chunk buffer, bytes
+    HEAD = 2 * 3 * NFFT * 2 * 2   # the stream's filter state, bytes
 
     @pytest.mark.parametrize("nint", [
         NINT,    # each chunk integrates inside its own program
@@ -384,9 +391,20 @@ class TestTheLinkIsBudgetedByTransfer:
             assert "link.inflight_bytes" not in table["hists"]
         else:
             peak = table["hists"]["link.inflight_bytes"]
-            # Every group's put and every fetched product was admitted.
+            # Every group's put (the head rides with the first chunk's
+            # two) and every fetched product was admitted.
             assert peak["n"] == 2 * len(seen) + table["readback"]["calls"]
             assert self.CHUNK // 2 <= peak["max"] < link
+        # Two groups a chunk, whatever the link: the head went up once,
+        # every later filter state stayed on the chip, no sample twice.
+        assert table["state.head"]["calls"] == 2
+        assert table["state.head"]["bytes"] == self.HEAD
+        assert table["state.carry"]["calls"] == 2 * (len(seen) - 1)
+        assert table["link.put"]["calls"] == 2 * len(seen) + 2
+        # 4096 samples: 61 frames, of which the 60 in whole chunks (and
+        # whole integrations) are dispatched, and the head's 3.
+        assert table["link.put"]["bytes"] == (60 + 3) * self.CHUNK // 4
+        assert "state" not in table
         if nint == 11:
             assert table["integrate.carry"]["calls"] > 3
             assert table["readback"]["calls"] < len(seen)

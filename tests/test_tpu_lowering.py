@@ -189,7 +189,7 @@ class TestReducerOnADeviceWithAMemoryLimit:
         monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 12_000_000)
         red = P.RawReducer(**kw)
         _, grouped = red.reduce(path)
-        assert red._channel_block((16, 19 * 1024, 2, 2)) < 16
+        assert red._channel_block((16, 16 * 1024, 2, 2)) < 16
         # Grouping changes no arithmetic; a backend may round a
         # differently-batched program differently in the last bit.
         np.testing.assert_allclose(grouped, whole, rtol=1e-6, atol=1e-6)
@@ -209,8 +209,8 @@ class TestReducerOnADeviceWithAMemoryLimit:
         monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 12_000_000)
         red = P.RawReducer(**kw)
         _, grouped = red.reduce(path)
-        full = red._channel_block((16, 19 * 1024, 2, 2))
-        assert full < 16 and full < red._channel_block((16, 11 * 1024, 2, 2))
+        full = red._channel_block((16, 16 * 1024, 2, 2))
+        assert full < 16 and full < red._channel_block((16, 8 * 1024, 2, 2))
         np.testing.assert_allclose(grouped, whole, rtol=1e-6, atol=1e-6)
 
     def test_a_chunk_that_cannot_fit_raises(self, tmp_path, monkeypatch):
@@ -225,7 +225,7 @@ class TestReducerOnADeviceWithAMemoryLimit:
         import blit.pipeline as P
 
         red = P.RawReducer(nfft=1024, chunk_frames=16)
-        assert red._channel_block((16, 19 * 1024, 2, 2)) == 16
+        assert red._channel_block((16, 16 * 1024, 2, 2)) == 16
 
     def test_chip_path_requires_the_native_reader(self, tmp_path,
                                                   monkeypatch):

@@ -49,7 +49,10 @@ from blit.pipeline import RawReducer  # noqa: E402
 
 TOY = sys.argv[1:] == ["toy"]
 NFFT = 1 << 10 if TOY else 1 << 20
-SHAPE = (4 if TOY else 64, 11 * NFFT, 2, 2)  # an 8-frame chunk
+# An 8-frame chunk as the pump sent it until PR 29: with the 3 frames of
+# filter state in front (they now stay on the chip; the probe keeps the
+# transfer sizes its recorded readings were taken at).
+SHAPE = (4 if TOY else 64, 11 * NFFT, 2, 2)
 
 
 def main() -> None:
@@ -148,7 +151,7 @@ def main() -> None:
           os.environ.get("TPU_PREMAPPED_BUFFER_SIZE"), flush=True)
     for nint in (1, 51):
         red = RawReducer(nfft=NFFT, nint=nint, chunk_frames=8)
-        cb = red._channel_block(SHAPE)
+        cb = red._channel_block((SHAPE[0], 8 * NFFT) + SHAPE[2:])
         if TOY:  # two groups, and a link that takes two of them and a half
             cb = SHAPE[0] // 2
             device.host_link_bytes = lambda: int(2.5 * a.nbytes // 2)
