@@ -239,6 +239,7 @@ def _cmd_scan(args: argparse.Namespace) -> int:
     from blit.parallel.scan import (
         reduce_scan_mesh_to_files,
         reduce_scan_pool_to_files,
+        scan_window_frames,
     )
 
     mdef = mesh_defaults()
@@ -249,7 +250,7 @@ def _cmd_scan(args: argparse.Namespace) -> int:
     sharded = args.sharded or (mdef["sharded"] and not args.pool)
 
     invs = [get_inventory(args.file_re or r"\.raw$", root=args.root)]
-    # The EFFECTIVE window (library default + nint rounding), so the
+    # The EFFECTIVE window (library default + scan_window_frames), so the
     # stats line reports what actually executed.  An unset --window-frames
     # consults this rig's tuning profile first (blit/tune.py): the scan's
     # frames-per-dispatch is the same quantity `blit tune` converged as
@@ -304,7 +305,18 @@ def _cmd_scan(args: argparse.Namespace) -> int:
             tuning = {"source": "default"}
     else:
         wf = args.window_frames
-    wf = max((wf // args.nint) * args.nint, args.nint)
+    # The library's own rule, so the stats line reports what executed:
+    # nint sizes the window only where an integration fits one; where it
+    # does not, the window stands and the integration is carried.
+    wf = scan_window_frames(args.nfft, args.nint, wf)
+    if sharded and wf % args.nint and not args.search:
+        if args.sharded:
+            raise SystemExit(
+                f"--sharded has no carry: an integration of {args.nint} "
+                f"frames does not fit the {wf}-frame window; drop "
+                "--sharded (the default mesh loop carries it across "
+                "windows)")
+        sharded = False  # the site's default plane cannot carry: mesh loop
     tl = Timeline()
     parallel = "sharded" if sharded else ("pool" if args.pool else "mesh")
     if args.search:
@@ -2302,11 +2314,14 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "the official bench's lever; product stays f32)")
     ps.add_argument("--window-frames", type=int, default=None,
                     help="PFB frames per device window (bounds HBM, host "
-                         "RSS, and per-window readback).  Default: "
-                         "8*2^20 samples' worth of frames — i.e. "
-                         "max(8, 2^23/nfft), the dispatch size measured "
-                         "HBM-safe at the hi-res preset; raise it only "
-                         "if you have measured headroom")
+                         "RSS, and per-window readback, whatever the scan "
+                         "length and --nint: an integration longer than "
+                         "the window is carried across windows on the "
+                         "mesh, and a window below --nint is kept as "
+                         "given).  Default: 8*2^20 samples' worth of "
+                         "frames — i.e. max(8, 2^23/nfft) — in whole "
+                         "integrations where one fits; 8 frames at nfft "
+                         "2^20 do NOT fit four 16 GB chips (pass 2)")
     ps.add_argument("--max-frames", type=int, default=None)
     ps.add_argument("--trace-logdir", default=None,
                     help="write a device-only JAX profiler trace of the "

@@ -846,7 +846,11 @@ def history_defaults(config: SiteConfig = DEFAULT) -> Dict:
 def default_window_frames(nfft: int) -> int:
     """HBM-bounded default ``window_frames`` for a given ``nfft``: the
     scan's device windows hold ~``WINDOW_SAMPLES`` samples per chip, with
-    a floor of 8 whole frames."""
+    a floor of 8 whole frames.  ``nint`` rounds it to whole integrations
+    only where one fits a dispatch
+    (:func:`blit.parallel.scan.scan_window_frames`); it never grows the
+    window past this bound — a longer integration is carried across
+    windows."""
     return max(8, WINDOW_SAMPLES // nfft)
 
 

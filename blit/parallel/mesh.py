@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from blit.device import host_link
 from blit.observability import Timeline
-from blit.ops.channelize import channelize
+from blit.ops.channelize import channelize, integrate_carry
 from blit.ops.despike import despike
 
 BAND_AXIS = "band"
@@ -63,6 +63,10 @@ PARTITION_RULES: Dict[str, P] = {
     "beamform_acc": P(),
     "vis_acc_standard": P(BAND_AXIS, None, None, BANK_AXIS),
     "vis_acc_packed": P(BAND_AXIS, BANK_AXIS),
+    # The scan's open integration (nband, nif, nchans): each chip holds
+    # its own bank's partial sum from window to window (band_carry) and
+    # nothing of it is gathered until a row closes.
+    "integration_acc": P(BAND_AXIS, None, BANK_AXIS),
 }
 
 # The collective-latency histograms of the sharded plane (ISSUE 9): every
@@ -183,7 +187,12 @@ def band_reduce(
 ) -> jax.Array:
     """The full multi-chip reduction step: every chip channelizes its own
     bank's voltage block, then the 8 banks of each band stitch their fine
-    spectra into a contiguous band over ICI.
+    spectra into a contiguous band over ICI.  ``nint`` integrates INSIDE
+    this one program, so it has to fit the block; an integration longer
+    than a window is the caller's to carry: this step at ``nint=1,
+    stitch=False`` (no collective), :func:`band_carry` per chip, and
+    :func:`stitch_despike` only for rows that closed
+    (:func:`blit.parallel.scan.reduce_scan_mesh_to_files`).
 
     Args:
       voltages: int8 ``(nband, nbank, nchan, ntime, npol, 2)``, sharded with
@@ -243,6 +252,60 @@ def band_reduce(
         step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(voltages, coeffs)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "nif", "nchans"))
+def carry_zeros(*, mesh: Mesh, nif: int, nchans: int) -> jax.Array:
+    """A scan's integration before its first frame: float32 zeros
+    ``(nband, nif, nchans)`` laid out by the ``integration_acc`` rule,
+    made on the chips (``nchans`` is the whole band's)."""
+    import jax.numpy as jnp
+
+    per_chip = nchans // mesh.devices.shape[1]
+    return jax.shard_map(
+        lambda: jnp.zeros((1, nif, per_chip), jnp.float32),
+        mesh=mesh, in_specs=(),
+        out_specs=partition_rule("integration_acc"), check_vma=False,
+    )()
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "nint"),
+                   donate_argnums=0)
+def band_carry(
+    acc: jax.Array, power: jax.Array, filled: jax.Array, *, mesh: Mesh,
+    nint: int,
+) -> tuple:
+    """One window of an integration longer than a window (or straddling
+    its boundary), per chip and with NO collective: every chip folds its
+    own bank's spectra into its own partial sum
+    (:func:`blit.ops.channelize.integrate_carry`, frame by frame in stream
+    order, ``filled`` a device scalar — one program wherever the row
+    boundary falls in the window grid).
+
+    ``power`` is ``band_reduce(..., nint=1, stitch=False)``'s product
+    ``(nband, nframes, nif, nchans)``, bank-sharded; ``acc`` ``(nband,
+    nif, nchans)`` float32 under the ``integration_acc`` rule is DONATED
+    (:class:`ShardedAccumulator`: the open integration is held once).
+    Returns ``(acc, rows)``: ``rows`` ``(nband, (nint - 1 + nframes) //
+    nint, nif, nchans)``, still bank-sharded, of which the first ``(filled
+    + nframes) // nint`` closed in this window (the rest are zeros) — what
+    :func:`stitch_despike` gathers, and only then.  ``rows`` is a fresh
+    output of every call, so it is also the token a caller waits on (the
+    accumulator is gone with the next fold)."""
+
+    def fold(a, x, at):
+        rows, a = integrate_carry(x[0], a[0], at, nint=nint)
+        return a[None], rows[None]
+
+    return jax.shard_map(
+        fold,
+        mesh=mesh,
+        in_specs=(partition_rule("integration_acc"),
+                  partition_rule("filterbank_sharded"), P()),
+        out_specs=(partition_rule("integration_acc"),
+                   partition_rule("filterbank_sharded")),
+        check_vma=False,  # per-chip fold, no collectives
+    )(acc, power, filled)
 
 
 def stitch_bands(x: jax.Array, mesh: Mesh) -> jax.Array:

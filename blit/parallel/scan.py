@@ -239,7 +239,7 @@ def _open_players(raw_paths, mesh):
 
 
 def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
-                 staged=None):
+                 staged=None, slab_ntime=None):
     """Assemble the global sharded voltage array for gap-free samples
     ``[start, start + ntime)`` of every player.  Every LOCAL player's
     window is read into host memory first, one after the other
@@ -251,11 +251,17 @@ def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
     partition-rule-driven implementation the sharded plane shares.
 
     ``staged`` (a list) makes each bank's window buffer a slab of the
-    process staging pool (blit/hostmem.py), of this window's exact
-    shape, and appends it: the caller gives the slabs back once THIS
-    window's dispatch has synchronized — never sooner, ``device_put``
-    returns before the bytes have landed (and on the CPU backend may
-    alias the slab outright).  Without it each window reads into fresh
+    process staging pool (blit/hostmem.py) and appends it: the caller
+    gives the slabs back once THIS window's dispatch has synchronized —
+    never sooner, ``device_put`` returns before the bytes have landed
+    (and on the CPU backend may alias the slab outright).  The slab has
+    ``slab_ntime`` samples per channel (the scan's full window; default
+    this window's own) and a shorter window — a scan's last — reads into
+    its leading bytes: one shape class per scan, so the pool hands every
+    window memory that is already faulted (a ragged last window of a
+    shape of its own pushed four full-window slabs out of the pool every
+    pass, and the next pass first-touched four fresh ones inside its
+    timed reads).  Without ``staged`` each window reads into fresh
     memory that the returned array keeps alive."""
     nband, nbank = mesh.devices.shape
     tl = tl if tl is not None else observability.Timeline()
@@ -264,9 +270,12 @@ def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
         r = raws[(b, k)]
         buf = None
         if staged is not None:
-            buf = hostmem.slab_pool().take((nchan, ntime, npol, 2), np.int8,
-                                           tl)
+            buf = hostmem.slab_pool().take(
+                (nchan, max(ntime, slab_ntime or 0), npol, 2), np.int8, tl)
             staged.append(buf)
+            if buf.shape[1] != ntime:  # contiguous, in the slab's head
+                buf = buf.reshape(-1)[:nchan * ntime * npol * 2].reshape(
+                    nchan, ntime, npol, 2)
         with tl.stage("feed.read", nchan * ntime * npol * 2):
             v = _gapless(r, ntime, skip=start, out=buf)
         if v.shape[0] != nchan or v.shape[1] < ntime or v.shape[2:] != (npol, 2):
@@ -320,9 +329,38 @@ def _scan_headers(raws, local, *, nfft, nint, stokes, fqav_by):
     return h0, bases, per_bank
 
 
+def scan_window_frames(nfft: int, nint: int,
+                       window_frames: Optional[int] = None) -> int:
+    """PFB frames per device window — the ONE rule of the scan's three
+    loops (mesh, ``--sharded``, ``--pool``) and of ``blit scan``'s stats
+    line.  A window moves in units of
+    :func:`blit.pipeline.fold_frames`: ``nint`` where a dispatch holds an
+    integration, 1 where it does not (rawspec's ``-f 1048576 -t 51``) —
+    the window then bounds memory "no matter the scan length" AND no
+    matter ``nint``, and the integration is carried across windows
+    (:func:`blit.parallel.mesh.band_carry`).  ``None`` is the HBM-safe
+    default (:func:`blit.config.default_window_frames`).  An explicit
+    window below one integration is the caller's measured bound
+    (``--window-frames 2`` on four 16 GB chips) and is kept as given, as
+    ``RawReducer`` keeps an explicit ``chunk_frames``: never rounded UP
+    to ``nint``."""
+    from blit.pipeline import fold_frames
+
+    unit = fold_frames(nfft, nint)
+    if window_frames is None:
+        from blit.config import default_window_frames
+
+        window_frames = default_window_frames(nfft)
+    elif 0 < window_frames < nint:
+        unit = 1
+    return max((window_frames // unit) * unit, unit)
+
+
 def _bitshuffle_window_chunk_rows(base: int, wrows: int) -> int:
     """Chunk rows for a windowed bitshuffle product: the pod-wide restart
-    offset is window-aligned and bitshuffle resume points must be
+    offset is window-aligned (row-aligned where the integration is
+    carried across windows: ``wrows`` 0, one row per chunk) and
+    bitshuffle resume points must be
     chunk-aligned, so the rows are ``gcd(default, window rows)`` — which
     silently collapses (to 1 for any window rows coprime with the 16-row
     default), degrading compression ratio and write throughput with no
@@ -330,13 +368,24 @@ def _bitshuffle_window_chunk_rows(base: int, wrows: int) -> int:
     gets fixed instead of silently eating the regression."""
     import math
 
+    if wrows < 1:
+        # An integration carried across windows: rows close one at a
+        # time and the restart offset is any whole row.
+        log.warning(
+            "bitshuffle chunk rows are 1: an integration longer than the "
+            "window is carried across windows and rows close one at a "
+            "time — a window of whole integrations (window_frames a "
+            "multiple of nint) keeps the default %d-row chunk's "
+            "compression and write throughput", base,
+        )
+        return 1
     rows = math.gcd(base, wrows)
     if rows < min(base, wrows):
         log.warning(
-            "bitshuffle chunk rows collapse to %d: window rows %d share "
-            "no larger factor with the default %d-row chunk — pick "
-            "window_frames/nint so the window rows divide (or are a "
-            "multiple of) %d to keep compression and write throughput",
+            "bitshuffle chunk rows collapse to %d: a window holds %d "
+            "rows (window_frames // nint), which share no larger factor "
+            "with the default %d-row chunk — pick them to divide (or be "
+            "a multiple of) %d to keep compression and write throughput",
             rows, wrows, base, base,
         )
     return rows
@@ -539,7 +588,11 @@ def _open_band_writers(
         agreed = int(_gather_int64(
             np.asarray([local_min], np.int64)
         ).min())
-        f0_start = min((agreed // wf) * wf, total)
+        # Whole windows — or, where the integration is carried across
+        # windows, whole rows: the cursor claims closed rows only, and
+        # the carry's sums do not depend on where the window grid falls.
+        unit = wf if wf % nint == 0 else nint
+        f0_start = min((agreed // unit) * unit, total)
 
     writers = {}
     try:
@@ -707,12 +760,29 @@ def reduce_scan_mesh_to_files(
 
     The reduction runs ``window_frames`` PFB frames per dispatch (each
     window re-reads the (ntap-1)*nfft-sample PFB prologue), so host RSS,
-    HBM, and per-window readback stay bounded no matter the scan length —
-    the mesh analog of ``RawReducer.reduce_to_file``'s slab streaming
-    (blit/pipeline.py).  ``window_frames=None`` (the default) derives an
-    HBM-safe bound from ``nfft``
-    (:func:`blit.config.default_window_frames`); pass a value >= the
-    scan length for a deliberate one-window run.  Products append slab-by-slab into ``.partial``
+    HBM, and per-window readback stay bounded no matter the scan length
+    and no matter ``nint`` — the mesh analog of
+    ``RawReducer.reduce_to_file``'s slab streaming (blit/pipeline.py).
+    ``window_frames=None`` (the default) derives an HBM-safe bound from
+    ``nfft`` (:func:`blit.config.default_window_frames`); pass a value >=
+    the scan length for a deliberate one-window run
+    (:func:`scan_window_frames` is the rule).
+
+    Where ``nint`` divides the window every window integrates inside its
+    own program, gathers, is fetched and written.  Where it does not —
+    an integration longer than the window (rawspec's ``-f 1048576 -t 51``
+    at the 2-frame window four 16 GB chips hold) or straddling its
+    boundary — the integration is CARRIED: every chip channelizes its
+    window at ``nint=1`` and folds the spectra, frame by frame in stream
+    order, into a float32 partial sum that stays on that chip from window
+    to window (:func:`blit.parallel.mesh.band_carry`, the accumulator
+    sharded over ``bank`` and held once), with no collective; a window
+    that closes no row is waited out, gives its staging slabs back and
+    fetches nothing; only a closed row is gathered and despiked
+    (:func:`blit.parallel.mesh.stitch_despike` — on the integrated row:
+    the clone commutes with the sum), fetched from the band owner's chip
+    and written.  Trailing frames that fill no integration are dropped.
+    Products append slab-by-slab into ``.partial``
     siblings and rename on success (SIGPROC derives nsamps from file size,
     so a crash mid-stream must not leave a valid-looking truncated file).
 
@@ -731,7 +801,13 @@ def reduce_scan_mesh_to_files(
     ``dispatch`` (async window dispatch, ~0 after the first compile),
     ``device`` (the blocking wait on the window's compute+collectives),
     ``readback`` (stitched-band device→host), ``write`` (product
-    append) — mirroring the single-chip ``RawReducer`` stages;
+    append) — mirroring the single-chip ``RawReducer`` stages; a carried
+    reduction adds ``blit reduce``'s two counted instants,
+    ``integrate.carry`` (``calls`` = windows that ended with the
+    integration open, ``bytes`` = accumulator bytes held on the mesh over
+    those boundaries, all chips) and ``integrate.emit`` (``calls`` = rows
+    closed, ``bytes`` = band product bytes handed to the readback), and
+    its ``readback`` / ``write`` have one call per closed row;
     ``blit scan`` prints the report as a stats JSON line.
     ``trace_logdir`` wraps the window loop in a device-only JAX profiler
     trace and writes the loop's spans beside it as ``blit-spans.json``
@@ -757,14 +833,20 @@ def reduce_scan_mesh_to_files(
     cursor claims it); re-running truncates any un-checkpointed tail and
     continues from the last window boundary every process agrees on
     (pod-wide MIN, window-aligned — the restart offset must be identical
-    on every process or the collectives deadlock).  ``.fil`` products
+    on every process or the collectives deadlock).  A carried reduction
+    continues from the last whole ROW instead (``rows * nint`` frames,
+    no longer a window boundary: the cursor claims closed rows only, a
+    run killed inside an integration loses its partial sum and re-adds
+    those frames, and since they are added in the order of their place
+    in the integration the bytes are the uninterrupted run's).  ``.fil`` products
     truncate by byte length; ``.h5`` products ``resize``-truncate the
     time-resizable dataset
     (:class:`blit.io.fbh5.ResumableFBH5Writer`), including under
     ``compression="bitshuffle"``, whose chunk rows are tied to the window
     granularity so pod restart offsets stay chunk-aligned (a changed
     ``window_frames`` therefore restarts bitshuffle ``.h5`` products
-    fresh — it is part of their cursor identity, as is the compression).
+    fresh — it is part of their cursor identity, as is the compression;
+    a carried reduction chunks them ONE row at a time, with a warning).
     Cursor identity covers the reduction config and this process's
     locally-fed member files; the finished product is identical to an
     uninterrupted run and the sidecars are removed on completion.
@@ -782,16 +864,13 @@ def reduce_scan_mesh_to_files(
         raise ValueError(
             f"scan too short: {min_samps} samples for nfft={nfft}"
         )
-    if window_frames is None:
-        # Bounded by default at EVERY entry point (VERDICT r4: an
-        # unbounded whole-scan window on the command whose purpose is
-        # bounded-window streaming): the HBM-safe sample budget, scaled
-        # to whole frames.  Pass an explicit window_frames >= the scan
-        # length for a deliberate one-window run.
-        from blit.config import default_window_frames
-
-        window_frames = default_window_frames(nfft)
-    wf = max((window_frames // nint) * nint, nint)
+    # Bounded by default at EVERY entry point (VERDICT r4: an unbounded
+    # whole-scan window on the command whose purpose is bounded-window
+    # streaming), and by nint never un-bounded (scan_window_frames).
+    # Pass an explicit window_frames >= the scan length for a deliberate
+    # one-window run.
+    wf = scan_window_frames(nfft, nint, window_frames)
+    carried = wf % nint != 0
 
     out_paths = _resolve_out_paths(
         band_ids, nband, out_dir, out_paths, compression
@@ -815,20 +894,75 @@ def reduce_scan_mesh_to_files(
 
         tl = timeline if timeline is not None else Timeline()
 
-        def flush(out, staged):
+        reduce_kw = dict(
+            mesh=mesh, nfft=nfft, ntap=ntap, stokes=stokes,
+            fft_method=fft_method, fqav_by=fqav_by, dtype=dtype,
+        )
+        # The open integration: each chip's own partial sum, on the mesh
+        # from window to window, held once (donated fold).
+        acc = M.ShardedAccumulator(mesh, "integration_acc")
+        filled = 0  # frames the open integration holds (resume: whole rows)
+
+        def reduce_window(volt, n):
+            """One window's programs -> ``(token, out)``: ``out`` the
+            stitched bands of the product rows the window closed
+            (``None`` where it closed none), ``token`` what is ready
+            once the window's voltages have been consumed."""
+            nonlocal filled
+            if not carried:
+                out = M.band_reduce(
+                    volt, coeffs, nint=nint, stitch=True,
+                    despike_nfpc=despike_nfpc, **reduce_kw)
+                return out, out
+            # Per chip, no collective: spectra at nint=1 (the sharded
+            # plane's per-chip program), folded into the chip's own sum.
+            power = M.band_reduce(
+                volt, coeffs, nint=1, stitch=False, despike_nfpc=0,
+                **reduce_kw)
+            if acc.value is None:  # the scan's first window
+                acc.init(M.carry_zeros(mesh=mesh, nif=STOKES_NIF[stokes],
+                                       nchans=nbank * per_bank))
+            part = None
+
+            def fold(a):
+                nonlocal part
+                a, part = M.band_carry(a, power, np.int32(filled),
+                                       mesh=mesh, nint=nint)
+                return a
+
+            acc.fold(fold)
+            closed, filled = divmod(filled + n, nint)
+            if filled:  # the window ended with the integration open
+                tl.mark("integrate.carry", acc.value.nbytes)
+            if not closed:
+                return part, None
+            if closed < part.shape[1]:
+                part = part[:, :closed]
+            # Despike on the integrated row: the clone commutes with the
+            # sum, the bits are those of despiking every spectrum.
+            out = M.stitch_despike(part, mesh=mesh,
+                                   despike_nfpc=despike_nfpc)
+            tl.mark("integrate.emit", len(mine) * out.nbytes // nband,
+                    calls=closed)
+            return out, out
+
+        def flush(token, out, staged):
             # Blocking readback of one window's stitched bands -> disk.
             # The compute wait is charged to "device" here (not at the
             # async dispatch): this is where the host actually blocks on
-            # the window's collectives, mirroring RawReducer's stage
-            # semantics.
+            # the window's programs, mirroring RawReducer's stage
+            # semantics — also for a window that closed no row and has
+            # nothing to fetch or write.
             with tl.stage("device", byte_free=True):
-                out.block_until_ready()
+                token.block_until_ready()
             # The window has consumed its input: only now may its
             # staging slabs serve another window (the one after next
             # takes them, already faulted).
             pool = hostmem.slab_pool()
             for buf in staged:
                 pool.give(buf, tl)
+            if out is None:
+                return
             by_dev = {s.device: s for s in out.addressable_shards}
             for b in mine:
                 band = by_dev[mesh.devices[b, 0]].data
@@ -845,6 +979,8 @@ def reduce_scan_mesh_to_files(
         # I/O overlaps device compute at one extra window of HBM.
         pending = None
         f0 = f0_start
+        # Every window stages through slabs of the largest window's shape.
+        slab_ntime = (min(wf, total - f0_start) + ntap - 1) * nfft
         with observability.span(
             "scan.reduce", nfft=nfft,
             out=out_paths[0],  # the first product, as reduce.to_file's
@@ -859,26 +995,13 @@ def reduce_scan_mesh_to_files(
                     with tl.stage("read", fed):
                         volt = _feed_window(
                             raws, local, mesh, nchan, npol, f0 * nfft,
-                            ntime, tl, staged,
+                            ntime, tl, staged, slab_ntime,
                         )
                     with tl.stage("dispatch", byte_free=True):
-                        out = M.band_reduce(
-                            volt,
-                            coeffs,
-                            mesh=mesh,
-                            nfft=nfft,
-                            ntap=ntap,
-                            nint=nint,
-                            stokes=stokes,
-                            fft_method=fft_method,
-                            stitch=True,
-                            despike_nfpc=despike_nfpc,
-                            fqav_by=fqav_by,
-                            dtype=dtype,
-                        )
+                        token, out = reduce_window(volt, n)
                     if pending is not None:
                         flush(*pending)
-                pending = (out, staged)
+                pending = (token, out, staged)
                 f0 += n
             if pending is not None:
                 flush(*pending)
@@ -963,11 +1086,9 @@ def reduce_scan_pool_to_files(
         total = min(total, (max_frames // nint) * nint)
     if total <= 0:
         raise ValueError("scan too short")
-    if window_frames is None:
-        from blit.config import default_window_frames
-
-        window_frames = default_window_frames(nfft)
-    wf = max((window_frames // nint) * nint, nint)
+    # The mesh loop's window (scan_window_frames); where it does not hold
+    # an integration RawReducer carries it (chunk_frames kept as given).
+    wf = scan_window_frames(nfft, nint, window_frames)
 
     out_paths = _resolve_out_paths(
         band_ids, nband, out_dir, out_paths, compression

@@ -63,6 +63,7 @@ from blit.parallel.scan import (
     _resolve_grid,
     _resolve_out_paths,
     _scan_headers,
+    scan_window_frames,
 )
 
 log = logging.getLogger("blit.sharded")
@@ -273,6 +274,16 @@ def reduce_scan_sharded_to_files(
         readback_extra_slots,
     )
 
+    wf = scan_window_frames(nfft, nint, window_frames)
+    if wf % nint:
+        # Raised from arguments alone, so on every process alike and
+        # before any collective.  Never a window rounded up to nint.
+        raise ValueError(
+            f"the sharded plane has no carry: an integration of {nint} "
+            f"frames does not fit its {wf}-frame window (nfft {nfft}).  "
+            "Use the default mesh loop (reduce_scan_mesh_to_files; `blit "
+            "scan` without --sharded), which carries it across windows"
+        )
     band_ids, raw_paths = _resolve_grid(raw_paths, scan, inventories)
     mesh, local, raws, nchan, npol, min_samps = _open_players(raw_paths, mesh)
     nband, nbank = mesh.devices.shape
@@ -284,11 +295,6 @@ def reduce_scan_sharded_to_files(
         raise ValueError(
             f"scan too short: {min_samps} samples for nfft={nfft}"
         )
-    if window_frames is None:
-        from blit.config import default_window_frames
-
-        window_frames = default_window_frames(nfft)
-    wf = max((window_frames // nint) * nint, nint)
     prefetch = max(2, prefetch_depth or 2)
     depth = max(2, out_depth or prefetch)
     if probe_windows is None:

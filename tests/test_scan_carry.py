@@ -1,0 +1,362 @@
+"""An integration carried across the mesh's windows (ISSUE 30): ``blit
+scan --nint`` beyond one window — rawspec's ``-f 1048576 -t 51`` at the
+2-frame window four 16 GB chips hold — against the plain whole-file
+reference, on four virtual CPU devices.
+
+Each chip folds its own bank's spectra into a partial sum that stays on
+the mesh (``parallel/mesh.band_carry``); nothing is gathered, fetched or
+written until a row closes."""
+
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from blit.io.guppi import GuppiRaw  # noqa: E402
+from blit.io.sigproc import read_fil_data, read_fil_header  # noqa: E402
+from blit.observability import Timeline  # noqa: E402
+from blit.ops.channelize import (  # noqa: E402
+    STOKES_NIF,
+    channelize_np,
+    pfb_coeffs,
+)
+from blit.parallel import mesh as M  # noqa: E402
+from blit.parallel import scan as S  # noqa: E402
+from blit.parallel.scan import (  # noqa: E402
+    reduce_scan_mesh_to_files,
+    reduce_scan_pool_to_files,
+    scan_window_frames,
+)
+from blit.pipeline import ReductionCursor  # noqa: E402
+from blit.testing import synth_raw  # noqa: E402
+
+NFFT, NTAP, NBANK, NCHAN = 32, 4, 4, 2
+# Scale-relative max error (max|got - want| / max|want|, compared in
+# float64, as benchmark/check.py holds the chip) against channelize_np over
+# each bank's whole file, stitched and despiked.  Both sides are float32
+# arithmetic that differ in FFT rounding and in the order of the sum (one
+# frame at a time on the mesh, numpy's pairwise in the reference):
+# tests/test_integrate_carry.py reads 3e-9 to 1.8e-7 for the same pair.  A
+# frame in the wrong row, added twice or not at all moves a tone's peak by
+# 1/nint >= 2e-2 of itself here, and a bank stitched into the wrong place
+# by all of it: 1e-5 is 50 times the worst reading and far under either.
+TOL = 1e-5
+
+CASES = [  # (nint, window_frames, whole rows, tail frames dropped)
+    (51, 2, 1, 3),
+    (51, 8, 2, 7),
+    (7, 3, 4, 2),
+    (6, 4, 5, 4),
+]
+
+
+def _ids(case):
+    return "nint{0}-wf{1}".format(*case)
+
+
+def make_band(tmp_path, frames, seed=0):
+    """One band of four banks, each holding ``frames`` PFB frames and a
+    part of one, tiling the band downwards in frequency."""
+    total = (frames + NTAP - 1) * NFFT + NFFT // 2
+    nblocks = 4
+    bank_bw = -187.5 / NBANK
+    row = []
+    for k in range(NBANK):
+        p = str(tmp_path / f"blc0{k}-{frames}-{seed}.raw")
+        synth_raw(p, nblocks=nblocks, obsnchan=NCHAN,
+                  ntime_per_block=-(-total // nblocks), seed=seed * 8 + k,
+                  tone_chan=k % NCHAN, obsbw=bank_bw,
+                  obsfreq=8000.0 + (k + 0.5) * bank_bw)
+        row.append(p)
+    return [row]
+
+
+def reference(paths, nint, stokes, rows, despike):
+    """channelize_np over each bank's whole file, stitched, despiked."""
+    banks = []
+    for p in paths[0]:
+        stream = np.concatenate(
+            [blk for _, blk in GuppiRaw(p).iter_blocks(drop_overlap=True)],
+            axis=1)
+        usable = (rows * nint + NTAP - 1) * NFFT
+        banks.append(np.asarray(channelize_np(
+            stream[:, :usable], pfb_coeffs(NTAP, NFFT), nfft=NFFT,
+            ntap=NTAP, nint=nint, stokes=stokes), np.float64))
+    want = np.concatenate(banks, axis=-1)
+    if despike:
+        want[..., NFFT // 2::NFFT] = want[..., NFFT // 2 - 1::NFFT]
+    return want
+
+
+def rel_err(got, want):
+    return float(np.abs(np.asarray(got, np.float64) - want).max()
+                 / np.abs(want).max())
+
+
+def windows_of(total, wf, nint):
+    """(windows, windows that end with the integration open)."""
+    ends = list(range(wf, total, wf)) + [total]
+    return len(ends), sum(e % nint != 0 for e in ends)
+
+
+def payload(path):
+    if path.endswith(".h5"):
+        from blit.io.fbh5 import read_fbh5_data
+
+        return np.asarray(read_fbh5_data(path)).tobytes()
+    with open(path, "rb") as f:
+        return f.read()
+
+
+def test_one_rule_for_the_window():
+    # fold_frames decides for the default; an explicit window below one
+    # integration is a measured bound and is kept as given.
+    assert scan_window_frames(1 << 20, 51) == 8          # not 51
+    assert scan_window_frames(1 << 20, 51, 2) == 2       # the cell
+    assert scan_window_frames(1 << 20, 51, 60) == 60     # carried, 2 rows
+    assert scan_window_frames(1 << 20, 1, 2) == 2
+    assert scan_window_frames(1 << 20, 8) == 8
+    assert scan_window_frames(1024, 51) == (8192 // 51) * 51
+    assert scan_window_frames(1024, 51, 2) == 2          # the rehearsal
+    assert scan_window_frames(1024, 51, 120) == 102      # whole rows
+    assert scan_window_frames(64, 2, 5) == 4
+    assert scan_window_frames(64, 2, 4) == 4
+
+
+@pytest.mark.parametrize("despike", [True, False], ids=["despike", "raw"])
+@pytest.mark.parametrize("stokes", ["I", "IQUV"])
+@pytest.mark.parametrize("case", CASES, ids=_ids)
+def test_carried_scan_matches_whole_file(tmp_path, monkeypatch, case, stokes,
+                                         despike):
+    nint, wf, rows, tail = case
+    paths = make_band(tmp_path, rows * nint + tail)
+    nif, nchans = STOKES_NIF[stokes], NBANK * NCHAN * NFFT
+    kw = dict(nfft=NFFT, nint=nint, stokes=stokes, despike=despike,
+              window_frames=wf)
+    tl = Timeline()
+    out = str(tmp_path / "mesh.fil")
+    seen = []
+    check = M.ShardedAccumulator._check
+
+    def watched(self, value):
+        seen.append(self.rule)
+        return check(self, value)
+
+    monkeypatch.setattr(M.ShardedAccumulator, "_check", watched)
+    written = reduce_scan_mesh_to_files(paths, out_paths=[out], timeline=tl,
+                                        **kw)
+    monkeypatch.undo()
+    hdr = written[0][1]
+    fhdr, got = read_fil_data(out)
+    want = reference(paths, nint, stokes, rows, despike)
+    # Whole rows only: the tail that fills no integration is dropped.
+    assert got.shape == want.shape == (rows, nif, nchans)
+    assert hdr["nsamps"] == fhdr["nsamps"] == rows
+    assert fhdr["nchans"] == nchans
+    tbin = GuppiRaw(paths[0][0]).header(0)["TBIN"]
+    assert fhdr["tsamp"] == pytest.approx(tbin * NFFT * nint, rel=1e-12)
+    assert rel_err(got, want) < TOL
+    if despike:
+        np.testing.assert_array_equal(got[..., NFFT // 2::NFFT],
+                                      got[..., NFFT // 2 - 1::NFFT])
+    # The counters: a window that closes nothing fetches and writes
+    # nothing, and the accumulator kept its rule through every fold.
+    st = tl.report()
+    nwin, open_ = windows_of(rows * nint, wf, nint)
+    assert st["read"]["calls"] == st["device"]["calls"] == nwin
+    assert st["integrate.carry"]["calls"] == open_
+    assert st["integrate.carry"]["bytes"] == open_ * nif * nchans * 4
+    assert st["integrate.emit"]["calls"] == rows
+    assert st["integrate.emit"]["bytes"] == got.nbytes
+    assert st["readback"]["calls"] == st["write"]["calls"] == rows < nwin
+    assert st["readback"]["bytes"] == got.nbytes
+    assert seen == ["integration_acc"] * (1 + nwin)
+    # The same RAW bytes give the same product bytes ...
+    again = str(tmp_path / "again.fil")
+    reduce_scan_mesh_to_files(paths, out_paths=[again], **kw)
+    assert payload(again) == payload(out)
+    assert not os.path.exists(again + ".partial")
+    # ... and the pool oracle's (per-bank RawReducer at chunk_frames = the
+    # window, host stitch and despike).
+    pool = str(tmp_path / "pool.fil")
+    reduce_scan_pool_to_files(paths, out_paths=[pool], **kw)
+    assert payload(pool) == payload(out)
+
+
+@pytest.mark.parametrize("stokes", ["I", "IQUV"])
+@pytest.mark.parametrize("nint,wf", [(2, 4), (4, 4), (1, 3), (2, 5)])
+def test_integration_inside_a_window_takes_the_old_path(tmp_path, nint, wf,
+                                                        stokes):
+    # nint | window: no accumulator, no integrate.* row, every window
+    # gathers and writes, and the bytes are those of band_reduce at that
+    # nint, window by window (the path every existing product takes).
+    import jax.numpy as jnp
+
+    frames = 13
+    paths = make_band(tmp_path, frames, seed=2)
+    tl = Timeline()
+    out = str(tmp_path / "mesh.fil")
+    reduce_scan_mesh_to_files(paths, out_paths=[out], nfft=NFFT, nint=nint,
+                              stokes=stokes, window_frames=wf, timeline=tl)
+    st = tl.report()
+    assert "integrate.carry" not in st and "integrate.emit" not in st
+    eff = scan_window_frames(NFFT, nint, wf)
+    assert eff % nint == 0
+    total = frames // nint * nint
+    assert st["readback"]["calls"] == st["read"]["calls"] == -(-total // eff)
+    mesh = M.make_mesh(1, NBANK)
+    streams = [np.concatenate(
+        [blk for _, blk in GuppiRaw(p).iter_blocks(drop_overlap=True)],
+        axis=1) for p in paths[0]]
+    h = jnp.asarray(pfb_coeffs(NTAP, NFFT))
+    want = []
+    for f0 in range(0, total, eff):
+        n = min(eff, total - f0)
+        volt = np.stack([s[:, f0 * NFFT:(f0 + n + NTAP - 1) * NFFT]
+                         for s in streams])[None]
+        want.append(np.asarray(M.band_reduce(
+            M.shard_voltages(volt, mesh), h, mesh=mesh, nfft=NFFT,
+            ntap=NTAP, nint=nint, stokes=stokes, stitch=True,
+            despike_nfpc=NFFT))[0])
+    assert read_fil_data(out)[1].tobytes() == np.concatenate(want).tobytes()
+
+
+def test_a_second_pass_allocates_no_staging_slab(tmp_path, monkeypatch):
+    # The cell's shape of scan: full windows and a ragged last one (51 =
+    # 25 x 2 + 1).  Every window stages through slabs of the full window's
+    # shape, so from the second reduction on the pool hands back memory
+    # that is already faulted: nothing allocated, nothing dropped.
+    from blit import hostmem
+
+    monkeypatch.setattr(hostmem, "_POOL", hostmem.SlabPool())  # an empty one
+    paths = make_band(tmp_path, 51 + 3, seed=4)
+    tables = []
+    for tag in ("a", "b", "c"):
+        tl = Timeline()
+        reduce_scan_mesh_to_files(
+            paths, out_paths=[str(tmp_path / f"{tag}.fil")], nfft=NFFT,
+            nint=51, window_frames=2, timeline=tl)
+        tables.append(tl.report())
+    assert tables[0]["staging.alloc"]["calls"] == 2 * NBANK  # two in flight
+    for st in tables[1:]:
+        assert st["staging.alloc"]["calls"] == 0
+        assert st["staging.drop"]["calls"] == 0
+        assert st["staging.reuse"]["calls"] == 26 * NBANK
+    assert hostmem.slab_pool().stats()["lent_bytes"] == 0
+    assert payload(str(tmp_path / "a.fil")) == payload(str(tmp_path / "c.fil"))
+
+
+def test_the_cells_own_grid():
+    # band4.hires51: 51 frames in 2-frame windows are 25 windows that
+    # leave the integration open and a one-frame window that closes the
+    # row; per GB of RAW the link carries (25 * 5 + 4) / 54 of it.
+    assert windows_of(51, 2, 51) == (26, 25)
+    assert scan_window_frames(1 << 20, 51, 2) == 2
+    assert round(1000 * (25 * 5 + 4) / 54) == 2389
+
+
+class TestResumeInsideAnIntegration:
+    """``--resume`` keeps whole rows only: a run killed between two rows or
+    inside one resumes at the last whole row (no longer a window boundary)
+    and ends with the uninterrupted run's bytes."""
+
+    NINT, WF, ROWS, TAIL = 7, 3, 5, 2
+
+    def _run(self, paths, out, **kw):
+        tl = Timeline()
+        written = reduce_scan_mesh_to_files(
+            paths, out_paths=[out], nfft=NFFT, nint=self.NINT,
+            window_frames=self.WF, resume=True, timeline=tl, **kw)
+        return written, tl.report()
+
+    @pytest.mark.parametrize("ext,comp", [(".fil", None), (".h5", None),
+                                          (".h5", "bitshuffle")])
+    @pytest.mark.parametrize("where", ["between_rows", "inside_a_row"])
+    def test_resumed_bytes_equal_uninterrupted(self, tmp_path, monkeypatch,
+                                               ext, comp, where):
+        paths = make_band(tmp_path, self.ROWS * self.NINT + self.TAIL,
+                          seed=3)
+        ref = str(tmp_path / ("ref" + ext))
+        self._run(paths, ref, compression=comp)
+        out = str(tmp_path / ("res" + ext))
+        real, windows = S._feed_window, []
+
+        def dying(raws, local, mesh, nchan, npol, start, ntime, *a, **k):
+            windows.append(start // NFFT)
+            # Window 5 starts at frame 15 (row 2 closed at 14: killed
+            # between two rows, its flush still pending); window 6 at
+            # frame 18, inside row 2..3's integration.
+            if len(windows) == (6 if where == "between_rows" else 7):
+                raise RuntimeError("killed")
+            return real(raws, local, mesh, nchan, npol, start, ntime,
+                        *a, **k)
+
+        monkeypatch.setattr(S, "_feed_window", dying)
+        with pytest.raises(RuntimeError, match="killed"):
+            self._run(paths, out, compression=comp)
+        monkeypatch.undo()
+        cur = ReductionCursor.load(out)
+        # Whole rows only; and the claim is no window boundary.
+        assert cur is not None and cur.frames_done % self.NINT == 0
+        assert 0 < cur.frames_done < self.ROWS * self.NINT
+        assert cur.frames_done % self.WF != 0
+        written, st = self._run(paths, out, compression=comp)
+        assert written[0][1]["nsamps"] == self.ROWS
+        # Resumed at the claimed row, not restarted ...
+        assert st["integrate.emit"]["calls"] \
+            == self.ROWS - cur.frames_done // self.NINT
+        # ... and still the uninterrupted run's bytes.
+        assert payload(out) == payload(ref)
+        assert not os.path.exists(ReductionCursor.path_for(out))
+
+    def test_bitshuffle_chunks_hold_one_row_when_carried(self, tmp_path,
+                                                         caplog):
+        # A window holds no whole row (window_frames // nint = 0): the
+        # bitshuffle product is chunked one row at a time — said once, as
+        # a warning — and any whole row is a resume point.
+        import logging
+
+        assert S._bitshuffle_window_chunk_rows(16, 0) == 1
+        paths = make_band(tmp_path, 2 * self.NINT)
+        out = str(tmp_path / "one.h5")
+        with caplog.at_level(logging.WARNING, logger="blit.scan"):
+            self._run(paths, out, compression="bitshuffle")
+        assert any("chunk rows are 1" in r.message for r in caplog.records)
+        import h5py
+
+        with h5py.File(out, "r") as f:
+            assert f["data"].chunks[0] == 1
+            assert f["data"].shape[0] == 2
+
+
+def test_sharded_plane_refuses_by_name(tmp_path):
+    # --sharded shares no window program with the default loop: it
+    # refuses an integration it would have to carry, naming the loop that
+    # does — never a window rounded up to nint.
+    from blit.parallel.sharded import reduce_scan_sharded_to_files
+
+    paths = make_band(tmp_path, 16)
+    with pytest.raises(ValueError, match="default mesh loop"):
+        reduce_scan_sharded_to_files(
+            paths, out_paths=[str(tmp_path / "s.fil")], nfft=NFFT, nint=7,
+            window_frames=3)
+    # Where the integration fits the window it runs as before.
+    out = str(tmp_path / "s.fil")
+    reduce_scan_sharded_to_files(paths, out_paths=[out], nfft=NFFT, nint=2,
+                                 window_frames=4)
+    mesh = str(tmp_path / "m.fil")
+    reduce_scan_mesh_to_files(paths, out_paths=[mesh], nfft=NFFT, nint=2,
+                              window_frames=4)
+    assert payload(out) == payload(mesh)
+
+
+def test_header_of_a_carried_product(tmp_path):
+    paths = make_band(tmp_path, 60)
+    out = str(tmp_path / "b.fil")
+    reduce_scan_mesh_to_files(paths, out_paths=[out], nfft=NFFT, nint=51,
+                              window_frames=2)
+    hdr, _ = read_fil_header(out)
+    assert hdr["nchans"] == NBANK * NCHAN * NFFT and hdr["nifs"] == 1
+    assert abs(hdr["foff"]) * hdr["nchans"] == pytest.approx(187.5)

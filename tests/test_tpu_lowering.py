@@ -150,6 +150,68 @@ class TestMeshWithPallasInside:
         np.testing.assert_allclose(out[0], want, rtol=1e-4, atol=1e-2)
 
 
+class TestCarriedMeshStepLowersForTheTpu:
+    """The carried scan's per-window programs (ISSUE 30) at the cell's own
+    shape — 64 ch x (2 + 3) frames x 2^20 per chip on the (1, 4) mesh —
+    cross-lowered for the TPU: the channeliser at ``nint`` 1, unstitched
+    (``fused1`` + ``tail2_detect`` inside ``shard_map``), and the per-chip
+    fold.  Neither holds a collective: nothing is gathered until a row
+    closes (``stitch_despike``, which does)."""
+
+    NCH, WF, NBANK = 64, 2, 4
+
+    def _spec(self, mesh, shape, dtype, rule):
+        from blit.parallel import mesh as M
+
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=M.sharding_for(mesh, rule))
+
+    def _export(self, fn, *specs):
+        exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*specs)
+        assert exported.nr_devices == self.NBANK
+        return exported.mlir_module()
+
+    @pytest.mark.parametrize("frames", [2, 1], ids=["window", "last-window"])
+    def test_channelise_and_fold_per_chip(self, monkeypatch, frames):
+        from blit.parallel import mesh as M
+
+        # What ``auto`` resolves to on a chip; nothing runs.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        mesh = M.make_mesh(1, self.NBANK)
+        band = self.NBANK * self.NCH * NFFT
+        text = self._export(
+            functools.partial(M.band_reduce, mesh=mesh, nfft=NFFT, ntap=NTAP,
+                              nint=1, stokes="I", stitch=False,
+                              despike_nfpc=0),
+            self._spec(mesh, (1, self.NBANK, self.NCH,
+                              (frames + NTAP - 1) * NFFT, 2, 2), "int8",
+                       "voltages"),
+            self._spec(mesh, (NTAP, NFFT), "float32", "replicated"))
+        assert text.count("tpu_custom_call") == 2
+        assert ch.last_kernel_plan()["pfb_kernel"] == "fused1"
+        assert ch.last_kernel_plan()["tail_kernel"] == "tail2_detect"
+        assert "all_gather" not in text and "all-gather" not in text
+        text = self._export(
+            functools.partial(M.band_carry, mesh=mesh, nint=51),
+            self._spec(mesh, (1, 1, band), "float32", "integration_acc"),
+            self._spec(mesh, (1, frames, 1, band), "float32",
+                       "filterbank_sharded"),
+            jax.ShapeDtypeStruct((), jnp.int32))
+        assert "all_gather" not in text and "all-gather" not in text
+
+    def test_the_stitch_of_a_closed_row_gathers(self):
+        from blit.parallel import mesh as M
+
+        mesh = M.make_mesh(1, self.NBANK)
+        band = self.NBANK * self.NCH * NFFT
+        text = self._export(
+            functools.partial(M.stitch_despike, mesh=mesh,
+                              despike_nfpc=NFFT),
+            self._spec(mesh, (1, 1, 1, band), "float32",
+                       "filterbank_sharded"))
+        assert "all_gather" in text or "all-gather" in text
+
+
 class TestKernelRequestsOffTpuAndCpu:
     def test_pallas_interpret_names_its_backends(self):
         assert device.pallas_interpret("tpu") is False
