@@ -289,6 +289,13 @@ class HostLink:
         link = host_link_bytes()
         return link is not None and _FETCH_DEBIT * nbytes >= link
 
+    def retire(self) -> None:
+        """Let go of the puts that have landed: what a caller does with
+        one it has waited in and is about to DONATE (:meth:`put`: a handle
+        the budget holds is never donated)."""
+        with self._cv:
+            self._retire()
+
     def inflight_bytes(self) -> int:
         """Bytes in flight now (what has landed is let go of first)."""
         with self._cv:

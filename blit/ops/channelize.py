@@ -735,21 +735,34 @@ def _word_samples(words: jax.Array) -> jax.Array:
     return v.reshape(words.shape + (v.shape[-1] // 2, 2))
 
 
-@functools.partial(jax.jit, static_argnames=_CHANNELIZE_STATIC,
-                   donate_argnames=("tail",))
-def channelize_stream(
+def stream_step(
     tail: jax.Array, body: jax.Array, coeffs: jax.Array, **kw
 ) -> Tuple[jax.Array, jax.Array]:
-    """One dispatch of a STREAM: ``concat(tail, body)`` reduced exactly as
+    """One chip's step of a STREAM, to be traced into a program that
+    donates ``tail``: ``concat(tail, body)`` reduced exactly as
     :func:`channelize` (kwargs ``kw``) reduces that gross block, and the
-    filter state the next dispatch of the same channels starts from.
+    filter state the next step of the same channels starts from.
 
     Both are :func:`sample_words` of voltages: ``tail`` ``(nchan,
     (ntap-1)*nfft)`` is the last ``ntap - 1`` frames' worth of samples
     before ``body`` ``(nchan, frames*nfft)``, the stream's new samples.
     Returns ``(product, next_tail)``: ``next_tail`` is the last
     ``(ntap-1)*nfft`` words of the concatenation (a body shorter than the
-    filter state keeps part of the old tail by the same line),
+    filter state keeps part of the old tail by the same line).  The ONE
+    body of the reducer's :func:`channelize_stream` and of the mesh's
+    per-chip :func:`blit.parallel.mesh.band_stream`."""
+    gross = jnp.concatenate([tail, body], axis=1)
+    return (channelize(_word_samples(gross), coeffs, **kw),
+            gross[:, gross.shape[1] - tail.shape[1]:])
+
+
+@functools.partial(jax.jit, static_argnames=_CHANNELIZE_STATIC,
+                   donate_argnames=("tail",))
+def channelize_stream(
+    tail: jax.Array, body: jax.Array, coeffs: jax.Array, **kw
+) -> Tuple[jax.Array, jax.Array]:
+    """One dispatch of a STREAM (:func:`stream_step` as a program of its
+    own): the product of ``concat(tail, body)`` and the next tail,
     device-resident data the next dispatch consumes like
     :func:`integrate_carry`'s accumulator — a stream's filter state
     crosses the host link once, as its head.  ``tail`` is DONATED: the
@@ -757,9 +770,7 @@ def channelize_stream(
     held once, not twice), and a device array passed as ``tail`` is
     deleted by the call.
     """
-    gross = jnp.concatenate([tail, body], axis=1)
-    return (channelize(_word_samples(gross), coeffs, **kw),
-            gross[:, gross.shape[1] - tail.shape[1]:])
+    return stream_step(tail, body, coeffs, **kw)
 
 
 def _direct_put(host, then: Callable):

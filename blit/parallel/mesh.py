@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from blit.device import host_link
 from blit.observability import Timeline
-from blit.ops.channelize import channelize, integrate_carry
+from blit.ops.channelize import channelize, integrate_carry, stream_step
 from blit.ops.despike import despike
 
 BAND_AXIS = "band"
@@ -67,6 +67,11 @@ PARTITION_RULES: Dict[str, P] = {
     # its own bank's partial sum from window to window (band_carry) and
     # nothing of it is gathered until a row closes.
     "integration_acc": P(BAND_AXIS, None, BANK_AXIS),
+    # The scan's filter state (nband, nbank, nchan, (ntap-1)*nfft), one
+    # word a sample: each chip holds the last ntap-1 frames' worth of its
+    # own bank's samples from window to window (band_stream); it comes up
+    # from the host once per stream, as the stream's head.
+    "filter_state": P(BAND_AXIS, BANK_AXIS),
 }
 
 # The collective-latency histograms of the sharded plane (ISSUE 9): every
@@ -189,10 +194,12 @@ def band_reduce(
     bank's voltage block, then the 8 banks of each band stitch their fine
     spectra into a contiguous band over ICI.  ``nint`` integrates INSIDE
     this one program, so it has to fit the block; an integration longer
-    than a window is the caller's to carry: this step at ``nint=1,
+    than a window is the caller's to carry: the step at ``nint=1,
     stitch=False`` (no collective), :func:`band_carry` per chip, and
     :func:`stitch_despike` only for rows that closed
-    (:func:`blit.parallel.scan.reduce_scan_mesh_to_files`).
+    (:func:`blit.parallel.scan.reduce_scan_mesh_to_files`, whose windows
+    run :func:`band_stream`: this step with the filter state left on the
+    chips).
 
     Args:
       voltages: int8 ``(nband, nbank, nchan, ntime, npol, 2)``, sharded with
@@ -220,28 +227,13 @@ def band_reduce(
       full band (stitched) or the global concatenation of per-bank channels
       (unstitched, sharded over ``bank``).
     """
-    in_specs = (P(BAND_AXIS, BANK_AXIS), P())
-    out_specs = (
-        P(BAND_AXIS, None, None, None)
-        if stitch
-        else P(BAND_AXIS, None, None, BANK_AXIS)
-    )
-
     def step(v, h):
         # v: (1, 1, nchan, ntime, npol, 2) — this chip's block.
         out = channelize(
             v[0, 0], h, nfft=nfft, ntap=ntap, nint=nint, stokes=stokes,
             fft_method=fft_method, fqav_by=fqav_by, dtype=dtype,
         )  # (t, nif, nchan*nfft//fqav_by)
-        if stitch:
-            out = jax.lax.all_gather(out, BANK_AXIS, axis=2, tiled=True)
-            if despike_nfpc >= 2:
-                out = despike(out, despike_nfpc)
-        elif despike_nfpc >= 2:
-            # Coarse channels never straddle banks, so the per-bank despike
-            # is exact in the sharded layout too.
-            out = despike(out, despike_nfpc)
-        return out[None]  # leading band axis block
+        return _band_product(out, stitch, despike_nfpc)
 
     # check_vma=False on both branches: the varying-mesh-axes analysis
     # cannot see that all_gather's output is bank-invariant, and it
@@ -249,9 +241,87 @@ def band_reduce(
     # TPU (their out_shape carries no vma) — the check must not pass only
     # on the XLA path the CPU takes.
     return jax.shard_map(
-        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False,
+        step, mesh=mesh, in_specs=(partition_rule("voltages"), P()),
+        out_specs=_product_rule(stitch), check_vma=False,
     )(voltages, coeffs)
+
+
+def _product_rule(stitch: bool) -> P:
+    """The layout of ``band_reduce`` / ``band_stream``'s product."""
+    return partition_rule(
+        "filterbank_stitched" if stitch else "filterbank_sharded")
+
+
+def _band_product(out: jax.Array, stitch: bool, despike_nfpc: int):
+    """One chip's ``(t, nif, nchans)`` spectra as its block of the band
+    product (inside ``shard_map``): gathered over ``bank`` where
+    ``stitch``, DC spikes repaired where ``despike_nfpc >= 2`` (coarse
+    channels never straddle banks, so the per-bank despike is exact in
+    the sharded layout too)."""
+    if stitch:
+        out = jax.lax.all_gather(out, BANK_AXIS, axis=2, tiled=True)
+    if despike_nfpc >= 2:
+        out = despike(out, despike_nfpc)
+    return out[None]  # leading band axis block
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "mesh", "nfft", "ntap", "nint", "stokes", "fft_method", "stitch",
+        "despike_nfpc", "fqav_by", "dtype",
+    ),
+    donate_argnames=("tail",),
+)
+def band_stream(
+    tail: jax.Array,
+    body: jax.Array,
+    coeffs: jax.Array,
+    *,
+    mesh: Mesh,
+    nfft: int,
+    ntap: int = 4,
+    nint: int = 1,
+    stokes: str = "I",
+    fft_method: str = "auto",
+    stitch: bool = True,
+    despike_nfpc: int = 0,
+    fqav_by: int = 1,
+    dtype: str = "float32",
+) -> tuple:
+    """One window of a band STREAM: :func:`band_reduce` of the gross block
+    ``concat(tail, body)``, whose filter state never left the chips.
+
+    Per chip, :func:`blit.ops.channelize.stream_step` — the reducer's
+    ``channelize_stream`` body, not a second implementation — on that
+    chip's own bank: ``tail`` ``(nband, nbank, nchan, (ntap-1)*nfft)``
+    under the ``filter_state`` rule and ``body`` ``(nband, nbank, nchan,
+    frames*nfft)`` under ``voltages``, both
+    :func:`blit.ops.channelize.sample_words` (one word a sample: the form
+    the host link carries at speed).  ``tail`` is DONATED
+    (:class:`ShardedAccumulator`: a bank's filter state is held once).
+    Returns ``(product, next_tail)``: the product exactly as
+    :func:`band_reduce` lays it out (``stitch``, ``despike_nfpc``,
+    ``fqav_by`` as there), ``next_tail`` the last ``(ntap-1)*nfft`` words
+    of each chip's concatenation (a body shorter than the state — a
+    scan's one-frame last window — keeps part of the old tail), with no
+    collective of its own."""
+
+    def step(t, v, h):
+        out, nxt = stream_step(
+            t[0, 0], v[0, 0], h, nfft=nfft, ntap=ntap, nint=nint,
+            stokes=stokes, fft_method=fft_method, fqav_by=fqav_by,
+            dtype=dtype,
+        )
+        return _band_product(out, stitch, despike_nfpc), nxt[None, None]
+
+    state = partition_rule("filter_state")
+    return jax.shard_map(
+        step, mesh=mesh,
+        in_specs=(state, partition_rule("voltages"), P()),
+        out_specs=(_product_rule(stitch), state),
+        check_vma=False,  # as band_reduce
+    )(tail, body, coeffs)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "nif", "nchans"))
@@ -374,9 +444,14 @@ def put_local_shards(
     now partition-rule-driven).  Each player's put is a ``feed.put``
     stage of its own (that block's bytes) on ``timeline`` and draws on
     the process's link budget (:class:`blit.device.HostLink`): one that
-    would not fit beside those in flight (four 1.34 GB banks against
-    4 GiB, and the fourth landed 7-9 s late) first waits, inside its
-    stage, for the oldest to land."""
+    would not fit beside those in flight first waits, inside its stage,
+    for the oldest to land.  The puts are issued one after the other
+    from the calling thread: a put of sample words returns at once and
+    the runtime copies the banks side by side behind it (four 0.54 GB
+    bodies to four chips land in 0.085 s from one thread and in 0.087 s
+    from four; ``tools/probe_mesh_puts.py``).  The budget holds each
+    put array until it has landed: a caller that DONATES the result to a
+    program first has the budget let go of it (``HostLink.put``)."""
     tl = timeline if timeline is not None else Timeline()
     shards = []
     for (b, k), blk in sorted(blocks.items()):
@@ -405,6 +480,8 @@ class ShardedAccumulator:
       preserve the rule — :meth:`fold` asserts the returned sharding
       still matches, so a drifted spec fails loudly at the first window
       instead of silently regathering every fold.
+    - :meth:`fold_aux` is :meth:`fold` for a step that also returns a
+      product (``(aux, value)``: the scan's stream program).
     - :attr:`value` holds the live pytree; ``spec``/``sharding`` expose
       the rule for finish programs (the correlator's closing band psum).
     """
@@ -434,6 +511,16 @@ class ShardedAccumulator:
         self.value = fn(self.value, *args, **kw)
         self._check(self.value)
         return self.value
+
+    def fold_aux(self, fn, *args, **kw):
+        """:meth:`fold` for an ``fn`` that returns ``(aux, value)`` — a
+        step with a product beside its state (:func:`band_stream`): the
+        value moves on and ``aux`` is returned."""
+        if self.value is None:
+            raise RuntimeError("ShardedAccumulator.fold before init")
+        aux, self.value = fn(self.value, *args, **kw)
+        self._check(self.value)
+        return aux
 
     def _check(self, value) -> None:
         want = self.sharding

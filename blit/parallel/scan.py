@@ -3,12 +3,12 @@
 The end-to-end BASELINE.json config-3 path: every bank's GUPPI RAW voltages
 feed the chip that plays that ``BLP<band><bank>`` player, the per-chip
 channelization runs under ``shard_map``, and the 8 banks of each band stitch
-over ICI (blit/parallel/mesh.band_reduce).  The host holds at most one
-bank's int8 voltages at a time — each player's block is placed directly on
-its chip and the global sharded array is assembled from those per-device
-shards.  This is the TPU rebuild of the reference's whole-scan workflow
-(``loadscan``, src/gbt.jl:90-114, which fetched per-bank arrays to the main
-process and ``vcat``-ed them there).
+over ICI (blit/parallel/mesh.band_stream: the filter state stays on the
+chips and every sample goes up once, as one word).  Each player's block is
+placed directly on its chip and the global sharded array is assembled from
+those per-device shards.  This is the TPU rebuild of the reference's
+whole-scan workflow (``loadscan``, src/gbt.jl:90-114, which fetched
+per-bank arrays to the main process and ``vcat``-ed them there).
 """
 
 from __future__ import annotations
@@ -26,6 +26,7 @@ from blit.ops.channelize import (
     STOKES_NIF,
     output_header,
     pfb_coeffs,
+    sample_words,
     usable_frames,
 )
 from blit.parallel import mesh as M
@@ -239,54 +240,87 @@ def _open_players(raw_paths, mesh):
 
 
 def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
-                 staged=None, slab_ntime=None):
-    """Assemble the global sharded voltage array for gap-free samples
-    ``[start, start + ntime)`` of every player.  Every LOCAL player's
-    window is read into host memory first, one after the other
-    (``feed.read`` per bank on ``tl``); then each block goes straight
-    onto its chip (``feed.put`` per bank) and the global array is built
+                 staged=None, slab_ntime=None, head_ntime=0):
+    """Put gap-free samples ``[start, start + ntime)`` of every LOCAL
+    player on that player's chip, as
+    :func:`blit.ops.channelize.sample_words` (one word a sample: a view
+    of what was read, and the form the host link carries at speed), and
+    return the global sharded array ``(nband, nbank, nchan, ntime)`` —
+    the body of one :func:`blit.parallel.mesh.band_stream`.  Every sample
+    of a scan goes up once: a window is its NEW samples, its filter state
+    is on the chips already.  Where it is not — a stream's first window —
+    ``head_ntime`` asks for the head too, samples ``[start - head_ntime,
+    start)``: a read and a put of its own per player, laid out by the
+    ``filter_state`` rule and free to be donated.  Returns ``(head,
+    body)``, ``head`` ``None`` where none was asked for.
+
+    Every local player is read into host memory first, one after the
+    other (``feed.read`` per read on ``tl``); then each goes straight
+    onto its chip (``feed.put`` per put) and the global array is built
     from the single-device shards (no whole-scan host buffer, no
     device_put to any non-addressable device) — the assembly itself is
     :func:`blit.parallel.mesh.put_local_shards`, the ONE
     partition-rule-driven implementation the sharded plane shares.
 
-    ``staged`` (a list) makes each bank's window buffer a slab of the
-    process staging pool (blit/hostmem.py) and appends it: the caller
-    gives the slabs back once THIS window's dispatch has synchronized —
-    never sooner, ``device_put`` returns before the bytes have landed
-    (and on the CPU backend may alias the slab outright).  The slab has
+    ``staged`` (a list) makes each read's buffer a slab of the process
+    staging pool (blit/hostmem.py) and appends it: the caller gives the
+    slabs back once THIS window's dispatch has synchronized — never
+    sooner, ``device_put`` returns before the bytes have landed (and on
+    the CPU backend may alias the slab outright).  A body's slab has
     ``slab_ntime`` samples per channel (the scan's full window; default
     this window's own) and a shorter window — a scan's last — reads into
     its leading bytes: one shape class per scan, so the pool hands every
     window memory that is already faulted (a ragged last window of a
     shape of its own pushed four full-window slabs out of the pool every
     pass, and the next pass first-touched four fresh ones inside its
-    timed reads).  Without ``staged`` each window reads into fresh
-    memory that the returned array keeps alive."""
+    timed reads).  A head's slab has the head's own shape, the same in
+    every scan of that ``nfft``.  Without ``staged`` each read goes into
+    fresh memory that the returned array keeps alive."""
+    import jax
+
     nband, nbank = mesh.devices.shape
     tl = tl if tl is not None else observability.Timeline()
-    blocks = {}
-    for b, k in local:
-        r = raws[(b, k)]
+
+    def read(r, skip, n, slab_n):
         buf = None
         if staged is not None:
             buf = hostmem.slab_pool().take(
-                (nchan, max(ntime, slab_ntime or 0), npol, 2), np.int8, tl)
+                (nchan, max(n, slab_n), npol, 2), np.int8, tl)
             staged.append(buf)
-            if buf.shape[1] != ntime:  # contiguous, in the slab's head
-                buf = buf.reshape(-1)[:nchan * ntime * npol * 2].reshape(
-                    nchan, ntime, npol, 2)
-        with tl.stage("feed.read", nchan * ntime * npol * 2):
-            v = _gapless(r, ntime, skip=start, out=buf)
-        if v.shape[0] != nchan or v.shape[1] < ntime or v.shape[2:] != (npol, 2):
+            if buf.shape[1] != n:  # contiguous, in the slab's head
+                buf = buf.reshape(-1)[:nchan * n * npol * 2].reshape(
+                    nchan, n, npol, 2)
+        with tl.stage("feed.read", nchan * n * npol * 2):
+            v = _gapless(r, n, skip=skip, out=buf)
+        if v.shape != (nchan, n, npol, 2):
             raise ValueError(
                 f"{r.path}: shape {v.shape} incompatible with "
-                f"(nchan={nchan}, ntime>={ntime}, npol={npol}, 2)"
+                f"(nchan={nchan}, ntime={n}, npol={npol}, 2)"
             )
-        blocks[(b, k)] = np.ascontiguousarray(v[None, None, :, :ntime])
-    return M.put_local_shards(
-        blocks, mesh, (nband, nbank, nchan, ntime, npol, 2), timeline=tl
-    )
+        return sample_words(v)[None, None]
+
+    heads, bodies = {}, {}
+    for bk in local:
+        if head_ntime:
+            heads[bk] = read(raws[bk], start - head_ntime, head_ntime, 0)
+        bodies[bk] = read(raws[bk], start, ntime, slab_ntime or 0)
+    block = (nband, nbank, nchan)
+    # The heads go up first, each a transfer of its own on the link
+    # budget (a head and its body as one transfer were let go of when
+    # the smaller body had landed, and the next bank's was then enqueued
+    # over the premapped region: 2-3 s of a pass, every other pass).
+    tail = M.put_local_shards(
+        heads, mesh, block + (head_ntime,), "filter_state", timeline=tl,
+    ) if head_ntime else None
+    body = M.put_local_shards(bodies, mesh, block + (ntime,), timeline=tl)
+    if tail is not None:
+        # The tail is DONATED to the window's program, and a handle the
+        # link budget holds must never be (HostLink.put): the heads are
+        # waited in, once per stream (inside ``read``), and the budget
+        # lets go of them.
+        jax.block_until_ready(tail)
+        host_link().retire()
+    return tail, body
 
 
 def _scan_headers(raws, local, *, nfft, nint, stokes, fqav_by):
@@ -700,12 +734,15 @@ def load_scan_mesh(
         raise ValueError(
             f"scan too short: {min_samps} samples for nfft={nfft}"
         )
-    ntime = (frames + ntap - 1) * nfft
-
-    volt = _feed_window(raws, local, mesh, nchan, npol, 0, ntime)
+    # One window that is the whole scan: the stream's head and all the
+    # rest as its one body.
+    head_ntime = (ntap - 1) * nfft
+    tail, body = _feed_window(raws, local, mesh, nchan, npol, head_ntime,
+                              frames * nfft, head_ntime=head_ntime)
     coeffs = jnp.asarray(pfb_coeffs(ntap, nfft, window))
-    out = M.band_reduce(
-        volt,
+    out, _ = M.band_stream(
+        tail,
+        body,
         coeffs,
         mesh=mesh,
         nfft=nfft,
@@ -758,11 +795,18 @@ def reduce_scan_mesh_to_files(
     """Reduce one scan across the mesh and STREAM each stitched band to a
     ``.fil`` product — the persistence epilogue ``load_scan_mesh`` lacks.
 
-    The reduction runs ``window_frames`` PFB frames per dispatch (each
-    window re-reads the (ntap-1)*nfft-sample PFB prologue), so host RSS,
-    HBM, and per-window readback stay bounded no matter the scan length
-    and no matter ``nint`` — the mesh analog of
+    The reduction runs ``window_frames`` PFB frames per dispatch, so host
+    RSS, HBM, and per-window readback stay bounded no matter the scan
+    length and no matter ``nint`` — the mesh analog of
     ``RawReducer.reduce_to_file``'s slab streaming (blit/pipeline.py).
+    Every sample crosses the host link once: a window reads and puts its
+    NEW frames only, as ``sample_words``, and each bank's filter state
+    (the ``(ntap-1)*nfft`` samples before the window) stays on its chip
+    as the previous window's donated output
+    (:func:`blit.parallel.mesh.band_stream`, rule ``filter_state``); it
+    comes up from the host once per stream — the first window's head, at
+    ``f0_start * nfft`` after a ``--resume`` — as a transfer of its own
+    that has landed, and left the link budget, before it is donated.
     ``window_frames=None`` (the default) derives an HBM-safe bound from
     ``nfft`` (:func:`blit.config.default_window_frames`); pass a value >=
     the scan length for a deliberate one-window run
@@ -773,7 +817,8 @@ def reduce_scan_mesh_to_files(
     an integration longer than the window (rawspec's ``-f 1048576 -t 51``
     at the 2-frame window four 16 GB chips hold) or straddling its
     boundary — the integration is CARRIED: every chip channelizes its
-    window at ``nint=1`` and folds the spectra, frame by frame in stream
+    window at ``nint=1`` (the same stream program, no collective) and
+    folds the spectra, frame by frame in stream
     order, into a float32 partial sum that stays on that chip from window
     to window (:func:`blit.parallel.mesh.band_carry`, the accumulator
     sharded over ``bank`` and held once), with no collective; a window
@@ -796,8 +841,15 @@ def reduce_scan_mesh_to_files(
 
     Observability (SURVEY.md §5 metrics bar): pass ``timeline`` (a
     :class:`blit.observability.Timeline`) to accumulate per-window stage
-    timings with byte counts — ``read`` (host RAW ingest + device feed;
-    inside it one ``feed.read`` and one ``feed.put`` per local bank),
+    timings with byte counts — ``read`` (host RAW ingest + device feed:
+    the bytes it read; inside it one ``feed.read`` per read and one
+    ``feed.put`` per put — a bank's new samples, and once per stream its
+    head), the counted instants ``state.head`` (``calls`` = local
+    banks whose filter state came up from the host: once per stream,
+    ``bytes`` = those tails) and ``state.carry`` (``calls`` =
+    bank-windows whose filter state was the previous window's output,
+    ``bytes`` = tail bytes that stayed on the chips), ``link.put``
+    (``bytes`` = the samples fed, once each),
     ``dispatch`` (async window dispatch, ~0 after the first compile),
     ``device`` (the blocking wait on the window's compute+collectives),
     ``readback`` (stitched-band device→host), ``write`` (product
@@ -898,27 +950,32 @@ def reduce_scan_mesh_to_files(
             mesh=mesh, nfft=nfft, ntap=ntap, stokes=stokes,
             fft_method=fft_method, fqav_by=fqav_by, dtype=dtype,
         )
-        # The open integration: each chip's own partial sum, on the mesh
-        # from window to window, held once (donated fold).
+        # Each bank's filter state and the open integration (each chip's
+        # own partial sum): on the mesh from window to window, held once
+        # (donated folds).
+        state = M.ShardedAccumulator(mesh, "filter_state")
         acc = M.ShardedAccumulator(mesh, "integration_acc")
         filled = 0  # frames the open integration holds (resume: whole rows)
 
-        def reduce_window(volt, n):
+        def channelise(body, **kw):
+            """The window's stream program: the product of the filter
+            state on the chips + ``body``; the state moves on."""
+            return state.fold_aux(M.band_stream, body, coeffs, **kw,
+                                  **reduce_kw)
+
+        def reduce_window(body, n):
             """One window's programs -> ``(token, out)``: ``out`` the
             stitched bands of the product rows the window closed
             (``None`` where it closed none), ``token`` what is ready
-            once the window's voltages have been consumed."""
+            once the window's samples have been consumed."""
             nonlocal filled
             if not carried:
-                out = M.band_reduce(
-                    volt, coeffs, nint=nint, stitch=True,
-                    despike_nfpc=despike_nfpc, **reduce_kw)
+                out = channelise(body, nint=nint, stitch=True,
+                                 despike_nfpc=despike_nfpc)
                 return out, out
-            # Per chip, no collective: spectra at nint=1 (the sharded
-            # plane's per-chip program), folded into the chip's own sum.
-            power = M.band_reduce(
-                volt, coeffs, nint=1, stitch=False, despike_nfpc=0,
-                **reduce_kw)
+            # Per chip, no collective: spectra at nint=1, folded into the
+            # chip's own sum.
+            power = channelise(body, nint=1, stitch=False, despike_nfpc=0)
             if acc.value is None:  # the scan's first window
                 acc.init(M.carry_zeros(mesh=mesh, nif=STOKES_NIF[stokes],
                                        nchans=nbank * per_bank))
@@ -979,26 +1036,36 @@ def reduce_scan_mesh_to_files(
         # I/O overlaps device compute at one extra window of HBM.
         pending = None
         f0 = f0_start
+        head_ntime = (ntap - 1) * nfft
         # Every window stages through slabs of the largest window's shape.
-        slab_ntime = (min(wf, total - f0_start) + ntap - 1) * nfft
+        slab_ntime = min(wf, total - f0_start) * nfft
         with observability.span(
             "scan.reduce", nfft=nfft,
             out=out_paths[0],  # the first product, as reduce.to_file's
         ), profile_trace(trace_logdir):
             while f0 < total:
                 n = min(wf, total - f0)
-                ntime = (n + ntap - 1) * nfft
+                # A stream's first window brings its head up with it.
+                head = head_ntime if state.value is None else 0
                 # Locally fed voltage bytes: complex int8 = 2 B/sample.
-                fed = len(raws) * nchan * ntime * npol * 2
+                per_sample = len(raws) * nchan * npol * 2
                 with observability.span("scan.window", f0=f0):
                     staged = []
-                    with tl.stage("read", fed):
-                        volt = _feed_window(
-                            raws, local, mesh, nchan, npol, f0 * nfft,
-                            ntime, tl, staged, slab_ntime,
+                    with tl.stage("read", per_sample * (head + n * nfft)):
+                        tail, body = _feed_window(
+                            raws, local, mesh, nchan, npol,
+                            f0 * nfft + head_ntime, n * nfft, tl, staged,
+                            slab_ntime, head,
                         )
+                        if head:
+                            state.init(tail)
+                    # Filter state by where it comes from: up from the
+                    # host (once per bank per stream) or left on the chip
+                    # by the last window.
+                    tl.mark("state.head" if head else "state.carry",
+                            per_sample * head_ntime, calls=len(raws))
                     with tl.stage("dispatch", byte_free=True):
-                        token, out = reduce_window(volt, n)
+                        token, out = reduce_window(body, n)
                     if pending is not None:
                         flush(*pending)
                 pending = (token, out, staged)

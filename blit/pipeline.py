@@ -602,7 +602,7 @@ class RawReducer:
             self._head_slab = None
         # Every dispatch is synced: the link budget lets go of the stream's
         # last handles now, not at the next reduction's first put.
-        host_link().inflight_bytes()
+        host_link().retire()
 
     @property
     def stats(self) -> ReductionStats:

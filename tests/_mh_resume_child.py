@@ -8,7 +8,7 @@ Run as: ``python tests/_mh_resume_child.py <pid> <nproc> <port> <outdir>``.
 Phases:
 
 1. clean run → golden per-band products;
-2. run with band_reduce crashing on its 3rd call → both processes leave
+2. run with band_stream crashing on its 3rd call → both processes leave
    per-band cursor sidecars (symmetric: same call count on every
    process);
 3. resume → must complete, drop the sidecars, and byte-match the golden;
@@ -86,7 +86,7 @@ def main() -> None:
 
     # 2. Symmetric crash on the 3rd window (same call count on every
     #    process — the loop is lockstep).
-    real = M.band_reduce
+    real = M.band_stream
     calls = []
 
     def flaky(*a, **kw):
@@ -95,13 +95,13 @@ def main() -> None:
             raise RuntimeError("synthetic pod crash")
         return real(*a, **kw)
 
-    M.band_reduce = flaky
+    M.band_stream = flaky
     crashed = False
     try:
         run("res", resume=True)
     except RuntimeError:
         crashed = True
-    M.band_reduce = real
+    M.band_stream = real
     assert crashed and len(calls) == 3, (
         "the injected 3rd-window crash did not fire (calls=%d) — the test "
         "would otherwise degrade to resume-from-zero" % len(calls)

@@ -95,6 +95,17 @@ def test_blocks_what_would_reach_the_link_until_the_oldest_lands(link, nbytes):
     del second
 
 
+def test_retire_lets_go_of_what_has_landed_and_of_nothing_else(link):
+    # What a caller does before it donates a put it has waited in.
+    a, b = link.put(Host(400)), link.put(Host(300))
+    a.land()
+    link.retire()
+    assert [h for h, _ in link._puts] == [b] and link._bytes == 300
+    b.land()
+    link.retire()
+    assert link._puts == [] and link._bytes == 0
+
+
 def test_releases_on_readiness_of_the_put_or_of_what_consumed_it(link):
     a = link.put(Host(400))
     made = []

@@ -172,7 +172,19 @@ def test_carried_scan_matches_whole_file(tmp_path, monkeypatch, case, stokes,
     assert st["integrate.emit"]["bytes"] == got.nbytes
     assert st["readback"]["calls"] == st["write"]["calls"] == rows < nwin
     assert st["readback"]["bytes"] == got.nbytes
-    assert seen == ["integration_acc"] * (1 + nwin)
+    assert seen.count("integration_acc") == 1 + nwin
+    # ... as each bank's filter state kept its own: up from the host once,
+    # then the previous window's output (ISSUE 31), every sample of the
+    # scan put once.
+    assert seen.count("filter_state") == 1 + nwin
+    tail = NBANK * NCHAN * (NTAP - 1) * NFFT * 4
+    assert (st["state.head"]["calls"], st["state.head"]["bytes"]) \
+        == (NBANK, tail)
+    assert (st["state.carry"]["calls"], st["state.carry"]["bytes"]) \
+        == (NBANK * (nwin - 1), tail * (nwin - 1))
+    fed = NBANK * NCHAN * (rows * nint + NTAP - 1) * NFFT * 4
+    assert st["link.put"]["bytes"] == st["read"]["bytes"] == fed
+    assert st["link.put"]["calls"] == NBANK * (nwin + 1)
     # The same RAW bytes give the same product bytes ...
     again = str(tmp_path / "again.fil")
     reduce_scan_mesh_to_files(paths, out_paths=[again], **kw)
@@ -239,11 +251,12 @@ def test_a_second_pass_allocates_no_staging_slab(tmp_path, monkeypatch):
             paths, out_paths=[str(tmp_path / f"{tag}.fil")], nfft=NFFT,
             nint=51, window_frames=2, timeline=tl)
         tables.append(tl.report())
-    assert tables[0]["staging.alloc"]["calls"] == 2 * NBANK  # two in flight
+    # Two windows in flight, and the stream's head beside the first.
+    assert tables[0]["staging.alloc"]["calls"] == 3 * NBANK
     for st in tables[1:]:
         assert st["staging.alloc"]["calls"] == 0
         assert st["staging.drop"]["calls"] == 0
-        assert st["staging.reuse"]["calls"] == 26 * NBANK
+        assert st["staging.reuse"]["calls"] == (26 + 1) * NBANK
     assert hostmem.slab_pool().stats()["lent_bytes"] == 0
     assert payload(str(tmp_path / "a.fil")) == payload(str(tmp_path / "c.fil"))
 
@@ -251,9 +264,12 @@ def test_a_second_pass_allocates_no_staging_slab(tmp_path, monkeypatch):
 def test_the_cells_own_grid():
     # band4.hires51: 51 frames in 2-frame windows are 25 windows that
     # leave the integration open and a one-frame window that closes the
-    # row; per GB of RAW the link carries (25 * 5 + 4) / 54 of it.
+    # row; the link carries the 3-frame head and every window's new
+    # frames, 54 of the recording's 54 (until ISSUE 31 each window re-sent
+    # its 3-frame prologue: (25 * 5 + 4) / 54 = 2.389 of it).
     assert windows_of(51, 2, 51) == (26, 25)
     assert scan_window_frames(1 << 20, 51, 2) == 2
+    assert (NTAP - 1) + 25 * 2 + 1 == 54
     assert round(1000 * (25 * 5 + 4) / 54) == 2389
 
 
@@ -284,7 +300,9 @@ class TestResumeInsideAnIntegration:
         real, windows = S._feed_window, []
 
         def dying(raws, local, mesh, nchan, npol, start, ntime, *a, **k):
-            windows.append(start // NFFT)
+            # A window is fed its NEW samples: those of its first frame
+            # start NTAP - 1 frames into that frame's filter window.
+            windows.append(start // NFFT - (NTAP - 1))
             # Window 5 starts at frame 15 (row 2 closed at 14: killed
             # between two rows, its flush still pending); window 6 at
             # frame 18, inside row 2..3's integration.
@@ -302,8 +320,19 @@ class TestResumeInsideAnIntegration:
         assert cur is not None and cur.frames_done % self.NINT == 0
         assert 0 < cur.frames_done < self.ROWS * self.NINT
         assert cur.frames_done % self.WF != 0
+        fed_before = len(windows) - 1
+        assert windows[:fed_before] == list(range(0, fed_before * self.WF,
+                                                  self.WF))
         written, st = self._run(paths, out, compression=comp)
         assert written[0][1]["nsamps"] == self.ROWS
+        # A resumed stream starts with a head of its own, read at the
+        # claimed row, and carries its filter state from there.
+        left = self.ROWS * self.NINT - cur.frames_done
+        assert st["state.head"]["calls"] == NBANK
+        assert st["state.carry"]["calls"] \
+            == NBANK * (-(-left // self.WF) - 1)
+        assert st["link.put"]["bytes"] == st["read"]["bytes"] \
+            == NBANK * NCHAN * (left + NTAP - 1) * NFFT * 4
         # Resumed at the claimed row, not restarted ...
         assert st["integrate.emit"]["calls"] \
             == self.ROWS - cur.frames_done // self.NINT
@@ -360,3 +389,184 @@ def test_header_of_a_carried_product(tmp_path):
     hdr, _ = read_fil_header(out)
     assert hdr["nchans"] == NBANK * NCHAN * NFFT and hdr["nifs"] == 1
     assert abs(hdr["foff"]) * hdr["nchans"] == pytest.approx(187.5)
+
+
+# -- the band's filter state stays on the chips (ISSUE 31) -------------------
+#
+# A window reads and puts its NEW frames only, as sample words; each bank's
+# filter state is the previous window's donated output
+# (``parallel/mesh.band_stream``).  The stitched windows (``nint`` divides
+# the window) and the carried ones take the one feed.
+
+STREAM_CASES = [  # (nint, window_frames, frames in the file, last window)
+    (51, 2, 54, 1),   # band4.hires51's grid: a 1-frame last window,
+    (7, 3, 30, 1),    # ... its body shorter than the 3-frame state
+    (7, 5, 30, 3),    # a ragged last window, carried
+    (1, 2, 7, 1),     # band4.hires's path: every window stitches
+    (1, 3, 8, 2),     # ... and a ragged last one
+    (4, 4, 14, 4),    # whole windows of one row each
+]
+
+
+@pytest.mark.parametrize("ext", [".fil", ".h5"])
+@pytest.mark.parametrize("case", STREAM_CASES, ids=_ids)
+def test_every_sample_goes_up_once(tmp_path, case, ext):
+    nint, wf, frames, last = case
+    paths = make_band(tmp_path, frames, seed=5)
+    rows = frames // nint
+    total = rows * nint
+    nwin = -(-total // wf)
+    assert total - (nwin - 1) * wf == last
+    kw = dict(nfft=NFFT, nint=nint, window_frames=wf)
+    tl = Timeline()
+    out = str(tmp_path / ("mesh" + ext))
+    reduce_scan_mesh_to_files(paths, out_paths=[out], timeline=tl, **kw)
+    # The pool oracle's bytes (per-bank RawReducer, host stitch) ...
+    pool = str(tmp_path / ("pool" + ext))
+    reduce_scan_pool_to_files(paths, out_paths=[pool], **kw)
+    assert payload(out) == payload(pool)
+    # ... and the whole-file reference's spectra.
+    got = np.frombuffer(payload(out)[-rows * NBANK * NCHAN * NFFT * 4:],
+                        np.float32).reshape(rows, 1, -1)
+    assert rel_err(got, reference(paths, nint, "I", rows, True)) < TOL
+    st = tl.report()
+    tail = NBANK * NCHAN * (NTAP - 1) * NFFT * 4
+    fed = NBANK * NCHAN * (total + NTAP - 1) * NFFT * 4
+    assert st["read"]["calls"] == nwin
+    assert st["link.put"]["bytes"] == st["read"]["bytes"] == fed
+    assert st["feed.read"]["bytes"] == st["feed.put"]["bytes"] == fed
+    assert st["link.put"]["calls"] == NBANK * (nwin + 1)
+    assert (st["state.head"]["calls"], st["state.head"]["bytes"]) \
+        == (NBANK, tail)
+    if nwin > 1:
+        assert (st["state.carry"]["calls"], st["state.carry"]["bytes"]) \
+            == (NBANK * (nwin - 1), tail * (nwin - 1))
+    else:
+        assert "state.carry" not in st
+
+
+@pytest.mark.parametrize("case", STREAM_CASES[:4], ids=_ids)
+def test_the_filter_state_is_held_once(tmp_path, monkeypatch, case):
+    # The tail a window hands to its program is deleted by it (donated:
+    # the next tail takes its place), and what comes back is laid out by
+    # the ``filter_state`` rule, one bank a chip.
+    nint, wf, frames, _ = case
+    paths = make_band(tmp_path, frames, seed=6)
+    real, tails = M.band_stream, []
+
+    def watched(tail, body, coeffs, **kw):
+        out, nxt = real(tail, body, coeffs, **kw)
+        mesh = kw["mesh"]
+        assert tail.is_deleted() and not body.is_deleted()
+        assert nxt.shape == (1, NBANK, NCHAN, (NTAP - 1) * NFFT)
+        assert nxt.dtype == body.dtype == np.int32  # words
+        assert nxt.sharding.is_equivalent_to(
+            M.sharding_for(mesh, "filter_state"), nxt.ndim)
+        assert {s.device for s in nxt.addressable_shards} \
+            == set(mesh.devices.flat)
+        tails.append((tail, nxt))
+        return out, nxt
+
+    monkeypatch.setattr(M, "band_stream", watched)
+    reduce_scan_mesh_to_files(paths, out_paths=[str(tmp_path / "m.fil")],
+                              nfft=NFFT, nint=nint, window_frames=wf)
+    assert len(tails) == -(-(frames // nint * nint) // wf) > 1
+    for w in range(1, len(tails)):
+        # A window's state IS the last window's second output, and is
+        # gone once its program has it.
+        assert tails[w][0] is tails[w - 1][1]
+        assert tails[w - 1][1].is_deleted()
+    assert not tails[-1][1].is_deleted()
+
+
+@pytest.mark.parametrize("stitch", [True, False], ids=["stitched", "sharded"])
+@pytest.mark.parametrize("frames", [1, 2, 3, 5])
+def test_band_stream_is_band_reduce_of_the_gross_block(frames, stitch):
+    # One program per window either way; the stream's reads the gross
+    # block as (state on the chip, new samples as words).  A body shorter
+    # than the state keeps part of the old tail.
+    import jax.numpy as jnp
+
+    from blit.ops.channelize import sample_words
+
+    mesh = M.make_mesh(1, NBANK)
+    rng = np.random.default_rng(frames)
+    ntail = (NTAP - 1) * NFFT
+    gross = rng.integers(-40, 40, (1, NBANK, NCHAN, ntail + frames * NFFT,
+                                   2, 2), np.int8)
+    h = jnp.asarray(pfb_coeffs(NTAP, NFFT))
+    kw = dict(mesh=mesh, nfft=NFFT, ntap=NTAP, nint=1, stitch=stitch,
+              despike_nfpc=NFFT)
+    want = np.asarray(M.band_reduce(M.shard_voltages(gross, mesh), h, **kw))
+    words = np.stack([sample_words(gross[0, k]) for k in range(NBANK)])[None]
+    tail = M.shard_voltages(np.ascontiguousarray(words[..., :ntail]), mesh)
+    body = M.shard_voltages(np.ascontiguousarray(words[..., ntail:]), mesh)
+    out, nxt = M.band_stream(tail, body, h, **kw)
+    assert np.asarray(out).tobytes() == want.tobytes()
+    np.testing.assert_array_equal(np.asarray(nxt), words[..., -ntail:])
+    assert tail.is_deleted()
+
+
+def test_the_budget_lets_go_of_a_head_before_it_is_donated(monkeypatch):
+    # A stream's heads are transfers of their own on the link budget, and
+    # a handle the budget holds must never be donated (its ``is_ready()``
+    # would race the deletion): by the time ``_feed_window`` hands the
+    # tail over, the heads have landed and the budget holds none of them.
+    from blit import device
+
+    mesh = M.make_mesh(1, NBANK)
+    link = device.HostLink()
+    monkeypatch.setattr(device, "_HOST_LINK", link)
+    monkeypatch.setattr(device, "host_link_bytes", lambda: 1 << 40)
+    rng = np.random.default_rng(0)
+    streams = {(0, k): rng.integers(-9, 9, (NCHAN, 5 * NFFT, 2, 2), np.int8)
+               for k in range(NBANK)}
+
+    def gapless(raw, n, skip=0, out=None):
+        return np.ascontiguousarray(raw[:, skip:skip + n])
+
+    monkeypatch.setattr(S, "_gapless", gapless)
+    tl = Timeline()
+    tail, body = S._feed_window(streams, sorted(streams), mesh, NCHAN, 2,
+                                3 * NFFT, 2 * NFFT, tl, head_ntime=3 * NFFT)
+    held = [a for handle, _ in link._puts
+            for a in jax.tree_util.tree_leaves(handle)]
+    mine = {id(s.data) for s in tail.addressable_shards}
+    assert not any(id(a) in mine for a in held)
+    assert tail.sharding.is_equivalent_to(
+        M.sharding_for(mesh, "filter_state"), tail.ndim)
+    from blit.ops.channelize import sample_words
+
+    for k in range(NBANK):
+        words = sample_words(streams[(0, k)])
+        np.testing.assert_array_equal(np.asarray(tail)[0, k],
+                                      words[:, :3 * NFFT])
+        np.testing.assert_array_equal(np.asarray(body)[0, k],
+                                      words[:, 3 * NFFT:])
+    st = tl.report()
+    assert st["feed.put"]["calls"] == st["link.put"]["calls"] == 2 * NBANK
+    assert st["link.put"]["bytes"] == st["feed.read"]["bytes"] \
+        == sum(v.nbytes for v in streams.values())
+
+
+def test_a_stitched_scan_allocates_nothing_the_second_time(tmp_path,
+                                                           monkeypatch):
+    # band4.hires's shape of scan (nint 1, 2-frame windows, a 1-frame
+    # last): head and body slabs are back in the pool when a scan ends.
+    from blit import hostmem
+
+    monkeypatch.setattr(hostmem, "_POOL", hostmem.SlabPool())
+    paths = make_band(tmp_path, 7, seed=7)
+    tables = []
+    for tag in ("a", "b"):
+        tl = Timeline()
+        reduce_scan_mesh_to_files(
+            paths, out_paths=[str(tmp_path / f"{tag}.fil")], nfft=NFFT,
+            nint=1, window_frames=2, timeline=tl)
+        tables.append(tl.report())
+    assert tables[0]["staging.alloc"]["calls"] == 3 * NBANK
+    assert tables[1]["staging.alloc"]["calls"] == 0
+    assert tables[1]["staging.drop"]["calls"] == 0
+    assert tables[1]["staging.reuse"]["calls"] == (4 + 1) * NBANK
+    assert hostmem.slab_pool().stats()["lent_bytes"] == 0
+    assert payload(str(tmp_path / "a.fil")) == payload(str(tmp_path / "b.fil"))

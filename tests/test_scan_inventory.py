@@ -238,7 +238,7 @@ class TestReduceScanMeshToFiles:
         from blit.parallel import mesh as M
 
         _, invs = tree
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -247,7 +247,7 @@ class TestReduceScanMeshToFiles:
                 raise RuntimeError("synthetic device failure")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError, match="synthetic device failure"):
             reduce_scan_mesh_to_files(
                 SESSION, SCAN, inventories=invs, out_dir=str(tmp_path),
@@ -316,7 +316,7 @@ class TestBf16StagesMeshProduct:
         from blit.parallel import mesh as M
 
         _, invs = tree
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -329,7 +329,7 @@ class TestBf16StagesMeshProduct:
                 raise RuntimeError("boom")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError):
             reduce_scan_mesh_to_files(
                 SESSION, SCAN, inventories=invs, out_dir=str(tmp_path),
@@ -338,7 +338,7 @@ class TestBf16StagesMeshProduct:
             )
         _, partial = read_fil_data(str(tmp_path / "band0.fil"), mmap=False)
         assert partial.shape[0] > 0  # the identity guard has work to undo
-        monkeypatch.setattr(M, "band_reduce", real)
+        monkeypatch.setattr(M, "band_stream", real)
         reduce_scan_mesh_to_files(
             SESSION, SCAN, inventories=invs, out_dir=str(tmp_path),
             nfft=NFFT, nint=NINT, window_frames=4, resume=True,
@@ -416,7 +416,7 @@ class TestMeshResume:
         # Crash mid-stream on the third device window.
         crash_dir = tmp_path / "crash"
         crash_dir.mkdir()
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -425,7 +425,7 @@ class TestMeshResume:
                 raise RuntimeError("synthetic crash")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError, match="synthetic crash"):
             self.run_resumable(invs, crash_dir)
         # The partial product + cursor sidecar survive the crash.
@@ -436,7 +436,7 @@ class TestMeshResume:
 
         # Resume: continues from the checkpoint, finishes, removes the
         # cursor, and the product is IDENTICAL to the uninterrupted run.
-        monkeypatch.setattr(M, "band_reduce", real)
+        monkeypatch.setattr(M, "band_stream", real)
         written = self.run_resumable(invs, crash_dir)
         assert not (crash_dir / "band0.fil.cursor").exists()
         _, data = read_fil_data(str(out))
@@ -448,7 +448,7 @@ class TestMeshResume:
         from blit.parallel import mesh as M
 
         _, invs = tree
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -457,10 +457,10 @@ class TestMeshResume:
                 raise RuntimeError("boom")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError):
             self.run_resumable(invs, tmp_path)
-        monkeypatch.setattr(M, "band_reduce", real)
+        monkeypatch.setattr(M, "band_stream", real)
         # Different fqav_by: the cursor must NOT match — the run restarts
         # cleanly instead of splicing incompatible spectra.
         written = self.run_resumable(invs, tmp_path, fqav_by=2,
@@ -491,7 +491,7 @@ class TestMeshResume:
 
         crash_dir = tmp_path / "crash"
         crash_dir.mkdir()
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -500,7 +500,7 @@ class TestMeshResume:
                 raise RuntimeError("synthetic crash")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError, match="synthetic crash"):
             self.run_resumable(invs, crash_dir, compression="bitshuffle")
         out = crash_dir / "band0.h5"
@@ -508,7 +508,7 @@ class TestMeshResume:
         partial = read_fbh5_data(str(out))
         assert 0 < partial.shape[0] < golden.shape[0]
 
-        monkeypatch.setattr(M, "band_reduce", real)
+        monkeypatch.setattr(M, "band_stream", real)
         written = self.run_resumable(invs, crash_dir,
                                      compression="bitshuffle")
         assert not (crash_dir / "band0.h5.cursor").exists()
@@ -543,7 +543,7 @@ class TestMeshResume:
         from blit.parallel import mesh as M
 
         _, invs = tree
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -552,10 +552,10 @@ class TestMeshResume:
                 raise RuntimeError("boom")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError):
             self.run_resumable(invs, tmp_path, compression="bitshuffle")
-        monkeypatch.setattr(M, "band_reduce", real)
+        monkeypatch.setattr(M, "band_stream", real)
         golden_dir = tmp_path / "golden"
         golden_dir.mkdir()
         reduce_scan_mesh_to_files(
@@ -595,7 +595,7 @@ class TestMeshResume:
         from blit.parallel import mesh as M
 
         _, invs = tree
-        real = M.band_reduce
+        real = M.band_stream
         calls = []
 
         def flaky(*a, **kw):
@@ -604,10 +604,10 @@ class TestMeshResume:
                 raise RuntimeError("boom")
             return real(*a, **kw)
 
-        monkeypatch.setattr(M, "band_reduce", flaky)
+        monkeypatch.setattr(M, "band_stream", flaky)
         with pytest.raises(RuntimeError):
             self.run_resumable(invs, tmp_path)  # despike=True default
-        monkeypatch.setattr(M, "band_reduce", real)
+        monkeypatch.setattr(M, "band_stream", real)
         self.run_resumable(invs, tmp_path, despike=False)
         _, data = read_fil_data(str(tmp_path / "band0.fil"))
         want = host_golden(invs)[: data.shape[0]]  # un-despiked golden

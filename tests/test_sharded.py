@@ -381,6 +381,20 @@ class TestShardedAccumulator:
                        jax.device_put(np.ones((8, 4), np.float32), sh))
         assert np.asarray(out).sum() == 32.0
 
+    def test_fold_aux_hands_back_the_product_and_keeps_the_state(self):
+        mesh = make_mesh(1, 8)
+        acc = M.ShardedAccumulator(mesh, "replicated")
+        with pytest.raises(RuntimeError, match="before init"):
+            acc.fold_aux(lambda v: (v, v))
+        sh = M.sharding_for(mesh, "replicated")
+        first = acc.init(jax.device_put(np.zeros((8, 4), np.float32), sh))
+        step = jax.jit(lambda a, p: ((a + p).sum(), a + p),
+                       donate_argnums=0)
+        aux = acc.fold_aux(step,
+                           jax.device_put(np.ones((8, 4), np.float32), sh))
+        assert float(aux) == 32.0 and first.is_deleted()
+        assert np.asarray(acc.value).sum() == 32.0
+
     def test_spec_drift_fails_loudly(self):
         from jax.sharding import PartitionSpec as P
 

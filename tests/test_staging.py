@@ -528,14 +528,19 @@ class TestMeshScanWindowFeed:
         paths = self.scan(tmp_path)
         spy = SpyPool()
         monkeypatch.setattr(hostmem, "_POOL", spy)
-        aliased = []
+        aliased, roles = [], []
         put = S.M.put_local_shards
 
-        def spying_put(blocks, *a, **kw):
+        def spying_put(blocks, mesh, shape, role="voltages", **kw):
             lent = list(spy._lent.values())
-            aliased.append(all(any(np.shares_memory(blk, s) for s in lent)
-                               for blk in blocks.values()))
-            return put(blocks, *a, **kw)
+            # Sample words: one int32 per dual-pol sample, a VIEW of the
+            # int8 slab the bank was read into.
+            aliased.append(all(
+                blk.dtype == np.int32
+                and any(np.shares_memory(blk, s) for s in lent)
+                for blk in blocks.values()))
+            roles.append((spy._window(kw["timeline"]), role, shape[-1]))
+            return put(blocks, mesh, shape, role, **kw)
 
         monkeypatch.setattr(S.M, "put_local_shards", spying_put)
         (tmp_path / "mesh").mkdir()
@@ -548,25 +553,36 @@ class TestMeshScanWindowFeed:
         nwin = tl.stages["read"].calls
         shapes = [sorted({s for w, s, _ in spy.takes if w == i})
                   for i in range(nwin)]
-        assert nwin >= 4 and all(len(s) == 1 for s in shapes)
-        # ONE shape class per scan: the ragged last window reads into the
-        # head of a full-window slab (a shape of its own pushed a set of
-        # full-window slabs out of the pool every pass; ISSUE 30).
-        assert shapes[0] == shapes[1] == shapes[2] == shapes[-1]
-        assert shapes[0][0][1] == (16 + 4 - 1) * NFFT
+        assert nwin >= 4
+        # A window stages its NEW samples only (ISSUE 31), and ONE shape
+        # class per scan holds them: the ragged last window reads into
+        # the head of a full-window slab (a shape of its own pushed a set
+        # of full-window slabs out of the pool every pass; ISSUE 30).
+        body = (2, 16 * NFFT, 2, 2)
+        assert shapes[1] == shapes[2] == shapes[-1] == [body]
+        # The stream's head is a read of its own into a slab of its own:
+        # the first window's, and no other's.
+        assert shapes[0] == sorted([body, (2, (4 - 1) * NFFT, 2, 2)])
+        # ... and a put of its own, under its own rule, before the first
+        # window's samples; every other put is a window's new samples.
+        assert roles[:2] == [(0, "filter_state", 3 * NFFT),
+                             (0, "voltages", 16 * NFFT)]
+        assert [(w, r) for w, r, _ in roles[2:]] \
+            == [(w, "voltages") for w in range(1, nwin)]
         # Two sets alternate: windows 0 and 1 allocate, window 2 takes
         # window 0's slabs back, already faulted.
         reused = [[r for w, _, r in spy.takes if w == i]
                   for i in range(nwin)]
-        assert reused[0] == reused[1] == [False] * self.NBANK
+        assert reused[0] == [False] * 2 * self.NBANK
+        assert reused[1] == [False] * self.NBANK
         assert reused[2] == reused[-1] == [True] * self.NBANK
         assert not spy.early, spy.early
         # device_put saw the slabs themselves, no copy of them.
-        assert aliased == [True] * nwin
+        assert aliased == [True] * (nwin + 1)
         table = tl.report()
         assert table["staging.reuse"]["calls"] >= self.NBANK
         assert table["staging.alloc"]["calls"] \
-            + table["staging.reuse"]["calls"] == nwin * self.NBANK
+            + table["staging.reuse"]["calls"] == (nwin + 1) * self.NBANK
         assert spy.stats()["lent_bytes"] == 0
         # Same bytes as the pool-path oracle: a slab handed on before its
         # window had read it (the CPU backend may alias a page-aligned

@@ -154,8 +154,9 @@ class TestCarriedMeshStepLowersForTheTpu:
     """The carried scan's per-window programs (ISSUE 30) at the cell's own
     shape — 64 ch x (2 + 3) frames x 2^20 per chip on the (1, 4) mesh —
     cross-lowered for the TPU: the channeliser at ``nint`` 1, unstitched
-    (``fused1`` + ``tail2_detect`` inside ``shard_map``), and the per-chip
-    fold.  Neither holds a collective: nothing is gathered until a row
+    (``band_stream`` since ISSUE 31: the 3 frames of filter state on the
+    chip, the 2 new ones as words; ``fused1`` + ``tail2_detect`` inside
+    ``shard_map``), and the per-chip fold.  Neither holds a collective: nothing is gathered until a row
     closes (``stitch_despike``, which does)."""
 
     NCH, WF, NBANK = 64, 2, 4
@@ -180,12 +181,13 @@ class TestCarriedMeshStepLowersForTheTpu:
         mesh = M.make_mesh(1, self.NBANK)
         band = self.NBANK * self.NCH * NFFT
         text = self._export(
-            functools.partial(M.band_reduce, mesh=mesh, nfft=NFFT, ntap=NTAP,
+            functools.partial(M.band_stream, mesh=mesh, nfft=NFFT, ntap=NTAP,
                               nint=1, stokes="I", stitch=False,
                               despike_nfpc=0),
-            self._spec(mesh, (1, self.NBANK, self.NCH,
-                              (frames + NTAP - 1) * NFFT, 2, 2), "int8",
-                       "voltages"),
+            self._spec(mesh, (1, self.NBANK, self.NCH, (NTAP - 1) * NFFT),
+                       "int32", "filter_state"),
+            self._spec(mesh, (1, self.NBANK, self.NCH, frames * NFFT),
+                       "int32", "voltages"),
             self._spec(mesh, (NTAP, NFFT), "float32", "replicated"))
         assert text.count("tpu_custom_call") == 2
         assert ch.last_kernel_plan()["pfb_kernel"] == "fused1"
@@ -210,6 +212,88 @@ class TestCarriedMeshStepLowersForTheTpu:
             self._spec(mesh, (1, 1, 1, band), "float32",
                        "filterbank_sharded"))
         assert "all_gather" in text or "all-gather" in text
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """The four chips of one v5e host, described and not attached: the
+    installed TPU compiler compiles for them without a chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+class TestMeshStreamStepCompilesForTheHost:
+    """The scan's per-window program since ISSUE 31 (``band_stream``: the
+    filter state stays on the chips, a window is its new samples as
+    words) COMPILED for ``v5e:2x2`` at ``band4.hires51``'s own shape —
+    per chip 64 ch x (3 + 2) frames x 2^20 — by the chip's own compiler:
+    what it refuses on the chip it refuses here."""
+
+    NCH, NBANK, HBM = 64, 4, 15.75 * 2 ** 30
+
+    def _compile(self, topo, monkeypatch, frames, **kw):
+        from jax.sharding import Mesh
+
+        from blit.parallel import mesh as M
+
+        # What ``auto`` resolves to on a chip; nothing runs.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        mesh = Mesh(np.asarray(topo.devices).reshape(1, self.NBANK),
+                    (M.BAND_AXIS, M.BANK_AXIS))
+
+        def spec(shape, dtype, rule):
+            return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                        sharding=M.sharding_for(mesh, rule))
+
+        return M.band_stream.lower(
+            spec((1, self.NBANK, self.NCH, (NTAP - 1) * NFFT), "int32",
+                 "filter_state"),
+            spec((1, self.NBANK, self.NCH, frames * NFFT), "int32",
+                 "voltages"),
+            spec((NTAP, NFFT), "float32", "replicated"),
+            mesh=mesh, nfft=NFFT, ntap=NTAP, stokes="I", **kw).compile()
+
+    def _held(self, compiled):
+        """Bytes one chip holds while the program runs (the donated tail
+        counted once)."""
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+    @pytest.mark.parametrize("frames", [2, 1], ids=["window", "last-window"])
+    def test_the_carried_window_has_no_collective_and_fits(
+            self, v5e_2x2, monkeypatch, frames):
+        compiled = self._compile(v5e_2x2, monkeypatch, frames, nint=1,
+                                 stitch=False, despike_nfpc=0)
+        text = compiled.as_text()
+        assert ch.last_kernel_plan()["pfb_kernel"] == "fused1"
+        assert ch.last_kernel_plan()["tail_kernel"] == "tail2_detect"
+        assert text.count("tpu_custom_call") >= 2
+        assert "all-gather" not in text and "all_gather" not in text
+        m = compiled.memory_analysis()
+        # The tail is donated: the next tail takes its place (0.75 GiB).
+        tail = self.NCH * (NTAP - 1) * NFFT * 4
+        assert m.alias_size_in_bytes == tail
+        # Beside it a chip keeps the next window's samples, the window
+        # before's product and its accumulator: 1.5 GiB of a 16 GB chip.
+        assert self._held(compiled) + 1.5 * 2 ** 30 < self.HBM
+
+    def test_the_stitched_window_gathers_and_fits(self, v5e_2x2,
+                                                  monkeypatch):
+        # band4.hires: nint 1 divides the window, every window stitches.
+        compiled = self._compile(v5e_2x2, monkeypatch, 2, nint=1,
+                                 stitch=True, despike_nfpc=NFFT)
+        text = compiled.as_text()
+        assert "all-gather" in text or "all_gather" in text
+        # Two windows in flight: the one before's 2 GiB gathered band,
+        # and the next one's samples.
+        assert self._held(compiled) + 2.5 * 2 ** 30 < self.HBM
 
 
 class TestKernelRequestsOffTpuAndCpu:
