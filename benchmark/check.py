@@ -9,6 +9,10 @@ timed interval.
   size and CRC against the bytes on disk;
 - every later product against the verified one: size, header bytes, whole
   file CRC, and a seeded sample of segments byte for byte.
+
+A pass may make several products (``run.py``): each function here is
+given one of them, with that product's own ``nfft``, ``nint``, rows and
+tolerance, and the harness calls it once per product.
 """
 
 from __future__ import annotations
@@ -31,7 +35,12 @@ MANIFEST_SUFFIX = ".manifest.json"
 
 
 class Incorrect(AssertionError):
-    """The product is wrong.  The run goes on to print ``correct: false``."""
+    """The product is wrong.  The run goes on to print ``correct: false``.
+    ``rel_err_by_slot``: the errors read, where the check got that far."""
+
+    def __init__(self, said: str, rel_err_by_slot=None):
+        super().__init__(said)
+        self.rel_err_by_slot = rel_err_by_slot or {}
 
 
 def read_fil_header(path: str):
@@ -124,7 +133,9 @@ def against_reference(path: str, slices, *, nslots: int, nfft: int, nint: int,
     """``slices``: one dict per checked coarse channel with ``volt`` (its
     int8 stream), ``slot`` (its index among the product's coarse channels),
     ``raw_hdr`` (its bank's RAW header), ``chan`` (its index in that bank)
-    and ``tone_fine_offset`` (or None where no tone was injected)."""
+    and ``tone_fine_offset`` (or None where no tone was injected), counted
+    in fine channels of ``tone_nfft`` (the pass's finest product: the same
+    slices check every product of a pass, each at its own ``nfft``)."""
     hdr, _, data = open_fil(path)
     geometry = {"nchans": nslots * nfft, "nifs": 1, "nbits": 32,
                 "nsamps": rows}
@@ -154,7 +165,7 @@ def against_reference(path: str, slices, *, nslots: int, nfft: int, nint: int,
             chan_bw = rh["OBSBW"] / rh["OBSNCHAN"]
             f_sky = (rh["OBSFREQ"] - rh["OBSBW"] / 2
                      + (s["chan"] + 0.5) * chan_bw
-                     + s["tone_fine_offset"] * chan_bw / nfft)
+                     + s["tone_fine_offset"] * chan_bw / s["tone_nfft"])
             predicted = int(round((f_sky - hdr["fch1"]) / hdr["foff"]))
             found = {lo + int(np.argmax(got[t])) for t in range(rows)}
             if found != {predicted}:
@@ -162,9 +173,11 @@ def against_reference(path: str, slices, *, nslots: int, nfft: int, nint: int,
                                 f"headers predict {predicted}")
             tones[s["slot"]] = predicted
         errs[s["slot"]] = rel_err(got, refs[i][:rows])
-        if errs[s["slot"]] > tolerance:
-            raise Incorrect(f"coarse slot {s['slot']}: rel err "
-                            f"{errs[s['slot']]:.3g} > {tolerance}")
+    over = {slot: e for slot, e in errs.items() if e > tolerance}
+    if over:
+        raise Incorrect("; ".join(
+            f"coarse slot {slot}: rel err {e:.3g} > {tolerance}"
+            for slot, e in over.items()), errs)
     return {"header": geometry, "tone_channel_by_slot": tones,
             "rel_err_by_slot": errs, "tolerance": tolerance}
 

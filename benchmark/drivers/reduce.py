@@ -1,10 +1,8 @@
 """Driver kind ``reduce``: one bank's recording through ``blit reduce``.
 
-A pass is the CLI's own ``main(argv)`` in this process.  The traced pass
-alone builds the reducer as ``blit.__main__._cmd_reduce`` does (same
-constructor, same ``reduce_to_file``), because ``blit reduce`` does not
-print the reducer's stage table and the table is what four per-layer
-metrics read (PERF.md lists printing it under the tracing issue).
+A pass is the CLI's own ``main(argv)`` in this process; the CLI prints
+its stage table (``stages``, since PR 24), so the traced pass is the same
+call.
 """
 
 from __future__ import annotations
@@ -30,9 +28,9 @@ def product(out: str) -> str:
     return out
 
 
-def argv(traffic: dict, inputs: dict, out: str, warm_rows=None) -> list:
+def argv(traffic: dict, inputs: dict, out: str) -> list:
     """The traffic file's argv with the recording and the product path
-    filled in.  The warm-up pass is a whole pass (``warm_rows`` unused)."""
+    filled in."""
     words = []
     for w in traffic["argv"]:
         if w == "{raws}":
@@ -42,18 +40,9 @@ def argv(traffic: dict, inputs: dict, out: str, warm_rows=None) -> list:
     return words
 
 
-def traced(traffic: dict, inputs: dict, out: str, run_cli) -> dict:
-    """One pass with the stage table in hand -> the table (``Timeline``
-    report: stage -> calls, seconds, bytes)."""
-    from blit.pipeline import RawReducer, reducer_for_product
-
-    how = traffic["reducer"]
-    kw = dict(stokes="I", fqav_by=1, dtype="float32")  # the CLI's defaults
-    if "product" in how:
-        red = reducer_for_product(how["product"], **kw)
-    else:
-        red = RawReducer(nfft=how["nfft"], nint=how["nint"], **kw)
-    raws = inputs["raws"][0]
-    red.reduce_to_file(raws[0] if len(raws) == 1 else raws, out,
-                       compression=None)
-    return red.timeline.report()
+def run_pass(traffic: dict, inputs: dict, out: str, run_cli,
+             warm_frames=None) -> dict:
+    """One pass -> the JSON the command printed (``kernel_plan``, and
+    ``stages``: the ``Timeline`` report, stage -> calls, seconds, bytes).
+    The warm-up pass is a whole pass (``warm_frames`` unused)."""
+    return run_cli(argv(traffic, inputs, out))[-1]

@@ -1,6 +1,6 @@
 """Driver kind ``scan``: one band's banks through ``blit scan`` on the
 mesh.  A pass is the CLI's own ``main(argv)`` in this process; the CLI
-prints its stage table, so the traced pass is the same call.
+prints its stage table (``stages``), so the traced pass is the same call.
 """
 
 from __future__ import annotations
@@ -30,16 +30,19 @@ def product(out: str) -> str:
     return os.path.join(out, "band0.fil")
 
 
-def argv(traffic: dict, inputs: dict, out: str, warm_rows=None) -> list:
-    """The warm-up pass is cut to ``warm_rows`` frames (one window): the
+def argv(traffic: dict, inputs: dict, out: str, warm_frames=None) -> list:
+    """The warm-up pass is cut to ``warm_frames`` frames (one window): the
     same program, a quarter of the set-up."""
     words = [w.format(out=out, root=inputs["rawdir"],
                       session=traffic["session"], scan=traffic["scan"])
              for w in traffic["argv"]]
-    if warm_rows is not None:
-        words += ["--max-frames", str(warm_rows)]
+    if warm_frames is not None:
+        words += ["--max-frames", str(warm_frames)]
     return words
 
 
-def traced(traffic: dict, inputs: dict, out: str, run_cli) -> dict:
-    return run_cli(argv(traffic, inputs, out))[-1]["stages"]
+def run_pass(traffic: dict, inputs: dict, out: str, run_cli,
+             warm_frames=None) -> dict:
+    """One pass -> the JSON the command printed (``kernel_plan``, and
+    ``stages``: the ``Timeline`` report, stage -> calls, seconds, bytes)."""
+    return run_cli(argv(traffic, inputs, out, warm_frames))[-1]

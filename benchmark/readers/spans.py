@@ -63,7 +63,7 @@ WRAPPERS = ("stream",)  # stages that wrap the pump, not a stage of it
 ROOTS = ("reduce.to_file", "scan.reduce")  # a pass; attr `out`: its product
 # wait -> the stages of the thread it waits on (how that thread is found)
 WAITS_ON = {
-    "wait.chunk": ("ingest", "state", "wait.ingest_slot"),
+    "wait.chunk": ("ingest", "wait.ingest_slot"),
     "wait.ingest_slot": ("device", "readback", "wait.slab"),
     "wait.out_slot": ("device", "readback", "wait.slab"),
     "wait.out_drain": ("device", "readback", "wait.slab"),

@@ -4,9 +4,11 @@
 
 One process (a chip belongs to one process at a time).  A *pass* is one
 whole user command, the CLI's own ``blit.__main__.main(argv)`` called
-here: seeded GUPPI RAW on RAM-backed scratch -> finished product at its
-final path, manifest published.  The pass's clock stops when this file's
-own ``os.fsync`` of the product has returned.
+here: seeded GUPPI RAW on RAM-backed scratch -> every finished product at
+its final path, manifest published.  A pass makes a LIST of products (the
+traffic file's ``products``; a file without the key is a list of one) and
+everything below is done to each.  The pass's clock stops when this file's
+own ``os.fsync`` of the last of them has returned.
 
 set-up   refuse without a TPU holding the cell's chips; fixed compile
          cache; empty tuning directory; ``make -B`` of blit/native; ask
@@ -15,7 +17,8 @@ set-up   refuse without a TPU holding the cell's chips; fixed compile
          against the plain reference.  All of it is ``setup_s``.
 window   passes back to back; a pass starts only while the summed time of
          the passes so far is under ``--seconds``, and every started pass
-         completes and counts.  ``reduce_rate`` is the median pass's.
+         completes and counts.  ``reduce_rate`` is the median pass's (in
+         a cell whose entry does not list it, the per-layer ``pass_rate``).
          Checks run between passes, outside every timed interval.  A
          compile inside a pass makes the run incorrect.
 traced   with ``--trace 1``, one more pass under ``jax.profiler``; the
@@ -120,59 +123,104 @@ def load_cell(workload: str, rehearse: bool) -> dict:
     if rehearse:  # toy sizes, named in the same files
         config["geometry"].update(config.get("rehearse", {}))
         traffic.update(traffic.get("rehearse", {}))
+    # What a pass makes, in the order it is reported.  A traffic file
+    # without the key makes one product: its top-level setting, at the
+    # driver's own path.  Nothing below asks which it was.
+    if "products" not in traffic:
+        traffic["products"] = [{k: traffic[k]
+                                for k in ("nfft", "nint", "tolerance")}
+                               | {"name": "product"}]
 
     def applies(metric):
         return workload in metric.get("workloads", [workload])
 
+    # A per-layer entry that lists no cell holds wherever the end-to-end
+    # metric it should move is reported (a cell whose rate is too unsteady
+    # to carry a bound reports it per layer, and the entries that move the
+    # rate are not this cell's).
+    e2e = [m for m in bench["end_to_end"] if applies(m)]
+    moved = {m["name"] for m in e2e}
     return {
         "name": workload, "chips": cell["chips"], "config": config,
         "traffic": traffic,
         "driver": importlib.import_module("drivers." + traffic["driver"]),
-        "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
-        "per_layer": [m for m in bench["per_layer"] if applies(m)],
+        "end_to_end": e2e,
+        "per_layer": [m for m in bench["per_layer"]
+                      if applies(m) and m["moves"] in moved],
     }
 
 
 def plan_pass(cell: dict, out_cap: int) -> dict:
     """How long a pass is on this machine.  A cap on the size of one file
     caps the rows one product holds: that cuts duration (blocks), never
-    width, down to whole ``align_rows`` (a chunk, a window) where it can."""
+    width.  One read feeds every product, so all are cut to the same
+    blocks: by the product with the most bytes, which is the one the
+    traffic file's ``align_rows`` (a chunk, a window) counts rows of, down
+    to whole ``align_rows`` where it can."""
     g, t = cell["config"]["geometry"], cell["traffic"]
-    nfft, nint, ntap = t["nfft"], t["nint"], t["ntap"]
+    ntap = t["ntap"]
     nslots = cell["config"]["banks"] * g["obsnchan"]
-    row_bytes = nslots * nfft * 4
-
-    def rows_of(blocks):
-        return ((blocks * g["block_samples"]) // nfft - (ntap - 1)) // nint
 
     from scratch import FIL_HEADER_ROOM
 
-    want_rows = rows_of(t["blocks"])
-    if want_rows < 1:
-        raise Refused(f"{t['blocks']} blocks of {g['block_samples']} samples "
-                      f"hold no product row at nfft {nfft}, nint {nint}")
-    rows = min(want_rows, (out_cap - FIL_HEADER_ROOM) // row_bytes)
-    if rows < 1:
-        raise Refused(
-            f"one product row of {cell['name']} is {row_bytes} B and the "
-            f"largest file this machine allows is {out_cap} B: this cell "
-            "cannot run here")
-    blocks = t["blocks"]
-    if rows < want_rows:
+    def rows_in(samples, p):
+        return (samples // p["nfft"] - (ntap - 1)) // p["nint"]
+
+    def sized(blocks):
+        return [{"name": p["name"], "nfft": p["nfft"], "nint": p["nint"],
+                 "tolerance": p["tolerance"],
+                 "row_bytes": nslots * p["nfft"] * 4,
+                 "rows": rows_in(blocks * g["block_samples"], p)}
+                for p in t["products"]]
+
+    def hold_rows(products, blocks, why=""):
+        for p in products:
+            if p["rows"] < 1:
+                raise Refused(
+                    f"{blocks} blocks of {g['block_samples']} samples{why} "
+                    f"hold no row of product {p['name']!r} at nfft "
+                    f"{p['nfft']}, nint {p['nint']}")
+
+    def fits(products):
+        return all(p["rows"] * p["row_bytes"] <= out_cap - FIL_HEADER_ROOM
+                   for p in products)
+
+    blocks, products = t["blocks"], sized(t["blocks"])
+    hold_rows(products, blocks)
+    for p in products:
+        if p["row_bytes"] > out_cap - FIL_HEADER_ROOM:
+            raise Refused(
+                f"one row of {cell['name']}'s product {p['name']!r} is "
+                f"{p['row_bytes']} B and the largest file this machine "
+                f"allows is {out_cap} B: this cell cannot run here")
+    rows_wanted = [p["rows"] for p in products]
+    big = max(products, key=lambda p: p["rows"] * p["row_bytes"])
+    if not fits(products):
+        rows = (out_cap - FIL_HEADER_ROOM) // big["row_bytes"]
         if rows >= t["align_rows"]:
             rows -= rows % t["align_rows"]
-        blocks = math.ceil((rows * nint + ntap - 1) * nfft
+        blocks = math.ceil((rows * big["nint"] + ntap - 1) * big["nfft"]
                            / g["block_samples"])
-        while rows_of(blocks) > rows:
+        while rows_in(blocks * g["block_samples"], big) > rows \
+                or not fits(sized(blocks)):
             blocks -= 1
-        rows = rows_of(blocks)
+        products = sized(blocks)
+        hold_rows(products, blocks, f" (all that a cap of {out_cap} B a "
+                  f"file leaves of {t['blocks']})")
+    # A cut warm-up (drivers' WARMUP_CUT) is one `align_rows` of that
+    # product; of the others, what as many samples give.
+    warm_frames = min(rows_in(blocks * g["block_samples"], big),
+                      t["align_rows"]) * big["nint"]
+    for p, want in zip(products, rows_wanted):
+        p.update(bytes=p["rows"] * p["row_bytes"], rows_wanted=want,
+                 warm_rows=rows_in((warm_frames + ntap - 1) * big["nfft"], p))
     block_bytes = g["block_samples"] * g["obsnchan"] * g["npol"] * 2
     return {
-        "blocks": blocks, "rows": rows, "row_bytes": row_bytes,
-        "nslots": nslots, "warm_rows": min(rows, t["align_rows"]),
+        "blocks": blocks, "blocks_wanted": t["blocks"], "nslots": nslots,
         "raw_bytes": cell["config"]["banks"] * blocks * block_bytes,
-        "product_bytes": rows * row_bytes,
-        "blocks_wanted": t["blocks"], "rows_wanted": want_rows,
+        "products": products, "sized_by": big["name"],
+        "warm_frames": warm_frames,
+        "product_bytes": sum(p["bytes"] for p in products),
     }
 
 
@@ -183,6 +231,8 @@ def write_inputs(cell: dict, plan: dict, rawdir: str, raw_cap: int,
 
     cfg, t, g = cell["config"], cell["traffic"], cell["config"]["geometry"]
     banks = cfg["banks"]
+    # A tone's `fine_offset` counts channels of the finest product.
+    tone_nfft = max(p["nfft"] for p in t["products"])
     workers = max(1, min(t["pool_blocks"], 8,
                          ((os.cpu_count() or 2) - 1) // banks))
 
@@ -194,12 +244,12 @@ def write_inputs(cell: dict, plan: dict, rawdir: str, raw_cap: int,
         keep = sorted({tone["chan"], *tone.get("also", [])})
         paths, kept = recording.write_recording(
             cell["driver"].stem(rawdir, k, t), g, hdr, plan["blocks"],
-            raw_cap, seed=[seed, k], nfft=t["nfft"],
+            raw_cap, seed=[seed, k], nfft=tone_nfft,
             tone_chan=tone["chan"],
             tone_fine_offset=tone["fine_offset"],
             pool_blocks=t["pool_blocks"], keep_chans=keep, workers=workers)
         slices = [{"volt": v, "chan": c, "slot": k * g["obsnchan"] + c,
-                   "raw_hdr": hdr,
+                   "raw_hdr": hdr, "tone_nfft": tone_nfft,
                    "tone_fine_offset": tone["fine_offset"]
                    if c == tone["chan"] else None}
                   for c, v in kept.items()]
@@ -212,6 +262,15 @@ def write_inputs(cell: dict, plan: dict, rawdir: str, raw_cap: int,
 
 
 # -- one pass ------------------------------------------------------------------
+
+def product_paths(cell: dict, out: str) -> list:
+    """Where a pass told to write ``out`` lands its products, in the traffic
+    file's order: each entry's ``path`` pattern over ``{out}``, else the
+    driver's ``product(out)``."""
+    return [p["path"].format(out=out) if "path" in p
+            else cell["driver"].product(out)
+            for p in cell["traffic"]["products"]]
+
 
 def run_cli(argv) -> list:
     """The CLI's own ``main()`` in this process; echoes what it printed and
@@ -266,47 +325,54 @@ def cpu_seconds() -> float:
 
 
 def timed_pass(cell: dict, inputs: dict, outdir: str, tag: str, *,
-               warm_rows=None, traced: bool = False) -> dict:
-    """One pass: command entry -> this file's fsync of the finished
-    product.  A watcher thread (20 ms poll, from chip_smoke.py) notes when
-    the first product rows are in the product file or its ``.partial``."""
+               warm_frames=None) -> dict:
+    """One pass: command entry -> this file's fsync of the last finished
+    product.  A watcher thread (20 ms poll, from chip_smoke.py) notes, for
+    every product, when its first rows are in the file or its
+    ``.partial``; the pass's ``first_product_s`` is the earliest, what a
+    user tailing the directory sees."""
     drv, t = cell["driver"], cell["traffic"]
     out = drv.new_out(outdir, tag)
-    product = drv.product(out)
+    products = product_paths(cell, out)
     first, done = {}, threading.Event()
 
     def watch(t0):
-        while not done.wait(WATCH_POLL_S):
-            for p in (product + ".partial", product):
-                try:
-                    if os.path.getsize(p) > FIRST_PRODUCT_BYTES:
-                        first["s"] = time.perf_counter() - t0
-                        return
-                except OSError:
-                    pass
+        while len(first) < len(products) and not done.wait(WATCH_POLL_S):
+            for p in products:
+                if p in first:
+                    continue
+                for path in (p + ".partial", p):
+                    try:
+                        if os.path.getsize(path) > FIRST_PRODUCT_BYTES:
+                            first[p] = time.perf_counter() - t0
+                            break
+                    except OSError:
+                        pass
 
-    res = {"tag": tag, "out": out, "product": product}
+    res = {"tag": tag, "out": out, "products": products}
     cpu0 = cpu_seconds()
     t0 = time.perf_counter()
     watcher = threading.Thread(target=watch, args=(t0,), daemon=True)
     watcher.start()
     try:
         with compile_account() as res["compiles"]:
-            if traced:
-                res["stages"] = drv.traced(t, inputs, out, run_cli)
-            else:
-                res["cli"] = run_cli(drv.argv(t, inputs, out, warm_rows))
-            fd = os.open(product, os.O_RDONLY)
-            try:
-                os.fsync(fd)
-            finally:
-                os.close(fd)
+            res["cli"] = drv.run_pass(t, inputs, out, run_cli, warm_frames)
+            res["cli_s"] = time.perf_counter() - t0
+            for p in products:
+                fd = os.open(p, os.O_RDONLY)
+                try:
+                    os.fsync(fd)
+                finally:
+                    os.close(fd)
             res["wall_s"] = time.perf_counter() - t0
     finally:
         done.set()
         watcher.join()
     res["cpu_s"] = cpu_seconds() - cpu0
-    res["first_product_s"] = first.get("s", res["wall_s"])
+    res["first_by_product"] = {
+        spec["name"]: first.get(p, res["wall_s"])
+        for spec, p in zip(t["products"], products)}
+    res["first_product_s"] = min(res["first_by_product"].values())
     return res
 
 
@@ -325,7 +391,7 @@ def profiled_pass(cell: dict, inputs: dict, outdir: str):
     opts.advanced_configuration = {"tpu_trace_mode": "TRACE_ONLY_XLA"}
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        tp = timed_pass(cell, inputs, outdir, "traced", traced=True)
+        tp = timed_pass(cell, inputs, outdir, "traced")
     finally:
         jax.profiler.stop_trace()
     return tp, sorted(glob.glob(os.path.join(
@@ -333,24 +399,28 @@ def profiled_pass(cell: dict, inputs: dict, outdir: str):
 
 
 def discard(res: dict) -> None:
-    """Remove a pass's product (and sidecar) once it has been checked."""
+    """Remove a pass's products (and their sidecars) once checked."""
     if os.path.isdir(res["out"]):
         shutil.rmtree(res["out"], ignore_errors=True)
-    else:
-        for p in (res["product"], res["product"] + ".manifest.json"):
+    for p in res["products"]:
+        for suffix in ("", ".manifest.json", ".partial"):
             with contextlib.suppress(FileNotFoundError):
-                os.remove(p)
+                os.remove(p + suffix)
 
 
 # -- the run -------------------------------------------------------------------
 
 def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
-                device: dict, breakdown=None) -> str:
-    """The contract's last line: these keys and no others."""
+                device: dict, breakdown=None, *, compared: dict) -> str:
+    """The contract's last line: its five keys, ``breakdown`` on a traced
+    run, and last of all ``compared``, which the contract asks for under a
+    key of its own: each number that decided ``correct`` beside its limit,
+    ``{name: [number, limit]}``."""
     doc = {"correct": bool(correct), "attempted": attempted, "failed": failed,
            "metrics": metrics, "device": device}
     if breakdown is not None:
         doc["breakdown"] = breakdown
+    doc["compared"] = compared
     return json.dumps(doc)
 
 
@@ -358,12 +428,18 @@ def layer_metrics(cell: dict, evidence: dict) -> dict:
     """Each per-layer metric of the cell through its own file
     (``layer_metrics/<name>.json`` names the reader and its arguments).  A
     reader that finds nothing to read returns nothing and the metric is
-    left out."""
+    left out.  A file with ``same_as`` reads what the file it names reads
+    (its reader, its arguments): the same quantity in the cells where it
+    moves another end-to-end metric, under a name of its own."""
+    def spec_of(name):
+        with open(os.path.join(HERE, "layer_metrics", name + ".json")) as f:
+            return json.load(f)
+
     out = {}
     for m in cell["per_layer"]:
-        with open(os.path.join(HERE, "layer_metrics",
-                               m["name"] + ".json")) as f:
-            spec = json.load(f)
+        spec = spec_of(m["name"])
+        if "same_as" in spec:
+            spec = spec_of(spec["same_as"])
         reader = importlib.import_module("readers." + spec["reader"])
         value = reader.read(spec.get("args", {}), evidence)
         if value is not None:
@@ -446,10 +522,12 @@ def run(args) -> int:
         raw_roots = ["/dev/shm", tempfile.gettempdir()]
         out_roots = [tempfile.gettempdir(), "/dev/shm"]
         say("host", **scratch.host_facts(sorted(set(raw_roots + out_roots))))
-        unbounded = plan_pass(cell, 1 << 62)
-        want_file = unbounded["product_bytes"] + scratch.FIL_HEADER_ROOM
+        unbounded = plan_pass(cell, 1 << 62)["products"]
+        want_file = max(p["bytes"] for p in unbounded) \
+            + scratch.FIL_HEADER_ROOM
         outdir, out_cap = scratch.scratch_dir(
-            out_roots, 2 * want_file + (1 << 30), want_file)
+            out_roots, 2 * sum(p["bytes"] + scratch.FIL_HEADER_ROOM
+                               for p in unbounded) + (1 << 30), want_file)
         made.append(outdir)
         plan = plan_pass(cell, out_cap)
         raw_file = plan["raw_bytes"] // cfg["banks"] \
@@ -462,11 +540,14 @@ def run(args) -> int:
             geometry=cfg["geometry"], banks=cfg["banks"])
         say("reduced", config=cfg["reduced"],
             blocks=[plan["blocks_wanted"], plan["blocks"]],
-            rows=[plan["rows_wanted"], plan["rows"]],
+            rows={p["name"]: [p["rows_wanted"], p["rows"]]
+                  for p in plan["products"]},
+            bytes={p["name"]: p["bytes"] for p in plan["products"]},
             why="as the traffic file asks" if plan["blocks"]
             == plan["blocks_wanted"] else
-            f"the largest file this machine allows is {out_cap} B; the "
-            f"product would be {want_file} B.  Duration is cut, width never")
+            f"the largest file this machine allows is {out_cap} B; product "
+            f"{plan['sized_by']!r} would be {want_file} B.  Duration is "
+            "cut, width never")
 
         t0 = time.perf_counter()
         inputs = write_inputs(cell, plan, rawdir, raw_cap, args.seed)
@@ -475,56 +556,71 @@ def run(args) -> int:
                             for ps in inputs["raws"]],
             seconds=parts["synth_s"], pool_blocks=t["pool_blocks"])
 
-        ref_kw = dict(nslots=plan["nslots"], nfft=t["nfft"], nint=t["nint"],
-                      ntap=t["ntap"], despike=t["despike"],
-                      tolerance=t["tolerance"])
         problems = []   # what made the run incorrect
-        bad = set()     # the passes whose product was wrong
+        bad = set()     # the passes with a product that was wrong
+        worst = {}      # product -> its largest error against the reference
 
-        small = plan["product_bytes"] <= SMALL_PRODUCT_BYTES
+        def note(product, errs):
+            if errs:
+                worst[product] = max([worst.get(product, 0.0),
+                                      *errs.values()])
 
         def verify(res, rows, *, read_all, against_reference=False,
                    golden=None):
-            """Guarantees, then the plain reference and/or the verified
-            product.  Outside every timed interval."""
-            try:
-                res["facts"] = check.guarantees(res["product"], rows,
-                                                read_all or small)
-                if against_reference:
-                    say("check.reference", pass_=res["tag"],
-                        **check.against_reference(
-                            res["product"], inputs["slices"], rows=rows,
-                            **ref_kw))
-                if golden is not None:
-                    check.same_product(res["product"], res["facts"], golden,
-                                       args.seed)
-                return True
-            except check.Incorrect as e:
-                problems.append(f"{res['tag']}: {e}")
-                bad.add(res["tag"])
-                say("INCORRECT", pass_=res["tag"], problem=str(e))
-                return False
+            """Every product of the pass: guarantees, then the plain
+            reference and/or the verified product (``golden``, one entry
+            a product).  ``rows`` names the plan's count to hold them to.
+            Outside every timed interval."""
+            res["facts"] = []
+            for i, (path, p) in enumerate(zip(res["products"],
+                                              plan["products"])):
+                try:
+                    facts = check.guarantees(
+                        path, p[rows],
+                        read_all or p["bytes"] <= SMALL_PRODUCT_BYTES)
+                    res["facts"].append(facts)
+                    if against_reference:
+                        said = check.against_reference(
+                            path, inputs["slices"], rows=p[rows],
+                            nslots=plan["nslots"], nfft=p["nfft"],
+                            nint=p["nint"], ntap=t["ntap"],
+                            despike=t["despike"], tolerance=p["tolerance"])
+                        say("check.reference", pass_=res["tag"],
+                            product=p["name"], **said)
+                        note(p["name"], said["rel_err_by_slot"])
+                    if golden is not None:
+                        check.same_product(path, facts, golden[i], args.seed)
+                except check.Incorrect as e:
+                    note(p["name"], e.rel_err_by_slot)
+                    problems.append(f"{res['tag']}, product {p['name']}: {e}")
+                    bad.add(res["tag"])
+                    say("INCORRECT", pass_=res["tag"], product=p["name"],
+                        problem=str(e))
+            return res["tag"] not in bad
 
         def keep_as_golden(res):
-            """The verified product's facts and seeded byte sample stay;
-            the product itself goes (memory is what a run is short of)."""
-            g = {**res["facts"], "sample": check.sample(
-                res["product"], res["facts"]["bytes"], args.seed)}
+            """The verified products' facts and seeded byte samples stay;
+            the products themselves go (memory is what a run is short
+            of)."""
+            g = [{**facts, "sample": check.sample(path, facts["bytes"],
+                                                  args.seed)}
+                 for path, facts in zip(res["products"], res["facts"])]
             discard(res)
             return g
 
         # Warm-up: compiles or loads this cell's own programs, faults the
         # staging pool in.
         t0 = time.perf_counter()
-        whole_warmup = not (drv.WARMUP_CUT
-                            and plan["warm_rows"] < plan["rows"])
+        whole_warmup = not (drv.WARMUP_CUT and any(
+            p["warm_rows"] < p["rows"] for p in plan["products"]))
         warm = timed_pass(cell, inputs, outdir, "warmup",
-                          warm_rows=None if whole_warmup
-                          else plan["warm_rows"])
+                          warm_frames=None if whole_warmup
+                          else plan["warm_frames"])
         parts["warmup_pass_s"] = time.perf_counter() - t0
-        plan_got = (warm["cli"][-1].get("kernel_plan") or {})
+        plan_got = (warm["cli"].get("kernel_plan") or {})
         say("warmup", wall_s=warm["wall_s"],
-            first_product_s=warm["first_product_s"], **warm["compiles"],
+            first_product_s=warm["first_product_s"],
+            first_by_product=warm["first_by_product"], **warm["compiles"],
             kernel_plan=plan_got, expected_plan=t.get("expect_plan"),
             plan_as_expected=None if rehearse or "expect_plan" not in t
             else all(plan_got.get(k) == v
@@ -533,25 +629,27 @@ def run(args) -> int:
         t0 = time.perf_counter()
         from blit.integrity import verify_product
 
-        # blit's own whole-file verification of the warm-up product (size
+        # blit's own whole-file verification of the warm-up products (size
         # and CRC against the manifest): the one full read of set-up.
-        _, said = verify_product(warm["product"])
-        if said:
-            problems.append(f"warmup: blit's own verify_product: {said}")
+        for path, p in zip(warm["products"], plan["products"]):
+            _, said = verify_product(path)
+            if said:
+                problems.append(f"warmup, product {p['name']}: blit's own "
+                                f"verify_product: {said}")
         golden = None
         if whole_warmup:
-            if verify(warm, plan["rows"], read_all=False,
-                      against_reference=True):
+            if verify(warm, "rows", read_all=False, against_reference=True):
                 golden = keep_as_golden(warm)
         else:
-            verify(warm, plan["warm_rows"], read_all=False)
+            verify(warm, "warm_rows", read_all=False)
         discard(warm)
         parts["warmup_check_s"] = time.perf_counter() - t0
         try:
             from blit.pipeline import RawReducer
 
-            tuning = RawReducer(nfft=t["nfft"], nint=t["nint"]
-                                ).tuning_provenance()
+            tuning = {p["name"]: RawReducer(
+                nfft=p["nfft"], nint=p["nint"]).tuning_provenance()
+                for p in plan["products"]}
         except Exception as e:  # noqa: BLE001 — a label for the log only
             tuning = f"{type(e).__name__}: {e}"
         say("tuning", BLIT_TUNE_DIR=os.environ["BLIT_TUNE_DIR"],
@@ -569,20 +667,21 @@ def run(args) -> int:
             p = last = timed_pass(cell, inputs, outdir, f"pass{len(passes)}")
             passes.append(p)
             say("pass", n=len(passes) - 1, wall_s=p["wall_s"],
-                first_product_s=p["first_product_s"], cpu_s=p["cpu_s"],
+                cli_s=p["cli_s"], fsync_s=p["wall_s"] - p["cli_s"],
+                first_product_s=p["first_product_s"],
+                first_by_product=p["first_by_product"], cpu_s=p["cpu_s"],
                 **p["compiles"], **memory_facts())
             if p["compiles"]["backend_compiles"]:
                 problems.append(f"{p['tag']}: {p['compiles']} — a compile "
                                 "inside the measured window")
             if golden is not None:
-                verify(p, plan["rows"], read_all=False, golden=golden)
-            elif verify(p, plan["rows"], read_all=False,
-                        against_reference=True):
-                # The first whole product (the warm-up was cut): the plain
-                # reference has checked it here, between passes.
+                verify(p, "rows", read_all=False, golden=golden)
+            elif verify(p, "rows", read_all=False, against_reference=True):
+                # The first whole products (the warm-up was cut): the plain
+                # reference has checked them here, between passes.
                 golden, last = keep_as_golden(p), None
         if last is not None:
-            verify(last, plan["rows"], read_all=True, golden=golden)
+            verify(last, "rows", read_all=True, golden=golden)
             discard(last)
         measured_s = sum(p["wall_s"] for p in passes)
         window_raw = plan["raw_bytes"] * len(passes)
@@ -591,14 +690,16 @@ def run(args) -> int:
         breakdown = None
         if args.trace:
             tp, found = profiled_pass(cell, inputs, outdir)
-            verify(tp, plan["rows"], read_all=False, golden=golden)
+            verify(tp, "rows", read_all=False, golden=golden)
             discard(tp)
             from readers import xplane
 
             trace = xplane.reduce_trace(found[-1], tp["wall_s"]) if found \
                 else None
+            stages = tp["cli"]["stages"]
             say("traced", **memory_facts(), wall_s=tp["wall_s"],
-                first_product_s=tp["first_product_s"], stages=tp["stages"],
+                first_product_s=tp["first_product_s"],
+                first_by_product=tp["first_by_product"], stages=stages,
                 trace_file_bytes=os.path.getsize(found[-1]) if found else 0,
                 chips=trace and trace["chips"],
                 busy_s_by_chip=trace and trace["busy_s_by_chip"],
@@ -620,7 +721,7 @@ def run(args) -> int:
 
                 breakdown = {"device_ops": top(trace["per_op_s"]),
                              "idle_gaps": top(trace["idle_gaps_s"])}
-                stage_s = {k: v["seconds"] for k, v in tp["stages"].items()
+                stage_s = {k: v["seconds"] for k, v in stages.items()
                            if isinstance(v, dict) and "seconds" in v
                            and k not in drv.WRAPPER_STAGES}
                 say("overlap", pass_wall_s=tp["wall_s"], stage_s=stage_s,
@@ -635,7 +736,7 @@ def run(args) -> int:
         device["memory_peak_bytes"] = peak
         if args.trace:
             metrics = layer_metrics(cell, {
-                "stages": tp["stages"], "trace": trace,
+                "stages": stages, "trace": trace,
                 "traced_raw_bytes": plan["raw_bytes"],
                 "traced_least_bytes": reference.least_bytes(
                     plan["raw_bytes"], plan["product_bytes"]),
@@ -643,6 +744,7 @@ def run(args) -> int:
                 "window_cpu_s": sum(p["cpu_s"] for p in passes),
                 "window_first_product_s": [p["first_product_s"]
                                            for p in passes],
+                "window_wall_s": [p["wall_s"] for p in passes],
                 "memory_peak_bytes": peak, "device_kind": device["kind"],
                 "peaks": peaks})
         else:
@@ -657,24 +759,38 @@ def run(args) -> int:
                     p["first_product_s"] for p in passes),
                 "setup_s": setup_s,
             }
-            metrics = {m["name"]: {"value": own[m["name"]], "unit": m["unit"]}
+            # `reduce_rate.<cells>`: an entry a later PR adds for its own
+            # cells (it may append to no accepted list) takes the statistic
+            # its name starts with.
+            metrics = {m["name"]: {"value": own[m["name"].split(".")[0]],
+                                   "unit": m["unit"]}
                        for m in cell["end_to_end"]}
         say("window", passes=len(passes), measured_s=measured_s,
             raw_bytes=window_raw, mean_rate_GBps=window_raw / measured_s / 1e9,
             problems=problems,
             wall_s_by_pass=[p["wall_s"] for p in passes])
+        # Every number that decided `correct`, beside its limit: the last
+        # lines of stderr, and the last key of the result line.
+        compiled = [p["compiles"]["backend_compiles"] for p in passes]
+        compared = {
+            **{f"rel_err.{p['name']}": [worst.get(p["name"]), p["tolerance"]]
+               for p in plan["products"]},
+            "wrong_products": [len(problems) - sum(map(bool, compiled)), 0],
+            "compiles_in_window": [sum(compiled), 0]}
+        for name, (got, limit) in compared.items():
+            print(f"compared {name} {got} limit {limit}", file=sys.stderr,
+                  flush=True)
+        failed = len(bad - {"warmup", "traced"})
         if rehearse:
             print(json.dumps({"rehearsal": True, "platform":
                               device["platform"], "correct": not problems,
-                              "attempted": len(passes),
-                              "failed": len(bad - {"warmup", "traced"}),
+                              "attempted": len(passes), "failed": failed,
                               "metric_names": sorted(metrics),
                               "breakdown": breakdown is not None}),
                   flush=True)
             return 0 if not problems else 1
-        print(result_line(not problems, len(passes),
-                          len(bad - {"warmup", "traced"}), metrics, device,
-                          breakdown), flush=True)
+        print(result_line(not problems, len(passes), failed, metrics, device,
+                          breakdown, compared=compared), flush=True)
         return 0
     finally:
         for d in made:

@@ -4,6 +4,7 @@ nothing of the chip.  Not part of tier-1 (``tests/``)."""
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -27,6 +28,78 @@ def run_harness(*args, root=ROOT, prelude="", timeout=300):
                        capture_output=True, text=True, timeout=timeout)
     out = [ln for ln in p.stdout.splitlines() if ln.strip()]
     return p, out
+
+
+def lines_of(out, phase):
+    """The JSON of every ``[phase] {...}`` line the harness printed."""
+    tag = f"[{phase}] "
+    return [json.loads(ln[len(tag):]) for ln in out if ln.startswith(tag)]
+
+
+# What a CPU rehearsal's traced run can report.  EVERY_PASS: the entries of
+# ``BENCHMARK.json`` with no ``workloads`` key whose reading is a row of the
+# stage table or a host clock: any ``blit reduce`` or ``blit scan`` pass has
+# them, so a cell added later reports them with no entry of its own.
+# PUMP_WAITS: the two waits only the ``blit reduce`` pump declares.
+EVERY_PASS = ["d2h_MB_per_GB", "dispatch_s_per_GB", "h2d_MB_per_GB",
+              "host_cpu_s_per_GB", "link_wait_s_per_GB", "read_rate",
+              "readback_s_per_GB", "write_s_per_GB"]
+PUMP_WAITS = ["wait_chunk_s_per_GB", "wait_out_slot_s_per_GB"]
+
+LOCAL_DRIVER = os.path.join(BENCH, "tests", "local_drivers", "reduce_each.py")
+
+
+def tree_with(tmp_path, *, traffic: dict, workloads, per_layer=(),
+              end_to_end=(), configs=(), files=None, drivers=()):
+    """A temporary checkout that holds the benchmark as committed plus what
+    a later PR would ADD, as files and entries only: traffic mixes, cells,
+    per-layer and end-to-end entries, configurations, driver files, any other file under
+    ``benchmark/`` (``files``: relative path -> text).  No file that is there is edited.
+    -> its root."""
+    b = tmp_path / "benchmark"
+    shutil.copytree(BENCH, b,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    os.symlink(os.path.join(ROOT, "blit"), tmp_path / "blit")
+    for name, t in traffic.items():
+        assert not (b / "traffic" / (name + ".json")).exists()
+        (b / "traffic" / (name + ".json")).write_text(json.dumps(t))
+    for path in drivers:
+        shutil.copy(path, b / "drivers")
+    for rel, text in (files or {}).items():
+        assert not (b / rel).exists()
+        (b / rel).write_text(text)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["workloads"] += list(workloads)
+    bench["per_layer"] += list(per_layer)
+    bench["end_to_end"] += list(end_to_end)
+    for cfg in configs:   # a copy of an accepted one under a name of its own
+        (b / "configs" / (cfg["name"] + ".json")).write_text(json.dumps(cfg))
+        entry = next(c for c in bench["configs"] if c["source"]
+                     == cfg["source"])
+        bench["configs"].append(dict(
+            entry, name=cfg["name"],
+            file=f"benchmark/configs/{cfg['name']}.json"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(tmp_path)
+
+
+def products_traffic(name: str, settings, **more) -> dict:
+    """A toy traffic mix whose pass makes one product per ``(name, nfft,
+    nint)`` of ``settings`` through the test-local ``reduce_each`` driver,
+    on the hires rehearsal's recording (38 blocks of 512 samples, 4 coarse
+    channels; the tone sits on the finest product's grid)."""
+    return {
+        "name": name, "driver": "reduce_each", "blocks": 38, "ntap": 4,
+        "despike": False, "align_rows": 8, "pool_blocks": 7,
+        "tones": [{"chan": 1, "fine_offset": 207, "also": [3]}],
+        "products": [
+            {"name": n, "nfft": nfft, "nint": nint, "tolerance": 0.012,
+             "path": "{out}." + n + ".fil",
+             "argv": ["reduce", "{raws}", "-o", "{path}", "--nfft",
+                      str(nfft), "--nint", str(nint)]}
+            for n, nfft, nint in settings],
+        **more}
 
 
 @pytest.fixture(scope="session")

@@ -3,31 +3,22 @@ Its toy run end to end — which DOES carry: an explicit 2-frame window
 below one integration is kept as given — the reference at ``nint`` 51 with
 the despike on, and ``readers/band_carry.py`` on what the builder's traced
 run on the four chips recorded (``data/band4.hires51.pr30.*``: the
-``.xplane.pb`` as written, and the run's stage table and result line)."""
+``.xplane.pb`` as written, and the run's stage table and result line, under
+the metric names of its day: ``test_layer_metrics.FOLDED`` maps them)."""
 
 import json
 import os
 
 import numpy as np
 import pytest
-from conftest import BENCH, run_harness
+from conftest import BENCH, EVERY_PASS, lines_of, run_harness
 
 import reference
 from readers import band_carry, stage_bytes, xplane
+from test_layer_metrics import FOLDED
 
 CELL = "band4.hires51"
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-NEW_METRICS = ["b51_carry_busy_s_per_GB", "b51_carry_roof_share",
-               "b51_idle_read_s_per_GB", "b51_idle_put_s_per_GB"]
-# The accepted metrics of the same layers list their cells by name, so this
-# cell reads them through files of its own over the readers that exist.
-TWINS = {"b51_collective_s_per_GB": "collective_s_per_GB",
-         "b51_launch_skew_s_per_GB": "launch_skew_s_per_GB",
-         "b51_read_rate": "read_rate",
-         "b51_idle_output_s_per_GB": "idle_output_s_per_GB",
-         "b51_idle_named_share": "idle_named_share",
-         "b51_h2d_MB_per_GB": "t51_h2d_MB_per_GB",
-         "b51_d2h_MB_per_GB": "d2h_MB_per_GB"}
 ROW = 4 * 64 * (1 << 20) * 4     # one band row: 1 GiB
 RAW = 4 * 108 * 134217728        # 58.0 GB
 
@@ -35,12 +26,6 @@ RAW = 4 * 108 * 134217728        # 58.0 GB
 def spec(name):
     with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
         return json.load(f)
-
-
-def stages_of(out):
-    """The stage table of the last ``blit scan`` the harness echoed."""
-    lines = [ln for ln in out if ln.startswith("  blit> {\"window_frames\"")]
-    return json.loads(lines[-1][len("  blit> "):])
 
 
 def test_end_to_end_run_at_toy_size_carries():
@@ -54,8 +39,9 @@ def test_end_to_end_run_at_toy_size_carries():
     # first_product_s lists its cells by name: this one is not among them
     assert doc["metric_names"] == ["reduce_rate", "setup_s"]
     assert "metrics" not in doc
-    plan = json.loads(next(ln for ln in out if ln.startswith("[plan]"))[7:])
-    assert plan["blocks"] == 108 and plan["rows"] == 1 == plan["warm_rows"]
+    (plan,) = lines_of(out, "plan")
+    (band,) = plan["products"]
+    assert plan["blocks"] == 108 and band["rows"] == 1 == band["warm_rows"]
     # the warm-up is a whole pass, checked against the reference in all
     # four banks' slots
     warm = json.loads(next(ln for ln in out
@@ -65,22 +51,10 @@ def test_end_to_end_run_at_toy_size_carries():
                           if ln.startswith("[check.reference]"))[18:])
     assert len(ref["rel_err_by_slot"]) == 4 == len(ref["tone_channel_by_slot"])
     assert ref["tolerance"] <= 1.0e-2
-    # 54 frames in 2-frame windows: 25 end with the integration open, the
-    # 26th (one frame) closes the row; one fetch and one write a pass
-    got = stages_of(out)
-    assert got["window_frames"] == 2 and got["parallel"] == "mesh"
-    st = got["stages"]
-    assert st["integrate.carry"]["calls"] == 25
-    assert st["integrate.emit"]["calls"] == 1
-    assert st["read"]["calls"] == st["device"]["calls"] == 26
-    assert st["readback"]["calls"] == st["write"]["calls"] == 1
-    assert st["readback"]["bytes"] == plan["product_bytes"] \
-        == st["integrate.emit"]["bytes"]
-    # from the second reduction of the shape on, nothing is allocated
-    assert st["staging.alloc"]["calls"] == 0
-    # (25 x 5 + 4) / 54 of the RAW goes up while windows re-send their
-    # prologue (ROADMAP A5.1)
-    assert st["link.put"]["bytes"] * 54 == plan["raw_bytes"] * 129
+    # what `blit scan` said it ran (the echo is cut at 1500 characters, so
+    # the stage table is read from the traced run's own line, below)
+    echoed = [ln for ln in out if ln.startswith("  blit> {")][-1]
+    assert '"window_frames": 2' in echoed and '"parallel": "mesh"' in echoed
 
 
 def test_traced_run_reports_only_what_a_cpu_can():
@@ -91,8 +65,28 @@ def test_traced_run_reports_only_what_a_cpu_can():
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     doc = json.loads(out[-1])
     assert doc["correct"] is True and doc["breakdown"] is False
-    assert doc["metric_names"] == ["b51_d2h_MB_per_GB", "b51_h2d_MB_per_GB",
-                                   "b51_read_rate", "host_cpu_s_per_GB"]
+    # `wait.link` is a declared wait: 0 calls on the CPU, and so 0.0 s/GB
+    assert doc["metric_names"] == EVERY_PASS
+    # The traced pass's stage table, whole, as `blit scan` printed it and
+    # `run_cli` returned it.  54 frames in 2-frame windows: 25 end with the
+    # integration open, the 26th (one frame) closes the row; one fetch and
+    # one write a pass
+    (plan,) = lines_of(out, "plan")
+    (traced,) = lines_of(out, "traced")
+    st = traced["stages"]
+    assert st["integrate.carry"]["calls"] == 25
+    assert st["integrate.emit"]["calls"] == 1
+    assert st["read"]["calls"] == st["device"]["calls"] == 26
+    assert st["readback"]["calls"] == st["write"]["calls"] == 1
+    assert st["readback"]["bytes"] == plan["product_bytes"] \
+        == st["integrate.emit"]["bytes"]
+    # from the second reduction of the shape on, nothing is allocated
+    assert st["staging.alloc"]["calls"] == 0
+    # every sample crosses the link once (PR 31): a window puts its new
+    # frames only and each bank's filter state stays on its chip
+    assert st["link.put"]["bytes"] == plan["raw_bytes"] == st["read"]["bytes"]
+    assert st["state.head"]["calls"] == 4                 # banks
+    assert st["state.carry"]["calls"] == 4 * (26 - 1)     # banks x windows-1
 
 
 def test_reference_integrates_51_spectra_and_despikes():
@@ -116,53 +110,6 @@ def test_reference_integrates_51_spectra_and_despikes():
         # channelize_np filters and sums in float32
         assert np.abs(got - ref).max() <= 1e-5 * np.abs(ref).max()
         assert (got[:, nfft // 2] == got[:, nfft // 2 - 1]).all()
-
-
-def test_new_metric_files_name_their_cell_and_a_reader():
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entries = {m["name"]: m for m in bench["per_layer"]}
-    for name in NEW_METRICS + sorted(TWINS):
-        s, e = spec(name), entries[name]
-        assert e["workloads"] == [CELL] == s["cells"]
-        assert e["moves"] == "reduce_rate"
-        for k in ("unit", "layer", "better", "source"):
-            assert s[k] == e[k], (name, k)
-        assert os.path.exists(os.path.join(BENCH, "readers",
-                                           s["reader"] + ".py"))
-    assert len(NEW_METRICS) + len(TWINS) == 11
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert cell == {"name": CELL, "config": "gbt-band4-rawspec",
-                    "traffic": "band-hires-t51", "chips": 4,
-                    "why": cell["why"]}
-    # the last entries of their lists: nothing was put in the middle
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "gbt-band4-rawspec"
-    assert [m["name"] for m in bench["per_layer"][-11:]] == [
-        "b51_collective_s_per_GB", "b51_launch_skew_s_per_GB",
-        "b51_read_rate", "b51_idle_read_s_per_GB", "b51_idle_put_s_per_GB",
-        "b51_idle_output_s_per_GB", "b51_idle_named_share",
-        "b51_h2d_MB_per_GB", "b51_d2h_MB_per_GB", "b51_carry_busy_s_per_GB",
-        "b51_carry_roof_share"]
-
-
-def test_twins_read_what_the_accepted_metric_reads():
-    """Same reader, same arguments, same unit, layer and direction as the
-    accepted metric: only the cell differs."""
-    for name, accepted in TWINS.items():
-        s, a = spec(name), spec(accepted)
-        for k in ("reader", "args", "unit", "layer", "better", "source",
-                  "moves"):
-            assert s[k] == a[k], (name, k)
-        assert a["cells"] == "all" or CELL not in a["cells"]
-    # the two idle buckets of this cell: the accepted reader, new ends
-    for name, ends in (("b51_idle_read_s_per_GB",
-                        ["ingest", "feed.read", "read"]),
-                       ("b51_idle_put_s_per_GB",
-                        ["dispatch", "feed.put", "wait.link"])):
-        s = spec(name)
-        assert s["reader"] == "spans"
-        assert s["args"] == {"value": "idle_s_per_GB", "ends_in": ends}
 
 
 def test_the_configuration_restates_no_guarantee_weaker():
@@ -210,15 +157,18 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
         spec("b51_carry_roof_share")["args"],
         dict(ev, trace={"per_op_s": {"jit_band_carry/while": 0.1},
                         "chips": ["a"] * 4})) is None
-    assert stage_bytes.read(spec("b51_d2h_MB_per_GB")["args"], ev) is None
+    assert stage_bytes.read(spec("d2h_MB_per_GB")["args"], ev) is None
     assert stage_bytes.read(
-        spec("b51_d2h_MB_per_GB")["args"],
+        spec("d2h_MB_per_GB")["args"],
         dict(ev, stages={"readback": {"bytes": ROW}})) \
         == pytest.approx(18.5185, rel=1e-4)
     assert stage_bytes.read(
-        spec("b51_h2d_MB_per_GB")["args"],
+        spec("h2d_MB_per_GB")["args"],
         dict(ev, stages={"link.put": {"bytes": RAW * 129 // 54}})) \
-        == pytest.approx(2388.9, rel=1e-4)
+        == pytest.approx(2388.9, rel=1e-4)   # the feed PR 31 replaced
+    assert stage_bytes.read(spec("h2d_MB_per_GB")["args"],
+                            dict(ev, stages={"link.put": {"bytes": RAW}})) \
+        == pytest.approx(1000.0)
 
 
 def test_roof_share_on_made_up_numbers():
@@ -267,8 +217,8 @@ def test_readers_on_the_recorded_traced_pass(recorded):
     assert st["dispatch"]["calls"] == 26 and st["readback"]["calls"] == 1
     for name in ("b51_carry_busy_s_per_GB", "b51_carry_roof_share",
                  "b51_collective_s_per_GB", "b51_d2h_MB_per_GB",
-                 "b51_h2d_MB_per_GB"):
-        s = spec(name)
+                 "b51_h2d_MB_per_GB"):   # as the run of PR 30 named them
+        s = spec(FOLDED.get(name, name))
         reader = {"band_carry": band_carry, "xplane": xplane,
                   "stage_bytes": stage_bytes}[s["reader"]]
         got = reader.read(s["args"], ev)

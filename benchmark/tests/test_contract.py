@@ -60,13 +60,13 @@ def test_metrics_meet_the_contract(bench_json):
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert m["moves"] in e2e and set(m.get("workloads", [])) <= cells
-        # reported only where the end-to-end metric it should move is
-        assert set(m.get("workloads", cells)) \
+        # reported only where the end-to-end metric it should move is: an
+        # entry that lists no cell holds in every cell that reports it
+        assert set(m.get("workloads", [])) \
             <= set(e2e[m["moves"]].get("workloads", cells)), m["name"]
     for c in cells:   # every cell: setup_s, another end-to-end, a per-layer
         assert sum(c in m.get("workloads", [c]) for m in e2e.values()) >= 2
-        assert any(c in m.get("workloads", [c])
-                   for m in bench_json["per_layer"])
+        assert run.load_cell(c, rehearse=False)["per_layer"]
 
 
 def test_every_named_thing_is_a_file_of_its_own(bench_json):
@@ -87,21 +87,35 @@ def test_every_named_thing_is_a_file_of_its_own(bench_json):
             spec = json.load(f)
         for k in ("unit", "layer", "moves", "better", "source"):
             assert spec[k] == m[k], (m["name"], k)
+        if "same_as" in spec:   # the file it names holds the reader
+            with open(os.path.join(BENCH, "layer_metrics",
+                                   spec["same_as"] + ".json")) as f:
+                spec = json.load(f)
         assert os.path.exists(os.path.join(BENCH, "readers",
                                            spec["reader"] + ".py"))
 
 
 def test_last_line_holds_the_contract_keys_and_no_others():
+    """The keys the driver reads, ``breakdown`` where a run was traced, and
+    last of all ``compared``: each number that decided ``correct`` beside
+    its limit."""
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
               "memory_peak_bytes": 1}
     metrics = {"reduce_rate": {"value": 0.123456789, "unit": "GB/s"}}
-    doc = json.loads(run.result_line(True, 3, 0, metrics, device))
-    assert tuple(doc) == run.RESULT_KEYS
+    compared = {"rel_err.product": [0.0031, 0.012], "wrong_products": [0, 0],
+                "compiles_in_window": [0, 0]}
+    doc = json.loads(run.result_line(True, 3, 0, metrics, device,
+                                     compared=compared))
+    assert tuple(doc) == run.RESULT_KEYS + ("compared",)
     assert doc["metrics"]["reduce_rate"]["value"] == 0.123456789
+    assert doc["compared"] == compared
     doc = json.loads(run.result_line(False, 3, 1, metrics, device,
-                                     {"device_ops": [], "idle_gaps": []}))
-    assert tuple(doc) == run.RESULT_KEYS + ("breakdown",)
+                                     {"device_ops": [], "idle_gaps": []},
+                                     compared=dict(compared,
+                                                   wrong_products=[1, 0])))
+    assert tuple(doc) == run.RESULT_KEYS + ("breakdown", "compared")
     assert doc["correct"] is False and doc["failed"] == 1
+    assert doc["compared"]["wrong_products"] == [1, 0]
 
 
 @pytest.mark.parametrize("kind", ["TPU v5 lite"])

@@ -9,24 +9,16 @@ import os
 
 import numpy as np
 import pytest
-from conftest import BENCH, run_harness
+from conftest import (BENCH, EVERY_PASS, PUMP_WAITS, lines_of,
+                      run_harness)
 
 import reference
 from readers import carry, stage_bytes, timeline, xplane
 
 CELL = "rawspec.hires51"
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-NEW_METRICS = ["t51_roof_share", "link_wait_s_per_GB", "idle_link_s_per_GB",
-               "carry_busy_s_per_GB", "carry_roof_share", "d2h_MB_per_GB"]
-# The accepted metrics of the same layers list their cells by name, so this
-# cell reads them through files of its own over the readers that exist.
-TWINS = {"t51_dispatch_s_per_GB": "dispatch_s_per_GB",
-         "t51_idle_dispatch_s_per_GB": "idle_dispatch_s_per_GB",
-         "t51_idle_named_share": "idle_named_share",
-         "t51_read_rate": "read_rate",
-         "t51_readback_s_per_GB": "readback_s_per_GB",
-         "t51_write_s_per_GB": "write_s_per_GB"}
-STAGE_TWINS = sorted(n for n in TWINS if "idle" not in n)
+STAGE_METRICS = ["dispatch_s_per_GB", "read_rate", "readback_s_per_GB",
+                 "write_s_per_GB"]
 
 
 def spec(name):
@@ -46,23 +38,22 @@ def test_end_to_end_run_at_toy_size():
     assert doc["metric_names"] == ["reduce_rate", "setup_s"]
     assert "metrics" not in doc
     assert any(ln.startswith("[check.reference]") for ln in out)
-    plan = json.loads(next(ln for ln in out if ln.startswith("[plan]"))[7:])
-    assert plan["blocks"] == 108 and plan["rows"] == 1
+    (plan,) = lines_of(out, "plan")
+    assert plan["blocks"] == 108 and plan["products"][0]["rows"] == 1
 
 
 def test_traced_run_reports_only_what_a_cpu_can():
     """The counters and host clocks have something to read on the CPU; the
     device readers (and the two that need the chip's trace) return
-    nothing.  ``link_wait_s_per_GB`` is a blocked-seconds row: there only
-    if the toy pass happened to block."""
+    nothing.  ``link_wait_s_per_GB`` reads ``wait.link``, a declared wait
+    (0 calls on the CPU, whose link is not budgeted): 0.0 s/GB."""
     p, out = run_harness("--workload", CELL, "--seed", "2600000006",
                          "--seconds", "0.05", "--trace", "1", "--rehearse")
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     doc = json.loads(out[-1])
     assert doc["correct"] is True and doc["breakdown"] is False
-    names = set(doc["metric_names"])
-    always = {"d2h_MB_per_GB", "host_cpu_s_per_GB", *STAGE_TWINS}
-    assert always <= names <= always | {"link_wait_s_per_GB"}
+    assert set(STAGE_METRICS) <= set(EVERY_PASS)
+    assert doc["metric_names"] == sorted(EVERY_PASS + PUMP_WAITS)
 
 
 def test_reference_integrates_51_spectra():
@@ -83,28 +74,6 @@ def test_reference_integrates_51_spectra():
         assert got.shape == ref.shape == (rows, nfft)
         # channelize_np filters and sums in float32
         assert np.abs(got - ref).max() <= 1e-5 * np.abs(ref).max()
-
-
-def test_new_metric_files_name_their_cell_and_a_reader():
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
-    for name in NEW_METRICS + sorted(TWINS):
-        s, e = spec(name), entries[name]
-        assert e["workloads"] == [CELL] == s["cells"]
-        assert e["moves"] == "reduce_rate"
-        assert os.path.exists(os.path.join(BENCH, "readers",
-                                           s["reader"] + ".py"))
-
-
-def test_twins_read_what_the_accepted_metric_reads():
-    """Same reader, same arguments, same unit, layer and direction as the
-    accepted metric of the name without ``t51_``: only the cell differs."""
-    for name, accepted in TWINS.items():
-        s, a = spec(name), spec(accepted)
-        for k in ("reader", "args", "unit", "layer", "better", "source",
-                  "moves"):
-            assert s[k] == a[k], (name, k)
-        assert CELL not in a["cells"]
 
 
 def test_carry_least_bytes_is_power_once_and_accumulators_twice():
@@ -171,14 +140,14 @@ def test_readers_on_the_recorded_traced_pass(recorded):
     least = (51 + 2 * st["dispatch"]["calls"]) * row
     assert share == pytest.approx(100 * least / 819e9 / own)
     assert 0 < share < 100
-    # the stage-table twins, from the same recorded table
+    # the stage-table metrics, from the same recorded table
     gb = facts["raw_bytes"] / 1e9
-    for name, stage in [("t51_dispatch_s_per_GB", "dispatch"),
-                        ("t51_readback_s_per_GB", "readback"),
-                        ("t51_write_s_per_GB", "write")]:
+    for name, stage in [("dispatch_s_per_GB", "dispatch"),
+                        ("readback_s_per_GB", "readback"),
+                        ("write_s_per_GB", "write")]:
         assert timeline.read(spec(name)["args"], ev) \
             == pytest.approx(st[stage]["seconds"] / gb)
-    assert timeline.read(spec("t51_read_rate")["args"], ev) \
+    assert timeline.read(spec("read_rate")["args"], ev) \
         == pytest.approx(st["ingest"]["bytes"] / st["ingest"]["seconds"]
                          / 1e9)
     # the dispatching thread's own copy sets the pace in this cell

@@ -6,9 +6,10 @@ import os
 import shutil
 
 import pytest
-from conftest import BENCH, ROOT, run_harness
+from conftest import (BENCH, EVERY_PASS, LOCAL_DRIVER, PUMP_WAITS, ROOT,
+                      lines_of, products_traffic, run_harness, tree_with)
 
-E2E = {"bank.hires": ["first_product_s", "reduce_rate", "setup_s"],
+E2E = {"bank.hires": ["first_product_s", "setup_s"],   # rate: `pass_rate`
        "bank.lowres": ["first_product_s", "reduce_rate", "setup_s"],
        # two 27 s passes to a run: too unsteady to carry a bound (PERF.md)
        "band4.hires": ["reduce_rate", "setup_s"]}
@@ -31,13 +32,17 @@ def test_end_to_end_run_at_toy_size(cell):
     assert "metrics" not in doc
     assert any(ln.startswith("[check.reference]") for ln in out)
     assert any(ln.startswith("[reduced]") for ln in out)
+    # one placement rule for every cell: products on $TMPDIR, the
+    # recording on RAM-backed scratch
+    (plan,) = lines_of(out, "plan")
+    assert os.path.dirname(plan["outdir"]) != os.path.dirname(plan["rawdir"])
 
 
 @pytest.mark.parametrize("cell, names", [
-    ("bank.hires", ["host_cpu_s_per_GB", "readback_s_per_GB",
-                    "write_s_per_GB"]),
-    ("band4.hires", ["first_product_wait_s", "host_cpu_s_per_GB", "read_rate",
-                     "readback_s_per_GB", "write_s_per_GB"]),
+    # `wait.link` is a declared wait: 0 calls on the CPU, and so 0.0 s/GB
+    ("bank.hires", sorted([n + ".first" for n in EVERY_PASS + PUMP_WAITS]
+                          + ["pass_rate"])),
+    ("band4.hires", sorted(EVERY_PASS + ["first_product_wait_s"])),
 ])
 def test_traced_run_reports_only_what_a_cpu_can(cell, names):
     """Host-side readers find their spans; the device readers find no
@@ -68,39 +73,86 @@ def test_alone_in_a_directory_exits_nonzero(tmp_path):
     assert p.returncode != 0 and not any(ln.startswith("{") for ln in out)
 
 
-def test_a_cell_added_as_files_only_is_found(tmp_path):
-    """A later PR adds a traffic mix, a cell and a per-layer metric with
-    its reader as new files and new entries, editing no file that is
-    there.  The harness finds them by name."""
-    shutil.copytree(BENCH, tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    os.symlink(os.path.join(ROOT, "blit"), tmp_path / "blit")
-    b = tmp_path / "benchmark"
-    with open(b / "traffic" / "lowres-6s.json") as f:
-        t = json.load(f)
-    t.update(name="lowres-2s", blocks=25)
-    (b / "traffic" / "lowres-2s.json").write_text(json.dumps(t))
-    (b / "readers" / "passes.py").write_text(
-        "def read(args, ev):\n"
-        "    return ev['window_raw_bytes'] / ev['traced_raw_bytes']\n")
-    (b / "layer_metrics" / "passes_in_window.json").write_text(json.dumps(
-        {"name": "passes_in_window", "reader": "passes", "args": {}}))
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["workloads"].append({"name": "bank.lowres2s", "config": "gbt-bank",
-                               "traffic": "lowres-2s", "chips": 1,
-                               "why": "added by a test, as files only"})
-    bench["per_layer"].append({
-        "name": "passes_in_window", "unit": "passes", "better": "higher",
-        "source": "program_counter", "layer": "whole host path",
-        "moves": "reduce_rate", "workloads": ["bank.lowres2s"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    p, out = run_harness("--workload", "bank.lowres2s", "--seed", "5",
+ONE_PRODUCT = dict(
+    cell={"name": "bank.lowres2s", "config": "gbt-bank",
+          "traffic": "lowres-2s", "chips": 1,
+          "why": "added by a test, as files only"},
+    blocks=25, products={"product": 2})
+# Three products a pass, as `rawspec -f 1024,8,64 -t 1,128,51` would make
+# them from one read of the toy recording: a traffic file with a
+# three-entry `products` list, a configuration file of its own, a driver
+# file of its own (the command that makes three from one read does not
+# exist yet, so the test's driver runs `blit reduce` per product).
+THREE_PRODUCTS = dict(
+    cell={"name": "rawspec3.toy", "config": "gbt-bank-three",
+          "traffic": "three-products", "chips": 1,
+          "why": "added by a test, as files only: three products a pass"},
+    blocks=38, products={"0000": 16, "0001": 18, "0002": 5})
+
+
+@pytest.mark.parametrize("case", [ONE_PRODUCT, THREE_PRODUCTS],
+                         ids=["one_product", "three_products"])
+def test_a_cell_added_as_files_only_is_found(tmp_path, case):
+    """A later PR adds a traffic mix, a cell, a configuration and a
+    per-layer metric with its reader as new files and new entries, editing
+    no file that is there.  The harness finds them by name, sizes every
+    product of the pass and verifies each.  ``reduce_rate`` lists its cells
+    (every cell but ``bank.hires``) and an accepted list takes no new name,
+    so the cell brings its rate as ``reduce_rate.<its own>``: the harness
+    takes the statistic from the name's stem, and an accepted reading
+    comes along as a ``same_as`` file."""
+    cell = case["cell"]
+    rate = "reduce_rate." + cell["name"]
+    files = {
+        "readers/passes_in_window.py":
+            "def read(args, ev):\n"
+            "    return ev['window_raw_bytes'] / ev['traced_raw_bytes']\n",
+        "layer_metrics/passes_in_window.json": json.dumps(
+            {"name": "passes_in_window", "reader": "passes_in_window",
+             "args": {}}),
+        "layer_metrics/read_rate.added.json": json.dumps(
+            {"name": "read_rate.added", "same_as": "read_rate"})}
+    drivers, configs = [], []
+    if case is ONE_PRODUCT:
+        with open(os.path.join(BENCH, "traffic", "lowres-6s.json")) as f:
+            t = json.load(f)
+        t.update(name="lowres-2s", blocks=25)
+    else:
+        t = products_traffic("three-products", [
+            ("0000", 1024, 1), ("0001", 8, 128), ("0002", 64, 51)])
+        drivers = [LOCAL_DRIVER]
+        with open(os.path.join(BENCH, "configs", "gbt-bank.json")) as f:
+            configs = [dict(json.load(f), name="gbt-bank-three")]
+    root = tree_with(
+        tmp_path, traffic={t["name"]: t}, workloads=[cell], files=files,
+        drivers=drivers, configs=configs, end_to_end=[{
+            "name": rate, "unit": "GB/s", "better": "higher", "bound": 0.15,
+            "source": "host_clock", "workloads": [cell["name"]]}],
+        per_layer=[{
+            "name": "passes_in_window", "unit": "passes", "better": "higher",
+            "source": "program_counter", "layer": "whole host path",
+            "moves": rate, "workloads": [cell["name"]]}, {
+            "name": "read_rate.added", "unit": "GB/s", "better": "higher",
+            "source": "program_span", "layer": "host read",
+            "moves": rate, "workloads": [cell["name"]]}])
+    p, out = run_harness("--workload", cell["name"], "--seed", "5",
                          "--seconds", "0.05", "--trace", "1", "--rehearse",
-                         root=str(tmp_path))
+                         root=root)
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     doc = last_doc(out)
-    assert doc["correct"] is True
-    assert "passes_in_window" in doc["metric_names"]
-    plan = json.loads(next(ln for ln in out if ln.startswith("[plan]"))[7:])
-    assert plan["blocks"] == 25
+    assert doc["correct"] is True and doc["failed"] == 0
+    # its own entries, `setup_s`'s none, and nothing that moves a rate it
+    # does not report
+    assert doc["metric_names"] == ["passes_in_window", "read_rate.added"]
+    if case is ONE_PRODUCT:   # and untraced: its own rate, first rows, set-up
+        p, out = run_harness("--workload", cell["name"], "--seed", "5",
+                             "--seconds", "0.05", "--trace", "0",
+                             "--rehearse", root=root)
+        assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+        assert last_doc(out)["metric_names"] == [rate, "setup_s"]
+    (plan,) = lines_of(out, "plan")
+    assert plan["blocks"] == case["blocks"]
+    assert {q["name"]: q["rows"] for q in plan["products"]} \
+        == case["products"]
+    assert [r["product"] for r in lines_of(out, "check.reference")] \
+        == list(case["products"])
