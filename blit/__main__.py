@@ -83,16 +83,72 @@ def _ran_on() -> dict:
     return {**device_facts(), "kernel_plan": last_kernel_plan()}
 
 
-def _cmd_reduce(args: argparse.Namespace) -> int:
-    from blit.pipeline import RawReducer, reducer_for_product
+def _int_list(text: str) -> List[int]:
+    """``1048576,8,1024`` -> [1048576, 8, 1024] (rawspec's ``-f`` / ``-t``
+    spelling; one number is a list of one)."""
+    try:
+        return [int(w) for w in text.split(",")]
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"{text!r} is not a comma list of whole numbers") from None
 
-    kw = dict(stokes=args.stokes, fqav_by=args.fqav, dtype=args.dtype)
+
+def rawspec_product_path(stem: str, k: int) -> str:
+    """Where product ``k`` of several lands: rawspec's own naming."""
+    return f"{stem}.rawspec.{k:04d}.fil"
+
+
+def _reduce_products(args: argparse.Namespace) -> List[tuple]:
+    """The ``(nfft, nint)`` list of a ``blit reduce``; refuses by flag,
+    before any byte is read, what cannot hold for every product alike."""
     if args.product is not None:
-        red = reducer_for_product(args.product, **kw)
-    else:
-        red = RawReducer(nfft=args.nfft, nint=args.nint, **kw)
+        from blit.pipeline import PRODUCT_PRESETS
+
+        return [PRODUCT_PRESETS[args.product]]
+    if len(args.nfft) != len(args.nint):
+        raise SystemExit(
+            f"blit reduce: --nfft lists {len(args.nfft)} products and "
+            f"--nint {len(args.nint)}: give one --nint per --nfft")
+    products = list(zip(args.nfft, args.nint))
+    for nfft, _ in products:
+        if args.fqav > 1 and nfft % args.fqav:
+            raise SystemExit(
+                f"blit reduce: --fqav {args.fqav} does not divide --nfft "
+                f"{nfft}: the averaging must hold for every product")
+    if len(products) > 1:
+        for flag, given in (("--resume", args.resume),
+                            ("--compression", args.compression)):
+            if given:
+                raise SystemExit(
+                    f"blit reduce: {flag} with several products is not "
+                    "supported (ROADMAP B1): run one product per command, "
+                    "or all of them without it")
+        if args.output.endswith((".h5", ".hdf5", ".fil")):
+            raise SystemExit(
+                "blit reduce: with several products -o is a STEM (product "
+                "k lands at <stem>.rawspec.000k.fil), not a product path: "
+                f"{args.output!r}")
+    return products
+
+
+def _cmd_reduce(args: argparse.Namespace) -> int:
+    from blit.pipeline import RawReducer
+
+    (nfft, nint), *also = _reduce_products(args)
+    red = RawReducer(nfft=nfft, nint=nint, also=tuple(also),
+                     stokes=args.stokes, fqav_by=args.fqav, dtype=args.dtype)
     src: object = args.raw[0] if len(args.raw) == 1 else args.raw
-    if args.resume:
+    doc = {"output": args.output}
+    if also:
+        paths = [rawspec_product_path(args.output, k)
+                 for k in range(len(red.products))]
+        hdrs = red.reduce_to_files(src, paths)
+        hdr = hdrs[0]
+        doc["products"] = [
+            {"path": path, "nfft": f, "nint": t, "nsamps": h.get("nsamps"),
+             "nchans": h.get("nchans")}
+            for path, (f, t), h in zip(paths, red.products, hdrs)]
+    elif args.resume:
         hdr = red.reduce_resumable(src, args.output,
                                    compression=args.compression)
     else:
@@ -102,7 +158,7 @@ def _cmd_reduce(args: argparse.Namespace) -> int:
     print(
         json.dumps(
             {
-                "output": args.output,
+                **doc,
                 "nsamps": hdr.get("nsamps"),
                 "nchans": hdr.get("nchans"),
                 "nifs": hdr.get("nifs"),
@@ -2151,14 +2207,23 @@ def main(argv: Optional[List[str]] = None) -> int:
     pr.add_argument("raw", nargs="+",
                     help="RAW file, .NNNN.raw sequence stem, or member list")
     pr.add_argument("-o", "--output", required=True,
-                    help="output product path (.fil streams; .h5 = FBH5)")
+                    help="output product path (.fil streams; .h5 = FBH5); "
+                         "with several products a STEM: product k lands "
+                         "at <stem>.rawspec.000k.fil, each with its own "
+                         ".partial and .manifest.json")
     pr.add_argument("--product", choices=list(_PRODUCTS),
                     help="rawspec product preset (else --nfft/--nint)")
-    pr.add_argument("--nfft", type=int, default=1024)
-    pr.add_argument("--nint", type=int, default=1)
+    pr.add_argument("--nfft", type=_int_list, default=[1024],
+                    help="fine channels per coarse channel; a comma list "
+                         "(rawspec's -f 1048576,8,1024) makes several "
+                         "products from ONE read and ONE upload")
+    pr.add_argument("--nint", type=_int_list, default=[1],
+                    help="spectra per output row; one per --nfft entry "
+                         "(rawspec's -t 51,128,3072)")
     pr.add_argument("--stokes", default="I")
     pr.add_argument("--fqav", type=int, default=1,
-                    help="on-device frequency averaging factor")
+                    help="on-device frequency averaging factor (must "
+                         "divide every --nfft)")
     pr.add_argument("--dtype", default="float32",
                     choices=["float32", "bfloat16"])
     pr.add_argument("--compression", default=None,
@@ -2166,7 +2231,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="codec for .h5 (FBH5) output")
     pr.add_argument("--resume", action="store_true",
                     help="crash-resumable streaming (cursor sidecar; "
-                         ".fil and .h5)")
+                         ".fil and .h5; refused with several products)")
     pr.set_defaults(fn=_cmd_reduce)
 
     ph = sub.add_parser(

@@ -751,26 +751,195 @@ def stream_step(
     filter state keeps part of the old tail by the same line).  The ONE
     body of the reducer's :func:`channelize_stream` and of the mesh's
     per-chip :func:`blit.parallel.mesh.band_stream`."""
-    gross = jnp.concatenate([tail, body], axis=1)
-    return (channelize(_word_samples(gross), coeffs, **kw),
-            gross[:, gross.shape[1] - tail.shape[1]:])
+    return _gross_step(jnp.concatenate([tail, body], axis=1), coeffs, **kw)
 
 
-@functools.partial(jax.jit, static_argnames=_CHANNELIZE_STATIC,
-                   donate_argnames=("tail",))
-def channelize_stream(
-    tail: jax.Array, body: jax.Array, coeffs: jax.Array, **kw
-) -> Tuple[jax.Array, jax.Array]:
-    """One dispatch of a STREAM (:func:`stream_step` as a program of its
-    own): the product of ``concat(tail, body)`` and the next tail,
-    device-resident data the next dispatch consumes like
-    :func:`integrate_carry`'s accumulator — a stream's filter state
-    crosses the host link once, as its head.  ``tail`` is DONATED: the
-    next tail takes its place in device memory (a group's filter state is
-    held once, not twice), and a device array passed as ``tail`` is
-    deleted by the call.
+def _gross_step(gross: jax.Array, coeffs: jax.Array, *,
+                frames: Optional[int] = None, lanes: int = 0, **kw):
+    """``gross``, the words of a filter state and the samples after it,
+    reduced to its first ``frames`` frames (all it holds, by default) and
+    the filter state the frame after them starts from.  ``lanes`` > 0
+    takes the small-``nfft`` path (:func:`channelize_lanes`, blocks of
+    that many words)."""
+    nfft, state = kw["nfft"], (kw.get("ntap", 4) - 1) * kw["nfft"]
+    if frames is None:
+        frames = (gross.shape[1] - state) // nfft
+    used = frames * nfft
+    if lanes:
+        power = channelize_lanes(
+            gross, coeffs, nfft=nfft, ntap=kw.get("ntap", 4), block=lanes,
+            frames=frames, stokes=kw.get("stokes", "I"))
+    else:
+        power = channelize(_word_samples(gross[:, :used + state]), coeffs,
+                           **kw)
+    return power, gross[:, used:used + state]
+
+
+_STREAM_STATIC = _CHANNELIZE_STATIC + ("frames", "lanes")
+
+
+@functools.lru_cache(maxsize=None)
+def leg_programs(name: str):
+    """``(step, head)``, the two programs of one leg of a stream, under
+    ``jit_<name>`` in a device trace (the trace names programs: a leg
+    whose seconds are to be read apart has a name of its own).
+
+    ``step(tail, body, coeffs, *, frames=None, lanes=0, **kw)`` is one
+    dispatch: :func:`stream_step` of the first ``frames`` frames (all, by
+    default) of ``concat(tail, body)``, with ``tail`` DONATED — the next
+    tail takes its place in device memory, so a group's filter state is
+    held once, and a device array passed as ``tail`` is deleted by the
+    call.  ``head(words, coeffs, **same)`` is a stream's first: ``words``
+    holds the leg's filter state and, after it, samples the stream's head
+    has beyond it (a leg whose ``nfft`` is not the largest: the head is
+    the largest's); it is not donated, the other legs read it too."""
+
+    def step(tail, body, coeffs, **kw):
+        return stream_step(tail, body, coeffs, **kw)
+
+    def head(words, coeffs, **kw):
+        return _gross_step(words, coeffs, **kw)
+
+    for fn in (step, head):
+        fn.__name__ = fn.__qualname__ = name
+    return (jax.jit(step, static_argnames=_STREAM_STATIC,
+                    donate_argnames=("tail",)),
+            jax.jit(head, static_argnames=_STREAM_STATIC))
+
+
+# One dispatch of a STREAM (:func:`stream_step` as a program of its own):
+# the product of ``concat(tail, body)`` and the next tail, device-resident
+# data the next dispatch consumes like :func:`integrate_carry`'s
+# accumulator — a stream's filter state crosses the host link once, as its
+# head.  ``tail`` is DONATED (:func:`leg_programs`).
+channelize_stream = leg_programs("channelize_stream")[0]
+
+
+def _fft_planes(xs: list) -> list:
+    """Radix-2 FFT of ``len(xs)`` (a power of two) planar ``(re, im)``
+    planes, each any shape: the transform runs ACROSS the list, every
+    plane elementwise — for an ``nfft`` far below a vector's width, where
+    the frames and not the channels fill the lanes."""
+    n = len(xs)
+    if n == 1:
+        return xs
+    even, odd = _fft_planes(xs[0::2]), _fft_planes(xs[1::2])
+    out = [None] * n
+    for k in range(n // 2):
+        (er, ei), (dr, di) = even[k], odd[k]
+        if k == 0:
+            tr, ti = dr, di
+        elif 4 * k == n:  # times -i
+            tr, ti = di, -dr
+        else:
+            wr = np.float32(math.cos(2 * math.pi * k / n))
+            wi = np.float32(-math.sin(2 * math.pi * k / n))
+            tr, ti = dr * wr - di * wi, dr * wi + di * wr
+        out[k] = (er + tr, ei + ti)
+        out[k + n // 2] = (er - tr, ei - ti)
+    return out
+
+
+# Coarse channels :func:`channelize_lanes` works on at a time.
+_LANES_CHANNELS = 8
+
+
+def lanes_block(nfft: int, nint: int, npol: int = 2, ntap: int = 4, *,
+                fqav_by: int = 1, dtype: str = "float32") -> int:
+    """Words per block of :func:`channelize_lanes` for a carried product
+    ``(nfft, nint)``, or 0 where that path does not serve it: one
+    integration's samples, ``nint * nfft``, where ``nfft`` is a power of
+    two too small to fill a vector's 128 lanes, the block is whole vectors
+    and holds the filter state (float32 spectra of two polarizations, not
+    frequency-averaged).  The shape alone decides, on every backend."""
+    small = nfft < 128 and nfft & (nfft - 1) == 0
+    block = nint * nfft
+    return block if (small and npol == 2 and block % 128 == 0
+                     and nint >= ntap - 1 and fqav_by == 1
+                     and dtype == "float32") else 0
+
+
+def channelize_lanes(
+    gross: jax.Array, coeffs: jax.Array, *, nfft: int, ntap: int,
+    block: int, frames: int, stokes: str = "I",
+) -> jax.Array:
+    """The channelizer for a SMALL ``nfft`` (rawspec's ``-f 8``), frames
+    on the lane axis.  :func:`channelize` lays a block out ``(...,
+    frames, nfft)``: at ``nfft`` 8 every float32 intermediate of a
+    2^23-sample group is padded 16 times over on a 128-lane machine.
+
+    ``gross`` ``(cb, (ntap-1)*nfft + samples)`` int32 words
+    (:func:`sample_words` at two polarizations).  The samples are cut
+    into blocks of ``block = m * nfft`` words (``m`` frames; the caller's
+    ``nint``) and ONE transpose of the words puts the blocks on the
+    lanes: ``(cb, groups, block) -> (cb, block, groups)``.  After it a
+    frame's tap ``k`` is a slice ``k`` rows down (the first ``ntap-1``
+    frames of the next block ride below each block's own), the ``nfft``
+    points of the FFT are ``nfft`` planes (:func:`_fft_planes`) and every
+    operation is elementwise over ``(..., groups)``.
+
+    Returns the power of frame ``g * m + p`` at ``[:, p, :, :, g]``,
+    ``(cb, m, nif, nfft, groups)`` float32, fftshifted like
+    :func:`channelize`'s: the layout :func:`integrate_carry` folds with
+    ``lanes=True``.  ``groups = ceil(frames / m)``; where ``gross`` ends
+    before the last block does it is padded with zeros, and where it goes
+    on past ``frames`` frames they are computed: either way only the
+    first ``frames`` frames are the stream's (the fold's ``nframes``).
     """
-    return stream_step(tail, body, coeffs, **kw)
+    cb, have = gross.shape
+    if cb > _LANES_CHANNELS and cb % _LANES_CHANNELS == 0:
+        # A few channels at a time, one after the other in the one
+        # program: the float32 planes between the passes below are held
+        # for those channels only.
+        power = jax.lax.map(
+            lambda g: channelize_lanes(
+                g, coeffs, nfft=nfft, ntap=ntap, block=block,
+                frames=frames, stokes=stokes),
+            gross.reshape(cb // _LANES_CHANNELS, _LANES_CHANNELS, have))
+        return power.reshape((cb,) + power.shape[2:])
+    state, m = (ntap - 1) * nfft, block // nfft
+    groups = -(-frames // m)
+    need = groups * block + state
+    if have < need:
+        gross = jnp.pad(gross, ((0, 0), (0, need - have)))
+    rows = jnp.swapaxes(
+        gross[:, :groups * block].reshape(cb, groups, block), 1, 2)
+    # Below each block's own rows, the first `state` words of the next.
+    below = jnp.concatenate(
+        [rows[:, :state, 1:], gross[:, groups * block:need, None]], axis=2)
+    words = jnp.concatenate([rows, below], axis=1).reshape(
+        cb, m + ntap - 1, nfft, groups)
+    # The shift theorem, as in channelize: (-1)^j on the input rolls the
+    # spectrum by nfft/2.
+    sign = np.where(np.arange(nfft) % 2 == 0, 1.0, -1.0).astype(np.float32)
+    taps = (coeffs * sign[None, :])[:, None, None, :, None]
+
+    def plane(byte):
+        """Byte ``byte`` of every word (0: the first polarization's real
+        part), sign-extended, through the filter: ``(cb, m, nfft,
+        groups)``.  Each tap converts its own slice of the words: the
+        float32 plane of all ``m + ntap - 1`` rows is never written."""
+        def rows_from(k):
+            return jax.lax.shift_right_arithmetic(
+                jax.lax.shift_left(words[:, k:k + m],
+                                   jnp.int32(24 - 8 * byte)),
+                jnp.int32(24)).astype(jnp.float32)
+
+        acc = taps[0] * rows_from(0)
+        for k in range(1, ntap):
+            acc = acc + taps[k] * rows_from(k)
+        return acc
+
+    pols = []
+    for pol in range(2):
+        re, im = plane(2 * pol), plane(2 * pol + 1)
+        pols.append(_fft_planes(
+            [(re[:, :, i], im[:, :, i]) for i in range(nfft)]))
+    sr = jnp.stack([jnp.stack([z[0] for z in p], axis=2) for p in pols],
+                   axis=2)  # (cb, m, npol, nfft, groups)
+    si = jnp.stack([jnp.stack([z[1] for z in p], axis=2) for p in pols],
+                   axis=2)
+    return detect_stokes_planar(sr, si, stokes)  # (cb, m, nif, nfft, groups)
 
 
 def _direct_put(host, then: Callable):
@@ -797,78 +966,289 @@ def split_tails(head, channel_block: int) -> list:
     return [head[c : c + groups.step] for c in groups]
 
 
-def _put_group(put: Callable, tail, body, program: Callable):
-    """One group's new samples through ``put``, as :func:`sample_words`,
-    to ``program(tail, body)`` — and with them its filter state, where
-    that is still the stream's head in host memory (one transfer of the
-    pair: the tail is donated to the program, so no handle on it may
-    outlive the call)."""
-    body = sample_words(body)
-    if isinstance(tail, np.ndarray):
-        return put((sample_words(tail), body),
-                   then=lambda up: program(*up))
-    return put(body, then=lambda up: program(tail, up))
+class StreamLeg:
+    """One product of a stream: its programs, and what it keeps on the
+    device between dispatches, per channel group — the filter state
+    (``tails``: the last ``(ntap-1)*nfft`` samples dispatched, the next
+    dispatch's first) and, where the integration is CARRIED across
+    dispatches, its accumulators (``accs``) and the frames the open
+    integration holds (``filled``).  A reduction is a list of legs that
+    read the same uploaded samples (:func:`channelize_fanout`).
+
+    ``kw`` are :func:`channelize`'s keywords less ``nint``.  A leg that is
+    not carried integrates inside its program (a dispatch's frames are a
+    multiple of ``nint``).  ``lanes`` > 0 runs the small-``nfft`` path in
+    blocks of that many words (:func:`lanes_block`; carried legs only).
+    ``name`` is the leg's program name in a device trace; ``label`` names
+    it in counters (``None``: a reduction of one product)."""
+
+    def __init__(self, coeffs, *, nint: int, carried: bool,
+                 name: str = "channelize_stream", label: Optional[str] = None,
+                 lanes: int = 0, **kw):
+        assert carried or not lanes
+        self.coeffs, self.nint, self.carried = coeffs, nint, carried
+        self.lanes, self.label, self.kw = lanes, label, kw
+        self.nfft = kw["nfft"]
+        self.state_words = (kw.get("ntap", 4) - 1) * self.nfft
+        self.step, self.head = leg_programs(name)
+        self.tails: Optional[list] = None
+        self.accs: Optional[list] = None
+        self.filled = 0
+        self._rows: Tuple[list, list] = ([], [])  # the head's, the chunk's
+
+    def _fold(self, g: int, out, frames: int, at: int, batch: int):
+        """A program's output for group ``g``, ``frames`` frames that
+        found ``at`` in the open integration → what the host may hold on
+        to until the group's input is consumed (never a tail: the next
+        dispatch donates it).  Of a carried leg that is the ACCUMULATOR
+        alone, ready when the fold is: the fold's row buffer is zeros
+        while no row closes (128 MiB a group at 2^20), and one kept alive
+        by the link budget's handle and the readback token for a dispatch
+        or two leaves the next group's samples no room beside a running
+        program (PERF.md section 6, PR 34)."""
+        power, self.tails[g] = out
+        if not self.carried:
+            self._rows[batch].append(power)
+            return power
+        if self.accs is None:
+            self.accs = [None] * len(self.tails)
+        acc = self.accs[g]
+        if acc is None:
+            acc = jnp.zeros(
+                power.shape[:1] + power.shape[2:4] if self.lanes
+                else power.shape[1:], jnp.float32)
+        rows, self.accs[g] = integrate_carry(
+            power, acc, np.int32(at),  # data, not static: one program
+            nint=self.nint, lanes=bool(self.lanes),
+            nframes=frames if self.lanes else None)
+        closed = (at + frames) // self.nint
+        if closed:
+            self._rows[batch].append(rows if closed == rows.shape[0]
+                                     else rows[:closed])
+        return self.accs[g]
+
+    def _program_kw(self, frames: Optional[int]) -> dict:
+        kw = dict(self.kw)
+        if not self.carried:
+            kw["nint"] = self.nint
+        if self.lanes:
+            kw["lanes"] = self.lanes
+        if frames is not None:
+            kw["frames"] = frames
+        return kw
+
+    def begin(self, g: int, head_up: jax.Array):
+        """Group ``g``'s first step of a stream whose head (``head_up``,
+        device words) is longer than this leg's filter state: the samples
+        past it are the leg's data."""
+        frames = (head_up.shape[1] - self.state_words) // self.nfft
+        if not frames:  # the largest filter state is this leg's own
+            self.tails[g] = jnp.array(head_up, copy=True)
+            return None
+        return self._fold(g, self.head(head_up, self.coeffs,
+                                       **self._program_kw(None)),
+                          frames, 0, 0)
+
+    def advance(self, g: int, body_up: jax.Array, frames: int,
+                begun: int = 0):
+        """Group ``g``'s dispatch: the first ``frames`` frames of the
+        samples ``body_up`` (device words) after the leg's tail;
+        ``begun``: the frames :meth:`begin` took in this dispatch."""
+        whole = frames * self.nfft == body_up.shape[1]
+        return self._fold(g, self.step(
+            self.tails[g], body_up, self.coeffs,
+            **self._program_kw(None if whole else frames)),
+            frames, (self.filled + begun) % self.nint, 1)
+
+    def close(self, frames: int, begun: int = 0) -> list:
+        """After every group has stepped ``begun`` + ``frames`` frames:
+        the batches of rows that closed (the head's, then the chunk's),
+        each ``(k, nif, nchan*nfft)`` assembled on the device — nothing
+        where none did, and then no group's partial sum is concatenated,
+        fetched or written."""
+        batches, self._rows = self._rows, ([], [])
+        if self.carried:
+            self.filled = (self.filled + begun + frames) % self.nint
+        return [rows[0] if len(rows) == 1
+                else jnp.concatenate(rows, axis=-1)
+                for rows in batches if rows]
 
 
-def channelize_blocked(
+def channelize_fanout(
     voltages,
-    coeffs,
-    tails: list,
+    legs: list,
+    frames: list,
     *,
     channel_block: int,
+    head=None,
     put: Callable = _direct_put,
-    **kw,
-) -> Tuple[jax.Array, list]:
-    """Host-looped channel blocking of a stream's chunk: the
-    compile-friendly replacement for ``channelize(channel_block=)``'s
-    in-jit ``lax.map`` (whose XLA loop blows compile time past 500 s at
-    nfft=2^20, DESIGN.md §3/§9).
+    shared: Optional[Callable] = None,
+) -> Tuple[list, list]:
+    """One dispatch of a stream's chunk to every leg: host-looped channel
+    blocking (the compile-friendly replacement for
+    ``channelize(channel_block=)``'s in-jit ``lax.map``, whose XLA loop
+    blows compile time past 500 s at nfft=2^20, DESIGN.md §3/§9) with each
+    group's samples put ONCE and consumed by all the legs' programs.
 
     ``voltages`` is the chunk's NEW samples only, host int8 ``(nchan,
-    frames*nfft, npol, 2)``; ``tails`` holds each group's filter state
-    (:func:`split_tails` of the stream's head for its first dispatch, the
-    previous dispatch's second result — device words — after that).
-    Dispatches :func:`channelize_stream` once per ``channel_block``-sized
-    group of coarse channels — ONE jit compile (group shape is constant, and a tail
-    that came up from the host has the shape and dtype of one that stayed
-    on the chip), dispatches enqueued async back-to-back, device-side
-    concatenation of the per-group products.  Peak HBM is bounded by one
-    group's intermediates plus the final product, so the per-*call* net
-    work can grow well past what the flat layout fits (the
-    dispatch-amortization lever of DESIGN.md §3 at bounded memory, now at
-    seconds-scale compile).  ``put(host, then=)`` takes host memory (a
-    group's :func:`sample_words`, or the pair ``(head, samples)`` of them
-    on a stream's first dispatch) to the device and returns what the
-    program ``then`` makes of it — the caller's transfer policy; by
-    default the jit's own argument transfer.
+    samples, npol, 2)``; ``frames[k]`` the frames leg ``k`` takes of them
+    (a flush may give a leg fewer than the samples hold, or none).
+    ``head``, on a stream's first dispatch, is its first samples, as long
+    as the LARGEST filter state among the legs: it goes up in the same
+    transfer as its group's samples (one put of the pair — the leg that
+    owns it takes it by donation, so no handle on it may outlive the
+    call), is that leg's first tail, and to every other leg its own
+    shorter filter state followed by data (:meth:`StreamLeg.begin`).
+    Groups are ``channel_block`` coarse channels: ONE compile per leg and
+    shape, dispatches enqueued async back-to-back, device-side
+    concatenation of the per-group rows.  ``put(host, then=)`` takes host
+    memory (a group's :func:`sample_words`, or the pair ``(head,
+    samples)`` of them) to the device and returns what the program
+    ``then`` makes of it — the caller's transfer policy; by default the
+    jit's own argument transfer.  ``shared(programs, nbytes)`` is told,
+    per group, how many programs consumed a transfer of ``nbytes`` they
+    did not upload.
 
-    Returns ``(product, tails)``: the product of ``channelize(concat(tail,
-    voltages), ..., channel_block=0)`` (golden-tested) and the tails to
-    hand to the stream's next dispatch.
+    Returns ``(rows, token)``: per leg the list of row batches that closed
+    (a leg's head step and its dispatch each close their own), and what is
+    ready once the chunk's input has been consumed.
     """
     groups = _channel_groups(voltages.shape[0], channel_block)
-    outs, new_tails = [], []
-
-    def program(tail, body):
-        # The product alone goes back to ``put`` (its handle on the
-        # transfer); the next tail leaves the same execution.
-        out, nxt = channelize_stream(tail, body, coeffs, **kw)
-        new_tails.append(nxt)
-        return out
-
+    if head is not None:
+        heads = split_tails(head, channel_block)
+        for leg in legs:
+            leg.tails, leg.accs, leg.filled = [None] * len(groups), None, 0
+        # The leg the head is the filter state of (the first of them).
+        owner = max(legs, key=lambda leg: leg.state_words)
+        begun = [(head.shape[1] - leg.state_words) // leg.nfft
+                 for leg in legs]
+    else:
+        begun = [0] * len(legs)
+    token = []
     for g, c in enumerate(groups):
-        outs.append(_put_group(put, tails[g],
-                               voltages[c : c + groups.step], program))
-    return (outs[0] if len(outs) == 1
-            else jnp.concatenate(outs, axis=-1)), new_tails
+        def programs(up, g=g):
+            held = []
+            if head is not None:
+                head_up, up = up
+                # Everyone reads the head before its owner donates it.
+                held += [leg.begin(g, head_up) for leg in legs
+                         if leg is not owner]
+                owner.tails[g] = head_up
+            held += [leg.advance(g, up, n, b)
+                     for leg, n, b in zip(legs, frames, begun) if n]
+            return held
+
+        body = sample_words(voltages[c : c + groups.step])
+        host = body if head is None else (sample_words(heads[g]), body)
+        token.append(put(host, then=programs))
+        if shared is not None and len(legs) > 1:
+            # Programs that ran on this transfer less the one that would
+            # have had to upload it, and what the others did not send.
+            took = sum(map(bool, frames))
+            shared(took + sum(map(bool, begun)) - 1,
+                   max(0, took - 1) * sum(
+                       a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    return [leg.close(n, b) for leg, n, b in zip(legs, frames, begun)], token
 
 
-@functools.partial(jax.jit, static_argnames=("nint",))
+# Positions of an integration one fused pass of the fold adds (a chain of
+# that many adds per value, the running sums held in registers between
+# them): past it the chain is a loop of such passes.
+_FOLD_UNROLL = 32
+
+
+def _seq_sum(start: jax.Array, xg: jax.Array, lo, hi, valid=None):
+    """``start + xg[:, lo] + xg[:, lo + 1] + ... + xg[:, hi - 1]``, added
+    in that order, one position (axis 1 of ``xg``) at a time; ``lo`` and
+    ``hi`` are data.  ``valid(p)``, where given, masks the values a
+    position ``p`` does not hold (they are left out, not added as zeros).
+    Up to :data:`_FOLD_UNROLL` positions are one elementwise chain; more
+    are a loop over blocks of that many, the blocks outside ``[lo, hi)``
+    never read."""
+    npos, unroll = xg.shape[1], min(xg.shape[1], _FOLD_UNROLL)
+
+    def block(first, s):
+        # dynamic_slice clamps a start that would run off the end: the
+        # positions it then repeats are below `first`, and masked.
+        at = jnp.minimum(first, npos - unroll)
+        for u in range(unroll):
+            p = at + u
+            take = (p >= jnp.maximum(lo, first)) & (p < hi)
+            if valid is not None:
+                take = take & valid(p)
+            s = jnp.where(take, s + jax.lax.dynamic_index_in_dim(
+                xg, p, axis=1, keepdims=False), s)
+        return s
+
+    if npos <= unroll:
+        return block(jnp.int32(0), start)
+    return jax.lax.fori_loop(
+        lo // unroll, -(-hi // unroll),
+        lambda j, s: block(j * unroll, s), start)
+
+
+def _fold_groups(xg: jax.Array, carry: jax.Array, filled, nint: int,
+                 last_valid: int, group_axis: int):
+    """Fold a slab of consecutive frame groups into rows.  ``xg`` holds
+    group ``g``'s position ``p`` at axis 1 and ``g`` at ``group_axis`` (0
+    or -1); every group holds ``xg.shape[1]`` frames (a whole
+    integration's ``nint``, unless the slab is one short group) and the
+    last only ``last_valid`` of them.  ``carry`` (``xg`` less both axes)
+    holds the ``filled`` frames the open integration has.
+
+    A row is its open head (the ``filled`` frames carried in, or the
+    previous group's last ``filled``) with the group's first ``nint -
+    filled`` added to it in order: the heads of all rows first, from
+    zero, then every row's remainder — ``nint`` steps over a ``(groups,
+    ...)`` slab, vectorised ACROSS rows and sequential WITHIN one.
+    Returns ``(rows, carry)``: a row per group (the last one zeros unless
+    its frames closed it), the group axis where it was, and the
+    accumulator after the slab."""
+    ngroups, npos = xg.shape[group_axis], xg.shape[1]
+    assert npos == nint or ngroups == 1, (xg.shape, nint)
+    split = jnp.minimum(nint - filled, npos)  # the open row takes these
+    valid = None
+    if last_valid < npos:  # the last group's frames past `last_valid`
+        shape = [1] * (xg.ndim - 1)
+        shape[group_axis] = ngroups
+        last = jax.lax.broadcasted_iota(
+            jnp.int32, shape, group_axis % len(shape)) == ngroups - 1
+
+        def valid(p):
+            return ~last | (p < last_valid)
+
+    def at(x, g):
+        return jax.lax.index_in_dim(x, g, group_axis, keepdims=False)
+
+    def shifted(first, x):
+        """``first`` in front of all of ``x`` but its last group."""
+        return jnp.concatenate(
+            [jnp.expand_dims(first, group_axis),
+             jax.lax.slice_in_dim(x, 0, ngroups - 1, axis=group_axis)],
+            axis=group_axis)
+
+    zeros = jnp.zeros(xg.shape[:1] + xg.shape[2:], xg.dtype)
+    heads = _seq_sum(zeros, xg, split, jnp.int32(npos), valid)
+    rows = _seq_sum(shifted(carry, heads), xg, jnp.int32(0), split, valid)
+    closed = last_valid >= nint - filled  # did the last group close a row?
+    last_row = at(rows, ngroups - 1)
+    carry = jnp.where(closed, at(heads, ngroups - 1), last_row)
+    last_row = jnp.where(closed, last_row, jnp.zeros_like(last_row))
+    # ... and the rows with the last one as it closed, or zeros.
+    rows = jnp.concatenate(
+        [jax.lax.slice_in_dim(rows, 0, ngroups - 1, axis=group_axis),
+         jnp.expand_dims(last_row, group_axis)], axis=group_axis)
+    return rows, carry
+
+
+@functools.partial(jax.jit, static_argnames=("nint", "nframes", "lanes"))
 def integrate_carry(
-    power: jax.Array, acc: jax.Array, filled: jax.Array, *, nint: int
+    power: jax.Array, acc: jax.Array, filled: jax.Array, *, nint: int,
+    nframes: Optional[int] = None, lanes: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Integrate one dispatch's spectra into an integration that is longer
-    than a dispatch, or straddles its boundary.
+    than a dispatch, or straddles its boundary: ONE fold for every leg of
+    a reduction (program ``jit_integrate_carry``).
 
     ``power`` is :func:`channelize`'s product at ``nint=1``, frame-major
     ``(nframes, nif, nchan)``; ``acc`` ``(nif, nchan)`` float32 holds the
@@ -879,80 +1259,42 @@ def integrate_carry(
     ``(filled + nframes) // nint`` closed in this dispatch (the rest are
     zeros), and the accumulator to hand to the next dispatch.
 
-    Frames are added one at a time in stream order and a fresh integration
-    starts from zero, so a row's bits depend on each frame's place in the
-    integration, not on where the dispatch grid fell: a reduction resumed
-    at another row gives the same bytes.
+    With ``lanes`` the power is :func:`channelize_lanes`'s: ``(cb, nint,
+    nif, nfft, groups)``, frame ``g * nint + p`` at ``[:, p, ..., g]``,
+    the first ``nframes`` of them real; ``acc`` is ``(cb, nif, nfft)`` and
+    the rows come back in the product's layout, ``(groups, nif, cb *
+    nfft)``.
+
+    The order of addition is part of the result: a row's frames are added
+    one at a time in stream order and a fresh integration starts from
+    zero, so a row's bits depend on each frame's place in its integration,
+    not on where the dispatch grid fell — a reduction resumed at another
+    row gives the same bytes.  The work is laid out across rows
+    (:func:`_fold_groups`): whole integrations in bulk, the short group
+    that ends the dispatch apart.
     """
-    nframes = power.shape[0]
-    rows = jnp.zeros(((nint - 1 + nframes) // nint,) + acc.shape, acc.dtype)
-
-    def add_frame(j, carry):
-        acc, rows = carry
-        acc = acc + power[j]
-        n = filled + j + 1
-        closes = n % nint == 0
-        rows = jax.lax.cond(
-            closes,
-            lambda: jax.lax.dynamic_update_index_in_dim(
-                rows, acc, n // nint - 1, 0),
-            lambda: rows,
-        )
-        return jnp.where(closes, jnp.zeros_like(acc), acc), rows
-
-    acc, rows = jax.lax.fori_loop(0, nframes, add_frame, (acc, rows))
-    return rows, acc
-
-
-def channelize_carry(
-    voltages,
-    coeffs,
-    tails: list,
-    accs: Optional[list],
-    filled: int,
-    *,
-    channel_block: int,
-    nint: int,
-    put: Callable = _direct_put,
-    **kw,
-) -> Tuple[Optional[jax.Array], list, list]:
-    """:func:`channelize_blocked` for an integration carried across
-    dispatches: each ``channel_block``-sized group of coarse channels is
-    channelized to frame-major power (:func:`channelize_stream`, its
-    filter state in ``tails`` as there) and folded into that group's
-    device-resident accumulator (:func:`integrate_carry`).
-
-    ``accs`` is the previous dispatch's third result (``None`` to start
-    a stream) and ``filled`` the frames the open integration holds.
-    Returns ``(rows, tails, accs)``: the ``(filled + nframes) // nint``
-    rows that closed, ``(k, nif, nchan*nfft)`` assembled on the device —
-    ``None`` when none did, and then no group's partial sum is
-    concatenated, fetched or written.  ``put`` as in
-    :func:`channelize_blocked`.
-    """
-    groups = _channel_groups(voltages.shape[0], channel_block)
-    at = np.int32(filled)  # data, not a static argument: one program
-    rows, new_tails, new_accs, nclosed = [], [], [], 0
-    for g, c in enumerate(groups):
-        def program(tail, body, g=g):
-            nonlocal nclosed
-            power, nxt = channelize_stream(tail, body, coeffs, nint=1, **kw)
-            new_tails.append(nxt)
-            nclosed = (filled + power.shape[0]) // nint
-            acc = (jnp.zeros(power.shape[1:], jnp.float32) if accs is None
-                   else accs[g])
-            return integrate_carry(power, acc, at, nint=nint)
-
-        closed, acc = _put_group(put, tails[g],
-                                 voltages[c : c + groups.step], program)
-        new_accs.append(acc)
-        if nclosed:
-            rows.append(closed if nclosed == closed.shape[0]
-                        else closed[:nclosed])
-    if not nclosed:
-        return None, new_tails, new_accs
-    return (rows[0] if len(rows) == 1
-            else jnp.concatenate(rows, axis=-1)), new_tails, new_accs
+    if lanes:
+        groups = power.shape[-1]
+        total = groups * nint if nframes is None else nframes
+        rows, acc = _fold_groups(
+            power, acc, filled, nint,
+            last_valid=total - (groups - 1) * nint, group_axis=-1)
+        cb, nif, nfft, _ = rows.shape
+        return (jnp.transpose(rows, (3, 1, 0, 2)).reshape(
+            groups, nif, cb * nfft), acc)
+    total = power.shape[0]
+    whole, rest = divmod(total, nint)
+    parts = []
+    if whole:
+        bulk = power[:whole * nint].reshape(
+            (whole, nint) + power.shape[1:])
+        rows, acc = _fold_groups(bulk, acc, filled, nint, nint, 0)
+        parts.append(rows)
+    if rest:
+        rows, acc = _fold_groups(power[None, whole * nint:], acc, filled,
+                                 nint, rest, 0)
+        parts.append(rows)
+    return (parts[0] if len(parts) == 1 else jnp.concatenate(parts)), acc
 
 
 @functools.lru_cache(maxsize=None)
@@ -965,7 +1307,7 @@ def channels_per_dispatch(
     samples' ``(nchan, frames*nfft, npol, 2)`` — one
     :func:`channelize_stream` dispatch (kwargs ``kw``) may take inside
     ``budget_bytes`` of device memory: the ``channel_block`` for
-    :func:`channelize_blocked`.  Cached process-wide: a fresh reducer per
+    :func:`channelize_fanout`.  Cached process-wide: a fresh reducer per
     request or per bank asks once.
 
     The account is the compiler's own, not a model of today's kernels: a
@@ -992,7 +1334,7 @@ def channels_per_dispatch(
         jax.ShapeDtypeStruct((probe, (ntap - 1) * nfft), word),
         jax.ShapeDtypeStruct((probe, shape[1]), word),
         jax.ShapeDtypeStruct((ntap, nfft), jnp.float32),
-        **kw,
+        **kw,  # `lanes` among them where the leg takes that path
     ).compile().memory_analysis()
     tail = probe * (ntap - 1) * nfft * word.itemsize
     per_chan = -(-(m.argument_size_in_bytes + m.temp_size_in_bytes

@@ -157,6 +157,9 @@ class OutputRotation:
         self._stop = threading.Event()
         self._free: List[np.ndarray] = []  # released ring slabs (reuse)
         self._nslabs = 0
+        # Ring slabs resident at most: one more than the puts in flight,
+        # times the outputs one put brings (:meth:`put`).
+        self._slab_limit = self.depth + 1
         self._wd = observability.StallWatchdog(
             stall_timeout_s, name,
             what="a wedged device fetch would otherwise hang the stream",
@@ -193,7 +196,7 @@ class OutputRotation:
                         self._eof = True
                         self._cv.notify_all()
                     return
-                out, nbytes, payload, on_consumed, fetch, t_enq = item
+                out, nbytes, outs, on_consumed, t_enq = item
                 t_got = time.perf_counter()
                 # Queue-side lag distribution (ISSUE 5 tentpole #2): how
                 # long dispatches wait before the readback thread reaches
@@ -213,66 +216,76 @@ class OutputRotation:
                     # Output ready ⇒ inputs consumed: ingest slots refill.
                     on_consumed()
                 self._wd.beat()
-                if not fetch:
-                    # Sync-only put (the sharded plane's non-writer pod
-                    # processes, ISSUE 9): the dispatch had to be waited
-                    # out — it pins feed slots and orders the stream —
-                    # but nothing reads its bytes host-side, so no
-                    # device→host fetch happens and no slab is emitted.
-                    del out, item
-                    with self._cv:
-                        self._pending -= 1
-                        self._cv.notify_all()
-                    continue
-                recycled = False
-                # The fetch draws on the process's link budget
-                # (blit.device.HostLink): it waits for what is going up.
-                with host_link().fetch(getattr(out, "nbytes", 0), self._tl), \
-                        self._tl.stage("readback"):
-                    host = np.asarray(out)
-                    if self.reuse and (host.base is not None
-                                       or not host.flags.owndata):
-                        # The fetch was a zero-copy VIEW aliasing the jax
-                        # buffer (CPU backends): copy into a ring slab so
-                        # the buffer frees now and the slab recycles.  On
-                        # backends where the fetch itself allocated fresh
-                        # host memory (TPU/GPU D2H), that array IS the
-                        # slab — a second product-sized memcpy on this
-                        # (critical, slow-link) thread would buy nothing,
-                        # and the ring could never avoid the allocation
-                        # np.asarray already made.
-                        slab = self._take_slab(host.shape, host.dtype)
-                        if slab is None:
-                            return  # closed while waiting for a slab
-                        np.copyto(slab, host)
-                        host = slab
-                        recycled = True
-                self._tl.stages["readback"].bytes += host.nbytes
-                # Drop the device reference NOW — HBM frees as soon as the
-                # host copy exists, not when the product hits disk.
+                # Drop the dispatch's own reference NOW: what is fetched
+                # below frees as soon as its host copy exists, not when
+                # the product hits disk.
                 del out, item
+                # Nothing to fetch (the sharded plane's non-writer pod
+                # processes, ISSUE 9; a dispatch that closed no row): the
+                # dispatch had to be waited out — it pins feed slots and
+                # orders the stream — but nothing reads its bytes
+                # host-side, so no slab is emitted.
+                slabs = []
+                while outs:
+                    arr, payload = outs.pop(0)
+                    slab = self._fetch(arr, payload)
+                    del arr
+                    if slab is None:
+                        return  # closed while waiting for a slab
+                    slabs.append(slab)
                 self._wd.beat()
-                release = (
-                    (lambda s=host: self._release_slab(s))
-                    if recycled else None
-                )
                 # Per-chunk service latency (sync wait + host fetch) —
                 # the distribution behind the aggregate device/readback
                 # stage seconds.
-                self._tl.observe("out.chunk_latency_s",
-                                 time.perf_counter() - t_got)
+                if slabs:
+                    self._tl.observe("out.chunk_latency_s",
+                                     time.perf_counter() - t_got)
                 with self._cv:
                     self._pending -= 1
-                    self._done.append(OutputSlab(host, payload, release))
+                    self._done.extend(slabs)
                     self._cv.notify_all()
         except BaseException as e:  # noqa: BLE001 — forwarded to the consumer
             with self._cv:
                 self._exc = e
                 self._cv.notify_all()
 
+    def _fetch(self, out, payload) -> Optional[OutputSlab]:
+        """One device array to a host slab (``None``: closed meanwhile).
+        The fetch draws on the process's link budget
+        (blit.device.HostLink): it waits for what is going up."""
+        recycled = False
+        with host_link().fetch(getattr(out, "nbytes", 0), self._tl), \
+                self._tl.stage("readback") as sp:
+            if sp is not None and payload is not None:
+                sp.attrs["product"] = payload
+            host = np.asarray(out)
+            if self.reuse and (host.base is not None
+                               or not host.flags.owndata):
+                # The fetch was a zero-copy VIEW aliasing the jax
+                # buffer (CPU backends): copy into a ring slab so
+                # the buffer frees now and the slab recycles.  On
+                # backends where the fetch itself allocated fresh
+                # host memory (TPU/GPU D2H), that array IS the
+                # slab — a second product-sized memcpy on this
+                # (critical, slow-link) thread would buy nothing,
+                # and the ring could never avoid the allocation
+                # np.asarray already made.
+                slab = self._take_slab(host.shape, host.dtype)
+                if slab is None:
+                    return None
+                np.copyto(slab, host)
+                host = slab
+                recycled = True
+        self._tl.stages["readback"].bytes += host.nbytes
+        release = (
+            (lambda s=host: self._release_slab(s))
+            if recycled else None
+        )
+        return OutputSlab(host, payload, release)
+
     def _take_slab(self, shape, dtype) -> Optional[np.ndarray]:
         """A free ring slab matching ``(shape, dtype)`` — allocating up to
-        ``depth + 1`` resident slabs, retiring a mismatched free slab when
+        ``depth + 1`` resident slabs (per output of a put), retiring a mismatched free slab when
         at the limit (the final flush chunk is smaller than steady state),
         else waiting for the consumer to release one.  That wait is
         back-pressure from the sink, not a readback stall — the beat keeps
@@ -284,7 +297,7 @@ class OutputRotation:
                 for i, s in enumerate(self._free):
                     if s.shape == shape and s.dtype == dtype:
                         return self._free.pop(i)
-                if self._nslabs <= self.depth:
+                if self._nslabs < self._slab_limit:
                     self._nslabs += 1
                     alloc_shape = shape
                     break
@@ -337,24 +350,32 @@ class OutputRotation:
             self._wd.check("readback stalled",
                            active=self._thread.is_alive())
 
-    def put(self, out, *, nbytes: Optional[int] = None, payload=None,
+    def put(self, out, *, nbytes: Optional[int] = None,
             on_consumed: Optional[Callable[[], None]] = None,
-            fetch: bool = True) -> List[OutputSlab]:
+            outs: Optional[list] = None) -> List[OutputSlab]:
         """Enqueue an async-dispatched device array for readback; return
         the slabs completed so far (possibly empty), blocking while
         ``depth`` outputs are pending.  ``nbytes`` (the dispatch's input
         bytes) lands on the ``device`` stage; omitted ⇒ byte-free.
-        ``fetch=False`` syncs the dispatch (and fires ``on_consumed``)
-        without a device→host fetch — no slab is ever emitted for it."""
+        ``out`` is what is waited on (any tree of arrays) and ``outs`` the
+        ``(array, payload)`` pairs to fetch, each its own slab, in that
+        order: ``out`` itself, with no payload, by default; several where
+        a dispatch made several products; none (``[]``) to sync the
+        dispatch (and fire ``on_consumed``) without a device→host fetch —
+        no slab is ever emitted for it."""
+        if outs is None:
+            outs = [(out, None)]
         # A product whose fetch takes the whole host link is waited out
         # here: its slab is handed on at once, and the next chunk's
         # voltages do not get on the link in front of it.
-        depth = 1 if fetch and host_link().fetch_takes_all(
-            getattr(out, "nbytes", 0)) else self.depth
+        depth = 1 if any(host_link().fetch_takes_all(
+            getattr(a, "nbytes", 0)) for a, _ in outs) else self.depth
         with self._cv:
             self._check()
             self._pending += 1
-        self._in.put((out, nbytes, payload, on_consumed, fetch,
+            self._slab_limit = max(self._slab_limit,
+                                   (self.depth + 1) * len(outs))
+        self._in.put((out, nbytes, list(outs), on_consumed,
                       time.perf_counter()))
         ready: List[OutputSlab] = []
         with self._tl.wait("wait.out_slot") as w, self._cv:
@@ -467,8 +488,12 @@ class AsyncSink:
     def __init__(self, writer, *, depth: int = 2,
                  timeline: Optional[Timeline] = None,
                  name: str = "blit-sink", key=None,
-                 stall_timeout_s: Optional[float] = None):
+                 stall_timeout_s: Optional[float] = None,
+                 product: Optional[str] = None):
         self._writer = writer
+        # Which of a reduction's products this sink writes (an attr of
+        # its `write` spans; None: the only one).
+        self._product = product
         self._tl = timeline if timeline is not None else Timeline()
         self._tl.declare("wait.sink", "wait.sink_flush")
         self._key = key if key is not None else getattr(writer, "path", None)
@@ -527,7 +552,9 @@ class AsyncSink:
                 try:
                     faults.fire("sink.write", key=self._key)
                     t0 = time.perf_counter()
-                    with self._tl.stage("write", nbytes=slab.nbytes):
+                    with self._tl.stage("write", nbytes=slab.nbytes) as sp:
+                        if sp is not None and self._product is not None:
+                            sp.attrs["product"] = self._product
                         self._writer.append(slab)
                     # Per-append latency distribution (ISSUE 8 satellite:
                     # the bench tables report write p50/p99, not just the

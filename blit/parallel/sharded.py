@@ -397,19 +397,19 @@ def reduce_scan_sharded_to_files(
                 # staged voltage block (async H2D transfers included) —
                 # syncing one band's shard would not cover devices in
                 # OTHER band rows, and the producer would overwrite a
-                # pinned slab a transfer still reads.  fetch=False:
+                # pinned slab a transfer still reads.  outs=[]:
                 # ordering/back-pressure only, no bytes move; processes
                 # owning no band row ride the same put.
                 fed = (len(local) * nchan * win.ntime * npol * 2)
-                for slab in rot.put(out, nbytes=fed, fetch=False,
+                for slab in rot.put(out, nbytes=fed, outs=[],
                                     on_consumed=win.release):
                     route(slab)
                 # Readback: ADDRESSABLE shards only — one per owned band
                 # row (the stitched band is replicated across the row).
                 by_dev = {s.device: s.data for s in out.addressable_shards}
                 for b in mine:
-                    for slab in rot.put(by_dev[mesh.devices[b, 0]],
-                                        payload=b):
+                    shard = by_dev[mesh.devices[b, 0]]
+                    for slab in rot.put(shard, outs=[(shard, b)]):
                         route(slab)
             # Drain + close run INSIDE the stream stage — its __exit__
             # already covers them (unlike RawReducer._pump, whose stage
@@ -715,7 +715,7 @@ def search_scan_sharded_to_files(
                 # not cover the other local chips.  The later jfn
                 # dispatches read `part` (device-resident), never the
                 # slab, so releasing here is safe.
-                for slab in rot.put(part, fetch=False,
+                for slab in rot.put(part, outs=[],
                                     on_consumed=win.release):
                     route(slab)
                 rows = win.frames // nint
@@ -733,10 +733,9 @@ def search_scan_sharded_to_files(
                         for s in packed.addressable_shards
                     }
                     for bk in local:
+                        shard = by_dev[mesh.devices[bk]]
                         for slab in rot.put(
-                            by_dev[mesh.devices[bk]],
-                            payload=(widx, bk),
-                        ):
+                                shard, outs=[(shard, (widx, bk))]):
                             route(slab)
             for slab in rot.drain():
                 route(slab)

@@ -32,9 +32,21 @@ Design:
   ``-f 1048576 -t 51`` — or an explicit ``chunk_frames`` that ``nint`` does
   not divide), the integration is CARRIED: a float32 accumulator per
   channel group stays on the device from dispatch to dispatch
-  (:func:`blit.ops.channelize.integrate_carry`), and a row leaves the chip
+  (:func:`blit.ops.channelize.integrate_carry`, one fold for every
+  product), and a row leaves the chip
   only when it closes.  Trailing samples that can't fill an integration are
   dropped, as rawspec does.
+- A reduction makes ONE product or SEVERAL from the same read
+  (``RawReducer(also=...)``: rawspec's ``-f 1048576,8,1024 -t
+  51,128,3072``).  The chunk grid is one — the sample budget's, sized by
+  no product's ``nint`` — and the head is the largest ``nfft``'s; every
+  channel group goes up the host link once and each product's leg
+  (:class:`blit.ops.channelize.StreamLeg`: its own channeliser program,
+  filter state and carried integration) consumes the same device array
+  (:func:`blit.ops.channelize.channelize_fanout`).  Each product has a
+  writer and a write-behind sink of its own; a dispatch may close rows of
+  some products and none of others, and is still one put on the readback
+  rotation.  One product is a list of one through the same code.
 - Ingest is PIPELINED: a producer thread fills a rotation of
   ``prefetch_depth`` stable chunk buffers straight from the file (native
   threaded pread per block when built) while the device works on earlier
@@ -64,12 +76,12 @@ from blit.io.guppi import GuppiRaw, RawSource, open_raw, require_native_reader
 from blit.observability import Timeline, profile_trace
 from blit.ops.channelize import (
     STOKES_NIF,
-    channelize_blocked,
-    channelize_carry,
+    StreamLeg,
+    channelize_fanout,
     channels_per_dispatch,
+    lanes_block,
     output_header,
     pfb_coeffs,
-    split_tails,
     usable_frames,
 )
 
@@ -116,19 +128,25 @@ class ReductionStats:
 class _Chunk:
     """A filled chunk buffer handed to the consumer.  ``view`` aliases the
     rotation buffer; it stays valid until :meth:`release`, after which the
-    producer may refill it.  A stream's first chunk also carries its
-    ``head``, the ``(ntap-1)*nfft`` samples before ``view`` (the reducer's
-    own slab, valid to the stream's end); every later one has ``None``."""
+    producer may refill it.  ``leg_frames`` are the frames each product
+    takes of it (``frames``: the first product's).  A stream's first chunk
+    also carries its ``head``, the ``(ntap-1)*nfft`` samples before
+    ``view`` (of the largest ``nfft``; the reducer's own slab, valid to
+    the stream's end); every later one has ``None``."""
 
-    __slots__ = ("view", "frames", "head", "_idx", "_free")
+    __slots__ = ("view", "leg_frames", "head", "_idx", "_free")
 
-    def __init__(self, view: np.ndarray, frames: int, idx: int, free,
-                 head: Optional[np.ndarray] = None) -> None:
+    def __init__(self, view: np.ndarray, leg_frames: Sequence[int],
+                 idx: int, free, head: Optional[np.ndarray] = None) -> None:
         self.view = view
-        self.frames = frames
+        self.leg_frames = tuple(leg_frames)
         self.head = head
         self._idx = idx
         self._free = free
+
+    @property
+    def frames(self) -> int:
+        return self.leg_frames[0]
 
     @property
     def nbytes(self) -> int:
@@ -143,24 +161,57 @@ class _Chunk:
 
 
 class _StreamState:
-    """What a stream keeps on the device between dispatches, per channel
-    group: the filter state (``tails``: the last ``(ntap-1)*nfft`` samples
-    dispatched, the next dispatch's first) and, where the integration is
-    carried, the frames the open integration has so far (``filled``) and
-    its accumulators (``accs``).  ``channel_block`` is the
-    group size both were laid out for — the stream's first chunk's, kept
-    for every later one (a smaller flush chunk would fit more channels
-    per dispatch, and find no tail or accumulator of that shape).
-    ``tails``, ``accs`` and ``channel_block`` are ``None`` until the
-    stream's first dispatch."""
+    """What a stream keeps on the device between dispatches: per product a
+    :class:`blit.ops.channelize.StreamLeg` (per channel group its filter
+    state and, where the integration is carried, its accumulator and the
+    frames the open integration has), and ``channel_block``, the group
+    size all of it is laid out for — the stream's first chunk's, kept for
+    every later one (a smaller flush chunk would fit more channels per
+    dispatch, and find no tail or accumulator of that shape).  Both are
+    ``None`` until the stream's first dispatch."""
 
-    __slots__ = ("filled", "accs", "tails", "channel_block")
+    __slots__ = ("legs", "channel_block")
 
     def __init__(self) -> None:
-        self.filled = 0
-        self.accs: Optional[list] = None
-        self.tails: Optional[list] = None
+        self.legs: Optional[List[StreamLeg]] = None
         self.channel_block: Optional[int] = None
+
+    @property
+    def filled(self) -> int:
+        """Frames the first product's open integration holds."""
+        return self.legs[0].filled if self.legs else 0
+
+
+class _DirectSink:
+    """The synchronous output path's sink (``async_output=False``): the
+    :class:`blit.outplane.AsyncSink` interface with every append on the
+    caller's thread."""
+
+    def __init__(self, writer) -> None:
+        self._writer = writer
+        self.flush = getattr(writer, "flush", lambda: None)
+        self.close, self.abort = writer.close, writer.abort
+
+    def append(self, slab: np.ndarray, release=None) -> None:
+        self._writer.append(slab)
+        if release is not None:
+            release()
+
+    @property
+    def nsamps(self) -> int:
+        return self._writer.nsamps
+
+
+def _withdraw(writer) -> None:
+    """Take back a product its writer has already published: one of
+    several whose sibling then failed to finish (all complete, or none at
+    a final path)."""
+    from blit.integrity import manifest_path
+
+    final = getattr(writer, "final_path", None)
+    for path in (final, final and manifest_path(final)):
+        if path and os.path.exists(path):
+            os.unlink(path)
 
 
 _ROT_ERR = object()  # producer-exception marker on the filled queue
@@ -424,10 +475,21 @@ class RawReducer:
     # stage timeline (published as tune.rec_* gauges; persisted as a
     # tuning profile when BLIT_TUNE_ONLINE=1).
     tune_online: bool = True
+    # Further products from the SAME read, each ``(nfft, nint)``: rawspec's
+    # ``-f 1048576,8,1024 -t 51,128,3072`` is ``nfft=1048576, nint=51,
+    # also=((8, 128), (1024, 3072))``.  Every channel group goes up once
+    # and feeds each product's own channeliser, filter state, integration
+    # and writer (:meth:`reduce_to_files`); each product is what the
+    # single reduction for its ``(nfft, nint)`` makes of the recording.
+    # The chunk grid is ONE, the per-dispatch sample budget's, sized by no
+    # product's ``nint``: ``chunk_frames`` counts frames of ``nfft`` (the
+    # first product's) and must hold whole frames of every product.
+    also: Tuple[Tuple[int, int], ...] = ()
 
     def __post_init__(self):
         from blit.ops.narrow import check_quant
 
+        self.also = tuple((int(f), int(t)) for f, t in self.also)
         if os.environ.get("BLIT_SYNC_OUTPUT"):
             self.async_output = False
         check_quant(self.nbits)
@@ -459,7 +521,10 @@ class RawReducer:
             "out_depth": "explicit" if self.out_depth is not None
             else "default",
         }
-        if (self.chunk_frames is None or self.prefetch_depth is None
+        # A profile is keyed by ONE product's shape: several products
+        # take the defaults.
+        if not self.also and (
+                self.chunk_frames is None or self.prefetch_depth is None
                 or self.out_depth is None):
             from blit import tune as _tune
 
@@ -483,6 +548,12 @@ class RawReducer:
         budget = dispatch_frames(self.nfft)
         fold = fold_frames(self.nfft, self.nint)
         fits = fold == self.nint
+        if self.also and self.chunk_frames is None:
+            # The shared grid: the sample budget's (one frame of the
+            # largest nfft where that is more), whatever the nints.
+            self.chunk_frames = max(
+                _DISPATCH_SAMPLES, *(f for f, _ in self.products)
+            ) // self.nfft
         if self.chunk_frames is None:
             # An integration the budget cannot hold (rawspec's -f 1048576
             # -t 51: 51 frames against 8) does not size the dispatch: the
@@ -500,27 +571,74 @@ class RawReducer:
                 self.chunk_frames = min(self.chunk_frames, budget)
         if self.chunk_frames < 1:
             raise ValueError(f"chunk_frames={self.chunk_frames} must be >= 1")
-        if self.fqav_by > 1 and self.nfft % self.fqav_by:
-            # Averaging groups must not straddle coarse-channel boundaries
-            # (despike/nfpc consumers key on fine-per-coarse counts).
-            raise ValueError(
-                f"fqav_by={self.fqav_by} does not divide nfft={self.nfft}"
-            )
-        self._pfb_coeffs = None  # built lazily by the _coeffs property
+        for nfft, nint in self.products:
+            if nfft < 2 or nint < 1:
+                raise ValueError(f"bad product (nfft={nfft}, nint={nint})")
+            if self.fqav_by > 1 and nfft % self.fqav_by:
+                # Averaging groups must not straddle coarse-channel
+                # boundaries (despike/nfpc consumers key on
+                # fine-per-coarse counts).
+                raise ValueError(
+                    f"fqav_by={self.fqav_by} does not divide nfft={nfft}"
+                )
+            if self._chunk_samples % nfft:
+                raise ValueError(
+                    f"chunk_frames={self.chunk_frames} of nfft={self.nfft} "
+                    f"holds no whole number of nfft={nfft} frames")
+        self._pfb_coeffs: Dict[int, object] = {}  # nfft -> device bank
 
     @property
-    def _coeffs(self):
+    def products(self) -> Tuple[Tuple[int, int], ...]:
+        """Every ``(nfft, nint)`` this reduction makes, the first first."""
+        return ((self.nfft, self.nint),) + self.also
+
+    @property
+    def _chunk_samples(self) -> int:
+        """New samples per coarse channel in one dispatch."""
+        return self.chunk_frames * self.nfft
+
+    @property
+    def _head_samples(self) -> int:
+        """A stream's head: the filter state of the largest nfft."""
+        return (self.ntap - 1) * max(f for f, _ in self.products)
+
+    def _head_frames(self, nfft: int) -> int:
+        """Frames of ``nfft`` the head holds beyond that product's own
+        filter state: its data, reduced with the first dispatch."""
+        return (self._head_samples - (self.ntap - 1) * nfft) // nfft
+
+    def _leg_carries(self, k: int) -> bool:
+        """Does product ``k``'s integration straddle dispatches (module
+        docstring)?  Not where every dispatch holds whole integrations:
+        the chunk's frames and the head's."""
+        nfft, nint = self.products[k]
+        return ((self._chunk_samples // nfft) % nint != 0
+                or self._head_frames(nfft) % nint != 0)
+
+    def _lanes(self, k: int, npol: int) -> int:
+        """Product ``k``'s block on the small-nfft path (0: not taken;
+        the shape decides, :func:`blit.ops.channelize.lanes_block`)."""
+        if not self._leg_carries(k):
+            return 0
+        return lanes_block(*self.products[k], npol, self.ntap,
+                           fqav_by=self.fqav_by, dtype=self.dtype)
+
+    def _coeffs_for(self, nfft: int):
         """PFB coefficient bank, built (and device-shipped) on FIRST
         compute use — not at construction.  Throwaway probe reducers
         (scan resolves tuning knobs through one) must not
         pay a multi-million-coefficient sinc*window build plus device
         transfer just to read provenance."""
-        if self._pfb_coeffs is None:
+        if nfft not in self._pfb_coeffs:
             import jax.numpy as jnp
 
-            self._pfb_coeffs = jnp.asarray(
-                pfb_coeffs(self.ntap, self.nfft, self.window))
-        return self._pfb_coeffs
+            self._pfb_coeffs[nfft] = jnp.asarray(
+                pfb_coeffs(self.ntap, nfft, self.window))
+        return self._pfb_coeffs[nfft]
+
+    @property
+    def _coeffs(self):
+        return self._coeffs_for(self.nfft)
 
     def _tune_fingerprint_kw(self) -> Dict:
         """The (rig, workload-shape) fingerprint components of this
@@ -616,14 +734,15 @@ class RawReducer:
         )
 
     # -- core streaming ---------------------------------------------------
-    @property
-    def _channelize_kw(self) -> Dict:
-        """The exact channelize kwarg set (jax.jit caches per call
-        signature, so the kwarg set must be bit-stable across callers —
-        fqav_by only appears when active, keeping the common-case cache
-        signature identical to callers that never heard of it)."""
+    def _leg_kw(self, k: int, nint: Optional[int] = None) -> Dict:
+        """The exact channelize kwarg set of product ``k`` (jax.jit caches
+        per call signature, so the kwarg set must be bit-stable across
+        callers — fqav_by only appears when active, keeping the
+        common-case cache signature identical to callers that never heard
+        of it)."""
+        nfft, own = self.products[k]
         kw = dict(
-            nfft=self.nfft, ntap=self.ntap, nint=self.nint,
+            nfft=nfft, ntap=self.ntap, nint=own if nint is None else nint,
             stokes=self.stokes, fft_method=self.fft_method,
         )
         if self.fqav_by > 1:
@@ -632,42 +751,71 @@ class RawReducer:
             kw["dtype"] = self.dtype
         return kw
 
+    @property
+    def _channelize_kw(self) -> Dict:
+        return self._leg_kw(0)
+
+    def _legs(self, npol: int) -> List[StreamLeg]:
+        """A stream's legs, one per product, with nothing on the device
+        yet.  The first keeps the program name a reduction of one product
+        has always had; the others' device work is named after them."""
+        legs = []
+        for k, (nfft, nint) in enumerate(self.products):
+            kw = self._leg_kw(k)
+            del kw["nint"]
+            legs.append(StreamLeg(
+                self._coeffs_for(nfft), nint=nint,
+                carried=self._leg_carries(k), lanes=self._lanes(k, npol),
+                name="channelize_stream" if k == 0 else f"channelize_{k:04d}",
+                label=f"{k:04d}" if self.also else None, **kw))
+        return legs
+
     def _channel_block(self, shape: Tuple[int, int, int, int]) -> int:
         """Coarse channels per device dispatch for chunks of ``shape`` (a
         chunk's new samples): all of them where the backend reports no
         memory limit (the CPU), else as many as the device holds beside
         what stays resident — every group's filter state, the products
         still in readback flight (``out_depth - 1``), this chunk's
-        per-group products and their concatenation.  A 64-channel hi-res
-        chunk is ~2 GB of int8 whose f32 intermediates alone exceed a
-        16 GB chip.  Grouping changes no
+        per-group products and their concatenation; all of that for every
+        product, whose programs run one after the other on the same
+        uploaded group (the fewest channels any of them fits).  A
+        64-channel hi-res chunk is ~2 GB of int8 whose f32 intermediates
+        alone exceed a 16 GB chip.  Grouping changes no
         arithmetic — every coarse channel reduces on its own
-        (``channelize_blocked``'s golden test) — though a backend may round
+        (``channelize_fanout``'s golden test) — though a backend may round
         a differently-batched program differently in the last bit."""
         nchan = shape[0]
         limit = hbm_bytes_limit()
         if limit is None:
             return nchan
-        frames = shape[1] // self.nfft
-        row = (STOKES_NIF[self.stokes] * nchan
-               * (self.nfft // self.fqav_by) * 4)
-        # Every group's filter state (the probe leaves its own out).
-        tails = nchan * (self.ntap - 1) * self.nfft * shape[2] * shape[3]
-        kw = self._channelize_kw
-        if self._carries:
-            # The accumulators (the old set lives until the new one is
-            # written) beside the rows a dispatch may close; the probe
-            # program is the frame-major one the carry reads.
-            product = (self.nint - 1 + frames) // self.nint * row
-            resident = (tails + 2 * row
-                        + (max(2, self.out_depth) + 1) * product)
-            kw = dict(kw, nint=1)
-        else:
-            product = frames // self.nint * row
-            resident = tails + (max(2, self.out_depth) + 1) * product
-        cb = channels_per_dispatch(
-            tuple(shape), int(_HBM_FRACTION * limit) - resident, **kw,
-        )
+        resident, probes = 0, []
+        for k, (nfft, nint) in enumerate(self.products):
+            frames = shape[1] // nfft
+            row = (STOKES_NIF[self.stokes] * nchan
+                   * (nfft // self.fqav_by) * 4)
+            # Every group's filter state (the probe leaves its own out).
+            resident += nchan * (self.ntap - 1) * nfft * shape[2] * shape[3]
+            if self._leg_carries(k):
+                # The accumulators (the old set lives until the new one
+                # is written) beside the rows a dispatch may close (the
+                # first also those of the head's frames); the probe
+                # program is the frame-major one the carry reads.
+                head = self._head_frames(nfft)
+                product = ((nint - 1 + frames) // nint
+                           + (nint - 1 + head) // nint * bool(head)) * row
+                resident += 2 * row
+                kw = self._leg_kw(k, nint=1)
+                lanes = self._lanes(k, shape[2])
+                if lanes:
+                    kw["lanes"] = lanes
+            else:
+                product = frames // nint * row
+                kw = self._leg_kw(k)
+            resident += (max(2, self.out_depth) + 1) * product
+            probes.append(kw)
+        budget = int(_HBM_FRACTION * limit) - resident
+        cb = min(channels_per_dispatch(tuple(shape), budget, **kw)
+                 for kw in probes)
         log.info("chunk %s: %d of %d coarse channels per dispatch "
                  "(device limit %d B, %d B resident)",
                  shape, cb, nchan, limit, resident)
@@ -675,63 +823,80 @@ class RawReducer:
 
     @property
     def _carries(self) -> bool:
-        """Does an integration straddle dispatches (module docstring)?"""
-        return self.chunk_frames % self.nint != 0
+        """Does the first product's integration straddle dispatches?"""
+        return self._leg_carries(0)
 
     def _dispatch(self, chunk: _Chunk, st: _StreamState):
-        """One host chunk → ``(product, token)``, dispatched async in as
+        """One host chunk → ``(outs, token)``, dispatched async in as
         many channel groups as :meth:`_channel_block` says for the
         stream's first chunk (each group's new samples go up on their own,
-        so the whole chunk is never resident as one input; its filter
-        state is on the chip already — ``st.tails``, the previous
+        ONCE, and every product's program consumes them there, so the
+        whole chunk is never resident as one input; its filter state is
+        on the chip already — each leg's ``tails``, the previous
         dispatch's output, or the stream's head going up once).  ``token``
         is ready once the chunk's input has been consumed (the next tails
-        leave the same programs).  Where the integration is carried the
-        product is the rows that closed in this chunk — ``None`` when none
+        leave the same programs).  ``outs`` are the row batches that
+        closed, ``(product index, rows)`` in product order: everything a
+        product integrated inside its program, the rows that closed in
+        this chunk where its integration is carried — nothing when none
         did.  ``st`` is the stream's own (a stream starts on a row
         boundary — ``skip_frames`` is whole rows — and with a head of its
         own) and moves on."""
-        body, frames = chunk.view, chunk.frames
-        put = functools.partial(host_link().put, timeline=self.timeline)
-        if chunk.head is not None:  # the stream's first dispatch
-            st.channel_block = self._channel_block(body.shape)
-            st.tails = split_tails(chunk.head, st.channel_block)
-        # Filter state by where it comes from: up from the host (once per
-        # group per stream) or left on the chip by the last dispatch.
-        self.timeline.mark(
-            "state.carry" if chunk.head is None else "state.head",
-            sum(t.nbytes for t in st.tails), calls=len(st.tails))
-        if not self._carries:
-            out, st.tails = channelize_blocked(
-                body, self._coeffs, st.tails,
-                channel_block=st.channel_block, put=put,
-                **self._channelize_kw)
-            self._output_frames += frames
-            return out, out
-        kw = self._channelize_kw
-        nint = kw.pop("nint")
-        rows, st.tails, st.accs = channelize_carry(
-            body, self._coeffs, st.tails, st.accs, st.filled,
-            channel_block=st.channel_block, nint=nint, put=put, **kw)
-        st.filled = (st.filled + frames) % nint
-        if st.filled:  # the dispatch left an integration open
-            self.timeline.mark("integrate.carry",
-                               sum(a.nbytes for a in st.accs))
-        if rows is None:
-            return None, st.accs
-        self.timeline.mark("integrate.emit", rows.nbytes,
-                           calls=rows.shape[0])
-        self._output_frames += rows.shape[0] * nint
-        return rows, rows
+        first = chunk.head is not None
+        if first:
+            # The stream's first dispatch builds the legs (a coefficient
+            # bank per nfft: half a second at 2^20) while the producer
+            # reads ahead, not before it starts.
+            st.legs = self._legs(chunk.view.shape[2])
+            st.channel_block = self._channel_block(chunk.view.shape)
+        rows, token = channelize_fanout(
+            chunk.view, st.legs, list(chunk.leg_frames),
+            channel_block=st.channel_block, head=chunk.head,
+            put=functools.partial(host_link().put, timeline=self.timeline),
+            # Programs that consumed a group they did not upload, and the
+            # H2D bytes that were not sent again for them.
+            shared=lambda programs, nbytes: self.timeline.mark(
+                "fanout.share", nbytes, calls=programs))
+        outs = []
+        for k, (leg, frames) in enumerate(zip(st.legs, chunk.leg_frames)):
+            if not frames:
+                continue
+            # Filter state by where it came from: up from the host (once
+            # per group per stream) or left on the chip by the last
+            # dispatch.
+            self.timeline.mark(
+                "state.head" if first else "state.carry",
+                sum(t.nbytes for t in leg.tails), calls=len(leg.tails))
+            if leg.carried and leg.filled:  # left an integration open
+                self.timeline.mark("integrate.carry",
+                                   sum(a.nbytes for a in leg.accs))
+            for batch in rows[k]:
+                if leg.carried:
+                    self.timeline.mark("integrate.emit", batch.nbytes,
+                                       calls=batch.shape[0])
+                if leg.label is not None:  # per product, where several
+                    self.timeline.mark(f"integrate.emit.{leg.label}",
+                                       batch.nbytes, calls=batch.shape[0])
+                if k == 0:
+                    self._output_frames += batch.shape[0] * leg.nint
+                outs.append((k, batch))
+        return outs, token
 
     def _run_chunk(self, chunk: _Chunk, st: _StreamState
-                   ) -> Optional[np.ndarray]:
+                   ) -> List[Tuple[int, np.ndarray]]:
         import jax
 
         with self.timeline.stage("device", nbytes=chunk.nbytes):
-            out, token = self._dispatch(chunk, st)
+            outs, token = self._dispatch(chunk, st)
             jax.block_until_ready(token)
-            return None if out is None else np.asarray(out)
+            return [(k, np.asarray(out)) for k, out in outs]
+
+    def _one_product(self, what: str) -> None:
+        if self.also:
+            raise ValueError(
+                f"{what} yields ONE product and this reducer makes "
+                f"{len(self.products)}: several go to files "
+                "(reduce_to_files)")
 
     def stream(self, raw: GuppiRaw, skip_frames: int = 0) -> Iterator[np.ndarray]:
         """Yield filterbank slabs ``(nspectra, nif, nchan*nfft)`` covering
@@ -753,37 +918,51 @@ class RawReducer:
         caller's to keep (never recycled under it); slab VALUES are
         byte-identical to the synchronous path's.
         """
+        self._one_product("stream()")
         with profile_trace(self.trace_logdir), observability.span(
             "reduce.stream", nfft=self.nfft, path=getattr(raw, "path", "")
         ):
-            if not self.async_output:
-                st = _StreamState()
-                for chunk in self._chunks(raw, skip_frames):
-                    try:
-                        out = self._run_chunk(chunk, st)
-                    finally:
-                        chunk.release()
-                    if out is not None:
-                        yield self._narrow_host(out)
-                self._retire_staging()
-                return
-            for slab in self._stream_async(raw, skip_frames, reuse=False,
-                                           narrow=True):
-                data = slab.data
-                slab.release()
+            for _, data, release in self._slabs(raw, skip_frames,
+                                                reuse=False):
+                if release is not None:
+                    release()
                 yield data
             # Normal exhaustion only: every dispatch synced, so the chunk
             # buffers are safe to hand to the next reducer via the pool.
             self._retire_staging()
 
+    def _slabs(self, raw: GuppiRaw, skip_frames: int, reuse: bool,
+               tuner=None) -> Iterator[Tuple[int, np.ndarray, object]]:
+        """Every product slab of a reduction in stream order, narrowed to
+        the product's on-disk form: ``(product index, data, release)``.
+        On the synchronous path (``async_output=False``, the seed's
+        serialized shape kept for A/B drills) each chunk is waited out
+        and narrowed on the host; else :meth:`_stream_async`, narrowed on
+        the device."""
+        if self.async_output:
+            for slab in self._stream_async(raw, skip_frames, reuse=reuse,
+                                           tuner=tuner):
+                # payload: the product's label (None: the only one)
+                yield int(slab.payload or 0), slab.data, slab.release
+            return
+        st = _StreamState()
+        for chunk in self._chunks(raw, skip_frames):
+            try:
+                outs = self._run_chunk(chunk, st)
+            finally:
+                chunk.release()
+            for k, out in outs:
+                yield k, self._narrow_host(out), None
+
     def _stream_async(self, raw: GuppiRaw, skip_frames: int,
-                      reuse: bool, narrow: bool = False,
-                      tuner=None) -> Iterator["object"]:
+                      reuse: bool, tuner=None) -> Iterator["object"]:
         """The overlapped streaming core behind :meth:`stream` and
         :meth:`_pump`: async-dispatch each chunk, hand the in-flight
-        output to an :class:`blit.outplane.OutputRotation` readback
+        outputs to an :class:`blit.outplane.OutputRotation` readback
         thread, and yield :class:`~blit.outplane.OutputSlab` handles in
-        stream order.  ``reuse=True`` recycles host slabs through the
+        stream order (``payload``: the product's label, which its
+        ``readback`` span carries as attr ``product``).  ``reuse=True``
+        recycles host slabs through the
         rotation's bounded ring (callers must release only after the
         slab's bytes are consumed — the AsyncSink wiring); ``reuse=False``
         yields caller-owned arrays (the public :meth:`stream` contract).
@@ -792,12 +971,13 @@ class RawReducer:
         over): with readback depth ``d``, ``put(chunk_w)`` returns once
         chunk ``w-(d-1)`` has been fetched — chunk ``w`` stays in
         un-synchronized flight while the consumer dispatches ``w+1``, so
-        compute and readback overlap.  Un-synced dispatches pin their
+        compute and readback overlap.  A chunk is ONE put however many
+        products closed rows in it.  Un-synced dispatches pin their
         ingest slots (released at ``block_until_ready``, before the
         fetch), so the chunk rotation runs one slot wider
         (``extra_slots=1``) to keep a slot free for the producer's
         read-ahead.  A chunk of a carried reduction that closed no row
-        is put sync-only (``fetch=False``): its slot is released the same
+        is put sync-only: its slot is released the same
         way, and nothing of it crosses to the host.
 
         What is on the host link at any instant is a channel group or
@@ -814,7 +994,7 @@ class RawReducer:
             timeline=self.timeline, reuse=reuse, name="blit-readback",
             stall_timeout_s=self.output_stall_timeout_s,
         )
-        do_narrow = narrow and self.nbits < 32
+        do_narrow = self.nbits < 32
         if do_narrow:
             from blit.ops.narrow import narrow_device
         st = _StreamState()
@@ -822,21 +1002,23 @@ class RawReducer:
             extra = readback_extra_slots(depth, self.prefetch_depth)
             for chunk in self._chunks(raw, skip_frames, extra_slots=extra):
                 with self.timeline.stage("dispatch", byte_free=True):
-                    out, token = self._dispatch(chunk, st)
-                    if do_narrow and out is not None:
+                    outs, token = self._dispatch(chunk, st)
+                    if do_narrow and outs:
                         # Quantize to the product's on-disk integer form
                         # BEFORE D2H: 4x (nbits=8) / 2x (nbits=16) fewer
                         # bytes cross the slow link, bit-identical to the
                         # sync path's host-side narrowing
                         # (blit/ops/narrow.py).
-                        out = token = narrow_device(
+                        outs = [(k, narrow_device(
                             out, self.nbits, self.quant_scale,
-                            self.quant_offset)
+                            self.quant_offset)) for k, out in outs]
+                        token = [out for _, out in outs]
                 if tuner is not None:
                     tuner.observe_chunk()
                 for slab in rot.put(token, nbytes=chunk.nbytes,
                                     on_consumed=chunk.release,
-                                    fetch=out is not None):
+                                    outs=[(out, st.legs[k].label)
+                                          for k, out in outs]):
                     yield slab
             # The chunker's "stream" stage closed when its generator
             # exhausted above; the readback tail it no longer covers is
@@ -849,16 +1031,16 @@ class RawReducer:
         finally:
             rot.close()
 
-    def _pump(self, raw: GuppiRaw, writer, skip_frames: int = 0) -> int:
-        """Drive the full reduction chain into a product writer — host
+    def _pump(self, raw: GuppiRaw, writer, skip_frames: int = 0):
+        """Drive the full reduction chain into the product writer(s) — host
         read → H2D → compute → D2H → disk write, every leg on its own
-        thread (ingest producer / main dispatch / readback / sink) with
-        back-pressure end to end — and finalize the writer.  Returns the
-        spectra written.  On error the writer is ``abort()``ed (its own
-        crash contract: ``.partial`` dropped, resumable file + cursor
-        kept) and the error re-raised.  The synchronous fallback
-        (``async_output=False``) keeps the seed's serialized shape for
-        A/B drills.
+        thread (ingest producer / main dispatch / readback / one sink per
+        product) with back-pressure end to end — and finalize them.
+        ``writer`` is one writer, or a list with one per product; returns
+        the spectra written, likewise.  On error every writer is
+        ``abort()``ed (its own crash contract: ``.partial`` dropped,
+        resumable file + cursor kept), nothing stays at a final path, and
+        the error is re-raised.
 
         Runs under :func:`blit.monitor.publishing` — every reduction
         (batch, stream, serve, search) streams its live timeline to the
@@ -866,29 +1048,21 @@ class RawReducer:
         disabled, the scope costs two env reads per reduction."""
         from blit.monitor import publishing
 
+        many = isinstance(writer, (list, tuple))
         with publishing(self.timeline):
-            return self._pump_impl(raw, writer, skip_frames)
+            nsamps = self._pump_impl(raw, list(writer) if many else [writer],
+                                     skip_frames)
+        return nsamps if many else nsamps[0]
 
-    def _pump_impl(self, raw: GuppiRaw, writer, skip_frames: int = 0
-                   ) -> int:
-        if not self.async_output:
-            try:
-                # stream() opens the profiler trace itself on this path,
-                # and narrows quantized products HOST-side — the twin of
-                # the async plane's on-device narrowing (byte-identical,
-                # blit/ops/narrow.py).
-                for slab in self.stream(raw, skip_frames=skip_frames):
-                    writer.append(slab)
-                writer.close()
-            except BaseException:
-                writer.abort()
-                raise
-            return writer.nsamps
-
+    def _pump_impl(self, raw: GuppiRaw, writers: list, skip_frames: int = 0
+                   ) -> List[int]:
         from blit.outplane import AsyncSink
 
+        if len(writers) != len(self.products):
+            raise ValueError(f"{len(self.products)} products, "
+                             f"{len(writers)} writers")
         tuner = None
-        if self.tune_online:
+        if self.tune_online and self.async_output and not self.also:
             from blit.tune import OnlineTuner
 
             tuner = OnlineTuner(
@@ -899,36 +1073,51 @@ class RawReducer:
                 # A carried integration does not bind the chunk size.
                 nint=1 if self._carries else self.nint,
             )
-        sink = AsyncSink(
-            writer, depth=max(2, self.out_depth),
-            timeline=self.timeline,
-            stall_timeout_s=self.output_stall_timeout_s,
-        )
+        if self.async_output:
+            sinks = [AsyncSink(
+                w, depth=max(2, self.out_depth), timeline=self.timeline,
+                stall_timeout_s=self.output_stall_timeout_s,
+                **(dict(name=f"blit-sink.{k:04d}", product=f"{k:04d}")
+                   if self.also else {}))
+                for k, w in enumerate(writers)]
+        else:
+            sinks = [_DirectSink(w) for w in writers]
+        closed = []
         try:
             with profile_trace(self.trace_logdir), observability.span(
                 "reduce.pump", nfft=self.nfft,
-                out=str(getattr(writer, "path", "")),
+                out=str(getattr(writers[0], "path", "")),
             ):
-                for slab in self._stream_async(raw, skip_frames,
-                                               reuse=True,
-                                               narrow=True, tuner=tuner):
-                    sink.append(slab.data, release=slab.release)
+                for k, data, release in self._slabs(
+                        raw, skip_frames, reuse=True, tuner=tuner):
+                    sinks[k].append(data, release=release)
                 # Final flush barrier + writer finalization; the write
                 # tail is streaming wall time like the readback tail.
+                # Every product's last byte is written before the first
+                # is renamed into place: all complete, or none there.
                 t0 = time.perf_counter()
-                sink.close()
+                for sink in sinks:
+                    sink.flush()
+                for sink in sinks:
+                    sink.close()
+                    closed.append(sink)
                 self.timeline.stages["stream"].seconds += (
                     time.perf_counter() - t0
                 )
         except BaseException:
-            sink.abort()
+            for sink, w in zip(sinks, writers):
+                if sink in closed:
+                    _withdraw(w)
+                else:
+                    sink.abort()
             raise
-        self.timeline.overlap_efficiency()
+        if self.async_output:
+            self.timeline.overlap_efficiency()
         self._retire_staging()
         if tuner is not None:
             tuner.maybe_persist(tuned_nchan=self._stream_nchan or 0,
                                 **self._tune_fingerprint_kw())
-        return sink.nsamps
+        return [sink.nsamps for sink in sinks]
 
     def _producer(
         self,
@@ -964,22 +1153,26 @@ class RawReducer:
         fixed-shape device chunks.
 
         The stream's first ``(ntap-1)*nfft`` samples after ``skip_frames``
-        — its HEAD, the filter state of its first frame — are read into a
-        slab of their own and ride with the first chunk emitted; after
-        that the device holds the filter state (:class:`_StreamState`).
+        — its HEAD, the filter state of its first frame (of the largest
+        ``nfft`` where there are several products; the others find their
+        own, shorter state at its start and data after it) — are read
+        into a slab of their own and ride with the first chunk emitted;
+        after that the device holds the filter state
+        (:class:`_StreamState`).
         Buffer ``j`` is ``chunk_frames * nfft`` NEW samples per channel,
         so a channel group ``buf[c:c+cb]`` is one contiguous run of host
         memory.  Every sample is read from the source exactly once,
         directly into place (``read_into(dst, t0, take)`` copies samples
         ``[t0, t0+take)`` of the block into ``dst[:, :take]``); payloads
-        are ``(frames, samples, head or None)``.
+        are ``(frames of each product, samples, head or None)``.
         """
         from blit import hostmem
 
-        nfft, ntap, nint = self.nfft, self.ntap, self.nint
-        chunk_samps = self.chunk_frames * nfft
-        state = (ntap - 1) * nfft
+        nfft, ntap = self.nfft, self.ntap
+        chunk_samps = self._chunk_samples
+        state = self._head_samples
         to_skip = skip_frames * nfft
+        whole = tuple(chunk_samps // f for f, _ in self.products)
 
         def slab(shape, cached: Optional[np.ndarray]) -> np.ndarray:
             """``cached`` if it has ``shape``, else a page-aligned,
@@ -997,7 +1190,7 @@ class RawReducer:
         head_left = state  # samples of the head still to read
         cur: Optional[int] = None
         filled = 0
-        emitted = 0  # frames in the chunks emitted so far
+        emitted = 0  # chunks emitted so far
         for hdr, nt, read_into in feed:
             if to_skip >= nt:
                 to_skip -= nt
@@ -1046,17 +1239,33 @@ class RawReducer:
                 t0 += take
                 nt -= take
                 if filled == chunk_samps:
-                    rot.emit(cur, (self.chunk_frames, chunk_samps, head))
-                    emitted += self.chunk_frames
+                    rot.emit(cur, (whole, chunk_samps, head))
+                    emitted += 1
                     head, cur = None, None
         if cur is not None and filled > 0:
-            # Flush: the whole frames remaining, up to the last that
-            # closes an integration (one carried in from earlier chunks
-            # counts with the frames it already holds).
-            frames = usable_frames(state + filled, nfft, ntap, nint,
-                                   open_frames=emitted % nint)
-            if frames > 0:
-                rot.emit(cur, (frames, frames * nfft, head))
+            # Flush: of each product the whole frames remaining, up to
+            # the last that closes an integration (one carried in from
+            # earlier chunks counts with the frames it already holds);
+            # the samples of the one that takes most go up.
+            frames = tuple(
+                usable_frames(
+                    (ntap - 1) * f + filled, f, ntap, t,
+                    open_frames=(self._head_frames(f)
+                                 + emitted * (chunk_samps // f)) % t)
+                for f, t in self.products)
+            if any(frames):
+                rot.emit(cur, (frames, max(
+                    n * f for n, (f, _) in zip(frames, self.products)),
+                    head))
+                head = None
+        if self.also and head is not None:
+            # The head never went up.  A single product ends here with no
+            # row; of several the smaller would have had rows out of the
+            # head itself.
+            raise ValueError(
+                "the recording ends inside (or with) the filter state of "
+                f"nfft={max(f for f, _ in self.products)}: several "
+                "products from one read need samples beyond it")
 
     def _chunks(
         self, raw: GuppiRaw, skip_frames: int = 0, extra_slots: int = 0
@@ -1122,19 +1331,18 @@ class RawReducer:
             st = _StreamState()
 
             def retire() -> float:
-                done, s, token = pending.popleft()
+                done, sums, token = pending.popleft()
                 # sync: the device is done with the input
-                part = float(s) if s is not None else 0.0
+                part = sum(float(s) for s in sums)
                 jax.block_until_ready(token)
                 done.release()
                 return part
 
             for chunk in self._chunks(raw):
                 with self.timeline.stage("device", nbytes=chunk.nbytes):
-                    out, token = self._dispatch(chunk, st)
+                    outs, token = self._dispatch(chunk, st)
                     pending.append(
-                        (chunk, None if out is None else jnp.sum(out),
-                         token))
+                        (chunk, [jnp.sum(out) for _, out in outs], token))
                 while len(pending) >= max(2, self.prefetch_depth):
                     total += retire()
             while pending:
@@ -1169,9 +1377,10 @@ class RawReducer:
             raise ValueError(f"empty or fully truncated RAW file: {raw.path}")
         return raw, self.header_for(raw)
 
-    def header_for(self, raw: GuppiRaw) -> Dict:
+    def header_for(self, raw: GuppiRaw, product: int = 0) -> Dict:
+        nfft, nint = self.products[product]
         hdr = output_header(
-            raw.header(0), nfft=self.nfft, nint=self.nint, stokes=self.stokes
+            raw.header(0), nfft=nfft, nint=nint, stokes=self.stokes
         )
         if self.fqav_by > 1:
             from blit.ops.fqav import fqav_range
@@ -1181,7 +1390,7 @@ class RawReducer:
             )
             hdr.update(
                 fch1=fch1, foff=foff, nchans=nchans,
-                nfpc=self.nfft // self.fqav_by,
+                nfpc=nfft // self.fqav_by,
             )
         return hdr
 
@@ -1191,6 +1400,7 @@ class RawReducer:
         → ``(filterbank_header, data)`` with data ``(nsamps, nif, nchans)``."""
         from blit.ops.narrow import NARROW_DTYPES
 
+        self._one_product("reduce()")
         raw, hdr = self._open_validated(raw_src)
         with observability.span("reduce", nfft=self.nfft):
             slabs = list(self.stream(raw))
@@ -1229,6 +1439,7 @@ class RawReducer:
         if out_path.endswith((".h5", ".hdf5")):
             from blit.io.fbh5 import FBH5Writer
 
+            self._one_product("an .h5 product")
             if self.nbits != 32:
                 raise ValueError("nbits=8/16 quantized output is a SIGPROC "
                                  ".fil feature; FBH5 products are float32")
@@ -1247,24 +1458,55 @@ class RawReducer:
                              "applies to .h5 output")
         if chunks is not None:
             raise ValueError("chunks applies to .h5 output")
+        return self.reduce_to_files(raw_src, [out_path])[0]
+
+    def reduce_to_files(self, raw_src: RawSource,
+                        out_paths: Sequence[str]) -> List[Dict]:
+        """Reduce ONE read of the recording to every product
+        (:attr:`products`, in order) as a ``.fil`` at ``out_paths[k]`` —
+        rawspec's ``-f 1048576,8,1024 -t 51,128,3072`` in one pass.
+        Each product is what the single reduction for its ``(nfft,
+        nint)`` defines over the same recording from sample 0: its own
+        filter state, rows, dropped tail and ``tsamp``.  Returns their
+        headers.
+
+        Every product streams into its own ``.partial`` sibling, renamed
+        on success with a manifest sidecar of its own: SIGPROC derives
+        nsamps from file size, so a crash mid-stream must not leave a
+        VALID-looking truncated product at a final path (silent data
+        loss for consumers that treat existence as completion).  All the
+        products finish or none is there: an error in any leaves no
+        final path and no ``.partial`` of any.  Resumable partial
+        products are reduce_resumable's job — there the cursor sidecar
+        marks incompleteness.  nbits<32 writes the narrow quantized form
+        (the header's nbits follows the writer dtype)."""
         from blit.io.sigproc import FilWriter
         from blit.ops.narrow import NARROW_DTYPES
 
-        raw, hdr = self._open_validated(raw_src)
+        out_paths = list(out_paths)
+        if len(out_paths) != len(self.products):
+            raise ValueError(f"{len(self.products)} products, "
+                             f"{len(out_paths)} paths")
+        if len(set(out_paths)) != len(out_paths):
+            raise ValueError(f"two products at one path: {out_paths}")
+        raw, _ = self._open_validated(raw_src)
         nif = STOKES_NIF[self.stokes]
-        # FilWriter streams into a .partial sibling and renames on success:
-        # SIGPROC derives nsamps from file size, so a crash mid-stream must
-        # not leave a VALID-looking truncated product at out_path (silent
-        # data loss for consumers that treat existence as completion).
-        # Resumable partial products are reduce_resumable's job — there the
-        # cursor sidecar marks incompleteness.  nbits<32 writes the narrow
-        # quantized form (the header's nbits follows the writer dtype).
-        w = FilWriter(out_path, hdr, nif, hdr["nchans"],
-                      dtype=NARROW_DTYPES[self.nbits])
-        with observability.span("reduce.to_file", out=out_path):
-            hdr["nsamps"] = self._pump(raw, w)
-        self._surface_integrity(raw, hdr)
-        return hdr
+        hdrs = [self.header_for(raw, k) for k in range(len(out_paths))]
+        writers = []
+        try:
+            for path, hdr in zip(out_paths, hdrs):
+                writers.append(FilWriter(path, hdr, nif, hdr["nchans"],
+                                         dtype=NARROW_DTYPES[self.nbits]))
+        except BaseException:
+            for w in writers:
+                w.abort()
+            raise
+        with observability.span("reduce.to_file", out=out_paths[0]):
+            for hdr, nsamps in zip(hdrs, self._pump(raw, writers)):
+                hdr["nsamps"] = nsamps
+        for hdr in hdrs:
+            self._surface_integrity(raw, hdr)
+        return hdrs
 
     def reduce_resumable(self, raw_src: RawSource, out_path: str,
                          compression: Optional[str] = None,
@@ -1291,6 +1533,7 @@ class RawReducer:
         at most one chunk row).  ``compression``/``chunks`` apply to
         ``.h5`` output only and are part of the resume identity.
         """
+        self._one_product("reduce_resumable()")
         is_h5 = out_path.endswith((".h5", ".hdf5"))
         if is_h5 and self.nbits != 32:
             raise ValueError("nbits=8/16 quantized output is a SIGPROC "

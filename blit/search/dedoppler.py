@@ -337,7 +337,7 @@ class DedopplerReducer:
                     with self.timeline.stage("dispatch", byte_free=True):
                         packed = jfn(jnp.asarray(win.view), thr)
                     for slab in rot.put(packed, nbytes=win.view.nbytes,
-                                        payload=win.index,
+                                        outs=[(packed, win.index)],
                                         on_consumed=win.release):
                         yield slab.payload, decode(slab.data, slab.payload)
                         slab.release()

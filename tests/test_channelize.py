@@ -22,6 +22,25 @@ def nan_guard():
 from blit.ops import channelize as ch  # noqa: E402
 
 
+def channelize_blocked(voltages, coeffs, tails, *, channel_block,
+                       put=None, **kw):
+    """:func:`channelize_fanout` for ONE product integrated inside its
+    program: ``tails`` is each group's filter state (``split_tails`` of the
+    stream's head for its first dispatch, device words after that).
+    Returns ``(product, tails)``."""
+    leg = ch.StreamLeg(coeffs, nint=kw.pop("nint", 1), carried=False, **kw)
+    head = None
+    if isinstance(tails[0], np.ndarray):  # the stream's head, still host
+        head = tails[0] if len(tails) == 1 else np.concatenate(tails)
+    else:
+        leg.tails = list(tails)
+    (rows,), _ = ch.channelize_fanout(
+        voltages, [leg], [voltages.shape[1] // leg.nfft],
+        channel_block=channel_block, head=head,
+        **({} if put is None else {"put": put}))
+    return rows[0], leg.tails
+
+
 def make_voltages(nchan=4, ntime=8 * 256, npol=2, seed=0, tone=None, nfft=256):
     rng = np.random.default_rng(seed)
     v = rng.integers(-32, 32, size=(nchan, ntime, npol, 2), dtype=np.int8)
@@ -162,7 +181,7 @@ class TestChannelize:
             puts.append(jax.tree_util.tree_map(np.shape, host))
             return then(host)
 
-        out, tails = ch.channelize_blocked(
+        out, tails = channelize_blocked(
             body, h, ch.split_tails(head, channel_block),
             channel_block=channel_block, put=put, nfft=nfft, ntap=ntap)
         np.testing.assert_array_equal(np.asarray(out), flat)
@@ -177,7 +196,7 @@ class TestChannelize:
         assert puts == [((channel_block, state),
                          (channel_block, frames * nfft))] * groups
         puts.clear()
-        again, _ = ch.channelize_blocked(
+        again, _ = channelize_blocked(
             body, h, [jnp.asarray(ch.sample_words(t)) for t in
                       ch.split_tails(head, channel_block)],
             channel_block=channel_block, put=put, nfft=nfft, ntap=ntap)
@@ -188,7 +207,7 @@ class TestChannelize:
         v = make_voltages(nchan=8, ntime=6 * 64)
         h = jnp.asarray(ch.pfb_coeffs(4, 64))
         with pytest.raises(ValueError, match="divide nchan"):
-            ch.channelize_blocked(v[:, 192:], h, [v[:, :192]],
+            channelize_blocked(v[:, 192:], h, [v[:, :192]],
                                   channel_block=3, nfft=64, ntap=4)
 
     def test_fqav_must_divide_nfft(self, tmp_path):
