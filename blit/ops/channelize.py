@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Optional, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -751,23 +751,25 @@ def stream_step(
     filter state keeps part of the old tail by the same line).  The ONE
     body of the reducer's :func:`channelize_stream` and of the mesh's
     per-chip :func:`blit.parallel.mesh.band_stream`."""
-    return _gross_step(jnp.concatenate([tail, body], axis=1), coeffs, **kw)
+    return _gross_step((tail, body), coeffs, **kw)
 
 
-def _gross_step(gross: jax.Array, coeffs: jax.Array, *,
+def _gross_step(parts: Tuple[jax.Array, ...], coeffs: jax.Array, *,
                 frames: Optional[int] = None, lanes: int = 0, **kw):
-    """``gross``, the words of a filter state and the samples after it,
-    reduced to its first ``frames`` frames (all it holds, by default) and
-    the filter state the frame after them starts from.  ``lanes`` > 0
-    takes the small-``nfft`` path (:func:`channelize_lanes`, blocks of
-    that many words)."""
+    """``parts`` end to end, the words of a filter state and the samples
+    after it, reduced to their first ``frames`` frames (all they hold, by
+    default) and the filter state the frame after them starts from.
+    ``lanes`` > 0 takes the small-``nfft`` path (:func:`channelize_lanes`,
+    blocks of that many words; it joins the parts itself, a few channels
+    at a time)."""
     nfft, state = kw["nfft"], (kw.get("ntap", 4) - 1) * kw["nfft"]
+    gross = jnp.concatenate(parts, axis=1)
     if frames is None:
         frames = (gross.shape[1] - state) // nfft
     used = frames * nfft
     if lanes:
         power = channelize_lanes(
-            gross, coeffs, nfft=nfft, ntap=kw.get("ntap", 4), block=lanes,
+            parts, coeffs, nfft=nfft, ntap=kw.get("ntap", 4), block=lanes,
             frames=frames, stokes=kw.get("stokes", "I"))
     else:
         power = channelize(_word_samples(gross[:, :used + state]), coeffs,
@@ -798,7 +800,7 @@ def leg_programs(name: str):
         return stream_step(tail, body, coeffs, **kw)
 
     def head(words, coeffs, **kw):
-        return _gross_step(words, coeffs, **kw)
+        return _gross_step((words,), coeffs, **kw)
 
     for fn in (step, head):
         fn.__name__ = fn.__qualname__ = name
@@ -815,18 +817,15 @@ def leg_programs(name: str):
 channelize_stream = leg_programs("channelize_stream")[0]
 
 
-def _fft_planes(xs: list) -> list:
-    """Radix-2 FFT of ``len(xs)`` (a power of two) planar ``(re, im)``
-    planes, each any shape: the transform runs ACROSS the list, every
-    plane elementwise — for an ``nfft`` far below a vector's width, where
-    the frames and not the channels fill the lanes."""
+def _fft_halves(xs: list) -> Tuple[list, list]:
+    """The last butterfly stage of :func:`_fft_planes`, not yet added up:
+    ``(e, t)``, ``len(xs) // 2`` planar ``(re, im)`` planes each, with
+    bin ``k`` of the transform ``e[k] + t[k]`` and bin ``k + len(xs) //
+    2`` ``e[k] - t[k]``."""
     n = len(xs)
-    if n == 1:
-        return xs
     even, odd = _fft_planes(xs[0::2]), _fft_planes(xs[1::2])
-    out = [None] * n
-    for k in range(n // 2):
-        (er, ei), (dr, di) = even[k], odd[k]
+    twiddled = []
+    for k, (dr, di) in enumerate(odd):
         if k == 0:
             tr, ti = dr, di
         elif 4 * k == n:  # times -i
@@ -835,9 +834,22 @@ def _fft_planes(xs: list) -> list:
             wr = np.float32(math.cos(2 * math.pi * k / n))
             wi = np.float32(-math.sin(2 * math.pi * k / n))
             tr, ti = dr * wr - di * wi, dr * wi + di * wr
-        out[k] = (er + tr, ei + ti)
-        out[k + n // 2] = (er - tr, ei - ti)
-    return out
+        twiddled.append((tr, ti))
+    return even, twiddled
+
+
+def _fft_planes(xs: list) -> list:
+    """Radix-2 FFT of ``len(xs)`` (a power of two) planar ``(re, im)``
+    planes, each any shape: the transform runs ACROSS the list, every
+    plane elementwise — for an ``nfft`` far below a vector's width, where
+    the frames and not the channels fill the lanes."""
+    if len(xs) == 1:
+        return xs
+    even, twiddled = _fft_halves(xs)
+    return ([(er + tr, ei + ti)
+             for (er, ei), (tr, ti) in zip(even, twiddled)]
+            + [(er - tr, ei - ti)
+               for (er, ei), (tr, ti) in zip(even, twiddled)])
 
 
 # Coarse channels :func:`channelize_lanes` works on at a time.
@@ -860,86 +872,122 @@ def lanes_block(nfft: int, nint: int, npol: int = 2, ntap: int = 4, *,
 
 
 def channelize_lanes(
-    gross: jax.Array, coeffs: jax.Array, *, nfft: int, ntap: int,
+    parts: Sequence[jax.Array], coeffs: jax.Array, *, nfft: int, ntap: int,
     block: int, frames: int, stokes: str = "I",
 ) -> jax.Array:
     """The channelizer for a SMALL ``nfft`` (rawspec's ``-f 8``), frames
     on the lane axis.  :func:`channelize` lays a block out ``(...,
     frames, nfft)``: at ``nfft`` 8 every float32 intermediate of a
     2^23-sample group is padded 16 times over on a 128-lane machine.
+    Nor may the ``nfft`` bins be the second-minor axis, which a vector's
+    8 sublanes tile: the FFT runs ACROSS the bins, so every input and
+    every result would be one sublane of each tile, and putting the
+    results side by side copies them a sublane at a time (on a v5e half
+    the program's seconds, PERF.md section 6, PR 35).
 
-    ``gross`` ``(cb, (ntap-1)*nfft + samples)`` int32 words
-    (:func:`sample_words` at two polarizations).  The samples are cut
+    ``parts`` are consecutive runs of int32 words (:func:`sample_words`
+    at two polarizations; a filter state and the samples after it, say)
+    that make ``gross`` ``(cb, (ntap-1)*nfft + samples)`` when put end to
+    end, which is done a few channels at a time and never as a whole
+    (1 GiB a group at the reducer's shape).  The samples are cut
     into blocks of ``block = m * nfft`` words (``m`` frames; the caller's
-    ``nint``) and ONE transpose of the words puts the blocks on the
-    lanes: ``(cb, groups, block) -> (cb, block, groups)``.  After it a
-    frame's tap ``k`` is a slice ``k`` rows down (the first ``ntap-1``
-    frames of the next block ride below each block's own), the ``nfft``
-    points of the FFT are ``nfft`` planes (:func:`_fft_planes`) and every
-    operation is elementwise over ``(..., groups)``.
+    ``nint``) and ONE transpose of the words puts the blocks on the lanes
+    and the coarse channels on the sublanes: ``(cb, groups, block) ->
+    (block, cb, groups)``.  After it the words of a block run down MAJOR
+    axes, ``(m + ntap - 1, nfft, cb, groups)``: a frame's tap ``k`` is a
+    slice ``k`` rows down (the first ``ntap-1`` frames of the next block
+    ride below each block's own), the ``nfft`` points of the FFT are
+    ``nfft`` planes of whole ``(cb, groups)`` tiles
+    (:func:`_fft_planes`), every operation is elementwise over them, and
+    the bins are detected a pair at a time (``k`` and ``k + nfft/2``, the
+    two ends of one butterfly): only the power is ever put together, bin
+    after bin on a major axis.
 
-    Returns the power of frame ``g * m + p`` at ``[:, p, :, :, g]``,
-    ``(cb, m, nif, nfft, groups)`` float32, fftshifted like
-    :func:`channelize`'s: the layout :func:`integrate_carry` folds with
-    ``lanes=True``.  ``groups = ceil(frames / m)``; where ``gross`` ends
-    before the last block does it is padded with zeros, and where it goes
-    on past ``frames`` frames they are computed: either way only the
-    first ``frames`` frames are the stream's (the fold's ``nframes``).
+    Returns the power of frame ``g * m + p`` of channel ``i * c + j`` at
+    ``[p, i, :, :, j, g]``, ``(m, cb // c, nif, nfft, c, groups)``
+    float32, fftshifted like :func:`channelize`'s: positions major,
+    frame groups on the lanes, the layout :func:`integrate_carry` folds
+    with ``lanes=True`` as it arrives.  ``c`` is :data:`_LANES_CHANNELS`
+    where that divides a larger ``cb``, else ``cb``.  ``groups =
+    ceil(frames / m)``; where ``gross`` ends before
+    the last block does it is padded with zeros, and where it goes on
+    past ``frames`` frames they are computed: either way only the first
+    ``frames`` frames are the stream's (the fold's ``nframes``).
     """
-    cb, have = gross.shape
+    cb = parts[0].shape[0]
     if cb > _LANES_CHANNELS and cb % _LANES_CHANNELS == 0:
-        # A few channels at a time, one after the other in the one
-        # program: the float32 planes between the passes below are held
-        # for those channels only.
-        power = jax.lax.map(
-            lambda g: channelize_lanes(
-                g, coeffs, nfft=nfft, ntap=ntap, block=block,
-                frames=frames, stokes=stokes),
-            gross.reshape(cb // _LANES_CHANNELS, _LANES_CHANNELS, have))
-        return power.reshape((cb,) + power.shape[2:])
+        # A sublane's count of channels at a time, one after the other in
+        # the one program: the parts joined and the float32 planes
+        # between the passes below are held for those channels only.
+        # The slabs land on the second axis (the compiler lays the loop's
+        # buffer out so: the swap is no copy).
+        return jnp.swapaxes(jax.lax.map(
+            lambda slab: channelize_lanes(
+                slab, coeffs, nfft=nfft, ntap=ntap, block=block,
+                frames=frames, stokes=stokes)[:, 0],
+            [p.reshape(cb // _LANES_CHANNELS, _LANES_CHANNELS, -1)
+             for p in parts]), 0, 1)
+    gross = jnp.concatenate(parts, axis=1)
+    have = gross.shape[1]
     state, m = (ntap - 1) * nfft, block // nfft
     groups = -(-frames // m)
     need = groups * block + state
     if have < need:
         gross = jnp.pad(gross, ((0, 0), (0, need - have)))
-    rows = jnp.swapaxes(
-        gross[:, :groups * block].reshape(cb, groups, block), 1, 2)
+    rows = jnp.transpose(
+        gross[:, :groups * block].reshape(cb, groups, block), (2, 0, 1))
     # Below each block's own rows, the first `state` words of the next.
     below = jnp.concatenate(
-        [rows[:, :state, 1:], gross[:, groups * block:need, None]], axis=2)
-    words = jnp.concatenate([rows, below], axis=1).reshape(
-        cb, m + ntap - 1, nfft, groups)
+        [rows[:state, :, 1:], gross[:, groups * block:need].T[:, :, None]],
+        axis=2)
+    # Held as written: left free, the compiler widens the bytes BEFORE the
+    # transpose and transposes four float32 planes for the one of words.
+    words = jax.lax.optimization_barrier(
+        jnp.concatenate([rows, below], axis=0).reshape(
+            m + ntap - 1, nfft, cb, groups))
     # The shift theorem, as in channelize: (-1)^j on the input rolls the
     # spectrum by nfft/2.
     sign = np.where(np.arange(nfft) % 2 == 0, 1.0, -1.0).astype(np.float32)
-    taps = (coeffs * sign[None, :])[:, None, None, :, None]
+    taps = coeffs * sign[None, :]
 
-    def plane(byte):
-        """Byte ``byte`` of every word (0: the first polarization's real
-        part), sign-extended, through the filter: ``(cb, m, nfft,
-        groups)``.  Each tap converts its own slice of the words: the
-        float32 plane of all ``m + ntap - 1`` rows is never written."""
+    def plane(byte, i):
+        """Byte ``byte`` of point ``i``'s words (0: the first
+        polarization's real part), sign-extended, through the filter:
+        ``(m, cb, groups)``.  Each tap converts its own slice of the
+        words: the float32 plane of all ``m + ntap - 1`` rows is never
+        written."""
         def rows_from(k):
             return jax.lax.shift_right_arithmetic(
-                jax.lax.shift_left(words[:, k:k + m],
+                jax.lax.shift_left(words[k:k + m, i],
                                    jnp.int32(24 - 8 * byte)),
                 jnp.int32(24)).astype(jnp.float32)
 
-        acc = taps[0] * rows_from(0)
+        acc = taps[0, i] * rows_from(0)
         for k in range(1, ntap):
-            acc = acc + taps[k] * rows_from(k)
+            acc = acc + taps[k, i] * rows_from(k)
         return acc
 
-    pols = []
-    for pol in range(2):
-        re, im = plane(2 * pol), plane(2 * pol + 1)
-        pols.append(_fft_planes(
-            [(re[:, :, i], im[:, :, i]) for i in range(nfft)]))
-    sr = jnp.stack([jnp.stack([z[0] for z in p], axis=2) for p in pols],
-                   axis=2)  # (cb, m, npol, nfft, groups)
-    si = jnp.stack([jnp.stack([z[1] for z in p], axis=2) for p in pols],
-                   axis=2)
-    return detect_stokes_planar(sr, si, stokes)  # (cb, m, nif, nfft, groups)
+    halves = [_fft_halves([(plane(2 * pol, i), plane(2 * pol + 1, i))
+                           for i in range(nfft)]) for pol in range(2)]
+    # Bins k and k + nfft/2 of both polarizations -> their power, ONE
+    # expression for the pair, e +- t: the compiler then makes the two in
+    # one pass over e and t (adding -1 * t is subtracting t, to the bit).
+    # Left as e + t here and e - t there it wrote both half-transforms
+    # out for eight passes to read back (PERF.md section 6, PR 35).
+    pm = jnp.asarray([1.0, -1.0], jnp.float32)[:, None, None, None]
+    pairs = []
+    for k in range(nfft // 2):
+        sr, si = [], []
+        for even, twiddled in halves:  # a polarization each
+            (er, ei), (tr, ti) = even[k], twiddled[k]
+            sr.append(er[None] + pm * tr[None])
+            si.append(ei[None] + pm * ti[None])
+        # (2, m, npol, cb, groups) -> (2, m, nif, cb, groups)
+        pairs.append(detect_stokes_planar(jnp.stack(sr, axis=2),
+                                          jnp.stack(si, axis=2), stokes))
+    power = jnp.stack(pairs, axis=1)  # (2, nfft/2, ...): bin h*nfft/2 + k
+    power = power.reshape((nfft,) + power.shape[2:])
+    return jnp.moveaxis(power, 0, 2)[:, None]  # (m, 1, nif, nfft, cb, groups)
 
 
 def _direct_put(host, then: Callable):
@@ -978,7 +1026,11 @@ class StreamLeg:
     ``kw`` are :func:`channelize`'s keywords less ``nint``.  A leg that is
     not carried integrates inside its program (a dispatch's frames are a
     multiple of ``nint``).  ``lanes`` > 0 runs the small-``nfft`` path in
-    blocks of that many words (:func:`lanes_block`; carried legs only).
+    blocks of that many words (:func:`lanes_block`; carried legs only):
+    its power and its accumulators keep :func:`channelize_lanes`'s layout
+    (positions major, channels on the sublanes, frame groups on the
+    lanes) from the program to the fold, and only closed ROWS are turned
+    into the product's.
     ``name`` is the leg's program name in a device trace; ``label`` names
     it in counters (``None``: a reduction of one product)."""
 
@@ -1015,7 +1067,7 @@ class StreamLeg:
         acc = self.accs[g]
         if acc is None:
             acc = jnp.zeros(
-                power.shape[:1] + power.shape[2:4] if self.lanes
+                power.shape[1:5] if self.lanes
                 else power.shape[1:], jnp.float32)
         rows, self.accs[g] = integrate_carry(
             power, acc, np.int32(at),  # data, not static: one program
@@ -1259,11 +1311,14 @@ def integrate_carry(
     ``(filled + nframes) // nint`` closed in this dispatch (the rest are
     zeros), and the accumulator to hand to the next dispatch.
 
-    With ``lanes`` the power is :func:`channelize_lanes`'s: ``(cb, nint,
-    nif, nfft, groups)``, frame ``g * nint + p`` at ``[:, p, ..., g]``,
-    the first ``nframes`` of them real; ``acc`` is ``(cb, nif, nfft)`` and
-    the rows come back in the product's layout, ``(groups, nif, cb *
-    nfft)``.
+    With ``lanes`` the power is :func:`channelize_lanes`'s: ``(nint, C,
+    nif, nfft, c, groups)``, frame ``g * nint + p`` of channel ``i * c +
+    j`` at ``[p, i, :, :, j, g]``, the first ``nframes`` of them real.
+    The positions are its major axis and the groups its lanes, which is
+    how the fold walks it: it is read where the leg wrote it, no copy in
+    front.  ``acc`` is ``(C, nif, nfft, c)`` and the rows come back in the
+    product's layout, ``(groups, nif, C * c * nfft)`` (a transpose of the
+    ROWS, a hundredth of the power).
 
     The order of addition is part of the result: a row's frames are added
     one at a time in stream order and a fresh integration starts from
@@ -1277,11 +1332,11 @@ def integrate_carry(
         groups = power.shape[-1]
         total = groups * nint if nframes is None else nframes
         rows, acc = _fold_groups(
-            power, acc, filled, nint,
+            power[None], acc[None], filled, nint,
             last_valid=total - (groups - 1) * nint, group_axis=-1)
-        cb, nif, nfft, _ = rows.shape
-        return (jnp.transpose(rows, (3, 1, 0, 2)).reshape(
-            groups, nif, cb * nfft), acc)
+        nif = rows.shape[2]
+        return (jnp.transpose(rows[0], (4, 1, 0, 3, 2)).reshape(
+            groups, nif, -1), acc[0])
     total = power.shape[0]
     whole, rest = divmod(total, nint)
     parts = []

@@ -507,30 +507,45 @@ def test_the_fold_gives_the_loops_bits(frames, nint, rest):
         assert np.asarray(got).tobytes() == want_acc.tobytes(), filled
 
 
+def frame_major(power):
+    """``channelize_lanes``'s power ``(m, C, nif, nfft, c, groups)`` as
+    ``channelize``'s at ``nint=1``: frame ``g * m + p`` of channel ``i * c
+    + j`` at ``[g * m + p, :, (i * c + j) * nfft:][:nfft]``."""
+    m, slabs, nif, nfft, c, groups = power.shape
+    return np.transpose(np.asarray(power), (5, 0, 2, 1, 4, 3)).reshape(
+        groups * m, nif, slabs * c * nfft)
+
+
+def lanes_acc(acc):
+    """The lanes fold's accumulator ``(C, nif, nfft, c)`` as the
+    frame-major fold's ``(nif, nchan * nfft)``."""
+    slabs, nif, nfft, c = acc.shape
+    return np.transpose(np.asarray(acc), (1, 0, 3, 2)).reshape(
+        nif, slabs * c * nfft)
+
+
 @pytest.mark.parametrize("valid", [5 * 16, 5 * 16 - 1, 4 * 16 + 1, 16, 7])
 def test_the_lanes_fold_is_the_same_fold(valid):
-    """Frames on the lane axis, the last group short: the rows and the
-    accumulator of the frame-major fold over the same frames."""
-    nint, cb, nfft = 16, 3, 8
+    """Positions major, channels on the sublanes, frame groups on the
+    lanes, the last group short: the rows and the accumulator of the
+    frame-major fold over the same frames."""
+    nint, slabs, c, nfft = 16, 2, 3, 8
     groups = -(-valid // nint)
     rng = np.random.default_rng(valid)
-    lanes = rng.standard_normal((cb, nint, 1, nfft, groups)) \
+    lanes = rng.standard_normal((nint, slabs, 1, nfft, c, groups)) \
         .astype(np.float32)
-    major = np.transpose(lanes, (4, 1, 2, 0, 3)).reshape(
-        groups * nint, 1, cb * nfft)[:valid]
+    major = frame_major(lanes)[:valid]
     for filled in (0, 1, 9, 15):
-        acc = rng.standard_normal((cb, 1, nfft)).astype(np.float32) \
+        acc = rng.standard_normal((slabs, 1, nfft, c)).astype(np.float32) \
             * (filled > 0)
-        want_rows, want_acc = frame_at_a_time(
-            major, np.transpose(acc, (1, 0, 2)).reshape(1, cb * nfft),
-            filled, nint)
+        want_rows, want_acc = frame_at_a_time(major, lanes_acc(acc), filled,
+                                              nint)
         rows, got = integrate_carry(lanes, acc, np.int32(filled), nint=nint,
                                     nframes=valid, lanes=True)
         closed = (filled + valid) // nint
         assert np.asarray(rows)[:closed].tobytes() \
             == want_rows[:closed].tobytes()
-        assert np.transpose(np.asarray(got), (1, 0, 2)).reshape(
-            1, cb * nfft).tobytes() == want_acc.tobytes()
+        assert lanes_acc(got).tobytes() == want_acc.tobytes()
 
 
 def test_a_row_does_not_depend_on_the_dispatch_grid_in_bulk():
@@ -577,14 +592,68 @@ def test_channelize_lanes_matches_the_reference(nfft, nint, frames, pad,
     want = channelize_np(v[:, :(frames + NTAP - 1) * nfft], h, nfft=nfft,
                          ntap=NTAP, nint=1, stokes=stokes)
     got = np.asarray(channelize_lanes(
-        jnp.asarray(sample_words(v)), jnp.asarray(h), nfft=nfft, ntap=NTAP,
-        block=block, frames=frames, stokes=stokes))
+        (jnp.asarray(sample_words(v)),), jnp.asarray(h), nfft=nfft,
+        ntap=NTAP, block=block, frames=frames, stokes=stokes))
     groups = -(-frames // nint)
-    assert got.shape == (cb, nint, want.shape[1], nfft, groups)
-    got = np.transpose(got, (4, 1, 2, 0, 3)).reshape(
-        groups * nint, want.shape[1], cb * nfft)[:frames]
+    assert got.shape == (nint, 1, want.shape[1], nfft, cb, groups)
     # float32 on both sides, no matrix unit in this path: 2e-7 read
-    assert rel_err(got, want) < 2e-6
+    assert rel_err(frame_major(got)[:frames], want) < 2e-6
+
+
+@pytest.mark.parametrize("stokes", ["I", "IQUV"])
+@pytest.mark.parametrize("cb", [9, 16], ids=["plain", "slabs"])
+def test_the_lanes_leg_end_to_end_is_the_frame_major_leg(cb, stokes):
+    """The ``nfft`` 8 leg's own programs over two dispatches, a row
+    straddling them (300 frames, then 350: 44 of 128 open between, 10
+    after), at 9 coarse channels (one slab) and at 16 (a ``lax.map`` over
+    slabs of 8).
+    Its rows are, byte for byte, the frame-major fold's over the same
+    power, and the frame-major LEG's (``channelize`` at ``nint=1`` ->
+    ``integrate_carry``) to float32 rounding: the two channelisers
+    transform by different butterflies (``_fft_planes`` across planes,
+    ``jnp.fft`` along the minor axis), so their power differs in the last
+    bit in half the values and no fold can make bytes of that."""
+    nfft, nint, fed = 8, 128, (300, 350)
+    state, lanes = (NTAP - 1) * nfft, lanes_block(nfft, nint)
+    rng = np.random.default_rng([cb, len(stokes)])
+    v = rng.integers(-40, 40, size=(cb, state + sum(fed) * nfft, 2, 2)) \
+        .astype(np.int8)
+    words, h = jnp.asarray(sample_words(v)), jnp.asarray(pfb_coeffs(NTAP,
+                                                                     nfft))
+    step = ch.leg_programs("channelize_0001")[0]
+    whole = np.asarray(ch.channelize(jnp.asarray(v), h, nfft=nfft,
+                                     ntap=NTAP, nint=1, stokes=stokes))
+    nif = whole.shape[1]
+    tail, at, filled, acc = words[:, :state], state, 0, None
+    own_acc = ref_acc = jnp.zeros((nif, cb * nfft), jnp.float32)
+    rows, own_rows, ref_rows = [], [], []
+    for frames in fed:
+        body = words[:, at:at + frames * nfft]
+        power, tail = step(tail, body, h, nfft=nfft, ntap=NTAP,
+                           stokes=stokes, lanes=lanes)
+        if acc is None:
+            acc = jnp.zeros(power.shape[1:5], jnp.float32)
+        closed = (filled + frames) // nint
+        r, acc = integrate_carry(power, acc, np.int32(filled), nint=nint,
+                                 nframes=frames, lanes=True)
+        rows.append(np.asarray(r)[:closed])
+        r, own_acc = integrate_carry(frame_major(power)[:frames], own_acc,
+                                     np.int32(filled), nint=nint)
+        own_rows.append(np.asarray(r)[:closed])
+        first = (at - state) // nfft
+        r, ref_acc = integrate_carry(whole[first:first + frames], ref_acc,
+                                     np.int32(filled), nint=nint)
+        ref_rows.append(np.asarray(r)[:closed])
+        at, filled = at + frames * nfft, (filled + frames) % nint
+    rows, own_rows, ref_rows = map(np.concatenate,
+                                   (rows, own_rows, ref_rows))
+    assert rows.shape == (sum(fed) // nint, nif, cb * nfft) and filled
+    assert rows.tobytes() == own_rows.tobytes()
+    assert lanes_acc(acc).tobytes() == np.asarray(own_acc).tobytes()
+    assert np.asarray(tail).tobytes() \
+        == np.asarray(words[:, at - state:at]).tobytes()
+    assert rel_err(rows, ref_rows) < 2e-6
+    assert rel_err(lanes_acc(acc), ref_acc) < 2e-6
 
 
 def test_lanes_block_serves_small_power_of_two_nfft_only():

@@ -296,6 +296,60 @@ class TestMeshStreamStepCompilesForTheHost:
         assert self._held(compiled) + 2.5 * 2 ** 30 < self.HBM
 
 
+class TestSmallNfftLegCompilesForTheChip:
+    """``rawspec3.hires51``'s ``nfft`` 8 leg (``channelize_lanes`` through
+    ``leg_programs``, then the lanes fold) COMPILED for one chip of
+    ``v5e:2x2`` at the cell's shape, a 32-channel group of 2^23 words in
+    blocks of 1024: the pin that keeps the plane assembly of PR 34 from
+    coming back (PERF.md section 6, PR 35)."""
+
+    CB, WORDS, NFFT8, NINT = 32, 1 << 23, 8, 128
+
+    def _spec(self, topo):
+        from jax.sharding import SingleDeviceSharding
+
+        chip = SingleDeviceSharding(topo.devices[0])
+        return lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=chip)
+
+    def test_the_step_assembles_no_planes_and_holds_no_more(self, v5e_2x2):
+        spec = self._spec(v5e_2x2)
+        lanes = ch.lanes_block(self.NFFT8, self.NINT)
+        assert lanes == 1024
+        compiled = ch.leg_programs("channelize_0001")[0].lower(
+            spec((self.CB, (NTAP - 1) * self.NFFT8), "int32"),
+            spec((self.CB, self.WORDS), "int32"),
+            spec((NTAP, self.NFFT8), "float32"),
+            nfft=self.NFFT8, ntap=NTAP, stokes="I", lanes=lanes).compile()
+        m = compiled.memory_analysis()
+        gib = 2 ** 30
+        assert m.argument_size_in_bytes // gib == 1
+        assert m.output_size_in_bytes // gib == 1
+        # The parent's account (PR 34): 3.169 GiB of temporaries.
+        assert m.temp_size_in_bytes <= 3.17 * gib
+        # A stack of planes that are one SUBLANE of each tile is compiled
+        # as T(1,128) planes copied a sublane at a time into the (8,128)
+        # tiles: half the leg's seconds on the chip, until PR 35.
+        sublane_copies = [
+            line.strip()[:120] for line in compiled.as_text().splitlines()
+            if "dynamic-update-slice" in line and "T(1,128)" in line]
+        assert not sublane_copies
+
+    def test_the_fold_reads_the_power_where_the_leg_wrote_it(self, v5e_2x2):
+        spec = self._spec(v5e_2x2)
+        slabs, c = self.CB // 8, 8
+        groups = self.WORDS // (self.NFFT8 * self.NINT)
+        m = ch.integrate_carry.lower(
+            spec((self.NINT, slabs, 1, self.NFFT8, c, groups), "float32"),
+            spec((slabs, 1, self.NFFT8, c), "float32"), spec((), "int32"),
+            nint=self.NINT, nframes=self.WORDS // self.NFFT8,
+            lanes=True).compile().memory_analysis()
+        # No position-major copy of the 1 GiB of power in front of the
+        # fold (the parent's account: 1.0 GiB of temporaries).
+        assert m.argument_size_in_bytes // 2 ** 30 == 1
+        assert m.temp_size_in_bytes < 2 ** 26
+
+
 class TestKernelRequestsOffTpuAndCpu:
     def test_pallas_interpret_names_its_backends(self):
         assert device.pallas_interpret("tpu") is False
