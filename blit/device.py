@@ -245,12 +245,13 @@ class HostLink:
         """``jax.device_put(host, device)`` through the budget, or with
         ``then`` what that program returns for what was put.  ``host`` is
         an array, or a tree of arrays admitted as one transfer (a stream's
-        head with its first samples).  Each array put is counted in the
-        row ``link.put`` of ``timeline`` (a counted instant: ``calls`` =
-        arrays, ``bytes`` = host->device bytes), budgeted backend or not:
-        the row says what a reduction sent up, whatever the link made of
-        it.  The handle kept in flight must outlive its transfer: a
-        program that takes a put array by donation hands back something
+        head with its first samples).  The ``device_put`` alone — after
+        the admit, before ``then`` — is the part ``link.put`` of
+        ``timeline`` (``calls`` = arrays, ``bytes`` = host->device bytes,
+        seconds = what the call held its caller for), budgeted backend or
+        not: the row says what a reduction sent up, whatever the link
+        made of it.  The handle kept in flight must outlive its transfer:
+        a program that takes a put array by donation hands back something
         else (``then``'s result), never the array."""
         import jax
 
@@ -258,9 +259,9 @@ class HostLink:
         nbytes = sum(a.nbytes for a in leaves)
         counted = self._admit(nbytes, timeline)
         try:
-            if timeline is not None:
-                timeline.mark("link.put", nbytes, calls=len(leaves))
-            out = jax.device_put(host, device)
+            with (timeline.part("link.put", nbytes, calls=len(leaves))
+                  if timeline is not None else contextlib.nullcontext()):
+                out = jax.device_put(host, device)
             if then is not None:
                 out = then(out)
         except BaseException:

@@ -59,6 +59,7 @@ back down only at verification time.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -335,12 +336,19 @@ class ManifestWriter:
     folded so far, running CRC — and :func:`verify_claim` replays them.
     ``save`` is best-effort (a failing manifest write must never fail the
     product it describes); the counters say when it happened.
+
+    Every byte digested — a fold, a file read back for its CRC — is the
+    part ``write.digest`` of ``timeline`` where the writer was handed one
+    (``calls`` = folds, ``bytes`` = bytes digested), inside whatever
+    stage its thread is in: ``write`` on the sink's, ``open`` / ``close``
+    at a pass's ends.
     """
 
     def __init__(self, final_path: str, fmt: str, *, data_offset: int = 0,
                  row_bytes: int = 0, fingerprint: Optional[str] = None,
-                 writer: str = ""):
+                 writer: str = "", timeline=None):
         self.final_path = final_path
+        self.timeline = timeline
         self.fmt = fmt
         self.data_offset = data_offset
         self.row_bytes = row_bytes
@@ -352,15 +360,23 @@ class ManifestWriter:
         self.ledger: List[List] = []
 
     # -- accumulation ------------------------------------------------------
+    def _digesting(self, nbytes: int):
+        if self.timeline is None:
+            return contextlib.nullcontext()
+        return self.timeline.part("write.digest", nbytes)
+
     def fold(self, buf) -> None:
         """Fold appended content (bytes / contiguous ndarray)."""
-        self.crc = crc32_update(self.crc, buf)
-        self.nbytes += memoryview(buf).nbytes
+        n = memoryview(buf).nbytes
+        with self._digesting(n):
+            self.crc = crc32_update(self.crc, buf)
+        self.nbytes += n
 
     def fold_path(self, path: str, length: Optional[int] = None) -> None:
         """Fold existing file bytes (header prologue; resume rebuild)."""
         n = os.path.getsize(path) if length is None else length
-        self.crc = crc32_file(path, 0, n, self.crc)
+        with self._digesting(n):
+            self.crc = crc32_file(path, 0, n, self.crc)
         self.nbytes += n
 
     def claim(self, rows: int) -> None:
@@ -413,7 +429,11 @@ class ManifestWriter:
         otherwise the running CRC is the file CRC (fil/hits)."""
         try:
             size = os.path.getsize(self.final_path)
-            crc = (crc32_file(self.final_path) if scan_file else self.crc)
+            if scan_file:
+                with self._digesting(size):
+                    crc = crc32_file(self.final_path)
+            else:
+                crc = self.crc
         except OSError:
             incr("integrity.manifest.error")
             log.warning("manifest publish of %s failed",

@@ -404,6 +404,7 @@ class FBH5Writer(_ChunkStream):
         dtype=np.float32,
         compression: Optional[str] = None,
         chunks: Optional[Tuple[int, int, int]] = None,
+        timeline=None,
     ):
         self.final_path = path
         self.path = path + ".partial"
@@ -445,7 +446,7 @@ class FBH5Writer(_ChunkStream):
         self._mf = integrity.ManifestWriter(
             self.final_path, "fbh5",
             row_bytes=nifs * nchans * self.dtype.itemsize,
-            writer=type(self).__name__)
+            writer=type(self).__name__, timeline=timeline)
         # Pending partial chunk row (the bitshuffle path buffers up to one;
         # the plain/gzip paths let libhdf5 chunk and never touch this).
         self._buf = (
@@ -554,7 +555,7 @@ class ResumableFBH5Writer(_ChunkStream):
                  start_rows: int, nint: int, cursor,
                  compression: Optional[str] = None,
                  chunks: Optional[Tuple[int, int, int]] = None,
-                 dtype=np.float32):
+                 dtype=np.float32, timeline=None):
         self.path = path
         self.dtype = np.dtype(dtype)
         self._nifs, self._nchans = nifs, nchans
@@ -644,7 +645,7 @@ class ResumableFBH5Writer(_ChunkStream):
 
         self._mf = integrity.ManifestWriter(
             path, "fbh5", row_bytes=nifs * nchans * self.dtype.itemsize,
-            writer=type(self).__name__)
+            writer=type(self).__name__, timeline=timeline)
         if start_rows > 0:
             row_bytes = nifs * nchans * self.dtype.itemsize
             step = max(1, (8 << 20) // max(1, row_bytes))

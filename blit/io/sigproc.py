@@ -171,7 +171,7 @@ class FilWriter:
     """
 
     def __init__(self, path: str, header: Dict, nifs: int, nchans: int,
-                 dtype=np.float32):
+                 dtype=np.float32, timeline=None):
         import os as _os
 
         from blit import integrity
@@ -186,12 +186,13 @@ class FilWriter:
         # Product manifest (ISSUE 13): per-window digests + whole-file
         # CRC, folded as slabs append (this runs on the write-behind
         # sink thread under the async plane — digesting rides the
-        # thread that already owns the bytes) and published as a
-        # <product>.manifest.json sidecar at close.
+        # thread that already owns the bytes: what the digest takes of
+        # its `write` is the part `write.digest` of ``timeline``) and
+        # published as a <product>.manifest.json sidecar at close.
         self._mf = integrity.ManifestWriter(
             self.final_path, "fil",
             row_bytes=nifs * nchans * self.dtype.itemsize,
-            writer=type(self).__name__)
+            writer=type(self).__name__, timeline=timeline)
         self._mf.data_offset = _os.path.getsize(self.path)
         self._mf.fold_path(self.path)
         self._f = open(self.path, "ab")

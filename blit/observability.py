@@ -369,18 +369,15 @@ class Timeline:
     )
 
     @contextlib.contextmanager
-    def stage(
-        self, name: str, nbytes: int = 0, byte_free: bool = False,
-        calls: int = 1,
-    ) -> Iterator[Optional["Span"]]:
-        """Time one stage: the sums below, and — so the seconds can be
-        laid against another clock — the interval itself, as a span of
-        the process tracer under the ambient span (attrs ``bytes`` and
-        ``stage=1``).  Yields that live span, for a caller with attrs of
-        its own to add; ``BLIT_SPANS=0`` keeps the sums, drops the span
-        and yields ``None``.  Span and row carry the same duration;
-        ``calls`` is what the row counts it as (:meth:`mark`)."""
-        sp = _TRACER.open_span(name, {"bytes": nbytes, "stage": 1})
+    def _timed(self, name: str, kind: str, nbytes: int, calls: int,
+               byte_free: bool = False) -> Iterator[Optional["Span"]]:
+        """The one record behind :meth:`stage`, :meth:`mark` and
+        :meth:`part`: a row of the table (``calls``, ``seconds``,
+        ``bytes``) and the interval itself, as a span of the process
+        tracer under the ambient span, attrs ``bytes`` and ``kind``=1.
+        ``BLIT_SPANS=0`` keeps the row, drops the span and yields
+        ``None``.  Span and row carry the same duration."""
+        sp = _TRACER.open_span(name, {"bytes": nbytes, kind: 1})
         t0 = time.perf_counter()
         try:
             yield sp
@@ -394,14 +391,36 @@ class Timeline:
                 s.byte_free = True
             if sp is None:
                 _FLIGHT.stage_event(name, dt, nbytes)
-            else:  # the span is the stage's one entry in the flight ring
+            else:  # the span is the record's one entry in the flight ring
                 _TRACER.close_span(sp, dt)
+
+    def stage(
+        self, name: str, nbytes: int = 0, byte_free: bool = False,
+        calls: int = 1,
+    ):
+        """Time one stage — what its thread is doing: the sums of the
+        table and, so the seconds can be laid against another clock, a
+        span (attr ``stage=1``).  Yields that live span, for a caller
+        with attrs of its own to add (``None`` under ``BLIT_SPANS=0``);
+        ``calls`` is what the row counts it as (:meth:`mark`)."""
+        return self._timed(name, "stage", nbytes, calls, byte_free)
 
     def mark(self, name: str, nbytes: int = 0, calls: int = 1) -> None:
         """A counted instant: a zero-length stage (row and span, on the
         clock of every other stage) standing for ``calls`` events."""
         with self.stage(name, nbytes=nbytes, calls=calls):
             pass
+
+    def part(self, name: str, nbytes: int = 0, calls: int = 1,
+             byte_free: bool = False):
+        """Time one PART of a stage — what some of the stage's seconds
+        were spent on (the coefficient bank inside ``dispatch``, the
+        digest inside ``write``): the same record as a stage, but its
+        span carries ``part=1`` and no ``stage``.  A part is not a state
+        of its thread: whatever names seconds by the innermost open
+        STAGE reads what it read without the part, and the enclosing
+        stage's row keeps its total."""
+        return self._timed(name, "part", nbytes, calls, byte_free)
 
     def wait(self, name: str) -> "StageWait":
         """A byte-free ``wait.<what>`` stage that starts only when the
@@ -987,8 +1006,8 @@ class FlightRecorder:
         ev = {"t": sp.t0, "kind": "span", "name": sp.name,
               "dur_s": round(sp.duration_s, 6),
               "span": sp.span_id, "parent": sp.parent_id}
-        if sp.attrs and sp.attrs.get("stage"):  # a Timeline stage's span
-            ev["bytes"] = sp.attrs.get("bytes", 0)
+        if sp.attrs and (sp.attrs.get("stage") or sp.attrs.get("part")):
+            ev["bytes"] = sp.attrs.get("bytes", 0)  # a Timeline row's span
         self._ring.append(ev)
 
     def stage_event(self, name: str, seconds: float, nbytes: int) -> None:

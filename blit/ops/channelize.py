@@ -30,6 +30,7 @@ TPU notes (pallas_guide.md; SURVEY.md §7 "hard parts"):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Callable, Optional, Sequence, Tuple
@@ -86,6 +87,17 @@ def pfb_coeffs(ntap: int, nfft: int, window: str = "hamming") -> np.ndarray:
     h = sinc * win
     h /= h.sum()  # unit DC gain: a constant input yields 1.0 in the DC bin pre-FFT-scaling
     return h.reshape(ntap, nfft).astype(np.float32)
+
+
+def coeff_bank(ntap: int, nfft: int, window: str, timeline) -> jax.Array:
+    """:func:`pfb_coeffs` built and shipped to the device, as the part
+    ``coeffs`` of ``timeline`` (``calls`` = banks built, ``bytes`` =
+    theirs; attr ``nfft``) inside whatever stage asked for it: half a
+    second of host arithmetic at 2^20."""
+    with timeline.part("coeffs", ntap * nfft * 4) as sp:
+        if sp is not None:
+            sp.attrs["nfft"] = nfft
+        return jnp.asarray(pfb_coeffs(ntap, nfft, window))
 
 
 def dequantize(voltages: jax.Array, dtype=jnp.float32) -> Tuple[jax.Array, jax.Array]:
@@ -1043,6 +1055,7 @@ class StreamLeg:
         self.nfft = kw["nfft"]
         self.state_words = (kw.get("ntap", 4) - 1) * self.nfft
         self.step, self.head = leg_programs(name)
+        self.program = f"jit_{name}"  # both, in a device trace
         self.tails: Optional[list] = None
         self.accs: Optional[list] = None
         self.filled = 0
@@ -1135,6 +1148,7 @@ def channelize_fanout(
     head=None,
     put: Callable = _direct_put,
     shared: Optional[Callable] = None,
+    calling: Optional[Callable] = None,
 ) -> Tuple[list, list]:
     """One dispatch of a stream's chunk to every leg: host-looped channel
     blocking (the compile-friendly replacement for
@@ -1159,7 +1173,10 @@ def channelize_fanout(
     ``then`` makes of it — the caller's transfer policy; by default the
     jit's own argument transfer.  ``shared(programs, nbytes)`` is told,
     per group, how many programs consumed a transfer of ``nbytes`` they
-    did not upload.
+    did not upload.  ``calling(names)`` is a context manager entered
+    around one group's program calls (every leg's ``begin`` / ``advance``;
+    ``names``: their jit names in call order), for a caller that times
+    them.
 
     Returns ``(rows, token)``: per leg the list of row batches that closed
     (a leg's head step and its dispatch each close their own), and what is
@@ -1176,18 +1193,23 @@ def channelize_fanout(
                  for leg in legs]
     else:
         begun = [0] * len(legs)
+    # A group's program calls, by name: the head steps, then the dispatch.
+    names = [leg.program for leg, n in zip(legs + legs, begun + list(frames))
+             if n]
     token = []
     for g, c in enumerate(groups):
         def programs(up, g=g):
             held = []
-            if head is not None:
-                head_up, up = up
-                # Everyone reads the head before its owner donates it.
-                held += [leg.begin(g, head_up) for leg in legs
-                         if leg is not owner]
-                owner.tails[g] = head_up
-            held += [leg.advance(g, up, n, b)
-                     for leg, n, b in zip(legs, frames, begun) if n]
+            with calling(names) if calling is not None \
+                    else contextlib.nullcontext():
+                if head is not None:
+                    head_up, up = up
+                    # Everyone reads the head before its owner donates it.
+                    held += [leg.begin(g, head_up) for leg in legs
+                             if leg is not owner]
+                    owner.tails[g] = head_up
+                held += [leg.advance(g, up, n, b)
+                         for leg, n, b in zip(legs, frames, begun) if n]
             return held
 
         body = sample_words(voltages[c : c + groups.step])

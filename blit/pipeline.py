@@ -58,6 +58,7 @@ Design:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import os
@@ -79,9 +80,9 @@ from blit.ops.channelize import (
     StreamLeg,
     channelize_fanout,
     channels_per_dispatch,
+    coeff_bank,
     lanes_block,
     output_header,
-    pfb_coeffs,
     usable_frames,
 )
 
@@ -441,8 +442,10 @@ class RawReducer:
     # Per-stage timing/byte registry ("ingest" / "stream" on the source
     # side; "dispatch" / "device" / "readback" / "write" on the output
     # plane — see blit/outplane.py; "wait.*" where a pump thread blocked;
-    # counted instants "link.put", "state.head" / "state.carry",
-    # "integrate.*").  Every stage is also a span of the process tracer.
+    # counted instants "state.head" / "state.carry", "integrate.*"; a
+    # pass's two ends "open" / "close"; and PARTS, what a stage's seconds
+    # went to: "coeffs", "link.put", "dispatch.call", "write.digest").
+    # Every row is also a span of the process tracer.
     timeline: Timeline = field(default_factory=Timeline)
     # When set, a device-only JAX profiler trace wraps every streaming run
     # and the run's spans land beside it as blit-spans.json
@@ -629,11 +632,9 @@ class RawReducer:
         (scan resolves tuning knobs through one) must not
         pay a multi-million-coefficient sinc*window build plus device
         transfer just to read provenance."""
-        if nfft not in self._pfb_coeffs:
-            import jax.numpy as jnp
-
-            self._pfb_coeffs[nfft] = jnp.asarray(
-                pfb_coeffs(self.ntap, nfft, self.window))
+        if nfft not in self._pfb_coeffs:  # a miss only is a bank built
+            self._pfb_coeffs[nfft] = coeff_bank(
+                self.ntap, nfft, self.window, self.timeline)
         return self._pfb_coeffs[nfft]
 
     @property
@@ -856,7 +857,8 @@ class RawReducer:
             # Programs that consumed a group they did not upload, and the
             # H2D bytes that were not sent again for them.
             shared=lambda programs, nbytes: self.timeline.mark(
-                "fanout.share", nbytes, calls=programs))
+                "fanout.share", nbytes, calls=programs),
+            calling=self._calling)
         outs = []
         for k, (leg, frames) in enumerate(zip(st.legs, chunk.leg_frames)):
             if not frames:
@@ -881,6 +883,18 @@ class RawReducer:
                     self._output_frames += batch.shape[0] * leg.nint
                 outs.append((k, batch))
         return outs, token
+
+    @contextlib.contextmanager
+    def _calling(self, programs: List[str]) -> Iterator[None]:
+        """The part ``dispatch.call``: one channel group's program calls
+        (``calls`` = programs called; attr ``programs``: their jit names
+        in call order, which pair the span with its runs in a device
+        trace)."""
+        with self.timeline.part("dispatch.call", calls=len(programs),
+                                byte_free=True) as sp:
+            if sp is not None:
+                sp.attrs["programs"] = programs
+            yield
 
     def _run_chunk(self, chunk: _Chunk, st: _StreamState
                    ) -> List[Tuple[int, np.ndarray]]:
@@ -1084,7 +1098,7 @@ class RawReducer:
             sinks = [_DirectSink(w) for w in writers]
         closed = []
         try:
-            with profile_trace(self.trace_logdir), observability.span(
+            with observability.span(
                 "reduce.pump", nfft=self.nfft,
                 out=str(getattr(writers[0], "path", "")),
             ):
@@ -1098,9 +1112,11 @@ class RawReducer:
                 t0 = time.perf_counter()
                 for sink in sinks:
                     sink.flush()
-                for sink in sinks:
-                    sink.close()
-                    closed.append(sink)
+                # The pass's far end: file close, rename, manifest.
+                with self.timeline.stage("close", byte_free=True):
+                    for sink in sinks:
+                        sink.close()
+                        closed.append(sink)
                 self.timeline.stages["stream"].seconds += (
                     time.perf_counter() - t0
                 )
@@ -1368,6 +1384,21 @@ class RawReducer:
                         header=hdr, timeline=self.timeline, kind="block")
 
     # -- whole-file conveniences ------------------------------------------
+    @contextlib.contextmanager
+    def _pass(self, root: str, out: str) -> Iterator:
+        """One pass to file(s), from its entry point's first line to its
+        return: the root span ``root`` (attr ``out``: the first product —
+        how a reader finds its own pass's trace) with the device profile
+        around it, so every span of the pass shares one trace id and the
+        profile holds them all.  Yields that span (``None`` with spans
+        off) and the pass's near end, the stage ``open``, for the entry
+        point to enter around everything up to the first read being
+        possible (the source and its index, the headers, the writers);
+        the far end, ``close``, is the pump's (:meth:`_pump_impl`)."""
+        with profile_trace(self.trace_logdir), observability.span(
+                root, out=out) as sp:
+            yield sp, self.timeline.stage("open", byte_free=True)
+
     def _open_validated(self, raw_src: RawSource):
         """Shared prologue of every whole-recording entry point: open the
         source, reject empty/truncated recordings, derive the product
@@ -1443,15 +1474,16 @@ class RawReducer:
             if self.nbits != 32:
                 raise ValueError("nbits=8/16 quantized output is a SIGPROC "
                                  ".fil feature; FBH5 products are float32")
-            raw, hdr = self._open_validated(raw_src)
-            nif = STOKES_NIF[self.stokes]
-            w = FBH5Writer(
-                out_path, hdr, nifs=nif, nchans=hdr["nchans"],
-                compression=compression, chunks=chunks,
-            )
-            with observability.span("reduce.to_file", out=out_path):
+            with self._pass("reduce.to_file", out_path) as (_, opening):
+                with opening:
+                    raw, hdr = self._open_validated(raw_src)
+                    w = FBH5Writer(
+                        out_path, hdr, nifs=STOKES_NIF[self.stokes],
+                        nchans=hdr["nchans"], compression=compression,
+                        chunks=chunks, timeline=self.timeline,
+                    )
                 hdr["nsamps"] = self._pump(raw, w)
-            self._surface_integrity(raw, hdr)
+                self._surface_integrity(raw, hdr)
             return hdr
         if compression is not None:
             raise ValueError(".fil products are uncompressed; compression "
@@ -1489,23 +1521,27 @@ class RawReducer:
                              f"{len(out_paths)} paths")
         if len(set(out_paths)) != len(out_paths):
             raise ValueError(f"two products at one path: {out_paths}")
-        raw, _ = self._open_validated(raw_src)
-        nif = STOKES_NIF[self.stokes]
-        hdrs = [self.header_for(raw, k) for k in range(len(out_paths))]
-        writers = []
-        try:
-            for path, hdr in zip(out_paths, hdrs):
-                writers.append(FilWriter(path, hdr, nif, hdr["nchans"],
-                                         dtype=NARROW_DTYPES[self.nbits]))
-        except BaseException:
-            for w in writers:
-                w.abort()
-            raise
-        with observability.span("reduce.to_file", out=out_paths[0]):
+        with self._pass("reduce.to_file", out_paths[0]) as (_, opening):
+            with opening:
+                raw, _ = self._open_validated(raw_src)
+                nif = STOKES_NIF[self.stokes]
+                hdrs = [self.header_for(raw, k)
+                        for k in range(len(out_paths))]
+                writers = []
+                try:
+                    for path, hdr in zip(out_paths, hdrs):
+                        writers.append(FilWriter(
+                            path, hdr, nif, hdr["nchans"],
+                            dtype=NARROW_DTYPES[self.nbits],
+                            timeline=self.timeline))
+                except BaseException:
+                    for w in writers:
+                        w.abort()
+                    raise
             for hdr, nsamps in zip(hdrs, self._pump(raw, writers)):
                 hdr["nsamps"] = nsamps
-        for hdr in hdrs:
-            self._surface_integrity(raw, hdr)
+            for hdr in hdrs:
+                self._surface_integrity(raw, hdr)
         return hdrs
 
     def reduce_resumable(self, raw_src: RawSource, out_path: str,
@@ -1543,6 +1579,30 @@ class RawReducer:
                              "applies to .h5 output")
         if not is_h5 and chunks is not None:
             raise ValueError("chunks applies to .h5 output")
+        with self._pass("reduce.resumable", out_path) as (root, opening):
+            with opening:
+                raw, hdr, w, start_rows, resuming = self._resume_point(
+                    raw_src, out_path, compression, chunks)
+            if root is not None:
+                root.attrs["resumed"] = bool(resuming)
+            # _pump aborts the writer on error — file + cursor stay as
+            # the resume point (the writer's own crash contract); under
+            # the async plane the cursor may simply sit a few
+            # queued-but-unwritten slabs earlier, which the skip-frames
+            # replay re-reduces identically.
+            hdr["nsamps"] = self._pump(raw, w,
+                                       skip_frames=start_rows * self.nint)
+            self._surface_integrity(raw, hdr)
+        return hdr
+
+
+    def _resume_point(self, raw_src: RawSource, out_path: str,
+                      compression: Optional[str],
+                      chunks: Optional[Tuple[int, int, int]]):
+        """:meth:`reduce_resumable`'s near end: the source, the cursor a
+        re-run may continue from, and the writer opened on it ->
+        ``(raw, header, writer, rows to start at, resuming)``."""
+        is_h5 = out_path.endswith((".h5", ".hdf5"))
         raw, hdr = self._open_validated(raw_src)
         # Cursor identity: the member path list (single files keep the plain
         # string so pre-existing sidecars stay valid).
@@ -1612,6 +1672,7 @@ class RawReducer:
             w = ResumableFBH5Writer(
                 out_path, hdr, nif, hdr["nchans"], start_rows, self.nint,
                 cur, compression=compression, chunks=chunks,
+                timeline=self.timeline,
             )
         else:
             from blit.ops.narrow import NARROW_DTYPES
@@ -1619,18 +1680,9 @@ class RawReducer:
             w = ResumableFilWriter(
                 out_path, hdr, nif, hdr["nchans"], start_rows, self.nint,
                 cur, dtype=NARROW_DTYPES[self.nbits],
+                timeline=self.timeline,
             )
-        # _pump aborts the writer on error — file + cursor stay as the
-        # resume point (the writer's own crash contract); under the async
-        # plane the cursor may simply sit a few queued-but-unwritten slabs
-        # earlier, which the skip-frames replay re-reduces identically.
-        with observability.span("reduce.resumable", out=out_path,
-                                resumed=bool(resuming)):
-            hdr["nsamps"] = self._pump(raw, w,
-                                       skip_frames=start_rows * self.nint)
-        self._surface_integrity(raw, hdr)
-        return hdr
-
+        return raw, hdr, w, start_rows, resuming
 
 def resume_fil_ok(path: str, nif: int, nchans: int, rows: int,
                   dtype=np.float32) -> bool:
@@ -1681,7 +1733,7 @@ class ResumableFilWriter:
 
     def __init__(self, path: str, header: Dict, nif: int, nchans: int,
                  start_rows: int, nint: int, cursor: "ReductionCursor",
-                 dtype=np.float32):
+                 dtype=np.float32, timeline=None):
         from blit import integrity
         from blit.io.sigproc import read_fil_header, write_fil
 
@@ -1694,7 +1746,7 @@ class ResumableFilWriter:
         row_bytes = nif * nchans * self.dtype.itemsize
         self._mf = integrity.ManifestWriter(
             path, "fil", row_bytes=row_bytes,
-            writer=type(self).__name__)
+            writer=type(self).__name__, timeline=timeline)
         if start_rows > 0 and os.path.exists(path):
             # The cursor may record more frames than the agreed restart
             # point (the mesh writer restarts at a pod-wide minimum): clamp

@@ -179,9 +179,10 @@ def main() -> None:
     real_init = P.ResumableFilWriter.__init__
 
     def spying_init(self, path, header, nif, nchans, start_rows, nint,
-                    cursor):
+                    cursor, **kw):  # kw: the scan's timeline (ISSUE 36)
         starts.append(start_rows)
-        real_init(self, path, header, nif, nchans, start_rows, nint, cursor)
+        real_init(self, path, header, nif, nchans, start_rows, nint, cursor,
+                  **kw)
 
     P.ResumableFilWriter.__init__ = spying_init
     try:

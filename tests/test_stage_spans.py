@@ -65,6 +65,10 @@ def stage_spans(spans):
     return [s for s in spans if (s.get("attrs") or {}).get("stage") == 1]
 
 
+def part_spans(spans):
+    return [s for s in spans if (s.get("attrs") or {}).get("part") == 1]
+
+
 class SlowWriter:
     """The slab-writer contract, ``delay`` seconds per append."""
 
@@ -97,7 +101,8 @@ class TestToyPass:
     def test_span_durations_sum_to_the_stage_table(self, tmp_path):
         table, spans = toy_pass(tmp_path)
         total = collections.Counter()
-        for s in stage_spans(spans):
+        # A row is its stage spans or its part spans (ISSUE 36).
+        for s in stage_spans(spans) + part_spans(spans):
             total[s["name"]] += s["duration_s"]
         rows = {k: v for k, v in table.items()
                 if isinstance(v, dict) and v.get("seconds", 0) > 0}
@@ -420,6 +425,289 @@ class TestScanWindows:
         assert table["feed.read"]["bytes"] == table["read"]["bytes"]
         assert table["feed.put"]["bytes"] == table["read"]["bytes"]
         assert by_id[windows[0]["parent"]]["name"] == "scan.reduce"
+
+
+# -- ISSUE 36: a pass's two ends are stages, a stage's insides are parts -------
+
+# The stage=1 span names of the toy pass before ISSUE 36 (the parent's
+# tree, `toy_pass` there); `wait.*` come and go with what blocked.
+PARENT_STAGE_NAMES = {"device", "dispatch", "flush", "ingest", "link.put",
+                      "readback", "state.carry", "state.head", "stream",
+                      "write"}
+# ... and its `link.put` row: 16 bodies + the stream's head, every sample
+# dispatched once.
+PARENT_LINK_PUT = {"calls": 17, "bytes": 523264}
+PARTS_OF = {"coeffs": "dispatch", "link.put": "dispatch",
+            "dispatch.call": "dispatch", "write.digest": "write"}
+
+
+def inside(inner, outer, slack=1e-3):
+    """Does span ``inner`` lie inside ``outer`` (t0 is ``time.time()``,
+    the duration ``perf_counter``: a millisecond of slack)?"""
+    return (outer["t0"] - slack <= inner["t0"] and
+            inner["t0"] + inner["duration_s"]
+            <= outer["t0"] + outer["duration_s"] + slack)
+
+
+def one_pass_shape(spans, root_name, dispatch="dispatch"):
+    """What every pass to files must look like -> (root, the dispatching
+    thread's stage spans in start order)."""
+    assert len({s["trace"] for s in spans}) == 1
+    roots = [s for s in spans if s["name"] == root_name]
+    assert len(roots) == 1 and roots[0]["parent"] is None
+    root = roots[0]
+    st = stage_spans(spans)
+    assert all(inside(s, root) for s in st), "a stage span outside the root"
+    tid = next(s["tid"] for s in st if s["name"] == dispatch)
+    assert root["tid"] == tid
+    mine = sorted((s for s in st if s["tid"] == tid
+                   and s["name"] != "stream"), key=lambda s: s["t0"])
+    assert [s["name"] for s in mine].count("open") == 1
+    assert [s["name"] for s in mine].count("close") == 1
+    first = mine[0]
+    last = max(mine, key=lambda s: s["t0"] + s["duration_s"])
+    assert first["name"] == "open" and last["name"] == "close"
+    # What starts after `close` does lies inside it (an AsyncSink's close
+    # passes its flush barrier once more: a `wait.sink_flush`).
+    assert all(inside(s, last) for s in mine if s["t0"] > last["t0"])
+    by_id = {s["span"]: s for s in spans}
+    for end in (first, last):
+        up = end  # under the root: its child, or the pump wrapper's
+        while up["parent"] is not None:
+            up = by_id[up["parent"]]
+            assert (up.get("attrs") or {}).get("stage") != 1, up["name"]
+        assert up is root
+        assert end["attrs"]["bytes"] == 0
+    return root, mine
+
+
+class TestParts:
+    @pytest.mark.parametrize("spans_on", [True, False])
+    def test_a_part_is_a_row_and_a_span_under_the_open_stage(
+            self, spans_on, monkeypatch):
+        monkeypatch.setattr(observability, "_TRACER",
+                            Tracer(enabled=spans_on))
+        tl = Timeline()
+        with tl.stage("dispatch", byte_free=True) as stage:
+            with tl.part("coeffs", nbytes=64, calls=2) as part:
+                time.sleep(0.002)
+            with tl.part("coeffs", nbytes=16):
+                pass
+        row = tl.report()["coeffs"]
+        assert (row["calls"], row["bytes"]) == (3, 80)
+        assert 0.002 <= row["seconds"] <= tl.stages["dispatch"].seconds
+        assert "byte_free" not in row
+        spans = observability.tracer().span_dicts()
+        if not spans_on:
+            assert spans == [] and stage is None and part is None
+            return
+        got = part_spans(spans)
+        assert [s["name"] for s in got] == ["coeffs", "coeffs"]
+        assert [s["attrs"] for s in got] == [{"bytes": 64, "part": 1},
+                                             {"bytes": 16, "part": 1}]
+        assert all(s["parent"] == stage.span_id for s in got)
+        assert [s["name"] for s in stage_spans(spans)] == ["dispatch"]
+        assert sum(s["duration_s"] for s in got) == pytest.approx(
+            tl.stages["coeffs"].seconds)
+
+    @pytest.mark.parametrize("spans_on", [True, False])
+    def test_a_part_is_one_entry_in_the_flight_ring(self, spans_on,
+                                                    monkeypatch):
+        tr, rec = observability.tracer(), observability.flight_recorder()
+        monkeypatch.setattr(tr, "enabled", spans_on)
+        rec.clear()
+        with Timeline().part("write.digest", nbytes=64):
+            pass
+        got = [e for e in rec.events() if e["name"] == "write.digest"]
+        assert [e["kind"] for e in got] == ["span" if spans_on else "stage"]
+        assert got[0]["bytes"] == 64
+
+    def test_the_toy_pass_has_its_ends_and_its_parts(self, tmp_path):
+        table, spans = toy_pass(tmp_path)
+        root, mine = one_pass_shape(spans, "reduce.to_file")
+        assert root["attrs"]["out"] == str(tmp_path / "toy.fil")
+        # The stage names are the parent's plus the two ends, less the
+        # zero-length `link.put` no reader could ever see open.
+        names = {s["name"] for s in stage_spans(spans)
+                 if not s["name"].startswith("wait.")}
+        assert names == (PARENT_STAGE_NAMES | {"open", "close"}) \
+            - {"link.put"}
+        assert table["open"]["calls"] == table["close"]["calls"] == 1
+        assert table["open"]["byte_free"] and table["close"]["byte_free"]
+        # Every part lies in a stage span of its enclosing stage's name,
+        # on that stage's thread, and takes no more than it.
+        by_id = {s["span"]: s for s in spans}
+        parts = part_spans(spans)
+        assert {s["name"] for s in parts} == set(PARTS_OF)
+        for s in parts:
+            assert "stage" not in s["attrs"]
+            up = by_id[s["parent"]]
+            want = "open" if (s["name"] == "write.digest"
+                              and up["name"] == "open") else \
+                PARTS_OF[s["name"]]
+            assert up["name"] == want, (s["name"], up["name"])
+            assert up["tid"] == s["tid"] and inside(s, up)
+        for part, stage in PARTS_OF.items():
+            assert 0 < table[part]["seconds"] <= table[stage]["seconds"]
+
+    def test_link_put_keeps_its_counts_and_gains_seconds(self, tmp_path):
+        table, spans = toy_pass(tmp_path)
+        row = table["link.put"]
+        assert {k: row[k] for k in PARENT_LINK_PUT} == PARENT_LINK_PUT
+        assert row["seconds"] > 0
+        puts = [s for s in part_spans(spans) if s["name"] == "link.put"]
+        assert sum(s["attrs"]["bytes"] for s in puts) == row["bytes"]
+        # A put ends before its group's programs are called.
+        calls = [s for s in part_spans(spans)
+                 if s["name"] == "dispatch.call"]
+        assert len(calls) == table["dispatch"]["calls"] == 16
+        for put, call in zip(sorted(puts[1:], key=lambda s: s["t0"]),
+                             sorted(calls[1:], key=lambda s: s["t0"])):
+            assert put["t0"] + put["duration_s"] <= call["t0"] + 1e-3
+
+    def test_coeffs_is_a_miss_only_and_lies_in_dispatch(self, tmp_path):
+        table, spans = toy_pass(tmp_path)
+        assert (table["coeffs"]["calls"], table["coeffs"]["bytes"]) == (
+            1, 4 * NFFT * 4)
+        got = [s for s in part_spans(spans) if s["name"] == "coeffs"]
+        assert [s["attrs"]["nfft"] for s in got] == [NFFT]
+
+    def test_the_digest_reads_every_byte_of_the_fil(self, tmp_path):
+        table, spans = toy_pass(tmp_path)
+        from blit.io.sigproc import read_fil_header
+
+        out = str(tmp_path / "toy.fil")
+        _, header_bytes = read_fil_header(out)
+        row = table["write.digest"]
+        # `write`'s bytes (the slabs) and, folded at open, the header.
+        assert row["bytes"] - header_bytes == table["write"]["bytes"]
+        assert row["bytes"] == os.path.getsize(out)
+        assert row["calls"] == table["write"]["calls"] + 1
+        sinks = {s["tid"] for s in stage_spans(spans)
+                 if s["name"] == "write"}
+        folds = [s for s in part_spans(spans)
+                 if s["name"] == "write.digest"]
+        assert {s["tid"] for s in folds[1:]} == sinks
+
+    def test_three_products_call_every_leg_for_every_group(self, tmp_path):
+        # tests/test_reduce_fanout.py's toy: 4 dispatches of one channel
+        # group, three legs each, + the two small legs' head steps.
+        nsamps = 18 * 1024
+        raw = str(tmp_path / "r.raw")
+        synth_raw(raw, nblocks=4, obsnchan=4, ntime_per_block=nsamps // 4,
+                  seed=7, tone_chan=1)
+        observability.tracer().reset()
+        red = RawReducer(nfft=1024, nint=3, also=((8, 128), (64, 51)),
+                         chunk_frames=4, tune_online=False)
+        outs = [str(tmp_path / f"p{k}.fil") for k in range(3)]
+        within(300, lambda: red.reduce_to_files(raw, outs))
+        table = red.timeline.report()
+        spans = observability.tracer().span_dicts()
+        one_pass_shape(spans, "reduce.to_file")
+        assert table["dispatch.call"]["calls"] == 4 * 3 + 2
+        assert table["dispatch.call"]["calls"] == (
+            table["fanout.share"]["calls"] + 4)
+        calls = sorted((s for s in part_spans(spans)
+                        if s["name"] == "dispatch.call"),
+                       key=lambda s: s["t0"])
+        legs = ["jit_channelize_stream", "jit_channelize_0001",
+                "jit_channelize_0002"]
+        assert [s["attrs"]["programs"] for s in calls] == (
+            [legs[1:] + legs] + [legs] * 3)
+        # A bank per nfft, each built once; a digest per product.
+        assert table["coeffs"]["calls"] == 3
+        assert table["coeffs"]["bytes"] == 4 * 4 * (1024 + 8 + 64)
+        assert table["write.digest"]["bytes"] == sum(
+            os.path.getsize(p) for p in outs)
+
+    def test_a_resumed_pass_is_one_trace_with_both_ends(self, tmp_path):
+        from blit.pipeline import ReductionCursor
+
+        def reducer():
+            return RawReducer(nfft=NFFT, nint=NINT, chunk_frames=32)
+
+        raw = toy_raw(tmp_path)
+        out, plain = str(tmp_path / "res.fil"), str(tmp_path / "plain.fil")
+        within(120, lambda: reducer().reduce_to_file(raw, plain))
+        golden = open(plain, "rb").read()
+        for resumed in (False, True):
+            if resumed:  # as a crash after 64 rows leaves it
+                os.remove(out)
+                _cut_resumable(reducer(), raw, out, rows=64)
+            observability.tracer().reset()
+            red = reducer()
+            within(120, lambda: red.reduce_resumable(raw, out))
+            root, _ = one_pass_shape(observability.tracer().span_dicts(),
+                                     "reduce.resumable")
+            assert root["attrs"]["resumed"] is resumed
+            assert root["attrs"]["out"] == out
+            assert open(out, "rb").read() == golden
+            # A resume digests the kept rows again at open, a fresh start
+            # the header: either way every byte of the product, once.
+            assert red.timeline.report()["write.digest"]["bytes"] == len(
+                golden)
+        assert ReductionCursor.load(out) is None
+
+    def test_a_mesh_scan_opens_with_the_bank_and_closes_last(
+            self, tmp_path):
+        from blit.parallel.scan import reduce_scan_mesh_to_files
+
+        bank_bw = -187.5 / 4
+        paths = [[]]
+        for k in range(4):
+            paths[0].append(str(tmp_path / f"blc0{k}.raw"))
+            synth_raw(paths[0][k], nblocks=4, obsnchan=2,
+                      ntime_per_block=1024, seed=k, obsbw=bank_bw,
+                      obsfreq=8000.0 + (k + 0.5) * bank_bw)
+        os.makedirs(tmp_path / "out")
+        observability.tracer().reset()
+        tl = Timeline()
+        written = within(300, lambda: reduce_scan_mesh_to_files(
+            paths, out_dir=str(tmp_path / "out"), nfft=NFFT, nint=NINT,
+            window_frames=16, timeline=tl))
+        spans = observability.tracer().span_dicts()
+        (out, _), = written.values()
+        root, mine = one_pass_shape(spans, "scan.reduce")
+        assert root["attrs"]["out"] == out
+        table = tl.report()
+        by_id = {s["span"]: s for s in spans}
+        coeffs, = [s for s in part_spans(spans) if s["name"] == "coeffs"]
+        assert by_id[coeffs["parent"]]["name"] == "open"
+        assert table["coeffs"]["seconds"] <= table["open"]["seconds"]
+        # The scan's puts are parts inside `feed.put`, its digests inside
+        # `write` (and the header's inside `open`): one thread does all.
+        ups = collections.Counter(
+            by_id[s["parent"]]["name"] for s in part_spans(spans)
+            if s["name"] == "link.put")
+        assert set(ups) == {"feed.put"}
+        assert table["link.put"]["bytes"] == table["read"]["bytes"]
+        assert table["link.put"]["seconds"] <= table["feed.put"]["seconds"]
+        ups = collections.Counter(
+            by_id[s["parent"]]["name"] for s in part_spans(spans)
+            if s["name"] == "write.digest")
+        assert set(ups) == {"open", "write"} and ups["open"] == 1
+        assert table["write.digest"]["bytes"] == os.path.getsize(out)
+        assert "dispatch.call" not in table  # the scan's dispatch IS its call
+
+
+def _cut_resumable(red, raw, out, rows):
+    """Leave ``out`` as a crash after ``rows`` rows would: run the
+    resumable reduction with a writer that dies on the append after."""
+    from blit.pipeline import ResumableFilWriter
+
+    real = ResumableFilWriter.append
+
+    def dying(self, slab):
+        if self.nsamps >= rows:
+            raise OSError("injected: the disk went away")
+        real(self, slab)
+
+    ResumableFilWriter.append = dying
+    try:
+        with pytest.raises(OSError, match="injected"):
+            within(120, lambda: red.reduce_resumable(raw, out))
+    finally:
+        ResumableFilWriter.append = real
 
 
 class TestProfileTrace:
