@@ -23,9 +23,12 @@ TPU notes (pallas_guide.md; SURVEY.md §7 "hard parts"):
   classic four-step decomposition) — each stage is a contiguous batch of
   ≤8K-point FFTs that XLA tiles comfortably; the twiddle and transpose fuse.
 - All control flow is static; ``jax.lax`` only.  No data-dependent shapes.
-- The FIR stage runs on separate real/imag float32 planes (``dequantize``),
-  keeping it real-valued VPU/MXU work; the FFT recombines via
-  ``lax.complex``.
+- The FIR stage runs on separate real/imag float32 planes, keeping it
+  real-valued VPU/MXU work; the FFT recombines via ``lax.complex``.
+- The XLA path reads the samples as the int32 words they are
+  (:func:`sample_words`) and filters them where they lie: 8 coarse
+  channels on the sublanes, a block's ``nfft`` points on the lanes, the
+  blocks down a major axis (``channelize``'s ``words_core``).
 """
 
 from __future__ import annotations
@@ -241,6 +244,39 @@ def fft(z: jax.Array, *, method: str = "auto") -> jax.Array:
     return jnp.swapaxes(b, -1, -2).reshape(z.shape)
 
 
+def _stokes_products(x: Tuple[jax.Array, jax.Array],
+                     y: Optional[Tuple[jax.Array, jax.Array]],
+                     stokes: str) -> list:
+    """The ``nif`` detection products of one spectrum, a plane each:
+    ``x`` and ``y`` are the two polarizations' planar ``(re, im)``, any
+    shape (everything here is elementwise); ``y`` is ``None`` at one
+    polarization, which only supports total power."""
+    xr, xi = x
+    if y is None:
+        if stokes not in ("I", "XX"):
+            raise ValueError(f"stokes={stokes!r} needs 2 pols, got 1")
+        return [xr**2 + xi**2]
+    yr, yi = y
+    xx = xr**2 + xi**2
+    yy = yr**2 + yi**2
+    if stokes == "I":
+        return [xx + yy]
+    if stokes == "XX":
+        return [xx]
+    if stokes == "YY":
+        return [yy]
+    if stokes == "XXYY":
+        return [xx, yy]
+    # X·conj(Y):
+    xy_re = xr * yr + xi * yi
+    xy_im = xi * yr - xr * yi
+    if stokes == "full":
+        return [xx, yy, xy_re, xy_im]
+    if stokes == "IQUV":
+        return [xx + yy, xx - yy, 2 * xy_re, -2 * xy_im]
+    raise ValueError(f"unknown stokes {stokes!r}")
+
+
 def detect_stokes_planar(
     sr: jax.Array, si: jax.Array, stokes: str
 ) -> jax.Array:
@@ -255,32 +291,13 @@ def detect_stokes_planar(
       - ``"IQUV"``: Stokes parameters                 (nif=4)
     Single-pol input only supports total power.
     """
-    npol = sr.shape[-3]
-    if npol == 1:
-        if stokes not in ("I", "XX"):
-            raise ValueError(f"stokes={stokes!r} needs 2 pols, got 1")
-        p = (sr**2 + si**2)[..., 0, :, :]
-        return p[..., None, :, :]
-    xr, yr = sr[..., 0, :, :], sr[..., 1, :, :]
-    xi, yi = si[..., 0, :, :], si[..., 1, :, :]
-    xx = xr**2 + xi**2
-    yy = yr**2 + yi**2
-    if stokes == "I":
-        return (xx + yy)[..., None, :, :]
-    if stokes == "XX":
-        return xx[..., None, :, :]
-    if stokes == "YY":
-        return yy[..., None, :, :]
-    if stokes == "XXYY":
-        return jnp.stack([xx, yy], axis=-3)
-    # X·conj(Y):
-    xy_re = xr * yr + xi * yi
-    xy_im = xi * yr - xr * yi
-    if stokes == "full":
-        return jnp.stack([xx, yy, xy_re, xy_im], axis=-3)
-    if stokes == "IQUV":
-        return jnp.stack([xx + yy, xx - yy, 2 * xy_re, -2 * xy_im], axis=-3)
-    raise ValueError(f"unknown stokes {stokes!r}")
+    pols = [(sr[..., p, :, :], si[..., p, :, :])
+            for p in range(sr.shape[-3])]
+    prods = _stokes_products(pols[0], pols[1] if len(pols) > 1 else None,
+                             stokes)
+    if len(prods) == 1:
+        return prods[0][..., None, :, :]
+    return jnp.stack(prods, axis=-3)
 
 
 def detect_stokes(spec: jax.Array, stokes: str) -> jax.Array:
@@ -299,6 +316,10 @@ def integrate(power: jax.Array, nint: int) -> jax.Array:
     shape = power.shape[:-2] + (nframes // nint, nint, power.shape[-1])
     return power.reshape(shape).sum(axis=-2)
 
+
+# Rows of a vector register's (8, 128) tile: the coarse channels the XLA
+# path and :func:`channelize_lanes` lay side by side on the sublanes.
+_SUBLANES = 8
 
 # Kernel resolution of the most recent channelize trace (see the
 # assignment inside channelize; read via last_kernel_plan()).
@@ -319,6 +340,66 @@ _CHANNELIZE_STATIC = (
     "channel_block", "dtype", "fqav_by", "dft_order", "pfb_kernel",
     "detect_kernel", "tail_kernel",
 )
+
+
+def _resolve_pfb_kernel(pfb_kernel: str, *, nfft: int, nblk: int, ntap: int,
+                        npol: int, resolved: str, twisted: bool,
+                        dtype: str) -> str:
+    """:func:`channelize`'s ``pfb_kernel`` made concrete for a block of
+    ``nblk`` blocks of ``nfft`` samples: ``"xla"`` | ``"pallas"`` |
+    ``"fused1"`` (``resolved``: the FFT method, ``twisted``: its order)."""
+    if pfb_kernel not in ("auto", "xla", "pallas", "fused1"):
+        raise ValueError(f"bad pfb_kernel {pfb_kernel!r}")
+    backend = jax.default_backend()
+    pol_ok = npol == 2
+    if pfb_kernel == "auto":
+        from blit.ops import pallas_pfb
+
+        # Prefer the fullest fusion that compiles natively AND fits the
+        # VMEM budget: fused1 (dequant+PFB+DFT stage 1; interleaved A/B
+        # 8.3-8.7 vs 6.4 GB/s) → pallas (dequant+PFB) → xla.  Large-
+        # nframes chunks (e.g. the '0002' preset) exceed any fine tile
+        # and take the XLA path.
+        pfb_kernel = "xla"
+        if backend == TPU_BACKEND and pol_ok:
+            # default_factors only inside the matmul guard: the FFT paths
+            # accept nfft values it cannot factor.
+            factors = (
+                dftmod.default_factors(nfft) if resolved == "matmul" else ()
+            )
+            if (
+                len(factors) >= 2
+                and not twisted  # fused1 ignores dft_order='twisted'
+                and pallas_pfb.fused1_fits(
+                    nfft, nblk, ntap, factors[0], dtype
+                )
+            ):
+                pfb_kernel = "fused1"
+            elif pallas_pfb.fits(nfft, nblk, ntap, dtype):
+                pfb_kernel = "pallas"
+    elif pfb_kernel in ("pallas", "fused1"):
+        if not pol_ok:
+            raise ValueError(
+                f"pfb_kernel={pfb_kernel!r} needs npol=2 complex int8"
+            )
+        pallas_interpret(backend)  # raises off TPU/CPU
+        if pfb_kernel == "fused1":
+            if resolved != "matmul":
+                raise ValueError(
+                    "pfb_kernel='fused1' fuses the matmul-DFT's first "
+                    "stage; it needs fft_method='matmul'"
+                )
+            if len(dftmod.default_factors(nfft)) < 2:
+                raise ValueError(
+                    "pfb_kernel='fused1' needs a multi-factor nfft "
+                    f"(> {dftmod.DIRECT_DFT_MAX})"
+                )
+            if twisted:
+                raise ValueError(
+                    "pfb_kernel='fused1' emits natural order; it does not "
+                    "combine with dft_order='twisted'"
+                )
+    return pfb_kernel
 
 
 @functools.partial(jax.jit, static_argnames=_CHANNELIZE_STATIC)
@@ -345,7 +426,12 @@ def channelize(
     Args:
       voltages: int8 ``(nchan_coarse, ntime, npol, 2)`` (GuppiRaw.read_block
         layout, blit/io/guppi.py) with ``ntime`` a multiple of ``nfft`` and
-        ``ntime//nfft >= ntap + nint - 1``.
+        ``ntime//nfft >= ntap + nint - 1`` — or the same memory as
+        :func:`sample_words`, ``(nchan_coarse, ntime)`` with one int32
+        (int16 at one polarization) per time sample.  Either form gives
+        the same bits: the XLA path (``pfb_kernel`` resolved to ``"xla"``)
+        reads words, the Pallas fronts int8, and the other form reaches
+        each through a bitcast inside the program.
       coeffs: ``(ntap, nfft)`` PFB prototype from :func:`pfb_coeffs`.
       nfft: fine channels per coarse channel (the rawspec product size; 2**20
         for the hi-res product).
@@ -374,13 +460,27 @@ def channelize(
         factor.  Callers must map the channel axis with
         :func:`blit.ops.fqav.fqav_range`.
 
+    Where things lie in the XLA path (DESIGN.md §3; the nested
+    ``words_core``): 8 coarse channels on the sublanes, a block's ``nfft``
+    points on the lanes, the blocks down a major axis — words tiled (8
+    channels x 128 samples) ARE rows ``(nchan/8, nblk*8, nfft)`` tiled
+    over their last two axes, so nothing moves before the filter, a tap
+    is a run of whole tiles, the integration sums a major axis and the
+    product is put together by moving major axes only.
+
     Returns:
       float32 ``(ntime_out, nif, nchan_coarse*nfft)`` in blit's canonical
       ``(time, pol, chan)`` layout — channel fastest, fine channels fftshifted
       within each coarse channel so the DC artifact sits at fine index
       ``nfft//2`` (despike parity, blit/ops/despike.py).
     """
-    nchan, _, npol, _ = voltages.shape
+    if voltages.ndim == 2:  # sample_words: one word per time sample
+        nchan, ntime = voltages.shape
+        npol = voltages.dtype.itemsize // 2
+    else:
+        nchan, ntime, npol, ncomp = voltages.shape
+        if ncomp != 2:
+            raise ValueError(f"channelize: (re, im) pairs, got {ncomp}")
     if precision == "highest":
         prec = jax.lax.Precision.HIGHEST
     elif precision is None:
@@ -438,58 +538,16 @@ def channelize(
     # so "auto" = pallas on the TPU and the jnp path elsewhere
     # (interpret-mode pallas is for the CPU tests only).  The kernel needs
     # npol=2 int8 input; other shapes fall back.
-    if pfb_kernel not in ("auto", "xla", "pallas", "fused1"):
-        raise ValueError(f"bad pfb_kernel {pfb_kernel!r}")
     backend = jax.default_backend()
-    pol_ok = voltages.shape[2] == 2 and voltages.shape[3] == 2
-    if pfb_kernel == "auto":
-        from blit.ops import pallas_pfb
-
-        # Prefer the fullest fusion that compiles natively AND fits the
-        # VMEM budget: fused1 (dequant+PFB+DFT stage 1; interleaved A/B
-        # 8.3-8.7 vs 6.4 GB/s) → pallas (dequant+PFB) → xla.  Large-
-        # nframes chunks (e.g. the '0002' preset) exceed any fine tile
-        # and take the XLA path.
-        nblk = voltages.shape[1] // nfft
-        pfb_kernel = "xla"
-        if backend == TPU_BACKEND and pol_ok:
-            # default_factors only inside the matmul guard: the FFT paths
-            # accept nfft values it cannot factor.
-            factors = (
-                dftmod.default_factors(nfft) if resolved == "matmul" else ()
-            )
-            if (
-                len(factors) >= 2
-                and not twisted  # fused1 ignores dft_order='twisted'
-                and pallas_pfb.fused1_fits(
-                    nfft, nblk, ntap, factors[0], dtype
-                )
-            ):
-                pfb_kernel = "fused1"
-            elif pallas_pfb.fits(nfft, nblk, ntap, dtype):
-                pfb_kernel = "pallas"
-    elif pfb_kernel in ("pallas", "fused1"):
-        if not pol_ok:
-            raise ValueError(
-                f"pfb_kernel={pfb_kernel!r} needs npol=2 complex int8"
-            )
-        pallas_interpret(backend)  # raises off TPU/CPU
-        if pfb_kernel == "fused1":
-            if resolved != "matmul":
-                raise ValueError(
-                    "pfb_kernel='fused1' fuses the matmul-DFT's first "
-                    "stage; it needs fft_method='matmul'"
-                )
-            if len(dftmod.default_factors(nfft)) < 2:
-                raise ValueError(
-                    "pfb_kernel='fused1' needs a multi-factor nfft "
-                    f"(> {dftmod.DIRECT_DFT_MAX})"
-                )
-            if twisted:
-                raise ValueError(
-                    "pfb_kernel='fused1' emits natural order; it does not "
-                    "combine with dft_order='twisted'"
-                )
+    pfb_kernel = _resolve_pfb_kernel(
+        pfb_kernel, nfft=nfft, nblk=ntime // nfft, ntap=ntap, npol=npol,
+        resolved=resolved, twisted=twisted, dtype=dtype)
+    # The XLA path reads the samples as words, where they lie; the Pallas
+    # kernels the int8 they are made of.  Either form of input reaches
+    # either through a bitcast inside the program.
+    if (pfb_kernel == "xla") != (voltages.ndim == 2):
+        voltages = (_word_samples(voltages) if voltages.ndim == 2
+                    else _samples_as_words(voltages))
     use_pallas_pfb = pfb_kernel == "pallas"
     use_fused1 = pfb_kernel == "fused1"
     interp = (use_pallas_pfb or use_fused1) and pallas_interpret(backend)
@@ -522,7 +580,7 @@ def channelize(
         from blit.ops.pallas_dft import tail2_fits
 
         _kw = dict(
-            npol=voltages.shape[2],
+            npol=npol,
             esize=2 if dtype == "bfloat16" else 4,
         )
         _factors = dftmod.default_factors(nfft)
@@ -532,12 +590,11 @@ def channelize(
             _factors, **_kw)
         td_eligible = pallas_detect.tail2_detect_fits(
             _factors, stokes=stokes, **_kw)
-        _nframes = voltages.shape[1] // nfft - ntap + 1
+        _nframes = ntime // nfft - ntap + 1
         tail_eligible = (
             len(_factors) == 3
             and tail2_fits(
-                voltages.shape[0] * voltages.shape[2] * _nframes
-                * _factors[0],
+                nchan * npol * _nframes * _factors[0],
                 _factors[1], _factors[2], dtype,
             )
         )
@@ -658,18 +715,13 @@ def channelize(
                 sr, si = sr.astype(jnp.float32), si.astype(jnp.float32)
             power = detect_stokes_planar(sr, si, stokes)
             return integrate(power, nint)
-        if use_pallas_pfb:
-            from blit.ops.pallas_pfb import pfb_dequant
+        if not use_pallas_pfb:
+            return words_core(v)
+        from blit.ops.pallas_pfb import pfb_dequant
 
-            fr, fi = pfb_dequant(
-                v, shifted_coeffs, dtype=dtype, interpret=interp,
-            )
-        else:
-            re, im = dequantize(v, dtype=work_dtype)  # (cb, ntime, npol)
-            re = jnp.moveaxis(re, -1, 1)  # (cb, npol, ntime)
-            im = jnp.moveaxis(im, -1, 1)
-            fr = pfb_frontend(re, wcoeffs)  # (cb, npol, nframes, nfft)
-            fi = pfb_frontend(im, wcoeffs)
+        fr, fi = pfb_dequant(
+            v, shifted_coeffs, dtype=dtype, interpret=interp,
+        )  # (cb, npol, nframes, nfft)
         sr, si = fft_planar(
             fr, fi, method=fft_method, precision=prec, dtype=dtype,
             order="twisted" if twisted else "natural",
@@ -684,6 +736,87 @@ def channelize(
             power = dftmod.untwist(power, dftmod.default_factors(nfft))
         return power
 
+    def words_core(words):
+        """The XLA path: ``(cb, T)`` words → power ``(cg, ntime_out, nif,
+        c, nfft)``, channel ``g * c + j`` at ``[g, :, :, j]``.
+
+        What the filter slices and what the integration sums lie on a
+        MAJOR axis; the two axes a vector register tiles hold what every
+        operation is elementwise over — ``c`` coarse channels (8, a
+        register's sublanes, where 8 divides a larger ``cb``; else all of
+        them) and the ``nfft`` points of a block.  The words arrive tiled
+        (8 channels x 128 samples), in memory ``(cb/8, T/128; 8, 128)``;
+        with ``T = nblk * nfft`` and ``nfft`` a multiple of 128 that is,
+        letter for letter, the memory of ROWS ``(cg, nblk * c, nfft)``
+        tiled over their last two axes — a block's ``c`` channels one
+        after the other, block after block — so no sample moves before
+        the filter reads it, tap ``k`` of every frame is the rows from
+        ``k * c`` on (whole tiles, at any ``k``), and the float32 planes
+        of all ``nblk`` blocks are never written: each tap widens its own
+        slice of the words.  Blocks and channels are ONE axis on purpose:
+        given ``(cg, nblk, c, nfft)`` the v5e's compiler puts the blocks
+        back on the sublanes (a copy of the words and a filter of
+        misaligned sublane slices); an axis it cannot split it cannot
+        re-tile.  (Frames on the sublanes, as ``(cb, npol, nblk, nfft)``
+        has them, cost two re-tiling passes over the float32 samples
+        besides: 69 % of the chip's seconds at ``nfft`` 1024, PERF.md
+        section 6, PR 37.)"""
+        cb, nsamp = words.shape
+        c = _SUBLANES if cb > _SUBLANES and cb % _SUBLANES == 0 else cb
+        cg = cb // c
+        nblk = nsamp // nfft
+        if nsamp % nfft or nblk < ntap:
+            raise ValueError(
+                f"channelize: {nsamp} samples are not >= {ntap} whole "
+                f"blocks of {nfft}")
+        nframes = nblk - ntap + 1
+        if nframes % nint:
+            raise ValueError(
+                f"integrate: nint={nint} does not divide nframes={nframes}")
+        rows = jnp.transpose(words.reshape(cg, c, nblk, nfft),
+                             (0, 2, 1, 3)).reshape(cg, nblk * c, nfft)
+        bits = 8 * words.dtype.itemsize
+
+        def plane(byte):
+            """Byte ``byte`` of every word (0: the first polarization's
+            real part), sign-extended, through the filter: ``(cg,
+            nframes * c, nfft)``."""
+            def tap(k):
+                x = jax.lax.shift_right_arithmetic(
+                    jax.lax.shift_left(
+                        rows[:, k * c:(k + nframes) * c],
+                        jnp.asarray(bits - 8 - 8 * byte, words.dtype)),
+                    jnp.asarray(bits - 8, words.dtype))
+                return wcoeffs[k] * x.astype(work_dtype)
+
+            acc = tap(0)
+            for k in range(1, ntap):
+                acc = acc + tap(k)
+            return acc
+
+        # A transform per polarization: stacked into one the four matmuls'
+        # outputs of both are alive at once (temporaries 4.5 GiB against
+        # 3.0 at bank.lowres's shape, the compiler's account for a v5e).
+        pols = []
+        for pol in range(npol):
+            sr, si = fft_planar(
+                plane(2 * pol), plane(2 * pol + 1), method=fft_method,
+                precision=prec, dtype=dtype,
+                order="twisted" if twisted else "natural",
+            )
+            # Detect + integrate accumulate in f32 (only the DFT
+            # intermediates stay half-width).
+            pols.append((sr.astype(jnp.float32), si.astype(jnp.float32)))
+        power = jnp.stack([
+            p.reshape(cg, nframes // nint, nint, c, nfft).sum(axis=2)
+            if nint > 1 else p.reshape(cg, nframes, c, nfft)
+            for p in _stokes_products(
+                pols[0], pols[1] if npol > 1 else None, stokes)], axis=2)
+        if twisted:
+            power = dftmod.untwist(power, dftmod.default_factors(nfft))
+        return power  # (cg, ntime_out, nif, c, nfft)
+
+    use_words = pfb_kernel == "xla"
     if channel_block and channel_block < nchan:
         if nchan % channel_block:
             raise ValueError(
@@ -698,13 +831,16 @@ def channelize(
             # transpose of the (already detected) power, the blocked
             # mode's price.
             power = jnp.moveaxis(power, 0, 2)  # (t, nif, g, cb, nfft)
-        else:
-            power = power.reshape((nchan,) + power.shape[2:])
+        else:  # the groups' leading axes (channels, or slabs of them)
+            power = power.reshape((-1,) + power.shape[2:])
     else:
         power = core(voltages)
-    if use_td:
-        # core's fused tail+detect already emitted the product layout
-        # (t, nif, [g,] cb, nfft); flatten the channel axes into place.
+    if use_words:
+        # (cg, t, nif, c, nfft) → (t, nif, cg, c, nfft): major axes only.
+        power = jnp.transpose(power, (1, 2, 0, 3, 4))
+    if use_td or use_words:
+        # The product layout but for the channel axes, (t, nif, ...,
+        # nfft): flatten them into place.
         out = power.reshape(power.shape[0], power.shape[1], nchan * nfft)
     else:
         # → (ntime_out, nif, nchan*nfft), channel fastest.
@@ -747,6 +883,15 @@ def _word_samples(words: jax.Array) -> jax.Array:
     return v.reshape(words.shape + (v.shape[-1] // 2, 2))
 
 
+def _samples_as_words(voltages: jax.Array) -> jax.Array:
+    """:func:`sample_words` in a program (the same bitcast the other way):
+    int8 ``(nchan, ntime, npol, 2)`` → ``(nchan, ntime)`` words."""
+    nchan, ntime, npol, ncomp = voltages.shape
+    return jax.lax.bitcast_convert_type(
+        voltages.reshape(nchan, ntime, npol * ncomp),
+        _word_dtype(npol, ncomp))
+
+
 def stream_step(
     tail: jax.Array, body: jax.Array, coeffs: jax.Array, **kw
 ) -> Tuple[jax.Array, jax.Array]:
@@ -764,6 +909,20 @@ def stream_step(
     body of the reducer's :func:`channelize_stream` and of the mesh's
     per-chip :func:`blit.parallel.mesh.band_stream`."""
     return _gross_step((tail, body), coeffs, **kw)
+
+
+def _reads_words(words: jax.Array, *, nfft: int, ntap: int = 4,
+                 fft_method: str = "auto", dft_order: str = "auto",
+                 pfb_kernel: str = "auto", dtype: str = "float32",
+                 **_) -> bool:
+    """Whether :func:`channelize` of such a block (``words``, its other
+    keywords) takes the XLA path, which reads the words as they are."""
+    resolved = resolve_fft_method(fft_method, nfft)
+    return _resolve_pfb_kernel(
+        pfb_kernel, nfft=nfft, nblk=words.shape[1] // nfft, ntap=ntap,
+        npol=words.dtype.itemsize // 2, resolved=resolved,
+        twisted=resolved == "matmul" and dft_order == "twisted",
+        dtype=dtype) == "xla"
 
 
 def _gross_step(parts: Tuple[jax.Array, ...], coeffs: jax.Array, *,
@@ -784,8 +943,10 @@ def _gross_step(parts: Tuple[jax.Array, ...], coeffs: jax.Array, *,
             parts, coeffs, nfft=nfft, ntap=kw.get("ntap", 4), block=lanes,
             frames=frames, stokes=kw.get("stokes", "I"))
     else:
-        power = channelize(_word_samples(gross[:, :used + state]), coeffs,
-                           **kw)
+        block = gross[:, :used + state]
+        if not _reads_words(block, **kw):  # a Pallas front: int8, as ever
+            block = _word_samples(block)
+        power = channelize(block, coeffs, **kw)
     return power, gross[:, used:used + state]
 
 
@@ -865,7 +1026,7 @@ def _fft_planes(xs: list) -> list:
 
 
 # Coarse channels :func:`channelize_lanes` works on at a time.
-_LANES_CHANNELS = 8
+_LANES_CHANNELS = _SUBLANES
 
 
 def lanes_block(nfft: int, nint: int, npol: int = 2, ntap: int = 4, *,

@@ -108,11 +108,29 @@ class TestPFB:
         np.testing.assert_allclose(y, x.reshape(4, 16) * h[0], rtol=1e-6)
 
 
+# Options of the XLA path, each to give what the reference gives and the
+# same bits from either form of input (ISSUE 37).
+WORDS_OPTIONS = {
+    "plain": {},
+    "channel_block": dict(channel_block=8),
+    "channel_block_small": dict(channel_block=2),
+    "fqav": dict(fqav_by=4),
+    "matmul": dict(fft_method="matmul"),
+    "matmul_channel_block": dict(fft_method="matmul", channel_block=8),
+    "bf16": dict(fft_method="matmul", dtype="bfloat16"),
+    "twisted": dict(fft_method="matmul", dft_order="twisted"),
+    "four_step": dict(fft_method="four_step"),
+    "highest": dict(fft_method="matmul", precision="highest"),
+}
+
+
 class TestChannelize:
-    @pytest.mark.parametrize("stokes", ["I", "XXYY", "full", "IQUV"])
-    def test_matches_numpy_reference(self, stokes):
+    @pytest.mark.parametrize("nchan", [3, 16])
+    @pytest.mark.parametrize("stokes",
+                             ["I", "XX", "YY", "XXYY", "full", "IQUV"])
+    def test_matches_numpy_reference(self, stokes, nchan):
         nfft, ntap, nint = 64, 4, 2
-        v = make_voltages(nchan=3, ntime=(ntap - 1 + 2 * nint) * nfft)
+        v = make_voltages(nchan=nchan, ntime=(ntap - 1 + 2 * nint) * nfft)
         h = ch.pfb_coeffs(ntap, nfft)
         got = np.asarray(
             ch.channelize(
@@ -121,8 +139,89 @@ class TestChannelize:
             )
         )
         want = ch.channelize_np(v, h, nfft=nfft, ntap=ntap, nint=nint, stokes=stokes)
-        assert got.shape == want.shape == (2, ch.STOKES_NIF[stokes], 3 * nfft)
+        assert got.shape == want.shape == (2, ch.STOKES_NIF[stokes],
+                                           nchan * nfft)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-2)
+
+    @pytest.mark.parametrize("npol", [2, 1])
+    @pytest.mark.parametrize("nint", [1, 3])
+    @pytest.mark.parametrize("nfft", [8, 128, 1024])
+    @pytest.mark.parametrize("nchan", [1, 2, 8, 16, 24])
+    def test_words_where_they_lie(self, nchan, nfft, nint, npol):
+        # The XLA path filters the words as rows (a block's channels one
+        # after the other, eight to a slab where eight divide more): the
+        # reference's product at every channel count on either side of a
+        # slab, and the same bits whether the samples come as int8 or as
+        # the words they are.
+        ntap = 4
+        v = make_voltages(nchan=nchan, ntime=(ntap - 1 + 2 * nint) * nfft,
+                          npol=npol, seed=nchan + nfft)
+        h = jnp.asarray(ch.pfb_coeffs(ntap, nfft))
+        # The matmul DFT, as on the chip (the CPU's FFT library does not
+        # give the same bits twice at 1024 points).
+        kw = dict(nfft=nfft, ntap=ntap, nint=nint)
+        got = np.asarray(ch.channelize(jnp.asarray(v), h,
+                                       fft_method="matmul", **kw))
+        want = ch.channelize_np(v, np.asarray(h), **kw)
+        kw["fft_method"] = "matmul"
+        assert got.shape == want.shape == (2, 1, nchan * nfft)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-2)
+        words = ch.sample_words(v)
+        assert words.dtype == (np.int32 if npol == 2 else np.int16)
+        same = np.asarray(ch.channelize(jnp.asarray(words), h, **kw))
+        assert same.tobytes() == got.tobytes()
+        assert ch.last_kernel_plan()["pfb_kernel"] == "xla"
+
+    @pytest.mark.parametrize("option", sorted(WORDS_OPTIONS))
+    def test_words_and_int8_give_the_same_bits(self, option):
+        nfft, ntap, nint = 128, 4, 2
+        kw = dict(nfft=nfft, ntap=ntap, nint=nint, stokes="IQUV",
+                  **WORDS_OPTIONS[option])
+        v = make_voltages(nchan=16, ntime=(ntap - 1 + 3 * nint) * nfft,
+                          seed=11)
+        h = jnp.asarray(ch.pfb_coeffs(ntap, nfft))
+        got = np.asarray(ch.channelize(jnp.asarray(v), h, **kw))
+        words = np.asarray(ch.channelize(jnp.asarray(ch.sample_words(v)),
+                                         h, **kw))
+        assert words.tobytes() == got.tobytes()
+        want = ch.channelize_np(v, np.asarray(h), nfft=nfft, ntap=ntap,
+                                nint=nint, stokes="IQUV")
+        by = kw.get("fqav_by", 1)
+        if by > 1:
+            from blit.ops.fqav import fqav
+
+            want = fqav(want, by)
+        assert got.shape == want.shape
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(
+            got / scale, want / scale,
+            atol=2e-2 if option == "bf16" else 1e-5)
+
+    def test_a_pallas_front_reads_words_too(self):
+        # Either form of input reaches either front end through a bitcast
+        # in the program (the kernels take int8, the XLA path words).
+        nfft, ntap = 128, 4
+        v = make_voltages(nchan=2, ntime=(ntap - 1 + 2) * nfft, seed=3)
+        h = jnp.asarray(ch.pfb_coeffs(ntap, nfft))
+        kw = dict(nfft=nfft, ntap=ntap, pfb_kernel="pallas")
+        got = np.asarray(ch.channelize(jnp.asarray(v), h, **kw))
+        assert ch.last_kernel_plan()["pfb_kernel"] == "pallas"
+        words = np.asarray(ch.channelize(jnp.asarray(ch.sample_words(v)),
+                                         h, **kw))
+        assert words.tobytes() == got.tobytes()
+        xla = np.asarray(ch.channelize(jnp.asarray(v), h, nfft=nfft,
+                                       ntap=ntap))
+        np.testing.assert_allclose(got, xla, rtol=1e-4, atol=1e-2)
+
+    def test_a_ragged_block_of_words_is_refused(self):
+        h = jnp.asarray(ch.pfb_coeffs(4, 64))
+        with pytest.raises(ValueError, match="whole blocks"):
+            ch.channelize(jnp.zeros((2, 4 * 64 + 3), jnp.int32), h, nfft=64)
+        with pytest.raises(ValueError, match="whole blocks"):
+            ch.channelize(jnp.zeros((2, 3 * 64, 2, 2), jnp.int8), h, nfft=64)
+        with pytest.raises(ValueError, match="does not divide nframes"):
+            ch.channelize(jnp.zeros((2, 6 * 64), jnp.int32), h, nfft=64,
+                          nint=2)
 
     def test_fqav_epilogue_matches_host_fqav(self):
         # On-device frequency averaging == host fqav of the full product

@@ -29,6 +29,7 @@ from blit.io.sigproc import read_fil_data  # noqa: E402
 from blit.observability import Timeline  # noqa: E402
 from blit.ops.channelize import (  # noqa: E402
     channelize,
+    channelize_np,
     channelize_stream,
     channels_per_dispatch,
     integrate_carry,
@@ -204,6 +205,34 @@ class TestTheProgram:
                                       sample_words(v[:, -STATE:]))
         want = channelize(v, h, nfft=NFFT, ntap=NTAP)
         assert np.asarray(out).tobytes() == np.asarray(want).tobytes()
+
+    @pytest.mark.parametrize("npol", [2, 1])
+    @pytest.mark.parametrize("nint", [1, 2])
+    @pytest.mark.parametrize("nfft", [8, 128, 1024])
+    @pytest.mark.parametrize("nchan", [1, 2, 8, 16, 24])
+    def test_a_step_is_the_whole_block(self, nchan, nfft, nint, npol):
+        # The stream's program hands the XLA path the WORDS it joined; the
+        # product's bits are those of the whole block as int8, at every
+        # channel count on either side of a slab of eight.
+        rng = np.random.default_rng(nchan * nfft + nint)
+        state = (NTAP - 1) * nfft
+        v = rng.integers(-128, 128, (nchan, state + 4 * nfft, npol, 2),
+                         dtype=np.int8)
+        h = jnp.asarray(pfb_coeffs(NTAP, nfft))
+        # The matmul DFT, as on the chip (the CPU's FFT library does not
+        # give the same bits twice at 1024 points).
+        kw = dict(nfft=nfft, ntap=NTAP, nint=nint, fft_method="matmul")
+        out, nxt = channelize_stream(
+            jnp.asarray(sample_words(v[:, :state])),
+            sample_words(v[:, state:]), h, **kw)
+        np.testing.assert_array_equal(np.asarray(nxt),
+                                      sample_words(v[:, -state:]))
+        want = channelize(v, h, **kw)
+        assert np.asarray(out).tobytes() == np.asarray(want).tobytes()
+        ref = channelize_np(v, np.asarray(h), nfft=nfft, ntap=NTAP,
+                            nint=nint)
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4,
+                                   atol=2e-2)
 
     def test_a_head_from_the_host_and_a_tail_from_the_chip_share_a_program(
             self):

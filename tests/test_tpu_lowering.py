@@ -350,6 +350,143 @@ class TestSmallNfftLegCompilesForTheChip:
         assert m.temp_size_in_bytes < 2 ** 26
 
 
+class TestNfft1024PathCompilesForTheChip:
+    """``bank.lowres``'s program and ``rawspec3.hires51``'s ``nfft`` 1024
+    leg (``channelize``'s XLA path through ``leg_programs``) COMPILED for
+    one chip of ``v5e:2x2`` at a reduced shape, 16 channels x 256 frames:
+    the pin that keeps the re-tiling of the float32 samples from coming
+    back (frames on the sublanes: two bare ``reshape``s and a fusion of
+    misaligned slices, 69 % of the chip's seconds; PERF.md section 6,
+    PR 37)."""
+
+    CB, FRAMES, N = 16, 256, 1024
+
+    def _entry_moves(self, compiled):
+        """``(bytes, line)`` of every bare ``reshape`` and ``copy`` of a
+        float32 array in the compiled program's entry computation."""
+        import re
+
+        text = compiled.as_text()
+        moves = []
+        for line in text[text.index("ENTRY"):].splitlines():
+            m = re.match(
+                r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]*)\]\S* "
+                r"(reshape|copy)\(", line)
+            if m:
+                size = 4 * int(np.prod([int(d) for d in
+                                        m.group(1).split(",") if d]))
+                moves.append((size, line.strip()[:100]))
+        return moves
+
+    def _compile(self, topo, name, **kw):
+        from jax.sharding import SingleDeviceSharding
+
+        chip = SingleDeviceSharding(topo.devices[0])
+
+        def spec(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                        sharding=chip)
+
+        compiled = ch.leg_programs(name)[0].lower(
+            spec((self.CB, (NTAP - 1) * self.N), "int32"),
+            spec((self.CB, self.FRAMES * self.N), "int32"),
+            spec((NTAP, self.N), "float32"),
+            nfft=self.N, ntap=NTAP, stokes="I", fft_method="matmul",
+            pfb_kernel="xla",  # what the cells' thousands of frames get
+            **kw).compile()
+        return compiled
+
+    def test_the_integrating_program_moves_no_float32_samples(
+            self, v5e_2x2):
+        compiled = self._compile(v5e_2x2, "channelize_stream",
+                                 nint=self.FRAMES)
+        words = self.CB * self.FRAMES * self.N * 4
+        big = [m for m in self._entry_moves(compiled) if m[0] >= words]
+        assert not big, big
+        # Temporaries: 2.07 times the words until PR 36, 1.04 now.
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * words
+
+    def test_the_per_frame_leg_moves_only_its_product(self, v5e_2x2):
+        # ``nint`` 1: the power leaves as the product, whose ``nif`` 1
+        # axis re-tiles it once on the way out (ROADMAP A2.2a); nothing
+        # larger moves, and not the float32 samples (four planes of it).
+        compiled = self._compile(v5e_2x2, "channelize_0002")
+        power = self.CB * self.FRAMES * self.N * 4
+        big = [m for m in self._entry_moves(compiled) if m[0] > power]
+        assert not big, big
+
+
+class TestAHiresProgramIsLoweredAsBefore:
+    """The XLA path reads words; the Pallas fronts (``fused1`` on the
+    chip: every hi-res cell) are handed int8 exactly as before ISSUE 37.
+    The stream's program cross-lowered for the TPU is, letter for letter,
+    the text of ``concat -> slice -> bitcast -> channelize(int8)`` written
+    out by hand — but for the source locations inside the serialized
+    Mosaic kernels, which name this file's lines."""
+
+    NCH, FR = 2, 8
+
+    @staticmethod
+    def _without_locations(text):
+        import base64
+        import re
+
+        from jax._src.lib import tpu
+        from jax._src.lib.mlir import ir
+
+        ctx = ir.Context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+
+        def body(m):
+            with ctx:
+                mod = ir.Module.parse(base64.b64decode(m.group(1)))
+                return "body: " + mod.operation.get_asm(
+                    enable_debug_info=False)
+
+        text, n = re.subn(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                          body, text)
+        return text, n
+
+    def _lower(self, fn, topo, monkeypatch, **kw):
+        from jax.sharding import SingleDeviceSharding
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        chip = SingleDeviceSharding(topo.devices[0])
+
+        def on_chip(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                        sharding=chip)
+
+        text = fn.lower(
+            on_chip((self.NCH, (NTAP - 1) * NFFT), "int32"),
+            on_chip((self.NCH, self.FR * NFFT), "int32"),
+            on_chip((NTAP, NFFT), "float32"), **kw).as_text()
+        assert ch.last_kernel_plan()["pfb_kernel"] == "fused1"
+        assert ch.last_kernel_plan()["tail_kernel"] == "tail2_detect"
+        return self._without_locations(text)
+
+    def test_the_stream_hands_a_pallas_front_int8(self, v5e_2x2,
+                                                  monkeypatch):
+        kw = dict(nfft=NFFT, ntap=NTAP, stokes="I")
+        state = (NTAP - 1) * NFFT
+
+        def channelize_stream(tail, body, coeffs):  # as until PR 36
+            gross = jnp.concatenate((tail, body), axis=1)
+            used = gross.shape[1] - state
+            return (ch.channelize(ch._word_samples(gross[:, :used + state]),
+                                  coeffs, **kw),
+                    gross[:, used:used + state])
+
+        want, kernels = self._lower(
+            jax.jit(channelize_stream, donate_argnames=("tail",)),
+            v5e_2x2, monkeypatch)
+        assert kernels == 2
+        got, _ = self._lower(ch.channelize_stream, v5e_2x2, monkeypatch,
+                             **kw)
+        assert got == want
+
+
 class TestKernelRequestsOffTpuAndCpu:
     def test_pallas_interpret_names_its_backends(self):
         assert device.pallas_interpret("tpu") is False
@@ -406,7 +543,8 @@ class TestReducerOnADeviceWithAMemoryLimit:
         kw = dict(nfft=1024, nint=24, chunk_frames=16)
         _, whole = P.RawReducer(**kw).reduce(path)
         assert whole.shape[0] == 3  # 77 frames: three rows, 5 dropped
-        monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 12_000_000)
+        # ~8 MB (12 until PR 37 halved the XLA path's temporaries).
+        monkeypatch.setattr(P, "hbm_bytes_limit", lambda: 8_000_000)
         red = P.RawReducer(**kw)
         _, grouped = red.reduce(path)
         full = red._channel_block((16, 16 * 1024, 2, 2))
