@@ -3,7 +3,8 @@ timed interval.
 
 - header geometry, and fch1/foff/tsamp as the RAW header implies them;
 - the injected tone in the product channel the headers predict;
-- chosen coarse channels x all spectra against ``reference.stokes_i``;
+- chosen coarse channels x all spectra against ``reference.stokes_i``'s
+  rows, which the run computes once (``refpool``) and keeps;
 - the guarantees the path gives today (configs/*.json ``guarantees``): no
   ``.partial`` left, the size the header implies, the manifest sidecar's
   size and CRC against the bytes on disk;
@@ -21,7 +22,6 @@ import json
 import os
 import struct
 import zlib
-from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -127,15 +127,17 @@ def rel_err(got, want) -> float:
                  / np.abs(want).max())
 
 
-def against_reference(path: str, slices, *, nslots: int, nfft: int, nint: int,
-                      ntap: int, despike: bool, rows: int,
-                      tolerance: float) -> dict:
-    """``slices``: one dict per checked coarse channel with ``volt`` (its
-    int8 stream), ``slot`` (its index among the product's coarse channels),
-    ``raw_hdr`` (its bank's RAW header), ``chan`` (its index in that bank)
-    and ``tone_fine_offset`` (or None where no tone was injected), counted
-    in fine channels of ``tone_nfft`` (the pass's finest product: the same
-    slices check every product of a pass, each at its own ``nfft``)."""
+def against_reference(path: str, slices, rows_of, *, nslots: int, nfft: int,
+                      nint: int, rows: int, tolerance: float) -> dict:
+    """``slices``: one dict per checked coarse channel with ``slot`` (its
+    index among the product's coarse channels), ``raw_hdr`` (its bank's RAW
+    header), ``chan`` (its index in that bank) and ``tone_fine_offset`` (or
+    None where no tone was injected), counted in fine channels of
+    ``tone_nfft`` (the pass's finest product: the same slices check every
+    product of a pass, each at its own ``nfft``).  ``rows_of(slot)`` gives
+    that channel's reference rows ``(nspectra, nfft)`` of THIS product: the
+    run computes them once (``refpool``) and every call — the warm-up's, a
+    pass's, the traced pass's — compares against the same kept rows."""
     hdr, _, data = open_fil(path)
     geometry = {"nchans": nslots * nfft, "nifs": 1, "nbits": 32,
                 "nsamps": rows}
@@ -148,14 +150,8 @@ def against_reference(path: str, slices, *, nslots: int, nfft: int, nint: int,
     for k, v in want.items():
         if abs(hdr[k] - v) > 1e-9 * max(1.0, abs(v)):
             raise Incorrect(f"product header {k}={hdr[k]}, want {v}")
-    # The reference rows of every checked channel, a thread each (NumPy's
-    # FFT releases the interpreter lock).
-    with ThreadPoolExecutor(max_workers=min(4, len(slices))) as ex:
-        refs = list(ex.map(lambda s: reference.stokes_i(
-            s["volt"], nfft=nfft, ntap=ntap, nint=nint, despike=despike),
-            slices))
     errs, tones = {}, {}
-    for i, s in enumerate(slices):
+    for s in slices:
         lo = s["slot"] * nfft
         got = data[:, 0, lo:lo + nfft]
         if not np.isfinite(got).all():
@@ -172,7 +168,7 @@ def against_reference(path: str, slices, *, nslots: int, nfft: int, nint: int,
                 raise Incorrect(f"tone found in channels {sorted(found)}, "
                                 f"headers predict {predicted}")
             tones[s["slot"]] = predicted
-        errs[s["slot"]] = rel_err(got, refs[i][:rows])
+        errs[s["slot"]] = rel_err(got, rows_of(s["slot"])[:rows])
     over = {slot: e for slot, e in errs.items() if e > tolerance}
     if over:
         raise Incorrect("; ".join(

@@ -13,13 +13,16 @@ own ``os.fsync`` of the last of them has returned.
 set-up   refuse without a TPU holding the cell's chips; fixed compile
          cache; empty tuning directory; ``make -B`` of blit/native; ask
          the machine what one file may hold and size the pass (``reduced``);
-         write the recording from ``--seed``; one warm-up pass, checked
-         against the plain reference.  All of it is ``setup_s``.
+         write the recording from ``--seed``; start the plain reference's
+         tasks in processes of their own (``refpool``); one warm-up pass
+         beside them; join them, keep their rows, check the warm-up's
+         products.  All of it is ``setup_s``.
 window   passes back to back; a pass starts only while the summed time of
          the passes so far is under ``--seconds``, and every started pass
          completes and counts.  ``reduce_rate`` is the median pass's (in
          a cell whose entry does not list it, the per-layer ``pass_rate``).
-         Checks run between passes, outside every timed interval.  A
+         Checks run between passes, outside every timed interval, against
+         the kept rows: no reference arithmetic runs beside a pass.  A
          compile inside a pass makes the run incorrect.
 traced   with ``--trace 1``, one more pass under ``jax.profiler``; the
          per-layer metrics come from it, from the window's rusage and
@@ -29,7 +32,9 @@ The harness holds no list of cells, traffic mixes, driver kinds or
 per-layer metrics: ``BENCHMARK.json`` names them and each is a file of its
 own (configs/, traffic/, drivers/, layer_metrics/, readers/, peaks.json).
 ``--rehearse`` runs the same code at toy sizes on the CPU and prints no
-metric; it proves nothing of the chip.
+metric; it proves nothing of the chip.  A run says what it took: ``[run]``
+on standard error, ahead of the numbers compared, gives the seconds from
+process start to the result line by phase.
 """
 
 from __future__ import annotations
@@ -63,6 +68,7 @@ FIRST_PRODUCT_BYTES = 1 << 16   # header + the first rows have landed
 WATCH_POLL_S = 0.02
 SMALL_PRODUCT_BYTES = 1 << 28   # read whole after every pass up to here
 MEMORY_HEADROOM_SHARE = 0.3   # of the machine's memory
+REFERENCE_SLACK_BYTES = 1 << 30   # kept over the guard's floor by the pool
 
 
 def memory_facts() -> dict:
@@ -79,14 +85,20 @@ def memory_facts() -> dict:
         return {}
 
 
-def memory_guard(stop: threading.Event) -> None:
+def memory_guard(stop: threading.Event, seen: dict) -> None:
     """End the run in order (SIGTERM -> the clean-up in ``run``) before the
     machine's own limit ends it without one: a run that meets that limit
     loses the machine.  Free, not available: the chip tool's limit counts
-    the page cache and ``/dev/shm``."""
+    the page cache and ``/dev/shm``.  ``seen`` keeps the lowest reading
+    and the floor, for the ``[run]`` line."""
     while not stop.wait(0.25):
         m = memory_facts()
-        if m and m["mem_free"] < MEMORY_HEADROOM_SHARE * m["mem_total"]:
+        if not m:
+            continue
+        seen["floor"] = MEMORY_HEADROOM_SHARE * m["mem_total"]
+        seen["lowest_mem_free"] = min(m["mem_free"],
+                                      seen.get("lowest_mem_free", 1 << 62))
+        if m["mem_free"] < seen["floor"]:
             print(f"benchmark refused: host memory nearly spent: {m}",
                   file=sys.stderr, flush=True)
             os.kill(os.getpid(), signal.SIGTERM)
@@ -447,9 +459,10 @@ def layer_metrics(cell: dict, evidence: dict) -> dict:
     return out
 
 
-def run(args) -> int:
+def run(args, memory_seen=None) -> int:
     import check
     import reference
+    import refpool
     import scratch
 
     rehearse = args.rehearse
@@ -478,6 +491,7 @@ def run(args) -> int:
     os.makedirs(os.environ["BLIT_TUNE_DIR"])
     made = [state]
     parts = {}
+    pool = None
     try:
         import jax
 
@@ -508,14 +522,14 @@ def run(args) -> int:
                           f"{device['kind']!r}; add it to benchmark/"
                           "peaks.json with its source")
         say("peaks", **peaks.get(device["kind"], {}))
-        parts["import_and_device_s"] = time.perf_counter() - T_START
+        parts["start_s"] = time.perf_counter() - T_START
 
         # blit/native is built on the machine that runs (-march=native).
         t0 = time.perf_counter()
         subprocess.run(["make", "-B", "-C", os.path.join(ROOT, "blit",
                                                           "native")],
                        check=True, stdout=subprocess.DEVNULL)
-        parts["native_build_s"] = time.perf_counter() - t0
+        parts["build_s"] = time.perf_counter() - t0
 
         # Size the pass from what this machine lets one file hold.
         scratch.raise_file_limit()
@@ -556,6 +570,19 @@ def run(args) -> int:
                             for ps in inputs["raws"]],
             seconds=parts["synth_s"], pool_blocks=t["pool_blocks"])
 
+        # The plain reference, once a run: a child process per (product,
+        # checked channel), started here, where nothing is timed, and
+        # joined before `setup_s` is taken.  No child is alive beside a
+        # measured or traced pass.
+        m = memory_facts()
+        pool = refpool.ReferencePool(
+            inputs["slices"], plan["products"], ntap=t["ntap"],
+            despike=t["despike"], workdir=os.path.join(rawdir, "reference"),
+            mem_free=lambda: memory_facts().get("mem_free", 1 << 62),
+            mem_floor=MEMORY_HEADROOM_SHARE * m.get("mem_total", 0)
+            + REFERENCE_SLACK_BYTES)
+        pool.start()
+
         problems = []   # what made the run incorrect
         bad = set()     # the passes with a product that was wrong
         worst = {}      # product -> its largest error against the reference
@@ -581,17 +608,18 @@ def run(args) -> int:
                     res["facts"].append(facts)
                     if against_reference:
                         said = check.against_reference(
-                            path, inputs["slices"], rows=p[rows],
-                            nslots=plan["nslots"], nfft=p["nfft"],
-                            nint=p["nint"], ntap=t["ntap"],
-                            despike=t["despike"], tolerance=p["tolerance"])
+                            path, inputs["slices"],
+                            lambda slot, name=p["name"]: pool.rows(name, slot),
+                            rows=p[rows], nslots=plan["nslots"],
+                            nfft=p["nfft"], nint=p["nint"],
+                            tolerance=p["tolerance"])
                         say("check.reference", pass_=res["tag"],
                             product=p["name"], **said)
                         note(p["name"], said["rel_err_by_slot"])
                     if golden is not None:
                         check.same_product(path, facts, golden[i], args.seed)
-                except check.Incorrect as e:
-                    note(p["name"], e.rel_err_by_slot)
+                except (check.Incorrect, refpool.ReferenceFailed) as e:
+                    note(p["name"], getattr(e, "rel_err_by_slot", {}))
                     problems.append(f"{res['tag']}, product {p['name']}: {e}")
                     bad.add(res["tag"])
                     say("INCORRECT", pass_=res["tag"], product=p["name"],
@@ -636,6 +664,12 @@ def run(args) -> int:
             if said:
                 problems.append(f"warmup, product {p['name']}: blit's own "
                                 f"verify_product: {said}")
+        t1 = time.perf_counter()
+        joined = pool.wait()
+        parts["reference_wait_s"] = time.perf_counter() - t1
+        say("reference", **joined, started_at_s=pool.started_at - T_START,
+            joined_at_s=pool.joined_at - T_START,
+            note="every child has ended; the rows are kept for the run")
         golden = None
         if whole_warmup:
             if verify(warm, "rows", read_all=False, against_reference=True):
@@ -643,7 +677,8 @@ def run(args) -> int:
         else:
             verify(warm, "warm_rows", read_all=False)
         discard(warm)
-        parts["warmup_check_s"] = time.perf_counter() - t0
+        parts["other_checks_s"] = time.perf_counter() - t0 \
+            - parts["reference_wait_s"]
         try:
             from blit.pipeline import RawReducer
 
@@ -685,12 +720,18 @@ def run(args) -> int:
             discard(last)
         measured_s = sum(p["wall_s"] for p in passes)
         window_raw = plan["raw_bytes"] * len(passes)
+        took = dict(parts, setup_s=setup_s, window_s=measured_s,
+                    between_pass_checks_s=time.perf_counter() - T_START
+                    - setup_s - measured_s)
+        t0 = time.perf_counter()
 
         # The traced pass and the per-layer metrics.
         breakdown = None
         if args.trace:
             tp, found = profiled_pass(cell, inputs, outdir)
-            verify(tp, "rows", read_all=False, golden=golden)
+            # (against the kept rows where no pass has been verified)
+            verify(tp, "rows", read_all=False, golden=golden,
+                   against_reference=golden is None)
             discard(tp)
             from readers import xplane
 
@@ -731,6 +772,8 @@ def run(args) -> int:
                     "perfect overlap the pass takes the largest, with none "
                     "their sum")
 
+        took["traced_pass_s"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
         peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use") or 0)
                    for d in devs)
         device["memory_peak_bytes"] = peak
@@ -759,16 +802,30 @@ def run(args) -> int:
                     p["first_product_s"] for p in passes),
                 "setup_s": setup_s,
             }
-            # `reduce_rate.<cells>`: an entry a later PR adds for its own
-            # cells (it may append to no accepted list) takes the statistic
-            # its name starts with.
-            metrics = {m["name"]: {"value": own[m["name"].split(".")[0]],
+            metrics = {m["name"]: {"value": own[m["name"]],
                                    "unit": m["unit"]}
                        for m in cell["end_to_end"]}
         say("window", passes=len(passes), measured_s=measured_s,
             raw_bytes=window_raw, mean_rate_GBps=window_raw / measured_s / 1e9,
             problems=problems,
             wall_s_by_pass=[p["wall_s"] for p in passes])
+        took["metrics_s"] = time.perf_counter() - t0
+        # What the run leaves goes now, so that the seconds it takes are on
+        # the `[run]` line (the `finally` below is for a run that ends
+        # early).
+        t0 = time.perf_counter()
+        pool.close()
+        for d in made:
+            shutil.rmtree(d, ignore_errors=True)
+        took["cleanup_s"] = time.perf_counter() - t0
+        took["start_to_result_s"] = time.perf_counter() - T_START
+        took["reference"] = {
+            "tasks": joined["tasks"], "workers": joined["workers"],
+            "pool_s": joined["pool_s"],
+            "longest_child_s": max(joined["child_s"].values(), default=None),
+            "last_child_joined_at_s": pool.joined_at - T_START}
+        took.update(memory_seen or {})
+        print("[run] " + json.dumps(took), file=sys.stderr, flush=True)
         # Every number that decided `correct`, beside its limit: the last
         # lines of stderr, and the last key of the result line.
         compiled = [p["compiles"]["backend_compiles"] for p in passes]
@@ -793,6 +850,8 @@ def run(args) -> int:
                           breakdown, compared=compared), flush=True)
         return 0
     finally:
+        if pool is not None:
+            pool.close()
         for d in made:
             shutil.rmtree(d, ignore_errors=True)
 
@@ -810,15 +869,15 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path[:0] = [HERE, ROOT]
     signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
-    guard_stop = threading.Event()
-    threading.Thread(target=memory_guard, args=(guard_stop,),
+    guard_stop, memory_seen = threading.Event(), {}
+    threading.Thread(target=memory_guard, args=(guard_stop, memory_seen),
                      daemon=True).start()
     import logging
 
     logging.basicConfig(stream=sys.stdout, format="  log> %(message)s")
     logging.getLogger("blit.pipeline").setLevel(logging.INFO)
     try:
-        return run(args)
+        return run(args, memory_seen)
     except Refused as e:
         # No result line; the reason goes LAST on stderr, which is all a
         # sealed machine hands back.

@@ -39,22 +39,52 @@ def lines_of(out, phase):
 # What a CPU rehearsal's traced run can report.  EVERY_PASS: the entries of
 # ``BENCHMARK.json`` with no ``workloads`` key whose reading is a row of the
 # stage table or a host clock: any ``blit reduce`` or ``blit scan`` pass has
-# them, so a cell added later reports them with no entry of its own.
-# PUMP_WAITS: the two waits only the ``blit reduce`` pump declares.
-EVERY_PASS = ["d2h_MB_per_GB", "dispatch_s_per_GB", "h2d_MB_per_GB",
-              "host_cpu_s_per_GB", "link_wait_s_per_GB", "read_rate",
-              "readback_s_per_GB", "write_s_per_GB"]
+# them, so a cell added later reports them with no entry of its own (PR 36
+# added a pass's two ends and three parts).  PUMP_WAITS: the two waits only
+# the ``blit reduce`` pump declares; PUMP_CALL: the part only its dispatch
+# has.  NO_TWIN: the rows ``bank.hires`` has no ``.first`` entry for.
+EVERY_PASS = ["close_s_per_GB", "coeffs_s_per_GB", "d2h_MB_per_GB",
+              "dispatch_s_per_GB", "h2d_MB_per_GB", "host_cpu_s_per_GB",
+              "link_wait_s_per_GB", "open_s_per_GB", "put_hold_s_per_GB",
+              "read_rate", "readback_s_per_GB", "write_digest_s_per_GB",
+              "write_s_per_GB"]
 PUMP_WAITS = ["wait_chunk_s_per_GB", "wait_out_slot_s_per_GB"]
+PUMP_CALL = "call_s_per_GB"
+NO_TWIN = ("open_s_per_GB", "close_s_per_GB")
+
+
+def run_line(p) -> dict:
+    """The ``[run]`` line of a run's standard error: it stands ahead of
+    the numbers compared, which are the last lines there."""
+    lines = p.stderr.splitlines()
+    (at,) = [i for i, ln in enumerate(lines) if ln.startswith("[run] ")]
+    assert all(ln.startswith("compared ") for ln in lines[at + 1:])
+    took = json.loads(lines[at][len("[run] "):])
+    # every phase is there, none is negative, and they fit the whole
+    phases = ["start_s", "build_s", "synth_s", "warmup_pass_s",
+              "reference_wait_s", "other_checks_s", "window_s",
+              "between_pass_checks_s", "traced_pass_s", "metrics_s",
+              "cleanup_s"]
+    assert all(took[k] >= 0 for k in phases), took
+    assert sum(took[k] for k in phases) <= took["start_to_result_s"]
+    # no child of the reference outlives set-up: none is alive beside a
+    # measured or a traced pass
+    assert took["reference"]["last_child_joined_at_s"] < took["setup_s"]
+    return took
+
 
 LOCAL_DRIVER = os.path.join(BENCH, "tests", "local_drivers", "reduce_each.py")
 
 
 def tree_with(tmp_path, *, traffic: dict, workloads, per_layer=(),
-              end_to_end=(), configs=(), files=None, drivers=()):
+              end_to_end=(), configs=(), files=None, drivers=(),
+              listed_under=()):
     """A temporary checkout that holds the benchmark as committed plus what
     a later PR would ADD, as files and entries only: traffic mixes, cells,
-    per-layer and end-to-end entries, configurations, driver files, any other file under
-    ``benchmark/`` (``files``: relative path -> text).  No file that is there is edited.
+    per-layer and end-to-end entries, configurations, driver files, any
+    other file under ``benchmark/`` (``files``: relative path -> text), and
+    the new cells' names at the END of the ``workloads`` of the accepted
+    entries ``listed_under`` names.  No file that is there is edited.
     -> its root."""
     b = tmp_path / "benchmark"
     shutil.copytree(BENCH, b,
@@ -73,6 +103,9 @@ def tree_with(tmp_path, *, traffic: dict, workloads, per_layer=(),
     bench["workloads"] += list(workloads)
     bench["per_layer"] += list(per_layer)
     bench["end_to_end"] += list(end_to_end)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in listed_under:
+            m["workloads"] += [w["name"] for w in workloads]
     for cfg in configs:   # a copy of an accepted one under a name of its own
         (b / "configs" / (cfg["name"] + ".json")).write_text(json.dumps(cfg))
         entry = next(c for c in bench["configs"] if c["source"]

@@ -11,7 +11,7 @@ import os
 
 import numpy as np
 import pytest
-from conftest import BENCH, EVERY_PASS, lines_of, run_harness
+from conftest import BENCH, EVERY_PASS, lines_of, run_harness, run_line
 
 import reference
 from readers import band_carry, stage_bytes, xplane
@@ -42,6 +42,9 @@ def test_end_to_end_run_at_toy_size_carries():
     (plan,) = lines_of(out, "plan")
     (band,) = plan["products"]
     assert plan["blocks"] == 108 and band["rows"] == 1 == band["warm_rows"]
+    (ref,) = lines_of(out, "reference")
+    assert ref["launched"] == ref["tasks"] == 4 == run_line(p)[
+        "reference"]["tasks"] and not ref["failed"]
     # the warm-up is a whole pass, checked against the reference in all
     # four banks' slots
     warm = json.loads(next(ln for ln in out

@@ -9,13 +9,19 @@ its ``reduce_rate`` too unsteady for any bound (the rig's product disk), so
 there the rate is the per-layer ``pass_rate`` and every per-layer entry of
 the cell names its other end-to-end metric, ``first_product_s``.  Such a
 file holds ``same_as`` and no reader: one (reader, arguments) pair is
-still written once."""
+still written once.
+
+What this file pinned as COUNTS for the five cells of PR 32 (the cells and
+configurations by name, the readers' and twins' number, which entries list
+no cell) is pinned for all the cells there are in
+``test_rawspec3_cell.py``, with ``FOLDED`` held against it there (PR 39
+folded the two)."""
 
 import json
 import os
 
 import pytest
-from conftest import BENCH, EVERY_PASS, PUMP_WAITS, ROOT
+from conftest import BENCH
 
 LM = os.path.join(BENCH, "layer_metrics")
 # the name until PR 31 -> the one entry that reads the same thing
@@ -116,58 +122,6 @@ def test_a_cell_reports_what_it_reported_under_the_folded_names(
     assert ("pass_rate" in mine) == (cell == UNSTEADY)
 
 
-def test_no_two_files_read_the_same_thing(bench_json):
-    """A (reader, arguments) pair is one metric: a second file over it is
-    the twin this PR removed.  42 entries and files became 26."""
-    seen, again = {}, {}
-    for m in bench_json["per_layer"]:
-        s = spec(m["name"])
-        if "same_as" in s:   # no reader, no arguments: the named file's
-            assert "reader" not in s and "args" not in s
-            again[m["name"]] = s["same_as"]
-            continue
-        key = (s["reader"], json.dumps(s.get("args", {}), sort_keys=True))
-        assert key not in seen, (m["name"], seen[key])
-        seen[key] = m["name"]
-    assert sorted(os.listdir(LM)) == sorted(
-        m["name"] + ".json" for m in bench_json["per_layer"])
-    assert len(seen) == 27   # the 26, and `pass_rate`
-    assert not set(FOLDED) & set(seen.values())
-    assert set(FOLDED.values()) <= set(seen.values())
-    # a second name for a reading exists only where it moves another
-    # end-to-end metric, in cells of its own
-    entries = {m["name"]: m for m in bench_json["per_layer"]}
-    cells = [w["name"] for w in bench_json["workloads"]]
-    assert len(again) == 18
-    for name, base in again.items():
-        assert name == base + FIRST and base in seen.values()
-        assert entries[name]["moves"] != entries[base]["moves"]
-        assert entries[name]["workloads"] == [UNSTEADY]
-        assert UNSTEADY not in entries[base].get("workloads", [])
-        for k in ("unit", "layer", "better", "source"):
-            assert entries[name][k] == entries[base][k]
-    assert set(cells) - set(
-        bench_json["end_to_end"][0]["workloads"]) == {UNSTEADY}
-
-
-def test_what_every_pass_can_report_lists_no_cell(bench_json):
-    """An entry whose reading any ``blit reduce`` or ``blit scan`` pass
-    has (a row of the stage table, a host clock, the device trace) has no
-    ``workloads`` key: it holds for every cell that reports ``reduce_rate``,
-    the ones a later PR adds too, which a PR that only adds could not
-    append to a list.  The pump's own waits list the ``reduce`` cells."""
-    listed = {m["name"]: m.get("workloads") for m in bench_json["per_layer"]}
-    assert sorted(n for n, cells in listed.items() if cells is None) \
-        == sorted(EVERY_PASS + ["device_busy_s_per_GB", "device_idle_share",
-                                "hbm_peak", "idle_named_share",
-                                "idle_dispatch_s_per_GB"])
-    for name in PUMP_WAITS:
-        assert listed[name] == ["bank.lowres", "rawspec.hires51"]
-        assert listed[name + FIRST] == [UNSTEADY]
-    # the output plane's stress cell reads the output plane's backpressure
-    assert listed["idle_output_s_per_GB" + FIRST] == [UNSTEADY]
-
-
 def test_the_files_with_arguments_of_their_own_stay():
     """``readers/band_carry.py`` holds the band's bytes per chip where
     ``readers/carry.py`` holds one chip's: two readers, two metrics."""
@@ -202,31 +156,3 @@ def test_the_link_wait_reads_the_links_own_row_and_the_idle_buckets_agree():
     assert timeline.read(spec("wait_chunk_s_per_GB")["args"], ev) == 0.25
     assert timeline.read(spec("wait_out_slot_s_per_GB")["args"], ev) is None
     assert timeline.read(spec("read_rate")["args"], ev) is None
-
-
-def test_the_cells_and_configurations_are_the_five_and_four(bench_json):
-    assert [w["name"] for w in bench_json["workloads"]] == [
-        "bank.hires", "bank.lowres", "band4.hires", "rawspec.hires51",
-        "band4.hires51"]
-    assert [c["name"] for c in bench_json["configs"]] == [
-        "gbt-bank", "gbt-band4", "gbt-bank-rawspec", "gbt-band4-rawspec"]
-    assert sum(w["chips"] == 4 for w in bench_json["workloads"]) == 2
-    assert bench_json["run_seconds"] == 35
-    assert [(m["name"], m["bound"]) for m in bench_json["end_to_end"]] == [
-        ("reduce_rate", 0.15), ("first_product_s", 0.06), ("setup_s", 0.25)]
-    assert bench_json["end_to_end"][1]["workloads"] == ["bank.hires",
-                                                        "bank.lowres"]
-    # every cell but the one whose product disk stalls (PERF.md section 2)
-    assert bench_json["end_to_end"][0]["workloads"] == [
-        "bank.lowres", "band4.hires", "rawspec.hires51", "band4.hires51"]
-    assert "workloads" not in bench_json["end_to_end"][2]
-    # no cell runs a `--product` preset: blit's can become BL's
-    for w in bench_json["workloads"]:
-        with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
-            t = json.load(f)
-        assert "--product" not in t["argv"] + t["rehearse"]["argv"]
-        assert "reducer" not in t and "reducer" not in t["rehearse"]
-    with open(os.path.join(ROOT, "benchmark", "traffic",
-                           "hires-19f.json")) as f:
-        assert json.load(f)["argv"][-4:] == ["--nfft", "1048576",
-                                             "--nint", "1"]

@@ -7,7 +7,7 @@ import json
 import os
 
 import pytest
-from conftest import BENCH, EVERY_PASS, PUMP_WAITS, run_harness
+from conftest import BENCH
 
 from readers import parts, spans, xplane
 from test_spans import DATA, chrome_spans
@@ -200,34 +200,7 @@ def test_the_new_files_load_and_no_two_read_the_same_thing(bench_json):
     assert timeline.read(spec("coeffs_s_per_GB")["args"], ev) is None
 
 
-# What a CPU traced run can report of the new readings: the rows of the
-# stage table (the three `idle_*` of the parts reader and `idle_ends` need
-# the chip's trace).  The pins of the accepted cells' own test files name
-# the lists of before PR 36 and fail by their wording (PERF.md section 7);
-# these carry their intent.
-ROWS = ["open_s_per_GB", "close_s_per_GB", "coeffs_s_per_GB",
-        "put_hold_s_per_GB", "write_digest_s_per_GB"]
-ROW_TWINS = ["coeffs_s_per_GB", "put_hold_s_per_GB", "write_digest_s_per_GB"]
-
-
-@pytest.mark.parametrize("cell, names", [
-    ("bank.hires", [n + ".first" for n in EVERY_PASS + PUMP_WAITS + ROW_TWINS]
-     + ["pass_rate"]),
-    ("bank.lowres", EVERY_PASS + PUMP_WAITS + ROWS + ["call_s_per_GB"]),
-    ("band4.hires", EVERY_PASS + ["first_product_wait_s"] + ROWS),
-])
-def test_a_cpu_traced_run_reports_the_new_rows(cell, names):
-    p, out = run_harness("--workload", cell, "--seed", "3600000006",
-                         "--seconds", "0.05", "--trace", "1", "--rehearse")
-    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
-    doc = json.loads(out[-1])
-    assert doc["correct"] is True and doc["breakdown"] is False
-    assert doc["metric_names"] == sorted(names)
-    # every pass has its two ends, once
-    (traced,) = [json.loads(ln[len("[traced] "):]) for ln in out
-                 if ln.startswith("[traced] ")]
-    st = traced["stages"]
-    assert st["open"]["calls"] == st["close"]["calls"] == 1
-    assert st["link.put"]["seconds"] > 0 and st["coeffs"]["calls"] >= 1
-    assert st["write.digest"]["bytes"] > st["write"]["bytes"]
-    assert ("dispatch.call" in st) == (cell != "band4.hires")
+# What a CPU traced run reports of these readings (the five rows of the
+# stage table; the three `idle_*` of the parts reader and `idle_ends` need
+# the chip's trace) is pinned with the accepted cells' own traced runs:
+# `conftest.EVERY_PASS` and `test_rehearse.py`.

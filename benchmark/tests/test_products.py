@@ -98,13 +98,16 @@ def test_a_misdeclared_nint_is_caught_by_the_reference(tmp_path):
     """The traffic file says the second product integrates 91 spectra; the
     command makes rows of 90.  Either way 301 frames hold 3 rows, so the
     guarantees pass and it is the reference, computed at the declared
-    setting, that the product disagrees with."""
+    setting, that the product disagrees with — in the warm-up, in every
+    pass and in the traced pass, against rows computed ONCE (PR 39: two
+    products x two channels are four children, however often they are
+    consulted)."""
     t = products_traffic("two-products", [TWO[0], ("coarse", 64, 90)])
     t["products"][1]["nint"] = 91
     root = tree_with(tmp_path, workloads=[CELL], drivers=[LOCAL_DRIVER],
                      traffic={"two-products": t})
     p, out = run_harness("--workload", "bank.two", "--seed", "3200000008",
-                         "--seconds", "0.05", "--trace", "0", "--rehearse",
+                         "--seconds", "0.05", "--trace", "1", "--rehearse",
                          root=root)
     assert p.returncode == 1, p.stdout[-3000:] + p.stderr[-3000:]
     doc = json.loads(out[-1])
@@ -113,6 +116,9 @@ def test_a_misdeclared_nint_is_caught_by_the_reference(tmp_path):
     # no pass's second product is ever verified, so each is held against
     # the reference again; the first product is found sound every time
     assert [w["pass_"] for w in wrong][:2] == ["warmup", "pass0"]
+    assert wrong[-1]["pass_"] == "traced"
+    (ref,) = lines_of(out, "reference")
+    assert ref["launched"] == ref["tasks"] == 4 and not ref["failed"]
     assert all(w["product"] == "coarse" and "tsamp" in w["problem"]
                for w in wrong)
     refs = lines_of(out, "check.reference")

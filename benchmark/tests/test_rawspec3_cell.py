@@ -6,15 +6,18 @@ changes the program may add no end-to-end entry), the accepted readings
 that list no cell come by themselves, five that list cells take its name.
 Its toy run end to end, its plan at the real size, its new reader on
 synthetic evidence — and, for the SIX cells, what ``test_layer_metrics.py``
-pinned for five: those pins fail by their own wording now (they name
-exactly five cells, four configurations, 27 readers) and a PR that adds a
-cell may not edit them; their intent is carried here."""
+pinned for five (the cells and configurations by name, the count of files
+with a reader and of ``same_as`` twins, which entries list no cell): PR 39,
+a ``benchmark`` PR, folded those pins into the ones here, with PR 36's ten
+readers and five twins counted in."""
 
 import json
 import os
 
 import pytest
-from conftest import BENCH, EVERY_PASS, PUMP_WAITS, ROOT, lines_of, run_harness
+from conftest import (BENCH, EVERY_PASS, PUMP_CALL, PUMP_WAITS, ROOT, lines_of,
+                      run_harness, run_line)
+from test_layer_metrics import FIRST, FOLDED, UNSTEADY
 
 from readers import carry, fanout, stage_bytes
 
@@ -26,7 +29,12 @@ LISTLESS = ["read_rate", "dispatch_s_per_GB", "idle_dispatch_s_per_GB",
             "link_wait_s_per_GB", "h2d_MB_per_GB", "d2h_MB_per_GB",
             "readback_s_per_GB", "write_s_per_GB", "device_busy_s_per_GB",
             "device_idle_share", "hbm_peak", "host_cpu_s_per_GB",
-            "idle_named_share"]
+            "idle_named_share",
+            # PR 36: a pass's two ends, the parts, the idle seconds in them
+            "open_s_per_GB", "close_s_per_GB", "coeffs_s_per_GB",
+            "put_hold_s_per_GB", "write_digest_s_per_GB",
+            "idle_ends_s_per_GB", "idle_coeffs_s_per_GB",
+            "idle_put_hold_s_per_GB", "idle_digest_s_per_GB"]
 # the accepted readings that list their cells and took this one's name, each
 # with the cells it listed before
 APPENDED = {"hbm_roof_share": ["rawspec.hires51"],
@@ -34,13 +42,16 @@ APPENDED = {"hbm_roof_share": ["rawspec.hires51"],
             "wait_out_slot_s_per_GB": ["bank.lowres", "rawspec.hires51"],
             "first_product_wait_s": ["band4.hires"],
             "carry_busy_s_per_GB": ["rawspec.hires51"]}
+# PR 36's part of the pump's dispatch came with the three `reduce` cells
+# listed, this one last
+LISTED_SINCE = {PUMP_CALL: ["bank.lowres", "rawspec.hires51", CELL]}
 # and the readings of what PR 34 added to the program
 NEW = ["p0001_busy_s_per_GB", "p0002_busy_s_per_GB",
        "fanout_saved_MB_per_GB", "p0001_roof_share", "p0002_roof_share",
        "fold_roof_share"]
 # of those, what a CPU rehearsal's traced run has something to read for
-ON_A_CPU = sorted(EVERY_PASS + PUMP_WAITS
-                  + ["first_product_wait_s", "fanout_saved_MB_per_GB"])
+ON_A_CPU = sorted(EVERY_PASS + PUMP_WAITS + [
+    PUMP_CALL, "first_product_wait_s", "fanout_saved_MB_per_GB"])
 
 
 def spec(name):
@@ -73,6 +84,9 @@ def test_end_to_end_run_at_toy_size():
     # ONE command made them: one stage table, the recording put once
     (warm,) = lines_of(out, "warmup")
     assert warm["whole_pass"] is True
+    (ref,) = lines_of(out, "reference")
+    assert ref["launched"] == ref["tasks"] == 6 == run_line(p)[
+        "reference"]["tasks"] and not ref["failed"]
     compared = [ln for ln in p.stderr.splitlines()
                 if ln.startswith("compared rel_err.")]
     assert [ln.split()[1] for ln in compared] \
@@ -137,7 +151,7 @@ def test_the_cells_metric_names_are_exactly_these(bench):
     assert sorted(m["name"] for m in cell["end_to_end"]) \
         == [RATE, "setup_s"]
     assert sorted(m["name"] for m in cell["per_layer"]) \
-        == sorted(LISTLESS + list(APPENDED) + NEW)
+        == sorted(LISTLESS + list(APPENDED) + list(LISTED_SINCE) + NEW)
     entries = {m["name"]: m for m in bench["per_layer"]}
     for m in cell["per_layer"]:
         e, s = entries[m["name"]], spec(m["name"])
@@ -150,9 +164,14 @@ def test_the_cells_metric_names_are_exactly_these(bench):
     # a new name goes at the END of an accepted list, and nothing else moves
     for name, before in APPENDED.items():
         assert entries[name]["workloads"] == before + [CELL]
+    for name, cells in LISTED_SINCE.items():
+        assert entries[name]["workloads"] == cells
     for name in NEW:
         assert entries[name]["workloads"] == [CELL]
-    assert [m["name"] for m in bench["per_layer"][-len(NEW):]] == NEW
+    # the new entries came in one block (PR 36's followed it)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
     # no accepted cell gained or lost a reading
     for w in bench["workloads"][:-1]:
         mine = run.load_cell(w["name"], rehearse=False)
@@ -185,10 +204,15 @@ def test_the_cells_and_configurations_are_the_six_and_five(bench):
     assert bench["end_to_end"][1]["workloads"] == ["bank.hires",
                                                    "bank.lowres"]
     assert "workloads" not in bench["end_to_end"][2]
+    # no cell runs a `--product` preset: blit's can become BL's
     for w in bench["workloads"]:
         with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
             t = json.load(f)
         assert "--product" not in t["argv"] + t["rehearse"]["argv"]
+        assert "reducer" not in t and "reducer" not in t["rehearse"]
+    with open(os.path.join(BENCH, "traffic", "hires-19f.json")) as f:
+        assert json.load(f)["argv"][-4:] == ["--nfft", "1048576",
+                                             "--nint", "1"]
 
 
 def test_no_two_files_read_the_same_thing(bench):
@@ -210,15 +234,45 @@ def test_no_two_files_read_the_same_thing(bench):
                                            s["reader"] + ".py"))
     assert sorted(os.listdir(LM)) == sorted(
         m["name"] + ".json" for m in bench["per_layer"])
-    assert len(seen) == 27 + len(NEW)
-    assert len(again) == 18
+    # PR 32's 26 and `pass_rate`, PR 34's six, PR 36's ten
+    assert len(seen) == 27 + len(NEW) + 10 == 43
+    # none of the names PR 32 folded away is back, each went somewhere
+    assert not set(FOLDED) & set(seen.values())
+    assert set(FOLDED.values()) <= set(seen.values())
+    # a second name for a reading exists only where it moves another
+    # end-to-end metric, in cells of its own: PR 32's 18 and PR 36's five
+    assert len(again) == 18 + 5 == 23
     entries = {m["name"]: m for m in bench["per_layer"]}
     for name, base in again.items():
         assert base in seen.values()
-        assert name == base + ".first"
+        assert name == base + FIRST
         assert entries[name]["moves"] != entries[base]["moves"]
-        assert entries[name]["workloads"] == ["bank.hires"]
-        assert "bank.hires" not in entries[base].get("workloads", [])
+        assert entries[name]["workloads"] == [UNSTEADY]
+        assert UNSTEADY not in entries[base].get("workloads", [])
+        for k in ("unit", "layer", "better", "source"):
+            assert entries[name][k] == entries[base][k]
+    # every cell but the one whose product disk stalls reports the rate
+    assert {w["name"] for w in bench["workloads"]} - set(
+        bench["end_to_end"][0]["workloads"]) == {UNSTEADY}
+
+
+def test_what_every_pass_can_report_lists_no_cell(bench):
+    """An entry whose reading any ``blit reduce`` or ``blit scan`` pass
+    has (a row of the stage table, a host clock, the device trace) has no
+    ``workloads`` key: it holds for every cell that reports ``reduce_rate``,
+    the ones a later PR adds too, which a PR that only adds could not
+    append to a list.  What only the ``blit reduce`` pump has (its two
+    waits, its dispatch's calls) lists the ``reduce`` cells."""
+    listed = {m["name"]: m.get("workloads") for m in bench["per_layer"]}
+    assert sorted(n for n, cells in listed.items() if cells is None) \
+        == sorted(LISTLESS)
+    assert set(EVERY_PASS) <= set(LISTLESS)
+    for name in PUMP_WAITS + [PUMP_CALL]:
+        assert listed[name] == ["bank.lowres", "rawspec.hires51", CELL]
+    for name in PUMP_WAITS:
+        assert listed[name + FIRST] == [UNSTEADY]
+    # the output plane's stress cell reads the output plane's backpressure
+    assert listed["idle_output_s_per_GB" + FIRST] == [UNSTEADY]
 
 
 # -- the new reader -------------------------------------------------------------

@@ -9,8 +9,8 @@ import os
 
 import numpy as np
 import pytest
-from conftest import (BENCH, EVERY_PASS, PUMP_WAITS, lines_of,
-                      run_harness)
+from conftest import (BENCH, EVERY_PASS, PUMP_CALL, PUMP_WAITS, lines_of,
+                      run_harness, run_line)
 
 import reference
 from readers import carry, stage_bytes, timeline, xplane
@@ -40,6 +40,9 @@ def test_end_to_end_run_at_toy_size():
     assert any(ln.startswith("[check.reference]") for ln in out)
     (plan,) = lines_of(out, "plan")
     assert plan["blocks"] == 108 and plan["products"][0]["rows"] == 1
+    (ref,) = lines_of(out, "reference")
+    assert ref["launched"] == ref["tasks"] == 2 == run_line(p)[
+        "reference"]["tasks"] and not ref["failed"]
 
 
 def test_traced_run_reports_only_what_a_cpu_can():
@@ -53,7 +56,8 @@ def test_traced_run_reports_only_what_a_cpu_can():
     doc = json.loads(out[-1])
     assert doc["correct"] is True and doc["breakdown"] is False
     assert set(STAGE_METRICS) <= set(EVERY_PASS)
-    assert doc["metric_names"] == sorted(EVERY_PASS + PUMP_WAITS)
+    assert doc["metric_names"] == sorted(EVERY_PASS + PUMP_WAITS
+                                         + [PUMP_CALL])
 
 
 def test_reference_integrates_51_spectra():

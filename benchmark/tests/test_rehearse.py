@@ -6,8 +6,9 @@ import os
 import shutil
 
 import pytest
-from conftest import (BENCH, EVERY_PASS, LOCAL_DRIVER, PUMP_WAITS, ROOT,
-                      lines_of, products_traffic, run_harness, tree_with)
+from conftest import (BENCH, EVERY_PASS, LOCAL_DRIVER, NO_TWIN, PUMP_CALL,
+                      PUMP_WAITS, ROOT, lines_of, products_traffic,
+                      run_harness, run_line, tree_with)
 
 E2E = {"bank.hires": ["first_product_s", "setup_s"],   # rate: `pass_rate`
        "bank.lowres": ["first_product_s", "reduce_rate", "setup_s"],
@@ -36,23 +37,38 @@ def test_end_to_end_run_at_toy_size(cell):
     # recording on RAM-backed scratch
     (plan,) = lines_of(out, "plan")
     assert os.path.dirname(plan["outdir"]) != os.path.dirname(plan["rawdir"])
+    # the reference once a run: a child per checked channel (two of the
+    # bank, one per bank of the band), all joined inside set-up
+    (ref,) = lines_of(out, "reference")
+    assert ref["launched"] == ref["tasks"] == run_line(p)["reference"][
+        "tasks"] == (4 if cell == "band4.hires" else 2)
+    assert not ref["failed"]
 
 
 @pytest.mark.parametrize("cell, names", [
     # `wait.link` is a declared wait: 0 calls on the CPU, and so 0.0 s/GB
-    ("bank.hires", sorted([n + ".first" for n in EVERY_PASS + PUMP_WAITS]
-                          + ["pass_rate"])),
-    ("band4.hires", sorted(EVERY_PASS + ["first_product_wait_s"])),
+    ("bank.hires", [n + ".first" for n in EVERY_PASS + PUMP_WAITS
+                    if n not in NO_TWIN] + ["pass_rate"]),
+    ("bank.lowres", EVERY_PASS + PUMP_WAITS + [PUMP_CALL]),
+    ("band4.hires", EVERY_PASS + ["first_product_wait_s"]),
 ])
 def test_traced_run_reports_only_what_a_cpu_can(cell, names):
-    """Host-side readers find their spans; the device readers find no
-    device plane in a CPU trace and return nothing."""
+    """Host-side readers find their spans; the device readers (the
+    ``idle_*`` of the parts and of the ends among them) find no device
+    plane in a CPU trace and return nothing.  Every pass has its two ends,
+    once, and its parts (PR 36; ``dispatch.call`` is the pump's)."""
     p, out = run_harness("--workload", cell, "--seed", "4", "--seconds",
                          "0.05", "--trace", "1", "--rehearse")
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     doc = last_doc(out)
-    assert doc["correct"] is True and doc["metric_names"] == names
+    assert doc["correct"] is True and doc["metric_names"] == sorted(names)
     assert doc["breakdown"] is False
+    (traced,) = lines_of(out, "traced")
+    st = traced["stages"]
+    assert st["open"]["calls"] == st["close"]["calls"] == 1
+    assert st["link.put"]["seconds"] > 0 and st["coeffs"]["calls"] >= 1
+    assert st["write.digest"]["bytes"] > st["write"]["bytes"]
+    assert ("dispatch.call" in st) == (cell != "band4.hires")
 
 
 def test_no_accelerator_exits_nonzero_before_any_work():
@@ -97,12 +113,13 @@ def test_a_cell_added_as_files_only_is_found(tmp_path, case):
     per-layer metric with its reader as new files and new entries, editing
     no file that is there.  The harness finds them by name, sizes every
     product of the pass and verifies each.  ``reduce_rate`` lists its cells
-    (every cell but ``bank.hires``) and an accepted list takes no new name,
-    so the cell brings its rate as ``reduce_rate.<its own>``: the harness
-    takes the statistic from the name's stem, and an accepted reading
-    comes along as a ``same_as`` file."""
+    (every cell but ``bank.hires``): the new cell's name goes at the END of
+    that list (the check refuses a new end-to-end entry from a PR that
+    changes the program), the accepted readings that list no cell then
+    come by themselves, and a second name for an accepted reading is a
+    ``same_as`` file."""
     cell = case["cell"]
-    rate = "reduce_rate." + cell["name"]
+    rate = "reduce_rate"
     files = {
         "readers/passes_in_window.py":
             "def read(args, ev):\n"
@@ -125,9 +142,7 @@ def test_a_cell_added_as_files_only_is_found(tmp_path, case):
             configs = [dict(json.load(f), name="gbt-bank-three")]
     root = tree_with(
         tmp_path, traffic={t["name"]: t}, workloads=[cell], files=files,
-        drivers=drivers, configs=configs, end_to_end=[{
-            "name": rate, "unit": "GB/s", "better": "higher", "bound": 0.15,
-            "source": "host_clock", "workloads": [cell["name"]]}],
+        drivers=drivers, configs=configs, listed_under=[rate],
         per_layer=[{
             "name": "passes_in_window", "unit": "passes", "better": "higher",
             "source": "program_counter", "layer": "whole host path",
@@ -141,9 +156,10 @@ def test_a_cell_added_as_files_only_is_found(tmp_path, case):
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     doc = last_doc(out)
     assert doc["correct"] is True and doc["failed"] == 0
-    # its own entries, `setup_s`'s none, and nothing that moves a rate it
-    # does not report
-    assert doc["metric_names"] == ["passes_in_window", "read_rate.added"]
+    # its own entries, what every pass can report, and nothing that lists
+    # other cells or moves a metric it does not report
+    assert doc["metric_names"] == sorted(
+        EVERY_PASS + ["passes_in_window", "read_rate.added"])
     if case is ONE_PRODUCT:   # and untraced: its own rate, first rows, set-up
         p, out = run_harness("--workload", cell["name"], "--seed", "5",
                              "--seconds", "0.05", "--trace", "0",
