@@ -98,36 +98,46 @@ def rawspec_product_path(stem: str, k: int) -> str:
     return f"{stem}.rawspec.{k:04d}.fil"
 
 
-def _reduce_products(args: argparse.Namespace) -> List[tuple]:
-    """The ``(nfft, nint)`` list of a ``blit reduce``; refuses by flag,
-    before any byte is read, what cannot hold for every product alike."""
-    if args.product is not None:
-        from blit.pipeline import PRODUCT_PRESETS
-
-        return [PRODUCT_PRESETS[args.product]]
+def _product_list(args: argparse.Namespace, cmd: str, alone) -> List[tuple]:
+    """The ``(nfft, nint)`` list of a ``blit reduce`` / ``blit scan``
+    (rawspec's ``-f`` / ``-t`` pairing: one ``--nint`` per ``--nfft``);
+    refuses by flag, before any byte is read, what cannot hold for every
+    product alike.  ``alone``: ``(flag, given)`` of the flags that go
+    with ONE product only."""
     if len(args.nfft) != len(args.nint):
         raise SystemExit(
-            f"blit reduce: --nfft lists {len(args.nfft)} products and "
+            f"blit {cmd}: --nfft lists {len(args.nfft)} products and "
             f"--nint {len(args.nint)}: give one --nint per --nfft")
     products = list(zip(args.nfft, args.nint))
     for nfft, _ in products:
         if args.fqav > 1 and nfft % args.fqav:
             raise SystemExit(
-                f"blit reduce: --fqav {args.fqav} does not divide --nfft "
+                f"blit {cmd}: --fqav {args.fqav} does not divide --nfft "
                 f"{nfft}: the averaging must hold for every product")
     if len(products) > 1:
-        for flag, given in (("--resume", args.resume),
-                            ("--compression", args.compression)):
+        for flag, given in alone:
             if given:
                 raise SystemExit(
-                    f"blit reduce: {flag} with several products is not "
+                    f"blit {cmd}: {flag} with several products is not "
                     "supported (ROADMAP B1): run one product per command, "
                     "or all of them without it")
-        if args.output.endswith((".h5", ".hdf5", ".fil")):
-            raise SystemExit(
-                "blit reduce: with several products -o is a STEM (product "
-                "k lands at <stem>.rawspec.000k.fil), not a product path: "
-                f"{args.output!r}")
+    return products
+
+
+def _reduce_products(args: argparse.Namespace) -> List[tuple]:
+    """The ``(nfft, nint)`` list of a ``blit reduce`` (a preset, or
+    :func:`_product_list`); with several, ``-o`` is a stem."""
+    if args.product is not None:
+        from blit.pipeline import PRODUCT_PRESETS
+
+        return [PRODUCT_PRESETS[args.product]]
+    products = _product_list(args, "reduce", (
+        ("--resume", args.resume), ("--compression", args.compression)))
+    if len(products) > 1 and args.output.endswith((".h5", ".hdf5", ".fil")):
+        raise SystemExit(
+            "blit reduce: with several products -o is a STEM (product "
+            "k lands at <stem>.rawspec.000k.fil), not a product path: "
+            f"{args.output!r}")
     return products
 
 
@@ -288,24 +298,13 @@ def _cmd_stream(args: argparse.Namespace) -> int:
     return 0
 
 
-def _cmd_scan(args: argparse.Namespace) -> int:
-    from blit.config import default_window_frames, mesh_defaults
-    from blit.inventory import get_inventory
-    from blit.observability import Timeline
-    from blit.parallel.scan import (
-        reduce_scan_mesh_to_files,
-        reduce_scan_pool_to_files,
-        scan_window_frames,
-    )
+def _scan_window(args: argparse.Namespace, mdef: dict, sharded: bool):
+    """``(wf, tuning, depths, sharded)`` of a ``blit scan`` of ONE
+    product: the effective window, where it came from, the sharded
+    plane's rotation depths and whether that plane runs."""
+    from blit.config import default_window_frames
+    from blit.parallel.scan import scan_window_frames
 
-    mdef = mesh_defaults()
-    # Parallelism selection (ISSUE 9): --sharded = the fully-threaded
-    # sharded reduction plane; --pool = the per-player pool fallback /
-    # byte-identity oracle; neither = SiteConfig/BLIT_MESH_SHARDED picks
-    # between the sharded plane and the serial mesh window loop.
-    sharded = args.sharded or (mdef["sharded"] and not args.pool)
-
-    invs = [get_inventory(args.file_re or r"\.raw$", root=args.root)]
     # The EFFECTIVE window (library default + scan_window_frames), so the
     # stats line reports what actually executed.  An unset --window-frames
     # consults this rig's tuning profile first (blit/tune.py): the scan's
@@ -373,6 +372,42 @@ def _cmd_scan(args: argparse.Namespace) -> int:
                 "--sharded (the default mesh loop carries it across "
                 "windows)")
         sharded = False  # the site's default plane cannot carry: mesh loop
+    return wf, tuning, depths, sharded
+
+
+def _cmd_scan(args: argparse.Namespace) -> int:
+    from blit.config import mesh_defaults
+    from blit.inventory import get_inventory
+    from blit.observability import Timeline
+    from blit.parallel.scan import (
+        reduce_scan_mesh_to_files,
+        reduce_scan_pool_to_files,
+        scan_window_frames,
+    )
+
+    mdef = mesh_defaults()
+    # Parallelism selection (ISSUE 9): --sharded = the fully-threaded
+    # sharded reduction plane; --pool = the per-player pool fallback /
+    # byte-identity oracle; neither = SiteConfig/BLIT_MESH_SHARDED picks
+    # between the sharded plane and the serial mesh window loop.
+    sharded = args.sharded or (mdef["sharded"] and not args.pool)
+
+    # rawspec's spelling: a comma list makes every product from ONE read
+    # and ONE upload of each mesh window (the mesh loop's legs).
+    (args.nfft, args.nint), *also = _product_list(args, "scan", (
+        ("--resume", args.resume), ("--compression", args.compression),
+        ("--sharded", args.sharded), ("--pool", args.pool),
+        ("--search", args.search)))
+    invs = [get_inventory(args.file_re or r"\.raw$", root=args.root)]
+    if also:
+        # One grid for every product, in frames of the largest --nfft;
+        # every integration is folded across windows, so none sizes it.
+        wf = scan_window_frames(max([args.nfft] + [f for f, _ in also]), 1,
+                                args.window_frames)
+        tuning = {"source": "explicit" if args.window_frames else "default"}
+        depths, sharded = {}, False
+    else:
+        wf, tuning, depths, sharded = _scan_window(args, mdef, sharded)
     tl = Timeline()
     parallel = "sharded" if sharded else ("pool" if args.pool else "mesh")
     if args.search:
@@ -489,22 +524,24 @@ def _cmd_scan(args: argparse.Namespace) -> int:
         )
     else:
         written = reduce_scan_mesh_to_files(
-            args.session, args.scan, resume=args.resume,
+            args.session, args.scan, resume=args.resume, also=tuple(also),
             trace_logdir=args.trace_logdir, **kw,
         )
-    for band, (path, hdr) in sorted(written.items()):
-        print(
-            json.dumps(
-                {
-                    "band": band,
-                    "output": path,
-                    "nsamps": hdr.get("nsamps"),
-                    "nchans": hdr.get("nchans"),
-                    "fch1": hdr.get("fch1"),
-                    "foff": hdr.get("foff"),
-                }
+    for band, made in sorted(written.items()):
+        # (Of several products a line each, in the order asked for.)
+        for path, hdr in made if also else [made]:
+            print(
+                json.dumps(
+                    {
+                        "band": band,
+                        "output": path,
+                        "nsamps": hdr.get("nsamps"),
+                        "nchans": hdr.get("nchans"),
+                        "fch1": hdr.get("fch1"),
+                        "foff": hdr.get("foff"),
+                    }
+                )
             )
-        )
     # Per-stage throughput (read/device/readback/write), like blit reduce.
     print(json.dumps({"window_frames": wf, "parallel": parallel,
                       "tuning": tuning, "stages": tl.report(),
@@ -2364,11 +2401,21 @@ def main(argv: Optional[List[str]] = None) -> int:
     ps.add_argument("root", help="data tree root (as `blit inventory`)")
     ps.add_argument("session", help="e.g. AGBT22B_999_01")
     ps.add_argument("scan", help="4-digit scan number, e.g. 0011")
-    ps.add_argument("-o", "--output-dir", required=True)
+    ps.add_argument("-o", "--output-dir", required=True,
+                    help="band <b> lands at <dir>/band<b>.fil (.h5 with "
+                         "--compression); of several products, product k "
+                         "at <dir>/band<b>.rawspec.000k.fil")
     ps.add_argument("--file-re", default=None,
                     help=r"inventory filename filter (default \.raw$)")
-    ps.add_argument("--nfft", type=int, default=1024)
-    ps.add_argument("--nint", type=int, default=1)
+    ps.add_argument("--nfft", type=_int_list, default=[1024],
+                    help="fine channels per coarse channel; a comma list "
+                         "(rawspec's -f 1048576,8,1024) makes several band "
+                         "products from ONE read and ONE upload of every "
+                         "mesh window (not with --resume, --compression, "
+                         "--sharded, --pool or --search)")
+    ps.add_argument("--nint", type=_int_list, default=[1],
+                    help="spectra per output row; one per --nfft entry "
+                         "(rawspec's -t 51,128,3072)")
     ps.add_argument("--stokes", default="I")
     ps.add_argument("--fqav", type=int, default=1,
                     help="per-chip frequency averaging before the stitch")
@@ -2386,8 +2433,14 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "given).  Default: 8*2^20 samples' worth of "
                          "frames — i.e. max(8, 2^23/nfft) — in whole "
                          "integrations where one fits; 8 frames at nfft "
-                         "2^20 do NOT fit four 16 GB chips (pass 2)")
-    ps.add_argument("--max-frames", type=int, default=None)
+                         "2^20 do NOT fit four 16 GB chips (pass 2).  Of "
+                         "several products: frames of the largest --nfft, "
+                         "one grid for all")
+    ps.add_argument("--max-frames", type=int, default=None,
+                    help="reduce this many frames at most (of several "
+                         "products: frames of the largest --nfft, and each "
+                         "product is what its own command makes of the "
+                         "recording cut to them)")
     ps.add_argument("--trace-logdir", default=None,
                     help="write a device-only JAX profiler trace of the "
                          "window loop (.xplane.pb; host and Python "

@@ -911,6 +911,17 @@ def stream_step(
     return _gross_step((tail, body), coeffs, **kw)
 
 
+def head_step(words: jax.Array, coeffs: jax.Array, **kw
+              ) -> Tuple[jax.Array, jax.Array]:
+    """One chip's FIRST step of a leg whose filter state is shorter than
+    the stream's head: ``words`` ``(nchan, head)`` holds the leg's
+    ``(ntap-1)*nfft`` words of state and, after them, samples that are
+    its data.  Returns ``(product, next_tail)`` as :func:`stream_step`
+    does.  The body of :func:`leg_programs`' ``head`` and of the mesh's
+    per-chip :func:`blit.parallel.mesh.band_programs`."""
+    return _gross_step((words,), coeffs, **kw)
+
+
 def _reads_words(words: jax.Array, *, nfft: int, ntap: int = 4,
                  fft_method: str = "auto", dft_order: str = "auto",
                  pfb_kernel: str = "auto", dtype: str = "float32",
@@ -973,7 +984,7 @@ def leg_programs(name: str):
         return stream_step(tail, body, coeffs, **kw)
 
     def head(words, coeffs, **kw):
-        return _gross_step((words,), coeffs, **kw)
+        return head_step(words, coeffs, **kw)
 
     for fn in (step, head):
         fn.__name__ = fn.__qualname__ = name

@@ -26,7 +26,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from blit.device import host_link
 from blit.observability import Timeline
-from blit.ops.channelize import channelize, integrate_carry, stream_step
+from blit.ops.channelize import (
+    _STREAM_STATIC,
+    channelize,
+    head_step,
+    integrate_carry,
+    stream_step,
+)
 from blit.ops.despike import despike
 
 BAND_AXIS = "band"
@@ -72,6 +78,12 @@ PARTITION_RULES: Dict[str, P] = {
     # own bank's samples from window to window (band_stream); it comes up
     # from the host once per stream, as the stream's head.
     "filter_state": P(BAND_AXIS, BANK_AXIS),
+    # A small-nfft leg's power and open integration in channelize_lanes'
+    # layout (frames on the lanes): ``(nband, nint, C, nif, nfft, c,
+    # groups)`` and ``(nband, C, nif, nfft, c)``, the coarse channels in
+    # ``C`` sublane-fulls of ``c``, sharded over bank like every product.
+    "lanes_power": P(BAND_AXIS, None, BANK_AXIS),
+    "lanes_acc": P(BAND_AXIS, BANK_AXIS),
 }
 
 # The collective-latency histograms of the sharded plane (ISSUE 9): every
@@ -265,95 +277,134 @@ def _band_product(out: jax.Array, stitch: bool, despike_nfpc: int):
     return out[None]  # leading band axis block
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "mesh", "nfft", "ntap", "nint", "stokes", "fft_method", "stitch",
-        "despike_nfpc", "fqav_by", "dtype",
-    ),
-    donate_argnames=("tail",),
-)
-def band_stream(
-    tail: jax.Array,
-    body: jax.Array,
-    coeffs: jax.Array,
-    *,
-    mesh: Mesh,
-    nfft: int,
-    ntap: int = 4,
-    nint: int = 1,
-    stokes: str = "I",
-    fft_method: str = "auto",
-    stitch: bool = True,
-    despike_nfpc: int = 0,
-    fqav_by: int = 1,
-    dtype: str = "float32",
-) -> tuple:
-    """One window of a band STREAM: :func:`band_reduce` of the gross block
-    ``concat(tail, body)``, whose filter state never left the chips.
+def _power_rule(stitch: bool, lanes: int) -> P:
+    """The layout of a leg's program output: the band product, or the
+    small-nfft path's power (never stitched: its rows are, once folded)."""
+    return partition_rule("lanes_power") if lanes else _product_rule(stitch)
 
-    Per chip, :func:`blit.ops.channelize.stream_step` — the reducer's
-    ``channelize_stream`` body, not a second implementation — on that
-    chip's own bank: ``tail`` ``(nband, nbank, nchan, (ntap-1)*nfft)``
-    under the ``filter_state`` rule and ``body`` ``(nband, nbank, nchan,
-    frames*nfft)`` under ``voltages``, both
-    :func:`blit.ops.channelize.sample_words` (one word a sample: the form
-    the host link carries at speed).  ``tail`` is DONATED
-    (:class:`ShardedAccumulator`: a bank's filter state is held once).
-    Returns ``(product, next_tail)``: the product exactly as
-    :func:`band_reduce` lays it out (``stitch``, ``despike_nfpc``,
-    ``fqav_by`` as there), ``next_tail`` the last ``(ntap-1)*nfft`` words
-    of each chip's concatenation (a body shorter than the state — a
-    scan's one-frame last window — keeps part of the old tail), with no
-    collective of its own."""
 
-    def step(t, v, h):
-        out, nxt = stream_step(
-            t[0, 0], v[0, 0], h, nfft=nfft, ntap=ntap, nint=nint,
-            stokes=stokes, fft_method=fft_method, fqav_by=fqav_by,
-            dtype=dtype,
+@functools.lru_cache(maxsize=None)
+def band_programs(name: str):
+    """``(step, head)``, the two programs of one leg of a band STREAM,
+    under ``jit_<name>`` in a device trace (a leg whose seconds are to be
+    read apart has a name of its own): per chip the bodies of
+    :func:`blit.ops.channelize.leg_programs` — the one-chip reducer's own
+    steps, not a second implementation — under ``shard_map``, each chip on
+    its own bank, with no collective but the product's own stitch.
+
+    ``step(tail, body, coeffs, *, mesh, stitch=True, despike_nfpc=0,
+    frames=None, lanes=0, **kw)`` is one window:
+    :func:`blit.ops.channelize.stream_step` of the first ``frames`` frames
+    (all, by default) of each chip's ``concat(tail, body)`` — ``tail``
+    ``(nband, nbank, nchan, (ntap-1)*nfft)`` under the ``filter_state``
+    rule and ``body`` ``(nband, nbank, nchan, samples)`` under
+    ``voltages``, both :func:`blit.ops.channelize.sample_words` (one word
+    a sample: the form the host link carries at speed).  ``tail`` is
+    DONATED (:class:`ShardedAccumulator`: a bank's filter state is held
+    once); ``body`` is not, every leg of the scan reads the same array.
+    Returns ``(product, next_tail)``: the product as :func:`band_reduce`
+    lays it out (``stitch``, ``despike_nfpc``, ``fqav_by`` as there) or,
+    with ``lanes`` (:func:`blit.ops.channelize.lanes_block`), the
+    small-nfft path's power under ``lanes_power``; ``next_tail`` the
+    ``(ntap-1)*nfft`` words after the frames taken (a body shorter than
+    the state — a scan's one-frame last window — keeps part of the old
+    tail).
+
+    ``head(words, coeffs, *, mesh, **same)`` is a stream's first step of
+    a leg whose ``nfft`` is not the scan's largest: ``words`` is the
+    stream's head under ``filter_state`` (the largest leg's filter
+    state), to this leg its own shorter state followed by data
+    (:func:`blit.ops.channelize.head_step`); it is not donated — its
+    owner takes it last."""
+
+    def on_each_chip(run, rules, mesh, stitch, despike_nfpc, lanes, kw):
+        """``run`` (a leg's per-chip step) under ``shard_map``: its
+        blocks under ``rules``, the coefficients replicated."""
+        assert not lanes or not (stitch or despike_nfpc)
+
+        def per_chip(*args):
+            out, nxt = run(*(a[0, 0] for a in args[:-1]), args[-1],
+                           lanes=lanes, **kw)
+            return _band_product(out, stitch, despike_nfpc), nxt[None, None]
+
+        return jax.shard_map(
+            per_chip, mesh=mesh,
+            in_specs=tuple(map(partition_rule, rules)) + (P(),),
+            out_specs=(_power_rule(stitch, lanes),
+                       partition_rule("filter_state")),
+            check_vma=False,  # as band_reduce
         )
-        return _band_product(out, stitch, despike_nfpc), nxt[None, None]
 
-    state = partition_rule("filter_state")
-    return jax.shard_map(
-        step, mesh=mesh,
-        in_specs=(state, partition_rule("voltages"), P()),
-        out_specs=(_product_rule(stitch), state),
-        check_vma=False,  # as band_reduce
-    )(tail, body, coeffs)
+    def step(tail, body, coeffs, *, mesh, stitch=True, despike_nfpc=0,
+             lanes=0, **kw):
+        return on_each_chip(stream_step, ("filter_state", "voltages"), mesh,
+                            stitch, despike_nfpc, lanes, kw)(
+                                tail, body, coeffs)
+
+    def head(words, coeffs, *, mesh, stitch=True, despike_nfpc=0, lanes=0,
+             **kw):
+        return on_each_chip(head_step, ("filter_state",), mesh, stitch,
+                            despike_nfpc, lanes, kw)(words, coeffs)
+
+    for fn in (step, head):
+        fn.__name__ = fn.__qualname__ = name
+    static = ("mesh", "stitch", "despike_nfpc") + _STREAM_STATIC
+    return (jax.jit(step, static_argnames=static, donate_argnames=("tail",)),
+            jax.jit(head, static_argnames=static))
 
 
-@functools.partial(jax.jit, static_argnames=("mesh", "nif", "nchans"))
-def carry_zeros(*, mesh: Mesh, nif: int, nchans: int) -> jax.Array:
+# One window of a band stream that makes ONE product (``blit scan``'s
+# program since PR 31, ``jit_band_stream``), and the first leg of one that
+# makes several: ``band_stream(tail, body, coeffs, *, mesh, nfft, ntap=4,
+# nint=1, stokes="I", fft_method="auto", stitch=True, despike_nfpc=0,
+# fqav_by=1, dtype="float32")`` -> ``(product, next_tail)``,
+# :func:`band_reduce` of the gross block ``concat(tail, body)`` whose
+# filter state never left the chips (:func:`band_programs`).
+band_stream = band_programs("band_stream")[0]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "nif", "nchans", "lanes"))
+def carry_zeros(*, mesh: Mesh, nif: int = 0, nchans: int = 0,
+                lanes: Optional[tuple] = None) -> jax.Array:
     """A scan's integration before its first frame: float32 zeros
     ``(nband, nif, nchans)`` laid out by the ``integration_acc`` rule,
-    made on the chips (``nchans`` is the whole band's)."""
+    made on the chips (``nchans`` is the whole band's).  ``lanes``
+    instead gives the shape ``(nband, C, nif, nfft, c)`` of a small-nfft
+    leg's (rule ``lanes_acc``: a leg's power less its positions and its
+    frame groups)."""
     import jax.numpy as jnp
 
-    per_chip = nchans // mesh.devices.shape[1]
+    nbank = mesh.devices.shape[1]
+    block = ((1, nif, nchans // nbank) if lanes is None
+             else (1, lanes[1] // nbank) + tuple(lanes[2:]))
     return jax.shard_map(
-        lambda: jnp.zeros((1, nif, per_chip), jnp.float32),
+        lambda: jnp.zeros(block, jnp.float32),
         mesh=mesh, in_specs=(),
-        out_specs=partition_rule("integration_acc"), check_vma=False,
+        out_specs=partition_rule(
+            "integration_acc" if lanes is None else "lanes_acc"),
+        check_vma=False,
     )()
 
 
-@functools.partial(jax.jit, static_argnames=("mesh", "nint"),
+@functools.partial(jax.jit, static_argnames=("mesh", "nint", "lanes",
+                                             "nframes"),
                    donate_argnums=0)
 def band_carry(
     acc: jax.Array, power: jax.Array, filled: jax.Array, *, mesh: Mesh,
-    nint: int,
+    nint: int, lanes: bool = False, nframes: Optional[int] = None,
 ) -> tuple:
     """One window of an integration longer than a window (or straddling
     its boundary), per chip and with NO collective: every chip folds its
     own bank's spectra into its own partial sum
-    (:func:`blit.ops.channelize.integrate_carry`, frame by frame in stream
+    (:func:`blit.ops.channelize.integrate_carry`, the ONE fold of every
+    leg on one chip and on the mesh: frame by frame in stream
     order, ``filled`` a device scalar — one program wherever the row
     boundary falls in the window grid).
 
-    ``power`` is ``band_reduce(..., nint=1, stitch=False)``'s product
-    ``(nband, nframes, nif, nchans)``, bank-sharded; ``acc`` ``(nband,
+    ``power`` is a leg's product at ``nint=1, stitch=False``
+    (:func:`band_programs`), ``(nband, nframes, nif, nchans)``,
+    bank-sharded; ``acc`` ``(nband,
     nif, nchans)`` float32 under the ``integration_acc`` rule is DONATED
     (:class:`ShardedAccumulator`: the open integration is held once).
     Returns ``(acc, rows)``: ``rows`` ``(nband, (nint - 1 + nframes) //
@@ -361,19 +412,24 @@ def band_carry(
     + nframes) // nint`` closed in this window (the rest are zeros) — what
     :func:`stitch_despike` gathers, and only then.  ``rows`` is a fresh
     output of every call, so it is also the token a caller waits on (the
-    accumulator is gone with the next fold)."""
+    accumulator is gone with the next fold).
+
+    With ``lanes`` the power is the small-nfft path's (rule
+    ``lanes_power``, its first ``nframes`` frames real) and ``acc`` its
+    accumulator (``lanes_acc``); the rows come back in the product's
+    layout all the same."""
 
     def fold(a, x, at):
-        rows, a = integrate_carry(x[0], a[0], at, nint=nint)
+        rows, a = integrate_carry(x[0], a[0], at, nint=nint, lanes=lanes,
+                                  nframes=nframes)
         return a[None], rows[None]
 
+    held = partition_rule("lanes_acc" if lanes else "integration_acc")
     return jax.shard_map(
         fold,
         mesh=mesh,
-        in_specs=(partition_rule("integration_acc"),
-                  partition_rule("filterbank_sharded"), P()),
-        out_specs=(partition_rule("integration_acc"),
-                   partition_rule("filterbank_sharded")),
+        in_specs=(held, _power_rule(False, lanes), P()),
+        out_specs=(held, partition_rule("filterbank_sharded")),
         check_vma=False,  # per-chip fold, no collectives
     )(acc, power, filled)
 

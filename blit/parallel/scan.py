@@ -16,6 +16,7 @@ from __future__ import annotations
 import logging
 from typing import Dict, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from blit import hostmem, observability
@@ -25,6 +26,7 @@ from blit.monitor import published
 from blit.ops.channelize import (
     STOKES_NIF,
     coeff_bank,
+    lanes_block,
     output_header,
     pfb_coeffs,
     sample_words,
@@ -665,6 +667,144 @@ def _open_band_writers(
     return mine, headers, writers, f0_start
 
 
+def rawspec_band_path(out_dir: str, band_id, k: int) -> str:
+    """Where band ``band_id``'s product ``k`` of several lands: rawspec's
+    own suffix on the band's stem."""
+    import os
+
+    return os.path.join(out_dir, f"band{band_id}.rawspec.{k:04d}.fil")
+
+
+class _ScanLeg:
+    """One product of a mesh scan: its two programs
+    (:func:`blit.parallel.mesh.band_programs`: per chip the one-chip
+    reducer's own steps), what it keeps ON THE CHIPS from window to window
+    — each bank's filter state and, where the integration is ``folded``
+    across windows, each chip's partial sum and the frames it holds — and
+    its band writers.  A scan is a list of legs that read the same
+    uploaded windows.
+
+    ``total`` frames of ``nfft`` make the product's rows; the stream's
+    samples ``[0, end)`` hold them and the leg's filter state.  ``lanes``
+    > 0 runs the small-``nfft`` path in blocks of that many words
+    (:func:`blit.ops.channelize.lanes_block`; folded legs only).
+    ``label`` names the product in counters and spans (``None``: a scan
+    of one product, which has none of the per-product rows); ``kw`` are
+    the channelizer's other keywords."""
+
+    def __init__(self, mesh, tl, *, nfft: int, nint: int, ntap: int,
+                 total: int, folded: bool, out_paths, name: str, label,
+                 coeffs, despike_nfpc: int, lanes: int, **kw):
+        assert folded or not lanes
+        self.mesh, self.tl, self.name, self.label = mesh, tl, name, label
+        self.nfft, self.nint = nfft, nint
+        self.folded, self.lanes, self.coeffs = folded, lanes, coeffs
+        self.despike_nfpc, self.out_paths = despike_nfpc, out_paths
+        self.state_ntime = (ntap - 1) * nfft
+        self.end = (total + ntap - 1) * nfft
+        self.kw = dict(kw, mesh=mesh, nfft=nfft, ntap=ntap)
+        # Each bank's filter state and the open integration (each chip's
+        # own partial sum): on the mesh from window to window, held once
+        # (donated folds).
+        self.state = M.ShardedAccumulator(mesh, "filter_state")
+        self.acc = M.ShardedAccumulator(
+            mesh, "lanes_acc" if lanes else "integration_acc")
+        self.filled = 0  # frames the open integration holds
+        self.mine, self.headers, self.writers, self.done = [], {}, {}, {}
+
+    def tag(self, span) -> None:
+        """Name the product on a ``readback`` / ``write`` span."""
+        if span is not None and self.label is not None:
+            span.attrs["product"] = self.label
+
+    def _programs(self):
+        # (Looked up per call: a test that patches M.band_stream is seen.)
+        if self.name == "band_stream":
+            return M.band_stream, M.band_programs(self.name)[1]
+        return M.band_programs(self.name)
+
+    def _program_kw(self, frames: Optional[int]) -> dict:
+        kw = dict(self.kw)
+        if self.folded:
+            # Per chip, no collective: spectra at nint=1, folded into the
+            # chip's own sum.
+            kw.update(nint=1, stitch=False, despike_nfpc=0)
+        else:
+            kw.update(nint=self.nint, stitch=True,
+                      despike_nfpc=self.despike_nfpc)
+        if self.lanes:
+            kw["lanes"] = self.lanes
+        if frames is not None:
+            kw["frames"] = frames
+        return kw
+
+    def begin(self, head, token: list, outs: list) -> None:
+        """The first step of a stream whose head (``head``, the largest
+        leg's filter state, on the chips) is longer than this leg's own:
+        the samples past its state are the leg's data."""
+        frames = (head.shape[-1] - self.state_ntime) // self.nfft
+        power, tail = self._programs()[1](head, self.coeffs,
+                                          **self._program_kw(None))
+        self.state.init(tail)
+        self._rows(power, frames, token, outs)
+
+    def advance(self, body, frames: int, whole: bool, token: list,
+                outs: list) -> None:
+        """One window: the first ``frames`` frames of the samples ``body``
+        after the filter state on the chips (``whole``: all it holds); the
+        state moves on."""
+        power = self.state.fold_aux(
+            self._programs()[0], body, self.coeffs,
+            **self._program_kw(None if whole else frames))
+        self._rows(power, frames, token, outs)
+
+    def _rows(self, power, frames: int, token: list, outs: list) -> None:
+        """A program's output of ``frames`` frames -> ``token`` gains what
+        is ready once its input has been consumed, ``outs`` ``(leg,
+        stitched bands)`` of the product rows that closed (nothing where
+        none did: only a closed row is gathered, fetched and written)."""
+        tl, mesh = self.tl, self.mesh
+        nband, nbank = mesh.devices.shape
+        if not self.folded:
+            token.append(power)
+            outs.append((self, power))
+            return
+        if self.acc.value is None:  # the leg's first frames: zeros of
+            # the power's layout less its frames
+            self.acc.init(M.carry_zeros(mesh=mesh, **(
+                dict(lanes=power.shape[:1] + power.shape[2:6]) if self.lanes
+                else dict(nif=power.shape[2], nchans=power.shape[3]))))
+        # (band_carry hands back (accumulator, rows): the rows are the aux.)
+        part = self.acc.fold_aux(lambda a: M.band_carry(
+            a, power, np.int32(self.filled), mesh=mesh, nint=self.nint,
+            **(dict(lanes=True, nframes=frames) if self.lanes else {}))[::-1])
+        token.append(part)
+        closed, self.filled = divmod(self.filled + frames, self.nint)
+        if self.filled:  # the window ended with the integration open
+            tl.mark("integrate.carry", self.acc.value.nbytes)
+        if not closed:
+            return
+        if closed < part.shape[1]:
+            # (A static slice is ONE program on every chip; `part[:, :n]`
+            # is a gather plus index conversions on the default device,
+            # and the chips' traces then no longer run the same sequence.)
+            part = jax.lax.slice_in_dim(part, 0, closed, axis=1)
+        # Despike on the integrated row: the clone commutes with the sum,
+        # the bits are those of despiking every spectrum.
+        out = M.stitch_despike(part, mesh=mesh,
+                               despike_nfpc=self.despike_nfpc)
+        nbytes = len(self.mine) * out.nbytes // nband
+        tl.mark("integrate.emit", nbytes, calls=closed)
+        if self.label is not None:  # per product, where several
+            tl.mark(f"integrate.emit.{self.label}", nbytes, calls=closed)
+            # What the gather moved, all chips: each receives the other
+            # banks' shards of the rows.
+            tl.mark(f"stitch.{self.label}", nband * nbank * M.gather_ici_bytes(
+                out.nbytes // nband // nbank, nbank))
+        token.append(out)
+        outs.append((self, out))
+
+
 def load_scan_mesh(
     raw_paths,
     scan: Optional[str] = None,
@@ -782,6 +922,7 @@ def reduce_scan_mesh_to_files(
     nfft: int,
     ntap: int = 4,
     nint: int = 1,
+    also: Sequence[Tuple[int, int]] = (),
     stokes: str = "I",
     fqav_by: int = 1,
     fft_method: str = "auto",
@@ -837,6 +978,39 @@ def reduce_scan_mesh_to_files(
 
     Call shapes and reduction parameters match :func:`load_scan_mesh`
     (explicit grid or ``(session, scan, inventories=...)``).
+
+    SEVERAL products from one read (``also=((nfft, nint), ...)`` beside
+    the first: rawspec's ``-f 1048576,8,1024 -t 51,128,3072`` on the
+    band): ONE window grid, in frames of the largest ``nfft``
+    (``window_frames`` and ``max_frames`` count those); each bank's
+    window is read once and put once, as words, and on every chip a leg
+    per product (:class:`_ScanLeg`; :func:`blit.parallel.mesh.
+    band_programs`, the one-chip reducer's own steps under ``shard_map``,
+    each under a program name of its own: ``jit_band_stream``,
+    ``jit_band_stream_0001``, ...) runs on that SAME device array.  What
+    stays on the chips is each leg's own filter state and accumulator;
+    the stream's head is the largest ``nfft``'s filter state and DATA to
+    the smaller legs, whose head steps ride the first window before the
+    head's owner takes it (and donates it with its first step).  Every
+    integration is FOLDED across windows (``band_carry``, the ONE fold,
+    one accumulator a product, sharded over ``bank``; a small ``nfft``
+    runs frames-on-lanes where :func:`blit.ops.channelize.lanes_block`
+    says the shape fits, as it does for a single folded product), only a
+    product that closed rows in a window is stitched, fetched and handed
+    to its writer, and each product is what its own command makes of the
+    scan: its own head, rows and dropped tail, the same frames added in
+    the same order (to the byte where that command folds too).  A writer
+    per product per band at ``<out_dir>/band<id>.rawspec.000k.fil``; all
+    of them are renamed and their manifests published, or none
+    (``.partial``s dropped, what was published withdrawn).  Counters
+    beside the ones below: ``fanout.share`` (``calls`` = programs that
+    ran on a bank's upload they did not put, ``bytes`` = H2D not sent
+    again), ``integrate.emit.<000k>`` per product, ``stitch.<000k>``
+    (``calls`` = stitches, one per window or head step in which the
+    product closed rows; ``bytes`` = what the gathers moved, all chips),
+    and ``readback`` / ``write`` spans carry ``product``.  Not with
+    ``resume``, ``compression`` or ``out_paths``; returns ``{band_id:
+    [(path, header), ...]}``, a pair per product.
 
     ``dtype`` selects the per-chip channelizer stage dtype ("float32" |
     "bfloat16" — the official bench's biggest lever, DESIGN.md §3; the
@@ -916,112 +1090,105 @@ def reduce_scan_mesh_to_files(
     from blit.observability import Timeline, profile_trace
 
     tl = timeline if timeline is not None else Timeline()
+    products = ((int(nfft), int(nint)),) + tuple(
+        (int(f), int(t)) for f, t in also)
+    many = len(products) > 1
+    # One grid for every product: the largest nfft's frames.
+    big = max(f for f, _ in products)
+    if many:
+        for what, given in (("resume", resume), ("compression", compression),
+                            ("out_paths", out_paths)):
+            if given:
+                raise ValueError(
+                    f"{what}= with several products is not supported "
+                    "(ROADMAP B1): one product per call, or all of them "
+                    "without it")
+        for f, _ in products:
+            if big % f or fqav_by > 1 and f % fqav_by:
+                raise ValueError(
+                    f"nfft={f} of several products: every nfft must "
+                    f"divide the largest ({big}) and be a multiple of "
+                    f"fqav_by={fqav_by}")
     # The root span and the profile around it hold the whole pass: its two
     # ends are the stages `open` (the grid, the players and their block
     # index, the headers, the coefficient bank, the writers) and `close`.
+    legs: list = []
     with profile_trace(trace_logdir), observability.span(
             "scan.reduce", nfft=nfft) as root:
-        with tl.stage("open", byte_free=True):
-            band_ids, raw_paths = _resolve_grid(raw_paths, scan, inventories)
-            mesh, local, raws, nchan, npol, min_samps = _open_players(
-                raw_paths, mesh)
-            nband, nbank = mesh.devices.shape
-
-            total = usable_frames(min_samps, nfft, ntap, nint)
-            if max_frames is not None:
-                total = min(total, (max_frames // nint) * nint)
-            if total <= 0:
-                raise ValueError(
-                    f"scan too short: {min_samps} samples for nfft={nfft}"
-                )
-            # Bounded by default at EVERY entry point (VERDICT r4: an
-            # unbounded whole-scan window on the command whose purpose is
-            # bounded-window streaming), and by nint never un-bounded
-            # (scan_window_frames).  Pass an explicit window_frames >= the
-            # scan length for a deliberate one-window run.
-            wf = scan_window_frames(nfft, nint, window_frames)
-            carried = wf % nint != 0
-
-            out_paths = _resolve_out_paths(
-                band_ids, nband, out_dir, out_paths, compression
-            )
-            if root is not None:  # the first product, as reduce.to_file's
-                root.attrs["out"] = out_paths[0]
-
-            h0, bases, per_bank = _scan_headers(
-                raws, local, nfft=nfft, nint=nint, stokes=stokes,
-                fqav_by=fqav_by,
-            )
-            coeffs = coeff_bank(ntap, nfft, window, tl)
-            despike_nfpc = _despike_nfpc(despike, nfft, fqav_by)
-
-            mine, headers, writers, f0_start = _open_band_writers(
-                mesh, raws, out_paths, h0=h0, bases=bases,
-                per_bank=per_bank, stokes=stokes, nfft=nfft, ntap=ntap,
-                nint=nint, window=window, fqav_by=fqav_by, dtype=dtype,
-                despike_nfpc=despike_nfpc, compression=compression,
-                resume=resume, wf=wf, total=total, timeline=tl,
-            )
         try:
-            reduce_kw = dict(
-                mesh=mesh, nfft=nfft, ntap=ntap, stokes=stokes,
-                fft_method=fft_method, fqav_by=fqav_by, dtype=dtype,
-            )
-            # Each bank's filter state and the open integration (each chip's
-            # own partial sum): on the mesh from window to window, held once
-            # (donated folds).
-            state = M.ShardedAccumulator(mesh, "filter_state")
-            acc = M.ShardedAccumulator(mesh, "integration_acc")
-            # Frames the open integration holds (resume: whole rows).
-            filled = 0
+            with tl.stage("open", byte_free=True):
+                band_ids, raw_paths = _resolve_grid(raw_paths, scan,
+                                                    inventories)
+                mesh, local, raws, nchan, npol, min_samps = _open_players(
+                    raw_paths, mesh)
+                nband, nbank = mesh.devices.shape
+                head_ntime = (ntap - 1) * big
+                if many and max_frames is not None:
+                    # Of several products each is what its own command
+                    # makes of the recording cut to that many frames of
+                    # the largest nfft.
+                    min_samps = min(min_samps,
+                                    head_ntime + max_frames * big)
+                # Bounded by default at EVERY entry point (VERDICT r4: an
+                # unbounded whole-scan window on the command whose purpose
+                # is bounded-window streaming), and by nint never
+                # un-bounded (scan_window_frames).  Pass an explicit
+                # window_frames >= the scan length for a deliberate
+                # one-window run.  Of several products every integration
+                # is folded across windows, so none sizes the window.
+                # (nint 1 to the window's rule.)
+                wf = scan_window_frames(
+                    *((big, 1) if many else (nfft, nint)), window_frames)
+                for k, (f, t) in enumerate(products):
+                    total = usable_frames(min_samps, f, ntap, t)
+                    if max_frames is not None and not many:
+                        total = min(total, (max_frames // t) * t)
+                    if total <= 0:
+                        raise ValueError(
+                            f"scan too short: {min_samps} samples for "
+                            f"nfft={f}")
+                    if many:
+                        if out_dir is None:
+                            raise ValueError("several products need out_dir=")
+                        paths = [rawspec_band_path(out_dir, band_ids[b], k)
+                                 for b in range(nband)]
+                    else:
+                        paths = _resolve_out_paths(
+                            band_ids, nband, out_dir, out_paths, compression)
+                    if root is not None and k == 0:
+                        root.attrs["out"] = paths[0]  # as reduce.to_file's
+                    folded = many or wf % t != 0
+                    leg = _ScanLeg(
+                        mesh, tl, nfft=f, nint=t, ntap=ntap, total=total,
+                        folded=folded, out_paths=paths,
+                        name="band_stream" if k == 0
+                        else f"band_stream_{k:04d}",
+                        label=f"{k:04d}" if many else None,
+                        coeffs=coeff_bank(ntap, f, window, tl),
+                        despike_nfpc=_despike_nfpc(despike, f, fqav_by),
+                        # (The shape decides, as on one chip.)
+                        lanes=folded and lanes_block(
+                            f, t, npol, ntap, fqav_by=fqav_by, dtype=dtype),
+                        stokes=stokes, fft_method=fft_method,
+                        fqav_by=fqav_by, dtype=dtype)
+                    legs.append(leg)
+                    h0, bases, per_bank = _scan_headers(
+                        raws, local, nfft=f, nint=t, stokes=stokes,
+                        fqav_by=fqav_by)
+                    leg.mine, leg.headers, leg.writers, f0_start = \
+                        _open_band_writers(
+                            mesh, raws, paths, h0=h0, bases=bases,
+                            per_bank=per_bank, stokes=stokes, nfft=f,
+                            ntap=ntap, nint=t, window=window,
+                            fqav_by=fqav_by, dtype=dtype,
+                            despike_nfpc=leg.despike_nfpc,
+                            compression=compression, resume=resume, wf=wf,
+                            total=total, timeline=tl)
+            # The leg whose filter state the stream's head is.
+            owner = next(leg for leg in legs if leg.nfft == big)
+            mine = legs[0].mine
 
-            def channelise(body, **kw):
-                """The window's stream program: the product of the filter
-                state on the chips + ``body``; the state moves on."""
-                return state.fold_aux(M.band_stream, body, coeffs, **kw,
-                                      **reduce_kw)
-
-            def reduce_window(body, n):
-                """One window's programs -> ``(token, out)``: ``out`` the
-                stitched bands of the product rows the window closed
-                (``None`` where it closed none), ``token`` what is ready
-                once the window's samples have been consumed."""
-                nonlocal filled
-                if not carried:
-                    out = channelise(body, nint=nint, stitch=True,
-                                     despike_nfpc=despike_nfpc)
-                    return out, out
-                # Per chip, no collective: spectra at nint=1, folded into the
-                # chip's own sum.
-                power = channelise(body, nint=1, stitch=False, despike_nfpc=0)
-                if acc.value is None:  # the scan's first window
-                    acc.init(M.carry_zeros(mesh=mesh, nif=STOKES_NIF[stokes],
-                                           nchans=nbank * per_bank))
-                part = None
-
-                def fold(a):
-                    nonlocal part
-                    a, part = M.band_carry(a, power, np.int32(filled),
-                                           mesh=mesh, nint=nint)
-                    return a
-
-                acc.fold(fold)
-                closed, filled = divmod(filled + n, nint)
-                if filled:  # the window ended with the integration open
-                    tl.mark("integrate.carry", acc.value.nbytes)
-                if not closed:
-                    return part, None
-                if closed < part.shape[1]:
-                    part = part[:, :closed]
-                # Despike on the integrated row: the clone commutes with the
-                # sum, the bits are those of despiking every spectrum.
-                out = M.stitch_despike(part, mesh=mesh,
-                                       despike_nfpc=despike_nfpc)
-                tl.mark("integrate.emit", len(mine) * out.nbytes // nband,
-                        calls=closed)
-                return out, out
-
-            def flush(token, out, staged):
+            def flush(token, outs, staged):
                 # Blocking readback of one window's stitched bands -> disk.
                 # The compute wait is charged to "device" here (not at the
                 # async dispatch): this is where the host actually blocks on
@@ -1029,76 +1196,126 @@ def reduce_scan_mesh_to_files(
                 # semantics — also for a window that closed no row and has
                 # nothing to fetch or write.
                 with tl.stage("device", byte_free=True):
-                    token.block_until_ready()
+                    jax.block_until_ready(token)
                 # The window has consumed its input: only now may its
                 # staging slabs serve another window (the one after next
                 # takes them, already faulted).
                 pool = hostmem.slab_pool()
                 for buf in staged:
                     pool.give(buf, tl)
-                if out is None:
-                    return
-                by_dev = {s.device: s for s in out.addressable_shards}
-                for b in mine:
-                    band = by_dev[mesh.devices[b, 0]].data
-                    # (Behind the next window's puts on the link budget.)
-                    with host_link().fetch(band.nbytes, tl), \
-                            tl.stage("readback"):
-                        slab = np.ascontiguousarray(np.asarray(band)[0])
-                    tl.stages["readback"].bytes += slab.nbytes
-                    with tl.stage("write", slab.nbytes):
-                        writers[b].append(slab)
+                # A product is handed the rows that closed in the window,
+                # and nothing where none did.
+                for leg, out in outs:
+                    by_dev = {s.device: s for s in out.addressable_shards}
+                    for b in mine:
+                        band = by_dev[mesh.devices[b, 0]].data
+                        # (Behind the next window's puts on the link budget.)
+                        with host_link().fetch(band.nbytes, tl), \
+                                tl.stage("readback") as sp:
+                            leg.tag(sp)
+                            slab = np.ascontiguousarray(np.asarray(band)[0])
+                        tl.stages["readback"].bytes += slab.nbytes
+                        with tl.stage("write", slab.nbytes) as sp:
+                            leg.tag(sp)
+                            leg.writers[b].append(slab)
 
             # One window in flight: window N+1's host RAW reads + device_put +
             # dispatch happen BEFORE blocking on window N's readback, so host
             # I/O overlaps device compute at one extra window of HBM.
             pending = None
-            f0 = f0_start
-            head_ntime = (ntap - 1) * nfft
+            # Samples, on the grid of the largest nfft: a window is `wf` of
+            # its frames and every leg takes the whole frames of its own
+            # that the window's samples hold, up to its last row's.
+            start = head_ntime + f0_start * nfft
+            end = max(leg.end for leg in legs)
             # Every window stages through slabs of the largest window's shape.
-            slab_ntime = min(wf, total - f0_start) * nfft
-            while f0 < total:
-                n = min(wf, total - f0)
+            slab_ntime = min(wf * big, end - start)
+            # Locally fed voltage bytes: complex int8 = 2 B/sample.
+            per_sample = len(raws) * nchan * npol * 2
+            while start < end:
+                n = min(wf * big, end - start)
+                frames = [max(0, min(leg.end, start + n) - start) // leg.nfft
+                          for leg in legs]
                 # A stream's first window brings its head up with it.
-                head = head_ntime if state.value is None else 0
-                # Locally fed voltage bytes: complex int8 = 2 B/sample.
-                per_sample = len(raws) * nchan * npol * 2
-                with observability.span("scan.window", f0=f0):
+                head = head_ntime if owner.state.value is None else 0
+                with observability.span("scan.window",
+                                        f0=(start - head_ntime) // big):
                     staged = []
-                    with tl.stage("read", per_sample * (head + n * nfft)):
+                    with tl.stage("read", per_sample * (head + n)):
                         tail, body = _feed_window(
-                            raws, local, mesh, nchan, npol,
-                            f0 * nfft + head_ntime, n * nfft, tl, staged,
-                            slab_ntime, head,
+                            raws, local, mesh, nchan, npol, start, n, tl,
+                            staged, slab_ntime, head,
                         )
-                        if head:
-                            state.init(tail)
                     # Filter state by where it comes from: up from the
                     # host (once per bank per stream) or left on the chip
                     # by the last window.
-                    tl.mark("state.head" if head else "state.carry",
-                            per_sample * head_ntime, calls=len(raws))
+                    for leg, took in zip(legs, frames):
+                        if took or head and leg is not owner:
+                            tl.mark("state.head" if head else "state.carry",
+                                    per_sample * leg.state_ntime,
+                                    calls=len(raws))
                     with tl.stage("dispatch", byte_free=True):
-                        token, out = reduce_window(body, n)
+                        token, outs = [], []
+                        if head:
+                            # Everyone reads the head before its owner
+                            # takes it (and donates it with its first
+                            # window).
+                            for leg in legs:
+                                if leg is not owner:
+                                    leg.begin(tail, token, outs)
+                            owner.state.init(tail)
+                        for leg, took in zip(legs, frames):
+                            if took:
+                                leg.advance(body, took, took * leg.nfft == n,
+                                            token, outs)
+                    if many:
+                        # Programs that ran on an upload they did not put
+                        # (a bank's), and the H2D bytes not sent again.
+                        stepped = sum(map(bool, frames))
+                        begun = (len(legs) - 1) * bool(head)
+                        tl.mark("fanout.share",
+                                max(0, stepped - 1) * per_sample * (head + n),
+                                calls=len(raws) * (stepped + begun - 1))
                     if pending is not None:
                         flush(*pending)
-                pending = (token, out, staged)
-                f0 += n
+                pending = (token, outs, staged)
+                start += n
             if pending is not None:
                 flush(*pending)
-            done = {}
-            # The pass's far end: file close, rename, manifest.
-            with tl.stage("close", byte_free=True):
-                for b in list(writers):
-                    # (On failure the finally aborts the rest.)
-                    writers[b].close()
-                    done[b] = writers.pop(b)
+            try:
+                # Every product's last byte is written before the first is
+                # renamed into place.
+                if many:
+                    for leg in legs:
+                        for w in leg.writers.values():
+                            w.flush()
+                # The pass's far end: file close, rename, manifest.
+                with tl.stage("close", byte_free=True):
+                    for leg in legs:
+                        for b in list(leg.writers):
+                            # (On failure the finally aborts the rest.)
+                            leg.writers[b].close()
+                            leg.done[b] = leg.writers.pop(b)
+            except BaseException:
+                if many:  # all complete, or none at a final path
+                    from blit.pipeline import _withdraw
+
+                    for leg in legs:
+                        for w in leg.done.values():
+                            _withdraw(w)
+                raise
         finally:
-            for w in writers.values():  # exception path: drop partials
-                w.abort()
-        for b in mine:
-            headers[b]["nsamps"] = done[b].nsamps
-    return {band_ids[b]: (out_paths[b], headers[b]) for b in mine}
+            for leg in legs:
+                for w in leg.writers.values():  # exception path: drop partials
+                    w.abort()
+        for leg in legs:
+            for b in mine:
+                leg.headers[b]["nsamps"] = leg.done[b].nsamps
+    if many:
+        return {band_ids[b]: [(leg.out_paths[b], leg.headers[b])
+                              for leg in legs] for b in mine}
+    return {band_ids[b]: (legs[0].out_paths[b], legs[0].headers[b])
+            for b in mine}
 
 
 @published

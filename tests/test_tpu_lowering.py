@@ -296,6 +296,130 @@ class TestMeshStreamStepCompilesForTheHost:
         assert self._held(compiled) + 2.5 * 2 ** 30 < self.HBM
 
 
+class TestThreeLegWindowCompilesForTheHost:
+    """``band4.rawspec3``'s window (ISSUE 40: ``blit scan --nfft
+    1048576,8,1024 --nint 51,128,3072 --window-frames 2``) COMPILED for
+    ``v5e:2x2`` at its own shape — per chip 64 ch x 2 frames x 2^20 words
+    put once, three legs' programs on them (``mesh.band_programs``), each
+    leg's fold (``band_carry``) and the stitch of the rows a window closes
+    — by the chip's own compiler.  The programs run one after the other,
+    so each has to fit beside what STAYS on a chip from window to window:
+    the three filter states, the three accumulators, the window's words
+    and the power a leg has written for its fold."""
+
+    NCH, NBANK, HBM = 64, 4, 15.75 * 2 ** 30
+    LEGS = (("band_stream", NFFT, 51), ("band_stream_0001", 8, 128),
+            ("band_stream_0002", 1024, 3072))
+    FRAMES = 2  # of 2^20: the window
+
+    def _mesh(self, topo, monkeypatch):
+        from jax.sharding import Mesh
+
+        from blit.parallel import mesh as M
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        mesh = Mesh(np.asarray(topo.devices).reshape(1, self.NBANK),
+                    (M.BAND_AXIS, M.BANK_AXIS))
+
+        def spec(shape, dtype, rule):
+            return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                        sharding=M.sharding_for(mesh, rule))
+
+        return M, mesh, spec
+
+    def _leg(self, topo, monkeypatch, name, nfft, nint, head=False):
+        """``(program, fold)`` compiled: the leg's window step (or its
+        head step over the 3 x 2^20-word head) and the fold of what it
+        wrote."""
+        M, mesh, spec = self._mesh(topo, monkeypatch)
+        lanes = ch.lanes_block(nfft, nint)
+        kw = dict(mesh=mesh, nfft=nfft, ntap=NTAP, stokes="I", nint=1,
+                  stitch=False, despike_nfpc=0, **(
+                      {"lanes": lanes} if lanes else {}))
+        block = (1, self.NBANK, self.NCH)
+        coeffs = spec((NTAP, nfft), "float32", "replicated")
+        step, first = M.band_programs(name)
+        if head:
+            frames = (NTAP - 1) * (NFFT - nfft) // nfft
+            program = first.lower(
+                spec(block + ((NTAP - 1) * NFFT,), "int32", "filter_state"),
+                coeffs, **kw).compile()
+        else:
+            frames = self.FRAMES * NFFT // nfft
+            program = step.lower(
+                spec(block + ((NTAP - 1) * nfft,), "int32", "filter_state"),
+                spec(block + (self.FRAMES * NFFT,), "int32", "voltages"),
+                coeffs, **kw).compile()
+        nslots, at = self.NBANK * self.NCH, jax.ShapeDtypeStruct(
+            (), jnp.int32)
+        if lanes:
+            m = lanes // nfft
+            fold = M.band_carry.lower(
+                spec((1, nslots // 8, 1, nfft, 8), "float32", "lanes_acc"),
+                spec((1, m, nslots // 8, 1, nfft, 8, -(-frames // m)),
+                     "float32", "lanes_power"),
+                at, mesh=mesh, nint=nint, lanes=True,
+                nframes=frames).compile()
+        else:
+            fold = M.band_carry.lower(
+                spec((1, 1, nslots * nfft), "float32", "integration_acc"),
+                spec((1, frames, 1, nslots * nfft), "float32",
+                     "filterbank_sharded"),
+                at, mesh=mesh, nint=nint).compile()
+        return program, fold
+
+    @staticmethod
+    def _held(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+    def _resident(self):
+        """Bytes a chip keeps whatever program runs: three filter states,
+        three accumulators (one bank's row each), the window in flight
+        and the next one's words."""
+        word = 4 * self.NCH
+        return (sum((NTAP - 1) * f * word + f * word for _, f, _ in
+                    self.LEGS) + 2 * self.FRAMES * NFFT * word)
+
+    @pytest.mark.parametrize("leg", range(3), ids=["0000", "0001", "0002"])
+    def test_a_legs_window_step_and_fold_fit(self, v5e_2x2, monkeypatch,
+                                             leg):
+        program, fold = self._leg(v5e_2x2, monkeypatch, *self.LEGS[leg])
+        for compiled in (program, fold):
+            text = compiled.as_text()
+            assert "all-gather" not in text and "all_gather" not in text
+            # Beside what stays and the power the leg before left for
+            # its fold (0.5 GiB a leg: 2^21 words a channel, float32).
+            assert (self._held(compiled) + self._resident()
+                    + 2 ** 29) < self.HBM
+        if leg == 0:
+            assert program.as_text().count("tpu_custom_call") >= 2
+
+    @pytest.mark.parametrize("leg", [1, 2], ids=["0001", "0002"])
+    def test_a_small_legs_head_step_fits(self, v5e_2x2, monkeypatch, leg):
+        """The stream's head (3 x 2^20 words a channel: the 0000 leg's
+        filter state) is DATA to the small legs: 393 213 frames of 8 and
+        3069 of 1024, reduced before the head's owner takes it."""
+        program, fold = self._leg(v5e_2x2, monkeypatch, *self.LEGS[leg],
+                                  head=True)
+        for compiled in (program, fold):
+            assert self._held(compiled) + self._resident() < self.HBM
+
+    @pytest.mark.parametrize("rows, nfft", [(1, NFFT), (2048, 8), (3071, 8),
+                                            (1, 1024)])
+    def test_the_stitch_of_each_products_rows_gathers_and_fits(
+            self, v5e_2x2, monkeypatch, rows, nfft):
+        M, mesh, spec = self._mesh(v5e_2x2, monkeypatch)
+        compiled = M.stitch_despike.lower(
+            spec((1, rows, 1, self.NBANK * self.NCH * nfft), "float32",
+                 "filterbank_sharded"), mesh=mesh,
+            despike_nfpc=nfft).compile()
+        text = compiled.as_text()
+        assert "all-gather" in text or "all_gather" in text
+        assert self._held(compiled) + self._resident() + 2 ** 29 < self.HBM
+
+
 class TestSmallNfftLegCompilesForTheChip:
     """``rawspec3.hires51``'s ``nfft`` 8 leg (``channelize_lanes`` through
     ``leg_programs``, then the lanes fold) COMPILED for one chip of
