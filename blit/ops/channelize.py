@@ -33,14 +33,17 @@ TPU notes (pallas_guide.md; SURVEY.md §7 "hard parts"):
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import math
+import threading
 from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from blit.device import TPU_BACKEND, pallas_interpret
@@ -92,15 +95,63 @@ def pfb_coeffs(ntap: int, nfft: int, window: str = "hamming") -> np.ndarray:
     return h.reshape(ntap, nfft).astype(np.float32)
 
 
+class _BankStore:
+    """The process's coefficient banks on the device: a small LRU behind
+    :func:`coeff_bank`, its one user.  A bank is a pure function of its
+    key, so keeping one is never wrong; ``SIZE`` bounds what a worker that
+    sweeps ``nfft`` leaves in HBM (rawspec's three products need three)."""
+
+    SIZE = 8
+
+    def __init__(self):
+        self._lock = threading.Lock()  # WorkerPool threads, serve/
+        self._banks: "collections.OrderedDict[tuple, jax.Array]" = (
+            collections.OrderedDict())
+
+    def get(self, ntap: int, nfft: int, window: str) -> Tuple[jax.Array, bool]:
+        """``(bank, hit)``: the bank kept for these, else a new one, kept
+        from now on.  The key is what a bank is a function of and the
+        device a new array lands on, so two backends or two
+        ``jax.default_device`` scopes never share one.  The build runs
+        under the lock: threads that ask for one bank at once get one
+        build and one array.  A kept bank whose backend was cleared
+        (``is_deleted``) is built again."""
+        key = (ntap, nfft, window, jax.extend.backend.get_default_device())
+        with self._lock:
+            bank = self._banks.get(key)
+            hit = bank is not None and not bank.is_deleted()
+            if not hit:
+                bank = self._banks[key] = jnp.asarray(
+                    pfb_coeffs(ntap, nfft, window))
+            self._banks.move_to_end(key)
+            while len(self._banks) > self.SIZE:
+                self._banks.popitem(last=False)
+            return bank, hit
+
+
+_BANKS = _BankStore()
+
+
 def coeff_bank(ntap: int, nfft: int, window: str, timeline) -> jax.Array:
-    """:func:`pfb_coeffs` built and shipped to the device, as the part
-    ``coeffs`` of ``timeline`` (``calls`` = banks built, ``bytes`` =
-    theirs; attr ``nfft``) inside whatever stage asked for it: half a
-    second of host arithmetic at 2^20."""
+    """The ``(ntap, nfft)`` coefficient bank on the device: :func:`pfb_coeffs`
+    built and shipped ONCE A PROCESS (half a second of host arithmetic at
+    2^20, with the chip waiting) and the same array from then on.
+
+    The process owns the bank (:class:`_BankStore`); every reduction's
+    programs read it and none may DONATE it (``donate_argnames`` are the
+    filter state's ``tail`` only): a donated bank would be deleted under
+    every later pass.
+
+    The lookup, hit or miss, is the part ``coeffs`` of ``timeline``
+    (``calls`` = banks asked for, ``bytes`` = theirs; attr ``nfft``) inside
+    whatever stage asked; ``coeffs.hit`` counts the lookups that found
+    their bank (0 in a process's first pass)."""
     with timeline.part("coeffs", ntap * nfft * 4) as sp:
         if sp is not None:
             sp.attrs["nfft"] = nfft
-        return jnp.asarray(pfb_coeffs(ntap, nfft, window))
+        bank, hit = _BANKS.get(ntap, nfft, window)
+    timeline.count("coeffs.hit", int(hit))
+    return bank
 
 
 def dequantize(voltages: jax.Array, dtype=jnp.float32) -> Tuple[jax.Array, jax.Array]:

@@ -28,7 +28,6 @@ from blit.ops.channelize import (
     coeff_bank,
     lanes_block,
     output_header,
-    pfb_coeffs,
     sample_words,
     usable_frames,
 )
@@ -865,8 +864,6 @@ def load_scan_mesh(
       full-band filterbank header, derived from this process's lowest
       (band, bank) player.
     """
-    import jax.numpy as jnp
-
     _, raw_paths = _resolve_grid(raw_paths, scan, inventories)
     mesh, local, raws, nchan, npol, min_samps = _open_players(raw_paths, mesh)
     nbank = mesh.devices.shape[1]
@@ -883,7 +880,7 @@ def load_scan_mesh(
     head_ntime = (ntap - 1) * nfft
     tail, body = _feed_window(raws, local, mesh, nchan, npol, head_ntime,
                               frames * nfft, head_ntime=head_ntime)
-    coeffs = jnp.asarray(pfb_coeffs(ntap, nfft, window))
+    coeffs = coeff_bank(ntap, nfft, window, observability.Timeline())
     out, _ = M.band_stream(
         tail,
         body,

@@ -52,7 +52,7 @@ import numpy as np
 from blit import faults, observability
 from blit.monitor import published
 from blit.observability import Timeline, profile_trace
-from blit.ops.channelize import pfb_coeffs, usable_frames
+from blit.ops.channelize import coeff_bank, usable_frames
 from blit.parallel import mesh as M
 from blit.parallel.scan import (
     _despike_nfpc,
@@ -266,8 +266,6 @@ def reduce_scan_sharded_to_files(
     it from outside the SPMD program.  The ``mesh.window`` fault point
     fires at the same cadence (``kill``/``hang`` chaos drills).
     """
-    import jax.numpy as jnp
-
     from blit.outplane import (
         AsyncSink,
         OutputRotation,
@@ -306,7 +304,8 @@ def reduce_scan_sharded_to_files(
     h0, bases, per_bank = _scan_headers(
         raws, local, nfft=nfft, nint=nint, stokes=stokes, fqav_by=fqav_by,
     )
-    coeffs = jnp.asarray(pfb_coeffs(ntap, nfft, window))
+    tl = timeline if timeline is not None else Timeline()
+    coeffs = coeff_bank(ntap, nfft, window, tl)
     despike_nfpc = _despike_nfpc(despike, nfft, fqav_by)
 
     mine, headers, writers, f0_start = _open_band_writers(
@@ -317,7 +316,6 @@ def reduce_scan_sharded_to_files(
         wf=wf, total=total,
     )
 
-    tl = timeline if timeline is not None else Timeline()
     feed = _ShardFeed(
         raws, local, mesh, nchan, npol, nfft=nfft, ntap=ntap, wf=wf,
         total=total, f0_start=f0_start, timeline=tl,
@@ -546,7 +544,6 @@ def search_scan_sharded_to_files(
     import os
 
     import jax  # noqa: F401
-    import jax.numpy as jnp
 
     from blit.io.hits import HitsWriter, ResumableHitsWriter, WindowHits
     from blit.outplane import OutputRotation, readback_extra_slots
@@ -608,7 +605,8 @@ def search_scan_sharded_to_files(
     hdrs = {bk: sred.header_for(raws[bk]) for bk in local}
     nbands = sred._nbands(nchan * nfft)
     thr = np.float32(sred.snr_threshold)
-    coeffs = jnp.asarray(pfb_coeffs(ntap, nfft, window))
+    tl = timeline if timeline is not None else Timeline()
+    coeffs = coeff_bank(ntap, nfft, window, tl)
     jfn = _mesh_dedoppler()
 
     # Pod-wide-agreed resume point (ISSUE 12): each local player's cursor
@@ -666,7 +664,6 @@ def search_scan_sharded_to_files(
         ).min())
         start_window = min((agreed // swin) * swin, nwin_total)
 
-    tl = timeline if timeline is not None else Timeline()
     feed = _ShardFeed(
         raws, local, mesh, nchan, npol, nfft=nfft, ntap=ntap, wf=wf,
         total=total, f0_start=start_window * unit, timeline=tl,

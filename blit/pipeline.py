@@ -588,7 +588,6 @@ class RawReducer:
                 raise ValueError(
                     f"chunk_frames={self.chunk_frames} of nfft={self.nfft} "
                     f"holds no whole number of nfft={nfft} frames")
-        self._pfb_coeffs: Dict[int, object] = {}  # nfft -> device bank
 
     @property
     def products(self) -> Tuple[Tuple[int, int], ...]:
@@ -627,15 +626,16 @@ class RawReducer:
                            fqav_by=self.fqav_by, dtype=self.dtype)
 
     def _coeffs_for(self, nfft: int):
-        """PFB coefficient bank, built (and device-shipped) on FIRST
-        compute use — not at construction.  Throwaway probe reducers
-        (scan resolves tuning knobs through one) must not
-        pay a multi-million-coefficient sinc*window build plus device
-        transfer just to read provenance."""
-        if nfft not in self._pfb_coeffs:  # a miss only is a bank built
-            self._pfb_coeffs[nfft] = coeff_bank(
-                self.ntap, nfft, self.window, self.timeline)
-        return self._pfb_coeffs[nfft]
+        """The process's coefficient bank for ``nfft``
+        (:func:`blit.ops.channelize.coeff_bank` owns it and records the
+        lookup as this reduction's part ``coeffs``), asked for on FIRST
+        compute use — not at construction: throwaway probe reducers (scan
+        resolves tuning knobs through one) must not pay a
+        multi-million-coefficient sinc*window build plus device transfer
+        just to read provenance.  The reducer keeps no bank of its own: a
+        stream's legs hold the array while they run, and nothing donates
+        it."""
+        return coeff_bank(self.ntap, nfft, self.window, self.timeline)
 
     @property
     def _coeffs(self):
