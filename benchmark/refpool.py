@@ -1,24 +1,32 @@
 """The plain reference, computed ONCE a run, in processes of its own.
 
-A task is one (product, checked channel) pair: one call of the unchanged
-``reference.stokes_i`` over that channel's whole int8 stream, all rows.
-Each runs in a child process (``python3 refpool.py <volt.npy> <out.npy>
-...``: it imports NumPy and ``reference`` and nothing else, so no
-interpreter lock is shared with the harness or with another task and no
-accelerator runtime is ever forked), as many at once as the host has cores
-to spare and memory over the guard's floor.  The harness starts them where
-nothing is being timed — beside the warm-up pass where that is a whole
-pass — joins them before it takes ``setup_s`` or starts the next pass,
-and keeps the rows they return for the run: every later comparison
-(``check.against_reference``) reads those rows, none computes them again.
+A task is one (product, coarse channel) pair, and WHAT it computes is the
+product kind's to say (``products/<kind>.py``: ``reference_tasks`` lists a
+product's tasks, ``compute`` is one task's work — for the filterbank kind
+one call of the unchanged plain reference over that channel's whole int8
+stream, all rows).  Each runs in a child process (``python3 refpool.py
+<the kind's module> <volt.npy> <out> <arguments as JSON>``: it imports
+NumPy, ``reference`` and the kind and nothing else, so no interpreter lock
+is shared with the harness or with another task and no accelerator runtime
+is ever forked), as many at once as the host has cores to spare and memory
+over the guard's floor.  The harness starts them where nothing is being
+timed — beside the warm-up pass where that is a whole pass — joins them
+before it takes ``setup_s`` or starts the next pass, and keeps what they
+return for the run: every later comparison (the kind's
+``against_reference``) reads what was kept, none computes it again.
 
 The channel streams go to the children as ``.npy`` files on the
 recording's RAM-backed scratch, mapped, not copied; the harness drops its
-own copy once they are written.
+own copy once they are written, and the files go when the last child has
+ended (a kind that reads the whole band has as many bytes of streams out
+as the recording holds: they do not stay beside the passes).
 """
 
 from __future__ import annotations
 
+import contextlib
+import importlib
+import json
 import os
 import subprocess
 import sys
@@ -45,23 +53,28 @@ class ReferenceFailed(Exception):
 
 
 class ReferencePool:
-    """``slices`` as ``run.write_inputs`` returns them (each holds its
-    channel's ``volt``, which ``start`` moves to ``workdir`` and removes
-    from the dict); ``products`` the plan's (``name``, ``nfft``, ``nint``).
-    ``mem_floor`` is the free memory (bytes) below which no child is
-    started while another is alive."""
+    """``products``: one ``(the plan's entry, its kind's module, the slices
+    that kind is given)`` a product; a slice is what ``run.write_inputs``
+    returns (it holds its channel's ``volt``, which ``start`` moves to
+    ``workdir`` and removes from the dict).  A task is ``(product, slot,
+    cost, the kind's module name, arguments)``.  ``mem_floor`` is the free
+    memory (bytes) below which no child is started while another is
+    alive."""
 
-    def __init__(self, slices, products, *, ntap: int, despike: bool,
+    def __init__(self, products, *, ntap: int, despike: bool,
                  workdir: str, mem_free=None, mem_floor: int = 0,
                  child=None, limit_s: float = LIMIT_S):
-        self.slices, self.workdir = slices, workdir
-        self.ntap, self.despike = ntap, despike
+        self.slices = {s["slot"]: s for _, _, ss in products for s in ss}
+        self.workdir = workdir
         self.mem_free, self.mem_floor = mem_free, mem_floor
         self.child, self.limit_s = list(child or CHILD), limit_s
-        # the longest first: the seconds grow with nfft
+        # the longest first: a kind states each task's cost
         self.tasks = sorted(
-            ((p["name"], s["slot"], p["nfft"], p["nint"])
-             for p in products for s in slices), key=lambda t: -t[2])
+            ((p["name"], slot, cost, kind.__name__, args)
+             for p, kind, slices in products
+             for slot, cost, args in kind.reference_tasks(
+                 p, slices, ntap=ntap, despike=despike)),
+            key=lambda t: -t[2])
         self.workers = max(1, min(len(self.tasks),
                                   (os.cpu_count() or 2) - SPARE_CORES))
         self.launched = self.most_at_once = 0
@@ -75,7 +88,7 @@ class ReferencePool:
     def start(self) -> None:
         """Take the streams from the slices and start the scheduler, which
         writes them out and runs the tasks; returns at once."""
-        volts = {s["slot"]: s.pop("volt") for s in self.slices}
+        volts = {slot: s.pop("volt") for slot, s in self.slices.items()}
         self.started_at = time.perf_counter()
         self._thread = threading.Thread(target=self._schedule, args=(volts,),
                                         name="reference-pool")
@@ -94,9 +107,10 @@ class ReferencePool:
                 "failed": {f"{n}/{slot}": why
                            for (n, slot), why in self._failed.items()}}
 
-    def rows(self, product: str, slot: int) -> np.ndarray:
-        """The reference rows ``(nspectra, nfft)`` float64 of ``product`` in
-        coarse slot ``slot``; waits for the pool where it still runs."""
+    def rows(self, product: str, slot: int):
+        """What the task of ``product`` in coarse slot ``slot`` kept (for
+        ``fil`` the reference rows ``(nspectra, nfft)`` float64; an array,
+        or a mapping of arrays); waits for the pool where it still runs."""
         self.wait()
         key = (product, slot)
         if key in self._failed:
@@ -104,7 +118,9 @@ class ReferencePool:
                 f"the reference of product {product}, coarse slot {slot}, "
                 f"gave no rows: {self._failed[key]}")
         if key not in self._rows:
-            self._rows[key] = np.load(self._out(*key))
+            kept = np.load(self._out(*key))
+            self._rows[key] = kept if isinstance(kept, np.ndarray) \
+                else dict(kept)
         return self._rows[key]
 
     def close(self) -> None:
@@ -127,9 +143,9 @@ class ReferencePool:
         return self.mem_free() - CHILD_BYTES >= self.mem_floor
 
     def _launch(self, task) -> None:
-        product, slot, nfft, nint = task
-        words = [self._volt(slot), self._out(product, slot), str(nfft),
-                 str(self.ntap), str(nint), str(int(self.despike))]
+        product, slot, _, kind, args = task
+        words = [kind, self._volt(slot), self._out(product, slot),
+                 json.dumps(args)]
         log = open(self._out(product, slot) + ".log", "w+")
         try:
             proc = subprocess.Popen(self.child + words, stdout=log,
@@ -158,10 +174,11 @@ class ReferencePool:
 
     def _schedule(self, volts: dict) -> None:
         waiting = list(self.tasks)
+        slots = list(volts)
         deadline = self.started_at + self.limit_s
         try:
             os.makedirs(self.workdir, exist_ok=True)
-            for slot in list(volts):   # each goes as soon as it is written
+            for slot in slots:   # each goes as soon as it is written
                 np.save(self._volt(slot), volts.pop(slot))
             while (waiting or self._alive) and not self._done.is_set():
                 while waiting and len(self._alive) < self.workers \
@@ -184,22 +201,26 @@ class ReferencePool:
                                if self._done.is_set() else late)
                 else:
                     self._reap(key)
-            for product, slot, _, _ in waiting:
+            for product, slot, *_ in waiting:
                 self._failed[product, slot] = "never started: " + late
+            for slot in slots:
+                with contextlib.suppress(OSError):
+                    os.remove(self._volt(slot))
             self.joined_at = time.perf_counter()
 
 
 def main(argv) -> int:
-    """The child: one task, the rows to ``out`` (written whole or not at
-    all), anything it has to say to its standard output."""
-    import reference
-
-    volt, out, nfft, ntap, nint, despike = argv
-    rows = reference.stokes_i(np.load(volt, mmap_mode="r"), nfft=int(nfft),
-                              ntap=int(ntap), nint=int(nint),
-                              despike=bool(int(despike)))
+    """The child: one task of the product kind whose module is ``kind``,
+    what it keeps to ``out`` (an array, or a mapping of arrays; written
+    whole or not at all), anything it has to say to its standard output."""
+    kind, volt, out, args = argv
+    kept = importlib.import_module(kind).compute(
+        np.load(volt, mmap_mode="r"), json.loads(args))
     with open(out + ".tmp", "wb") as f:
-        np.save(f, rows)
+        if isinstance(kept, np.ndarray):
+            np.save(f, kept)
+        else:
+            np.savez(f, **kept)
     os.replace(out + ".tmp", out)
     return 0
 

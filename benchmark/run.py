@@ -6,31 +6,40 @@ One process (a chip belongs to one process at a time).  A *pass* is one
 whole user command, the CLI's own ``blit.__main__.main(argv)`` called
 here: seeded GUPPI RAW on RAM-backed scratch -> every finished product at
 its final path, manifest published.  A pass makes a LIST of products (the
-traffic file's ``products``; a file without the key is a list of one) and
-everything below is done to each.  The pass's clock stops when this file's
-own ``os.fsync`` of the last of them has returned.
+traffic file's ``products``; a file without the key is a list of one),
+each of a KIND (``products/<kind>.py``; ``fil`` where the entry names
+none), and everything below is done to each, by asking its kind.  The
+pass's clock stops when this file's own ``os.fsync`` of the last product
+has returned.
 
 set-up   refuse without a TPU holding the cell's chips; fixed compile
          cache; empty tuning directory; ``make -B`` of blit/native; ask
          the machine what one file may hold and size the pass (``reduced``);
          write the recording from ``--seed``; start the plain reference's
          tasks in processes of their own (``refpool``); one warm-up pass
-         beside them; join them, keep their rows, check the warm-up's
-         products.  All of it is ``setup_s``.
+         beside them; join them, keep what they computed, check the
+         warm-up's products.  All of it is ``setup_s``.
 window   passes back to back; a pass starts only while the summed time of
          the passes so far is under ``--seconds``, and every started pass
-         completes and counts.  ``reduce_rate`` is the median pass's (in
-         a cell whose entry does not list it, the per-layer ``pass_rate``).
-         Checks run between passes, outside every timed interval, against
-         the kept rows: no reference arithmetic runs beside a pass.  A
+         completes and counts.  ``reduce_rate`` is the RAW bytes one pass
+         reads over the median pass's seconds (in a cell whose entry does
+         not list it, the per-layer ``pass_rate``).  Checks run between
+         passes, outside every timed interval, against what the reference
+         kept: no reference arithmetic runs beside a pass.  A
          compile inside a pass makes the run incorrect.
 traced   with ``--trace 1``, one more pass under ``jax.profiler``; the
          per-layer metrics come from it, from the window's rusage and
          from ``memory_stats``.
 
-The harness holds no list of cells, traffic mixes, driver kinds or
-per-layer metrics: ``BENCHMARK.json`` names them and each is a file of its
-own (configs/, traffic/, drivers/, layer_metrics/, readers/, peaks.json).
+The harness holds no list of cells, traffic mixes, driver kinds, product
+kinds or per-layer metrics: ``BENCHMARK.json`` names the first, the files
+it names name the rest, and each is a file of its own (configs/,
+traffic/, drivers/, products/, layer_metrics/, readers/, peaks.json).
+What a run READS is GUPPI RAW from ``recording.py``, written here
+(``write_inputs``): no command of blit reads anything else as a pass's
+input yet.  A command's report may lack what another's has:
+a pass whose JSON holds no stage table yields the device-trace and
+host-clock readings only.
 ``--rehearse`` runs the same code at toy sizes on the CPU and prints no
 metric; it proves nothing of the chip.  A run says what it took: ``[run]``
 on standard error, ahead of the numbers compared, gives the seconds from
@@ -64,7 +73,6 @@ from concurrent.futures import ThreadPoolExecutor  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device")
-FIRST_PRODUCT_BYTES = 1 << 16   # header + the first rows have landed
 WATCH_POLL_S = 0.02
 SMALL_PRODUCT_BYTES = 1 << 28   # read whole after every pass up to here
 MEMORY_HEADROOM_SHARE = 0.3   # of the machine's memory
@@ -118,8 +126,9 @@ def say(phase: str, **facts) -> None:
 # -- what BENCHMARK.json names -------------------------------------------------
 
 def load_cell(workload: str, rehearse: bool) -> dict:
-    """The cell's entry, its configuration file, its traffic file and its
-    driver module, each found by the name ``BENCHMARK.json`` gives."""
+    """The cell's entry, its configuration file, its traffic file, its
+    driver module and the module of each product's kind, each found by the
+    name ``BENCHMARK.json`` or one of those files gives."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -156,95 +165,105 @@ def load_cell(workload: str, rehearse: bool) -> dict:
         "name": workload, "chips": cell["chips"], "config": config,
         "traffic": traffic,
         "driver": importlib.import_module("drivers." + traffic["driver"]),
+        "kinds": product_kinds(traffic),
         "end_to_end": e2e,
         "per_layer": [m for m in bench["per_layer"]
                       if applies(m) and m["moves"] in moved],
     }
 
 
+def product_kinds(traffic: dict) -> list:
+    """The module of each product's kind, in the traffic file's order
+    (``products/<kind>.py``; ``fil`` where the entry names none)."""
+    return [importlib.import_module("products." + p.get("kind", "fil"))
+            for p in traffic["products"]]
+
+
 def plan_pass(cell: dict, out_cap: int) -> dict:
     """How long a pass is on this machine.  A cap on the size of one file
     caps the rows one product holds: that cuts duration (blocks), never
-    width.  One read feeds every product, so all are cut to the same
-    blocks: by the product with the most bytes, which is the one the
-    traffic file's ``align_rows`` (a chunk, a window) counts rows of, down
-    to whole ``align_rows`` where it can."""
-    g, t = cell["config"]["geometry"], cell["traffic"]
+    width.  One read feeds every product, so all are cut to
+    the same blocks: by the product with the most bytes among those whose
+    size follows from the plan (a ragged kind never sizes the cut), which
+    is the one the traffic file's ``align_rows`` (a chunk, a window) counts
+    rows of, down to whole ``align_rows`` where it can.  What a row is,
+    what it weighs and what holds none is each product's kind's to say."""
+    g, t, kinds = cell["config"]["geometry"], cell["traffic"], cell["kinds"]
     ntap = t["ntap"]
     nslots = cell["config"]["banks"] * g["obsnchan"]
 
-    from scratch import FIL_HEADER_ROOM
-
-    def rows_in(samples, p):
-        return (samples // p["nfft"] - (ntap - 1)) // p["nint"]
-
     def sized(blocks):
-        return [{"name": p["name"], "nfft": p["nfft"], "nint": p["nint"],
-                 "tolerance": p["tolerance"],
-                 "row_bytes": nslots * p["nfft"] * 4,
-                 "rows": rows_in(blocks * g["block_samples"], p)}
-                for p in t["products"]]
+        return [k.sized(spec, blocks * g["block_samples"], nslots=nslots,
+                        ntap=ntap)
+                for k, spec in zip(kinds, t["products"])]
 
     def hold_rows(products, blocks, why=""):
-        for p in products:
-            if p["rows"] < 1:
-                raise Refused(
-                    f"{blocks} blocks of {g['block_samples']} samples{why} "
-                    f"hold no row of product {p['name']!r} at nfft "
-                    f"{p['nfft']}, nint {p['nint']}")
+        for k, p in zip(kinds, products):
+            if said := k.nothing(p):
+                raise Refused(f"{blocks} blocks of {g['block_samples']} "
+                              f"samples{why} hold {said}")
 
     def fits(products):
-        return all(p["rows"] * p["row_bytes"] <= out_cap - FIL_HEADER_ROOM
-                   for p in products)
+        return all(k.bytes_at(p) <= out_cap
+                   for k, p in zip(kinds, products))
 
     blocks, products = t["blocks"], sized(t["blocks"])
     hold_rows(products, blocks)
-    for p in products:
-        if p["row_bytes"] > out_cap - FIL_HEADER_ROOM:
+    for k, p in zip(kinds, products):
+        if k.bytes_at(p, rows=1) > out_cap:
             raise Refused(
                 f"one row of {cell['name']}'s product {p['name']!r} is "
                 f"{p['row_bytes']} B and the largest file this machine "
                 f"allows is {out_cap} B: this cell cannot run here")
     rows_wanted = [p["rows"] for p in products]
-    big = max(products, key=lambda p: p["rows"] * p["row_bytes"])
+    fixed = [i for i, k in enumerate(kinds) if not k.RAGGED]
+    b = max(fixed or range(len(kinds)),
+            key=lambda i: products[i]["rows"] * products[i]["row_bytes"])
+    kind, big = kinds[b], products[b]
     if not fits(products):
-        rows = (out_cap - FIL_HEADER_ROOM) // big["row_bytes"]
+        rows = kind.rows_under(big, out_cap)
         if rows >= t["align_rows"]:
             rows -= rows % t["align_rows"]
-        blocks = math.ceil((rows * big["nint"] + ntap - 1) * big["nfft"]
+        blocks = math.ceil(kind.samples_for(big, rows, ntap)
                            / g["block_samples"])
-        while rows_in(blocks * g["block_samples"], big) > rows \
-                or not fits(sized(blocks)):
+        while (products := sized(blocks))[b]["rows"] > rows \
+                or not fits(products):
             blocks -= 1
-        products = sized(blocks)
         hold_rows(products, blocks, f" (all that a cap of {out_cap} B a "
                   f"file leaves of {t['blocks']})")
+        big = products[b]
     # A cut warm-up (drivers' WARMUP_CUT) is one `align_rows` of that
     # product; of the others, what as many samples give.
-    warm_frames = min(rows_in(blocks * g["block_samples"], big),
-                      t["align_rows"]) * big["nint"]
-    for p, want in zip(products, rows_wanted):
+    warm_rows = min(big["rows"], t["align_rows"])
+    warm = [k.sized(spec, kind.samples_for(big, warm_rows, ntap),
+                    nslots=nslots, ntap=ntap)
+            for k, spec in zip(kinds, t["products"])]
+    for p, want, w in zip(products, rows_wanted, warm):
         p.update(bytes=p["rows"] * p["row_bytes"], rows_wanted=want,
-                 warm_rows=rows_in((warm_frames + ntap - 1) * big["nfft"], p))
+                 warm_rows=w["rows"])
     block_bytes = g["block_samples"] * g["obsnchan"] * g["npol"] * 2
     return {
         "blocks": blocks, "blocks_wanted": t["blocks"], "nslots": nslots,
         "raw_bytes": cell["config"]["banks"] * blocks * block_bytes,
         "products": products, "sized_by": big["name"],
-        "warm_frames": warm_frames,
+        "warm_frames": kind.frames(big, warm_rows),
         "product_bytes": sum(p["bytes"] for p in products),
     }
 
 
 def write_inputs(cell: dict, plan: dict, rawdir: str, raw_cap: int,
-                 seed: int) -> dict:
-    """The recordings of every bank, and the reference's input slices."""
+                 seed: int, *, whole_band: bool = False) -> dict:
+    """The recordings of every bank, and the reference's input slices: one
+    a checked coarse channel (a tone's, or one the entry lists under
+    ``also``) and, with ``whole_band``, one of every other channel too
+    (``checked`` false)."""
     import recording
 
     cfg, t, g = cell["config"], cell["traffic"], cell["config"]["geometry"]
     banks = cfg["banks"]
     # A tone's `fine_offset` counts channels of the finest product.
-    tone_nfft = max(p["nfft"] for p in t["products"])
+    finest = max(t["products"], key=lambda p: p["nfft"])
+    tone_nfft = finest["nfft"]
     workers = max(1, min(t["pool_blocks"], 8,
                          ((os.cpu_count() or 2) - 1) // banks))
 
@@ -252,19 +271,27 @@ def write_inputs(cell: dict, plan: dict, rawdir: str, raw_cap: int,
         hdr = recording.raw_header(
             g, obsfreq=cfg["first_bank_obsfreq_mhz"] + k * cfg["obsbw_mhz"],
             obsbw=cfg["obsbw_mhz"])
-        tone = t["tones"][k]
-        keep = sorted({tone["chan"], *tone.get("also", [])})
+        tones = recording.tones_of(t["tones"][k])
+        checked = {tone["chan"] for tone in tones} \
+            | set(t["tones"][k].get("also", []))
+        keep = sorted(range(g["obsnchan"]) if whole_band else checked)
         paths, kept = recording.write_recording(
             cell["driver"].stem(rawdir, k, t), g, hdr, plan["blocks"],
-            raw_cap, seed=[seed, k], nfft=tone_nfft,
-            tone_chan=tone["chan"],
-            tone_fine_offset=tone["fine_offset"],
-            pool_blocks=t["pool_blocks"], keep_chans=keep, workers=workers)
-        slices = [{"volt": v, "chan": c, "slot": k * g["obsnchan"] + c,
-                   "raw_hdr": hdr, "tone_nfft": tone_nfft,
-                   "tone_fine_offset": tone["fine_offset"]
-                   if c == tone["chan"] else None}
-                  for c, v in kept.items()]
+            raw_cap, seed=[seed, k], nfft=tone_nfft, tones=tones,
+            pool_blocks=t["pool_blocks"], keep_chans=keep, workers=workers,
+            nint=finest["nint"])
+        slices = []
+        for c, v in kept.items():
+            mine = [tone for tone in tones if tone["chan"] == c]
+            # where the channel holds ONE tone and it stands still, the
+            # headers predict the fine channel it peaks in
+            still = len(mine) == 1 and not mine[0].get("drift")
+            slices.append({
+                "volt": v, "chan": c, "slot": k * g["obsnchan"] + c,
+                "raw_hdr": hdr, "tone_nfft": tone_nfft,
+                "tone_fine_offset": mine[0]["fine_offset"] if still
+                else None,
+                "checked": c in checked})
         return paths, slices
 
     with ThreadPoolExecutor(max_workers=banks) as ex:
@@ -340,26 +367,19 @@ def timed_pass(cell: dict, inputs: dict, outdir: str, tag: str, *,
                warm_frames=None) -> dict:
     """One pass: command entry -> this file's fsync of the last finished
     product.  A watcher thread (20 ms poll, from chip_smoke.py) notes, for
-    every product, when its first rows are in the file or its
-    ``.partial``; the pass's ``first_product_s`` is the earliest, what a
-    user tailing the directory sees."""
+    every product, when its kind calls its first rows landed; the pass's
+    ``first_product_s`` is the earliest, what a user tailing the directory
+    sees."""
     drv, t = cell["driver"], cell["traffic"]
     out = drv.new_out(outdir, tag)
-    products = product_paths(cell, out)
+    products, kinds = product_paths(cell, out), cell["kinds"]
     first, done = {}, threading.Event()
 
     def watch(t0):
         while len(first) < len(products) and not done.wait(WATCH_POLL_S):
-            for p in products:
-                if p in first:
-                    continue
-                for path in (p + ".partial", p):
-                    try:
-                        if os.path.getsize(path) > FIRST_PRODUCT_BYTES:
-                            first[p] = time.perf_counter() - t0
-                            break
-                    except OSError:
-                        pass
+            for p, kind in zip(products, kinds):
+                if p not in first and kind.landed(p):
+                    first[p] = time.perf_counter() - t0
 
     res = {"tag": tag, "out": out, "products": products}
     cpu0 = cpu_seconds()
@@ -468,6 +488,7 @@ def run(args, memory_seen=None) -> int:
     rehearse = args.rehearse
     cell = load_cell(args.workload, rehearse)
     cfg, t, drv = cell["config"], cell["traffic"], cell["driver"]
+    kinds = cell["kinds"]
     if not os.path.exists(os.path.join(ROOT, "blit", "__main__.py")):
         raise Refused("the system under test (blit/) is not in this "
                       f"checkout: {ROOT}")
@@ -536,12 +557,11 @@ def run(args, memory_seen=None) -> int:
         raw_roots = ["/dev/shm", tempfile.gettempdir()]
         out_roots = [tempfile.gettempdir(), "/dev/shm"]
         say("host", **scratch.host_facts(sorted(set(raw_roots + out_roots))))
-        unbounded = plan_pass(cell, 1 << 62)["products"]
-        want_file = max(p["bytes"] for p in unbounded) \
-            + scratch.FIL_HEADER_ROOM
+        unbounded = [k.bytes_at(p) for k, p in zip(
+            kinds, plan_pass(cell, 1 << 62)["products"])]
+        want_file = max(unbounded)
         outdir, out_cap = scratch.scratch_dir(
-            out_roots, 2 * sum(p["bytes"] + scratch.FIL_HEADER_ROOM
-                               for p in unbounded) + (1 << 30), want_file)
+            out_roots, 2 * sum(unbounded) + (1 << 30), want_file)
         made.append(outdir)
         plan = plan_pass(cell, out_cap)
         raw_file = plan["raw_bytes"] // cfg["banks"] \
@@ -564,33 +584,48 @@ def run(args, memory_seen=None) -> int:
             "cut, width never")
 
         t0 = time.perf_counter()
-        inputs = write_inputs(cell, plan, rawdir, raw_cap, args.seed)
+        # A kind whose reference reads the WHOLE band (`ALL_CHANNELS`) is
+        # given every channel's slice, any other the checked ones; asked
+        # here and nowhere else.
+        whole_band = any(k.ALL_CHANNELS for k in kinds)
+        inputs = write_inputs(cell, plan, rawdir, raw_cap, args.seed,
+                              whole_band=whole_band)
+        checked = [s for s in inputs["slices"] if s["checked"]]
+        slices_of = [inputs["slices"] if k.ALL_CHANNELS else checked
+                     for k in kinds]
         parts["synth_s"] = time.perf_counter() - t0
         say("synth", files=[[os.path.basename(p) for p in ps]
                             for ps in inputs["raws"]],
             seconds=parts["synth_s"], pool_blocks=t["pool_blocks"])
 
-        # The plain reference, once a run: a child process per (product,
-        # checked channel), started here, where nothing is timed, and
+        # The plain reference, once a run: a child process per task of a
+        # product's kind (for `fil` one a checked channel), started here,
+        # where nothing is timed, and
         # joined before `setup_s` is taken.  No child is alive beside a
         # measured or traced pass.
         m = memory_facts()
         pool = refpool.ReferencePool(
-            inputs["slices"], plan["products"], ntap=t["ntap"],
+            list(zip(plan["products"], kinds, slices_of)), ntap=t["ntap"],
             despike=t["despike"], workdir=os.path.join(rawdir, "reference"),
             mem_free=lambda: memory_facts().get("mem_free", 1 << 62),
             mem_floor=MEMORY_HEADROOM_SHARE * m.get("mem_total", 0)
             + REFERENCE_SLACK_BYTES)
         pool.start()
+        # With the whole band's streams out, as many bytes again as the
+        # recording, the children are joined before a pass takes memory of
+        # its own, not beside it.
+        t0 = time.perf_counter()
+        if whole_band:
+            pool.wait()
+        early = time.perf_counter() - t0
 
         problems = []   # what made the run incorrect
         bad = set()     # the passes with a product that was wrong
-        worst = {}      # product -> its largest error against the reference
+        worst = {}      # name compared -> the largest number read under it
 
-        def note(product, errs):
-            if errs:
-                worst[product] = max([worst.get(product, 0.0),
-                                      *errs.values()])
+        def note(numbers):
+            for name, got in numbers.items():
+                worst[name] = max(worst.get(name, got), got)
 
         def verify(res, rows, *, read_all, against_reference=False,
                    golden=None):
@@ -599,27 +634,25 @@ def run(args, memory_seen=None) -> int:
             a product).  ``rows`` names the plan's count to hold them to.
             Outside every timed interval."""
             res["facts"] = []
-            for i, (path, p) in enumerate(zip(res["products"],
-                                              plan["products"])):
+            for i, (path, p, kind, slices) in enumerate(zip(
+                    res["products"], plan["products"], kinds, slices_of)):
                 try:
-                    facts = check.guarantees(
-                        path, p[rows],
+                    facts = kind.guarantees(
+                        path, p, p[rows],
                         read_all or p["bytes"] <= SMALL_PRODUCT_BYTES)
                     res["facts"].append(facts)
                     if against_reference:
-                        said = check.against_reference(
-                            path, inputs["slices"],
+                        said, numbers = kind.against_reference(
+                            path, p, slices,
                             lambda slot, name=p["name"]: pool.rows(name, slot),
-                            rows=p[rows], nslots=plan["nslots"],
-                            nfft=p["nfft"], nint=p["nint"],
-                            tolerance=p["tolerance"])
+                            rows=p[rows], nslots=plan["nslots"])
                         say("check.reference", pass_=res["tag"],
                             product=p["name"], **said)
-                        note(p["name"], said["rel_err_by_slot"])
+                        note(numbers)
                     if golden is not None:
-                        check.same_product(path, facts, golden[i], args.seed)
+                        kind.same_product(path, facts, golden[i], args.seed)
                 except (check.Incorrect, refpool.ReferenceFailed) as e:
-                    note(p["name"], getattr(e, "rel_err_by_slot", {}))
+                    note(getattr(e, "compared", {}))
                     problems.append(f"{res['tag']}, product {p['name']}: {e}")
                     bad.add(res["tag"])
                     say("INCORRECT", pass_=res["tag"], product=p["name"],
@@ -630,9 +663,9 @@ def run(args, memory_seen=None) -> int:
             """The verified products' facts and seeded byte samples stay;
             the products themselves go (memory is what a run is short
             of)."""
-            g = [{**facts, "sample": check.sample(path, facts["bytes"],
-                                                  args.seed)}
-                 for path, facts in zip(res["products"], res["facts"])]
+            g = [{**facts, "sample": kind.sample(path, facts, args.seed)}
+                 for path, facts, kind in zip(res["products"], res["facts"],
+                                              kinds)]
             discard(res)
             return g
 
@@ -666,7 +699,8 @@ def run(args, memory_seen=None) -> int:
                                 f"verify_product: {said}")
         t1 = time.perf_counter()
         joined = pool.wait()
-        parts["reference_wait_s"] = time.perf_counter() - t1
+        late = time.perf_counter() - t1
+        parts["reference_wait_s"] = early + late
         say("reference", **joined, started_at_s=pool.started_at - T_START,
             joined_at_s=pool.joined_at - T_START,
             note="every child has ended; the rows are kept for the run")
@@ -677,8 +711,7 @@ def run(args, memory_seen=None) -> int:
         else:
             verify(warm, "warm_rows", read_all=False)
         discard(warm)
-        parts["other_checks_s"] = time.perf_counter() - t0 \
-            - parts["reference_wait_s"]
+        parts["other_checks_s"] = time.perf_counter() - t0 - late
         try:
             from blit.pipeline import RawReducer
 
@@ -737,10 +770,13 @@ def run(args, memory_seen=None) -> int:
 
             trace = xplane.reduce_trace(found[-1], tp["wall_s"]) if found \
                 else None
-            stages = tp["cli"]["stages"]
+            # A command's report may lack what another's has: without a
+            # stage table the readers of stages find nothing to read.
+            stages = tp["cli"].get("stages") or {}
             say("traced", **memory_facts(), wall_s=tp["wall_s"],
                 first_product_s=tp["first_product_s"],
-                first_by_product=tp["first_by_product"], stages=stages,
+                first_by_product=tp["first_by_product"],
+                stages=stages or "absent",
                 trace_file_bytes=os.path.getsize(found[-1]) if found else 0,
                 chips=trace and trace["chips"],
                 busy_s_by_chip=trace and trace["busy_s_by_chip"],
@@ -782,7 +818,9 @@ def run(args, memory_seen=None) -> int:
                 "stages": stages, "trace": trace,
                 "traced_raw_bytes": plan["raw_bytes"],
                 "traced_least_bytes": reference.least_bytes(
-                    plan["raw_bytes"], plan["product_bytes"]),
+                    plan["raw_bytes"], sum(
+                        k.least_bytes(p)
+                        for k, p in zip(kinds, plan["products"]))),
                 "window_raw_bytes": window_raw,
                 "window_cpu_s": sum(p["cpu_s"] for p in passes),
                 "window_first_product_s": [p["first_product_s"]
@@ -830,8 +868,9 @@ def run(args, memory_seen=None) -> int:
         # lines of stderr, and the last key of the result line.
         compiled = [p["compiles"]["backend_compiles"] for p in passes]
         compared = {
-            **{f"rel_err.{p['name']}": [worst.get(p["name"]), p["tolerance"]]
-               for p in plan["products"]},
+            **{name: [worst.get(name), limit]
+               for k, p in zip(kinds, plan["products"])
+               for name, limit in k.limits(p).items()},
             "wrong_products": [len(problems) - sum(map(bool, compiled)), 0],
             "compiles_in_window": [sum(compiled), 0]}
         for name, (got, limit) in compared.items():

@@ -15,13 +15,41 @@ ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, ROOT]
 
 
-def run_harness(*args, root=ROOT, prelude="", timeout=300):
+# The harness rebuilds blit/native in every run (``make -B``: the library
+# is built on the machine that runs).  Six rehearsals at once (``-n 6``)
+# raced on that one directory, so the tests build it ONCE a session
+# (``native_built``, under a lock the workers share) and a rehearsal
+# started from here finds its ``make`` already done — but for the ones
+# that ask for the harness as it is (``run_harness(rebuild=True)``:
+# ``test_rehearse.py`` keeps one that builds and one whose build fails).
+NO_REBUILD = (
+    "import subprocess as _sp\n_run = _sp.run\n"
+    "_sp.run = lambda cmd, *a, **kw: _sp.CompletedProcess(cmd, 0) "
+    "if list(cmd)[:2] == ['make', '-B'] else _run(cmd, *a, **kw)\n")
+
+
+@pytest.fixture(scope="session", autouse=True)
+def native_built():
+    import fcntl
+    import tempfile
+
+    native = os.path.join(ROOT, "blit", "native")
+    with open(os.path.join(tempfile.gettempdir(),
+                           "blit-bench-tests-native.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        subprocess.run(["make", "-C", native], check=True,
+                       stdout=subprocess.DEVNULL)
+
+
+def run_harness(*args, root=ROOT, prelude="", timeout=300, rebuild=False):
     """``benchmark/run.py`` as a fresh process (the harness sets JAX's
     platform and device count, so it cannot share this one).  ``prelude``
-    is Python run before ``main`` — how a test injects a file-size cap."""
+    is Python run before ``main`` — how a test injects a file-size cap.
+    ``rebuild``: the harness's own ``make -B`` runs."""
     code = ("import sys; sys.path[:0] = [%r, %r]; import run\n%s\n"
-            "sys.exit(run.main(%r))" % (os.path.join(root, "benchmark"), root,
-                                        prelude, list(args)))
+            "sys.exit(run.main(%r))" % (
+                os.path.join(root, "benchmark"), root,
+                ("" if rebuild else NO_REBUILD) + prelude, list(args)))
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
     p = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,

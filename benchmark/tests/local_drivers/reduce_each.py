@@ -30,7 +30,7 @@ def run_pass(traffic: dict, inputs: dict, out: str, run_cli,
             words += inputs["raws"][0] if w == "{raws}" \
                 else [w.format(path=path)]
         doc = run_cli(words)[-1]
-        for name, row in doc["stages"].items():
+        for name, row in doc.get("stages", {}).items():
             if "seconds" not in row:
                 continue
             have = stages.setdefault(name, dict(row, calls=0, seconds=0.0,
@@ -56,9 +56,9 @@ def break_product(path: str, kind: str) -> None:
         with open(path + ".partial", "wb") as f:
             f.write(b"left behind")
     elif kind == "short":
-        import check
+        from products import fil
 
-        hdr, _ = check.read_fil_header(path)
+        hdr, _ = fil.read_header(path)
         os.truncate(path, os.path.getsize(path) - hdr["nchans"] * 4)
     else:
         raise ValueError(f"reduce_each: unknown fault {kind!r}")

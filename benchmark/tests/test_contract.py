@@ -125,3 +125,36 @@ def test_peaks_table_has_its_source(kind):
     assert "Google Cloud" in peaks["source"]
     assert peaks["by_device_kind"][kind]["hbm_GBps"] == 819.0
     assert "cpu" not in peaks["by_device_kind"]
+
+
+def test_the_harness_names_no_format(bench_json):
+    """What a product IS is a file of its own (``products/<kind>.py``):
+    the harness's own files no longer say what a filterbank file is."""
+    for name in ("run.py", "refpool.py", "check.py"):
+        with open(os.path.join(BENCH, name)) as f:
+            text = f.read()
+        for word in (".fil", "nifs", "stokes_i"):
+            assert word not in text, (name, word)
+    assert "The harness holds no list of cells, traffic mixes, driver " \
+        "kinds, product\nkinds or per-layer metrics" in run.__doc__
+    # every kind a committed file names is a file of its own
+    kinds = {"fil"}
+    for w in bench_json["workloads"]:
+        with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
+            kinds |= {p.get("kind", "fil")
+                      for p in json.load(f).get("products", [])}
+    for kind in kinds | {"hits"}:
+        assert os.path.exists(os.path.join(BENCH, "products", kind + ".py"))
+
+
+@pytest.mark.parametrize("kind", ["fil", "hits"])
+def test_a_product_kind_answers_everything_the_harness_asks(kind):
+    import importlib
+
+    mod = importlib.import_module("products." + kind)
+    for name in ("sized", "nothing", "bytes_at", "rows_under", "frames",
+                 "samples_for", "least_bytes", "landed", "guarantees",
+                 "sample", "same_product", "reference_tasks", "compute",
+                 "against_reference", "limits"):
+        assert callable(getattr(mod, name)), (kind, name)
+    assert isinstance(mod.RAGGED, bool) and isinstance(mod.ALL_CHANNELS, bool)

@@ -183,9 +183,11 @@ def test_the_new_files_load_and_no_two_read_the_same_thing(bench_json):
         assert e["workloads"] == ["bank.hires"]
         for k in ("unit", "layer", "better", "source"):
             assert s[k] == e[k] == entries[base][k]
-    # ... at the end of the list, in the layers PERF.md section 3 has.
-    assert [m["name"] for m in bench_json["per_layer"][-15:]] == (
-        READINGS + [b + ".first" for b in TWINS])
+    # ... in one block (PR 40's followed it), in the layers PERF.md
+    # section 3 has.
+    names = [m["name"] for m in bench_json["per_layer"]]
+    at = names.index(READINGS[0])
+    assert names[at:at + 15] == READINGS + [b + ".first" for b in TWINS]
     assert {entries[n]["layer"] for n in READINGS} == {
         "whole host path", "pack and H2D", "D2H and write"}
     # The timeline readings read the rows the program records.

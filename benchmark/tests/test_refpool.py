@@ -15,6 +15,7 @@ from conftest import lines_of, run_harness
 
 import reference
 import refpool
+from products import fil
 
 # the three products of `rawspec3-t51`'s rehearsal
 PRODUCTS = [{"name": "0000", "nfft": 1024, "nint": 3},
@@ -32,7 +33,8 @@ def streams():
 
 def pool_of(tmp_path, volts, **kw):
     slices = [{"slot": slot, "volt": v.copy()} for slot, v in volts.items()]
-    pool = refpool.ReferencePool(slices, PRODUCTS, ntap=4, despike=False,
+    pool = refpool.ReferencePool([(p, fil, slices) for p in PRODUCTS],
+                                 ntap=4, despike=False,
                                  workdir=str(tmp_path / "reference"), **kw)
     pool.start()
     # the harness's copy of a stream goes once the children can map it
@@ -77,8 +79,8 @@ def test_a_task_runs_once_however_often_its_rows_are_read(ran):
 def test_despike_reaches_the_children(tmp_path):
     volts = {1: streams()[1]}
     slices = [{"slot": 1, "volt": volts[1].copy()}]
-    pool = refpool.ReferencePool(slices, PRODUCTS[2:], ntap=4, despike=True,
-                                 workdir=str(tmp_path / "r"))
+    pool = refpool.ReferencePool([(PRODUCTS[2], fil, slices)], ntap=4,
+                                 despike=True, workdir=str(tmp_path / "r"))
     pool.start()
     assert np.array_equal(pool.rows("0002", 1), reference.stokes_i(
         volts[1], nfft=64, ntap=4, nint=51, despike=True))

@@ -11,7 +11,8 @@ from conftest import (BENCH, EVERY_PASS, LOCAL_DRIVER, NO_TWIN, PUMP_CALL,
                       run_harness, run_line, tree_with)
 
 E2E = {"bank.hires": ["first_product_s", "setup_s"],   # rate: `pass_rate`
-       "bank.lowres": ["first_product_s", "reduce_rate", "setup_s"],
+       # its first rows come in two modes of pass: `first_product_wait_s`
+       "bank.lowres": ["reduce_rate", "setup_s"],
        # two 27 s passes to a run: too unsteady to carry a bound (PERF.md)
        "band4.hires": ["reduce_rate", "setup_s"]}
 
@@ -22,9 +23,14 @@ def last_doc(out):
 
 @pytest.mark.parametrize("cell", ["bank.hires", "bank.lowres", "band4.hires"])
 def test_end_to_end_run_at_toy_size(cell):
+    # one of the three runs the harness as it is: its own `make -B` of
+    # blit/native (the others find the session's build, conftest.py)
+    rebuild = cell == "bank.lowres"
     p, out = run_harness("--workload", cell, "--seed", "3", "--seconds",
-                         "0.05", "--trace", "0", "--rehearse")
+                         "0.05", "--trace", "0", "--rehearse",
+                         rebuild=rebuild)
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    assert (run_line(p)["build_s"] > 0.2) == rebuild
     doc = last_doc(out)
     assert doc["rehearsal"] is True and doc["platform"] == "cpu"
     assert doc["correct"] is True and doc["failed"] == 0
@@ -49,7 +55,8 @@ def test_end_to_end_run_at_toy_size(cell):
     # `wait.link` is a declared wait: 0 calls on the CPU, and so 0.0 s/GB
     ("bank.hires", [n + ".first" for n in EVERY_PASS + PUMP_WAITS
                     if n not in NO_TWIN] + ["pass_rate"]),
-    ("bank.lowres", EVERY_PASS + PUMP_WAITS + [PUMP_CALL]),
+    ("bank.lowres", EVERY_PASS + PUMP_WAITS + [PUMP_CALL,
+                                               "first_product_wait_s"]),
     ("band4.hires", EVERY_PASS + ["first_product_wait_s"]),
 ])
 def test_traced_run_reports_only_what_a_cpu_can(cell, names):
@@ -69,6 +76,34 @@ def test_traced_run_reports_only_what_a_cpu_can(cell, names):
     assert st["link.put"]["seconds"] > 0 and st["coeffs"]["calls"] >= 1
     assert st["write.digest"]["bytes"] > st["write"]["bytes"]
     assert ("dispatch.call" in st) == (cell != "band4.hires")
+
+
+def test_a_native_build_that_fails_ends_the_run_with_no_result(tmp_path):
+    """The harness's ``make -B`` as it is, in a tree whose ``blit/native``
+    cannot be built: no pass is made, no result line is printed, the exit
+    code is not 0 and nothing is left on scratch."""
+    root = tree_with(tmp_path / "tree", traffic={}, workloads=[])
+    os.remove(os.path.join(root, "blit"))
+    os.mkdir(os.path.join(root, "blit"))
+    for name in os.listdir(os.path.join(ROOT, "blit")):
+        if name != "native":
+            os.symlink(os.path.join(ROOT, "blit", name),
+                       os.path.join(root, "blit", name))
+    os.mkdir(os.path.join(root, "blit", "native"))
+    with open(os.path.join(root, "blit", "native", "Makefile"), "w") as f:
+        f.write("all:\n\t@echo this build fails >&2; false\n")
+    scratch = tmp_path / "scratch"
+    scratch.mkdir()
+    p, out = run_harness("--workload", "bank.lowres", "--seed", "5",
+                         "--seconds", "0.05", "--trace", "0", "--rehearse",
+                         root=root, rebuild=True, timeout=120,
+                         prelude=f"import tempfile; tempfile.tempdir = "
+                                 f"{str(scratch)!r}")
+    assert p.returncode not in (0, 2), p.stdout[-3000:] + p.stderr[-3000:]
+    assert "this build fails" in p.stderr \
+        and "CalledProcessError" in p.stderr.strip().splitlines()[-1]
+    assert not any(ln.startswith(("{", "[plan]", "[synth]")) for ln in out)
+    assert os.listdir(scratch) == []
 
 
 def test_no_accelerator_exits_nonzero_before_any_work():

@@ -26,6 +26,7 @@ def plan_of(settings, cap, blocks=38):
     width (the ``gbt-bank`` geometry), ``blocks`` blocks wanted."""
     cell = run.load_cell("bank.hires", rehearse=False)
     cell["traffic"] = products_traffic("mix", settings, blocks=blocks)
+    cell["kinds"] = run.product_kinds(cell["traffic"])
     return run.plan_pass(cell, cap)
 
 
