@@ -19,6 +19,7 @@ from typing import Dict, Optional, Tuple
 
 import h5py
 import numpy as np
+from h5py._objects import phil as _h5_lock
 
 from blit import faults
 from blit.config import nfpc_from_foff
@@ -28,6 +29,26 @@ from blit.io.bshuf import BITSHUFFLE_FILTER_ID
 # products have 2^20-point spectra, where BL's conventional 16-spectra chunk
 # row would be 16 GiB — defaults must clamp, not crash at writer open.
 H5_CHUNK_LIMIT = 2**32 - 1
+
+
+# h5py serializes every call into libhdf5 (which is not thread-safe) behind
+# one process-wide reentrant lock — every call but the direct-chunk pair:
+# ``DatasetID.read_direct_chunk`` / ``write_direct_chunk`` (h5py 3.14) enter
+# libhdf5 without it.  Any h5py call of another thread then runs inside
+# libhdf5 beside them — a finalizer is enough: a cyclic GC that happens on
+# a feed thread closes a dead writer's property lists there — and libhdf5's
+# one API context is torn ("ring type mismatch", "no VOL object wrap
+# context?", an error with no description, a corrupted heap: 4 of 16 writes
+# beside a thread that only creates property lists; with the lock 0 of
+# 120 000).  So blit holds h5py's lock itself around the pair.
+def _read_chunk(ds, corner) -> bytes:
+    with _h5_lock:
+        return ds.id.read_direct_chunk(corner)[1]
+
+
+def _write_chunk(ds, corner, payload) -> None:
+    with _h5_lock:
+        ds.id.write_direct_chunk(corner, payload)
 
 
 def default_chunks(
@@ -124,7 +145,7 @@ def _read_bitshuffle_chunks(ds, bbox: Tuple[Tuple[int, int], ...]) -> np.ndarray
 
     corners = list(itertools.product(*ranges))
     if len(corners) == 1:
-        place(corners[0], ds.id.read_direct_chunk(corners[0])[1])
+        place(corners[0], _read_chunk(ds, corners[0]))
         return out
     # Stream: reads stay serial, decodes overlap them in the pool; bounding
     # the in-flight futures bounds how many compressed payloads are resident
@@ -135,7 +156,7 @@ def _read_bitshuffle_chunks(ds, bbox: Tuple[Tuple[int, int], ...]) -> np.ndarray
     inflight: deque = deque()
     with ThreadPoolExecutor(nthreads) as pool:
         for corner in corners:
-            payload = ds.id.read_direct_chunk(corner)[1]
+            payload = _read_chunk(ds, corner)
             inflight.append(pool.submit(place, corner, payload))
             while len(inflight) > 2 * nthreads:
                 inflight.popleft().result()  # re-raises worker errors
@@ -249,7 +270,7 @@ def _write_bitshuffle_chunks(ds, data: np.ndarray) -> None:
             padded = np.zeros(chunk, data.dtype)
             padded[tuple(slice(0, b) for b in block.shape)] = block
             block = padded
-        ds.id.write_direct_chunk(corner, bshuf.compress_chunk(block))
+        _write_chunk(ds, corner, bshuf.compress_chunk(block))
 
 
 def _header_attrs(ds, header: Dict) -> None:
@@ -340,7 +361,7 @@ class _ChunkStream:
             # the direct-chunk write lands at a fixed corner.
             faults.fire("fbh5.write", key=self.path)
             self._ds.resize(self.nsamps + rows, axis=0)
-            self._ds.id.write_direct_chunk(corner, payload)
+            _write_chunk(self._ds, corner, payload)
 
         faults.retry_io(_write, describe=f"fbh5 chunk write {self.path}")
         self.nsamps += rows

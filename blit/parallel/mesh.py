@@ -496,7 +496,7 @@ def put_local_shards(
     straight onto its chip and the global array is built from the
     single-device shards, so the host never materializes the whole scan
     and no ``device_put`` targets a non-addressable device (the
-    multi-process contract of :func:`blit.parallel.scan._feed_window`,
+    multi-process contract of :func:`blit.parallel.scan._put_window`,
     now partition-rule-driven).  Each player's put is a ``feed.put``
     stage of its own (that block's bytes) on ``timeline`` and draws on
     the process's link budget (:class:`blit.device.HostLink`): one that

@@ -241,28 +241,21 @@ def _open_players(raw_paths, mesh):
     return mesh, local, raws, int(geo[0][0]), int(geo[0][1]), int(samps.min())
 
 
-def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
+def _read_window(raws, local, nchan, npol, start, ntime, tl=None,
                  staged=None, slab_ntime=None, head_ntime=0):
-    """Put gap-free samples ``[start, start + ntime)`` of every LOCAL
-    player on that player's chip, as
+    """The READ half of a window's feed: gap-free samples ``[start, start
+    + ntime)`` of every LOCAL player, read into host memory one player
+    after the other (``feed.read`` per read on ``tl``), as
     :func:`blit.ops.channelize.sample_words` (one word a sample: a view
-    of what was read, and the form the host link carries at speed), and
-    return the global sharded array ``(nband, nbank, nchan, ntime)`` —
-    the body of one :func:`blit.parallel.mesh.band_stream`.  Every sample
-    of a scan goes up once: a window is its NEW samples, its filter state
-    is on the chips already.  Where it is not — a stream's first window —
-    ``head_ntime`` asks for the head too, samples ``[start - head_ntime,
-    start)``: a read and a put of its own per player, laid out by the
-    ``filter_state`` rule and free to be donated.  Returns ``(head,
-    body)``, ``head`` ``None`` where none was asked for.
-
-    Every local player is read into host memory first, one after the
-    other (``feed.read`` per read on ``tl``); then each goes straight
-    onto its chip (``feed.put`` per put) and the global array is built
-    from the single-device shards (no whole-scan host buffer, no
-    device_put to any non-addressable device) — the assembly itself is
-    :func:`blit.parallel.mesh.put_local_shards`, the ONE
-    partition-rule-driven implementation the sharded plane shares.
+    of what was read, and the form the host link carries at speed).
+    Every sample of a scan is read once: a window is its NEW samples, its
+    filter state is on the chips already.  Where it is not — a stream's
+    first window — ``head_ntime`` asks for the head too, samples ``[start
+    - head_ntime, start)``: a read of its own per player.  Returns
+    ``(heads, bodies)``, each ``{(band, bank): (1, 1, nchan, n) words}``,
+    ``heads`` empty where none was asked for.  Touches no device: the
+    scan's feed thread runs it a window ahead of the loop
+    (:func:`reduce_scan_mesh_to_files`).
 
     ``staged`` (a list) makes each read's buffer a slab of the process
     staging pool (blit/hostmem.py) and appends it: the caller gives the
@@ -277,21 +270,19 @@ def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
     pass, and the next pass first-touched four fresh ones inside its
     timed reads).  A head's slab has the head's own shape, the same in
     every scan of that ``nfft``.  Without ``staged`` each read goes into
-    fresh memory that the returned array keeps alive."""
-    import jax
-
-    nband, nbank = mesh.devices.shape
+    fresh memory that the returned arrays keep alive."""
     tl = tl if tl is not None else observability.Timeline()
 
-    def read(r, skip, n, slab_n):
-        buf = None
-        if staged is not None:
-            buf = hostmem.slab_pool().take(
-                (nchan, max(n, slab_n), npol, 2), np.int8, tl)
-            staged.append(buf)
-            if buf.shape[1] != n:  # contiguous, in the slab's head
-                buf = buf.reshape(-1)[:nchan * n * npol * 2].reshape(
-                    nchan, n, npol, 2)
+    def slab(n, slab_n):
+        buf = hostmem.slab_pool().take(
+            (nchan, max(n, slab_n), npol, 2), np.int8, tl)
+        staged.append(buf)
+        if buf.shape[1] != n:  # contiguous, in the slab's head
+            buf = buf.reshape(-1)[:nchan * n * npol * 2].reshape(
+                nchan, n, npol, 2)
+        return buf
+
+    def read(r, skip, n, buf):
         with tl.stage("feed.read", nchan * n * npol * 2):
             v = _gapless(r, n, skip=skip, out=buf)
         if v.shape != (nchan, n, npol, 2):
@@ -302,24 +293,55 @@ def _feed_window(raws, local, mesh, nchan, npol, start, ntime, tl=None,
         return sample_words(v)[None, None]
 
     heads, bodies = {}, {}
+    reads = []  # (into, player, first sample, samples, the slab's samples)
     for bk in local:
         if head_ntime:
-            heads[bk] = read(raws[bk], start - head_ntime, head_ntime, 0)
-        bodies[bk] = read(raws[bk], start, ntime, slab_ntime or 0)
-    block = (nband, nbank, nchan)
+            reads.append((heads, bk, start - head_ntime, head_ntime, 0))
+        reads.append((bodies, bk, start, ntime, slab_ntime or 0))
+    # All of the window's slabs are taken before its first read: what the
+    # pool has lent at its peak is then whole windows, the same in every
+    # pass.  The pool keeps what a stretch lent at its peak; taken bank by
+    # bank, how many the feed thread held when the loop gave a window's
+    # back moved with the timing, and a pass that held one more than the
+    # pass before first-touched a slab inside its timed reads (0.54 GB,
+    # 0.2-0.8 s of a band pass).
+    bufs = [slab(n, slab_n) if staged is not None else None
+            for _, _, _, n, slab_n in reads]
+    for (into, bk, skip, n, _), buf in zip(reads, bufs):
+        into[bk] = read(raws[bk], skip, n, buf)
+    return heads, bodies
+
+
+def _put_window(heads, bodies, mesh, tl=None):
+    """The PUT half of a window's feed: what :func:`_read_window` read
+    goes straight onto each player's chip (``feed.put`` per put) and the
+    global sharded arrays are built from the single-device shards (no
+    whole-scan host buffer, no device_put to any non-addressable device)
+    — the assembly itself is :func:`blit.parallel.mesh.put_local_shards`,
+    the ONE partition-rule-driven implementation the sharded plane
+    shares.  Returns ``(head, body)``: ``body`` the global ``(nband,
+    nbank, nchan, ntime)`` array of one
+    :func:`blit.parallel.mesh.band_stream`, ``head`` its filter state
+    laid out by the ``filter_state`` rule and free to be donated, or
+    ``None`` where no head was read.  The link budget, and the rule that
+    a donated array has left it, are written for ONE putting thread: the
+    thread that calls the programs."""
+    import jax
+
+    def put(blocks, role):
+        shape = mesh.devices.shape + next(iter(blocks.values())).shape[2:]
+        return M.put_local_shards(blocks, mesh, shape, role, timeline=tl)
+
     # The heads go up first, each a transfer of its own on the link
     # budget (a head and its body as one transfer were let go of when
     # the smaller body had landed, and the next bank's was then enqueued
     # over the premapped region: 2-3 s of a pass, every other pass).
-    tail = M.put_local_shards(
-        heads, mesh, block + (head_ntime,), "filter_state", timeline=tl,
-    ) if head_ntime else None
-    body = M.put_local_shards(bodies, mesh, block + (ntime,), timeline=tl)
+    tail = put(heads, "filter_state") if heads else None
+    body = put(bodies, "voltages")
     if tail is not None:
         # The tail is DONATED to the window's program, and a handle the
         # link budget holds must never be (HostLink.put): the heads are
-        # waited in, once per stream (inside ``read``), and the budget
-        # lets go of them.
+        # waited in, once per stream, and the budget lets go of them.
         jax.block_until_ready(tail)
         host_link().retire()
     return tail, body
@@ -878,8 +900,9 @@ def load_scan_mesh(
     # One window that is the whole scan: the stream's head and all the
     # rest as its one body.
     head_ntime = (ntap - 1) * nfft
-    tail, body = _feed_window(raws, local, mesh, nchan, npol, head_ntime,
-                              frames * nfft, head_ntime=head_ntime)
+    tail, body = _put_window(
+        *_read_window(raws, local, nchan, npol, head_ntime, frames * nfft,
+                      head_ntime=head_ntime), mesh)
     coeffs = coeff_bank(ntap, nfft, window, observability.Timeline())
     out, _ = M.band_stream(
         tail,
@@ -941,6 +964,14 @@ def reduce_scan_mesh_to_files(
     RSS, HBM, and per-window readback stay bounded no matter the scan
     length and no matter ``nint`` — the mesh analog of
     ``RawReducer.reduce_to_file``'s slab streaming (blit/pipeline.py).
+    The window's READ runs one window ahead on a thread of its own
+    (:class:`blit.pipeline.BufferRotation`, three slots: window N-1 on
+    the chips, N being put and dispatched, N+1 being read into pooled
+    slabs; :func:`_read_window` touches no device); the puts, the program
+    calls, the fetches and the appends stay on the calling thread, in
+    the order a serial feed has them, and a window's slot and slabs are
+    given back once its programs have been waited out.  A read that
+    fails surfaces here, as itself, when the loop asks for that window.
     Every sample crosses the host link once: a window reads and puts its
     NEW frames only, as ``sample_words``, and each bank's filter state
     (the ``(ntap-1)*nfft`` samples before the window) stays on its chip
@@ -1016,10 +1047,18 @@ def reduce_scan_mesh_to_files(
 
     Observability (SURVEY.md §5 metrics bar): pass ``timeline`` (a
     :class:`blit.observability.Timeline`) to accumulate per-window stage
-    timings with byte counts — ``read`` (host RAW ingest + device feed:
-    the bytes it read; inside it one ``feed.read`` per read and one
-    ``feed.put`` per put — a bank's new samples, and once per stream its
-    head), the counted instants ``state.head`` (``calls`` = local
+    timings with byte counts — ``ingest`` (a window's host RAW read, on
+    the FEED thread: the bytes it read; inside it one ``feed.read`` per
+    read — a bank's new samples, and once per stream its head),
+    ``feed.put`` (one per put, on the loop's thread beside ``dispatch``),
+    the rotation's two waits, blocked seconds only and rows even at 0 —
+    ``wait.chunk`` (the loop waiting for a window that is not read yet:
+    the part of the read that is NOT hidden) and ``wait.ingest_slot``
+    (the feed waiting for a free slot: the loop is the slower side),
+    both counting the times it blocked: ``1 - wait.chunk.calls /
+    ingest.calls`` is the share of the windows that were read before the
+    loop asked (give or take the one wait for the stream's end) — and
+    the counted instants ``state.head`` (``calls`` = local
     banks whose filter state came up from the host: once per stream,
     ``bytes`` = those tails) and ``state.carry`` (``calls`` =
     bank-windows whose filter state was the previous window's output,
@@ -1084,7 +1123,9 @@ def reduce_scan_mesh_to_files(
     locally-fed member files; the finished product is identical to an
     uninterrupted run and the sidecars are removed on completion.
     """
+    from blit.config import stream_defaults
     from blit.observability import Timeline, profile_trace
+    from blit.pipeline import BufferRotation
 
     tl = timeline if timeline is not None else Timeline()
     products = ((int(nfft), int(nint)),) + tuple(
@@ -1110,6 +1151,7 @@ def reduce_scan_mesh_to_files(
     # ends are the stages `open` (the grid, the players and their block
     # index, the headers, the coefficient bank, the writers) and `close`.
     legs: list = []
+    rot = None
     with profile_trace(trace_logdir), observability.span(
             "scan.reduce", nfft=nfft) as root:
         try:
@@ -1185,7 +1227,7 @@ def reduce_scan_mesh_to_files(
             owner = next(leg for leg in legs if leg.nfft == big)
             mine = legs[0].mine
 
-            def flush(token, outs, staged):
+            def flush(token, outs, staged, slot):
                 # Blocking readback of one window's stitched bands -> disk.
                 # The compute wait is charged to "device" here (not at the
                 # async dispatch): this is where the host actually blocks on
@@ -1195,11 +1237,12 @@ def reduce_scan_mesh_to_files(
                 with tl.stage("device", byte_free=True):
                     jax.block_until_ready(token)
                 # The window has consumed its input: only now may its
-                # staging slabs serve another window (the one after next
-                # takes them, already faulted).
+                # staging slabs serve another window (the feed thread's
+                # next takes them, already faulted), and its slot.
                 pool = hostmem.slab_pool()
                 for buf in staged:
                     pool.give(buf, tl)
+                rot.release(slot)
                 # A product is handed the rows that closed in the window,
                 # and nothing where none did.
                 for leg, out in outs:
@@ -1216,33 +1259,51 @@ def reduce_scan_mesh_to_files(
                             leg.tag(sp)
                             leg.writers[b].append(slab)
 
-            # One window in flight: window N+1's host RAW reads + device_put +
-            # dispatch happen BEFORE blocking on window N's readback, so host
-            # I/O overlaps device compute at one extra window of HBM.
-            pending = None
             # Samples, on the grid of the largest nfft: a window is `wf` of
             # its frames and every leg takes the whole frames of its own
             # that the window's samples hold, up to its last row's.
-            start = head_ntime + f0_start * nfft
+            first = head_ntime + f0_start * nfft
             end = max(leg.end for leg in legs)
             # Every window stages through slabs of the largest window's shape.
-            slab_ntime = min(wf * big, end - start)
+            slab_ntime = min(wf * big, end - first)
             # Locally fed voltage bytes: complex int8 = 2 B/sample.
             per_sample = len(raws) * nchan * npol * 2
-            while start < end:
-                n = min(wf * big, end - start)
+
+            def read_ahead(rot):
+                # The feed thread: the loop's window grid, read a window
+                # ahead of it (no device, no collective: host arrays and
+                # the slabs they live in).  A stream's first window
+                # brings its head up with it.
+                start, head = first, head_ntime
+                while start < end and (slot := rot.acquire()) is not None:
+                    n = min(wf * big, end - start)
+                    staged = []
+                    with tl.stage("ingest", per_sample * (head + n)) as sp:
+                        if sp is not None:
+                            sp.attrs["f0"] = (start - head_ntime) // big
+                        heads, bodies = _read_window(
+                            raws, local, nchan, npol, start, n, tl, staged,
+                            slab_ntime, head)
+                    rot.emit(slot, (start, n, head, heads, bodies, staged))
+                    start, head = start + n, 0
+
+            # Three windows alive: N-1 on the chips (its flush is what
+            # gives its slabs and its slot back), N being put and
+            # dispatched here, N+1 being read.  The puts, the program
+            # calls, the fetches and the appends stay on this thread, in
+            # this order: the link budget and the donation rule are one
+            # putting thread's.
+            rot = BufferRotation(
+                3, read_ahead, timeline=tl,
+                stall_timeout_s=stream_defaults()["stall_timeout_s"])
+            pending = None
+            for slot, window in rot.slots():
+                start, n, head, heads, bodies, staged = window
                 frames = [max(0, min(leg.end, start + n) - start) // leg.nfft
                           for leg in legs]
-                # A stream's first window brings its head up with it.
-                head = head_ntime if owner.state.value is None else 0
                 with observability.span("scan.window",
                                         f0=(start - head_ntime) // big):
-                    staged = []
-                    with tl.stage("read", per_sample * (head + n)):
-                        tail, body = _feed_window(
-                            raws, local, mesh, nchan, npol, start, n, tl,
-                            staged, slab_ntime, head,
-                        )
+                    tail, body = _put_window(heads, bodies, mesh, tl)
                     # Filter state by where it comes from: up from the
                     # host (once per bank per stream) or left on the chip
                     # by the last window.
@@ -1275,8 +1336,7 @@ def reduce_scan_mesh_to_files(
                                 calls=len(raws) * (stepped + begun - 1))
                     if pending is not None:
                         flush(*pending)
-                pending = (token, outs, staged)
-                start += n
+                pending = (token, outs, staged, slot)
             if pending is not None:
                 flush(*pending)
             try:
@@ -1302,6 +1362,8 @@ def reduce_scan_mesh_to_files(
                             _withdraw(w)
                 raise
         finally:
+            if rot is not None:
+                rot.close()  # exception path: the feed thread reads no further
             for leg in legs:
                 for w in leg.writers.values():  # exception path: drop partials
                     w.abort()

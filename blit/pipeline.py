@@ -336,7 +336,16 @@ class BufferRotation:
                     return
                 slot, payload = item
                 if slot is _ROT_ERR:
-                    raise payload
+                    # Raised from this frame the exception holds the frame
+                    # (its traceback), so the frame must not hold the
+                    # exception: the cycle kept the CONSUMER's frames — the
+                    # writers, the readers, the staged slabs of a run that
+                    # died — until some thread's cyclic GC.
+                    del item
+                    try:
+                        raise payload
+                    finally:
+                        del payload
                 with self._held_lock:
                     self._held += 1
                 yield slot, payload

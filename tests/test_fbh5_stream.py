@@ -228,3 +228,48 @@ class TestChunkClamp:
             assert w.chunks[0] * w.chunks[1] * w.chunks[2] * 4 < 2**32
         finally:
             w.abort()
+
+
+class TestDirectChunksBesideAnotherThread:
+    """h5py's ``read_direct_chunk`` / ``write_direct_chunk`` enter libhdf5
+    without h5py's lock (3.14): beside ANY h5py call of another thread —
+    a property list closed by a cyclic GC that ran on a feed thread is
+    enough — libhdf5's state is torn (a quarter of the writes failed).
+    blit's two helpers hold the lock themselves."""
+
+    @pytest.mark.parametrize("what", ["write", "read"])
+    def test_the_pair_holds_h5pys_lock(self, tmp_path, what):
+        import sys
+        import threading
+
+        from blit.io import fbh5
+
+        stop = threading.Event()
+        every = sys.getswitchinterval()
+
+        def churn():  # what finalizers of dead writers do, all the time
+            while not stop.is_set():
+                del [h5py.h5p.create(h5py.h5p.DATASET_XFER)
+                     for _ in range(20)][:]
+
+        payload = np.zeros(256, np.float32).tobytes()
+        with h5py.File(tmp_path / "x.h5", "w") as h5:
+            ds = h5.create_dataset("data", shape=(300, 1, 256),
+                                   chunks=(1, 1, 256), dtype="f4")
+            for n in range(300):
+                fbh5._write_chunk(ds, (n, 0, 0), payload)
+            beside = threading.Thread(target=churn)
+            beside.start()
+            # (The two threads change places as often as they can.)
+            sys.setswitchinterval(1e-5)
+            try:
+                for n in range(3000):
+                    if what == "write":
+                        fbh5._write_chunk(ds, (n % 300, 0, 0), payload)
+                    else:
+                        assert fbh5._read_chunk(ds, (n % 300, 0, 0)) \
+                            == payload
+            finally:
+                sys.setswitchinterval(every)
+                stop.set()
+                beside.join()

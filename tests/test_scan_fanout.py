@@ -284,10 +284,10 @@ def test_the_band_is_read_and_put_once(band, three):
     _, st, _ = three
     raw = NBANK * NCHAN * NSAMPS * 4
     assert st["feed.read"]["bytes"] == st["link.put"]["bytes"] \
-        == st["read"]["bytes"] == raw
+        == st["ingest"]["bytes"] == raw
     # A head and eight bodies a bank.
     assert st["link.put"]["calls"] == st["feed.read"]["calls"] == NBANK * 9
-    assert st["read"]["calls"] == st["dispatch"]["calls"] == 8
+    assert st["ingest"]["calls"] == st["dispatch"]["calls"] == 8
     # Per bank: window 1 runs three steps and two head steps on one upload
     # (4 programs that did not put it), windows 2-7 three steps, window 8
     # two (0002 ended in the 7th).  What they did not send again: twice

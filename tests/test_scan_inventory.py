@@ -387,7 +387,7 @@ class TestBoundedDefaultWindow:
             SESSION, SCAN, inventories=invs, out_dir=str(tmp_path),
             nfft=NFFT, nint=NINT, timeline=tl,
         )
-        assert tl.stages["read"].calls > 1  # it actually windowed
+        assert tl.stages["ingest"].calls > 1  # it actually windowed
         _, out = load_scan_mesh(SESSION, SCAN, inventories=invs,
                                 nfft=NFFT, nint=NINT)
         _, data = read_fil_data(written[0][0])

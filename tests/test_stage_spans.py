@@ -409,21 +409,42 @@ class TestScanWindows:
         assert len({s["trace"] for s in spans}) == 1
         assert all(w["parent"] == roots[0]["span"] for w in windows)
         assert len({w["attrs"]["f0"] for w in windows}) == len(windows)
+        # The feed thread's `ingest`, one a window (attr `f0`, as the
+        # window's own), holds the window's reads; the window's puts stand
+        # on the loop's thread, beside `dispatch`.
+        ingests = {s["attrs"]["f0"]: s for s in spans
+                   if s["name"] == "ingest"}
+        assert len(ingests) == len(windows)
+        loop = {s["tid"] for s in spans if s["name"] == "dispatch"}
+        assert len(loop) == 1
+        assert {s["tid"] for s in ingests.values()}.isdisjoint(loop)
         for w in windows:
-            reads = [s for s in spans if s["name"] == "read"
-                     and s["parent"] == w["span"]]
-            assert len(reads) == 1
-            kids = [s["name"] for s in spans
-                    if s["parent"] == reads[0]["span"]]
+            read = ingests[w["attrs"]["f0"]]
+            assert read["parent"] == roots[0]["span"]
             # A window reads and puts its NEW samples; the stream's head
             # is a read and a put of its own per bank, in its first window.
             n = 8 if w["attrs"]["f0"] == 0 else 4
-            assert kids == ["feed.read"] * n + ["feed.put"] * n
-        # The per-bank stages carry the bank's bytes; `read` what it read:
-        # every sample of the scan, once.
+            assert [s["name"] for s in spans
+                    if s["parent"] == read["span"]] == ["feed.read"] * n
+            kids = [s for s in spans if s["parent"] == w["span"]
+                    and (s.get("attrs") or {}).get("stage") == 1]
+            assert [s["name"] for s in kids
+                    if s["name"].startswith("feed.p")] == ["feed.put"] * n
+            assert {s["tid"] for s in kids} == loop
+        # The per-bank stages carry the bank's bytes; `ingest` what it
+        # read: every sample of the scan, once.
         table = tl.report()
-        assert table["feed.read"]["bytes"] == table["read"]["bytes"]
-        assert table["feed.put"]["bytes"] == table["read"]["bytes"]
+        assert table["feed.read"]["bytes"] == table["ingest"]["bytes"]
+        assert table["feed.put"]["bytes"] == table["ingest"]["bytes"]
+        assert table["link.put"]["bytes"] == table["ingest"]["bytes"]
+        # The rotation's two waits are rows even where nothing blocked,
+        # and the loop waits at most once a window (and once more where
+        # it also waited for the stream's end): the windows it did not
+        # wait for were read ahead.
+        assert table["wait.chunk"]["byte_free"]
+        assert table["wait.ingest_slot"]["byte_free"]
+        assert table["ingest"]["calls"] == len(windows)
+        assert 0 <= table["wait.chunk"]["calls"] <= len(windows) + 1
         assert by_id[windows[0]["parent"]]["name"] == "scan.reduce"
 
 
@@ -675,12 +696,13 @@ class TestParts:
         assert by_id[coeffs["parent"]]["name"] == "open"
         assert table["coeffs"]["seconds"] <= table["open"]["seconds"]
         # The scan's puts are parts inside `feed.put`, its digests inside
-        # `write` (and the header's inside `open`): one thread does all.
+        # `write` (and the header's inside `open`): the loop's thread does
+        # all of them (the feed thread reads, and nothing else).
         ups = collections.Counter(
             by_id[s["parent"]]["name"] for s in part_spans(spans)
             if s["name"] == "link.put")
         assert set(ups) == {"feed.put"}
-        assert table["link.put"]["bytes"] == table["read"]["bytes"]
+        assert table["link.put"]["bytes"] == table["ingest"]["bytes"]
         assert table["link.put"]["seconds"] <= table["feed.put"]["seconds"]
         ups = collections.Counter(
             by_id[s["parent"]]["name"] for s in part_spans(spans)

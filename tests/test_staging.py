@@ -488,8 +488,8 @@ class SpyPool(hostmem.SlabPool):
         self.early = []
 
     @staticmethod
-    def _window(tl):          # the `read` stage of window w is still open
-        return tl.stages["read"].calls
+    def _window(tl):          # the feed thread's `ingest` of window w is
+        return tl.stages["ingest"].calls  # still open when it takes
 
     def take(self, shape, dtype=np.int8, timeline=None):
         before = self.reused
@@ -539,7 +539,9 @@ class TestMeshScanWindowFeed:
                 blk.dtype == np.int32
                 and any(np.shares_memory(blk, s) for s in lent)
                 for blk in blocks.values()))
-            roles.append((spy._window(kw["timeline"]), role, shape[-1]))
+            # (Window w is put before its `dispatch`, on the loop's thread.)
+            roles.append((kw["timeline"].stages["dispatch"].calls, role,
+                          shape[-1]))
             return put(blocks, mesh, shape, role, **kw)
 
         monkeypatch.setattr(S.M, "put_local_shards", spying_put)
@@ -550,7 +552,7 @@ class TestMeshScanWindowFeed:
         got = S.reduce_scan_mesh_to_files(
             paths, out_dir=str(tmp_path / "mesh"), nfft=NFFT, nint=NINT,
             window_frames=16, timeline=tl)
-        nwin = tl.stages["read"].calls
+        nwin = tl.stages["ingest"].calls
         shapes = [sorted({s for w, s, _ in spy.takes if w == i})
                   for i in range(nwin)]
         assert nwin >= 4
@@ -569,13 +571,16 @@ class TestMeshScanWindowFeed:
                              (0, "voltages", 16 * NFFT)]
         assert [(w, r) for w, r, _ in roles[2:]] \
             == [(w, "voltages") for w in range(1, nwin)]
-        # Two sets alternate: windows 0 and 1 allocate, window 2 takes
-        # window 0's slabs back, already faulted.
+        # Three sets go round (a window on the chips, one being put, one
+        # being read): windows 0 and 1 allocate, window 2 does unless
+        # window 0 is back already, and window 3 has a slot only once
+        # window 0 gave its slabs back, already faulted.
         reused = [[r for w, _, r in spy.takes if w == i]
                   for i in range(nwin)]
         assert reused[0] == [False] * 2 * self.NBANK
         assert reused[1] == [False] * self.NBANK
-        assert reused[2] == reused[-1] == [True] * self.NBANK
+        assert reused[2] in ([False] * self.NBANK, [True] * self.NBANK)
+        assert reused[-1] == [True] * self.NBANK
         assert not spy.early, spy.early
         # device_put saw the slabs themselves, no copy of them.
         assert aliased == [True] * (nwin + 1)
@@ -695,5 +700,36 @@ class TestMeshScanWindowFeed:
         assert tables[0]["staging.alloc"]["calls"] > 0
         assert tables[1]["staging.alloc"]["calls"] == 0
         assert tables[1]["staging.drop"]["calls"] == 0
+        assert filecmp.cmp(tmp_path / "one" / "band0.fil",
+                           tmp_path / "two" / "band0.fil", shallow=False)
+
+    def test_three_windows_of_slabs_are_all_a_scan_holds(
+            self, tmp_path, fresh_process_pool):
+        """The read runs ONE window ahead: a window on the chips, one
+        being put and one being read are alive at once, however many
+        windows the scan has, and a second pass finds them all faulted."""
+        from blit.parallel.scan import reduce_scan_mesh_to_files
+
+        paths = self.scan(tmp_path)
+        tables = []
+        for tag in ("one", "two"):
+            (tmp_path / tag).mkdir()
+            tl = Timeline()
+            # 61 usable frames in windows of 13: four full and a ragged one.
+            reduce_scan_mesh_to_files(
+                paths, out_dir=str(tmp_path / tag), nfft=NFFT, nint=NINT,
+                window_frames=13, timeline=tl)
+            tables.append(tl.report())
+        first, second = tables
+        assert first["ingest"]["calls"] == 5
+        # Bodies of at most three windows, and the stream's heads.
+        assert 2 * self.NBANK < first["staging.alloc"]["calls"] \
+            <= (3 + 1) * self.NBANK
+        assert first["staging.alloc"]["calls"] \
+            + first["staging.reuse"]["calls"] == (5 + 1) * self.NBANK
+        assert second["staging.alloc"]["calls"] == 0
+        assert second["staging.drop"]["calls"] == 0
+        assert second["staging.reuse"]["calls"] == (5 + 1) * self.NBANK
+        assert hostmem.slab_pool().stats()["lent_bytes"] == 0
         assert filecmp.cmp(tmp_path / "one" / "band0.fil",
                            tmp_path / "two" / "band0.fil", shallow=False)
