@@ -25,8 +25,8 @@ TPU notes (pallas_guide.md; SURVEY.md §7 "hard parts"):
 - All control flow is static; ``jax.lax`` only.  No data-dependent shapes.
 - The FIR stage runs on separate real/imag float32 planes, keeping it
   real-valued VPU/MXU work; the FFT recombines via ``lax.complex``.
-- The XLA path reads the samples as the int32 words they are
-  (:func:`sample_words`) and filters them where they lie: 8 coarse
+- Every path reads the samples as the int32 words they are
+  (:func:`sample_words`), where they lie.  The XLA path filters 8 coarse
   channels on the sublanes, a block's ``nfft`` points on the lanes, the
   blocks down a major axis (``channelize``'s ``words_core``).
 """
@@ -479,10 +479,14 @@ def channelize(
         layout, blit/io/guppi.py) with ``ntime`` a multiple of ``nfft`` and
         ``ntime//nfft >= ntap + nint - 1`` — or the same memory as
         :func:`sample_words`, ``(nchan_coarse, ntime)`` with one int32
-        (int16 at one polarization) per time sample.  Either form gives
-        the same bits: the XLA path (``pfb_kernel`` resolved to ``"xla"``)
-        reads words, the Pallas fronts int8, and the other form reaches
-        each through a bitcast inside the program.
+        (int16 at one polarization) per time sample — or a tuple of such
+        runs of words, each whole blocks of ``nfft``, consecutive in time
+        (a stream's filter state and its new samples).  Every form gives
+        the same bits: words are the only form inside the program (the
+        XLA path and both Pallas fronts read them where they lie; int8
+        becomes words by a bitcast, :func:`_samples_as_words`), and a
+        tuple's runs are put end to end only where a front needs ONE run
+        (``fused1`` takes them as they are, an operand each).
       coeffs: ``(ntap, nfft)`` PFB prototype from :func:`pfb_coeffs`.
       nfft: fine channels per coarse channel (the rawspec product size; 2**20
         for the hi-res product).
@@ -525,13 +529,22 @@ def channelize(
       within each coarse channel so the DC artifact sits at fine index
       ``nfft//2`` (despike parity, blit/ops/despike.py).
     """
-    if voltages.ndim == 2:  # sample_words: one word per time sample
-        nchan, ntime = voltages.shape
-        npol = voltages.dtype.itemsize // 2
+    if isinstance(voltages, (tuple, list)):  # runs of words, end to end
+        parts = tuple(voltages)
+    elif voltages.ndim == 2:  # sample_words: one word per time sample
+        parts = (voltages,)
     else:
-        nchan, ntime, npol, ncomp = voltages.shape
-        if ncomp != 2:
-            raise ValueError(f"channelize: (re, im) pairs, got {ncomp}")
+        if voltages.shape[-1] != 2:
+            raise ValueError(
+                f"channelize: (re, im) pairs, got {voltages.shape[-1]}")
+        parts = (_samples_as_words(voltages),)
+    nchan = parts[0].shape[0]
+    ntime = sum(p.shape[1] for p in parts)
+    npol = parts[0].dtype.itemsize // 2
+    if any(p.shape[1] % nfft for p in parts):
+        raise ValueError(
+            f"channelize: runs of {[p.shape[1] for p in parts]} samples "
+            f"are not whole blocks of {nfft}")
     if precision == "highest":
         prec = jax.lax.Precision.HIGHEST
     elif precision is None:
@@ -593,12 +606,6 @@ def channelize(
     pfb_kernel = _resolve_pfb_kernel(
         pfb_kernel, nfft=nfft, nblk=ntime // nfft, ntap=ntap, npol=npol,
         resolved=resolved, twisted=twisted, dtype=dtype)
-    # The XLA path reads the samples as words, where they lie; the Pallas
-    # kernels the int8 they are made of.  Either form of input reaches
-    # either through a bitcast inside the program.
-    if (pfb_kernel == "xla") != (voltages.ndim == 2):
-        voltages = (_word_samples(voltages) if voltages.ndim == 2
-                    else _samples_as_words(voltages))
     use_pallas_pfb = pfb_kernel == "pallas"
     use_fused1 = pfb_kernel == "fused1"
     interp = (use_pallas_pfb or use_fused1) and pallas_interpret(backend)
@@ -640,7 +647,8 @@ def channelize(
         detect_eligible = stokes == "I" and pallas_detect.fits(
             _factors, **_kw)
         td_eligible = pallas_detect.tail2_detect_fits(
-            _factors, stokes=stokes, **_kw)
+            _factors[:1] + tuple(sorted(_factors[1:])), stokes=stokes,
+            **_kw)
         _nframes = ntime // nfft - ntap + 1
         tail_eligible = (
             len(_factors) == 3
@@ -720,9 +728,14 @@ def channelize(
                 # Whole remaining pipeline — tail levels, untwist, detect,
                 # product transpose — in one pass; power arrives frame-
                 # major in the product layout.
+                # The remaining levels with the LARGER factor last: the
+                # stage-1 rows are read as (f2, f3) panels, f3 on the
+                # lanes, and 2^20 = 128 x (64 x 128) fills them where
+                # 128 x (128 x 64) half-fills them and makes XLA re-tile
+                # both planes in between (PERF.md section 6, PR 46).
+                f2, f3 = sorted(factors[1:])
                 power = tail2_detect(
-                    ur, ui, factors[1], factors[2], stokes=stokes,
-                    interpret=interp,
+                    ur, ui, f2, f3, stokes=stokes, interpret=interp,
                 )  # (nframes, nif, cb, nfft)
                 if nint > 1:
                     if power.shape[0] % nint:
@@ -767,7 +780,7 @@ def channelize(
             power = detect_stokes_planar(sr, si, stokes)
             return integrate(power, nint)
         if not use_pallas_pfb:
-            return words_core(v)
+            return words_core(jnp.concatenate(v, axis=1))
         from blit.ops.pallas_pfb import pfb_dequant
 
         fr, fi = pfb_dequant(
@@ -873,9 +886,9 @@ def channelize(
             raise ValueError(
                 f"channel_block={channel_block} does not divide nchan={nchan}"
             )
-        groups = voltages.reshape(
-            (nchan // channel_block, channel_block) + voltages.shape[1:]
-        )
+        groups = tuple(
+            p.reshape(nchan // channel_block, channel_block, p.shape[1])
+            for p in parts)
         power = jax.lax.map(core, groups)
         if use_td:
             # (g, t, nif, cb, nfft): channel-major assembly — one
@@ -885,7 +898,7 @@ def channelize(
         else:  # the groups' leading axes (channels, or slabs of them)
             power = power.reshape((-1,) + power.shape[2:])
     else:
-        power = core(voltages)
+        power = core(parts)
     if use_words:
         # (cg, t, nif, c, nfft) → (t, nif, cg, c, nfft): major axes only.
         power = jnp.transpose(power, (1, 2, 0, 3, 4))
@@ -926,17 +939,10 @@ def sample_words(voltages: np.ndarray) -> np.ndarray:
         _word_dtype(npol, ncomp))[..., 0]
 
 
-def _word_samples(words: jax.Array) -> jax.Array:
-    """The inverse of :func:`sample_words`, in a program: ``(nchan, ntime)``
-    words → int8 ``(nchan, ntime, npol, 2)`` (a bitcast: byte 0 of a word
-    is the first polarization's real part, as on the host)."""
-    v = jax.lax.bitcast_convert_type(words, jnp.int8)
-    return v.reshape(words.shape + (v.shape[-1] // 2, 2))
-
-
 def _samples_as_words(voltages: jax.Array) -> jax.Array:
-    """:func:`sample_words` in a program (the same bitcast the other way):
-    int8 ``(nchan, ntime, npol, 2)`` → ``(nchan, ntime)`` words."""
+    """:func:`sample_words` in a program: int8 ``(nchan, ntime, npol, 2)``
+    → ``(nchan, ntime)`` words (a bitcast: byte 0 of a word is the first
+    polarization's real part, as on the host)."""
     nchan, ntime, npol, ncomp = voltages.shape
     return jax.lax.bitcast_convert_type(
         voltages.reshape(nchan, ntime, npol * ncomp),
@@ -973,18 +979,17 @@ def head_step(words: jax.Array, coeffs: jax.Array, **kw
     return _gross_step((words,), coeffs, **kw)
 
 
-def _reads_words(words: jax.Array, *, nfft: int, ntap: int = 4,
-                 fft_method: str = "auto", dft_order: str = "auto",
-                 pfb_kernel: str = "auto", dtype: str = "float32",
-                 **_) -> bool:
-    """Whether :func:`channelize` of such a block (``words``, its other
-    keywords) takes the XLA path, which reads the words as they are."""
-    resolved = resolve_fft_method(fft_method, nfft)
-    return _resolve_pfb_kernel(
-        pfb_kernel, nfft=nfft, nblk=words.shape[1] // nfft, ntap=ntap,
-        npol=words.dtype.itemsize // 2, resolved=resolved,
-        twisted=resolved == "matmul" and dft_order == "twisted",
-        dtype=dtype) == "xla"
+def _span(parts: Sequence[jax.Array], start: int, stop: int
+          ) -> Tuple[jax.Array, ...]:
+    """Words ``[start, stop)`` of ``parts`` put end to end, as the runs
+    they lie in: slices of the parts, nothing joined."""
+    out, at = [], 0
+    for p in parts:
+        lo, hi = max(start - at, 0), min(stop - at, p.shape[1])
+        if lo < hi:
+            out.append(p if (lo, hi) == (0, p.shape[1]) else p[:, lo:hi])
+        at += p.shape[1]
+    return tuple(out)
 
 
 def _gross_step(parts: Tuple[jax.Array, ...], coeffs: jax.Array, *,
@@ -992,24 +997,21 @@ def _gross_step(parts: Tuple[jax.Array, ...], coeffs: jax.Array, *,
     """``parts`` end to end, the words of a filter state and the samples
     after it, reduced to their first ``frames`` frames (all they hold, by
     default) and the filter state the frame after them starts from.
-    ``lanes`` > 0 takes the small-``nfft`` path (:func:`channelize_lanes`,
-    blocks of that many words; it joins the parts itself, a few channels
-    at a time)."""
+    Nothing here joins them: :func:`channelize` takes the runs as they
+    are, and so does the small-``nfft`` path (``lanes`` > 0:
+    :func:`channelize_lanes`, blocks of that many words; it joins the
+    parts itself, a few channels at a time)."""
     nfft, state = kw["nfft"], (kw.get("ntap", 4) - 1) * kw["nfft"]
-    gross = jnp.concatenate(parts, axis=1)
     if frames is None:
-        frames = (gross.shape[1] - state) // nfft
+        frames = (sum(p.shape[1] for p in parts) - state) // nfft
     used = frames * nfft
     if lanes:
         power = channelize_lanes(
             parts, coeffs, nfft=nfft, ntap=kw.get("ntap", 4), block=lanes,
             frames=frames, stokes=kw.get("stokes", "I"))
     else:
-        block = gross[:, :used + state]
-        if not _reads_words(block, **kw):  # a Pallas front: int8, as ever
-            block = _word_samples(block)
-        power = channelize(block, coeffs, **kw)
-    return power, gross[:, used:used + state]
+        power = channelize(_span(parts, 0, used + state), coeffs, **kw)
+    return power, jnp.concatenate(_span(parts, used, used + state), axis=1)
 
 
 _STREAM_STATIC = _CHANNELIZE_STATIC + ("frames", "lanes")
@@ -1617,7 +1619,8 @@ def channels_per_dispatch(
     the concatenation materialises is in it), less what the program
     aliases (the donated tail) and less the filter state itself — every
     group's stays on the device between dispatches, so it is the caller's
-    to count, whole, against ``budget_bytes``.  Those scale
+    to count, whole, against ``budget_bytes`` — plus one more group's new
+    samples, the next dispatch's, in flight beside it.  Those scale
     linearly with the channel count from about 8 channels up (below that
     the TPU's tiled layouts pad the small batch and the per-channel figure
     is off by up to 2.5x), so the probe runs at 8 channels and the answer
@@ -1637,9 +1640,13 @@ def channels_per_dispatch(
         **kw,  # `lanes` among them where the leg takes that path
     ).compile().memory_analysis()
     tail = probe * (ntap - 1) * nfft * word.itemsize
+    # ... plus the NEXT group's new samples, which go up the link while
+    # this group's program runs (PERF.md section 6, PR 34: whatever the
+    # host still references must fit beside a running program).
+    ahead = probe * shape[1] * word.itemsize
     per_chan = -(-(m.argument_size_in_bytes + m.temp_size_in_bytes
                    + m.output_size_in_bytes - m.alias_size_in_bytes
-                   - tail) // probe)
+                   - tail + ahead) // probe)
     fit = budget_bytes // per_chan
     if fit < 1:
         raise MemoryError(

@@ -179,24 +179,56 @@ def tail2_detect_fits(factors, npol: int = 2, esize: int = 2,
                         _STOKES_NIF[stokes]) > 0
 
 
-def _td_kernel(npol, tile, stokes, xr_ref, xi_ref, w2r_ref, w2i_ref,
-               w3r_ref, w3i_ref, tr_ref, ti_ref, o_ref):
+def _td_panels(f3: int, tile: int, esize: int = 4) -> Tuple[int, int]:
+    """``(group, lane)`` of :func:`_td_rows`: the stage-1 rows a block
+    interleaves (the sublanes of a tile, where the f1 tile is whole
+    tiles) and the lanes a row of it holds."""
+    lane = 128 if f3 % 128 == 0 else f3
+    # A strided load takes 32-bit rows from ONE tile's width of lanes: an
+    # f3 that is not whole tiles (no shape the chip runs) and bfloat16
+    # spectra (tiled 16 rows deep; the opt-in) keep the (f1, f2, f3)
+    # view, which XLA re-tiles as it did for every shape until PR 46.
+    return (8 if tile % 8 == 0 and lane == 128 and esize == 4 else 1), lane
+
+
+def _td_rows(u: jax.Array, group: int, lane: int) -> jax.Array:
+    """Stage-1 spectra ``(nchan, npol, nframes, f1, m)`` as ``(nchan,
+    npol, nframes, f1/g, (m/lane)*g, lane)``: row ``q * g + s`` of slab
+    ``a`` is lanes ``[q*lane, (q+1)*lane)`` of stage-1 row ``a * g + s``.
+    With ``g`` 8 and ``lane`` 128 both shapes tile (8, 128) into the SAME
+    memory — :func:`blit.ops.pallas_pfb.pfb_dft1` wrote the tiles, this
+    reads them where they lie — so on the chip this is no operation."""
+    nchan, npol, nframes, f1, m = u.shape
+    return jnp.transpose(
+        u.reshape(nchan, npol, nframes, f1 // group, group, m // lane, lane),
+        (0, 1, 2, 3, 5, 4, 6),
+    ).reshape(nchan, npol, nframes, f1 // group, (m // lane) * group, lane)
+
+
+def _td_kernel(npol, tile, stokes, f2, f3, group, xr_ref, xi_ref, w2r_ref,
+               w2i_ref, w3r_ref, w3i_ref, tr_ref, ti_ref, o_ref):
     """DFT levels 2+3 + inner untwist + Stokes detect, one VMEM pass.
 
-    Blocks: x (1, npol, 1, tile_f1, f2, f3) planar stage-1 row panels;
-    o (1, nif, 1, f3, tile_f1, f2) — natural order up to ONE final lane
-    swap (f1 ⇄ f2) that the caller leaves to XLA.  Mosaic requires the
-    last two block dims be (8, 128)-divisible or full: f1 is tiled, so it
-    cannot sit in the lane dim, and lane-slice stores into a resident
-    full-f1 block need 128-aligned offsets — keeping f2 (=128 at the
-    production shape) as the lane axis satisfies both, and the leftover
-    swap is in XLA's fastest transpose class rather than the slow fused
+    Blocks: x (1, npol, 1, tile_f1/g, (m/lane)*g, lane), planar stage-1
+    rows as :func:`_td_rows` views them: the ``(f2, f3)`` panel of one
+    stage-1 row is every ``g * f3/lane``-th row of its slab, a strided
+    load (``f3`` on the lanes: 128 of them at every shape the chip runs);
+    o (1, nif, 1, f2, tile_f1, f3) — natural order up to ONE final
+    transpose ((f2 f1, f3) → (f3, f2 f1)) that the caller leaves to XLA.
+    Mosaic requires the last two block dims be (8, 128)-divisible or
+    full: f1 is tiled, so it cannot sit in the lane dim, and lane-slice
+    stores into a resident full-f1 block need 128-aligned offsets —
+    keeping f3 (= 128 at every shape the chip runs) as the lane axis of
+    what comes in AND of what goes out satisfies both, and the leftover
+    move is in XLA's fastest transpose class rather than the slow fused
     detect pass (DESIGN.md §9).  The DFT body is
     pallas_dft._tail2_kernel's (batched dots and transposes only —
     mosaic rejects reshapes that collapse transposed vector axes); the
     epilogue forms the detection product planes
     (detect_stokes_planar's table) from the per-pol spectra.
     """
+    from jax.experimental import pallas as pl
+
     # bf16 mode runs the dots at the MXU's full (4x) rate.  Accuracy: the
     # bf16-STORED spectra lose nothing (their products are exact in the
     # f32 accumulator), but the f32 DFT matrices and the post-twiddle
@@ -205,28 +237,47 @@ def _td_kernel(npol, tile, stokes, xr_ref, xi_ref, w2r_ref, w2i_ref,
     # not bit-identical to all-f32 dots.  The twiddle multiply stays f32
     # on the VPU.
     dot_dtype = xr_ref.dtype if xr_ref.dtype == jnp.bfloat16 else jnp.float32
-    xr4 = xr_ref[0, :, 0].astype(dot_dtype)  # (npol, tile, f2, f3)
-    xi4 = xi_ref[0, :, 0].astype(dot_dtype)
-    _, _, f2, f3 = xr4.shape
-    b = npol * tile
-    xr = xr4.reshape(b, f2, f3)  # leading-axis collapse only: mosaic-safe
-    xi = xi4.reshape(b, f2, f3)
+    lane = xr_ref.shape[-1]
+    pieces = f3 // lane
+
+    def panels(ref):
+        """(npol * tile, f2, f3): one stage-1 row's panel after the
+        other, each read where the front kernel wrote it."""
+        out = []
+        for p in range(npol):
+            for t in range(tile):
+                slab, s = divmod(t, group)
+                parts = [ref[0, p, 0, slab,
+                             pl.ds(h * group + s, f2, stride=group * pieces)]
+                         for h in range(pieces)]
+                out.append(parts[0] if pieces == 1
+                           else jnp.concatenate(parts, axis=-1))
+        return jnp.stack(out).astype(dot_dtype)
+
+    xr = panels(xr_ref)
+    xi = panels(xi_ref)
     w2r = w2r_ref[...].astype(dot_dtype)
     w2i = w2i_ref[...].astype(dot_dtype)
 
+    # Stage 2 down the panels' rows: W2 (f2k, f2l) @ panel (f2l, f3),
+    # batched over the panels with the matrix repeated — nothing is
+    # transposed on either side of it (timed on the chip against the
+    # form that contracts the panel's sublane axis and transposes the
+    # result back: 24.3 against 25.9 ms a 32-channel dispatch; PERF.md
+    # section 6, PR 46).
+    nb = xr.shape[0]
+    w2rb = jnp.broadcast_to(w2r[None], (nb,) + w2r.shape)
+    w2ib = jnp.broadcast_to(w2i[None], (nb,) + w2i.shape)
+
     def stage2(w, a):
-        # (b, f2l, f3) × (f2k, f2l) → dot layout (b, f3, f2k)
+        # (b, f2k, f2l) × (b, f2l, f3) → (b, f2k, f3)
         return jax.lax.dot_general(
-            a, w, (((1,), (1,)), ((), ())),
+            w, a, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
 
-    rr = stage2(w2r, xr)
-    ii = stage2(w2i, xi)
-    ri = stage2(w2r, xi)
-    ir = stage2(w2i, xr)
-    sr = (rr - ii).transpose(0, 2, 1)  # (b, f2k, f3)
-    si = (ri + ir).transpose(0, 2, 1)
+    sr = stage2(w2rb, xr) - stage2(w2ib, xi)
+    si = stage2(w2rb, xi) + stage2(w2ib, xr)
     tr = tr_ref[...][None]
     ti = ti_ref[...][None]
     ur = (sr * tr - si * ti).astype(dot_dtype)
@@ -235,7 +286,12 @@ def _td_kernel(npol, tile, stokes, xr_ref, xi_ref, w2r_ref, w2i_ref,
     w3i = w3i_ref[...].astype(dot_dtype)
 
     def stage3(a, w):
-        # (b, f2, f3j) × (f3j, f3k) → (b, f2, f3k)
+        # (b, f2, f3j) × (f3j, f3k) → (b, f2, f3k): ONE matrix product of
+        # all the panels' rows where they are whole tiles (leading-axis
+        # collapse only: mosaic-safe).
+        if f2 % 8 == 0:
+            return jnp.dot(a.reshape(nb * f2, f3), w,
+                           preferred_element_type=jnp.float32)
         return jax.lax.dot_general(
             a, w, (((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -272,10 +328,11 @@ def _td_kernel(npol, tile, stokes, xr_ref, xi_ref, w2r_ref, w2i_ref,
             else:  # IQUV
                 planes = [xx + yy, xx - yy, 2 * xy_re, -2 * xy_im]
     # Natural order within a coarse channel is (k3, k2, k1); the block
-    # keeps f2 in the lane dim — (f3, tile_f1, f2) — and the caller's
-    # final XLA swap moves k1 innermost.
+    # keeps f3 in the lane dim — (f2, tile_f1, f3), the panels with their
+    # two leading axes swapped, the lanes where they are — and the
+    # caller's final XLA transpose moves k1 innermost.
     for i, p in enumerate(planes):
-        o_ref[0, i, 0] = jnp.transpose(p, (2, 0, 1))
+        o_ref[0, i, 0] = jnp.transpose(p, (1, 0, 2))
 
 
 def tail2_detect(
@@ -327,17 +384,17 @@ def tail2_detect(
             f"tail2_detect: ({f2}, {f3}) panels exceed the VMEM budget — "
             "use the unfused tail (channelize tail_kernel='xla')"
         )
-    ur6 = ur.reshape(nchan, npol, nframes, f1, f2, f3)
-    ui6 = ui.reshape(nchan, npol, nframes, f1, f2, f3)
+    group, lane = _td_panels(f3, tile, ur.dtype.itemsize)
     w2r, w2i = (jnp.asarray(a) for a in dft_matrices(f2, "float32"))
     w3r, w3i = (jnp.asarray(a) for a in dft_matrices(f3, "float32"))
     t2r, t2i = (jnp.asarray(a) for a in twiddles(f2, f3, "float32"))
-    kern = functools.partial(_td_kernel, npol, tile, stokes)
-    x_spec = pl.BlockSpec((1, npol, 1, tile, f2, f3),
-                          lambda c, t, j: (c, 0, t, j, 0, 0))
-    # f2 stays the lane dim (128-divisible or full); the tiled f1 sits in
+    kern = functools.partial(_td_kernel, npol, tile, stokes, f2, f3, group)
+    x_spec = pl.BlockSpec(
+        (1, npol, 1, tile // group, (m // lane) * group, lane),
+        lambda c, t, j: (c, 0, t, j, 0, 0))
+    # f3 stays the lane dim (128-divisible or full); the tiled f1 sits in
     # the sublane dim where an 8-divisible tile is legal.
-    o_spec = pl.BlockSpec((1, nif, 1, f3, tile, f2),
+    o_spec = pl.BlockSpec((1, nif, 1, f2, tile, f3),
                           lambda c, t, j: (t, 0, c, 0, j, 0))
     w_spec2 = pl.BlockSpec((f2, f2), lambda c, t, j: (0, 0))
     w_spec3 = pl.BlockSpec((f3, f3), lambda c, t, j: (0, 0))
@@ -349,16 +406,20 @@ def tail2_detect(
                   t_spec, t_spec],
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (nframes, nif, nchan, f3, f1, f2), jnp.float32
+            (nframes, nif, nchan, f2, f1, f3), jnp.float32
         ),
         interpret=interpret,
-    )(ur6, ui6, w2r, w2i, w3r, w3i, t2r, t2i)
-    # One XLA lane swap finishes natural order — (f3, f2, f1) row-major is
-    # the per-channel natural index k = k1 + f1·k2 + f1·f2·k3.  (A pallas
-    # per-tile transpose of the same swap was measured SLOWER: 20.2 vs
-    # 11.9 ms at the production shape — mosaic's lane⇄sublane relayout
-    # loses to XLA's transpose lowering here, so the swap stays in XLA.)
-    return jnp.swapaxes(out, -1, -2).reshape(nframes, nif, nchan, f1 * m)
+    )(_td_rows(ur, group, lane), _td_rows(ui, group, lane),
+      w2r, w2i, w3r, w3i, t2r, t2i)
+    # One XLA transpose finishes natural order — (f3, f2, f1) row-major is
+    # the per-channel natural index k = k1 + f1·k2 + f1·f2·k3, and (f2 f1,
+    # f3) → (f3, f2 f1) is a plain 2-D transpose of whole tiles.  (A
+    # pallas per-tile transpose that put k1 on the lanes was measured
+    # SLOWER: 20.2 vs 11.9 ms at the production shape — mosaic's
+    # lane⇄sublane relayout loses to XLA's transpose lowering here, so the
+    # last move stays in XLA.)
+    return jnp.transpose(out, (0, 1, 2, 5, 3, 4)).reshape(
+        nframes, nif, nchan, f1 * m)
 
 
 # Backwards-compatible alias for the Stokes-I-only round-3 entry point.

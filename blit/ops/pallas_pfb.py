@@ -17,6 +17,19 @@ Opt-in from :func:`blit.ops.channelize.channelize` via
 ``pfb_kernel="pallas"``; CPU tests run in interpreter mode (golden vs the
 jnp path).  npol=2, NBITS=8 only — the GBT recording format
 (SURVEY.md §0); other shapes fall back to the jnp path.
+
+Both fronts take the samples as :func:`blit.ops.channelize.sample_words`
+— ``(nchan, T)`` int32, one word a time sample — and :func:`pfb_dft1`,
+the front every hi-res cell takes, reads them WHERE THEY LIE (PERF.md
+section 6, PR 46).  On the chip the words are tiled 8 channels x 128
+samples, which is, letter for letter, the memory of ``(nchan/8, nblk,
+n1*8, m)`` tiled over its last two axes (row ``j1*8 + c``: channel ``c``
+of the group, row ``j1`` of the block's ``(n1, m)`` matrix): the view
+costs nothing, a kernel's block holds a group's 8 channels, and an
+instance takes its own channel's rows by a strided load.  A stream's
+``(tail, body)`` are two operands of that kernel, never put end to end.
+(:func:`pfb_dequant` wants a channel's BLOCKS on the sublanes, which is
+one re-tiling of the words; XLA writes it.)
 """
 
 from __future__ import annotations
@@ -33,9 +46,20 @@ import jax.numpy as jnp
 # 16384/32768 ≈ 94-95 — smaller tiles pipeline HBM↔VMEM better.
 _DEF_TILE_J = 4096
 
-# Per-instance VMEM budget (v5e has ~16 MB; leave room for double
-# buffering and the compiler's own scratch).
+# Per-instance VMEM budget of :func:`pfb_dequant`'s blocks, and of
+# :func:`pfb_dft1`'s (whose block of words holds a group's 8 channels: at
+# the hi-res shape 5.5 MiB of its 7.9).
 _VMEM_BUDGET = 6 << 20
+_FUSED1_BUDGET = 12 << 20
+
+# What a kernel may hold at once: its blocks twice (the pipeline fetches
+# the next while one is worked on) and room for the compiler's own.  A
+# v5e's VMEM is 128 MiB; the compiler's default scope is 16.
+_VMEM_LIMIT = 48 << 20
+
+# Channels a block of words holds: the sublanes of the (8, 128) tiles the
+# words lie in (:func:`_grouped`).
+_GROUP = 8
 
 
 def _tile_bytes(tile_j: int, nblk: int, nframes: int, ntap: int,
@@ -72,13 +96,26 @@ def fits(nfft: int, nblk: int, ntap: int, dtype: str = "float32") -> bool:
     return pick_tile(nfft, nblk, nblk - ntap + 1, ntap, esize) > 0
 
 
+def _channel_rows(ref, lead: tuple, nrows: int, group: int) -> jax.Array:
+    """This instance's channel out of a block that holds ``group``
+    channels' rows interleaved (row ``r * group + c``): ``ref[lead]``'s
+    ``nrows`` rows of channel ``program_id(2)``, one strided load."""
+    from jax.experimental import pallas as pl
+
+    if group == 1:
+        return ref[lead]
+    return ref[lead + (pl.ds(pl.program_id(2), nrows, stride=group),)]
+
+
+def _byte(x: jax.Array, i: int) -> jax.Array:
+    """Little-endian byte ``i`` of each int32 (0: the first polarization's
+    real part), sign-extended from int8."""
+    return ((((x >> (8 * i)) & 0xFF) ^ 0x80) - 0x80).astype(jnp.float32)
+
+
 def _kernel(nframes: int, ntap: int, out_dtype, v_ref, w_ref, or_ref, oi_ref):
     x = v_ref[0]  # (nblk, tile_j) int32 — packed (p0r, p0i, p1r, p1i) bytes
     w = w_ref[...]  # (ntap, tile_j) f32 (sign-folded window)
-
-    def byte(i: int) -> jax.Array:
-        # Little-endian byte i of each int32, sign-extended from int8.
-        return ((((x >> (8 * i)) & 0xFF) ^ 0x80) - 0x80).astype(jnp.float32)
 
     def pfb(p: jax.Array) -> jax.Array:
         # p: (nblk, tile_j) f32 → (nframes, tile_j): windowed tap sums.
@@ -87,33 +124,35 @@ def _kernel(nframes: int, ntap: int, out_dtype, v_ref, w_ref, or_ref, oi_ref):
             acc = acc + w[k] * p[k : k + nframes]
         return acc.astype(out_dtype)
 
-    or_ref[0, 0] = pfb(byte(0))
-    oi_ref[0, 0] = pfb(byte(1))
-    or_ref[0, 1] = pfb(byte(2))
-    oi_ref[0, 1] = pfb(byte(3))
+    or_ref[0, 0] = pfb(_byte(x, 0))
+    oi_ref[0, 0] = pfb(_byte(x, 1))
+    or_ref[0, 1] = pfb(_byte(x, 2))
+    oi_ref[0, 1] = pfb(_byte(x, 3))
 
 
-def _fused1_kernel(nframes: int, ntap: int, n1: int, out_dtype,
-                   v_ref, w_ref, w1r_ref, w1i_ref, tr_ref, ti_ref,
-                   or_ref, oi_ref):
+def _fused1_kernel(nblks: Tuple[int, ...], ntap: int, n1: int, group: int,
+                   out_dtype, *refs):
     """dequant + PFB + DFT stage 1 (+twiddle), one VMEM pass.
 
     Blocks (per grid instance, fine columns ``j2``-tiled):
-      v:   (1, nblk, n1, tile_m) int32  packed voltages
+      v:   (1, nblk_p, n1 * group, tile_m) int32, one per part: packed
+           voltages, a group's channels interleaved row by row
       w:   (ntap, n1, tile_m)    f32    sign-folded window
       w1:  (n1, n1)              f32    stage-1 DFT matrix (re, im)
       tw:  (n1, tile_m)          f32    stage-1 twiddle (re, im)
       out: (1, npol, nframes, n1, tile_m) out_dtype (re, im)
     """
-    x = v_ref[0]  # (nblk, n1, tile_m) int32
+    v_refs = refs[:len(nblks)]
+    w_ref, w1r_ref, w1i_ref, tr_ref, ti_ref, or_ref, oi_ref = refs[len(nblks):]
+    # The parts end to end, block by block: (n1, tile_m) int32 each.
+    blocks = [_channel_rows(ref, (0, b), n1, group)
+              for ref, nblk in zip(v_refs, nblks) for b in range(nblk)]
+    nframes = len(blocks) - ntap + 1
     w = w_ref[...]
     w1r = w1r_ref[...]
     w1i = w1i_ref[...]
     tr = tr_ref[...]
     ti = ti_ref[...]
-
-    def byte(i: int) -> jax.Array:
-        return ((((x >> (8 * i)) & 0xFF) ^ 0x80) - 0x80).astype(jnp.float32)
 
     # bf16 mode runs the MXU at full rate: f32-input dots cost 4x on a
     # v5e, and bf16-grade multiplies are exactly what the XLA path's
@@ -125,7 +164,8 @@ def _fused1_kernel(nframes: int, ntap: int, n1: int, out_dtype,
     w1r = w1r.astype(dot_dtype)
     w1i = w1i.astype(dot_dtype)
 
-    planes = (byte(0), byte(1), byte(2), byte(3))  # p0r p0i p1r p1i
+    # p0r p0i p1r p1i, each a list over the blocks.
+    planes = [[_byte(x, i) for x in blocks] for i in range(4)]
     for p in range(2):
         re_g, im_g = planes[2 * p], planes[2 * p + 1]
         for f in range(nframes):
@@ -147,6 +187,39 @@ def _fused1_kernel(nframes: int, ntap: int, n1: int, out_dtype,
             oi_ref[0, p, f] = (sr * ti + si * tr).astype(out_dtype)
 
 
+def _parts(words) -> Tuple[jax.Array, ...]:
+    """``words`` as a tuple of consecutive runs of int32 words."""
+    parts = tuple(words) if isinstance(words, (tuple, list)) else (words,)
+    nchan = parts[0].shape[0]
+    for p in parts:
+        if p.ndim != 2 or p.dtype != jnp.int32 or p.shape[0] != nchan:
+            raise ValueError(
+                "npol=2 complex int8 samples as int32 words (nchan, T) "
+                f"required, got {p.dtype}{p.shape}")
+    return parts
+
+
+def _group(nchan: int, cols: int) -> int:
+    """Channels interleaved in one block of :func:`_grouped` rows: the 8
+    of a tile where the words' tiles are whole ones of the view too;
+    else 1, and XLA re-tiles the words (a shape no cell runs)."""
+    return _GROUP if nchan % _GROUP == 0 and cols % 128 == 0 else 1
+
+
+def _grouped(words: jax.Array, rows: int, cols: int) -> jax.Array:
+    """``(nchan, nblk * rows * cols)`` words as ``(nchan/g, nblk, rows * g,
+    cols)``: row ``r * g + c`` of block ``b`` is channel ``c`` of the
+    group's ``[b, r]`` run of ``cols`` samples.  With ``g`` 8 and ``cols``
+    a multiple of 128 both shapes tile (8, 128) into the SAME memory, so
+    on the chip this is no operation."""
+    nchan, nsamp = words.shape
+    g = _group(nchan, cols)
+    nblk = nsamp // (rows * cols)
+    return jnp.transpose(
+        words.reshape(nchan // g, g, nblk, rows, cols), (0, 2, 3, 1, 4)
+    ).reshape(nchan // g, nblk, rows * g, cols)
+
+
 def fused1_fits(nfft: int, nblk: int, ntap: int, n1: int,
                 dtype: str = "float32") -> bool:
     """VMEM-fit gate for :func:`pfb_dft1` (see :func:`_fused1_tile`)."""
@@ -154,26 +227,29 @@ def fused1_fits(nfft: int, nblk: int, ntap: int, n1: int,
 
 
 def _fused1_tile(nfft: int, nblk: int, ntap: int, n1: int,
-                 dtype: str, target: int = 512) -> int:
+                 dtype: str, target: int = 512, group: int = _GROUP) -> int:
     esize = 2 if dtype == "bfloat16" else 4
     m = nfft // n1
     nframes = nblk - ntap + 1
+    if group > 1:
+        # A strided load takes its rows from ONE tile's width of lanes.
+        target = 128
     for t in range(min(target, m), 0, -1):
         if m % t or (t % 128 and t != m):
             continue
         bts = t * (
-            nblk * n1 * 4          # packed input
+            nblk * n1 * 4 * group  # packed input, a group's channels
             + ntap * n1 * 4        # window
             + 2 * n1 * 4           # twiddles
             + 2 * 2 * nframes * n1 * esize  # outputs (2 planes x 2 pols)
         ) + 2 * n1 * n1 * 4        # DFT matrices
-        if bts <= _VMEM_BUDGET:
+        if bts <= (_FUSED1_BUDGET if group > 1 else _VMEM_BUDGET):
             return t
     return 0
 
 
 def pfb_dft1(
-    voltages: jax.Array,
+    words,
     coeffs: jax.Array,
     w1r: jax.Array,
     w1i: jax.Array,
@@ -186,11 +262,15 @@ def pfb_dft1(
     """Fused dequant + PFB + first Cooley-Tukey DFT stage.
 
     One HBM pass replaces three: the PFB frame planes never materialize —
-    int8 in, stage-1 spectra (twiddled, ready for the remaining factors of
+    words in, stage-1 spectra (twiddled, ready for the remaining factors of
     :func:`blit.ops.dft._dft_rec`) out.
 
     Args:
-      voltages: int8 ``(nchan, ntime, 2, 2)``.
+      words: int32 ``(nchan, ntime)``, one word a time sample
+        (:func:`blit.ops.channelize.sample_words`), or a sequence of such
+        runs, each whole blocks of ``nfft``, that are consecutive in time
+        (a stream's filter state and its new samples): each is an operand
+        of the kernel and they are never written end to end.
       coeffs: ``(ntap, nfft)`` f32 sign-folded window.
       w1r, w1i: ``(n1, n1)`` stage-1 DFT matrix parts.
       tr, ti: ``(n1, nfft//n1)`` stage-1 twiddle parts.
@@ -198,59 +278,64 @@ def pfb_dft1(
     Returns ``(ur, ui)`` shaped ``(nchan, npol, nframes, n1, nfft//n1)``.
     """
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    nchan, ntime, npol, ncomp = voltages.shape
-    if npol != 2 or ncomp != 2:
-        raise ValueError("pfb_dft1: npol=2 complex int8 input required")
+    parts = _parts(words)
+    nchan, npol = parts[0].shape[0], 2
     ntap, nfft = coeffs.shape
     n1 = w1r.shape[0]
     m = nfft // n1
-    if ntime % nfft:
-        raise ValueError(f"ntime={ntime} not a multiple of nfft={nfft}")
-    nblk = ntime // nfft
+    for p in parts:
+        if p.shape[1] % nfft:
+            raise ValueError(
+                f"ntime={p.shape[1]} not a multiple of nfft={nfft}")
+    nblks = tuple(p.shape[1] // nfft for p in parts)
+    nblk = sum(nblks)
     nframes = nblk - ntap + 1
-    tile_m = _fused1_tile(nfft, nblk, ntap, n1, dtype)
-    if tile_m == 0:
+    group = _group(nchan, m)
+    tile_m = _fused1_tile(nfft, nblk, ntap, n1, dtype, group=group)
+    if tile_m == 0 or nframes < 1:
         raise ValueError(
             "pfb_dft1: no column tile fits VMEM at these shapes — use the "
             "unfused path"
         )
 
-    packed = jax.lax.bitcast_convert_type(
-        voltages.reshape(nchan, nblk, n1, m, npol * ncomp), jnp.int32
-    )  # (nchan, nblk, n1, m)
     wv = coeffs.reshape(ntap, n1, m)
     out_dtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-    kern = functools.partial(_fused1_kernel, nframes, ntap, n1, out_dtype)
+    kern = functools.partial(_fused1_kernel, nblks, ntap, n1, group,
+                             out_dtype)
     out_shape = [
         jax.ShapeDtypeStruct((nchan, npol, nframes, n1, m), out_dtype),
         jax.ShapeDtypeStruct((nchan, npol, nframes, n1, m), out_dtype),
     ]
+    out_spec = pl.BlockSpec((1, npol, nframes, n1, tile_m),
+                            lambda g, j, c: (g * group + c, 0, 0, 0, j))
+    # The channel of a group is the fastest grid axis: a block of words is
+    # fetched once for its ``group`` instances.
     ur, ui = pl.pallas_call(
         kern,
-        grid=(nchan, m // tile_m),
+        grid=(nchan // group, m // tile_m, group),
         in_specs=[
-            pl.BlockSpec((1, nblk, n1, tile_m), lambda c, j: (c, 0, 0, j)),
-            pl.BlockSpec((ntap, n1, tile_m), lambda c, j: (0, 0, j)),
-            pl.BlockSpec((n1, n1), lambda c, j: (0, 0)),
-            pl.BlockSpec((n1, n1), lambda c, j: (0, 0)),
-            pl.BlockSpec((n1, tile_m), lambda c, j: (0, j)),
-            pl.BlockSpec((n1, tile_m), lambda c, j: (0, j)),
+            pl.BlockSpec((1, nb, n1 * group, tile_m),
+                         lambda g, j, c: (g, 0, 0, j))
+            for nb in nblks
+        ] + [
+            pl.BlockSpec((ntap, n1, tile_m), lambda g, j, c: (0, 0, j)),
+            pl.BlockSpec((n1, n1), lambda g, j, c: (0, 0)),
+            pl.BlockSpec((n1, n1), lambda g, j, c: (0, 0)),
+            pl.BlockSpec((n1, tile_m), lambda g, j, c: (0, j)),
+            pl.BlockSpec((n1, tile_m), lambda g, j, c: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, npol, nframes, n1, tile_m),
-                         lambda c, j: (c, 0, 0, 0, j)),
-            pl.BlockSpec((1, npol, nframes, n1, tile_m),
-                         lambda c, j: (c, 0, 0, 0, j)),
-        ],
+        out_specs=[out_spec, out_spec],
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(packed, wv, w1r, w1i, tr, ti)
+    )(*(_grouped(p, n1, m) for p in parts), wv, w1r, w1i, tr, ti)
     return ur, ui
 
 
 def pfb_dequant(
-    voltages: jax.Array,
+    words,
     coeffs: jax.Array,
     *,
     dtype: str = "float32",
@@ -260,8 +345,11 @@ def pfb_dequant(
     """Fused int8 dequant + polyphase FIR, one HBM pass.
 
     Args:
-      voltages: int8 ``(nchan, ntime, npol=2, 2)`` with ``ntime`` a
-        multiple of ``coeffs.shape[1]`` (GuppiRaw block layout).
+      words: int32 ``(nchan, ntime)``, one word a time sample of ``npol=2``
+        complex int8 (:func:`blit.ops.channelize.sample_words`), ``ntime``
+        a multiple of ``coeffs.shape[1]`` (GuppiRaw block layout) — or a
+        sequence of such runs, consecutive in time (joined here: the
+        kernel's taps slide over ONE run of blocks).
       coeffs: ``(ntap, nfft)`` float32 window (fftshift sign already
         folded by the caller, as in :func:`channelize`).
 
@@ -270,9 +358,9 @@ def pfb_dequant(
     """
     from jax.experimental import pallas as pl
 
-    nchan, ntime, npol, ncomp = voltages.shape
-    if npol != 2 or ncomp != 2:
-        raise ValueError("pfb_dequant: npol=2 complex int8 input required")
+    gross = jnp.concatenate(_parts(words), axis=1)
+    nchan, ntime = gross.shape
+    npol = 2
     ntap, nfft = coeffs.shape
     if ntime % nfft:
         raise ValueError(f"ntime={ntime} not a multiple of nfft={nfft}")
@@ -289,11 +377,10 @@ def pfb_dequant(
             f"(channelize pfb_kernel='xla'; 'auto' gates on pallas_pfb.fits)"
         )
 
-    # Pack each sample's 4 int8 components into one int32 lane element —
-    # a pure bitcast of the contiguous buffer (no data movement).
-    packed = jax.lax.bitcast_convert_type(
-        voltages.reshape(nchan, nblk, nfft, npol * ncomp), jnp.int32
-    )  # (nchan, nblk, nfft)
+    # A channel's blocks on the sublanes: ONE re-tiling of the words (8
+    # channels lie there), which XLA writes; :func:`pfb_dft1`, the front
+    # the hi-res cells take, writes none.
+    packed = gross.reshape(nchan, nblk, nfft)
     grid = (nchan, nfft // tile_j)
     out_dtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     kern = functools.partial(_kernel, nframes, ntap, out_dtype)

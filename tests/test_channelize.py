@@ -198,8 +198,8 @@ class TestChannelize:
             atol=2e-2 if option == "bf16" else 1e-5)
 
     def test_a_pallas_front_reads_words_too(self):
-        # Either form of input reaches either front end through a bitcast
-        # in the program (the kernels take int8, the XLA path words).
+        # Either form of input reaches either front end: words are the
+        # only form inside the program, int8 becomes them by a bitcast.
         nfft, ntap = 128, 4
         v = make_voltages(nchan=2, ntime=(ntap - 1 + 2) * nfft, seed=3)
         h = jnp.asarray(ch.pfb_coeffs(ntap, nfft))

@@ -250,7 +250,8 @@ class TestTheProgram:
     def test_the_probe_leaves_the_filter_state_to_the_caller(self):
         # The compiler's account of the program that runs, per channel,
         # less the tail: every group's is resident between dispatches and
-        # counted once, by RawReducer._channel_block.
+        # counted once, by RawReducer._channel_block.  Plus the next
+        # group's new samples, which go up while this program runs.
         shape = (8, 8 * NFFT, 2, 2)
         kw = dict(nfft=NFFT, ntap=NTAP)
         m = channelize_stream.lower(
@@ -260,7 +261,7 @@ class TestTheProgram:
         ).compile().memory_analysis()
         total = (m.argument_size_in_bytes + m.temp_size_in_bytes
                  + m.output_size_in_bytes - m.alias_size_in_bytes)
-        per_chan = -(-(total - 8 * STATE * 4) // 8)
+        per_chan = -(-(total - 8 * STATE * 4 + 8 * shape[1] * 4) // 8)
         assert channels_per_dispatch(shape, 4 * per_chan, **kw) == 4
         assert channels_per_dispatch(shape, 4 * per_chan - 1, **kw) == 2
         with pytest.raises(MemoryError, match="device memory"):
@@ -416,12 +417,70 @@ class TestSampleWords:
         np.testing.assert_array_equal(
             w.view(np.int8).reshape(v.shape), v)
 
-    @pytest.mark.parametrize("npol", [1, 2])
-    def test_the_program_reads_them_back(self, npol):
-        from blit.ops.channelize import _word_samples
 
-        rng = np.random.default_rng(7)
-        v = rng.integers(-128, 128, (2, 16, npol, 2), dtype=np.int8)
-        got = jax.jit(_word_samples)(sample_words(v))
-        assert got.dtype == jnp.int8
-        np.testing.assert_array_equal(np.asarray(got), v)
+class TestWordsAreTheOnlyFormInside:
+    """Every front reads words (ISSUE 46): int8 handed to ``channelize``
+    becomes words by a bitcast, a stream's ``(tail, body)`` reach a
+    Pallas front as they are (``fused1``: an operand each, a group's 8
+    channels to a block, a strided load a channel), and each gives the
+    bits of the one gross block.  Interpret mode, 8 channels (one whole
+    group) and 3 (none)."""
+
+    NFFT = 8192  # (128, 64): the least ``fused1`` takes
+
+    @staticmethod
+    def _voltages(nchan, frames, nfft, seed):
+        rng = np.random.default_rng(seed)
+        return rng.integers(-128, 128, (nchan, (NTAP - 1 + frames) * nfft,
+                                        2, 2), dtype=np.int8)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("pfb_kernel", ["fused1", "pallas"])
+    @pytest.mark.parametrize("nchan", [8, 3])
+    def test_int8_and_its_words_give_the_same_bits(self, nchan, pfb_kernel,
+                                                   dtype):
+        nfft = self.NFFT
+        v = self._voltages(nchan, 2, nfft, seed=nchan)
+        h = jnp.asarray(pfb_coeffs(NTAP, nfft))
+        kw = dict(nfft=nfft, ntap=NTAP, fft_method="matmul",
+                  pfb_kernel=pfb_kernel, dtype=dtype)
+        from blit.ops.channelize import last_kernel_plan
+
+        a = np.asarray(channelize(jnp.asarray(v), h, **kw))
+        assert last_kernel_plan()["pfb_kernel"] == pfb_kernel
+        b = np.asarray(channelize(jnp.asarray(sample_words(v)), h, **kw))
+        assert a.tobytes() == b.tobytes()
+        # ... and they are the samples' spectra, not only each other's.
+        want = channelize_np(v, np.asarray(h), nfft=nfft, ntap=NTAP)
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(
+            a / scale, want / scale,
+            atol=2e-2 if dtype == "bfloat16" else 1e-4)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("pfb_kernel", ["fused1", "pallas"])
+    @pytest.mark.parametrize("frames", [4, 2], ids=["body", "short-body"])
+    def test_a_stream_step_is_the_gross_block(self, frames, pfb_kernel,
+                                              dtype):
+        # ``frames`` 2 < ntap - 1: the next tail keeps part of the old.
+        nfft, nchan = self.NFFT, 8
+        state = (NTAP - 1) * nfft
+        w = sample_words(self._voltages(nchan, frames, nfft, seed=frames))
+        h = jnp.asarray(pfb_coeffs(NTAP, nfft))
+        kw = dict(nfft=nfft, ntap=NTAP, fft_method="matmul",
+                  pfb_kernel=pfb_kernel, dtype=dtype)
+        want = np.asarray(channelize(jnp.asarray(w), h, **kw))
+        got, tail = channelize_stream(
+            jnp.asarray(w[:, :state]), jnp.asarray(w[:, state:]), h, **kw)
+        assert np.asarray(got).tobytes() == want.tobytes()
+        np.testing.assert_array_equal(np.asarray(tail), w[:, -state:])
+        # The same runs handed to ``channelize`` as a tuple.
+        runs = np.asarray(channelize(
+            (jnp.asarray(w[:, :state]), jnp.asarray(w[:, state:])), h, **kw))
+        assert runs.tobytes() == want.tobytes()
+
+    def test_runs_that_are_not_whole_blocks_are_refused(self):
+        h = jnp.asarray(pfb_coeffs(NTAP, 64))
+        with pytest.raises(ValueError, match="whole blocks"):
+            channelize((jnp.zeros((2, 3 * 64), jnp.int32),
+                        jnp.zeros((2, 2 * 64 + 1), jnp.int32)), h, nfft=64)

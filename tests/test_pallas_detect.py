@@ -128,6 +128,35 @@ class TestTail2Detect:
         np.testing.assert_allclose(got, want, rtol=1e-5,
                                    atol=1e-4 * np.abs(want).max())
 
+    @pytest.mark.parametrize("npol,stokes", [(2, "full"), (1, "I"),
+                                             (2, "I")])
+    @pytest.mark.parametrize("factors", [(16, 4, 128), (8, 2, 256),
+                                         (16, 8, 8)],
+                             ids=["lanes128", "lanes256", "lanes8"])
+    def test_the_panels_are_read_where_the_front_wrote_them(
+            self, factors, npol, stokes):
+        # ISSUE 46: the block is the stage-1 rows as they lie — 8 of them
+        # interleaved, 128 lanes a row where f3 has them (one strided
+        # load a panel; two where f3 is 256) — not an (f2, f3) re-tiling.
+        from blit.ops.channelize import detect_stokes_planar
+        from blit.ops import pallas_detect as pd
+
+        f1, f2, f3 = factors
+        assert pd._td_panels(f3, min(f1, 16)) == (
+            (8, 128) if f3 % 128 == 0 else (1, f3))
+        rng = np.random.default_rng(f3 + npol)
+        shape = (2, npol, 2, f1, f2 * f3)
+        ur = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+        ui = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+        got = np.asarray(tail2_detect(ur, ui, f2, f3, stokes=stokes,
+                                      interpret=True))
+        sr, si = D.dft_tail(ur, ui, factors)
+        want = np.asarray(detect_stokes_planar(sr, si, stokes))
+        want = want.transpose(2, 1, 0, 3)  # (nframes, nif, nchan, n)
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-4 * np.abs(want).max())
+
     def test_single_pol_guard(self):
         ur = jnp.zeros((1, 1, 1, 8, 128), jnp.float32)
         with pytest.raises(ValueError, match="2 pols"):

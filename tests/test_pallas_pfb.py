@@ -26,7 +26,8 @@ class TestPfbDequant:
         nchan, nfft, ntap, nblk = 3, 256, 4, 6
         v = rng.integers(-128, 128, (nchan, nblk * nfft, 2, 2), np.int8)
         coeffs = ch.pfb_coeffs(ntap, nfft)
-        fr, fi = pfb_dequant(jnp.asarray(v), jnp.asarray(coeffs),
+        fr, fi = pfb_dequant(jnp.asarray(ch.sample_words(v)),
+                             jnp.asarray(coeffs),
                              dtype=dtype, interpret=True)
         wr, wi = jnp_reference(
             v, coeffs, jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
@@ -50,8 +51,8 @@ class TestPfbDequant:
         block = block[None]  # (1, 2048, 2, 2)
         coeffs = np.zeros((4, 256), np.float32)
         coeffs[0] = 1.0  # tap-0 passthrough: frames = raw blocks
-        fr, fi = pfb_dequant(jnp.asarray(block), jnp.asarray(coeffs),
-                             interpret=True)
+        fr, fi = pfb_dequant(jnp.asarray(ch.sample_words(block)),
+                             jnp.asarray(coeffs), interpret=True)
         want = block.reshape(1, 8, 256, 2, 2).astype(np.float32)
         np.testing.assert_array_equal(
             np.asarray(fr)[0, 0], want[0, :5, :, 0, 0])
@@ -123,7 +124,7 @@ class TestPfbDequant:
         assert pp.fits(1 << 20, 11, 4, "bfloat16")
         assert not pp.fits(1 << 10, 2051, 4, "float32")
         # And pfb_dequant refuses outright rather than failing in mosaic.
-        v = jnp.zeros((1, 2051 * 1024, 2, 2), jnp.int8)
+        v = jnp.zeros((1, 2051 * 1024), jnp.int32)
         h = jnp.asarray(ch.pfb_coeffs(4, 1024))
         with pytest.raises(ValueError, match="VMEM"):
             pp.pfb_dequant(v, h, interpret=True)
