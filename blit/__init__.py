@@ -41,10 +41,6 @@ Layer map (mirrors SURVEY.md §1, rebuilt TPU-first):
   ``/healthz``/``/snapshot`` HTTP endpoint), multi-window burn-rate SLO
   evaluation with load-shed breach actions, and the ``blit top``
   terminal dashboard.
-- ``blit.tune``      — the ingest autotuner: per-rig content-addressed
-  tuning profiles (chunk_frames / prefetch_depth / out_depth) converged
-  offline (``blit tune``) or online during the first windows of a
-  reduction, loaded automatically by every reducer.
 - ``blit.hostmem``   — pinned host staging: page-aligned slab allocation
   and the process-wide staging pool behind the chunk rotations and
   readback rings.
@@ -125,7 +121,6 @@ def __getattr__(name):
         "stream",
         "observability",
         "monitor",
-        "tune",
         "hostmem",
     ):
         import importlib

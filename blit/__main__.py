@@ -29,11 +29,6 @@ Commands:
              /healthz /metrics /drain) beating a heartbeat lease;
              SIGTERM drains gracefully — refuse new, finish in-flight,
              release live capacity holds.
-  tune       Offline ingest autotune (ISSUE 8): sweep the ingest knobs
-             (chunk_frames / prefetch_depth / out_depth) with real timed
-             reductions on THIS rig and persist the winner as a
-             content-addressed per-rig tuning profile that reduce /
-             scan / serve / stream load automatically.
   telemetry  Fleet telemetry (ISSUE 5): harvest per-worker Timelines,
              fault counters and spans into one per-host report (text /
              Prometheus exposition / JSON), render a saved report, or
@@ -298,72 +293,32 @@ def _cmd_stream(args: argparse.Namespace) -> int:
     return 0
 
 
-def _scan_window(args: argparse.Namespace, mdef: dict, sharded: bool):
-    """``(wf, tuning, depths, sharded)`` of a ``blit scan`` of ONE
-    product: the effective window, where it came from, the sharded
-    plane's rotation depths and whether that plane runs."""
+def _scan_window(args: argparse.Namespace, mdef: dict, sharded: bool,
+                 also=()):
+    """``(wf, tuning, depths, sharded)`` of a ``blit scan``: the
+    effective window, whose it is (``--window-frames``: ``explicit``,
+    else ``default``), the sharded plane's rotation depths and whether
+    that plane runs."""
     from blit.config import default_window_frames
     from blit.parallel.scan import scan_window_frames
 
-    # The EFFECTIVE window (library default + scan_window_frames), so the
-    # stats line reports what actually executed.  An unset --window-frames
-    # consults this rig's tuning profile first (blit/tune.py): the scan's
-    # frames-per-dispatch is the same quantity `blit tune` converged as
-    # chunk_frames, so the profile transfers.
-    tuning = {"source": "explicit"}
+    given = args.window_frames is not None
+    tuning = {"source": "explicit" if given else "default"}
+    if also:
+        # One grid for every product, in frames of the largest --nfft;
+        # every integration is folded across windows, so none sizes it.
+        # The mesh loop alone makes several products.
+        wf = scan_window_frames(max([args.nfft] + [f for f, _ in also]), 1,
+                                args.window_frames)
+        return wf, tuning, {}, False
     depths = {"prefetch_depth": mdef["prefetch_depth"],
               "out_depth": mdef["out_depth"]}
-    if args.window_frames is None:
-        # Resolve through a throwaway probe reducer so the profile key
-        # comes out of EXACTLY the code path reduce/serve/stream use —
-        # a scan flag can never silently diverge from the fingerprint
-        # (the probe supplies RawReducer's own defaults for every knob
-        # scan doesn't expose).
-        from blit.pipeline import RawReducer
-
-        probe = RawReducer(nfft=args.nfft, nint=args.nint,
-                           stokes=args.stokes, fqav_by=args.fqav,
-                           dtype=args.dtype)
-        probe_prov = probe.tuning_provenance()
-        # The sharded plane's rotation depths resolve from the SAME
-        # profile (unless BLIT_MESH_PREFETCH/BLIT_MESH_OUT_DEPTH pinned
-        # them) — the "tuning profiles resolved per-rig as today" rule.
-        for knob in ("prefetch_depth", "out_depth"):
-            if (depths[knob] is None
-                    and probe_prov["sources"][knob] == "profile"):
-                depths[knob] = getattr(probe, knob)
-        if probe_prov["sources"]["chunk_frames"] == "profile":
-            wf = probe.chunk_frames
-            prov = probe_prov["profile"]
-            prov["profile_source"] = prov.pop("source")
-            tuning = {"source": "profile", **prov}
-            # The profile's chunk_frames was converged on the REDUCE
-            # path, whose per-dispatch overhead is lighter than scan's
-            # per-window mesh stitch + readback sync — a profile far
-            # below scan's own default shrinks windows enough to let
-            # that overhead dominate.  Keep the profile (the operator
-            # tuned this rig) but say so, loudly and in the stats line.
-            default_wf = default_window_frames(args.nfft)
-            if wf * 16 < default_wf:
-                import logging
-
-                tuning["window_vs_default"] = {"window_frames": wf,
-                                               "default": default_wf}
-                logging.getLogger("blit.scan").warning(
-                    "tuning profile sets window_frames=%d, far below the "
-                    "scan default of %d for nfft=%d; if per-window "
-                    "overhead dominates, pass --window-frames explicitly "
-                    "or re-run `blit tune` at scan-scale chunk_frames",
-                    wf, default_wf, args.nfft)
-        else:
-            wf = default_window_frames(args.nfft)
-            tuning = {"source": "default"}
-    else:
-        wf = args.window_frames
     # The library's own rule, so the stats line reports what executed:
     # nint sizes the window only where an integration fits one; where it
     # does not, the window stands and the integration is carried.
-    wf = scan_window_frames(args.nfft, args.nint, wf)
+    wf = scan_window_frames(
+        args.nfft, args.nint,
+        args.window_frames if given else default_window_frames(args.nfft))
     if sharded and wf % args.nint and not args.search:
         if args.sharded:
             raise SystemExit(
@@ -399,15 +354,7 @@ def _cmd_scan(args: argparse.Namespace) -> int:
         ("--sharded", args.sharded), ("--pool", args.pool),
         ("--search", args.search)))
     invs = [get_inventory(args.file_re or r"\.raw$", root=args.root)]
-    if also:
-        # One grid for every product, in frames of the largest --nfft;
-        # every integration is folded across windows, so none sizes it.
-        wf = scan_window_frames(max([args.nfft] + [f for f, _ in also]), 1,
-                                args.window_frames)
-        tuning = {"source": "explicit" if args.window_frames else "default"}
-        depths, sharded = {}, False
-    else:
-        wf, tuning, depths, sharded = _scan_window(args, mdef, sharded)
+    wf, tuning, depths, sharded = _scan_window(args, mdef, sharded, also)
     tl = Timeline()
     parallel = "sharded" if sharded else ("pool" if args.pool else "mesh")
     if args.search:
@@ -795,120 +742,6 @@ def _monitor_from_flags(args: argparse.Namespace):
     return pub
 
 
-def _cmd_tune(args: argparse.Namespace) -> int:
-    """Offline ingest autotune (ISSUE 8 tentpole): coordinate descent
-    over ``chunk_frames`` / ``prefetch_depth`` / ``out_depth`` with real
-    timed file→product reductions on THIS rig, persisting the winner as
-    a content-addressed per-rig tuning profile
-    (:mod:`blit.tune`) that every subsequent ``reduce`` / ``scan`` /
-    ``serve`` / ``stream`` with unset knobs loads automatically.  Note
-    each new ``chunk_frames`` candidate costs one XLA compile — tuning
-    is an offline, once-per-rig operation by design."""
-    import os
-    import tempfile
-
-    from blit.outplane import INGEST_HISTS
-    import time as _time
-
-    from blit import tune as T
-    from blit.pipeline import RawReducer, dispatch_frames, fold_frames
-    from blit.testing import synth_raw
-
-    def build(knobs: dict, **kw) -> "RawReducer":
-        return RawReducer(
-            nfft=args.nfft, nint=args.nint, fqav_by=args.fqav,
-            dtype=args.dtype, nbits=args.nbits,
-            chunk_frames=knobs["chunk_frames"],
-            prefetch_depth=knobs["prefetch_depth"],
-            out_depth=knobs["out_depth"], tune_online=False, **kw,
-        )
-
-    with tempfile.TemporaryDirectory(prefix="blit-tune-") as td:
-        if args.raw:
-            raw_path = args.raw
-            file_bytes = os.path.getsize(raw_path)
-            from blit.io.guppi import open_raw
-
-            rdr = open_raw(raw_path)
-            tuned_nchan = int(rdr.header(0)["OBSNCHAN"])
-            total_samps = sum(rdr.block_ntime_kept(i)
-                              for i in range(rdr.nblocks))
-        else:
-            raw_path = os.path.join(td, "tune.raw")
-            ntime = (args.chunks * args.chunk_frames + 3) * args.nfft
-            _, blocks = synth_raw(raw_path, nblocks=args.blocks,
-                                  obsnchan=args.nchan,
-                                  ntime_per_block=-(-ntime // args.blocks))
-            file_bytes = sum(b.nbytes for b in blocks)
-            tuned_nchan = args.nchan
-            total_samps = sum(b.shape[1] for b in blocks)
-        # Candidates must keep >=2 full chunks inside the recording:
-        # a chunk spanning most of the file measures a degenerate
-        # near-zero-overhead run that always wins and then missizes
-        # every real reduction on the rig.
-        # chunk_frames moves in whole integrations where a dispatch
-        # holds one; one it cannot hold (-f 1048576 -t 51) is carried,
-        # binds nothing, and the ladder stays inside the dispatch budget.
-        fold = fold_frames(args.nfft, args.nint)
-        max_cf = max(fold, total_samps // args.nfft // 2)
-        if fold != args.nint:
-            max_cf = min(max_cf, dispatch_frames(args.nfft))
-        # Normalize FIRST so the untimed warmup (jit compile + page
-        # faults) runs at the exact knob set tune() measures first — a
-        # recording-clamped base must not pay its compile inside the
-        # first timed trial (that would understate baseline_gbps).
-        base = T.normalize_base({"chunk_frames": args.chunk_frames},
-                                nint=fold, max_chunk_frames=max_cf)
-        build(base).reduce_to_file(raw_path, os.path.join(td, "warm.fil"))
-        seq = [0]
-
-        def measure(knobs: dict) -> float:
-            best = 0.0
-            for _ in range(max(1, args.reps)):
-                red = build(knobs)
-                out = os.path.join(td, f"t{seq[0]}.fil")
-                seq[0] += 1
-                t0 = _time.perf_counter()
-                red.reduce_to_file(raw_path, out)
-                best = max(best,
-                           file_bytes / (_time.perf_counter() - t0) / 1e9)
-                os.unlink(out)
-            return best
-
-        best, trials = T.tune(measure, base=base, nint=fold,
-                              max_trials=args.trials,
-                              max_chunk_frames=max_cf)
-        # One confirmation pass at the winner captures the stage tails
-        # that travel with the profile as provenance.
-        winner = build(best)
-        t0 = _time.perf_counter()
-        winner.reduce_to_file(raw_path, os.path.join(td, "winner.fil"))
-        score = file_bytes / (_time.perf_counter() - t0) / 1e9
-        key, ident = T.rig_fingerprint(**winner._tune_fingerprint_kw())
-        prof = T.TuningProfile(
-            key=key, rig=ident, source="offline",
-            tuned_nchan=tuned_nchan,
-            score_gbps=round(score, 4), trials=len(trials),
-            stages=winner.timeline.hist_quantiles(INGEST_HISTS),
-            **{k: int(best[k]) for k in T.KNOBS},
-        )
-        path = T.save_profile(prof)
-        # trials[0] IS the base measurement (tune() scores its — possibly
-        # recording-size-clamped — starting point first), so the baseline
-        # survives even when the requested chunk_frames was capped.
-        base_score = trials[0]["score"] if trials else None
-        print(json.dumps({
-            "profile": path,
-            "key": key,
-            "winner": prof.knobs(),
-            "score_gbps": prof.score_gbps,
-            "baseline_gbps": (round(base_score, 4)
-                              if base_score is not None else None),
-            "trials": trials,
-        }))
-    return 0
-
-
 def _chaos_run(sup) -> dict:
     """Run a supervisor for the chaos drill, converting an exhausted
     recovery budget into a failed REPORT instead of a traceback — the
@@ -1165,8 +998,7 @@ def _chaos_corrupt(args: argparse.Namespace, work: str,
     # but for block 0, whose first samples are the stream's head: a
     # delivery of its own, ahead of the rest of the block.
     cf = max(args.nint, (nblocks * per_block) // args.nfft - 3)
-    kw = dict(nfft=args.nfft, nint=args.nint, chunk_frames=cf,
-              tune_online=False)
+    kw = dict(nfft=args.nfft, nint=args.nint, chunk_frames=cf)
     oracle = os.path.join(work, "oracle.fil")
     RawReducer(**kw).reduce_to_file(
         os.path.join(oracle_dir, "chaos.raw"), oracle)
@@ -1789,13 +1621,12 @@ def _cmd_chaos(args: argparse.Namespace) -> int:
                 oracle_raw = os.path.join(work, "chaos_zeroed.raw")
                 write_raw(oracle_raw, hdr0, zb)
         RawReducer(nfft=args.nfft, nint=args.nint,
-                   chunk_frames=args.window_frames,
-                   tune_online=False).reduce_to_file(oracle_raw, oracle)
+                   chunk_frames=args.window_frames
+                   ).reduce_to_file(oracle_raw, oracle)
         sup = StreamSupervisor(
             raw, out, kind="reduce",
             knobs=dict(nfft=args.nfft, nint=args.nint,
-                       chunk_frames=args.window_frames,
-                       tune_online=False),
+                       chunk_frames=args.window_frames),
             replay_rate=args.replay_rate, source=source, faults=fault,
             lease_ttl_s=args.lease_ttl, poll_s=args.poll,
             max_attempts=args.attempts, timeline=tl,
@@ -2500,36 +2331,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     pf = sub.add_parser("info", help="print a file's normalized header")
     pf.add_argument("file")
     pf.set_defaults(fn=_cmd_info)
-
-    pn = sub.add_parser(
-        "tune",
-        help="autotune the ingest knobs on THIS rig and persist the "
-             "winner as the per-rig tuning profile (ISSUE 8)",
-    )
-    pn.add_argument("--raw", default=None,
-                    help="tune against this real recording instead of a "
-                         "synthetic one")
-    pn.add_argument("--nfft", type=int, default=1024)
-    pn.add_argument("--nint", type=int, default=1)
-    pn.add_argument("--nchan", type=int, default=4,
-                    help="synthetic recording coarse channels")
-    pn.add_argument("--chunk-frames", type=int, default=8,
-                    help="sweep starting point (and synthetic sizing)")
-    pn.add_argument("--chunks", type=int, default=8,
-                    help="device chunks in the synthetic recording")
-    pn.add_argument("--blocks", type=int, default=4,
-                    help="RAW blocks the synthetic recording is split into")
-    pn.add_argument("--fqav", type=int, default=1)
-    pn.add_argument("--dtype", default="float32",
-                    choices=["float32", "bfloat16"])
-    pn.add_argument("--nbits", type=int, default=32, choices=[8, 16, 32])
-    pn.add_argument("--trials", type=int, default=12,
-                    help="measurement budget (each new chunk_frames "
-                         "candidate costs one compile)")
-    pn.add_argument("--reps", type=int, default=1,
-                    help="repetitions per measurement (best-of; raise on "
-                         "noisy rigs)")
-    pn.set_defaults(fn=_cmd_tune)
 
     pfp = sub.add_parser(
         "fleet-peer",

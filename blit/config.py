@@ -153,13 +153,10 @@ class SiteConfig:
     packet_rcvbuf_bytes: int = 32 << 20
     packet_ntime: int = 64
     packet_horizon_blocks: int = 2
-    # Ingest performance plane (blit/tune.py + blit/hostmem.py; ISSUE 8).
-    # tune_dir overrides where per-rig tuning profiles live (None = the
-    # BLIT_TUNE_DIR env, else ~/.cache/blit/tune); staging_pool_bytes is
+    # Ingest staging (blit/hostmem.py; ISSUE 8): staging_pool_bytes is
     # a fixed byte cap on the process-wide staging-slab pool (env
     # BLIT_STAGING_BYTES wins; 0 disables pooling; None = the pool keeps
     # what one stretch of work held at its peak, blit/hostmem.py).
-    tune_dir: Optional[str] = None
     staging_pool_bytes: Optional[int] = None
     # Sharded reduction plane (blit/parallel/sharded.py; ISSUE 9).
     # mesh_sharded makes `blit scan` default to the fully-threaded
@@ -171,9 +168,9 @@ class SiteConfig:
     # ``mesh.gather_s``; 0 disables the probe — steady-state windows
     # only account ICI bytes).  mesh_prefetch_depth / mesh_out_depth
     # size the feed rotation and readback/write-behind planes (None =
-    # the ingest-plane defaults, or this rig's tuning profile via the
-    # CLI).  Per-process overrides: BLIT_MESH_SHARDED / BLIT_MESH_PROBE
-    # / BLIT_MESH_PREFETCH / BLIT_MESH_OUT_DEPTH (:func:`mesh_defaults`).
+    # the ingest-plane defaults).  Per-process overrides:
+    # BLIT_MESH_SHARDED / BLIT_MESH_PROBE / BLIT_MESH_PREFETCH /
+    # BLIT_MESH_OUT_DEPTH (:func:`mesh_defaults`).
     mesh_sharded: bool = False
     mesh_probe_windows: int = 2
     mesh_prefetch_depth: Optional[int] = None

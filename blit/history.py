@@ -1019,7 +1019,7 @@ class IncidentBundler:
     def _provenance(self) -> Dict:
         """Config/tuning provenance: which knobs shaped the paging
         process — the effective defaults dicts plus every BLIT_* env
-        override and the tuner's state."""
+        override."""
         from blit.config import monitor_defaults, slo_defaults
 
         prov: Dict = {
@@ -1030,13 +1030,6 @@ class IncidentBundler:
             "env": {k: v for k, v in sorted(os.environ.items())
                     if k.startswith("BLIT_")},
         }
-        try:
-            from blit import tune
-
-            prov["tune"] = {"enabled": tune.enabled(),
-                            "dir": tune.profile_dir(self.config)}
-        except Exception:  # noqa: BLE001
-            pass
         return prov
 
 

@@ -72,8 +72,7 @@ log = logging.getLogger("blit.outplane")
 
 _EOF = object()
 
-# The output plane's per-chunk histograms, in the order `blit tune`'s
-# profile reports them.
+# The output plane's per-chunk histograms.
 INGEST_HISTS = ("out.chunk_latency_s", "out.readback_lag_s", "out.write_s")
 
 

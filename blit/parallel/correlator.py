@@ -482,10 +482,10 @@ def correlate_stream(
             if nband > 1:
                 # Warm-up dispatch: this is _finish_vis's first call of
                 # the stream, so a timed cold call would sample
-                # trace+XLA compile, not the collective (the PR 8
-                # OnlineTuner chunk-1 lesson; .lower().compile() does
-                # NOT warm the jit call cache on supported jax).  The
-                # warm-up also syncs every fold, so the timed
+                # trace+XLA compile, not the collective
+                # (.lower().compile() does NOT warm the jit call
+                # cache on supported jax).  The warm-up also syncs
+                # every fold, so the timed
                 # re-dispatch below is the psum program alone — the
                 # honest mesh.psum_s sample, one extra end-of-stream
                 # collective, never per-window.

@@ -1467,7 +1467,7 @@ def reduce_scan_pool_to_files(
     red_kw = dict(
         nfft=nfft, ntap=ntap, nint=nint, stokes=stokes, window=window,
         fft_method=fft_method, fqav_by=fqav_by, dtype=dtype,
-        chunk_frames=wf, tune_online=False,
+        chunk_frames=wf,
     )
 
     def reduce_bank(b, k):

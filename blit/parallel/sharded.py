@@ -250,8 +250,7 @@ def reduce_scan_sharded_to_files(
 
     New knobs: ``prefetch_depth``/``out_depth`` size the feed rotation
     and the readback/write-behind planes (``None`` = the ingest-plane
-    defaults — the CLI resolves them from this rig's tuning profile,
-    exactly as ``blit reduce`` does); ``probe_windows`` (default
+    defaults); ``probe_windows`` (default
     ``BLIT_MESH_PROBE`` / SiteConfig ``mesh_probe_windows``) is how many
     leading windows time the stitch collective honestly — those windows
     sync the per-chip compute first, so ``mesh.gather_s`` measures the
@@ -564,7 +563,7 @@ def search_scan_sharded_to_files(
         fft_method=fft_method, dtype=dtype, window_spectra=window_spectra,
         top_k=top_k, snr_threshold=snr_threshold,
         max_drift_bins=max_drift_bins, kernel=kernel, interpret=interpret,
-        prefetch_depth=prefetch_depth, out_depth=out_depth,
+        prefetch_depth=prefetch_depth or 2, out_depth=out_depth,
     )
     T = sred.window_spectra
     unit = T * nint  # frames per search window

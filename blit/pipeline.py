@@ -103,8 +103,7 @@ def dispatch_frames(nfft: int) -> int:
 def fold_frames(nfft: int, nint: int) -> int:
     """The multiple ``chunk_frames`` moves in — THE rule for "does an
     integration fit a dispatch", for the reducer's own sizing and for
-    everything that recommends a chunk size (``blit tune``,
-    :mod:`blit.tune`'s ``nint`` arguments): ``nint`` where
+    everything else that sizes a chunk: ``nint`` where
     :func:`dispatch_frames` holds one, so it folds inside one program;
     else 1 — the integration is carried across dispatches
     (:func:`blit.ops.channelize.integrate_carry`) and binds no chunk."""
@@ -430,23 +429,22 @@ class RawReducer:
     # Chunk buffers in the ingest rotation (>= 2).  2 = classic double
     # buffering: the producer thread reads chunk i+1 from the file while the
     # device works on chunk i.  Host memory held: prefetch_depth chunk-sized
-    # int8 buffers.  None (the default) = this rig's tuning profile when
-    # one exists (blit/tune.py), else 2.
-    prefetch_depth: Optional[int] = None
+    # int8 buffers.
+    prefetch_depth: int = 2
     # Output-plane depth: device outputs in readback flight + write-behind
-    # queue slots (blit/outplane.py).  None = the tuning profile, else
-    # prefetch_depth.  Deeper hides a laggier D2H link at the cost of one
-    # pinned chunk buffer (and its HBM output) per extra slot.
+    # queue slots (blit/outplane.py).  None = follow prefetch_depth.
+    # Deeper hides a laggier D2H link at the cost of one pinned chunk
+    # buffer (and its HBM output) per extra slot.
     out_depth: Optional[int] = None
     # Working dtype of the channelizer's DFT stages ("float32"|"bfloat16").
     # bf16 halves the inter-stage HBM, fitting ~2x the frames per dispatch
     # at a measured accuracy cost (DESIGN.md §8).
     dtype: str = "float32"
-    # Output frames per device call.  None = the tuning profile, else the
-    # per-dispatch sample budget's: a multiple of nint where an
-    # integration fits it, else the budget's frames with the integration
-    # carried across dispatches.  An explicit value is kept as given
-    # (nint need not divide it: the reduction then carries).
+    # Output frames per device call.  None = the per-dispatch sample
+    # budget's: a multiple of nint where an integration fits it, else
+    # the budget's frames with the integration carried across
+    # dispatches.  An explicit value is kept as given (nint need not
+    # divide it: the reduction then carries).
     chunk_frames: Optional[int] = None
     # Per-stage timing/byte registry ("ingest" / "stream" on the source
     # side; "dispatch" / "device" / "readback" / "write" on the output
@@ -482,11 +480,6 @@ class RawReducer:
     nbits: int = 32
     quant_scale: float = 1.0
     quant_offset: float = 0.0
-    # Online autotuning (blit/tune.py): after the first windows of a
-    # streaming reduction, derive a knob recommendation from the live
-    # stage timeline (published as tune.rec_* gauges; persisted as a
-    # tuning profile when BLIT_TUNE_ONLINE=1).
-    tune_online: bool = True
     # Further products from the SAME read, each ``(nfft, nint)``: rawspec's
     # ``-f 1048576,8,1024 -t 51,128,3072`` is ``nfft=1048576, nint=51,
     # also=((8, 128), (1024, 3072))``.  Every channel group goes up once
@@ -517,38 +510,16 @@ class RawReducer:
         # The slab a stream's head is read into (same discipline).
         self._head_slab: Optional[np.ndarray] = None
 
-        # Per-rig tuning profile (ISSUE 8): knobs the caller left unset
-        # resolve from this rig's content-addressed profile when one
-        # exists — `blit tune` (or an online-converged run) wrote it; a
-        # profile for a different rig/workload shape hashes to a
-        # different key and is never found.  BLIT_TUNE=0 disables.
-        self._tuning_profile = None
-        self._stream_nchan: Optional[int] = None
-        self._profile_nchan_mismatch: Optional[int] = None
+        # The three ingest knobs are the caller's, else what this
+        # method derives: no state outside the process sets them.
         self._knob_sources = {
             "chunk_frames": "explicit" if self.chunk_frames is not None
             else "default",
-            "prefetch_depth": "explicit" if self.prefetch_depth is not None
+            "prefetch_depth": "explicit" if self.prefetch_depth != 2
             else "default",
             "out_depth": "explicit" if self.out_depth is not None
             else "default",
         }
-        # A profile is keyed by ONE product's shape: several products
-        # take the defaults.
-        if not self.also and (
-                self.chunk_frames is None or self.prefetch_depth is None
-                or self.out_depth is None):
-            from blit import tune as _tune
-
-            prof = _tune.lookup(**self._tune_fingerprint_kw())
-            if prof is not None:
-                self._tuning_profile = prof
-                for knob, value in prof.knobs().items():
-                    if getattr(self, knob) is None:
-                        setattr(self, knob, value)
-                        self._knob_sources[knob] = "profile"
-        if self.prefetch_depth is None:
-            self.prefetch_depth = 2
         if self.out_depth is None:
             self.out_depth = max(2, self.prefetch_depth)
         self.out_depth = max(2, self.out_depth)
@@ -558,8 +529,7 @@ class RawReducer:
         # dispatch); the 1M-point hi-res product gets few (the complex64
         # FFT intermediates are what bound HBM, not dispatch overhead).
         budget = dispatch_frames(self.nfft)
-        fold = fold_frames(self.nfft, self.nint)
-        fits = fold == self.nint
+        fits = fold_frames(self.nfft, self.nint) == self.nint
         if self.also and self.chunk_frames is None:
             # The shared grid: the sample budget's (one frame of the
             # largest nfft where that is more), whatever the nints.
@@ -573,14 +543,6 @@ class RawReducer:
             self.chunk_frames = (
                 self.nint * max(1, min(64, budget) // self.nint)
                 if fits else budget)
-        elif self._knob_sources["chunk_frames"] == "profile":
-            if fits:
-                self.chunk_frames = -(-self.chunk_frames // fold) * fold
-            else:
-                # A profile from before the carry rounded up to the
-                # integration (51 frames at 2^20: a 14.5 GB chunk); one
-                # the budget cannot hold is re-sized, never honoured.
-                self.chunk_frames = min(self.chunk_frames, budget)
         if self.chunk_frames < 1:
             raise ValueError(f"chunk_frames={self.chunk_frames} must be >= 1")
         for nfft, nint in self.products:
@@ -638,70 +600,25 @@ class RawReducer:
         """The process's coefficient bank for ``nfft``
         (:func:`blit.ops.channelize.coeff_bank` owns it and records the
         lookup as this reduction's part ``coeffs``), asked for on FIRST
-        compute use — not at construction: throwaway probe reducers (scan
-        resolves tuning knobs through one) must not pay a
-        multi-million-coefficient sinc*window build plus device transfer
-        just to read provenance.  The reducer keeps no bank of its own: a
-        stream's legs hold the array while they run, and nothing donates
-        it."""
+        compute use — not at construction.  The reducer keeps no bank of
+        its own: a stream's legs hold the array while they run, and
+        nothing donates it."""
         return coeff_bank(self.ntap, nfft, self.window, self.timeline)
 
     @property
     def _coeffs(self):
         return self._coeffs_for(self.nfft)
 
-    def _tune_fingerprint_kw(self) -> Dict:
-        """The (rig, workload-shape) fingerprint components of this
-        reduction — what a tuning profile is keyed under
-        (:func:`blit.tune.rig_fingerprint`)."""
-        return dict(
-            nfft=self.nfft, ntap=self.ntap, nint=self.nint,
-            stokes=self.stokes, window=self.window, fqav_by=self.fqav_by,
-            dtype=self.dtype, fft_method=self.fft_method, nbits=self.nbits,
-            workload="reduce",
-        )
-
     def tuning_provenance(self) -> Dict:
-        """Where this reducer's ingest knobs came from, so every recorded
-        number can name the profile (or default) behind it."""
-        prov = {
+        """This reducer's ingest knobs and where each came from
+        (``explicit``: the caller's; ``default``: derived here), so every
+        recorded number can name them."""
+        return {
             "chunk_frames": self.chunk_frames,
             "prefetch_depth": self.prefetch_depth,
             "out_depth": self.out_depth,
             "sources": dict(self._knob_sources),
         }
-        if self._tuning_profile is not None:
-            prov["profile"] = self._tuning_profile.provenance()
-        if self._profile_nchan_mismatch is not None:
-            prov["profile_nchan_mismatch"] = {
-                "tuned": self._profile_nchan_mismatch,
-                "stream": self._stream_nchan,
-            }
-        return prov
-
-    def _note_stream_nchan(self, nchan: int) -> None:
-        """Profile-staleness guard: the rig fingerprint deliberately
-        excludes the recording's channel count (lookup happens at
-        construction, before any recording is open, and tuning transfers
-        across same-shaped workloads) — but per-chunk staging bytes and
-        stage cost scale linearly with it.  Warn once per stream when a
-        loaded profile was measured on a different-width recording, and
-        surface the mismatch in :meth:`tuning_provenance`."""
-        if self._stream_nchan == nchan:
-            return
-        self._stream_nchan = nchan
-        prof = self._tuning_profile
-        tuned = int(getattr(prof, "tuned_nchan", 0) or 0) if prof else 0
-        if tuned and tuned != nchan:
-            self._profile_nchan_mismatch = tuned
-            log.warning(
-                "tuning profile %s was measured on a %d-channel recording "
-                "but this stream has %d channels; per-chunk cost scales "
-                "with the channel count — re-run `blit tune` on a matching "
-                "recording (or set chunk_frames/prefetch_depth/out_depth "
-                "explicitly) if ingest underperforms",
-                prof.key[:12], tuned, nchan,
-            )
 
     def _narrow_host(self, slab: np.ndarray) -> np.ndarray:
         """The synchronous-path product narrowing (identity at nbits=32):
@@ -954,8 +871,8 @@ class RawReducer:
             # buffers are safe to hand to the next reducer via the pool.
             self._retire_staging()
 
-    def _slabs(self, raw: GuppiRaw, skip_frames: int, reuse: bool,
-               tuner=None) -> Iterator[Tuple[int, np.ndarray, object]]:
+    def _slabs(self, raw: GuppiRaw, skip_frames: int,
+               reuse: bool) -> Iterator[Tuple[int, np.ndarray, object]]:
         """Every product slab of a reduction in stream order, narrowed to
         the product's on-disk form: ``(product index, data, release)``.
         On the synchronous path (``async_output=False``, the seed's
@@ -963,8 +880,7 @@ class RawReducer:
         and narrowed on the host; else :meth:`_stream_async`, narrowed on
         the device."""
         if self.async_output:
-            for slab in self._stream_async(raw, skip_frames, reuse=reuse,
-                                           tuner=tuner):
+            for slab in self._stream_async(raw, skip_frames, reuse=reuse):
                 # payload: the product's label (None: the only one)
                 yield int(slab.payload or 0), slab.data, slab.release
             return
@@ -978,7 +894,7 @@ class RawReducer:
                 yield k, self._narrow_host(out), None
 
     def _stream_async(self, raw: GuppiRaw, skip_frames: int,
-                      reuse: bool, tuner=None) -> Iterator["object"]:
+                      reuse: bool) -> Iterator["object"]:
         """The overlapped streaming core behind :meth:`stream` and
         :meth:`_pump`: async-dispatch each chunk, hand the in-flight
         outputs to an :class:`blit.outplane.OutputRotation` readback
@@ -1036,8 +952,6 @@ class RawReducer:
                             out, self.nbits, self.quant_scale,
                             self.quant_offset)) for k, out in outs]
                         token = [out for _, out in outs]
-                if tuner is not None:
-                    tuner.observe_chunk()
                 for slab in rot.put(token, nbytes=chunk.nbytes,
                                     on_consumed=chunk.release,
                                     outs=[(out, st.legs[k].label)
@@ -1084,18 +998,6 @@ class RawReducer:
         if len(writers) != len(self.products):
             raise ValueError(f"{len(self.products)} products, "
                              f"{len(writers)} writers")
-        tuner = None
-        if self.tune_online and self.async_output and not self.also:
-            from blit.tune import OnlineTuner
-
-            tuner = OnlineTuner(
-                self.timeline,
-                {"chunk_frames": self.chunk_frames,
-                 "prefetch_depth": self.prefetch_depth,
-                 "out_depth": self.out_depth},
-                # A carried integration does not bind the chunk size.
-                nint=1 if self._carries else self.nint,
-            )
         if self.async_output:
             sinks = [AsyncSink(
                 w, depth=max(2, self.out_depth), timeline=self.timeline,
@@ -1112,7 +1014,7 @@ class RawReducer:
                 out=str(getattr(writers[0], "path", "")),
             ):
                 for k, data, release in self._slabs(
-                        raw, skip_frames, reuse=True, tuner=tuner):
+                        raw, skip_frames, reuse=True):
                     sinks[k].append(data, release=release)
                 # Final flush barrier + writer finalization; the write
                 # tail is streaming wall time like the readback tail.
@@ -1139,9 +1041,6 @@ class RawReducer:
         if self.async_output:
             self.timeline.overlap_efficiency()
         self._retire_staging()
-        if tuner is not None:
-            tuner.maybe_persist(tuned_nchan=self._stream_nchan or 0,
-                                **self._tune_fingerprint_kw())
         return [sink.nsamps for sink in sinks]
 
     def _producer(
@@ -1223,7 +1122,6 @@ class RawReducer:
             t0, nt = to_skip, nt - to_skip
             to_skip = 0
             nchan = hdr["OBSNCHAN"]
-            self._note_stream_nchan(nchan)
             npol = 2 if hdr["NPOL"] > 2 else hdr["NPOL"]
             if head is None and not emitted:
                 head = self._head_slab = slab((nchan, state, npol, 2),

@@ -98,9 +98,8 @@ class DedopplerReducer:
     # "pallas" | "auto"; interpret=True runs the pallas kernel on CPU.
     kernel: str = "auto"
     interpret: bool = False
-    # None = the rig's tuning profile via the inner RawReducer
-    # (blit/tune.py), else the RawReducer defaults.
-    prefetch_depth: Optional[int] = None
+    # The inner RawReducer's ingest knobs, with its defaults.
+    prefetch_depth: int = 2
     out_depth: Optional[int] = None
     chunk_frames: Optional[int] = None
     timeline: Timeline = field(default_factory=Timeline)
@@ -142,9 +141,8 @@ class DedopplerReducer:
             async_output=self.async_output,
             output_stall_timeout_s=self.output_stall_timeout_s,
         )
-        # The inner reducer resolved the knobs (profile or default) —
-        # mirror them so this reducer's own rotation depths agree.
-        self.prefetch_depth = self._red.prefetch_depth
+        # The inner reducer resolved the knobs — mirror them so this
+        # reducer's own rotation depths agree.
         self.out_depth = self._red.out_depth
         if self.chunk_frames is None:
             self.chunk_frames = self._red.chunk_frames

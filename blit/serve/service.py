@@ -653,18 +653,9 @@ class ProductService:
                 if sp is not None:
                     sp.attrs = dict(sp.attrs, fp=fp[:16])
                 # Construct INSIDE the span/stage: reducer construction
-                # (tuning-profile lookup, and at hi-res nfft the PFB
-                # coefficient build) is request work — it must show in
-                # the request's timing and parent onto its trace.  The
-                # resolved profile key lands on the live span so a trace
-                # names which knob set served the request.
-                reducer = request.reducer()
-                prov_fn = getattr(reducer, "tuning_provenance", None)
-                prov = prov_fn() if prov_fn is not None else {}
-                tuned = prov.get("profile", {}).get("key", "")[:16]
-                if sp is not None and tuned:
-                    sp.attrs = dict(sp.attrs or {}, tuned=tuned)
-                header, data = reducer.reduce(request.raw_source)
+                # is request work — it must show in the request's timing
+                # and parent onto its trace.
+                header, data = request.reducer().reduce(request.raw_source)
             data = self.cache.put(fp, header, data,
                                   recipe=request.recipe())
             # Tier accounting (ISSUE 19): this request was satisfied by

@@ -635,9 +635,8 @@ def stream_reduce(source: ChunkSource, out_path: str, *,
                           heartbeat=heartbeat, start_rows=start_rows)
         hdr["nsamps"] = red._pump(live, tap,
                                   skip_frames=start_rows * red.nint)
-    # Which ingest knobs the live reduction ran (tuning profile /
-    # defaults — blit/tune.py): a slow live session's report names the
-    # knob source before anyone reaches for `blit tune`.
+    # Which ingest knobs the live reduction ran, and whose they were
+    # (the caller's or the defaults).
     hdr["stream_tuning"] = red.tuning_provenance()
     hdr.update(live.stream_report())
     hdr["stream_degraded_spectra"] = live.degraded_rows(

@@ -115,7 +115,6 @@ def require_tpu(rehearse: bool) -> dict:
     except Exception:  # noqa: BLE001 — a version label only
         libtpu = "unknown"
     from blit.device import use_compile_cache
-    from blit.tune import profile_dir
 
     cache = use_compile_cache()
     say("device", **device, jax=jax.__version__, jaxlib=jaxlib.__version__,
@@ -123,7 +122,6 @@ def require_tpu(rehearse: bool) -> dict:
         compile_cache=cache,
         compile_cache_entries=len(os.listdir(cache))
         if os.path.isdir(cache) else 0,
-        tune_dir=profile_dir(),
         JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS"),
         cpus=os.cpu_count())
     if rehearse:

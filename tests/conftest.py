@@ -21,21 +21,8 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
-# Hermetic ingest-plane env (ISSUE 8): the suite must not pick up a real
-# per-rig profile from ~/.cache/blit/tune OR from a BLIT_TUNE_DIR the
-# shell happens to export (reducer knob defaults are asserted by tests),
-# nor write into either, and a shell-exported staging budget (the
-# hostmem.py A/B lever) must not reshape SlabPool behavior under test.
-# An empty per-session dir keeps the tuning machinery ENABLED — tests
-# that exercise it point BLIT_TUNE_DIR at their own tmp_path via
-# monkeypatch.
-import atexit
-import shutil
-import tempfile
-
-os.environ["BLIT_TUNE_DIR"] = tempfile.mkdtemp(prefix="blit-tune-test-")
-atexit.register(shutil.rmtree, os.environ["BLIT_TUNE_DIR"],
-                ignore_errors=True)
+# A shell-exported staging budget (the hostmem.py A/B lever) must not
+# reshape SlabPool behavior under test.
 os.environ.pop("BLIT_STAGING_BYTES", None)
 
 import logging

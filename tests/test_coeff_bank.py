@@ -173,8 +173,7 @@ def test_two_reducers_of_one_process_share_the_banks(tmp_path):
         # "matmul" is what the chip runs, and the one FFT whose bytes
         # repeat on the CPU at nfft 1024.
         red = RawReducer(nfft=nfft, nint=nint, also=tuple(also),
-                         chunk_frames=4, tune_online=False,
-                         fft_method="matmul")
+                         chunk_frames=4, fft_method="matmul")
         outs.append([str(tmp_path / f"{tag}{k}.fil") for k in range(3)])
         red.reduce_to_files(raw, outs[-1])
         tables.append(red.timeline.report())

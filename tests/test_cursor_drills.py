@@ -38,7 +38,7 @@ def _clean_faults():
 
 
 def _kw():
-    return dict(nfft=NFFT, chunk_frames=CF, tune_online=False)
+    return dict(nfft=NFFT, chunk_frames=CF)
 
 
 def _bytes(path):

@@ -107,8 +107,7 @@ def reference(raw_path, nint, carried):
 
 
 def reducer(cf, nint, **kw):
-    return RawReducer(nfft=NFFT, nint=nint, chunk_frames=cf,
-                      tune_online=False, **kw)
+    return RawReducer(nfft=NFFT, nint=nint, chunk_frames=cf, **kw)
 
 
 @pytest.fixture

@@ -102,7 +102,7 @@ def test_carried_integration_matches_whole_file(tmp_path, case, stokes,
     frames = rows * nint + tail
     raw = _recording(tmp_path, nfft, frames)
     red = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf, stokes=stokes,
-                     async_output=async_output, tune_online=False)
+                     async_output=async_output)
     assert red.chunk_frames == cf and red._carries
     hdr, got = red.reduce(raw)
     want = _reference(raw, nfft, nint, stokes, rows)
@@ -126,7 +126,7 @@ def test_carried_integration_matches_whole_file(tmp_path, case, stokes,
     # writer too (the pump, the sink and the manifest see rows only).
     out = str(tmp_path / "again.fil")
     red2 = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf, stokes=stokes,
-                      async_output=async_output, tune_online=False)
+                      async_output=async_output)
     fhdr = red2.reduce_to_file(raw, out)
     assert fhdr["nsamps"] == rows
     assert read_fil_data(out)[1].tobytes() == got.tobytes()
@@ -155,8 +155,7 @@ def test_integration_inside_a_chunk_takes_the_old_path(tmp_path):
     # every existing product takes).
     nfft, nint, cf = 32, 4, 8
     raw = _recording(tmp_path, nfft, 3 * cf)
-    red = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf,
-                     tune_online=False)
+    red = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf)
     assert not red._carries
     _, got = red.reduce(raw)
     st = red.timeline.report()
@@ -171,8 +170,8 @@ def test_integration_inside_a_chunk_takes_the_old_path(tmp_path):
         for k in range(3)]
     assert got.tobytes() == np.concatenate(want).tobytes()
     # And a carried reduction of the same file agrees to rounding.
-    _, carried = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf - 1,
-                            tune_online=False).reduce(raw)
+    _, carried = RawReducer(nfft=nfft, nint=nint,
+                            chunk_frames=cf - 1).reduce(raw)
     assert carried.shape == got.shape
     assert _rel_err(carried, got) < 1e-5
 
@@ -192,8 +191,7 @@ def test_channel_groups_are_laid_out_once_per_stream(tmp_path, monkeypatch):
         return asked[-1]
 
     monkeypatch.setattr(RawReducer, "_channel_block", sized_by_shape)
-    red = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf,
-                     tune_online=False)
+    red = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf)
     _, got = red.reduce(raw)
     assert asked == [2]  # asked once, for the stream's first chunk
     # ... and the filter state is laid out with the accumulators: two
@@ -203,8 +201,7 @@ def test_channel_groups_are_laid_out_once_per_stream(tmp_path, monkeypatch):
     assert st["state.head"]["calls"] == 2
     assert st["state.carry"]["calls"] == 2 * (dispatches - 1)
     monkeypatch.undo()
-    _, whole = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf,
-                          tune_online=False).reduce(raw)
+    _, whole = RawReducer(nfft=nfft, nint=nint, chunk_frames=cf).reduce(raw)
     assert got.shape == (rows, 1, 4 * nfft)
     assert _rel_err(got, whole) < 1e-6
     assert _rel_err(got, _reference(raw, nfft, nint, "I", rows)) < TOL
@@ -251,8 +248,7 @@ class TestResumeInsideAnIntegration:
         faults.reset_counters()
 
     def _kw(self):
-        return dict(nfft=self.NFFT, nint=self.NINT, chunk_frames=self.CF,
-                    tune_online=False)
+        return dict(nfft=self.NFFT, nint=self.NINT, chunk_frames=self.CF)
 
     def _payload(self, path):
         if path.endswith(".h5"):

@@ -44,7 +44,7 @@ def _isolate_quarantine_watch():
 
 
 def _kw(cf=4):
-    return dict(nfft=NFFT, chunk_frames=cf, tune_online=False)
+    return dict(nfft=NFFT, chunk_frames=cf)
 
 
 def _flip_byte(path, back=9):
@@ -125,8 +125,7 @@ class TestIngestDigests:
         # delivery of its own): after=3 targets exactly block 2.
         raw = self._setup(tmp_path)
         integrity.write_raw_digests(raw)
-        kw = dict(nfft=NFFT, chunk_frames=4 * 512 // NFFT - 3,
-                  tune_online=False)
+        kw = dict(nfft=NFFT, chunk_frames=4 * 512 // NFFT - 3)
         rdr0 = GuppiRaw(raw, native=False)
         blocks = [np.array(rdr0.read_block(i)) for i in range(4)]
         blocks[2][:] = 0

@@ -44,7 +44,7 @@ def _isolate_quarantine_watch():
 
 
 def _kw():
-    return dict(nfft=NFFT, chunk_frames=CF, tune_online=False)
+    return dict(nfft=NFFT, chunk_frames=CF)
 
 
 def _bytes(path):

@@ -376,7 +376,7 @@ class TestMetricsPublisher:
         raw = tmp_path / "r.raw"
         synth_raw(str(raw), nblocks=1, obsnchan=2,
                   ntime_per_block=(8 + 3) * 256)
-        RawReducer(nfft=256, tune_online=False).reduce_to_file(
+        RawReducer(nfft=256).reduce_to_file(
             str(raw), str(tmp_path / "r.fil"))
         monitor.shutdown_publisher()
         report, samples = monitor.merge_spool(str(spool))

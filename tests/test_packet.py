@@ -344,7 +344,7 @@ class TestSessionOrchestration:
             "source": dict({"kind": "packet-replay", "raw": str(raw),
                             "rate": 1e6, "packet_ntime": 64}, **src_kw),
             "knobs": dict(nfft=NFFT, nint=NINT,
-                          chunk_frames=CHUNK_FRAMES, tune_online=False),
+                          chunk_frames=CHUNK_FRAMES),
         }
 
     def test_source_from_spec_dispatch(self, tmp_path):
@@ -407,8 +407,7 @@ class TestSessionOrchestration:
         out = tmp_path / "s.fil"
         sup = StreamSupervisor(
             str(raw), str(out), kind="reduce",
-            knobs=dict(nfft=NFFT, nint=NINT, chunk_frames=CHUNK_FRAMES,
-                       tune_online=False),
+            knobs=dict(nfft=NFFT, nint=NINT, chunk_frames=CHUNK_FRAMES),
             source={"kind": "packet-replay", "raw": str(raw),
                     "rate": 1e6, "packet_ntime": 64,
                     "drop_blocks": [3]},

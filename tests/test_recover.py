@@ -225,13 +225,12 @@ class TestStreamSupervisorDrill:
         synth_raw(raw, nblocks=4, obsnchan=2, ntime_per_block=512,
                   seed=3)
         oracle = str(tmp_path / "oracle.fil")
-        RawReducer(nfft=NFFT, chunk_frames=WF,
-                   tune_online=False).reduce_to_file(raw, oracle)
+        RawReducer(nfft=NFFT, chunk_frames=WF).reduce_to_file(raw, oracle)
         out = str(tmp_path / "live.fil")
         tl = Timeline()
         sup = StreamSupervisor(
             raw, out, kind="reduce",
-            knobs=dict(nfft=NFFT, chunk_frames=WF, tune_online=False),
+            knobs=dict(nfft=NFFT, chunk_frames=WF),
             replay_rate=500.0, faults="stream.chunk:kill:after=2",
             lease_ttl_s=3.0, poll_s=0.05, max_attempts=3, timeline=tl)
         rep = sup.run()

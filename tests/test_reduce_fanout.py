@@ -86,8 +86,7 @@ def rel_err(got, want):
 def fanout(products=PRODUCTS, **kw):
     (nfft, nint), *also = products
     kw.setdefault("chunk_frames", 4)
-    return RawReducer(nfft=nfft, nint=nint, also=tuple(also),
-                      tune_online=False, **kw)
+    return RawReducer(nfft=nfft, nint=nint, also=tuple(also), **kw)
 
 
 def paths(tmp_path, tag="out"):
@@ -207,7 +206,7 @@ def test_products_in_another_order_and_a_shared_nfft(tmp_path, recording):
     raw, stream = recording
     prods = [(8, 128), (1024, 3), (1024, 5), (64, 51)]
     red = RawReducer(nfft=8, nint=128, also=tuple(prods[1:]),
-                     chunk_frames=512, tune_online=False)
+                     chunk_frames=512)
     outs = [str(tmp_path / f"p{k}.fil") for k in range(4)]
     red.reduce_to_files(raw, outs)
     for (nfft, nint), out in zip(prods, outs):
@@ -223,8 +222,7 @@ def test_a_first_product_that_integrates_inside_and_owns_no_head(
     the head's and the chunk's, and every frame is counted once."""
     raw, stream = recording
     prods = [(8, 3), (1024, 3)]
-    red = RawReducer(nfft=8, nint=3, also=((1024, 3),), chunk_frames=384,
-                     tune_online=False)
+    red = RawReducer(nfft=8, nint=3, also=((1024, 3),), chunk_frames=384)
     assert [red._leg_carries(k) for k in range(2)] == [False, False]
     outs = [str(tmp_path / f"p{k}.fil") for k in range(2)]
     red.reduce_to_files(raw, outs)
@@ -246,8 +244,7 @@ def test_each_product_equals_its_own_command(tmp_path, recording):
         # folds on the same grid.  The others' own commands dispatch on a
         # grid of their own.
         kw = dict(chunk_frames=4) if k == 0 else {}
-        RawReducer(nfft=nfft, nint=nint, tune_online=False,
-                   **kw).reduce_to_file(raw, alone)
+        RawReducer(nfft=nfft, nint=nint, **kw).reduce_to_file(raw, alone)
         got, want = read_fil_data(out)[1], read_fil_data(alone)[1]
         assert got.shape == want.shape
         # Same frames, same order of addition.  Not held to the byte on

@@ -376,7 +376,7 @@ class TestLiveAdmission:
         from blit.pipeline import RawReducer
 
         oracle = str(tmp_path / "oracle.fil")
-        RawReducer(nfft=NFFT, nint=1, tune_online=False).reduce_to_file(
+        RawReducer(nfft=NFFT, nint=1).reduce_to_file(
             raw, oracle)
         out = str(tmp_path / "live.fil")
         svc = make_service(tmp_path, concurrency=2)

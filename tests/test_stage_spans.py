@@ -350,7 +350,7 @@ class TestSpansAcrossAYield:
         assert by["reduce.stream"]["parent"] == by["first"]["span"]
 
     def test_a_stage_yields_its_span(self):
-        """The serving door adds its attrs (``fp``, ``tuned``, ``out``) to
+        """The serving door adds its attrs (``fp``, ``out``) to
         the stage's own span instead of wrapping it in a second one; a
         stage nested in one of its own name still gets its interval."""
         observability.tracer().reset()
@@ -619,7 +619,7 @@ class TestParts:
                   seed=7, tone_chan=1)
         observability.tracer().reset()
         red = RawReducer(nfft=1024, nint=3, also=((8, 128), (64, 51)),
-                         chunk_frames=4, tune_online=False)
+                         chunk_frames=4)
         outs = [str(tmp_path / f"p{k}.fil") for k in range(3)]
         within(300, lambda: red.reduce_to_files(raw, outs))
         table = red.timeline.report()

@@ -354,8 +354,7 @@ class TestTheLinkIsBudgetedByTransfer:
         synth_raw(p, nblocks=2, obsnchan=2, ntime_per_block=2048)
 
         def reducer():
-            return RawReducer(nfft=NFFT, nint=nint, chunk_frames=4,
-                              tune_online=False)
+            return RawReducer(nfft=NFFT, nint=nint, chunk_frames=4)
 
         reducer().reduce_to_file(p, str(tmp_path / "ref.fil"))
         seen.clear()
@@ -425,8 +424,7 @@ class TestTheLinkIsBudgetedByTransfer:
         assert self.CHUNK // 2 < link  # a group fits, twice a product not
         monkeypatch.setattr(device, "host_link_bytes", lambda: link)
         monkeypatch.setattr(device, "_HOST_LINK", device.HostLink())
-        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4,
-                         tune_online=False)
+        red = RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4)
         put = jax.device_put
 
         def marked_put(*a, **kw):
@@ -438,8 +436,7 @@ class TestTheLinkIsBudgetedByTransfer:
         red.reduce_to_file(p, str(tmp_path / "got.fil"))
         spans = observability.tracer().span_dicts()
         monkeypatch.undo()
-        RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4,
-                   tune_online=False).reduce_to_file(
+        RawReducer(nfft=NFFT, nint=NINT, chunk_frames=4).reduce_to_file(
                        p, str(tmp_path / "ref.fil"))
         assert filecmp.cmp(tmp_path / "ref.fil", tmp_path / "got.fil",
                            shallow=False)

@@ -51,7 +51,7 @@ def _bytes(path):
 
 
 def _kw():
-    return dict(nfft=NFFT, chunk_frames=CF, tune_online=False)
+    return dict(nfft=NFFT, chunk_frames=CF)
 
 
 class TestStreamCursor:
@@ -70,8 +70,7 @@ class TestStreamCursor:
         assert cur.matches(red, "sess.raw", "filterbank")
         assert not cur.matches(red, "other.raw", "filterbank")
         assert not cur.matches(red, "sess.raw", "hits")
-        other = RawReducer(nfft=NFFT * 2, chunk_frames=CF,
-                           tune_online=False)
+        other = RawReducer(nfft=NFFT * 2, chunk_frames=CF)
         assert not cur.matches(other, "sess.raw", "filterbank")
 
     def test_hits_claim_ledger(self):
